@@ -5,34 +5,6 @@ Public API parity with the reference ``deepspeed/__init__.py``:
 (:273), ``add_config_arguments`` (:250) — implemented over JAX/XLA/Pallas.
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.7 ships shard_map under experimental with the older kwarg
-    # surface; the codebase uses the modern ``jax.shard_map`` spelling —
-    # install a translating alias so one tree runs on both:
-    #   check_vma=...  -> check_rep=...
-    #   axis_names=...  -> dropped: every call site's specs leave the
-    #     non-manual axes' dims unsharded, so full-manual mode computes the
-    #     same result (those axes just see replicated blocks). The literal
-    #     translation (``auto = mesh axes - axis_names``) is NOT usable here:
-    #     0.4.x partial-auto aborts XLA on the qgZ program and raises
-    #     NotImplementedError on all_to_all (Ulysses).
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map_compat(f, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        # default the rep check OFF: the old checker has no replication rule
-        # for primitives the modern one handles (e.g. remat's `name`), and
-        # it is a validation layer only
-        kw.setdefault("check_rep", False)
-        kw.pop("axis_names", None)
-        return _exp_shard_map(f, **kw)
-
-    _shard_map_compat._dstpu_shim = True  # old-jax sentinel (see engine._donate)
-    _jax.shard_map = _shard_map_compat
-
 from . import comm  # noqa: F401
 from .accelerator import get_accelerator  # noqa: F401
 from .runtime.config import DeepSpeedConfig

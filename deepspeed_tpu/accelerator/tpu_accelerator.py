@@ -8,8 +8,8 @@ import functools
 
 from .abstract_accelerator import DeepSpeedAccelerator
 
-# per-chip HBM fallback for runtimes that don't expose memory_stats()
-# (virtual CPU meshes, some plugin backends); live stats win when present
+# per-chip HBM for runtimes that don't expose memory_stats(); live stats win
+# when present. A device kind that is in neither is an error, not 16 GB
 _HBM_TABLE = {
     "TPU v4": 32e9,
     "TPU v5 lite": 16e9,
@@ -56,10 +56,7 @@ class TPU_Accelerator(DeepSpeedAccelerator):
 
     # ------------------------- device properties -------------------------
     def device_kind(self, device_index=0) -> str:
-        try:
-            return self.devices()[device_index].device_kind
-        except Exception:
-            return "unknown"
+        return self.devices()[device_index].device_kind
 
     def total_memory(self, device_index=0) -> int:
         """Per-chip HBM: live runtime stats when available, else the known
@@ -68,7 +65,12 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         live = super().total_memory(device_index)
         if live:
             return live
-        return int(_HBM_TABLE.get(self.device_kind(device_index), 16e9))
+        kind = self.device_kind(device_index)
+        if kind not in _HBM_TABLE:
+            raise KeyError(
+                f"device_kind {kind!r} reports no memory_stats() and is not "
+                "in tpu_accelerator._HBM_TABLE: add it with its source")
+        return int(_HBM_TABLE[kind])
 
     def memory_stats(self, device_index=0) -> dict:
         return self._stats(device_index)

@@ -96,7 +96,7 @@ def _jax_version() -> str:
 
 def _sub_jaxprs(value):
     """Yield every (Closed)Jaxpr nested in an eqn param value."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
     elif isinstance(value, Jaxpr):

@@ -478,12 +478,7 @@ def all_reduce(tensor, op: str = "sum", group=None, async_op: bool = False, log_
     def _reduce(x):
         return inprog_all_reduce(x, active, op)
 
-    try:
-        from jax import shard_map  # jax >= 0.7 top-level export
-    except ImportError:  # older jax: the function lives under experimental
-        from jax.experimental.shard_map import shard_map
-
-    f = shard_map(_reduce, mesh=mesh, in_specs=in_spec, out_specs=_drop_axes(in_spec, active))
+    f = jax.shard_map(_reduce, mesh=mesh, in_specs=in_spec, out_specs=_drop_axes(in_spec, active))
     out = jax.jit(f, out_shardings=NamedSharding(mesh, PartitionSpec()))(tensor)
     return out
 

@@ -423,6 +423,24 @@ class InferenceEngineV2:
         self._prefill_fns["ragged"] = fn
         return fn
 
+    def lower_ragged(self, rows: int, greedy: bool = True):
+        """The paged program lowered at ``rows`` token-rows, as a
+        ``jax.stages.Lowered``: ``token_budget`` rows is the mixed
+        prefill+decode shape, ``max_seqs`` rows the decode round. ``.compile()``
+        ``.as_text()`` shows whether the paged-decode kernel is in it. Nothing
+        runs and the pool is not donated."""
+        M = self.max_seqs
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        f32 = jax.ShapeDtypeStruct((M,), jnp.float32)
+        return self._get_ragged().lower(
+            self.params, self.kv, i32(rows, 1),
+            i32(rows, self.block_mgr.max_blocks_per_seq), i32(rows),
+            i32(M), i32(M), i32(M), i32(M), f32, i32(M), f32,  # see ragged()
+            self._bias(), greedy)
+
     def _get_cow(self):
         """Single fixed-shape block-copy program for copy-on-write: duplicate
         pool block ``src`` into ``dst``. ``src``/``dst`` are traced scalars, so

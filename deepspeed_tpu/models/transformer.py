@@ -38,6 +38,7 @@ from jax.sharding import PartitionSpec as P
 from ..comm.topology import ZERO_AXES
 from ..ops.quantizer.woq import dequant_params as _dequant_woq
 from ..ops.transformer.attention import attention as _attention_op
+from ..utils.logging import logger
 
 
 @dataclass(frozen=True)
@@ -576,15 +577,21 @@ class TransformerLM:
             # NOTE: evaluated at TRACE time — the env override (used by tests
             # to exercise this branch in interpret mode) and set_default_impl
             # must be set before the engine compiles its decode program
-            use_kernel = (
-                S == 1 and cfg.pos_embedding != "alibi"
-                and not cfg.logit_softcap
-                and get_default_impl() != "xla"  # operator escape hatch
-                and hd in (64, 128, 256)  # Mosaic-validated head dims
-                and kp.shape[2] % 8 == 0  # block_size sublane alignment
-                and (jax.default_backend() == "tpu"
-                     or os.environ.get("DSTPU_FORCE_PAGED_KERNEL") == "1")
-            )
+            want_kernel = S == 1 and get_default_impl() != "xla" and (
+                jax.default_backend() == "tpu"
+                or os.environ.get("DSTPU_FORCE_PAGED_KERNEL") == "1")
+            # what the kernel documents as unsupported; each gives way to the
+            # gather path below, and says so as the program is traced
+            gaps = [why for bad, why in (
+                (cfg.pos_embedding == "alibi", "ALiBi bias"),
+                (bool(cfg.logit_softcap), "logit softcap"),
+                (hd not in (64, 128, 256), f"head_dim {hd}"),
+                (kp.shape[2] % 8 != 0, f"block size {kp.shape[2]} % 8 != 0"),
+            ) if bad]
+            use_kernel = want_kernel and not gaps
+            if want_kernel and gaps:
+                logger.warning("paged decode takes the XLA gather path, not "
+                               f"the Pallas kernel: {', '.join(gaps)}")
             if use_kernel:
                 # Pallas paged decode: pool blocks stream via the block table's
                 # index map — no materialized gather copy (paged_attention.py)

@@ -84,9 +84,7 @@ def quantized_reduce_scatter(grad, axis_name: str, num_bits: int = 8,
     dequantize + local sum (reference ``runtime/comm/coalesced_collectives.py``
     ``all_to_all_quant_reduce``). Call inside shard_map over ``axis_name``; the
     input's leading dim must equal the axis size (one chunk per destination)."""
-    # jax < 0.6 has no lax.axis_size; psum of a literal folds to a static int
-    n = (jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size")
-         else jax.lax.psum(1, axis_name))
+    n = jax.lax.axis_size(axis_name)
     assert grad.shape[0] == n, "leading dim must equal axis size"
 
     def q(chunk):

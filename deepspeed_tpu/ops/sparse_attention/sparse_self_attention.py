@@ -12,9 +12,11 @@ here is what it would consume.
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...utils.logging import logger
 from ..transformer.attention import attention
 from .sparsity_config import SparsityConfig
 
@@ -57,21 +59,19 @@ class SparseSelfAttention:
         S = q.shape[1]
         if causal is None:
             causal = getattr(self.config, "attention", "bidirectional") == "unidirectional"
-        if use_kernel != "never":
+        # interpreted Pallas off the TPU would be a silent massive slowdown
+        # against the fused XLA mask path, so "auto" means the kernel on TPU
+        if use_kernel == "always" or (
+                use_kernel == "auto" and jax.default_backend() == "tpu"):
+            from .block_sparse_kernel import block_sparse_attention
+
             try:
-                import jax
-
-                # mirror _auto_impl: interpreted Pallas on CPU/GPU would be a
-                # silent massive slowdown vs the fused XLA mask path
-                if use_kernel == "auto" and jax.default_backend() != "tpu":
-                    raise NotImplementedError("block_sparse kernel: TPU only")
-                from .block_sparse_kernel import block_sparse_attention
-
                 return block_sparse_attention(
                     q, k, v, self._layout(S), self.config.block, causal=causal)
-            except NotImplementedError:
+            except NotImplementedError as e:
                 if use_kernel == "always":
                     raise
+                logger.warning(f"sparse attention takes the masked XLA path: {e}")
         bias = self._bias(S)  # (H, S, S)
         # bias broadcast: attention expects (B?, h, groups, Sq, Sk)-compatible
         return attention(q, k, v, causal=causal,

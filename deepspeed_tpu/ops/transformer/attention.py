@@ -8,15 +8,21 @@ attention kernel (``ops/transformer/flash_attention.py``); the XLA einsum path
 below is the always-available fallback and the numerics oracle for kernel tests
 (mirroring the reference's kernel-vs-torch test strategy, SURVEY.md §4).
 
-Dispatch: ``attention()`` picks the registered implementation ("pallas" on real
-TPU when shapes allow, "xla" otherwise) — the op-builder registry seam
-(reference ``op_builder/builder.py`` + ``accelerator.create_op_builder``).
+Dispatch: ``attention()`` picks the registered implementation — the op-builder
+registry seam (reference ``op_builder/builder.py`` +
+``accelerator.create_op_builder``). On a TPU backend that is the flash kernel;
+it gives way to the XLA path only for the features it documents as unsupported
+(bias, softcap, q_offset) and for shapes it refuses with ``UnsupportedShape``,
+and the latter is logged when the program is traced. A kernel that fails to
+import, lower or fit raises.
 """
 
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from ...utils.logging import logger
 
 _IMPLS = {}
 _DEFAULT_IMPL = None
@@ -39,19 +45,24 @@ def get_default_impl() -> Optional[str]:
     return _DEFAULT_IMPL
 
 
-def _auto_impl(q) -> str:
+def _auto_impl() -> str:
     if _DEFAULT_IMPL is not None:
         return _DEFAULT_IMPL
-    try:
-        platform = q.devices().pop().platform if hasattr(q, "devices") else jax.default_backend()
-    except Exception:
-        platform = jax.default_backend()
-    if platform == "tpu" and "pallas_flash" in _IMPLS:
-        # flash kernel needs seq multiple of its block size and head_dim ≤ lane width
-        S, hd = q.shape[1], q.shape[-1]
-        if S % 128 == 0 and hd in (64, 128, 256):
-            return "pallas_flash"
-    return "xla"
+    return "pallas_flash" if jax.default_backend() == "tpu" else "xla"
+
+
+class UnsupportedFeature(NotImplementedError):
+    """A kernel asked for a feature it documents as unsupported (bias, softcap,
+    q_offset): ``attention()`` takes the XLA path. Its own class, so that a
+    ``NotImplementedError`` out of lowering or compiling is never swallowed."""
+
+
+class UnsupportedShape(ValueError):
+    """A kernel refusing a shape (block divisibility, head size, VMEM window).
+
+    ``attention()`` lets it give way to the XLA path only when it chose the
+    kernel itself, and logs the reason as the program is traced; a caller that
+    named the kernel gets the error."""
 
 
 @register_impl("xla")
@@ -92,23 +103,19 @@ def attention(q, k, v, *, causal=True, q_offset=0, num_kv_groups=1,
     q: (B, Sq, num_heads, head_dim); k/v: (B, Skv, kv_heads, head_dim).
     Returns (B, Sq, num_heads, head_dim) in q.dtype.
     """
-    name = impl or _auto_impl(q)
-    fn = _IMPLS.get(name, _IMPLS["xla"])
+    kw = dict(causal=causal, q_offset=q_offset, num_kv_groups=num_kv_groups,
+              softcap=softcap, bias=bias, scale=scale)
     try:
-        return fn(q, k, v, causal=causal, q_offset=q_offset,
-                  num_kv_groups=num_kv_groups, softcap=softcap, bias=bias, scale=scale)
-    except NotImplementedError:
-        return _IMPLS["xla"](q, k, v, causal=causal, q_offset=q_offset,
-                             num_kv_groups=num_kv_groups, softcap=softcap,
-                             bias=bias, scale=scale)
-
-
-# register the Pallas kernel lazily (import cost + TPU-only lowering)
-def _try_register_pallas():
-    try:
-        from . import flash_attention  # noqa: F401  (registers itself)
-    except Exception:  # pragma: no cover - pallas unavailable
+        return _IMPLS[impl or _auto_impl()](q, k, v, **kw)
+    except UnsupportedFeature:
         pass
+    except UnsupportedShape as e:
+        if impl is not None or _DEFAULT_IMPL is not None:
+            raise
+        # trace time: once per compiled program, never per step
+        logger.warning(f"attention: q{tuple(q.shape)} k{tuple(k.shape)} takes "
+                       f"the XLA einsum path: {e}")
+    return xla_attention(q, k, v, **kw)
 
 
-_try_register_pallas()
+from . import flash_attention  # noqa: E402,F401  (registers "pallas_flash")

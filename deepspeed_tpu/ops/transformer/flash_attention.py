@@ -15,8 +15,9 @@ MXU-aligned (seq_block, head_dim); the public entry transposes from the model's
 q head // groups) for forward/dq; dk/dv are produced per-q-head and group-summed
 by the caller.
 
-Falls back (NotImplementedError → XLA path in ``attention.py``) for: bias,
-softcap, q_offset (cache decode), or shapes not divisible by the block size.
+Gives way to the XLA path in ``attention.py`` for bias, softcap and q_offset
+(cache decode) with ``UnsupportedFeature``, and refuses shapes it cannot tile
+or fit with ``UnsupportedShape``, which ``attention()`` logs.
 """
 
 import functools
@@ -25,7 +26,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .attention import register_impl
+from jax.sharding import PartitionSpec as P
+
+from ...comm.topology import MODEL_AXIS, SEQ_AXIS, ZERO_AXES
+from ..pallas_utils import open_mesh_axes
+from .attention import UnsupportedFeature, UnsupportedShape, register_impl
 
 NEG_INF = -1e30
 
@@ -262,7 +267,7 @@ def flash_attention(q, k, v, *, causal=True, q_offset=0, num_kv_groups=1,
             not isinstance(q_offset, int)) or q_offset != 0:
         # a TRACED q_offset (KV-cache decode under jit/vmap) must also fall
         # back — comparing it would raise TracerBoolConversionError
-        raise NotImplementedError("flash kernel: bias/softcap/q_offset unsupported")
+        raise UnsupportedFeature("flash kernel: bias/softcap/q_offset unsupported")
     B, Sq, nh, hd = q.shape
     Skv = k.shape[1]
 
@@ -276,7 +281,9 @@ def flash_attention(q, k, v, *, causal=True, q_offset=0, num_kv_groups=1,
     block_q = fit(block_q, Sq)
     block_k = fit(block_k, Skv)
     if block_q < 128 or block_k < 128 or hd not in (64, 128, 256):
-        raise NotImplementedError("flash kernel: unsupported shape")
+        raise UnsupportedShape(
+            f"flash kernel needs sequence lengths that are multiples of 128 "
+            f"(got {Sq}, {Skv}) and head_dim in (64, 128, 256) (got {hd})")
     # VMEM budget guard (long-context should use ring attention): the forward
     # stages a full-length K/V window per grid cell; the fused backward
     # additionally holds full-length q/do windows PLUS the revisited fp32 dq
@@ -284,10 +291,29 @@ def flash_attention(q, k, v, *, causal=True, q_offset=0, num_kv_groups=1,
     fwd_bytes = 2 * Skv * hd * k.dtype.itemsize
     bwd_bytes = Sq * hd * 8 + 2 * 512 * hd * k.dtype.itemsize
     if max(fwd_bytes, bwd_bytes) > 12 * 1024 * 1024:
-        raise NotImplementedError("flash kernel: VMEM window exceeds budget")
+        raise UnsupportedShape(
+            f"flash kernel: a {max(fwd_bytes, bwd_bytes)}-byte K/V or q/dq "
+            "window exceeds the 12 MiB VMEM budget")
     scale = scale if scale is not None else hd ** -0.5
-    qt = jnp.transpose(q, (0, 2, 1, 3))
-    kt = jnp.transpose(k, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
-    out = _flash(qt, kt, vt, causal, num_kv_groups, scale, block_q, block_k)
-    return jnp.transpose(out, (0, 2, 1, 3))
+
+    def local(q, k, v):
+        qt = jnp.transpose(q, (0, 2, 1, 3))
+        kt = jnp.transpose(k, (0, 2, 1, 3))
+        vt = jnp.transpose(v, (0, 2, 1, 3))
+        out = _flash(qt, kt, vt, causal, num_kv_groups, scale, block_q, block_k)
+        return jnp.transpose(out, (0, 2, 1, 3))
+
+    mesh, axes = open_mesh_axes()
+    if not axes:
+        return local(q, k, v)
+
+    # under a mesh: every device runs the kernel on its own batch rows and
+    # heads (the model's Ulysses layout: batch over the DP axes, heads over
+    # seq x model); attention needs nothing from another device
+    def over(names):
+        return tuple(a for a in names if a in axes) or None
+
+    spec = P(over(ZERO_AXES), None, over((SEQ_AXIS, MODEL_AXIS)), None)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, axis_names=frozenset(axes),
+                         check_vma=False)(q, k, v)

@@ -21,11 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.5); support both so the
-# kernel loads against whichever jaxlib the image ships
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -192,7 +187,7 @@ def _paged_decode_stream(q, k_pool, v_pool, tables, lens, *, scale):
                           pack=pack),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kvh, g, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
     )(tables, lens, qg, k_pool, v_pool)
@@ -251,7 +246,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lens, *, scale=None,
                           max_blocks=MAXB),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kvh, g, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(tables, lens, qg, k_pool, v_pool)

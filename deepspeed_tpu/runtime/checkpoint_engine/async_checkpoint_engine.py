@@ -18,6 +18,11 @@ Durability contract:
   pointer write on the same FIFO queue (``enqueue_task``), so ``latest`` can
   never point at a tag whose files are still in flight — a crash mid-save
   resumes from the previous complete checkpoint;
+- one checkpoint in flight: ``create(tag)`` — the hook every
+  ``save_checkpoint`` calls before its first save — blocks until everything
+  queued for earlier tags (files and ``latest`` pointer) is on disk. So when
+  save N starts, save N-1 is durable, a crash loses at most the newest tag, and
+  the writer never holds more than one host snapshot of the state;
 - ``wait()`` is the hard barrier (drains the queue, re-raises writer errors);
   ``load()`` on a path with an in-flight save waits for that save first
   (read-your-writes within a process).
@@ -111,6 +116,15 @@ class AsyncCheckpointEngine(NativeCheckpointEngine):
         return seq
 
     # ------------------------------------------------------------------
+    def create(self, tag):
+        """One checkpoint in flight: block until every earlier tag has fully
+        drained (errors stay stored for ``wait()``). Without this the queue —
+        and the host snapshots it holds — grows without bound whenever a step
+        is shorter than a write, and a crash can lose any number of saves."""
+        with self._cv:
+            target = self._enq_seq
+            self._cv.wait_for(lambda: self._done_seq >= target)
+
     def save(self, state_dict, path):
         """Enqueue and return. ``state_dict`` leaves must be host-owned (the
         engine's ``_gather_to_host`` yields fresh numpy copies, so the

@@ -96,9 +96,7 @@ def _ring_attention_sharded(q, k, v, *, axis_name: str, causal: bool,
     kvh = k.shape[2]
     g = num_kv_groups
     scale = scale if scale is not None else hd ** -0.5
-    # jax < 0.6 has no lax.axis_size; psum of a literal folds to a static int
-    p_size = (lax.axis_size(axis_name) if hasattr(lax, "axis_size")
-              else lax.psum(1, axis_name))
+    p_size = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
 
     qf = q.astype(jnp.float32) * scale

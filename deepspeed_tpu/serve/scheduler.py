@@ -82,7 +82,7 @@ The engine exposes the mechanism (``put`` / ``decode_step`` / ``flush`` /
 - **graceful drain**: :meth:`close` rejects new admits, cancels
   never-admitted queued requests, finishes everything that was started
   (including preempted requests awaiting re-admission), and blocks on
-  outstanding device work before returning — the r4 transfer-guard
+  outstanding device work before returning — the transfer-guard
   discipline (``deepspeed_tpu/utils/transfer.py``): never abandon queued
   transfers. With a watchdog ``drain_budget_s`` the drain is bounded:
   stragglers are cancelled rather than hanging shutdown forever.
@@ -983,7 +983,7 @@ class ContinuousBatchScheduler:
         if self._swap_s_per_byte == 0.0:
             # before the first measured swap_in, seed from the engine's
             # TransferEngine H2D bandwidth EMA (docs/TRANSFER.md): ANY
-            # promote/swap traffic already priced the tunnel, so the cost
+            # promote/swap traffic already priced the host link, so the cost
             # model starts informed instead of blind-probing
             te = getattr(self.engine, "transfer", None)
             seed = te.s_per_byte("h2d") if te is not None else 0.0
@@ -2060,8 +2060,8 @@ class ContinuousBatchScheduler:
         """Graceful drain: reject new admits, cancel never-admitted queued
         requests, finish everything that was started — including preempted
         requests waiting in the queue for re-admission — then block on
-        outstanding device work (transfer discipline: exiting with transfers
-        queued is the r4 wedge). With ``watchdog.drain_budget_s`` set the
+        outstanding device work (transfer discipline: never exit with
+        transfers queued). With ``watchdog.drain_budget_s`` set the
         drain is bounded: past the budget, stragglers are cancelled
         (``reason="drain_timeout"``, counted in ``drain_aborts``) so a sick
         engine cannot hang shutdown forever."""

@@ -3,9 +3,6 @@ benches, and tests (everything that self-provisions a virtual CPU device mesh).
 """
 
 import os
-import re
-import subprocess
-import sys
 
 #: stability flags for the virtual CPU mesh on oversubscribed hosts:
 #: - the concurrency-optimized thunk scheduler reorders independent
@@ -31,40 +28,6 @@ def force_device_count_flags(flags: str, n: int) -> str:
     return (kept + f" --xla_force_host_platform_device_count={n}").strip()
 
 
-#: env marker so child processes (conftest re-exec, bench subprocesses)
-#: inherit an already-validated flag string instead of re-probing
-_VALIDATED_ENV = "_DSTPU_XLA_FLAGS_VALIDATED"
-
-
-def drop_unsupported_flags(flags: str) -> str:
-    """Drop XLA_FLAGS entries the linked jaxlib does not recognize.
-
-    XLA's env-flag parsing is FATAL on unknown flags (``parse_flags_from_env``
-    aborts the process), so a stability flag introduced after the installed
-    jaxlib was built would kill every backend init — the whole test suite dies
-    at the first ``jax.devices()``. Probe once in a throwaway subprocess and
-    strip exactly the flags it rejects; the result is cached in the
-    environment so re-execs and bench subprocesses skip the probe."""
-    if not flags:
-        return flags
-    if os.environ.get(_VALIDATED_ENV) == flags:
-        return flags
-    probe = subprocess.run(
-        [sys.executable, "-c", "import jax; jax.devices()"],
-        env={**os.environ, "XLA_FLAGS": flags, "JAX_PLATFORMS": "cpu"},
-        capture_output=True, text=True)
-    if probe.returncode != 0:
-        m = re.search(r"Unknown flags in XLA_FLAGS: (.*)", probe.stderr)
-        if m:
-            bad = {f.split("=")[0] for f in m.group(1).split()}
-            flags = " ".join(f for f in flags.split()
-                             if f.split("=")[0] not in bad)
-        # any other failure mode is not flag parsing — let the caller hit it
-        # with full context rather than masking it here
-    os.environ[_VALIDATED_ENV] = flags
-    return flags
-
-
 def virtual_mesh_flags(flags: str, n: int) -> str:
     """Device-count flag plus the stability flags (deduplicated) — the one
     call every virtual-mesh entry point (conftest, gate, benches) should use."""
@@ -72,4 +35,32 @@ def virtual_mesh_flags(flags: str, n: int) -> str:
     for f in VIRTUAL_MESH_STABILITY_FLAGS:
         if f.split("=")[0] not in out:
             out += " " + f
-    return drop_unsupported_flags(out)
+    return out
+
+
+#: where the persistent compile cache goes when the environment names no place:
+#: one fixed path inside the checkout (the path is part of the cache's key, so
+#: a directory built from a temp name, pid or time would never hit)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".dstpu_build", "jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a script; returns its
+    directory. Entry points (``chip_smoke.py``, the benches, the examples)
+    call this before their first compile; library code never does.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX already reads it, nothing is set in
+    code. Unset: ``DEFAULT_COMPILE_CACHE_DIR``. The minimum compile time for
+    an entry drops from 1 s to 0 so the many small serving programs are kept
+    too."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
+

@@ -1,22 +1,20 @@
 """Serve a model with continuous batching (FastGen-style paged KV).
 
 Demonstrates InferenceEngineV2: staggered arrivals, chunked prefill, and
-decode rounds share one compiled ragged program.
+decode rounds share one compiled ragged program. Uses whatever device JAX
+finds; `JAX_PLATFORMS=cpu python examples/serve_paged.py` is the CPU demo.
 """
-
-import os
-
-if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu"):
-    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 import numpy as np
 
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.models import build_model
+from deepspeed_tpu.utils.xla_env import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     model = build_model("llama-tiny", vocab_size=32000, hidden_size=256,
                         num_layers=4, num_heads=8, num_kv_heads=4,
                         intermediate_size=512, max_seq_len=512)

@@ -1,8 +1,8 @@
 """Train a GPT-2 model with ZeRO-3 + bf16 on any device mesh.
 
-Runs anywhere: real TPU (just `python examples/train_gpt2.py`) or the
-virtual CPU mesh (`JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8`,
-set in-Python below when no accelerator is present).
+`python examples/train_gpt2.py` uses whatever device JAX finds (the TPU on
+the chip machine); `JAX_PLATFORMS=cpu python examples/train_gpt2.py` is the
+scaled-down demo on an 8-device virtual CPU mesh.
 
 Mirrors a reference DeepSpeed script: build a ds_config dict, call
 initialize(), loop forward/backward/step, save a checkpoint.
@@ -10,19 +10,18 @@ initialize(), loop forward/backward/step, save a checkpoint.
 
 import os
 
-if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu"):
-    # no accelerator attached: demo on an 8-device virtual CPU mesh
-    # no accelerator (or CPU requested): demo on an 8-device virtual mesh
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    # CPU asked for: demo on an 8-device virtual mesh
     if "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_count=8")
-    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax
 import numpy as np
 
 import deepspeed_tpu
 from deepspeed_tpu.models import TransformerLM, gpt2_config
+from deepspeed_tpu.utils.xla_env import enable_compile_cache
 
 # full 125M on an accelerator; a scaled-down stand-in for the CPU demo
 ON_CPU = jax.default_backend() == "cpu"
@@ -45,6 +44,7 @@ ds_config = {
 
 
 def main():
+    enable_compile_cache()
     cfg = gpt2_config("125m", max_seq_len=SEQ, remat=True, **DIMS)
     model = TransformerLM(cfg)
     engine, _, _, lr_sched = deepspeed_tpu.initialize(model=model,

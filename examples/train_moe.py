@@ -6,21 +6,23 @@ loss, and the PR-MoE residual branch (use_residual semantics).
 
 import os
 
-if os.environ.get("JAX_PLATFORMS", "") in ("", "cpu"):
-    # no accelerator (or CPU requested): demo on an 8-device virtual mesh
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    # CPU asked for: demo on an 8-device virtual mesh; unset means "use what
+    # JAX finds", and the mesh below then needs that many real devices
     if "host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                    + " --xla_force_host_platform_device_count=8")
-    os.environ["JAX_PLATFORMS"] = "cpu"
 
 import numpy as np
 
 import deepspeed_tpu
 from deepspeed_tpu.models import TransformerLM, gpt2_config
+from deepspeed_tpu.utils.xla_env import enable_compile_cache
 
 SEQ = 128
 
 def main():
+    enable_compile_cache()
     cfg = gpt2_config("125m", hidden_size=128, num_layers=4, num_heads=4,
                       max_seq_len=SEQ, num_experts=4, moe_top_k=2,
                       moe_use_residual=True)

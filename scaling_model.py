@@ -28,6 +28,8 @@ import sys
 
 import numpy as np
 
+from bench import PEAK_BF16_FLOPS
+
 DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4,
                "s8": 1, "u8": 1, "pred": 1, "s64": 8, "u64": 8, "s16": 2,
                "u16": 2, "f8e4m3fn": 1, "f8e5m2": 1}
@@ -142,7 +144,8 @@ def profile_config(name, ds_config, model_kw, micro_bs=2, seq=128):
     tokens_per_chip = 8192  # headline-config scale (8 x 1024), not the
     # toy profiling batch: comm volume is batch-independent, compute is not
     flops_step = 6 * n_params * tokens_per_chip
-    t_compute = flops_step / (197e12 * 0.5)  # at measured headline MFU ~0.5
+    # v5e peak from the one table (bench.py), at the old headline MFU ~0.5
+    t_compute = flops_step / (PEAK_BF16_FLOPS["TPU v5e"] * 0.5)
     for n in (8, 64, 256):
         wire = wire_bytes_per_chip(totals, n, dp0)
         t_comm = wire / ici_bytes_per_s

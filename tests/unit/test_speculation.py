@@ -309,8 +309,12 @@ class TestSpecScheduler:
         serve/spec/* counters, and keeps the program bounds."""
         m, params = setup
         prompts = _prompts()
-        _, s1, r1 = _run_sched(m, params, prompts)
-        eng, ss, rs = _run_sched(m, params, prompts, decode_horizon=4,
+        # 32 tokens, not the default 16: on the installed JAX the first draft
+        # is a chance prompt match that misses, the per-request EMA collapses
+        # for SpecPolicy.revive_after rounds, and only then does lookup meet
+        # the cycle the greedy stream has fallen into
+        _, s1, r1 = _run_sched(m, params, prompts, gen=32)
+        eng, ss, rs = _run_sched(m, params, prompts, gen=32, decode_horizon=4,
                                  proposer=PromptLookupProposer())
         assert [r.tokens for r in rs] == [r.tokens for r in r1]
         assert ss.metrics.tokens_generated == s1.metrics.tokens_generated
@@ -386,13 +390,16 @@ class TestSpecScheduler:
         only the culpable request."""
         m, params = setup
         prompts = _prompts()
-        refs = [_run_sched(m, params, [p])[2][0].tokens for p in prompts]
+        # 32 tokens: enough verify dispatches for the nth=2 fault (see
+        # test_spec_bitwise_and_counters)
+        refs = [_run_sched(m, params, [p], gen=32)[2][0].tokens
+                for p in prompts]
         inj = FaultInjector(seed=3)
         inj.inject(site="verify_multi", kind="transient", nth=2, count=2)
         eng = _engine(m, params, decode_horizon=4)
         sched = ContinuousBatchScheduler(inj.wrap(eng),
                                          proposer=PromptLookupProposer())
-        reqs = [sched.submit(p, max_new_tokens=16) for p in prompts]
+        reqs = [sched.submit(p, max_new_tokens=32) for p in prompts]
         sched.run_until_complete()
         assert inj.fired["transient"] == 2
         assert inj.calls["verify_multi"] > 0
@@ -402,7 +409,7 @@ class TestSpecScheduler:
         eng2 = _engine(m, params, decode_horizon=4)
         sched2 = ContinuousBatchScheduler(inj2.wrap(eng2),
                                           proposer=PromptLookupProposer())
-        reqs2 = [sched2.submit(p, max_new_tokens=16) for p in prompts]
+        reqs2 = [sched2.submit(p, max_new_tokens=32) for p in prompts]
         inj2.inject(site="verify_multi", kind="persistent", uid=reqs2[1].uid)
         sched2.run_until_complete()
         assert reqs2[1].state is RequestState.FAILED

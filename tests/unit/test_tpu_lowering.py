@@ -22,14 +22,8 @@ def _force_mosaic(monkeypatch):
     monkeypatch.setenv("DSTPU_PALLAS_INTERPRET", "0")
 
 
-try:
-    _export_mod = jax.export
-except AttributeError:  # jax < 0.4.38: same API at its pre-public location
-    from jax._src.export import _export as _export_mod
-
-
 def _export_tpu(fn, *avals):
-    exp = _export_mod.export(jax.jit(fn), platforms=["tpu"])(*avals)
+    exp = jax.export.export(jax.jit(fn), platforms=["tpu"])(*avals)
     txt = exp.mlir_module()
     assert "tpu_custom_call" in txt, \
         "no Mosaic custom call in the exported module — kernel fell back"

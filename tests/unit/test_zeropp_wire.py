@@ -55,11 +55,7 @@ def _collective_bytes(zero_over, mb=2, seq=128):
             engine.params, batch, engine.scaler_state.cur_scale,
             jnp.asarray(0, jnp.int32)).compile().as_text()
     else:
-        args = (engine.params,
-                engine.master_params if engine._mixed else None,
-                engine.opt_state, engine.scaler_state, batch,
-                jnp.asarray(0, jnp.int32), jnp.asarray(1e-4, jnp.float32))
-        hlo = engine._fused_step_fn.lower(*args).compile().as_text()
+        hlo = engine.lower_train_step(batch).compile().as_text()
     totals, _ = parse_collectives(hlo, n_devices=8)
     _CACHE[key] = totals
     return totals
