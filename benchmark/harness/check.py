@@ -1,0 +1,148 @@
+"""What decides ``correct``: the program against the plain reference.
+
+The reference gets the seed's float32 weights and the seed's token ids and
+nothing the program has made. Every comparison yields one number that is
+printed beside its limit; the limits live in the configuration file
+(``tolerances``) with the readings they were set from.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..reference import ein_f32
+
+
+def architecture(config):
+    return importlib.import_module(
+        f"benchmark.reference.{config['architecture']}")
+
+
+#: per-layer leaves whose first update is compared: a matrix on the attention
+#: path, one on the MLP path, a bias and a LayerNorm scale
+UPDATE_LEAVES = ("wq", "w_down", "mlp_up_bias", "ln1_scale")
+
+
+def train_reference(config, weights, batch, ein=ein_f32, devices=None):
+    """(loss, gradients of ``UPDATE_LEAVES``) of one optimizer step's batch
+    (N, S): next-token cross entropy averaged over all N*(S-1) predicted
+    tokens, one sequence at a time, gradients accumulated in float32. On
+    several ``devices`` each takes N/len(devices) of the sequences against a
+    whole copy of the weights."""
+    arch = architecture(config)
+    n_dev = len(devices) if devices else 1
+    sub = {k: weights["blocks"][k] for k in UPDATE_LEAVES}
+
+    def seq_loss(sub, w, ids):
+        w = {**w, "blocks": {**w["blocks"], **sub}}
+        lg = arch.logits(w, arch.hidden(w, ids, config, ein), ein)[:-1]
+        nll = jax.nn.logsumexp(lg, axis=-1) - jnp.take_along_axis(
+            lg, ids[1:, None], axis=-1)[:, 0]
+        return jnp.mean(nll)
+
+    def whole(sub, w, batch):
+        def row(seqs):
+            def body(acc, ids):
+                loss, grads = jax.value_and_grad(seq_loss)(sub, w, ids)
+                return (acc[0] + loss, jax.tree.map(jnp.add, acc[1], grads)), None
+
+            zero = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, sub))
+            return jax.lax.scan(body, zero, seqs)[0]
+
+        loss, grads = jax.vmap(row)(batch.reshape(n_dev, -1, batch.shape[-1]))
+        n = batch.shape[0]
+        return loss.sum() / n, jax.tree.map(lambda g: g.sum(0) / n, grads)
+
+    if n_dev > 1:
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        mesh = Mesh(np.asarray(devices), ("all",))
+        sub, weights = jax.device_put((sub, weights), NamedSharding(mesh, P()))
+        batch = jax.device_put(batch, NamedSharding(mesh, P("all")))
+    loss, grads = jax.jit(whole)(sub, weights, batch)
+    return float(loss), grads
+
+
+def update_sign_mismatch(before, after, ref_grads, lr, weight_decay):
+    """How far the program's first AdamW update of ``UPDATE_LEAVES`` departs
+    from the reference gradient. At step 1 AdamW moves every element by
+    ``-lr * (g / (|g| + eps) + weight_decay * p)``, so the update (less its
+    decay term) carries the sign of the program's own gradient, element by
+    element, after forward, backward, clipping and the optimizer. The number
+    is the share of the reference gradient's L1 mass on elements whose sign
+    the program got wrong: noise of relative size s flips a share of about
+    s**2 / 4, so it grows with the square of the precision lost."""
+    @jax.jit
+    def measure(before, after, ref_grads):
+        num = den = 0.0
+        for k, g in ref_grads.items():
+            moved = -((after[k] - before[k]) / lr + weight_decay * before[k])
+            num += jnp.sum(jnp.abs(g) * (moved * g < 0))
+            den += jnp.sum(jnp.abs(g))
+        return num / den
+
+    return float(measure(before, after, ref_grads))
+
+
+def serve_reference(config, weights, ids, rows, ein=ein_f32):
+    """Logits (K, R, V) of K padded sequences ``ids`` (K, T) at the R
+    positions ``rows`` (K, R) of each: the full causal forward, so padding
+    after a position cannot reach it."""
+    arch = architecture(config)
+
+    def one(w, seq, pos):
+        return arch.logits(w, arch.hidden(w, seq, config, ein)[pos], ein)
+
+    def whole(w, ids, rows):
+        return jax.lax.map(lambda a: one(w, *a), (ids, rows))
+
+    return np.asarray(jax.jit(whole)(weights, jnp.asarray(ids),
+                                     jnp.asarray(rows)))
+
+
+def logits_rel_err(got, want):
+    """Root-mean-square error of ``got`` against ``want`` over the spread of
+    ``want`` about each row's mean (the part of a logit row that decides
+    anything)."""
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    spread = want - want.mean(axis=-1, keepdims=True)
+    return float(np.sqrt(np.sum((got - want) ** 2) / np.sum(spread ** 2)))
+
+
+def rel_err(got, want):
+    return abs(float(got) - float(want)) / abs(float(want))
+
+
+def weights_mismatch_share(served, weights, dtype):
+    """Share of the served tree's leaves that are not, bit for bit, the
+    seed's weights rounded to the configuration's dtype (1.0 if the trees
+    differ in structure, as a quantised tree does)."""
+    a, ta = jax.tree.flatten(served)
+    b, tb = jax.tree.flatten(weights)
+    if ta != tb:
+        return 1.0
+    bad = sum(x.dtype != dtype or not bool(jnp.array_equal(x, y.astype(dtype)))
+              for x, y in zip(a, b))
+    return bad / len(a)
+
+
+class Verdict:
+    """Collects (name, value, limit) rows; ``correct`` when every value is
+    finite and within its limit."""
+
+    def __init__(self, tolerances):
+        self.tolerances = tolerances
+        self.rows = []
+
+    def add(self, name, value):
+        limit = self.tolerances[name]["limit"]
+        ok = bool(np.isfinite(value)) and value <= limit
+        self.rows.append((name, float(value), limit, ok))
+        print(f"[check] {name} = {value:.6g}  (limit {limit:g})  "
+              f"{'ok' if ok else 'NOT CORRECT'}", flush=True)
+
+    @property
+    def correct(self):
+        return bool(self.rows) and all(ok for *_, ok in self.rows)
