@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...analysis.program_audit import audited_jit
+from ...utils import tracing
 from ...analysis.sanitizer import checked_cache_cls, sanitize_enabled
 from ...models.transformer import sample_or_argmax
 from ...resilience.errors import (ContextOverflowError, EngineUsageError,
@@ -77,7 +78,8 @@ class DecodeDispatchHandle:
             # THE deferred transfer: the synchronous twin pays this same
             # np.asarray inline inside _put_paged; here it lands only after
             # the next round was dispatched, so the device never idles on it
-            lg = np.asarray(self._dev)  # dstpu-lint: ignore[DSTPU001]
+            with tracing.span("engine.fetch"):
+                lg = np.asarray(self._dev)  # dstpu-lint: ignore[DSTPU001]
             self._out = {uid: int(lg[i]) for i, uid in enumerate(self.uids)}
             self._dev = None
         if self._eng is not None:
@@ -139,6 +141,8 @@ class InferenceEngineV2:
         #: not be allocated (the pool served the rows that fit instead of
         #: failing the whole step) — chunked-prefill pressure diagnostics
         self.plan_deferrals = 0
+        #: block allocations / COW copies seen by the previous dispatch
+        self._count_marks = (0, 0)
         self._prefill_fns = {}
         self._decode_fn = None
         self._cow_fn = None
@@ -1047,109 +1051,24 @@ class InferenceEngineV2:
             if not work:
                 return
             steps += 1
-            work.sort(key=lambda d: (d.in_flight, d.slot))
-            # decode-round fast path: when every pending item is a single
-            # token and they fit in max_seqs rows, use the small compiled
-            # shape — steady-state decode must not pay the prefill budget's
-            # padded rows (second of the two fixed shapes, see _get_ragged)
-            if (self.token_budget > self.max_seqs
-                    and len(work) <= self.max_seqs
-                    and all(d.in_flight == 1 for d in work)):
-                T = self.max_seqs
-            else:
-                T = self.token_budget
-            plan: List[Tuple] = []
-            used = 0
-            for d in work:
-                if used >= T:
-                    break
-                take = min(d.in_flight, self.prefill_chunk, T - used)
-                if d.seen_tokens + take > self.max_seq_len:
-                    raise ContextOverflowError(
-                        f"uid {d.uid}: prompt exceeds context "
-                        f"({d.seen_tokens}+{take} > {self.max_seq_len})",
-                        uid=d.uid)
-                plan.append((d, take))
-                used += take
-            # allocate blocks for the WHOLE step before mutating any sequence
-            # state. A row whose blocks cannot be allocated is DEFERRED (its
-            # tokens stay pending for a later dispatch) rather than failing
-            # rows that can run — under chunked interleaved prefill, live
-            # decodes must keep progressing (and freeing blocks) while a big
-            # prompt waits for pool capacity. Exhaustion raises only when
-            # nothing at all is dispatchable, with every descriptor's
-            # pending/seen state intact (blocks already grown are kept and
-            # used by the retried step, the standing retry contract).
-            ready: List[Tuple] = []
-            pool_exhausted: Optional[PoolExhaustedError] = None
-            for d, take in plan:
-                try:
-                    self.block_mgr.ensure(d, d.seen_tokens + take)
-                except PoolExhaustedError as e:
-                    pool_exhausted = e
-                    self.plan_deferrals += 1
-                    continue
-                ready.append((d, take))
-            if not ready:
-                raise pool_exhausted
-            plan = ready
-            if self.prefix_cache:
-                # copy-on-write: a write landing inside a block some OTHER
-                # sequence also references (a full-prompt cache hit recomputes
-                # its final token inside the last shared block) must first
-                # detach a private copy — shared blocks are immutable. Fresh
-                # ensure()-allocated blocks have refcount 1 and are skipped.
-                for d, take in plan:
-                    bs = self.block_mgr.block_size
-                    first = d.seen_tokens // bs
-                    last = min((d.seen_tokens + take - 1) // bs,
-                               len(d.blocks) - 1)
-                    for j in range(first, last + 1):
-                        if self.block_mgr.refcount(d.blocks[j]) > 1:
-                            src, dst = self.block_mgr.copy_on_write(d, j)
-                            self.kv = self._get_cow()(
-                                self.kv, jnp.int32(src), jnp.int32(dst))
-            M = self.max_seqs
-            (ids, tables, starts, logit_rows, slots, seeds, poss, top_ks,
-             temps, top_ps) = self._scratch_for(
-                ("ragged", T),
-                ((T, 1), (T, self.block_mgr.max_blocks_per_seq), (T,),
-                 (M,), (M,), (M,), (M,), (M,), (M,), (M,)),
-                dtypes=(np.int32,) * 8 + (np.float32, np.float32))
-            finals = []
-            r = 0
-            for d, take in plan:
-                completes = take == d.in_flight
-                # fill the first row in place, then broadcast-copy it to the
-                # sequence's remaining rows — no per-row temp allocation
-                r0 = r
-                self.block_mgr.fill_table_row(d, tables[r0])
-                if take > 1:
-                    tables[r0 + 1:r0 + take] = tables[r0]
-                for j in range(take):
-                    ids[r, 0] = d.pending[j]
-                    starts[r] = d.seen_tokens + j
-                    r += 1
-                if completes:
-                    logit_rows[len(finals)] = r - 1
-                    # the produced token's absolute index is the consumed
-                    # count — seen_tokens is pre-advance here, so the
-                    # counter-based key position is seen + take
-                    self._fill_sampling(d, len(finals), slots, seeds, temps,
-                                        top_ks, top_ps, poss=poss,
-                                        pos=d.seen_tokens + take)
-                    finals.append(d)
-                if self.prefix_cache:
-                    d.history.extend(d.pending[:take])
-                del d.pending[:take]
-                d.seen_tokens += take
+            self._ragged_step(work, out, greedy)
+
+    def _ragged_step(self, work, out: Dict[int, np.ndarray],
+                     greedy: bool) -> None:
+        """One compiled ragged dispatch of :meth:`_put_paged`: build the
+        batch, enqueue the program, fetch its one result."""
+        with tracing.span("engine.dispatch", program="ragged") as disp:
+            with tracing.span("engine.build"):
+                T, plan, finals, feed = self._build_ragged_step(work)
+                self._count_dispatch(disp, T, plan)
             fn = self._get_ragged()
-            lg, self.kv = fn(self.params, self.kv, jnp.asarray(ids),
-                             jnp.asarray(tables), jnp.asarray(starts),
-                             jnp.asarray(logit_rows), jnp.asarray(slots),
-                             jnp.asarray(seeds), jnp.asarray(poss),
-                             jnp.asarray(temps), jnp.asarray(top_ks),
-                             jnp.asarray(top_ps), self._bias(), greedy)
+            with tracing.span("engine.enqueue"):
+                args = (self.params, self.kv,
+                        *(jnp.asarray(a) for a in feed), self._bias(), greedy)
+                if disp.recording:
+                    tracing.note_program("engine_v2.ragged", fn, args,
+                                         key=(T, greedy))
+                lg, self.kv = fn(*args)
             if self.prefix_cache:
                 # the step's writes are dispatched: every block it filled now
                 # holds valid prefix content — publish to the content index
@@ -1157,10 +1076,145 @@ class InferenceEngineV2:
                 for d, _ in plan:
                     self.block_mgr.register(d)
             # THE step's one designed transfer (ships the whole batch's
-            # results at once; everything above is dispatch-only)
-            lg = np.asarray(lg)  # dstpu-lint: ignore[DSTPU001]
+            # results at once; everything above is dispatch-only): the
+            # device wait
+            with tracing.span("engine.fetch"):
+                lg = np.asarray(lg)  # dstpu-lint: ignore[DSTPU001]
             for i, d in enumerate(finals):
                 out[d.uid] = int(lg[i]) if greedy else lg[i]
+
+    def _count_dispatch(self, disp, padded_rows: int, plan,
+                        fused: bool = False) -> None:
+        """The counts ``engine.dispatch`` carries (docs/TRACING.md), taken
+        where the batch is built. ``plan``: [(descriptor, tokens taken)]. A
+        ragged step counts after its descriptors advanced; a ``fused``
+        K-position program (fused decode, verify) before, and all its rows
+        are decode rows. Blocks and copies are those since the previous
+        dispatch."""
+        mgr = self.block_mgr
+        marks = (mgr.allocations, mgr.stats["cow_copies"])
+        if disp.recording:
+            rows = ctx = by_row = decode = 0
+            for d, take in plan:
+                seen = d.seen_tokens if fused else d.seen_tokens - take
+                rows += take
+                ctx += seen + take
+                by_row += take * seen + take * (take + 1) // 2
+                # one token pending: a decode step (or a prompt's last token)
+                if fused or take == 1:
+                    decode += take
+            disp.set(padded_rows=padded_rows, rows=rows, decode_rows=decode,
+                     prefill_tokens=rows - decode, seqs=len(plan),
+                     ctx_tokens=ctx, ctx_tokens_by_row=by_row,
+                     blocks_allocated=max(0, marks[0] - self._count_marks[0]),
+                     cow_copies=max(0, marks[1] - self._count_marks[1]))
+        self._count_marks = marks
+
+    def _build_ragged_step(self, work):
+        """Plan one ragged step over ``work`` (the sequences with pending
+        tokens), allocate its blocks, copy shared ones on write and fill the
+        host arrays. Returns (padded rows, [(descriptor, tokens taken)],
+        the sequences that yield an output, the program's array arguments in
+        call order). Sequence state is advanced here; a raise leaves every
+        descriptor intact."""
+        work.sort(key=lambda d: (d.in_flight, d.slot))
+        # decode-round fast path: when every pending item is a single
+        # token and they fit in max_seqs rows, use the small compiled
+        # shape — steady-state decode must not pay the prefill budget's
+        # padded rows (second of the two fixed shapes, see _get_ragged)
+        if (self.token_budget > self.max_seqs
+                and len(work) <= self.max_seqs
+                and all(d.in_flight == 1 for d in work)):
+            T = self.max_seqs
+        else:
+            T = self.token_budget
+        plan: List[Tuple] = []
+        used = 0
+        for d in work:
+            if used >= T:
+                break
+            take = min(d.in_flight, self.prefill_chunk, T - used)
+            if d.seen_tokens + take > self.max_seq_len:
+                raise ContextOverflowError(
+                    f"uid {d.uid}: prompt exceeds context "
+                    f"({d.seen_tokens}+{take} > {self.max_seq_len})",
+                    uid=d.uid)
+            plan.append((d, take))
+            used += take
+        # allocate blocks for the WHOLE step before mutating any sequence
+        # state. A row whose blocks cannot be allocated is DEFERRED (its
+        # tokens stay pending for a later dispatch) rather than failing
+        # rows that can run — under chunked interleaved prefill, live
+        # decodes must keep progressing (and freeing blocks) while a big
+        # prompt waits for pool capacity. Exhaustion raises only when
+        # nothing at all is dispatchable, with every descriptor's
+        # pending/seen state intact (blocks already grown are kept and
+        # used by the retried step, the standing retry contract).
+        ready: List[Tuple] = []
+        pool_exhausted: Optional[PoolExhaustedError] = None
+        for d, take in plan:
+            try:
+                self.block_mgr.ensure(d, d.seen_tokens + take)
+            except PoolExhaustedError as e:
+                pool_exhausted = e
+                self.plan_deferrals += 1
+                continue
+            ready.append((d, take))
+        if not ready:
+            raise pool_exhausted
+        plan = ready
+        if self.prefix_cache:
+            # copy-on-write: a write landing inside a block some OTHER
+            # sequence also references (a full-prompt cache hit recomputes
+            # its final token inside the last shared block) must first
+            # detach a private copy — shared blocks are immutable. Fresh
+            # ensure()-allocated blocks have refcount 1 and are skipped.
+            for d, take in plan:
+                bs = self.block_mgr.block_size
+                first = d.seen_tokens // bs
+                last = min((d.seen_tokens + take - 1) // bs,
+                           len(d.blocks) - 1)
+                for j in range(first, last + 1):
+                    if self.block_mgr.refcount(d.blocks[j]) > 1:
+                        src, dst = self.block_mgr.copy_on_write(d, j)
+                        self.kv = self._get_cow()(
+                            self.kv, jnp.int32(src), jnp.int32(dst))
+        M = self.max_seqs
+        (ids, tables, starts, logit_rows, slots, seeds, poss, top_ks,
+         temps, top_ps) = self._scratch_for(
+            ("ragged", T),
+            ((T, 1), (T, self.block_mgr.max_blocks_per_seq), (T,),
+             (M,), (M,), (M,), (M,), (M,), (M,), (M,)),
+            dtypes=(np.int32,) * 8 + (np.float32, np.float32))
+        finals = []
+        r = 0
+        for d, take in plan:
+            completes = take == d.in_flight
+            # fill the first row in place, then broadcast-copy it to the
+            # sequence's remaining rows — no per-row temp allocation
+            r0 = r
+            self.block_mgr.fill_table_row(d, tables[r0])
+            if take > 1:
+                tables[r0 + 1:r0 + take] = tables[r0]
+            for j in range(take):
+                ids[r, 0] = d.pending[j]
+                starts[r] = d.seen_tokens + j
+                r += 1
+            if completes:
+                logit_rows[len(finals)] = r - 1
+                # the produced token's absolute index is the consumed
+                # count — seen_tokens is pre-advance here, so the
+                # counter-based key position is seen + take
+                self._fill_sampling(d, len(finals), slots, seeds, temps,
+                                    top_ks, top_ps, poss=poss,
+                                    pos=d.seen_tokens + take)
+                finals.append(d)
+            if self.prefix_cache:
+                d.history.extend(d.pending[:take])
+            del d.pending[:take]
+            d.seen_tokens += take
+        return T, plan, finals, (ids, tables, starts, logit_rows, slots, seeds,
+                                 poss, temps, top_ks, top_ps)
 
     # ------------------------------------------------------------------
     # reference surface
@@ -1370,44 +1424,44 @@ class InferenceEngineV2:
         # pre-allocate the WHOLE horizon's blocks before dispatch (positions
         # seen .. seen+K-1); a PoolExhaustedError here leaves seen_tokens/
         # history untouched — allocated blocks are used by the retried step
-        self._drain_promotions()  # queued tier promotions land first
-        for uid in tokens:
-            d = self.state.seqs[uid]
-            self.block_mgr.ensure(d, d.seen_tokens + K)
-        descs = sorted((self.state.seqs[u] for u in tokens),
-                       key=lambda d: d.slot)
-        if self.prefix_cache:
-            # copy-on-write for every block the K writes can land in —
-            # shared blocks are immutable (same discipline as _put_paged)
-            bs = self.block_mgr.block_size
-            for d in descs:
-                first = d.seen_tokens // bs
-                last = min((d.seen_tokens + K - 1) // bs, len(d.blocks) - 1)
-                for j in range(first, last + 1):
-                    if self.block_mgr.refcount(d.blocks[j]) > 1:
-                        src, dst = self.block_mgr.copy_on_write(d, j)
-                        self.kv = self._get_cow()(
-                            self.kv, jnp.int32(src), jnp.int32(dst))
-        B = self.max_seqs
-        toks, tables, starts, slots, seeds, top_ks, temps, top_ps = \
-            self._scratch_for(
-                ("fused", B),
-                ((B,), (B, self.block_mgr.max_blocks_per_seq), (B,),
-                 (B,), (B,), (B,), (B,), (B,)),
-                dtypes=(np.int32,) * 6 + (np.float32, np.float32))
-        for r, d in enumerate(descs):
-            toks[r] = tokens[d.uid]
-            self.block_mgr.fill_table_row(d, tables[r])  # in place, no temp
-            starts[r] = d.seen_tokens
-            # per-position keys are folded inside the scan from (seed,
-            # starts+round+1) — no per-round host state (docs/SAMPLING.md)
-            self._fill_sampling(d, r, slots, seeds, temps, top_ks, top_ps)
-        ys, self.kv = self._get_fused()(
-            self.params, self.kv, jnp.asarray(toks), jnp.asarray(tables),
-            jnp.asarray(starts), jnp.asarray(slots), jnp.asarray(seeds),
-            jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
-            self._bias())
-        ys = np.asarray(ys)  # (max_seqs, K); one transfer per K tokens
+        with tracing.span("engine.dispatch", program="fused") as disp:
+            with tracing.span("engine.build"):
+                self._drain_promotions()  # queued tier promotions land first
+                for uid in tokens:
+                    d = self.state.seqs[uid]
+                    self.block_mgr.ensure(d, d.seen_tokens + K)
+                descs = sorted((self.state.seqs[u] for u in tokens),
+                               key=lambda d: d.slot)
+                if self.prefix_cache:
+                    # copy-on-write for every block the K writes can land in —
+                    # shared blocks are immutable (same discipline as _put_paged)
+                    bs = self.block_mgr.block_size
+                    for d in descs:
+                        first = d.seen_tokens // bs
+                        last = min((d.seen_tokens + K - 1) // bs, len(d.blocks) - 1)
+                        for j in range(first, last + 1):
+                            if self.block_mgr.refcount(d.blocks[j]) > 1:
+                                src, dst = self.block_mgr.copy_on_write(d, j)
+                                self.kv = self._get_cow()(
+                                    self.kv, jnp.int32(src), jnp.int32(dst))
+                B = self.max_seqs
+                toks, tables, starts, slots, seeds, top_ks, temps, top_ps = \
+                    self._scratch_for(
+                        ("fused", B),
+                        ((B,), (B, self.block_mgr.max_blocks_per_seq), (B,),
+                         (B,), (B,), (B,), (B,), (B,)),
+                        dtypes=(np.int32,) * 6 + (np.float32, np.float32))
+                for r, d in enumerate(descs):
+                    toks[r] = tokens[d.uid]
+                    self.block_mgr.fill_table_row(d, tables[r])  # in place, no temp
+                    starts[r] = d.seen_tokens
+                    # per-position keys are folded inside the scan from (seed,
+                    # starts+round+1) — no per-round host state (docs/SAMPLING.md)
+                    self._fill_sampling(d, r, slots, seeds, temps, top_ks, top_ps)
+                self._count_dispatch(disp, B * K, [(d, K) for d in descs],
+                                     fused=True)
+            ys = self._enqueue_fetch(disp, "fused", self._get_fused(), (
+                toks, tables, starts, slots, seeds, temps, top_ks, top_ps))
         out: Dict[int, List[int]] = {}
         for r, d in enumerate(descs):
             seq = [int(t) for t in ys[r]]
@@ -1419,6 +1473,19 @@ class InferenceEngineV2:
             d.uncommitted = K  # rollback may truncate at most this step
             out[d.uid] = seq
         return out
+
+    def _enqueue_fetch(self, disp, program: str, fn, feed):
+        """Enqueue a K-position program (fused decode, speculative verify)
+        and fetch its (max_seqs, K) result: ONE designed transfer per K
+        tokens, the same budget as the ragged step's."""
+        with tracing.span("engine.enqueue"):
+            args = (self.params, self.kv,
+                    *(jnp.asarray(a) for a in feed), self._bias())
+            if disp.recording:
+                tracing.note_program("engine_v2." + program, fn, args)
+            ys, self.kv = fn(*args)
+        with tracing.span("engine.fetch"):
+            return np.asarray(ys)  # dstpu-lint: ignore[DSTPU001]
 
     def verify_multi(self, tokens: Dict[int, int],
                      drafts: Dict[int, Sequence[int]]) -> Dict[int, List[int]]:
@@ -1471,51 +1538,49 @@ class InferenceEngineV2:
                     f"uid {uid}: verify width {K} exceeds context "
                     f"({d.seen_tokens}+{K} > {self.max_seq_len}); collapse "
                     "to horizon 1 or flush the sequence", uid=uid)
-        self._drain_promotions()  # queued tier promotions land first
-        for uid in tokens:
-            d = self.state.seqs[uid]
-            self.block_mgr.ensure(d, d.seen_tokens + K)
-        descs = sorted((self.state.seqs[u] for u in tokens),
-                       key=lambda d: d.slot)
-        if self.prefix_cache:
-            # copy-on-write for every block the K writes can land in —
-            # shared blocks are immutable (same discipline as decode_multi)
-            bs = self.block_mgr.block_size
-            for d in descs:
-                first = d.seen_tokens // bs
-                last = min((d.seen_tokens + K - 1) // bs, len(d.blocks) - 1)
-                for j in range(first, last + 1):
-                    if self.block_mgr.refcount(d.blocks[j]) > 1:
-                        src, dst = self.block_mgr.copy_on_write(d, j)
-                        self.kv = self._get_cow()(
-                            self.kv, jnp.int32(src), jnp.int32(dst))
-        B = self.max_seqs
-        segs, tables, starts, slots, seeds, top_ks, temps, top_ps = \
-            self._scratch_for(
-                ("verify", B, K),
-                ((B, K), (B, self.block_mgr.max_blocks_per_seq), (B,),
-                 (B,), (B,), (B,), (B,), (B,)),
-                dtypes=(np.int32,) * 6 + (np.float32, np.float32))
-        fed: Dict[int, List[int]] = {}
-        for r, d in enumerate(descs):
-            row = [int(tokens[d.uid])] + [int(t) for t in drafts.get(d.uid, ())]
-            fed[d.uid] = row
-            for j, t in enumerate(row):  # positions past the draft stay 0
-                segs[r, j] = t           # (zeroed pad — always rolled back)
-            self.block_mgr.fill_table_row(d, tables[r])  # in place, no temp
-            starts[r] = d.seen_tokens
-            # sampled rows: each position j gets the target's own sample
-            # under key (seed, starts+j+1) — the token sequential sampled
-            # decode emits there, which is what draft prefix-matching needs
-            self._fill_sampling(d, r, slots, seeds, temps, top_ks, top_ps)
-        ys, self.kv = self._get_verify()(
-            self.params, self.kv, jnp.asarray(segs), jnp.asarray(tables),
-            jnp.asarray(starts), jnp.asarray(slots), jnp.asarray(seeds),
-            jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
-            self._bias())
-        # (max_seqs, K); ONE designed transfer per verified horizon — the
-        # same budget as the fused path's result ship
-        ys = np.asarray(ys)  # dstpu-lint: ignore[DSTPU001]
+        with tracing.span("engine.dispatch", program="verify") as disp:
+            with tracing.span("engine.build"):
+                self._drain_promotions()  # queued tier promotions land first
+                for uid in tokens:
+                    d = self.state.seqs[uid]
+                    self.block_mgr.ensure(d, d.seen_tokens + K)
+                descs = sorted((self.state.seqs[u] for u in tokens),
+                               key=lambda d: d.slot)
+                if self.prefix_cache:
+                    # copy-on-write for every block the K writes can land in —
+                    # shared blocks are immutable (same discipline as decode_multi)
+                    bs = self.block_mgr.block_size
+                    for d in descs:
+                        first = d.seen_tokens // bs
+                        last = min((d.seen_tokens + K - 1) // bs, len(d.blocks) - 1)
+                        for j in range(first, last + 1):
+                            if self.block_mgr.refcount(d.blocks[j]) > 1:
+                                src, dst = self.block_mgr.copy_on_write(d, j)
+                                self.kv = self._get_cow()(
+                                    self.kv, jnp.int32(src), jnp.int32(dst))
+                B = self.max_seqs
+                segs, tables, starts, slots, seeds, top_ks, temps, top_ps = \
+                    self._scratch_for(
+                        ("verify", B, K),
+                        ((B, K), (B, self.block_mgr.max_blocks_per_seq), (B,),
+                         (B,), (B,), (B,), (B,), (B,)),
+                        dtypes=(np.int32,) * 6 + (np.float32, np.float32))
+                fed: Dict[int, List[int]] = {}
+                for r, d in enumerate(descs):
+                    row = [int(tokens[d.uid])] + [int(t) for t in drafts.get(d.uid, ())]
+                    fed[d.uid] = row
+                    for j, t in enumerate(row):  # positions past the draft stay 0
+                        segs[r, j] = t           # (zeroed pad — always rolled back)
+                    self.block_mgr.fill_table_row(d, tables[r])  # in place, no temp
+                    starts[r] = d.seen_tokens
+                    # sampled rows: each position j gets the target's own sample
+                    # under key (seed, starts+j+1) — the token sequential sampled
+                    # decode emits there, which is what draft prefix-matching needs
+                    self._fill_sampling(d, r, slots, seeds, temps, top_ks, top_ps)
+                self._count_dispatch(disp, B * K, [(d, K) for d in descs],
+                                     fused=True)
+            ys = self._enqueue_fetch(disp, "verify", self._get_verify(), (
+                segs, tables, starts, slots, seeds, temps, top_ks, top_ps))
         out: Dict[int, List[int]] = {}
         for r, d in enumerate(descs):
             row = fed[d.uid]
@@ -1575,57 +1640,63 @@ class InferenceEngineV2:
                     f"uid {uid}: context full ({d.seen_tokens} >= "
                     f"{self.max_seq_len}); flush the sequence or raise "
                     "max_seq_len", uid=uid)
-        self._drain_promotions()  # queued tier promotions land first
-        for uid in tokens:
-            d = self.state.seqs[uid]
-            self.block_mgr.ensure(d, d.seen_tokens + 1)
-        descs = sorted((self.state.seqs[u] for u in tokens),
-                       key=lambda d: d.slot)
-        if self.prefix_cache:
-            # copy-on-write for the block the single write lands in —
-            # shared blocks are immutable (same discipline as _put_paged)
-            bs = self.block_mgr.block_size
-            for d in descs:
-                j = min(d.seen_tokens // bs, len(d.blocks) - 1)
-                if self.block_mgr.refcount(d.blocks[j]) > 1:
-                    src, dst = self.block_mgr.copy_on_write(d, j)
-                    self.kv = self._get_cow()(
-                        self.kv, jnp.int32(src), jnp.int32(dst))
-        # the decode-round fast shape of the ragged program (see _put_paged):
-        # a pure single-token round never pays the prefill budget's padding
-        T = (self.max_seqs if self.token_budget > self.max_seqs
-             else self.token_budget)
-        M = self.max_seqs
-        (ids, tables, starts, logit_rows, slots, seeds, poss, top_ks,
-         temps, top_ps) = self._scratch_for(
-            ("ragged", T),
-            ((T, 1), (T, self.block_mgr.max_blocks_per_seq), (T,),
-             (M,), (M,), (M,), (M,), (M,), (M,), (M,)),
-            dtypes=(np.int32,) * 8 + (np.float32, np.float32))
-        for r, d in enumerate(descs):
-            tok = int(tokens[d.uid])
-            ids[r, 0] = tok
-            self.block_mgr.fill_table_row(d, tables[r])  # in place, no temp
-            starts[r] = d.seen_tokens
-            logit_rows[r] = r  # every row is a final: one token per uid
-            self._fill_sampling(d, r, slots, seeds, temps, top_ks, top_ps,
-                                poss=poss, pos=d.seen_tokens + 1)
-            if self.prefix_cache:
-                d.history.append(tok)
-            d.seen_tokens += 1
-            d.uncommitted += 1  # stacked: commit_step settles per absorb
-        fn = self._get_ragged()
-        # the whole feed rides ONE batched host→device staging call: at
-        # K=1 the per-call Python dispatch overhead of ten separate small
-        # transfers is itself a large slice of the host-bound round, and
-        # the dispatch stage exists to get off the device's critical path
-        (ids_d, tables_d, starts_d, logit_rows_d, slots_d, seeds_d,
-         poss_d, temps_d, top_ks_d, top_ps_d) = jax.device_put(
-            (ids, tables, starts, logit_rows, slots, seeds, poss,
-             temps, top_ks, top_ps))
-        lg, self.kv = fn(self.params, self.kv, ids_d, tables_d, starts_d,
-                         logit_rows_d, slots_d, seeds_d, poss_d, temps_d,
-                         top_ks_d, top_ps_d, self._bias(), True)
+        with tracing.span("engine.dispatch", program="ragged",
+                          deferred=True) as disp:
+            with tracing.span("engine.build"):
+                self._drain_promotions()  # queued tier promotions land first
+                for uid in tokens:
+                    d = self.state.seqs[uid]
+                    self.block_mgr.ensure(d, d.seen_tokens + 1)
+                descs = sorted((self.state.seqs[u] for u in tokens),
+                               key=lambda d: d.slot)
+                if self.prefix_cache:
+                    # copy-on-write for the block the single write lands in —
+                    # shared blocks are immutable (same discipline as _put_paged)
+                    bs = self.block_mgr.block_size
+                    for d in descs:
+                        j = min(d.seen_tokens // bs, len(d.blocks) - 1)
+                        if self.block_mgr.refcount(d.blocks[j]) > 1:
+                            src, dst = self.block_mgr.copy_on_write(d, j)
+                            self.kv = self._get_cow()(
+                                self.kv, jnp.int32(src), jnp.int32(dst))
+                # the decode-round fast shape of the ragged program (see _put_paged):
+                # a pure single-token round never pays the prefill budget's padding
+                T = (self.max_seqs if self.token_budget > self.max_seqs
+                     else self.token_budget)
+                M = self.max_seqs
+                (ids, tables, starts, logit_rows, slots, seeds, poss, top_ks,
+                 temps, top_ps) = self._scratch_for(
+                    ("ragged", T),
+                    ((T, 1), (T, self.block_mgr.max_blocks_per_seq), (T,),
+                     (M,), (M,), (M,), (M,), (M,), (M,), (M,)),
+                    dtypes=(np.int32,) * 8 + (np.float32, np.float32))
+                for r, d in enumerate(descs):
+                    tok = int(tokens[d.uid])
+                    ids[r, 0] = tok
+                    self.block_mgr.fill_table_row(d, tables[r])  # in place, no temp
+                    starts[r] = d.seen_tokens
+                    logit_rows[r] = r  # every row is a final: one token per uid
+                    self._fill_sampling(d, r, slots, seeds, temps, top_ks, top_ps,
+                                        poss=poss, pos=d.seen_tokens + 1)
+                    if self.prefix_cache:
+                        d.history.append(tok)
+                    d.seen_tokens += 1
+                    d.uncommitted += 1  # stacked: commit_step settles per absorb
+                self._count_dispatch(disp, T, [(d, 1) for d in descs])
+            fn = self._get_ragged()
+            with tracing.span("engine.enqueue"):
+                # the whole feed rides ONE batched host→device staging call:
+                # at K=1 the per-call Python dispatch overhead of ten separate
+                # small transfers is itself a large slice of the host-bound
+                # round, and the dispatch stage exists to get off the device's
+                # critical path
+                args = (self.params, self.kv, *jax.device_put(
+                    (ids, tables, starts, logit_rows, slots, seeds, poss,
+                     temps, top_ks, top_ps)), self._bias(), True)
+                if disp.recording:
+                    tracing.note_program("engine_v2.ragged", fn, args,
+                                         key=(T, True))
+                lg, self.kv = fn(*args)
         # no np.asarray and no register here — both are deferred: the
         # transfer to fetch(), the prefix-index publish to commit_step()
         handle = DecodeDispatchHandle([d.uid for d in descs], lg, eng=self)

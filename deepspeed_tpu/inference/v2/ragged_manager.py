@@ -122,6 +122,9 @@ class BlockedKVCache:
         #: only ever come OUT of ``_host``) and engine-supplied spill/load fns
         self.nvme_blocks = nvme_blocks if self.host_tier_blocks else 0
         self._free: List[int] = list(range(1, num_blocks))[::-1]  # 0 reserved
+        #: blocks handed out so far, ever (the engine's dispatch spans report
+        #: the difference between two dispatches, docs/TRACING.md)
+        self.allocations = 0
         self._ref: Dict[int, int] = {}  # block -> refcount (present iff > 0)
         # content index: (parent block id | _ROOT, token tuple) -> block id.
         # Exact keys (no hashing) — a collision would silently serve another
@@ -577,6 +580,7 @@ class BlockedKVCache:
                     f"{self.num_blocks - 1} usable blocks)", uid=uid)
         b = self._free.pop()
         self._ref[b] = 1
+        self.allocations += 1
         return b
 
     # ------------------------------------------------------------------

@@ -526,166 +526,170 @@ class TransformerLM:
         from jax.ad_checkpoint import checkpoint_name
 
         post_ln = cfg.norm_position == "post"
-        h = x if post_ln else checkpoint_name(_norm(
-            x, blk["ln1_scale"], blk.get("ln1_bias"), cfg.norm, cfg.norm_eps,
-            cfg.norm_weight_offset), "ln_out")
-        # activation quantization hook (reference basic_layer.py:17 QuantAct —
-        # each compressed linear quantizes its input): set by
-        # compression.init_compression; None costs nothing
-        act_q = getattr(self, "_act_quant_fn", None)
-        if act_q is not None:
-            h = act_q(h)
-        q = h @ blk["wq"].astype(h.dtype)
-        kk = h @ blk["wk"].astype(h.dtype)
-        v = h @ blk["wv"].astype(h.dtype)
-        if "wq_bias" in blk:
-            q = q + blk["wq_bias"].astype(h.dtype)
-            kk = kk + blk["wk_bias"].astype(h.dtype)
-            v = v + blk["wv_bias"].astype(h.dtype)
-        q = q.reshape(B, S, nh, hd)
-        kk = kk.reshape(B, S, kvh, hd)
-        v = v.reshape(B, S, kvh, hd)
-        if cfg.pos_embedding == "rope":
-            q, kk = _rope(q, kk, positions, hd, cfg.rope_theta, cfg.rotary_dim)
+        with jax.named_scope("attn"):
+            h = x if post_ln else checkpoint_name(_norm(
+                x, blk["ln1_scale"], blk.get("ln1_bias"), cfg.norm, cfg.norm_eps,
+                cfg.norm_weight_offset), "ln_out")
+            # activation quantization hook (reference basic_layer.py:17 QuantAct —
+            # each compressed linear quantizes its input): set by
+            # compression.init_compression; None costs nothing
+            act_q = getattr(self, "_act_quant_fn", None)
+            if act_q is not None:
+                h = act_q(h)
+            q = h @ blk["wq"].astype(h.dtype)
+            kk = h @ blk["wk"].astype(h.dtype)
+            v = h @ blk["wv"].astype(h.dtype)
+            if "wq_bias" in blk:
+                q = q + blk["wq_bias"].astype(h.dtype)
+                kk = kk + blk["wk_bias"].astype(h.dtype)
+                v = v + blk["wv_bias"].astype(h.dtype)
+            q = q.reshape(B, S, nh, hd)
+            kk = kk.reshape(B, S, kvh, hd)
+            v = v.reshape(B, S, kvh, hd)
+            if cfg.pos_embedding == "rope":
+                q, kk = _rope(q, kk, positions, hd, cfg.rope_theta, cfg.rotary_dim)
 
-        def _alibi_bias(kpos):
-            # slopes · key-position; equivalent to slopes · (k-q) distance under
-            # softmax's per-query shift invariance. kpos (Skv,) → bias
-            # (1, kvh, groups, 1, Skv), or (B, Skv) → (B, kvh, groups, 1, Skv)
-            # (random-LTD passes the kept tokens' ORIGINAL positions per batch)
-            slopes = jnp.asarray(alibi_slopes(nh) * cfg.alibi_slope_scale
-                                 ).reshape(kvh, nh // kvh)
-            kpos = kpos.astype(jnp.float32)
-            if kpos.ndim == 1:
-                kpos = kpos[None]
-            return kpos[:, None, None, None, :] * slopes[None, :, :, None, None]
+            def _alibi_bias(kpos):
+                # slopes · key-position; equivalent to slopes · (k-q) distance under
+                # softmax's per-query shift invariance. kpos (Skv,) → bias
+                # (1, kvh, groups, 1, Skv), or (B, Skv) → (B, kvh, groups, 1, Skv)
+                # (random-LTD passes the kept tokens' ORIGINAL positions per batch)
+                slopes = jnp.asarray(alibi_slopes(nh) * cfg.alibi_slope_scale
+                                     ).reshape(kvh, nh // kvh)
+                kpos = kpos.astype(jnp.float32)
+                if kpos.ndim == 1:
+                    kpos = kpos[None]
+                return kpos[:, None, None, None, :] * slopes[None, :, :, None, None]
 
-        new_kv = None
-        if paged is not None:
-            kp, vp, tables = paged  # pool: (kvh, NB, BS, hd) kv-head-major
-            BS = kp.shape[2]
-            # scatter this segment's k/v into the pool at its block/offset
-            blk_idx = jnp.take_along_axis(tables, positions // BS, axis=1)  # (B,S)
-            off = positions % BS
-            kp = kp.at[:, blk_idx, off].set(
-                kk.astype(kp.dtype).transpose(2, 0, 1, 3))
-            vp = vp.at[:, blk_idx, off].set(
-                v.astype(vp.dtype).transpose(2, 0, 1, 3))
-            new_kv = (kp, vp)
-            from ..ops.transformer.attention import get_default_impl
+            new_kv = None
+            if paged is not None:
+                kp, vp, tables = paged  # pool: (kvh, NB, BS, hd) kv-head-major
+                BS = kp.shape[2]
+                with jax.named_scope("kv_write"):
+                    # scatter this segment's k/v into the pool at its block/offset
+                    blk_idx = jnp.take_along_axis(tables, positions // BS, axis=1)  # (B,S)
+                    off = positions % BS
+                    kp = kp.at[:, blk_idx, off].set(
+                        kk.astype(kp.dtype).transpose(2, 0, 1, 3))
+                    vp = vp.at[:, blk_idx, off].set(
+                        v.astype(vp.dtype).transpose(2, 0, 1, 3))
+                new_kv = (kp, vp)
+                with jax.named_scope("paged_attn"):
+                    from ..ops.transformer.attention import get_default_impl
 
-            # NOTE: evaluated at TRACE time — the env override (used by tests
-            # to exercise this branch in interpret mode) and set_default_impl
-            # must be set before the engine compiles its decode program
-            want_kernel = S == 1 and get_default_impl() != "xla" and (
-                jax.default_backend() == "tpu"
-                or os.environ.get("DSTPU_FORCE_PAGED_KERNEL") == "1")
-            # what the kernel documents as unsupported; each gives way to the
-            # gather path below, and says so as the program is traced
-            gaps = [why for bad, why in (
-                (cfg.pos_embedding == "alibi", "ALiBi bias"),
-                (bool(cfg.logit_softcap), "logit softcap"),
-                (hd not in (64, 128, 256), f"head_dim {hd}"),
-                (kp.shape[2] % 8 != 0, f"block size {kp.shape[2]} % 8 != 0"),
-            ) if bad]
-            use_kernel = want_kernel and not gaps
-            if want_kernel and gaps:
-                logger.warning("paged decode takes the XLA gather path, not "
-                               f"the Pallas kernel: {', '.join(gaps)}")
-            if use_kernel:
-                # Pallas paged decode: pool blocks stream via the block table's
-                # index map — no materialized gather copy (paged_attention.py)
-                from ..ops.transformer.paged_attention import paged_decode_attention
+                    # NOTE: evaluated at TRACE time — the env override (used by tests
+                    # to exercise this branch in interpret mode) and set_default_impl
+                    # must be set before the engine compiles its decode program
+                    want_kernel = S == 1 and get_default_impl() != "xla" and (
+                        jax.default_backend() == "tpu"
+                        or os.environ.get("DSTPU_FORCE_PAGED_KERNEL") == "1")
+                    # what the kernel documents as unsupported; each gives way to the
+                    # gather path below, and says so as the program is traced
+                    gaps = [why for bad, why in (
+                        (cfg.pos_embedding == "alibi", "ALiBi bias"),
+                        (bool(cfg.logit_softcap), "logit softcap"),
+                        (hd not in (64, 128, 256), f"head_dim {hd}"),
+                        (kp.shape[2] % 8 != 0, f"block size {kp.shape[2]} % 8 != 0"),
+                    ) if bad]
+                    use_kernel = want_kernel and not gaps
+                    if want_kernel and gaps:
+                        logger.warning("paged decode takes the XLA gather path, not "
+                                       f"the Pallas kernel: {', '.join(gaps)}")
+                    if use_kernel:
+                        # Pallas paged decode: pool blocks stream via the block table's
+                        # index map — no materialized gather copy (paged_attention.py)
+                        from ..ops.transformer.paged_attention import paged_decode_attention
 
-                attn_out = paged_decode_attention(
-                    q[:, 0], kp, vp, tables, positions[:, 0] + 1)[:, None]
-            else:
-                gk = jnp.moveaxis(kp[:, tables], 0, 3).reshape(B, -1, kvh, hd)
-                gv = jnp.moveaxis(vp[:, tables], 0, 3).reshape(B, -1, kvh, hd)
-                T = gk.shape[1]
-                kpos = jnp.arange(T)
-                mask = kpos[None, None, :] <= positions[:, :, None]  # (B,S,T)
-                bias = jnp.where(mask, 0.0, -1e30)[:, None, None]  # (B,1,1,S,T)
-                if cfg.pos_embedding == "alibi":
-                    bias = bias + _alibi_bias(kpos)
+                        attn_out = paged_decode_attention(
+                            q[:, 0], kp, vp, tables, positions[:, 0] + 1)[:, None]
+                    else:
+                        gk = jnp.moveaxis(kp[:, tables], 0, 3).reshape(B, -1, kvh, hd)
+                        gv = jnp.moveaxis(vp[:, tables], 0, 3).reshape(B, -1, kvh, hd)
+                        T = gk.shape[1]
+                        kpos = jnp.arange(T)
+                        mask = kpos[None, None, :] <= positions[:, :, None]  # (B,S,T)
+                        bias = jnp.where(mask, 0.0, -1e30)[:, None, None]  # (B,1,1,S,T)
+                        if cfg.pos_embedding == "alibi":
+                            bias = bias + _alibi_bias(kpos)
+                        attn_out = _attention_op(
+                            q, gk, gv, causal=False, num_kv_groups=nh // kvh,
+                            softcap=cfg.logit_softcap, bias=bias,
+                        )
+            elif kv_cache is not None:
+                ck, cv = kv_cache  # (B, T, kvh, hd)
+                ck = jax.lax.dynamic_update_slice(ck, kk.astype(ck.dtype), (0, cache_index, 0, 0))
+                cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, cache_index, 0, 0))
+                new_kv = (ck, cv)
+                bias = (_alibi_bias(jnp.arange(ck.shape[1]))
+                        if cfg.pos_embedding == "alibi" else None)
                 attn_out = _attention_op(
-                    q, gk, gv, causal=False, num_kv_groups=nh // kvh,
+                    q, ck, cv, causal=True, q_offset=cache_index,
+                    num_kv_groups=nh // kvh, softcap=cfg.logit_softcap, bias=bias,
+                )
+            else:
+                # Ulysses reshard: gather seq, shard heads (no-op when seq axis == 1)
+                q = self._constraint(q, self._heads_spec())
+                kk = self._constraint(kk, self._heads_spec())
+                v = self._constraint(v, self._heads_spec())
+                bias = _alibi_bias(positions) if cfg.pos_embedding == "alibi" else None
+                if attn_mask_bias is not None:  # encoder padding mask (B,1,1,1,S)
+                    bias = attn_mask_bias if bias is None else bias + attn_mask_bias
+                attn_out = _attention_op(
+                    q, kk, v, causal=cfg.causal, num_kv_groups=nh // kvh,
                     softcap=cfg.logit_softcap, bias=bias,
                 )
-        elif kv_cache is not None:
-            ck, cv = kv_cache  # (B, T, kvh, hd)
-            ck = jax.lax.dynamic_update_slice(ck, kk.astype(ck.dtype), (0, cache_index, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, cache_index, 0, 0))
-            new_kv = (ck, cv)
-            bias = (_alibi_bias(jnp.arange(ck.shape[1]))
-                    if cfg.pos_embedding == "alibi" else None)
-            attn_out = _attention_op(
-                q, ck, cv, causal=True, q_offset=cache_index,
-                num_kv_groups=nh // kvh, softcap=cfg.logit_softcap, bias=bias,
-            )
-        else:
-            # Ulysses reshard: gather seq, shard heads (no-op when seq axis == 1)
-            q = self._constraint(q, self._heads_spec())
-            kk = self._constraint(kk, self._heads_spec())
-            v = self._constraint(v, self._heads_spec())
-            bias = _alibi_bias(positions) if cfg.pos_embedding == "alibi" else None
-            if attn_mask_bias is not None:  # encoder padding mask (B,1,1,1,S)
-                bias = attn_mask_bias if bias is None else bias + attn_mask_bias
-            attn_out = _attention_op(
-                q, kk, v, causal=cfg.causal, num_kv_groups=nh // kvh,
-                softcap=cfg.logit_softcap, bias=bias,
-            )
-        attn_out = attn_out.reshape(B, S, nh * hd)
-        attn_out = attn_out @ blk["wo"].astype(h.dtype)
-        if "attn_bias" in blk:
-            attn_out = attn_out + blk["attn_bias"].astype(h.dtype)
-        attn_out = self._constraint(attn_out, self._act_spec(kv_cache is None))
-        if rng is not None:
-            rng, r1 = jax.random.split(rng)
-            attn_out = _dropout(attn_out, cfg.dropout, r1, train)
+            attn_out = attn_out.reshape(B, S, nh * hd)
+            attn_out = attn_out @ blk["wo"].astype(h.dtype)
+            if "attn_bias" in blk:
+                attn_out = attn_out + blk["attn_bias"].astype(h.dtype)
+            attn_out = self._constraint(attn_out, self._act_spec(kv_cache is None))
+            if rng is not None:
+                rng, r1 = jax.random.split(rng)
+                attn_out = _dropout(attn_out, cfg.dropout, r1, train)
 
-        if post_ln:
-            x = _norm(x + attn_out, blk["ln1_scale"], blk.get("ln1_bias"),
-                      cfg.norm, cfg.norm_eps, cfg.norm_weight_offset)
-            h2 = x
-        elif cfg.parallel_block:
-            h2 = h if cfg.parallel_shared_ln else _norm(
-                x, blk["ln2_scale"], blk.get("ln2_bias"), cfg.norm, cfg.norm_eps,
-                cfg.norm_weight_offset)
-        else:
-            x = x + attn_out
-            h2 = checkpoint_name(
-                _norm(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg.norm,
-                      cfg.norm_eps, cfg.norm_weight_offset), "ln_out")
-        if act_q is not None:
-            h2 = act_q(h2)
-        aux = jnp.zeros((), jnp.float32)
-        if cfg.num_experts > 0:
-            mlp_out, aux = self._moe_ffn(h2, blk, train)
-        else:
-            if cfg.activation in ("swiglu", "geglu"):
-                g = checkpoint_name(h2 @ blk["w_gate"].astype(h.dtype), "mlp_up")
-                u = checkpoint_name(h2 @ blk["w_up"].astype(h.dtype), "mlp_up")
-                act = jax.nn.silu if cfg.activation == "swiglu" else \
-                    partial(jax.nn.gelu, approximate=True)
-                inter = act(g) * u
+        with jax.named_scope("mlp"):
+            if post_ln:
+                x = _norm(x + attn_out, blk["ln1_scale"], blk.get("ln1_bias"),
+                          cfg.norm, cfg.norm_eps, cfg.norm_weight_offset)
+                h2 = x
+            elif cfg.parallel_block:
+                h2 = h if cfg.parallel_shared_ln else _norm(
+                    x, blk["ln2_scale"], blk.get("ln2_bias"), cfg.norm, cfg.norm_eps,
+                    cfg.norm_weight_offset)
             else:
-                up = h2 @ blk["w_up"].astype(h.dtype)
-                if "mlp_up_bias" in blk:
-                    up = up + blk["mlp_up_bias"].astype(h.dtype)
-                up = checkpoint_name(up, "mlp_up")
-                if cfg.activation == "relu":
-                    inter = jax.nn.relu(up)
+                x = x + attn_out
+                h2 = checkpoint_name(
+                    _norm(x, blk["ln2_scale"], blk.get("ln2_bias"), cfg.norm,
+                          cfg.norm_eps, cfg.norm_weight_offset), "ln_out")
+            if act_q is not None:
+                h2 = act_q(h2)
+            aux = jnp.zeros((), jnp.float32)
+            if cfg.num_experts > 0:
+                mlp_out, aux = self._moe_ffn(h2, blk, train)
+            else:
+                if cfg.activation in ("swiglu", "geglu"):
+                    g = checkpoint_name(h2 @ blk["w_gate"].astype(h.dtype), "mlp_up")
+                    u = checkpoint_name(h2 @ blk["w_up"].astype(h.dtype), "mlp_up")
+                    act = jax.nn.silu if cfg.activation == "swiglu" else \
+                        partial(jax.nn.gelu, approximate=True)
+                    inter = act(g) * u
                 else:
-                    inter = jax.nn.gelu(up, approximate=cfg.activation != "gelu_exact")
-            inter = checkpoint_name(inter, "mlp_act")
-            mlp_out = inter @ blk["w_down"].astype(h.dtype)
-        if "mlp_bias" in blk:
-            mlp_out = mlp_out + blk["mlp_bias"].astype(h.dtype)
-        mlp_out = self._constraint(mlp_out, self._act_spec(kv_cache is None))
-        if rng is not None:
-            rng, r2 = jax.random.split(rng)
-            mlp_out = _dropout(mlp_out, cfg.dropout, r2, train)
+                    up = h2 @ blk["w_up"].astype(h.dtype)
+                    if "mlp_up_bias" in blk:
+                        up = up + blk["mlp_up_bias"].astype(h.dtype)
+                    up = checkpoint_name(up, "mlp_up")
+                    if cfg.activation == "relu":
+                        inter = jax.nn.relu(up)
+                    else:
+                        inter = jax.nn.gelu(up, approximate=cfg.activation != "gelu_exact")
+                inter = checkpoint_name(inter, "mlp_act")
+                mlp_out = inter @ blk["w_down"].astype(h.dtype)
+            if "mlp_bias" in blk:
+                mlp_out = mlp_out + blk["mlp_bias"].astype(h.dtype)
+            mlp_out = self._constraint(mlp_out, self._act_spec(kv_cache is None))
+            if rng is not None:
+                rng, r2 = jax.random.split(rng)
+                mlp_out = _dropout(mlp_out, cfg.dropout, r2, train)
         if post_ln:
             y = _norm(x + mlp_out, blk["ln2_scale"], blk.get("ln2_bias"),
                       cfg.norm, cfg.norm_eps, cfg.norm_weight_offset)
@@ -952,8 +956,9 @@ class TransformerLM:
         if attention_mask is not None:  # encoder padding: mask keys out
             mask_bias = jnp.where(attention_mask.astype(bool), 0.0, -1e30
                                   )[:, None, None, None, :]
-        x = self._embed(params, input_ids, positions, dtype,
-                        token_type_ids=token_type_ids)
+        with jax.named_scope("embed"):
+            x = self._embed(params, input_ids, positions, dtype,
+                            token_type_ids=token_type_ids)
         x = self._constraint(x, self._act_spec(True))
         if ltd_keep is not None and train:
             if pld_theta is not None:
@@ -967,7 +972,8 @@ class TransformerLM:
         else:
             x, aux = self._trunk(params, x, positions, rng, train,
                                  pld_theta=pld_theta, attn_mask_bias=mask_bias)
-        return self._head(params, x), aux
+        with jax.named_scope("lm_head_loss"):
+            return self._head(params, x), aux
 
     def logits(self, params, input_ids, positions=None, train=False, rng=None,
                attention_mask=None, token_type_ids=None):
@@ -1016,13 +1022,14 @@ class TransformerLM:
             labels = jnp.concatenate(
                 [input_ids[:, 1:], jnp.full_like(input_ids[:, :1], -100)], axis=1
             )
-        lg = lg.astype(jnp.float32)
-        mask = labels != -100
-        safe = jnp.where(mask, labels, 0)
-        logz = jax.scipy.special.logsumexp(lg, axis=-1)
-        gold = jnp.take_along_axis(lg, safe[..., None], axis=-1)[..., 0]
-        nll = (logz - gold) * mask
-        loss = jnp.sum(nll) / jnp.maximum(jnp.sum(mask), 1)
+        with jax.named_scope("lm_head_loss"):
+            lg = lg.astype(jnp.float32)
+            mask = labels != -100
+            safe = jnp.where(mask, labels, 0)
+            logz = jax.scipy.special.logsumexp(lg, axis=-1)
+            gold = jnp.take_along_axis(lg, safe[..., None], axis=-1)[..., 0]
+            nll = (logz - gold) * mask
+            loss = jnp.sum(nll) / jnp.maximum(jnp.sum(mask), 1)
         if self.config.num_experts > 0:
             loss = loss + self.config.moe_aux_loss_coef * aux
         return loss
@@ -1090,7 +1097,8 @@ class TransformerLM:
         positions = starts[:, None] + jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32), (B, S))
         dtype = kv_pool[0].dtype
-        x = self._embed(params, input_ids, positions, dtype)
+        with jax.named_scope("embed"):
+            x = self._embed(params, input_ids, positions, dtype)
 
         def body(h, layer):
             blk, kp_l, vp_l = layer
@@ -1100,8 +1108,11 @@ class TransformerLM:
             )
             return y, new_kv
 
-        x, (nkp, nvp) = jax.lax.scan(
-            body, x, (params["blocks"], kv_pool[0], kv_pool[1]))
+        # the scan slices each layer's pool out of the stacked pool and writes
+        # it back: "kv_carry" is what that plumbing alone costs on the device
+        with jax.named_scope("kv_carry"):
+            x, (nkp, nvp) = jax.lax.scan(
+                body, x, (params["blocks"], kv_pool[0], kv_pool[1]))
         # project only each sequence's last VALID position — skips the
         # (S, V) vocab matmul over the rest of the chunk
         if n_valid is None:
@@ -1111,7 +1122,8 @@ class TransformerLM:
         x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # (B,H)
         if logit_rows is not None:
             x_last = x_last[logit_rows]  # (R,H)
-        lg = self._head(params, x_last[:, None])[:, 0]
+        with jax.named_scope("lm_head_loss"):
+            lg = self._head(params, x_last[:, None])[:, 0]
         return lg, (nkp, nvp)
 
     def decode_paged_multi(self, params, kv_pool, toks, tables, starts, k: int,
@@ -1287,8 +1299,9 @@ def sample_or_argmax(lg, seeds, positions, temps, top_ks, top_ps):
 
         return jax.vmap(one)(lg, seeds, positions, temps, top_ks, top_ps)
 
-    return jax.lax.cond(jnp.any(temps > 0.0), _sampled, _greedy,
-                        (lg, seeds, positions, temps, top_ks, top_ps))
+    with jax.named_scope("sample"):
+        return jax.lax.cond(jnp.any(temps > 0.0), _sampled, _greedy,
+                            (lg, seeds, positions, temps, top_ks, top_ps))
 
 
 def build_model(preset: str, **overrides) -> TransformerLM:

@@ -93,4 +93,5 @@ def woq_matmul(x, codes, scale, num_bits: int, *, block_out: int = 512):
         out_shape=jax.ShapeDtypeStruct((B, Out), jnp.float32),
         scratch_shapes=[pltpu.VMEM((B, bo), jnp.float32)],
         interpret=_interpret(),
+        name="woq_gemm",
     )(x, codes, scale)

@@ -197,6 +197,7 @@ def _sparse_fwd(q, k, v, kcnt, kidx, *, causal, g, scale, block):
             jax.ShapeDtypeStruct((B, nh, Sq, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="block_sparse_fwd",
     )(kcnt, kidx, q, k, v)
     return out, lse
 
@@ -224,6 +225,7 @@ def _sparse_bwd(kcnt, kidx, qcnt, qidx, causal, g, scale, block, res, do):
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=_interpret(),
+        name="block_sparse_dq",
     )(kcnt, kidx, q, k, v, do, lse, delta)
 
     dkh, dvh = pl.pallas_call(
@@ -248,6 +250,7 @@ def _sparse_bwd(kcnt, kidx, qcnt, qidx, causal, g, scale, block, res, do):
             jax.ShapeDtypeStruct((B, nh, Skv, hd), q.dtype),
         ],
         interpret=_interpret(),
+        name="block_sparse_dkv",
     )(qcnt, qidx, q, k, v, do, lse, delta)
 
     if g > 1:

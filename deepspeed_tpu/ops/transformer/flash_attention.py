@@ -118,6 +118,7 @@ def _fwd(q, k, v, *, causal, num_kv_groups, scale, block_q, block_k):
             jax.ShapeDtypeStruct((B, nh, Sq, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -214,6 +215,7 @@ def _bwd(causal, num_kv_groups, scale, block_q, block_k, res, do):
             jax.ShapeDtypeStruct((B, nh, Skv, hd), q.dtype),
         ],
         interpret=_interpret(),
+        name="flash_bwd",
     )(q, k, v, do, lse, delta)
     dq = dq.astype(q.dtype)
 

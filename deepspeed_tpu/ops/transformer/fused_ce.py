@@ -101,6 +101,7 @@ def _ce_fwd_impl(x, w, labels, block_r, block_v, write_lg=True):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=_interpret(),
+        name="fused_ce_fwd",
     )(x[None], w[None], labels[None, :, None])
     lg, (m, l, gold) = (outs[0][0], outs[1:]) if write_lg else (None, outs)
     lse = m[0, :, 0] + jnp.log(l[0, :, 0])
@@ -167,6 +168,7 @@ def _ce_bwd_impl(lg, lse, labels, g, x, w, block_r, block_v):
             jax.ShapeDtypeStruct((ni, V, H), x.dtype),
         ],
         interpret=_interpret(),
+        name="fused_ce_bwd",
     )(lg[None], lse[None, :, None], labels[None, :, None],
       g[None, :, None], x[None], w[None])
     dw = dwp.astype(jnp.float32).sum(axis=0) if ni > 1 else dwp[0].astype(jnp.float32)

@@ -190,6 +190,7 @@ def _paged_decode_stream(q, k_pool, v_pool, tables, lens, *, scale):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
+        name="paged_decode",
     )(tables, lens, qg, k_pool, v_pool)
     return out.reshape(B, nh, hd)
 
@@ -249,5 +250,6 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lens, *, scale=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="paged_decode_grid",
     )(tables, lens, qg, k_pool, v_pool)
     return out.reshape(B, nh, hd)

@@ -35,6 +35,7 @@ from ..analysis.program_audit import audited_jit
 from ..comm.topology import MeshTopology
 from ..resilience.errors import CheckpointCorruptError, EngineUsageError
 from ..ops.optimizers import Optimizer, build_optimizer
+from ..utils import tracing
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (
     BACKWARD_GLOBAL_TIMER,
@@ -88,6 +89,11 @@ def _gather_to_host(tree):
 
         check_gather_conservation(tree, out)
     return out
+
+
+def _batch_key(batch):
+    """What tells two compiled variants of a step program apart."""
+    return tuple(getattr(x, "shape", None) for x in jax.tree.leaves(batch))
 
 
 def _tree_select(pred, on_true, on_false):
@@ -148,7 +154,11 @@ class LazyLoss:
                 "fwd+bwd — ~2x forward cost this micro-step. Read losses "
                 "after backward() (or use engine.eval() for validation). "
                 "[warned once]")
-        loss, grads = self._fused_fn(*self._args)
+        with tracing.span("engine.enqueue", program="fwd_bwd") as sp:
+            if sp.recording:
+                tracing.note_program("engine.fwd_bwd", self._fused_fn,
+                                     self._args, key=_batch_key(self._args[1]))
+            loss, grads = self._fused_fn(*self._args)
         self._loss = loss
         self._args = None
         return loss, grads
@@ -808,6 +818,9 @@ class DeepSpeedEngine:
         check_overflow = cfg.fp16_enabled
         compute_dtype = self.compute_dtype
 
+        # everything after the gradients: unscale, norm, clip, update, the
+        # cast back to the compute dtype (device time by scope: "optimizer")
+        @jax.named_scope("optimizer")
         def step_fn(lp_params, master, opt_state, acc_grads, scaler_state, lr):
             inv = 1.0 / scaler_state.cur_scale
             grads = jax.tree.map(lambda g: g.astype(jnp.float32) * inv, acc_grads)
@@ -1653,7 +1666,7 @@ class DeepSpeedEngine:
             raise EngineUsageError("no optimizer configured")
         self.timers(STEP_MICRO_TIMER).start()
         lr = jnp.asarray(self.get_lr()[0], jnp.float32)
-        (new_lp, new_master, new_opt, new_scaler, gnorm, overflow) = self._step_fn(
+        args = (
             self.params,
             self.master_params if self._mixed else None,
             self.opt_state,
@@ -1661,6 +1674,11 @@ class DeepSpeedEngine:
             self.scaler_state,
             lr,
         )
+        with tracing.span("engine.enqueue", program="step") as sp:
+            if sp.recording:
+                tracing.note_program("engine.step", self._step_fn, args)
+            (new_lp, new_master, new_opt, new_scaler, gnorm, overflow) = \
+                self._step_fn(*args)
         self.params = new_lp
         if self._mixed:
             self.master_params = new_master
@@ -1694,7 +1712,13 @@ class DeepSpeedEngine:
     def train_batch(self, data_iter=None):
         """One full global batch = GAS micro-steps + optimizer step. Returns the
         mean micro-loss (reference ``PipelineEngine.train_batch`` surface on the
-        plain engine)."""
+        plain engine). Under a profiler session the call is one step of
+        xprof's step view and an ``engine.train_batch`` span
+        (docs/TRACING.md)."""
+        with tracing.step_span("engine.train_batch", self.global_steps):
+            return self._train_batch(data_iter)
+
+    def _train_batch(self, data_iter):
         if data_iter is None and self.training_dataloader is None:
             raise ValueError("train_batch needs a data_iter or training_data at init")
         if data_iter is not None:
@@ -1732,7 +1756,9 @@ class DeepSpeedEngine:
                 # global_steps, which would be stale for steps 2..K of a window)
                 loss = self._multi_exec_step(it)
             else:
-                loss = self._fused_micro_step(next(it))
+                with tracing.span("engine.next_batch"):
+                    batch = next(it)
+                loss = self._fused_micro_step(batch)
             self.tput_timer.stop(global_step=True)
             return loss
         if self._multi_step_fn is not None and not getattr(self, "_warned_spe", False):
@@ -1743,7 +1769,8 @@ class DeepSpeedEngine:
                 "take per-step dispatches)")
         losses = []
         for _ in range(self.config.gradient_accumulation_steps):
-            batch = next(it)
+            with tracing.span("engine.next_batch"):
+                batch = next(it)
             loss = self.forward(batch)
             self.backward(loss)
             losses.append(loss.value if isinstance(loss, LazyLoss) else loss)
@@ -1874,8 +1901,13 @@ class DeepSpeedEngine:
     def _fused_micro_step(self, batch):
         """One fwd+bwd+optimizer step as a single compiled program (GAS=1 path)."""
         self.timers(STEP_MICRO_TIMER).start()
-        (new_lp, new_master, new_opt, new_scaler, loss, gnorm, overflow) = \
-            self._fused_step_fn(*self._fused_step_args(batch))
+        args = self._fused_step_args(batch)
+        with tracing.span("engine.enqueue", program="fused_step") as sp:
+            if sp.recording:
+                tracing.note_program("engine.fused_step", self._fused_step_fn,
+                                     args, key=_batch_key(args[4]))
+            (new_lp, new_master, new_opt, new_scaler, loss, gnorm, overflow) = \
+                self._fused_step_fn(*args)
         self.params = new_lp
         if self._mixed:
             self.master_params = new_master

@@ -108,6 +108,7 @@ from ..resilience.errors import (ContextOverflowError, DeadlineShedError,
 from ..resilience.recovery import RecoveryPolicy, RequestJournal
 from ..resilience.retry import RetryPolicy
 from ..resilience.watchdog import StepWatchdog
+from ..utils import tracing
 from ..utils.logging import logger
 from .metrics import Event, ServeMetrics
 from .request import Request, RequestState
@@ -310,6 +311,9 @@ class ContinuousBatchScheduler:
         #: absorb work staged by step_dispatch for step_absorb (the pool's
         #: two-phase drive): (prev record, fetched tokens, timing)
         self._pending_absorb: Optional[Dict[str, object]] = None
+        #: engine dispatches so far: a traced request's ``req.prefill`` event
+        #: says how many its prompt rode (docs/TRACING.md)
+        self._dispatches = 0
         self._closed = False
 
     # ------------------------------------------------------------------
@@ -1166,6 +1170,12 @@ class ContinuousBatchScheduler:
         req.state = RequestState.PREFILL
         if req.admitted_time is None:
             req.admitted_time = now
+            if tracing.enabled():
+                tracing.event("req.queue", int(req.arrival_time * 1e9),
+                              int(now * 1e9), uid=req.uid,
+                              prompt_tokens=len(req.prompt))
+                req._traced_admit = (self._dispatches,
+                                     self._skipped_prefill_tokens())
         self._live[req.uid] = req
         self.metrics.admitted += 1
         if req.tenant is not None:
@@ -1274,6 +1284,14 @@ class ContinuousBatchScheduler:
         if req.first_token_time is None:
             req.first_token_time = now
             self.metrics.ttft_s.append(now - req.arrival_time)
+            if tracing.enabled():
+                seen = getattr(req, "_traced_admit", None)
+                # admitted before the session began: the counts are unknown
+                counts = {} if seen is None else {
+                    "chunks": self._dispatches - seen[0],
+                    "cached_tokens": self._skipped_prefill_tokens() - seen[1]}
+                tracing.event("req.prefill", int(req.admitted_time * 1e9),
+                              int(now * 1e9), uid=req.uid, **counts)
         req.state = RequestState.DECODE
         sp = req.sampling
         scan = None
@@ -1366,11 +1384,19 @@ class ContinuousBatchScheduler:
         self._stop_scanners.pop(req.uid, None)
         req.state = RequestState.DONE
         req.finish_time = now
+        if tracing.enabled() and req.first_token_time is not None:
+            tracing.event("req.decode", int(req.first_token_time * 1e9),
+                          int(now * 1e9), uid=req.uid, tokens=len(req.tokens))
         self.journal.resolve(req.uid)
         self._release_tenant(req, "completed")
         self.metrics.completed += 1
         if self.spec is not None:
             self.spec.forget(req.uid)
+
+    def _skipped_prefill_tokens(self) -> int:
+        """Prompt tokens the prefix cache has spared so far (0 without one)."""
+        mgr = getattr(self.engine, "block_mgr", None)
+        return mgr.stats["skipped_prefill_tokens"] if mgr is not None else 0
 
     def _prefill_backlog(self) -> int:
         """Pending prompt tokens registered with the engine but not yet
@@ -1479,56 +1505,61 @@ class ContinuousBatchScheduler:
         is committed, the rest rolled back — the same all-or-nothing
         K-position shape as the fused path, so retries, containment, and
         the duty cycle treat both identically."""
-        backlog = self._prefill_backlog() if self.chunked_prefill else 0
-        if not backlog:
-            # no pending prompt tokens: nothing is starved, and the fused
-            # duty cycle re-arms (must happen even when this round has no
-            # feed either — a stale starvation flag would gate admission
-            # of an empty system forever)
-            self._starved_prio = None
-            self._fused_since_prefill = 0
-        if self.chunked_prefill:
-            # a fed token deferred by a trimmed dispatch (pool pressure, or
-            # a fault raised after enqueue) still sits in the engine's
-            # pending queue — refeeding it would double-advance the request
-            feed = {}
-            for uid, r in self._live.items():
-                if r.state is not RequestState.DECODE:
-                    continue
-                d = self.engine.state.seqs.get(uid)
-                if d is not None and d.in_flight == 0:
-                    feed[uid] = r.tokens[-1]
-        else:
-            feed = {uid: r.tokens[-1] for uid, r in self._live.items()
-                    if r.state is RequestState.DECODE}
-        if not feed and not backlog:
-            return
-        horizon = self._effective_horizon(now, feed) if feed else 1
-        # drafts are collected ONCE, outside the retry loop: an injected
-        # fault retries the verify dispatch with the SAME drafts, so the
-        # retried step is verbatim (chaos parity)
-        drafts: Optional[Dict[int, List[int]]] = None
-        if horizon > 1 and self.spec is not None:
-            drafts = self._collect_drafts(feed)
-            if not drafts:
-                self.metrics.observe_spec_degraded()
+        with tracing.span("sched.plan"):
+            backlog = self._prefill_backlog() if self.chunked_prefill else 0
+            if not backlog:
+                # no pending prompt tokens: nothing is starved, and the fused
+                # duty cycle re-arms (must happen even when this round has no
+                # feed either — a stale starvation flag would gate admission
+                # of an empty system forever)
+                self._starved_prio = None
+                self._fused_since_prefill = 0
+            if self.chunked_prefill:
+                # a fed token deferred by a trimmed dispatch (pool pressure, or
+                # a fault raised after enqueue) still sits in the engine's
+                # pending queue — refeeding it would double-advance the request
+                feed = {}
+                for uid, r in self._live.items():
+                    if r.state is not RequestState.DECODE:
+                        continue
+                    d = self.engine.state.seqs.get(uid)
+                    if d is not None and d.in_flight == 0:
+                        feed[uid] = r.tokens[-1]
+            else:
+                feed = {uid: r.tokens[-1] for uid, r in self._live.items()
+                        if r.state is RequestState.DECODE}
+            if not feed and not backlog:
+                return
+            horizon = self._effective_horizon(now, feed) if feed else 1
+            # drafts are collected ONCE, outside the retry loop: an injected
+            # fault retries the verify dispatch with the SAME drafts, so the
+            # retried step is verbatim (chaos parity)
+            drafts: Optional[Dict[int, List[int]]] = None
+            if horizon > 1 and self.spec is not None:
+                drafts = self._collect_drafts(feed)
+                if not drafts:
+                    self.metrics.observe_spec_degraded()
+        kind = "decode" if not backlog else ("mixed" if feed else "prefill")
         attempt = 0
         while True:
-            t0 = time.perf_counter()
+            # one pair of clock readings: the span's are the gauges'
+            disp = tracing.timed_span("sched.dispatch", kind=kind,
+                                      rows=len(feed))
             try:
-                if drafts:
-                    out = self.engine.verify_multi(feed, drafts)
-                elif horizon > 1:
-                    out = self.engine.decode_multi(feed, horizon=horizon)
-                elif backlog:
-                    # the mixed chunked-prefill dispatch: decode rows first
-                    # (the engine's shortest-pending-first order), prompt
-                    # chunks filling the rest of the token budget
-                    uids = list(feed)
-                    out = self.engine.put(uids, [[feed[u]] for u in uids],
-                                          greedy=True, max_steps=1)
-                else:
-                    out = self.engine.decode_step(feed, greedy=True)
+                with disp:
+                    if drafts:
+                        out = self.engine.verify_multi(feed, drafts)
+                    elif horizon > 1:
+                        out = self.engine.decode_multi(feed, horizon=horizon)
+                    elif backlog:
+                        # the mixed chunked-prefill dispatch: decode rows
+                        # first (the engine's shortest-pending-first order),
+                        # prompt chunks filling the rest of the token budget
+                        uids = list(feed)
+                        out = self.engine.put(uids, [[feed[u]] for u in uids],
+                                              greedy=True, max_steps=1)
+                    else:
+                        out = self.engine.decode_step(feed, greedy=True)
                 break
             except TransientEngineError as e:
                 site = "verify_multi" if drafts else "decode_step"
@@ -1565,38 +1596,39 @@ class ContinuousBatchScheduler:
                     raise
                 self._preempt(victim)
                 return  # retry next step with the shrunken batch
-        dt = time.perf_counter() - t0
-        kind = "decode" if not backlog else ("mixed" if feed else "prefill")
-        self._observe_engine_ok(kind, dt, scale=horizon)
-        if feed:
-            self.metrics.observe_step(dt, len(feed), horizon=horizon)
-            self.metrics.observe_decode(horizon, fused=horizon > 1)
-            per_tok = dt / horizon
-            self._token_est_s = (per_tok if self._token_est_s == 0.0
-                                 else 0.5 * self._token_est_s + 0.5 * per_tok)
-        if backlog:
-            # chunked-prefill accounting + the fused/prefill duty cycle:
-            # a dispatch that consumed prompt tokens re-arms one fused
-            # dispatch; one that couldn't (rows trimmed under pool
-            # pressure) applies admission-style preemption pressure so a
-            # lower-priority resident can't starve a prefilling request
-            consumed = max(0, backlog - self._prefill_backlog())
-            if consumed:
-                self.metrics.observe_prefill_chunk(consumed,
-                                                   interleaved=bool(feed))
-                self._fused_since_prefill = 0
-                self._starved_prio = None
+        dt = disp.seconds
+        self._dispatches += 1
+        with tracing.span("sched.absorb"):
+            self._observe_engine_ok(kind, dt, scale=horizon)
+            if feed:
+                self.metrics.observe_step(dt, len(feed), horizon=horizon)
+                self.metrics.observe_decode(horizon, fused=horizon > 1)
+                per_tok = dt / horizon
+                self._token_est_s = (per_tok if self._token_est_s == 0.0
+                                     else 0.5 * self._token_est_s + 0.5 * per_tok)
+            if backlog:
+                # chunked-prefill accounting + the fused/prefill duty cycle:
+                # a dispatch that consumed prompt tokens re-arms one fused
+                # dispatch; one that couldn't (rows trimmed under pool
+                # pressure) applies admission-style preemption pressure so a
+                # lower-priority resident can't starve a prefilling request
+                consumed = max(0, backlog - self._prefill_backlog())
+                if consumed:
+                    self.metrics.observe_prefill_chunk(consumed,
+                                                       interleaved=bool(feed))
+                    self._fused_since_prefill = 0
+                    self._starved_prio = None
+                elif horizon > 1:
+                    self._fused_since_prefill += 1
+                else:
+                    self.metrics.observe_prefill_deferred()
+                    self._relieve_prefill_pressure(now)
+            if drafts:
+                self._absorb_speculation(out, drafts, now)
             elif horizon > 1:
-                self._fused_since_prefill += 1
+                self._absorb_multi(out, now)
             else:
-                self.metrics.observe_prefill_deferred()
-                self._relieve_prefill_pressure(now)
-        if drafts:
-            self._absorb_speculation(out, drafts, now)
-        elif horizon > 1:
-            self._absorb_multi(out, now)
-        else:
-            self._absorb(out, now)
+                self._absorb(out, now)
 
     # ------------------------------------------------------------------
     # pipelined dispatch (docs/SERVING.md "Pipelined dispatch")
@@ -1634,77 +1666,86 @@ class ContinuousBatchScheduler:
         :meth:`_pipeline_absorb_stage` — which runs while the new dispatch
         executes. Returns None when the round took the synchronous path
         (pipeline barrier) or there was nothing to fetch."""
-        t_plan0 = time.perf_counter()
-        backlog = self._prefill_backlog() if self.chunked_prefill else 0
-        if not backlog:
-            # same re-arm rule as the synchronous loop (see _decode_sync)
-            self._starved_prio = None
-            self._fused_since_prefill = 0
-        # candidate decode rows, the sync twin's feed-build rule: a token
-        # deferred inside the engine (in_flight) is never double-fed
-        cands: Dict[int, int] = {}
-        for uid, r in self._live.items():
-            if r.state is not RequestState.DECODE:
-                continue
-            d = self.engine.state.seqs.get(uid)
-            if d is not None and d.in_flight == 0:
-                cands[uid] = r.tokens[-1]
-        if self._pipeline_barrier(now, cands, backlog):
+        # the stage gauges and the spans share their clock readings
+        with tracing.timed_span("sched.plan") as plan:
+            backlog = self._prefill_backlog() if self.chunked_prefill else 0
+            if not backlog:
+                # same re-arm rule as the synchronous loop (see _decode_sync)
+                self._starved_prio = None
+                self._fused_since_prefill = 0
+            # candidate decode rows, the sync twin's feed-build rule: a token
+            # deferred inside the engine (in_flight) is never double-fed
+            cands: Dict[int, int] = {}
+            for uid, r in self._live.items():
+                if r.state is not RequestState.DECODE:
+                    continue
+                d = self.engine.state.seqs.get(uid)
+                if d is not None and d.in_flight == 0:
+                    cands[uid] = r.tokens[-1]
+            barrier = self._pipeline_barrier(now, cands, backlog)
+            if not barrier:
+                prev = self._inflight
+                raw: Optional[Dict[int, int]] = None
+                wait_dt = 0.0
+                if prev is not None:
+                    # the device wait, inside the plan span: the plan gauge is
+                    # the plan span's self time
+                    with tracing.timed_span("sched.wait") as wait:
+                        try:
+                            raw = prev["handle"].fetch()
+                        except UnrecoverableEngineError:
+                            # the round died with the device: nothing of it was
+                            # absorbed, so journal replay regenerates its tokens
+                            # bitwise from the last committed state
+                            self._inflight = None
+                            raise
+                    wait_dt = wait.seconds
+                if cands or prev is not None:
+                    # plan the next feed. Rows riding the fetched round are fed their
+                    # brand-new token; predicted finishes (EOS / max_new_tokens —
+                    # decidable from the raw token alone) are NOT fed. Stop-sequence
+                    # finishes are NOT predicted (the scan is stateful): those rows
+                    # are fed speculatively and the successor token rolled back at
+                    # absorb — the speculative-absorb rule.
+                    next_feed: Dict[int, int] = {}
+                    for uid, last_tok in cands.items():
+                        r = self._live[uid]
+                        if prev is not None and raw is not None and uid in prev["rows"]:
+                            rec_req, rec_desc, rec_emitted = prev["rows"][uid]
+                            if (r is rec_req and len(r.tokens) == rec_emitted
+                                    and self.engine.state.seqs.get(uid) is rec_desc):
+                                tok = raw[uid]
+                                if (len(r.tokens) + 1 >= r.max_new_tokens
+                                        or (r.eos_token is not None
+                                            and tok == r.eos_token)):
+                                    continue  # finishes at absorb: never fed
+                                next_feed[uid] = tok
+                                continue
+                            # stale row (preempted/re-admitted since dispatch): its
+                            # in-flight token is discarded at absorb; feeding the
+                            # committed last token regenerates it bitwise
+                        next_feed[uid] = last_tok
+        if barrier:
             if self._inflight is not None:
                 self.metrics.observe_pipeline_stall()
                 self._drain_inflight(now)
             self._decode_sync(now)
             return None
-        prev = self._inflight
-        raw: Optional[Dict[int, int]] = None
-        wait_dt = 0.0
-        if prev is not None:
-            t_wait0 = time.perf_counter()
-            try:
-                raw = prev["handle"].fetch()
-            except UnrecoverableEngineError:
-                # the round died with the device: nothing of it was
-                # absorbed, so journal replay regenerates its tokens
-                # bitwise from the last committed state
-                self._inflight = None
-                raise
-            wait_dt = time.perf_counter() - t_wait0
         if not cands and prev is None:
             return None
-        # plan the next feed. Rows riding the fetched round are fed their
-        # brand-new token; predicted finishes (EOS / max_new_tokens —
-        # decidable from the raw token alone) are NOT fed. Stop-sequence
-        # finishes are NOT predicted (the scan is stateful): those rows
-        # are fed speculatively and the successor token rolled back at
-        # absorb — the speculative-absorb rule.
-        next_feed: Dict[int, int] = {}
-        for uid, last_tok in cands.items():
-            r = self._live[uid]
-            if prev is not None and raw is not None and uid in prev["rows"]:
-                rec_req, rec_desc, rec_emitted = prev["rows"][uid]
-                if (r is rec_req and len(r.tokens) == rec_emitted
-                        and self.engine.state.seqs.get(uid) is rec_desc):
-                    tok = raw[uid]
-                    if (len(r.tokens) + 1 >= r.max_new_tokens
-                            or (r.eos_token is not None
-                                and tok == r.eos_token)):
-                        continue  # finishes at absorb: never fed
-                    next_feed[uid] = tok
-                    continue
-                # stale row (preempted/re-admitted since dispatch): its
-                # in-flight token is discarded at absorb; feeding the
-                # committed last token regenerates it bitwise
-            next_feed[uid] = last_tok
-        plan_dt = time.perf_counter() - t_plan0 - wait_dt
+        plan_dt = plan.seconds - wait_dt
         handle = None
         enqueue_dt = 0.0
         if next_feed:
             attempt = 0
             while True:
-                t0 = time.perf_counter()
+                disp = tracing.timed_span("sched.dispatch", kind="decode",
+                                          rows=len(next_feed))
                 try:
-                    handle = self.engine.decode_dispatch(next_feed)
-                    enqueue_dt = time.perf_counter() - t0
+                    with disp:
+                        handle = self.engine.decode_dispatch(next_feed)
+                    enqueue_dt = disp.seconds
+                    self._dispatches += 1
                     break
                 except TransientEngineError as e:
                     if not self._retry_transient("decode_step", attempt, e):
@@ -1766,37 +1807,37 @@ class ContinuousBatchScheduler:
         regenerate bitwise from committed state on replay."""
         prev, raw = staged["prev"], staged["raw"]
         cur = self._inflight
-        t0 = time.perf_counter()
-        absorbed = 0
-        for uid, (req, desc, emitted) in prev["rows"].items():
-            r = self._live.get(uid)
-            if r is None:  # cancelled between dispatch and absorb
-                self._engine_flush(uid)
-                continue
-            if (r is not req or r.state is not RequestState.DECODE
-                    or len(r.tokens) != emitted
-                    or self.engine.state.seqs.get(uid) is not desc):
-                continue  # stale: the in-flight token is discarded
-            finished = self._emit_token(r, raw[uid], now)
-            absorbed += 1
-            drop = 0
-            retain = 0
-            if cur is not None and uid in cur["rows"]:
+        with tracing.timed_span("sched.absorb") as absorb:
+            absorbed = 0
+            for uid, (req, desc, emitted) in prev["rows"].items():
+                r = self._live.get(uid)
+                if r is None:  # cancelled between dispatch and absorb
+                    self._engine_flush(uid)
+                    continue
+                if (r is not req or r.state is not RequestState.DECODE
+                        or len(r.tokens) != emitted
+                        or self.engine.state.seqs.get(uid) is not desc):
+                    continue  # stale: the in-flight token is discarded
+                finished = self._emit_token(r, raw[uid], now)
+                absorbed += 1
+                drop = 0
+                retain = 0
+                if cur is not None and uid in cur["rows"]:
+                    if finished:
+                        drop = 1
+                        del cur["rows"][uid]
+                        self.metrics.observe_pipeline_rollback(1)
+                    else:
+                        retain = 1
+                        # the successor round snapshotted this row BEFORE the
+                        # emit above; refresh its expected-emitted count so the
+                        # next absorb's staleness check sees the new length
+                        c_req, c_desc, _ = cur["rows"][uid]
+                        cur["rows"][uid] = (c_req, c_desc, len(r.tokens))
+                self._engine_commit(uid, drop, retain)
                 if finished:
-                    drop = 1
-                    del cur["rows"][uid]
-                    self.metrics.observe_pipeline_rollback(1)
-                else:
-                    retain = 1
-                    # the successor round snapshotted this row BEFORE the
-                    # emit above; refresh its expected-emitted count so the
-                    # next absorb's staleness check sees the new length
-                    c_req, c_desc, _ = cur["rows"][uid]
-                    cur["rows"][uid] = (c_req, c_desc, len(r.tokens))
-            self._engine_commit(uid, drop, retain)
-            if finished:
-                self._finish(r, now)
-        absorb_dt = time.perf_counter() - t0
+                    self._finish(r, now)
+        absorb_dt = absorb.seconds
         dt = prev["enqueue_dt"] + staged["wait_dt"]
         self._observe_engine_ok("decode", dt, scale=1.0)
         if absorbed:
@@ -1817,13 +1858,13 @@ class ContinuousBatchScheduler:
         prev = self._inflight
         if prev is None:
             return
-        t0 = time.perf_counter()
-        try:
-            raw = prev["handle"].fetch()
-        except UnrecoverableEngineError:
-            self._inflight = None
-            raise
-        wait_dt = time.perf_counter() - t0
+        with tracing.timed_span("sched.wait") as wait:
+            try:
+                raw = prev["handle"].fetch()
+            except UnrecoverableEngineError:
+                self._inflight = None
+                raise
+        wait_dt = wait.seconds
         self._inflight = None
         self._pipeline_absorb_stage(
             {"prev": prev, "raw": raw, "wait_dt": wait_dt, "plan_dt": 0.0},
@@ -1926,8 +1967,9 @@ class ContinuousBatchScheduler:
         :meth:`_recover` instead of propagating; the step ends after the
         rebuild and the replay proceeds from the next step's normal
         admission."""
-        self.step_dispatch()
-        return self.step_absorb()
+        with tracing.span("sched.step"):
+            self.step_dispatch()
+            return self.step_absorb()
 
     def step_dispatch(self) -> None:
         """Pool phase 1 (docs/SERVING.md "Pipelined dispatch"): admission +
@@ -1946,10 +1988,11 @@ class ContinuousBatchScheduler:
                 raise exc
             self._recover(exc, now)
             now = self._clock()
-        self.breaker.poll(now)
-        self._expire_deadlines(now)
         try:
-            self._admit(now)
+            with tracing.span("sched.admit"):
+                self.breaker.poll(now)
+                self._expire_deadlines(now)
+                self._admit(now)
             if self._stalled:
                 self._absorb(self._engine_put([], []), now)
             self._pending_absorb = self._pipeline_dispatch_stage(now)
@@ -1976,7 +2019,8 @@ class ContinuousBatchScheduler:
                 if self.escalate_losses:
                     raise
                 self._recover(e, now)
-            self._step_postamble()
+            with tracing.span("sched.postamble"):
+                self._step_postamble()
             return bool(self._queue or self._live
                         or self._inflight is not None)
         if self._engine_dead is not None:
@@ -1985,10 +2029,11 @@ class ContinuousBatchScheduler:
                 raise exc
             self._recover(exc, now)
             now = self._clock()
-        self.breaker.poll(now)
-        self._expire_deadlines(now)
         try:
-            self._admit(now)
+            with tracing.span("sched.admit"):
+                self.breaker.poll(now)
+                self._expire_deadlines(now)
+                self._admit(now)
             if self._stalled:
                 self._absorb(self._engine_put([], []), now)
             self._decode_once(now)
@@ -2000,7 +2045,8 @@ class ContinuousBatchScheduler:
                 # the pool's detach sweep.
                 raise
             self._recover(e, now)
-        self._step_postamble()
+        with tracing.span("sched.postamble"):
+            self._step_postamble()
         return bool(self._queue or self._live)
 
     def _step_postamble(self) -> None:

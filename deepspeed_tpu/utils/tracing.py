@@ -1,0 +1,352 @@
+"""The program's one span recorder (docs/TRACING.md).
+
+Spans are recorded where the work happens: the serving scheduler, the serving
+engine and the training engine open them around their own phases, with counts
+as attributes. The recorder follows the JAX profiler and has no switch of its
+own:
+
+- **on** exactly while a profiler session is active (``jax.profiler.
+  start_trace`` / ``start_server`` capture, the benchmark's ``Tracer``):
+  every :func:`span` is recorded in memory *and* is a
+  ``jax.profiler.TraceAnnotation``, so it sits on the trace's clock on the
+  ``/host:CPU`` plane next to the device's operations;
+- **off** otherwise: :func:`span` costs one flag read and returns one shared
+  no-op object; nothing is recorded.
+
+The flag is ``TraceMe.is_enabled()`` of the installed jaxlib's profiler
+bindings (``jax._src.lib._profiler``), the same flag ``TraceAnnotation``
+itself consults; ``enabled`` is the only name bound to it.
+
+While on, a ``jax.monitoring`` listener records every backend compile, and
+every load of a program from the compile cache, as a ``compile`` span, and
+:func:`note_program` remembers which jitted programs ran, so that
+:func:`device_scopes` can map the device's instruction names to the
+``jax.named_scope`` they were traced under — after the window, never in it.
+
+No other module of ``deepspeed_tpu`` calls ``jax.profiler`` annotations
+directly.
+"""
+
+import collections
+import itertools
+import re
+import threading
+import time
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
+
+import jax
+from jax._src.lib import _profiler
+
+#: spans kept; older ones fall off (a traced window of 8 s writes ~1e3)
+MAX_SPANS = 1 << 16
+
+#: the recorder's clock, in ns. ``time.monotonic`` is the serving
+#: scheduler's default clock, so request events and spans share it
+clock_ns = time.monotonic_ns
+
+
+class Record(NamedTuple):
+    """One finished span. ``parent`` is the id of the span that was open on
+    the same thread when this one started (0: none)."""
+    id: int
+    name: str
+    start: int
+    end: int
+    parent: int
+    attrs: dict
+
+
+_buf: "collections.deque[Record]" = collections.deque(maxlen=MAX_SPANS)
+_ids = itertools.count(1)
+_local = threading.local()
+
+
+#: True while a JAX profiler session is recording: the profiler's own flag,
+#: the one ``TraceAnnotation`` consults. Everything below asks this name.
+enabled = _profiler.TraceMe.is_enabled
+
+
+def _stack() -> List[int]:
+    try:
+        return _local.stack
+    except AttributeError:
+        _local.stack = []
+        return _local.stack
+
+
+class _NoSpan:
+    """What :func:`span` returns while off: one shared object."""
+    __slots__ = ()
+    recording = False
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
+class Span:
+    """An open span. ``set()`` adds attributes known only later (the counts
+    of a dispatch are known once the batch is built). ``seconds`` is the
+    duration after exit, for callers that feed a gauge from the same two
+    clock readings (:func:`timed_span`)."""
+    __slots__ = ("name", "attrs", "recording", "step", "id", "parent",
+                 "start", "end", "_ann")
+
+    def __init__(self, name: str, attrs: dict, recording: bool):
+        self.name, self.attrs, self.recording = name, attrs, recording
+        self.step = None
+        self.start = self.end = 0
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+
+    @property
+    def seconds(self) -> float:
+        return (self.end - self.start) / 1e9
+
+    def __enter__(self):
+        if self.recording:
+            stack = _stack()
+            self.id = next(_ids)
+            self.parent = stack[-1] if stack else 0
+            stack.append(self.id)
+            self._ann = (
+                jax.profiler.TraceAnnotation(self.name) if self.step is None
+                else jax.profiler.StepTraceAnnotation(self.name,
+                                                      step_num=self.step))
+            self._ann.__enter__()
+        self.start = clock_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.end = clock_ns()
+        if self.recording:
+            self._ann.__exit__(*exc)
+            stack = _stack()
+            # an exception that skipped inner exits cannot leave them open
+            while stack and stack.pop() != self.id:
+                pass
+            _buf.append(Record(self.id, self.name, self.start, self.end,
+                               self.parent, self.attrs))
+        return False
+
+
+def span(name: str, **attrs):
+    """Context manager around one phase. Off: the shared no-op."""
+    if not enabled():
+        return NO_SPAN
+    return Span(name, attrs, True)
+
+
+def step_span(name: str, step: int, **attrs):
+    """:func:`span` around one training step, marked with
+    ``jax.profiler.StepTraceAnnotation(step_num=step)`` while on."""
+    if not enabled():
+        return NO_SPAN
+    sp = Span(name, dict(attrs, step=int(step)), True)
+    sp.step = int(step)
+    return sp
+
+
+def timed_span(name: str, **attrs) -> Span:
+    """A span whose caller needs the duration whether or not anyone records
+    (a gauge fed from the same timestamps): always reads the clock, records
+    only while on."""
+    return Span(name, attrs, enabled())
+
+
+def event(name: str, start: int, end: int, **attrs) -> None:
+    """A span whose ends are known only afterwards (a request's life cycle),
+    on :data:`clock_ns`. Its parent is the span open on this thread now: the
+    one that caused the transition."""
+    if enabled():
+        stack = _stack()
+        _buf.append(Record(next(_ids), name, int(start), int(end),
+                           stack[-1] if stack else 0, attrs))
+
+
+def snapshot() -> List[Record]:
+    """The recorded spans, oldest first."""
+    return list(_buf)
+
+
+def clear() -> None:
+    """Forget the recorded spans and the programs noted for
+    :func:`device_scopes`."""
+    _buf.clear()
+    _programs.clear()
+    _scope_cache.clear()
+
+
+# -- reading spans ---------------------------------------------------------
+
+def _covered(intervals: Iterable[Tuple[int, int]], lo: int, hi: int) -> int:
+    """Length of the union of ``intervals`` clipped to [lo, hi]."""
+    total, edge = 0, lo
+    for s, e in sorted(intervals):
+        s, e = max(s, edge), min(e, hi)
+        if e > s:
+            total += e - s
+            edge = e
+    return total
+
+
+def self_time(spans: Iterable[Record]) -> Dict[int, int]:
+    """{span id: ns} of each span's duration minus the part of its interval
+    that its child spans cover (children may overlap; the part is their
+    union)."""
+    spans = list(spans)
+    kids: Dict[int, List[Tuple[int, int]]] = {}
+    for s in spans:
+        kids.setdefault(s.parent, []).append((s.start, s.end))
+    return {s.id: (s.end - s.start)
+            - _covered(kids.get(s.id, ()), s.start, s.end) for s in spans}
+
+
+def descendants(spans: Iterable[Record], root: int) -> List[Record]:
+    """Every span below ``root`` (children, their children...)."""
+    spans = list(spans)
+    kids: Dict[int, List[Record]] = {}
+    for s in spans:
+        kids.setdefault(s.parent, []).append(s)
+    out, todo = [], [root]
+    while todo:
+        for s in kids.get(todo.pop(), ()):
+            out.append(s)
+            todo.append(s.id)
+    return out
+
+
+# -- compiles --------------------------------------------------------------
+
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _on_duration(name: str, seconds: float, **kw) -> None:
+    """JAX reports ``_COMPILE`` once per program it had to make runnable,
+    compiled or loaded from the compile cache; a load reports ``_CACHE_READ``
+    first, from inside it, on the same thread."""
+    if name == _CACHE_READ:
+        _local.cache_read = True
+    elif name == _COMPILE:
+        cached = getattr(_local, "cache_read", False)
+        _local.cache_read = False
+        if enabled():
+            end = clock_ns()
+            event("compile", end - int(seconds * 1e9), end,
+                  program=kw.get("fun_name", ""), cached=cached)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+# -- device time by scope --------------------------------------------------
+
+#: (name, key) -> (jitted function, abstract args, abstract kwargs)
+_programs: Dict[tuple, tuple] = {}
+_scope_cache: Dict[tuple, Dict[str, str]] = {}
+
+#: ``jax.named_scope`` names the program uses, by what they classify as
+MODEL_SCOPES = ("embed", "attn", "mlp", "lm_head_loss")
+SERVE_SCOPES = ("kv_write", "paged_attn", "sample")
+
+
+def _abstract(x):
+    if hasattr(x, "shape") and hasattr(x, "dtype"):
+        # only a committed array's placement is part of the signature
+        sharding = x.sharding if getattr(x, "committed", False) else None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+    return x
+
+
+def note_program(name: str, fn, args: tuple, kwargs: Optional[dict] = None,
+                 key=None) -> None:
+    """Remember that the jitted ``fn`` ran with ``args`` while recording, by
+    shapes only (donated buffers are not kept). ``key`` tells a program's
+    compiled variants apart; the first call of each is kept."""
+    if not enabled() or (name, key) in _programs:
+        return
+    _programs[(name, key)] = (fn, jax.tree.map(_abstract, args),
+                              jax.tree.map(_abstract, kwargs or {}))
+
+
+def classify(op_name: str) -> str:
+    """The scope of one HLO ``op_name`` (JAX's name stack, ``jit(step)/
+    transpose(jvp(attn))/dot_general``): ``optimizer``; ``remat`` (the
+    forward recomputed under the backward); ``bwd``; ``fwd`` (the
+    differentiated forward); the serving scopes ``kv_write``, ``paged_attn``,
+    ``sample``; ``model`` (a model scope in a program without gradients);
+    ``kv_carry`` (the paged program's layer scan itself: slicing the stacked
+    pool and writing it back); else ``unscoped``."""
+    parts = re.split(r"[/()]", op_name)
+    if "optimizer" in parts:
+        return "optimizer"
+    for s in SERVE_SCOPES:
+        if s in parts:
+            return s
+    if "rematted_computation" in parts:
+        return "remat"
+    if "transpose" in parts:
+        return "bwd"
+    if "jvp" in parts:
+        return "fwd"
+    if any(s in parts for s in MODEL_SCOPES):
+        return "model"
+    if "kv_carry" in parts:
+        return "kv_carry"
+    return "unscoped"
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \(?([a-z0-9]+\[[0-9,]*\])?")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def op_key(line: str) -> str:
+    """``<instruction> <result shape>`` of an HLO line, or of a device trace
+    event's name (a TPU trace names an event by its HLO line without
+    metadata). The shape tells apart the same instruction name in two
+    programs."""
+    m = _INSTR.match(line)
+    if not m:
+        return line.strip().lstrip("%")
+    return f"{m.group(1)} {m.group(2) or ''}".strip()
+
+
+def scopes_of_hlo(text: str) -> Dict[str, str]:
+    """{op_key: scope} of every instruction in optimized HLO text, and
+    {instruction: scope} for a trace that names events by the instruction
+    alone (the CPU client's)."""
+    out: Dict[str, str] = {}
+    for line in text.splitlines():
+        if " = " not in line:
+            continue
+        found = _OP_NAME.search(line)
+        scope = classify(found.group(1)) if found else "unscoped"
+        key = op_key(line)
+        for k in (key, key.split(" ")[0]):
+            out[k] = scope if out.get(k, scope) == scope else "mixed"
+    return out
+
+
+def device_scopes() -> Dict[str, str]:
+    """{op_key: scope} for the programs noted while recording, from their
+    optimized HLO (``lower().compile().as_text()`` on the noted shapes: a
+    compile-cache hit). Call it after the traced window. An instruction two
+    programs put under different scopes reads ``mixed``."""
+    merged: Dict[str, str] = {}
+    for ident, (fn, args, kwargs) in list(_programs.items()):
+        if ident not in _scope_cache:
+            text = fn.lower(*args, **kwargs).compile().as_text()
+            _scope_cache[ident] = scopes_of_hlo(text)
+        for k, scope in _scope_cache[ident].items():
+            merged[k] = scope if merged.get(k, scope) == scope else "mixed"
+    return merged

@@ -332,33 +332,3 @@ class TestRandomLTD:
                 "mesh": {"data": 8},
                 "data_efficiency": {"data_routing": {
                     "enabled": True, "random_ltd": {"enabled": True}}}})
-
-
-def test_nvtx_shim_annotates_and_preserves_metadata():
-    """utils/nvtx.py (reference instrument_w_nvtx): spans wrap calls via
-    jax.profiler.TraceAnnotation and the decorator preserves function
-    metadata; push/pop pairs nest without error."""
-    from deepspeed_tpu.utils.nvtx import (annotate, instrument_w_nvtx,
-                                          range_pop, range_push)
-
-    calls = []
-
-    @instrument_w_nvtx
-    def traced(x):
-        calls.append(x)
-        return x + 1
-
-    assert traced.__name__ == "traced"
-    with annotate("outer"):
-        a = range_push("inner")
-        assert traced(1) == 2
-        range_pop(a)
-    assert calls == [1]
-    # a span that is no longer (or never was) on this thread's stack must not
-    # be closed again — double __exit__ on the TraceAnnotation corrupts the
-    # profiler state
-    range_pop(a)  # already popped above: no-op
-    b = range_push("once")
-    range_pop()
-    range_pop(b)  # popped by the no-arg form already: no-op
-    assert range_pop() is None  # empty stack stays a no-op
