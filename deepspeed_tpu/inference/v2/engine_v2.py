@@ -35,6 +35,7 @@ from ...analysis.program_audit import audited_jit
 from ...utils import tracing
 from ...analysis.sanitizer import checked_cache_cls, sanitize_enabled
 from ...models.transformer import sample_or_argmax
+from ...ops.transformer import paged_attention
 from ...resilience.errors import (ContextOverflowError, EngineUsageError,
                                   PoolExhaustedError)
 from ...utils.logging import log_dist
@@ -237,7 +238,7 @@ class InferenceEngineV2:
             #: device bytes of one block's K+V across all layers — the unit
             #: of every tier/swap byte counter and of the scheduler's
             #: swap-vs-recompute cost model
-            self.block_bytes = sum(int(a.nbytes) for a in self.kv) // num_blocks
+            self.block_bytes = int(self.kv.nbytes) // num_blocks
             log_dist(
                 f"InferenceEngineV2(paged): blocks={num_blocks}x{block_size} "
                 f"seqs<={max_seqs} ctx={self.max_seq_len} chunk={prefill_chunk} "
@@ -454,10 +455,7 @@ class InferenceEngineV2:
         if self._cow_fn is None:
 
             def cow(kv, src, dst):
-                k, v = kv  # (L, kvh, NB, BS, hd) each; block axis = 2
-                k = k.at[:, :, dst].set(k[:, :, src])
-                v = v.at[:, :, dst].set(v[:, :, src])
-                return k, v
+                return kv.at[:, :, dst].set(kv[:, :, src])  # block axis = 2
 
             self._cow_fn = audited_jit("engine_v2.cow", cow,
                                        donate_argnums=(0,))
@@ -468,16 +466,16 @@ class InferenceEngineV2:
     # ------------------------------------------------------------------
     def _get_tier_gather(self):
         """Single fixed-shape block-gather program: pull pool block ``src``
-        out as one (2, L, kvh, BS, hd) array (K stacked on V). ``src`` is a
+        out as one (2, L, kvh, BS, hd) array (K stacked on V: the payload
+        format, whatever the pool's own row layout is). ``src`` is a
         traced scalar — ONE compiled trace serves every demotion and
         swap-out, so tier traffic adds data movement, not programs. No
         donation: the pool stays live (the gather is dispatched alongside
         decode steps that keep consuming it)."""
         if self._tier_gather_fn is None:
 
-            def gather(kv, src):
-                k, v = kv  # (L, kvh, NB, BS, hd) each; block axis = 2
-                return jnp.stack((k[:, :, src], v[:, :, src]))
+            def gather(kv, src):  # a closure: this engine's own trace cache
+                return paged_attention.get_block(kv, src)
 
             self._tier_gather_fn = audited_jit("engine_v2.tier_gather",
                                                gather)
@@ -492,12 +490,9 @@ class InferenceEngineV2:
         if self._tier_scatter_fn is None:
 
             def scatter(kv, batch, row, dst):
-                k, v = kv
                 blk = jax.lax.dynamic_index_in_dim(batch, row, 0,
                                                    keepdims=False)
-                k = k.at[:, :, dst].set(blk[0])
-                v = v.at[:, :, dst].set(blk[1])
-                return k, v
+                return paged_attention.set_block(kv, dst, blk)
 
             self._tier_scatter_fn = audited_jit("engine_v2.tier_scatter",
                                                 scatter, donate_argnums=(0,))
@@ -509,9 +504,8 @@ class InferenceEngineV2:
         scatter program's batch shape constant (no retrace) and bounds
         staging memory; larger batches go in chunks. The buffer itself lives
         in the TransferEngine's bounded pool (docs/TRANSFER.md)."""
-        k = self.kv[0]
-        return ((self.block_mgr.max_blocks_per_seq, 2)
-                + tuple(k.shape[:2]) + tuple(k.shape[3:]))
+        return ((self.block_mgr.max_blocks_per_seq,)
+                + paged_attention.payload_shape(self.kv))
 
     def _bind_nvme_tier(self) -> None:
         """Wire the allocator's NVMe spill hooks to the TransferEngine's
@@ -571,7 +565,7 @@ class InferenceEngineV2:
         if not payloads:
             return
         te = self.transfer
-        buf = te.acquire_staging(self._tier_buf_shape(), self.kv[0].dtype)
+        buf = te.acquire_staging(self._tier_buf_shape(), self.kv.dtype)
         try:
             cap = buf.shape[0]
             scatter = self._get_tier_scatter()
@@ -770,7 +764,7 @@ class InferenceEngineV2:
             "nbytes": nbytes,
             "crc32": blocks_crc32(blocks),
             "block_shape": tuple(self._tier_buf_shape()[1:]),
-            "dtype": str(np.dtype(self.kv[0].dtype)),
+            "dtype": str(np.dtype(self.kv.dtype)),
         }
 
     def import_swap(self, uid: int, payload) -> int:
@@ -806,7 +800,7 @@ class InferenceEngineV2:
         blocks = payload["blocks"]
         seen = int(payload["seen_tokens"])
         shape = tuple(self._tier_buf_shape()[1:])
-        dtype = np.dtype(self.kv[0].dtype)
+        dtype = np.dtype(self.kv.dtype)
         need = self.block_mgr.blocks_needed(seen)
         if len(blocks) != need or len(blocks) > self.block_mgr.max_blocks_per_seq:
             raise EngineUsageError(

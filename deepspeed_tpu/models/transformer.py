@@ -507,13 +507,16 @@ class TransformerLM:
         """One transformer block on (B, S, H). Returns (y, new_kv) where new_kv is
         the updated (k, v) when decoding with a cache.
 
-        ``paged``: (kp, vp, tables) for a blocked KV pool — kp/vp kv-head-major
-        (kvh, NB, BS, hd), tables (B, MAXB) of pool block ids (0 = reserved
-        trash block). Tokens write at their ``positions`` via block-table
-        scatter; attention runs against the table-gathered logical cache with
-        a per-sequence position mask (covers chunked prefill AND decode —
-        reference ``inference/v2/ragged_ops/blocked_flash`` + ``kv_cache.py
-        BlockedKVCache``)."""
+        ``paged``: (pool, layer, tables) for the blocked KV pool — the WHOLE
+        stacked pool (``ops/transformer/paged_attention.py`` owns its layout),
+        this block's layer index (traced), tables (B, MAXB) of pool block ids
+        (0 = reserved trash block); new_kv is then the updated pool. Tokens
+        write at their ``positions`` as whole rows of the pool, in place;
+        attention reads the pool where it lies, through the Pallas kernel
+        for one-token rows or the table-gathered logical cache with a
+        per-sequence position mask otherwise (covers chunked prefill AND
+        decode — reference ``inference/v2/ragged_ops/blocked_flash`` +
+        ``kv_cache.py BlockedKVCache``)."""
         cfg = self.config
         nh, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         B, S, H = x.shape
@@ -563,17 +566,13 @@ class TransformerLM:
 
             new_kv = None
             if paged is not None:
-                kp, vp, tables = paged  # pool: (kvh, NB, BS, hd) kv-head-major
-                BS = kp.shape[2]
+                from ..ops.transformer import paged_attention as pa
+
+                pool, layer, tables = paged
+                BS = pool.shape[3]
                 with jax.named_scope("kv_write"):
-                    # scatter this segment's k/v into the pool at its block/offset
-                    blk_idx = jnp.take_along_axis(tables, positions // BS, axis=1)  # (B,S)
-                    off = positions % BS
-                    kp = kp.at[:, blk_idx, off].set(
-                        kk.astype(kp.dtype).transpose(2, 0, 1, 3))
-                    vp = vp.at[:, blk_idx, off].set(
-                        v.astype(vp.dtype).transpose(2, 0, 1, 3))
-                new_kv = (kp, vp)
+                    pool = pa.write_rows(pool, layer, tables, positions, kk, v)
+                new_kv = pool
                 with jax.named_scope("paged_attn"):
                     from ..ops.transformer.attention import get_default_impl
 
@@ -589,22 +588,20 @@ class TransformerLM:
                         (cfg.pos_embedding == "alibi", "ALiBi bias"),
                         (bool(cfg.logit_softcap), "logit softcap"),
                         (hd not in (64, 128, 256), f"head_dim {hd}"),
-                        (kp.shape[2] % 8 != 0, f"block size {kp.shape[2]} % 8 != 0"),
+                        (BS % 8 != 0, f"block size {BS} % 8 != 0"),
                     ) if bad]
                     use_kernel = want_kernel and not gaps
                     if want_kernel and gaps:
                         logger.warning("paged decode takes the XLA gather path, not "
                                        f"the Pallas kernel: {', '.join(gaps)}")
                     if use_kernel:
-                        # Pallas paged decode: pool blocks stream via the block table's
-                        # index map — no materialized gather copy (paged_attention.py)
-                        from ..ops.transformer.paged_attention import paged_decode_attention
-
-                        attn_out = paged_decode_attention(
-                            q[:, 0], kp, vp, tables, positions[:, 0] + 1)[:, None]
+                        # Pallas paged decode: the kernel streams this layer's
+                        # blocks out of the stacked pool by layer index and block
+                        # table — no slice, no gathered copy (paged_attention.py)
+                        attn_out = pa.paged_decode(
+                            q[:, 0], pool, layer, tables, positions[:, 0] + 1)[:, None]
                     else:
-                        gk = jnp.moveaxis(kp[:, tables], 0, 3).reshape(B, -1, kvh, hd)
-                        gv = jnp.moveaxis(vp[:, tables], 0, 3).reshape(B, -1, kvh, hd)
+                        gk, gv = pa.gather_context(pool, layer, tables)
                         T = gk.shape[1]
                         kpos = jnp.arange(T)
                         mask = kpos[None, None, :] <= positions[:, :, None]  # (B,S,T)
@@ -1075,12 +1072,15 @@ class TransformerLM:
     # paged (blocked) KV cache — reference inference/v2 BlockedKVCache path
     # ------------------------------------------------------------------
     def init_kv_pool(self, num_blocks: int, block_size: int, dtype=jnp.bfloat16):
-        """Blocked KV pool (L, kvh, NB, BS, hd) — kv-head-major so the Pallas
-        paged-decode kernel can stream (BS, hd) tiles; block 0 is the reserved
-        trash block that masked/padded writes land in."""
+        """The blocked KV pool: ONE array (L, kvh, NB, BS, 2*hd) whose rows are
+        ``[k_t | v_t]`` (layout and access: ``ops/transformer/
+        paged_attention.py``); block 0 is the reserved trash block that
+        masked/padded writes land in."""
+        from ..ops.transformer.paged_attention import init_pool
+
         cfg = self.config
-        shape = (cfg.num_layers, cfg.kv_heads, num_blocks, block_size, cfg.head_dim)
-        return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+        return init_pool(cfg.num_layers, cfg.kv_heads, num_blocks, block_size,
+                         cfg.head_dim, dtype)
 
     def forward_paged(self, params, input_ids, kv_pool, tables, starts,
                       n_valid=None, logit_rows=None):
@@ -1096,23 +1096,24 @@ class TransformerLM:
         B, S = input_ids.shape
         positions = starts[:, None] + jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32), (B, S))
-        dtype = kv_pool[0].dtype
+        dtype = kv_pool.dtype
         with jax.named_scope("embed"):
             x = self._embed(params, input_ids, positions, dtype)
 
-        def body(h, layer):
-            blk, kp_l, vp_l = layer
-            y, new_kv, _ = self._block(
+        def body(carry, blk):
+            h, pool, layer = carry
+            y, pool, _ = self._block(
                 h, blk, positions=positions, rng=None, train=False,
-                paged=(kp_l, vp_l, tables),
+                paged=(pool, layer, tables),
             )
-            return y, new_kv
+            return (y, pool, layer + 1), None
 
-        # the scan slices each layer's pool out of the stacked pool and writes
-        # it back: "kv_carry" is what that plumbing alone costs on the device
+        # the pool rides the layer scan as a carry, one donated buffer from
+        # entry to exit, written and read where it lies: "kv_carry" is what
+        # the scan's own plumbing still costs on the device
         with jax.named_scope("kv_carry"):
-            x, (nkp, nvp) = jax.lax.scan(
-                body, x, (params["blocks"], kv_pool[0], kv_pool[1]))
+            (x, kv_pool, _), _ = jax.lax.scan(
+                body, (x, kv_pool, jnp.int32(0)), params["blocks"])
         # project only each sequence's last VALID position — skips the
         # (S, V) vocab matmul over the rest of the chunk
         if n_valid is None:
@@ -1124,7 +1125,7 @@ class TransformerLM:
             x_last = x_last[logit_rows]  # (R,H)
         with jax.named_scope("lm_head_loss"):
             lg = self._head(params, x_last[:, None])[:, 0]
-        return lg, (nkp, nvp)
+        return lg, kv_pool
 
     def decode_paged_multi(self, params, kv_pool, toks, tables, starts, k: int,
                            sampling=None):

@@ -1,17 +1,24 @@
-"""Pallas paged-attention decode kernel (blocked KV pool + block tables).
+"""The paged KV pool: its one physical layout, the programs' access to it, and
+the Pallas paged-attention decode kernel (blocked KV pool + block tables).
 
 Reference: ``deepspeed/inference/v2/kernels/ragged_ops/blocked_flash`` — flash
 attention over paged KV blocks addressed through per-sequence block tables.
 
-TPU design: the XLA fallback in ``TransformerLM.forward_paged`` materializes
-the table-gathered logical cache (read pool + write copy) every decode step;
-this kernel instead streams ONE pool block per grid step straight from HBM,
-with the block id resolved in the BlockSpec index map from the
-scalar-prefetched table — the canonical TPU paged-attention pattern. Online
-softmax state lives in VMEM scratch across the (sequential) block-step axis
-of the grid.
+The pool is ONE array ``(L, kvh, NB, BS, 2*hd)``: layer, kv head, pool block,
+token of the block, and a row ``[k_t | v_t]`` of that token's key beside its
+value. At head size 64 a row is exactly the TPU's 128 lanes, at 128 it is two
+tiles: no head size needs a re-laid-out view between the write and the read.
+Every program touches it through the functions here: the model writes whole
+rows at computed row numbers of the pool viewed flat (:func:`write_rows`, in
+place on a donated buffer) and reads either through the kernel
+(:func:`paged_decode`, which streams ``pool[layer, head, block]`` tiles from
+HBM by the scalar-prefetched layer and block table) or through one XLA gather
+with the layer among its indices (:func:`gather_context`); the engine's block
+programs (COW, tier demote/promote, swap) use :func:`get_block` /
+:func:`set_block`. No program slices a layer out of the pool.
 
-Decode only (one query token per sequence); prefill keeps the XLA path.
+The kernel is decode only (one query token per row; a prefill chunk is rows of
+one token each); segments longer than one token keep the gather path.
 """
 
 import functools
@@ -30,6 +37,71 @@ def _interpret() -> bool:
     return pallas_interpret()
 
 
+# ----------------------------------------------------------------------
+# the pool's layout
+# ----------------------------------------------------------------------
+def init_pool(num_layers, kv_heads, num_blocks, block_size, head_dim,
+              dtype=jnp.bfloat16):
+    """The zeroed pool, ``(L, kvh, NB, BS, 2*hd)``; block 0 is the reserved
+    trash block that masked/padded writes land in."""
+    return jnp.zeros((num_layers, kv_heads, num_blocks, block_size,
+                      2 * head_dim), dtype)
+
+
+def write_rows(pool, layer, tables, positions, k, v):
+    """Write the (B, S) new tokens' keys and values of layer ``layer`` into
+    the pool: k, v (B, S, kvh, hd) land as whole ``[k | v]`` rows at
+    ``((layer*kvh + head)*NB + block)*BS + offset`` of the pool viewed flat
+    as (rows, 2*hd), which is a bitcast of the row-major pool, so the scatter
+    updates the carried buffer in place. Padding rows all land in trash
+    block 0, so the row numbers are not unique."""
+    L, kvh, NB, BS, row = pool.shape
+    if L * kvh * NB * BS >= 2 ** 31:
+        raise ValueError(f"pool {pool.shape} has more rows than int32 "
+                         "row numbers reach")
+    blk = jnp.take_along_axis(tables, positions // BS, axis=1)  # (B, S)
+    head0 = (layer * kvh + jnp.arange(kvh, dtype=jnp.int32)) * NB
+    rows = ((head0[None, None, :] + blk[:, :, None]) * BS
+            + (positions % BS)[:, :, None])  # (B, S, kvh)
+    kv = jnp.concatenate((k, v), axis=-1).astype(pool.dtype)
+    return pool.reshape(-1, row).at[rows].set(kv).reshape(pool.shape)
+
+
+def gather_context(pool, layer, tables):
+    """Layer ``layer``'s logical cache of each row's table, gathered by XLA:
+    (k, v) of shape (B, MAXB*BS, kvh, hd). One gather with the layer among
+    its indices; the whole-context copy it makes is what the kernel avoids."""
+    hd = pool.shape[-1] // 2
+    B = tables.shape[0]
+    ctx = pool[layer, :, tables]  # (B, MAXB, kvh, BS, 2*hd)
+    ctx = jnp.swapaxes(ctx, 2, 3).reshape(B, -1, pool.shape[1], 2 * hd)
+    return ctx[..., :hd], ctx[..., hd:]
+
+
+def get_block(pool, block):
+    """Pool block ``block`` of every layer as the host-side payload
+    (2, L, kvh, BS, hd), K stacked on V: the format tiers, swaps, CRCs and
+    cross-engine hand-off keep whatever the pool's layout is."""
+    hd = pool.shape[-1] // 2
+    blk = pool[:, :, block]  # (L, kvh, BS, 2*hd)
+    return jnp.stack((blk[..., :hd], blk[..., hd:]))
+
+
+def set_block(pool, block, payload):
+    """Write a :func:`get_block` payload into pool block ``block``."""
+    return pool.at[:, :, block].set(
+        jnp.concatenate((payload[0], payload[1]), axis=-1).astype(pool.dtype))
+
+
+def payload_shape(pool):
+    """Shape of one block's :func:`get_block` payload."""
+    L, kvh, _, BS, row = pool.shape
+    return (2, L, kvh, BS, row // 2)
+
+
+# ----------------------------------------------------------------------
+# the kernels
+# ----------------------------------------------------------------------
 def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, block_size, scale, max_blocks):
     b, j = pl.program_id(0), pl.program_id(2)
@@ -68,9 +140,8 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0, :, :] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
 
 
-def _decode_kernel_stream(tables_ref, lens_ref, q_ref, kpool_ref, vpool_ref,
-                          o_ref, kbuf, vbuf, ksem, vsem, *, block_size, scale,
-                          pack):
+def _decode_kernel_stream(layer_ref, tables_ref, lens_ref, q_ref, pool_ref,
+                          o_ref, buf, sem, *, block_size, scale):
     """Grid (B, kvh): ONE cell per (sequence, kv head); the kernel itself
     streams this sequence's ACTIVE pool blocks from HBM with double-buffered
     DMA (prefetch j+1 while computing j). Versus the grid-per-block variant
@@ -78,28 +149,27 @@ def _decode_kernel_stream(tables_ref, lens_ref, q_ref, kpool_ref, vpool_ref,
     sequence's real length — the serving regime has mostly-short sequences
     against a long max-context table.
 
-    ``pack``: Mosaic requires HBM DMA slices 128-lane-aligned; for hd=64 the
-    pool arrives viewed as (kvh, NB, BS/2, 128) — each buffer row holds two
-    interleaved tokens ([t_{2i} | t_{2i+1}]), and the kernel processes the
-    even/odd half-lanes as two sub-tiles of the same block."""
+    The pool is the whole stacked pool as it lies in HBM (see
+    :func:`init_pool`): one DMA fetches block ``pool[layer, h, tables[b, j]]``,
+    a (BS, 2*hd) tile whose rows are ``[k_t | v_t]``; K and V are its lane
+    halves. The row is a multiple of 128 lanes for every supported head size,
+    which is what Mosaic asks of an HBM DMA slice."""
     b = pl.program_id(0)
     h = pl.program_id(1)
+    layer = layer_ref[0]
     seq_len = lens_ref[b]
     nblk = (seq_len + block_size - 1) // block_size
     g = q_ref.shape[2]
     hd = q_ref.shape[3]
     q = q_ref[0, 0, :, :].astype(jnp.float32) * scale  # (g, hd)
 
-    def start(j, slot):
-        blk = tables_ref[b, j]
-        pltpu.make_async_copy(kpool_ref.at[h, blk], kbuf.at[slot],
-                              ksem.at[slot]).start()
-        pltpu.make_async_copy(vpool_ref.at[h, blk], vbuf.at[slot],
-                              vsem.at[slot]).start()
+    def copy(j, slot):
+        return pltpu.make_async_copy(pool_ref.at[layer, h, tables_ref[b, j]],
+                                     buf.at[slot], sem.at[slot])
 
     @pl.when(nblk > 0)
     def _prologue():
-        start(0, 0)
+        copy(0, 0).start()
 
     def body(j, carry):
         m, l, acc = carry
@@ -107,42 +177,24 @@ def _decode_kernel_stream(tables_ref, lens_ref, q_ref, kpool_ref, vpool_ref,
 
         @pl.when(j + 1 < nblk)
         def _prefetch():
-            start(j + 1, 1 - slot)
+            copy(j + 1, 1 - slot).start()
 
-        blk = tables_ref[b, j]
-        pltpu.make_async_copy(kpool_ref.at[h, blk], kbuf.at[slot],
-                              ksem.at[slot]).wait()
-        pltpu.make_async_copy(vpool_ref.at[h, blk], vbuf.at[slot],
-                              vsem.at[slot]).wait()
-        kb = kbuf[slot].astype(jnp.float32)  # (BS, hd) or packed (BS/2, 2hd)
-        vb = vbuf[slot].astype(jnp.float32)
-        iota1 = jax.lax.broadcasted_iota
-
-        def online_update(carry, k, v, kpos):
-            m, l, acc = carry
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            s = jnp.where(kpos < seq_len, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[:, None])
-            alpha = jnp.exp(m - m_new)
-            l_new = alpha * l + jnp.sum(p, axis=-1)
-            acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return m_new, l_new, acc_new
-
-        base = j * block_size
-        if pack:
-            # two interleaved sub-tiles of the block = two online updates
-            # (online softmax is associative over any partition of the keys)
-            half = iota1(jnp.int32, (q.shape[0], kb.shape[0]), 1)
-            carry = online_update((m, l, acc), kb[:, :hd], vb[:, :hd],
-                                  base + 2 * half)
-            return online_update(carry, kb[:, hd:], vb[:, hd:],
-                                 base + 2 * half + 1)
-        kpos = base + iota1(jnp.int32, (q.shape[0], kb.shape[0]), 1)
-        return online_update((m, l, acc), kb, vb, kpos)
+        copy(j, slot).wait()
+        kv = buf[slot].astype(jnp.float32)  # (BS, 2*hd)
+        k, v = kv[:, :hd], kv[:, hd:]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        kpos = j * block_size + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < seq_len, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[:, None])
+        alpha = jnp.exp(m - m_new)
+        l_new = alpha * l + jnp.sum(p, axis=-1)
+        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
 
     m0 = jnp.full((g,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((g,), jnp.float32)
@@ -152,70 +204,67 @@ def _decode_kernel_stream(tables_ref, lens_ref, q_ref, kpool_ref, vpool_ref,
     o_ref[0, 0, :, :] = (acc / l_safe[:, None]).astype(o_ref.dtype)
 
 
-def _paged_decode_stream(q, k_pool, v_pool, tables, lens, *, scale):
+def paged_decode(q, pool, layer, tables, lens, *, scale=None):
+    """One-token decode attention of layer ``layer`` against the stacked pool,
+    read where it lies.
+
+    q: (B, nh, hd); pool: (L, kvh, NB, BS, 2*hd) as :func:`init_pool` lays it
+    out, left in HBM whole; layer: int32 scalar (traced or not), a
+    scalar-prefetch operand the kernel's DMAs index the pool by, so no layer
+    is ever sliced out; tables: (B, MAXB) int32 pool block ids (0-padded);
+    lens: (B,) int32 valid token counts (position + 1). Returns (B, nh, hd)
+    in q's dtype."""
     B, nh, hd = q.shape
-    kvh, NB, BS, _ = k_pool.shape
+    _, kvh, _, BS, _ = pool.shape
     g = nh // kvh
-    qg = q.reshape(B, kvh, g, hd)
-    pack = hd < 128
-    if pack:
-        if BS % 2:
-            raise NotImplementedError("packed stream kernel needs even block_size")
-        # free view: two consecutive tokens side by side → 128-lane DMA slices
-        k_pool = k_pool.reshape(kvh, NB, BS // 2, 2 * hd)
-        v_pool = v_pool.reshape(kvh, NB, BS // 2, 2 * hd)
-    buf_shape = (2,) + k_pool.shape[2:]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # tables, lens
+        num_scalar_prefetch=3,  # layer, tables, lens
         grid=(B, kvh),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, tables, lens: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # k pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),  # v pool stays in HBM
+            pl.BlockSpec((1, 1, g, hd), lambda b, h, *_: (b, h, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # the pool stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda b, h, tables, lens: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, *_: (b, h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM(buf_shape, k_pool.dtype),   # k double buffer
-            pltpu.VMEM(buf_shape, v_pool.dtype),   # v double buffer
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((2,) + pool.shape[3:], pool.dtype),  # double buffer
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel_stream, block_size=BS, scale=scale,
-                          pack=pack),
+        functools.partial(_decode_kernel_stream, block_size=BS,
+                          scale=scale if scale is not None else hd ** -0.5),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kvh, g, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
         name="paged_decode",
-    )(tables, lens, qg, k_pool, v_pool)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables, lens,
+      q.reshape(B, kvh, g, hd), pool)
     return out.reshape(B, nh, hd)
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lens, *, scale=None,
                            stream: bool = True):
-    """One-token decode attention against a blocked KV pool.
+    """One-token decode attention against ONE layer's blocked K and V pools:
+    the kernel's numerical reference entry, not the model's (the model calls
+    :func:`paged_decode` on its stacked pool).
 
     q: (B, nh, hd) — this step's query per sequence.
-    k_pool/v_pool: (kvh, NB, BS, hd) — kv-head-major so a pool block is a
-    Mosaic-tileable (BS, hd) tile; tables: (B, MAXB) int32 pool block ids
+    k_pool/v_pool: (kvh, NB, BS, hd); tables: (B, MAXB) int32 pool block ids
     (0-padded); lens: (B,) int32 valid token counts (position + 1).
     Returns (B, nh, hd) in q's dtype.
 
-    ``stream=True`` (default) uses the (B, kvh)-grid kernel with an in-kernel
-    double-buffered DMA loop over only the ACTIVE blocks; ``stream=False``
-    keeps the (B, kvh, MAXB)-grid variant whose block fetch rides the
-    BlockSpec index map (one grid cell per table slot — simpler, but cell
-    count scales with max context rather than actual lengths).
+    ``stream=True`` (default) lays K and V side by side as a one-layer stacked
+    pool (a copy, which is why the model does not come this way) and runs the
+    same kernel as :func:`paged_decode`; ``stream=False`` keeps the
+    (B, kvh, MAXB)-grid variant whose block fetch rides the BlockSpec index
+    map (one grid cell per table slot — simpler, but cell count scales with
+    max context rather than actual lengths).
     """
     if stream:
-        B, nh, hd = q.shape
-        scale_v = scale if scale is not None else hd ** -0.5
-        return _paged_decode_stream(q, k_pool, v_pool, tables, lens,
-                                    scale=scale_v)
+        pool = jnp.concatenate((k_pool, v_pool), axis=-1)[None]
+        return paged_decode(q, pool, 0, tables, lens, scale=scale)
     B, nh, hd = q.shape
     kvh, NB, BS, _ = k_pool.shape
     MAXB = tables.shape[1]

@@ -1934,23 +1934,27 @@ class DeepSpeedEngine:
         ``force`` fires the cadence actions regardless of the modulo — used by
         the multi-step path whose counters advance in K-jumps."""
         every = self.config.steps_per_print
-        # the offload/sharded step paths skip the norm when clipping is off —
-        # telemetry must not crash on the absent value
-        gn = float("nan") if gnorm is None else float(gnorm)
+
+        def gn():
+            # float() is a device sync: read the norm only at the print
+            # cadence, so that dispatching a step never waits for the step.
+            # The offload/sharded step paths skip the norm when clipping is
+            # off — telemetry must not crash on the absent value
+            return float("nan") if gnorm is None else float(gnorm)
+
         if every and (force or self.global_steps % every == 0):
             log_dist(
                 f"step={self.global_steps} lr={self.get_lr()} "
-                f"grad_norm={gn:.4f} skipped={self.skipped_steps}",
+                f"grad_norm={gn():.4f} skipped={self.skipped_steps}",
                 ranks=[0],
             )
         if self.monitor.enabled and jax.process_index() == 0:
-            # float() is a device sync — pay it only at the print cadence
             if force or self.global_steps % max(1, every or 1) == 0:
                 events = [
                     ("Train/Samples/lr", float(self.get_lr()[0]), self.global_samples),
                     ("Train/Samples/loss_scale", float(self.scaler_state.cur_scale),
                      self.global_samples),
-                    ("Train/Samples/grad_norm", gn, self.global_samples),
+                    ("Train/Samples/grad_norm", gn(), self.global_samples),
                 ]
                 # train/zero/* counter group (docs/ZERO.md "Observability")
                 events += [(f"Train/ZeRO/{k}", float(v), self.global_samples)

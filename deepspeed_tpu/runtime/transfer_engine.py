@@ -422,6 +422,10 @@ class TransferEngine:
         self.submitted_bytes["h2d"] += nb
         self.submitted_transfers["h2d"] += 1
         t0 = time.perf_counter()
+        if isinstance(host_arr, np.ndarray) and jax.default_backend() == "cpu":
+            # the CPU client hands back a VIEW of an aligned numpy buffer
+            # (whatever ``may_alias`` says), and callers refill theirs
+            host_arr = host_arr.copy()
         t._result = jax.device_put(host_arr, sharding) if sharding is not None \
             else jax.device_put(host_arr)
         self._note("h2d", nb, time.perf_counter() - t0)

@@ -285,8 +285,9 @@ def classify(op_name: str) -> str:
     forward recomputed under the backward); ``bwd``; ``fwd`` (the
     differentiated forward); the serving scopes ``kv_write``, ``paged_attn``,
     ``sample``; ``model`` (a model scope in a program without gradients);
-    ``kv_carry`` (the paged program's layer scan itself: slicing the stacked
-    pool and writing it back); else ``unscoped``."""
+    ``kv_carry`` (the paged program's layer scan itself, which carries the
+    stacked pool: whatever it does to the pool besides the layers' own
+    in-place writes); else ``unscoped``."""
     parts = re.split(r"[/()]", op_name)
     if "optimizer" in parts:
         return "optimizer"

@@ -9,12 +9,19 @@ model uses (``attention()``, the paged branch of ``forward_paged``), with the
 ``jax.default_backend()`` gate steered inside the test. Shapes are the ones
 ``chip_smoke.py`` runs: flash attention at (8, 1024, 16, 64) bf16; paged
 decode at its server's pool (129 blocks of 64) and block tables (16 wide), at
-both row counts of the serving program (8 and 256).
+both row counts of the serving program (8 and 256). The serving program
+itself is compiled at the benchmark cell's engine geometry (832 blocks of 64,
+rows 64 and 256) for heads of 64 and of 128, and searched for any operation
+that moves a layer's pool or more: the pool is written in place and read
+where it lies.
 
 All of it lives in this one file, and the topology is described inside a
 fixture: only the worker that runs this file loads libtpu, and every worker
 collects the same tests.
 """
+
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +31,7 @@ from jax.sharding import SingleDeviceSharding
 B, S, NH, HD = 8, 1024, 16, 64          # the trainer's attention shape
 NB, BS, MAXB = 129, 64, 16              # the server's pool and block tables
 ROWS = (8, 256)                         # decode round, mixed prefill+decode
+CELL_NB, CELL_SEQS = 832, 64            # the serve-chat cell's pool and rows
 
 
 @pytest.fixture(scope="module")
@@ -135,6 +143,113 @@ def test_paged_branch_of_the_model_compiles(one_chip, no_compile_cache, as_tpu,
         aval(one_chip, (rows,), jnp.int32))
     assert "tpu_custom_call" in text, \
         "paged decode took the XLA gather path at the smoke's own shapes"
+
+
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = ([a-z0-9]+)\[([0-9,]*)\]\S* ([\w\-]+)\(")
+#: what moves an array: a pool-sized result of one of these is a pool copy
+_MOVERS = ("copy", "dynamic-slice", "dynamic-update-slice", "transpose",
+           "concatenate", "pad", "slice", "gather")
+
+
+def pool_sized_movers(text, elems):
+    """Instructions of optimized HLO (fused computations included) that move
+    an array of at least ``elems`` elements."""
+    found = []
+    for line in text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if not m or m.group(3) not in _MOVERS:
+            continue
+        if math.prod(int(d) for d in m.group(2).split(",") if d) >= elems:
+            found.append(line.strip()[:160])
+    return found
+
+
+def serving_model(head_dim, layers):
+    from deepspeed_tpu.models import (TransformerConfig, TransformerLM,
+                                      gpt2_config)
+
+    if head_dim == 64:      # GPT-2 350M, the cell's own configuration
+        return TransformerLM(gpt2_config("350m", num_layers=layers))
+    return TransformerLM(TransformerConfig(   # Pythia-1.4B's widths
+        vocab_size=50304, hidden_size=2048, num_layers=layers, num_heads=16,
+        intermediate_size=8192, max_seq_len=2048, pos_embedding="rope",
+        rotary_dim=32, norm="layernorm", activation="gelu_exact",
+        parallel_block=True, tie_embeddings=False, qkv_bias=True))
+
+
+def serving_avals(one_chip, model, rows):
+    """(params, pool, tables, starts) of the cell's engine, as shapes."""
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(
+            lambda a: aval(one_chip, a.shape, dtype or a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)),
+                     jnp.bfloat16)
+    pool = on_chip(jax.eval_shape(
+        lambda: model.init_kv_pool(CELL_NB, BS, dtype=jnp.bfloat16)))
+    return (params, pool, aval(one_chip, (rows, MAXB), jnp.int32),
+            aval(one_chip, (rows,), jnp.int32))
+
+
+def assert_moves_no_pool(compiled, pool, layers):
+    """No ``copy``, slice, update-slice or transpose whose result holds a
+    layer's pool or more, no pool-sized temporary, the pool aliased from
+    input to output, the kernel in the program."""
+    text, pools = compiled.as_text(), jax.tree.leaves(pool)
+    layer_elems = min(a.size for a in pools) // layers
+    assert pool_sized_movers(text, layer_elems) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        layer_elems * pools[0].dtype.itemsize
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_", text)
+    assert aliases and aliases.group(1).count("alias)") == len(pools), \
+        "the donated pool is no longer updated in place"
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("head_dim,layers,rows", [
+    (64, 4, CELL_SEQS), (64, 2, 256), (128, 2, CELL_SEQS), (128, 3, 256)])
+def test_serving_program_moves_no_pool(one_chip, no_compile_cache, as_tpu,
+                                       head_dim, layers, rows):
+    """The ragged program (``forward_paged`` over one-token rows, only the
+    sequences' rows projected, pool donated) at the cell's pool, for both
+    row counts and for heads of 64 and of 128."""
+    model = serving_model(head_dim, layers)
+    assert model.config.head_dim == head_dim
+    params, pool, tables, starts = serving_avals(one_chip, model, rows)
+
+    def ragged(params, pool, ids, tables, starts, logit_rows):
+        return model.forward_paged(params, ids, pool, tables, starts,
+                                   logit_rows=logit_rows)
+
+    compiled = jax.jit(ragged, donate_argnums=(1,)).lower(
+        params, pool, aval(one_chip, (rows, 1), jnp.int32), tables, starts,
+        aval(one_chip, (CELL_SEQS,), jnp.int32)).compile()
+    assert_moves_no_pool(compiled, pool, layers)
+
+
+@pytest.mark.parametrize("program", ["fused", "verify"])
+def test_multi_step_programs_move_no_pool(one_chip, no_compile_cache, as_tpu,
+                                          program):
+    """The K-step fused decode (rounds scanned over the layer scan, the pool
+    carried through both) and the verify program (K one-token rows a
+    sequence) inherit the property."""
+    layers, K = 2, 4
+    model = serving_model(64, layers)
+    params, pool, tables, starts = serving_avals(one_chip, model, CELL_SEQS)
+    if program == "fused":
+        def fn(params, pool, toks, tables, starts):
+            return model.decode_paged_multi(params, pool, toks, tables,
+                                            starts, K)
+        toks = aval(one_chip, (CELL_SEQS,), jnp.int32)
+    else:
+        def fn(params, pool, segs, tables, starts):
+            return model.verify_paged_multi(params, pool, segs, tables,
+                                            starts)
+        toks = aval(one_chip, (CELL_SEQS, K), jnp.int32)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, toks, tables, starts).compile()
+    assert_moves_no_pool(compiled, pool, layers)
 
 
 def test_flash_refusal_is_loud(as_tpu, monkeypatch):

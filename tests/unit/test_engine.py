@@ -474,3 +474,23 @@ def test_legacy_curriculum_truncates_tuple_batches():
     assert isinstance(out3, Batch)
     assert out3.input_ids.shape == (2, 16) and out3.labels.shape == (2, 16)
     assert out3.meta == "keep"
+
+
+@pytest.mark.parametrize("every, step, reads", [(0, 3, 0), (5, 3, 0), (5, 10, 1)])
+def test_step_telemetry_reads_the_norm_only_at_the_print_cadence(every, step, reads):
+    """``float(gnorm)`` waits for the step that computes it: off the cadence
+    the telemetry must not touch the value, or no step is ever dispatched
+    behind the running one."""
+    engine, *_ = deepspeed_tpu.initialize(
+        model=make_simple_model(HIDDEN), config=base_config(steps_per_print=every))
+    engine.global_steps = step
+
+    class Norm:
+        n = 0
+
+        def __float__(self):
+            Norm.n += 1
+            return 1.0
+
+    engine._step_telemetry(Norm())
+    assert Norm.n == reads

@@ -379,6 +379,96 @@ class TestEngineTier:
 # scheduler: swap-vs-recompute preemption, bitwise in all three modes
 # ---------------------------------------------------------------------------
 
+def _logical_kv(eng, uid):
+    """``uid``'s KV as the pool holds it, in logical order: (2, L, kvh,
+    tokens, hd) on the host, read block by block through its table."""
+    from deepspeed_tpu.ops.transformer.paged_attention import get_block
+
+    d = eng.state.seqs[uid]
+    blocks = [np.asarray(get_block(eng.kv, b)) for b in d.blocks]
+    return np.concatenate(blocks, axis=3)[:, :, :, :d.seen_tokens]
+
+
+class TestPoolLayoutRoundTrips:
+    """The pool is one (L, kvh, NB, BS, 2*hd) array of ``[k | v]`` rows;
+    every block program goes through ``paged_attention.get_block`` /
+    ``set_block`` and the (2, L, kvh, BS, hd) payload: each must hand a
+    sequence's KV back bit for bit."""
+
+    def _decoding(self, m, params, uid=1, **kw):
+        rng = np.random.default_rng(11)
+        eng = _engine(m, params, num_blocks=17, host_tier_blocks=8, **kw)
+        out = eng.put([uid], [rng.integers(0, 128, 37).tolist()])
+        for _ in range(2):
+            out = eng.decode_step({uid: int(np.argmax(out[uid]))})
+        return eng, _logical_kv(eng, uid)
+
+    def test_pool_shape_and_payload_geometry(self, setup):
+        m, params = setup
+        eng = _engine(m, params, num_blocks=17)
+        cfg = m.config
+        assert eng.kv.shape == (cfg.num_layers, cfg.kv_heads, 17, 16,
+                                2 * cfg.head_dim)
+        assert eng._tier_buf_shape() == (
+            eng.block_mgr.max_blocks_per_seq, 2, cfg.num_layers,
+            cfg.kv_heads, 16, cfg.head_dim)
+        assert eng.block_bytes == eng.kv.nbytes // 17
+
+    def test_cow_copies_one_block_bitwise(self, setup):
+        from deepspeed_tpu.ops.transformer.paged_attention import get_block
+
+        m, params = setup
+        eng, _ = self._decoding(m, params)
+        src = eng.state.seqs[1].blocks[1]
+        dst = next(b for b in range(1, 17)
+                   if b not in eng.state.seqs[1].blocks)
+        before = np.asarray(eng.kv)
+        eng.kv = eng._get_cow()(eng.kv, jax.numpy.int32(src),
+                                jax.numpy.int32(dst))
+        after = np.asarray(eng.kv)
+        np.testing.assert_array_equal(after[:, :, dst], before[:, :, src])
+        keep = np.arange(17) != dst
+        np.testing.assert_array_equal(after[:, :, keep], before[:, :, keep])
+        np.testing.assert_array_equal(np.asarray(get_block(eng.kv, dst)),
+                                      np.asarray(get_block(eng.kv, src)))
+
+    def test_demote_then_promote_one_block_bitwise(self, setup):
+        from deepspeed_tpu.ops.transformer.paged_attention import get_block
+
+        m, params = setup
+        eng, _ = self._decoding(m, params)
+        src = eng.state.seqs[1].blocks[0]
+        dst = next(b for b in range(1, 17)
+                   if b not in eng.state.seqs[1].blocks)
+        payload = eng._demote_block(src)        # device -> host ticket
+        eng._scatter_blocks([payload], [dst])   # host -> device
+        np.testing.assert_array_equal(np.asarray(get_block(eng.kv, dst)),
+                                      np.asarray(get_block(eng.kv, src)))
+        host = eng.transfer.drain_before([eng._demote_block(dst)])[0]
+        assert host.shape == eng._tier_buf_shape()[1:]
+        np.testing.assert_array_equal(
+            host, np.asarray(get_block(eng.kv, src)))
+
+    def test_swap_out_in_restores_kv_bitwise(self, setup):
+        m, params = setup
+        eng, kv = self._decoding(m, params)
+        assert eng.swap_out(1) and eng.swap_in(1)
+        np.testing.assert_array_equal(_logical_kv(eng, 1), kv)
+
+    def test_export_import_restores_kv_bitwise(self, setup):
+        m, params = setup
+        src, kv = self._decoding(m, params, uid=5)
+        payload = src.export_swap(5)
+        assert payload["block_shape"] == tuple(src._tier_buf_shape()[1:])
+        assert all(b.shape == payload["block_shape"]
+                   for b in payload["blocks"])
+        dst = _engine(m, params, num_blocks=17, host_tier_blocks=8)
+        dst.put([9], [[3, 4, 5] * 7])  # the blocks it lands in are not src's
+        dst.import_swap(5, payload)
+        assert dst.swap_in(5)
+        np.testing.assert_array_equal(_logical_kv(dst, 5), kv)
+
+
 class TestSwapPreemption:
     @pytest.mark.parametrize("swap,sampled",
                              [(True, False), (None, False), (False, False),
