@@ -82,30 +82,36 @@ def serve_readings(cell, seed, devices, control, rehearsal, state):
     cfg = train.reference_config(cell, rehearsal)
     vocab, ctx = model.config.vocab_size, mix["engine"]["max_seq_len"]
     recs, _ = serve.plan(mix, seed, 45.0, vocab, ctx)    # as a run's own
-    samples, ids, rows = serve.check_samples(mix["check"], recs, seed, vocab)
-    w = train.seeded_weights(cell, model, seed, devices)
+    samples, ids, rows = serve.check_samples(mix, recs, seed, vocab)
+    # as a run does: the reference streamed, then the tree in the serving
+    # dtype; no float32 tree of the whole model at any point
+    w = train.seeded(cell, model, seed)
     want = check.serve_reference(cfg, w, ids, rows)
     dtype = jnp.dtype(cell.config["dtype"])
     out = {"seed": seed, "prompt_lens": [len(p) for p, _ in samples]}
-    if "engine" not in state:
-        state["engine"] = InferenceEngineV2(model, w, paged=True, dtype=dtype,
-                                            **mix["engine"])
-    engine = state["engine"]
     if control:
         ctl = check.serve_reference(cfg, w, ids, rows, ein=ein_fp8)
         out["control_fp8"] = {"logits_rel_err": check.logits_rel_err(ctl, want)}
-        engine.load_params(quantize_param_tree(w, num_bits=8))
-        out["control_program_int8_woq"] = {
-            "weights_mismatch_share": check.weights_mismatch_share(
-                engine.params, w, dtype),
-            "logits_rel_err": check.logits_rel_err(
-                serve.engine_logits(engine, samples), want)}
-    engine.load_params(w)
-    out["program"] = {
-        "weights_mismatch_share": check.weights_mismatch_share(
-            engine.params, w, dtype),
-        "logits_rel_err": check.logits_rel_err(
-            serve.engine_logits(engine, samples), want)}
+    engine = state.get("engine")
+    if engine is not None:
+        engine.params = None      # the last seed's tree goes before this one's comes
+    served = w.tree_as(dtype)
+    if engine is None:
+        engine = state["engine"] = InferenceEngineV2(
+            model, served, paged=True, dtype=dtype, **mix["engine"])
+
+    def readings():
+        return {"weights_mismatch_share": check.weights_mismatch_share(
+                    engine.params, w, dtype),
+                "logits_rel_err": check.logits_rel_err(
+                    serve.engine_logits(engine, samples), want)}
+
+    if control:
+        # the program's own int8 path, fed the tree the bf16 program is fed
+        engine.load_params(quantize_param_tree(served, num_bits=8))
+        out["control_program_int8_woq"] = readings()
+    engine.load_params(served)
+    out["program"] = readings()
     return out
 
 

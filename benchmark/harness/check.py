@@ -1,7 +1,11 @@
 """What decides ``correct``: the program against the plain reference.
 
 The reference gets the seed's float32 weights and the seed's token ids and
-nothing the program has made. Every comparison yields one number that is
+nothing the program has made. The training reference holds the whole float32
+tree (a training cell's state is 14 bytes a parameter: its float32 copy is not
+what caps the model). The serving reference and the weights comparison never
+do: they draw one layer, or one slice, at a time (``weights.Seeded``), so a
+served model may fill the chip. Every comparison yields one number that is
 printed beside its limit; the limits live in the configuration file
 (``tolerances``) with the readings they were set from.
 """
@@ -86,20 +90,32 @@ def update_sign_mismatch(before, after, ref_grads, lr, weight_decay):
     return float(measure(before, after, ref_grads))
 
 
-def serve_reference(config, weights, ids, rows, ein=ein_f32):
+def serve_reference(config, seeded, ids, rows, ein=ein_f32):
     """Logits (K, R, V) of K padded sequences ``ids`` (K, T) at the R
     positions ``rows`` (K, R) of each: the full causal forward, so padding
-    after a position cannot reach it."""
+    after a position cannot reach it. The model is walked layer by layer:
+    layer ``l`` of ``seeded`` (a ``weights.Seeded``) is drawn in float32,
+    applied by the once-compiled layer to the hidden states of all K
+    sequences (one sequence at a time), and dropped before the next is
+    drawn: the host waits for each layer, so that no queue of drawn layers
+    stands on the chip."""
     arch = architecture(config)
+    ids, rows = jnp.asarray(ids), jnp.asarray(rows)
 
-    def one(w, seq, pos):
-        return arch.logits(w, arch.hidden(w, seq, config, ein)[pos], ein)
+    def over(f):
+        return jax.jit(lambda w, *xs: jax.lax.map(lambda a: f(w, *a), xs))
 
-    def whole(w, ids, rows):
-        return jax.lax.map(lambda a: one(w, *a), (ids, rows))
-
-    return np.asarray(jax.jit(whole)(weights, jnp.asarray(ids),
-                                     jnp.asarray(rows)))
+    x = over(lambda w, seq: arch.embed(w, seq, config))(seeded.unstacked(), ids)
+    layer = over(lambda b, x: arch.layer(x, b, config, ein))
+    for group, n in arch.groups(config):
+        if n != seeded.layers(group):
+            raise ValueError(f"reference: '{group}' has {seeded.layers(group)} "
+                             f"layers of weights, the configuration {n}")
+        for l in range(n):
+            x = layer(seeded.layer(group, l), x).block_until_ready()
+    head = over(lambda w, x, pos: arch.logits(
+        w, arch.final(w, x, config)[pos], ein))
+    return np.asarray(head(seeded.unstacked(), x, rows))
 
 
 def logits_rel_err(got, want):
@@ -115,17 +131,38 @@ def rel_err(got, want):
     return abs(float(got) - float(want)) / abs(float(want))
 
 
-def weights_mismatch_share(served, weights, dtype):
+@jax.jit
+def _differ(served, drawn, l):
+    """Per leaf: does layer ``l`` of ``served`` (the whole leaf, ``l`` None)
+    differ anywhere from ``drawn``? Both arrive in the served dtype: no
+    convert in this program (``weights.cast``)."""
+    return {k: jnp.any((x if l is None else x[l]) != drawn[k])
+            for k, x in served.items()}
+
+
+def weights_mismatch_share(served, seeded, dtype):
     """Share of the served tree's leaves that are not, bit for bit, the
     seed's weights rounded to the configuration's dtype (1.0 if the trees
-    differ in structure, as a quantised tree does)."""
-    a, ta = jax.tree.flatten(served)
-    b, tb = jax.tree.flatten(weights)
-    if ta != tb:
+    differ in structure, as a quantised tree does). ``seeded`` (a
+    ``weights.Seeded``) draws each layer again, by the programs that built
+    the served tree, to compare it."""
+    if jax.tree.structure(served) != seeded.treedef:
         return 1.0
-    bad = sum(x.dtype != dtype or not bool(jnp.array_equal(x, y.astype(dtype)))
-              for x, y in zip(a, b))
-    return bad / len(a)
+    n = len(jax.tree.leaves(served))
+    bad = sum(x.dtype != dtype for x in jax.tree.leaves(served))
+    if bad:
+        return bad / n
+    flags = list(_differ({k: served[k] for k in seeded.top},
+                         seeded.unstacked(dtype), None).values())
+    for group in seeded.groups:
+        worst = None
+        for l in range(seeded.layers(group)):
+            now = _differ(served[group], seeded.layer(group, l, dtype),
+                          np.int32(l))
+            worst = now if worst is None else jax.block_until_ready(
+                jax.tree.map(jnp.logical_or, worst, now))
+        flags += list(worst.values())
+    return sum(bool(f) for f in flags) / n
 
 
 class Verdict:

@@ -10,9 +10,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import check, traffic
+from .setup import SetupClock
 from .stats import quantile
 from .trace import Tracer
-from .train import build_model, reference_config, seeded_weights
+from .train import build_model, reference_config, seeded
 
 CHECK_UID = 1 << 40          # uids of the correctness pass, clear of the scheduler's
 
@@ -39,13 +40,17 @@ def engine_logits(engine, samples):
     return np.stack(out)
 
 
-def check_samples(chk, recs, seed, vocab):
+def check_samples(mix, recs, seed, vocab):
     """(samples, padded ids, logit rows) for the correctness pass: the first
-    requests' prompts, each followed by seeded forced tokens."""
+    requests' prompts, each followed by seeded forced tokens. The ids are
+    padded to the longest prompt the mix can send, so every seed runs the
+    reference's programs at one shape and only a checkout's first run
+    compiles them."""
+    chk = mix["check"]
     rng = np.random.default_rng([int(seed), 2])
     samples = [(r.prompt, rng.integers(0, vocab, chk["forced_tokens"]).tolist())
                for r in recs[:chk["samples"]]]
-    width = -(-max(len(p) + len(f) for p, f in samples) // 128) * 128
+    width = -(-(mix["prompt_len"]["hi"] + chk["forced_tokens"]) // 128) * 128
     ids = np.zeros((len(samples), width), np.int32)
     rows = np.zeros((len(samples), chk["forced_tokens"] + 1), np.int32)
     for k, (p, f) in enumerate(samples):
@@ -182,28 +187,41 @@ def run(cell, seed, seconds, trace, devices, rehearsal=False):
     horizon = ramp + seconds
     recs, more = plan(mix, seed, seconds, vocab, ctx)
 
-    # -- correctness, before the engine fills the chip: reference logits for
-    # the first requests' prompts followed by seeded forced tokens
-    samples, ids, rows = check_samples(mix["check"], recs, seed, vocab)
-    w = seeded_weights(cell, model, seed, devices)
-    want = check.serve_reference(reference_config(cell, rehearsal), w, ids, rows)
+    # -- correctness. A serving run never holds the model's whole float32
+    # tree: beside the engine's own (served weights, pool, temporaries) there
+    # is at most one float32 layer or slice. The reference goes first, on an
+    # otherwise empty chip, walking the model layer by layer: logits for the
+    # first requests' prompts followed by seeded forced tokens
+    setup = SetupClock()
+    samples, ids, rows = check_samples(mix, recs, seed, vocab)
+    weights = seeded(cell, model, seed)
+    want = check.serve_reference(reference_config(cell, rehearsal), weights,
+                                 ids, rows)
+    setup.mark("reference")
 
+    # the engine gets the tree already in its dtype, built slice by slice
     dtype = jnp.dtype(cell.config["dtype"])
-    engine = InferenceEngineV2(model, w, paged=True, dtype=dtype,
+    served = weights.tree_as(dtype)
+    setup.mark("weights", served)
+    engine = InferenceEngineV2(model, served, paged=True, dtype=dtype,
                                **mix["engine"])
+    del served
+    setup.mark("engine", engine.kv)
     verdict = check.Verdict(cell.config["tolerances"]["serve"])
     verdict.add("weights_mismatch_share",
-                check.weights_mismatch_share(engine.params, w, dtype))
-    del w
+                check.weights_mismatch_share(engine.params, weights, dtype))
     verdict.add("logits_rel_err",
                 check.logits_rel_err(engine_logits(engine, samples), want))
+    setup.mark("check")
 
     tracer = Tracer(trace)
     with ContinuousBatchScheduler(engine) as sched:
         warm_up(sched, mix, seed, vocab)
+        setup.mark("warm_up")
         sent, counters, setup_done, end = drive(
             sched, recs, ramp=ramp, seconds=seconds, drain=mix["drain_s"],
             tracer=tracer, outstanding=mix.get("outstanding"), more=more)
+    setup.marks.append(("ramp", setup_done))
     summary = tracer.summary()
 
     def done(r):
@@ -249,7 +267,7 @@ def run(cell, seed, seconds, trace, devices, rehearsal=False):
         "correct": verdict.correct and wrong == 0,
         "checks": verdict.rows,
         "attempted": len(measured), "failed": failed,
-        "setup_done": setup_done,
+        "setup_done": setup_done, "setup_marks": setup.marks,
         "end_to_end": e2e, "counters": counters,
         "spans": tracer.spans, "trace": summary, "window_s": float(seconds),
     }

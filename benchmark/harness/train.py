@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import check, weights
+from .setup import SetupClock
 from .trace import Tracer
 
 
@@ -30,16 +31,21 @@ def reference_config(cell, rehearsal):
     return cfg
 
 
+def seeded(cell, model, seed):
+    """The seed's weights of ``model``, addressed by slice."""
+    return weights.Seeded(
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)), seed,
+        cell.config["init_std"], model.config.num_layers)
+
+
 def seeded_weights(cell, model, seed, devices):
-    """The seed's float32 weights: whole on one chip, spread over all of
-    several."""
-    abstract = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
-    shardings = None
+    """The seed's whole float32 tree, for training: whole on one chip, spread
+    over all of several."""
+    drawn, shardings = seeded(cell, model, seed), None
     if len(devices) > 1:
         mesh = jax.sharding.Mesh(np.asarray(devices), ("all",))
-        shardings = weights.spread(abstract, mesh)
-    return weights.make_weights(abstract, seed, cell.config["init_std"],
-                                model.config.num_layers, shardings)
+        shardings = weights.spread(drawn.abstract, mesh)
+    return drawn.tree(shardings)
 
 
 def first_step(engine, it):
@@ -66,16 +72,20 @@ def run(cell, seed, seconds, trace, devices, rehearsal=False):
     n = len(devices)
     global_batch = job["engine"]["train_micro_batch_size_per_gpu"] * n
 
+    setup = SetupClock()
     w = seeded_weights(cell, model, seed, devices)
     pool = weights.make_ids(seed, 1, (job["distinct_batches"], global_batch, seq),
                             vocab)
+    setup.mark("weights", w, pool)
     ref_loss, ref_grads = check.train_reference(
         reference_config(cell, rehearsal), w, pool[0], devices=devices)
+    setup.mark("reference", ref_grads)
 
     topology.reset_topology()
     engine = deepspeed_tpu.initialize(
         model=model, model_parameters=w, config=job["engine"])[0]
     del w
+    setup.mark("engine", engine.params)
     sharding = jax.sharding.NamedSharding(engine.topology.mesh,
                                           batch_spec(engine.topology))
     batches = [{"input_ids": jax.device_put(pool[i], sharding)}
@@ -92,6 +102,7 @@ def run(cell, seed, seconds, trace, devices, rehearsal=False):
     # warm-up: the first step compiles (or loads) the one program of the
     # window and is the step compared with the reference
     first = first_step(engine, it)
+    setup.mark("first_step")
     float(engine.train_batch(it))
 
     opt = job["engine"]["optimizer"]["params"]
@@ -114,7 +125,7 @@ def run(cell, seed, seconds, trace, devices, rehearsal=False):
 
     tracer = Tracer(trace)
     losses, step_s, prev = [], [], None
-    setup_done = time.time()
+    setup_done = setup.mark("warm_up")
     tracer.start()
     t0 = t_last = time.perf_counter()
     while True:
@@ -145,7 +156,7 @@ def run(cell, seed, seconds, trace, devices, rehearsal=False):
         "correct": verdict.correct and bad == 0,
         "checks": verdict.rows,
         "attempted": len(losses), "failed": bad,
-        "setup_done": setup_done,
+        "setup_done": setup_done, "setup_marks": setup.marks,
         "end_to_end": {"train_tokens_per_s_per_chip": tokens / elapsed / n},
         "counters": {"tokens_per_s_per_chip": tokens / elapsed / n,
                      "seq_len": seq, "global_batch": global_batch,

@@ -9,7 +9,18 @@ precision) and as the lower-precision control (``ein_fp8``, used by
 
 Weights arrive in the layout the benchmark generates them in (see
 ``harness/weights.py``): matrices are (in, out), per-layer leaves are stacked on
-a leading layer axis under ``blocks``.
+a leading layer axis under a group such as ``blocks``.
+
+An architecture module gives its forward in parts, so that the serving
+reference can walk a model layer by layer with one layer's float32 weights on
+the chip at a time (``harness/check.py``):
+
+- ``groups(cfg)``: the stacked groups in forward order, ``[(key, layers), ...]``;
+- ``embed(w, ids, cfg)``: (S, H) float32 from the unstacked leaves ``w``;
+- ``layer(x, b, cfg, ein)``: one layer over ``b``, that layer's leaves;
+- ``final(w, x, cfg)`` and ``logits(w, h, ein)``: the closing norm and the head;
+- ``hidden(w, ids, cfg, ein)``: the same parts over a whole tree, layers under
+  ``scan_layers``, for the training reference and the tests.
 """
 
 import jax
@@ -44,13 +55,26 @@ def layer_norm(x, scale, bias, eps):
     return (x - mean) / jnp.sqrt(var + eps) * scale + bias
 
 
-def causal_attention(q, k, v, ein):
-    """q, k, v: (S, heads, head_dim) of one sequence."""
+def causal_attention(q, k, v, ein, q_block=None):
+    """q, k, v: (S, heads, head_dim) of one sequence. With ``q_block`` (a
+    divisor of S) the queries go ``q_block`` at a time, so that a long
+    sequence holds (heads, q_block, S) scores and never (heads, S, S)."""
     s = q.shape[0]
-    scores = ein("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.float32(q.shape[-1]))
-    mask = jnp.tril(jnp.ones((s, s), bool))
-    probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
-    return ein("hqk,khd->qhd", probs, v)
+
+    def rows(q, first):
+        scores = ein("qhd,khd->hqk", q, k) / jnp.sqrt(jnp.float32(q.shape[-1]))
+        mask = (first + jnp.arange(q.shape[0]))[:, None] >= jnp.arange(s)[None]
+        probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+        return ein("hqk,khd->qhd", probs, v)
+
+    if q_block is None or q_block >= s:
+        return rows(q, 0)
+    if s % q_block:
+        raise ValueError(f"q_block {q_block} does not divide the length {s}")
+    out = jax.lax.map(lambda a: rows(*a), (
+        q.reshape(s // q_block, q_block, *q.shape[1:]),
+        jnp.arange(0, s, q_block)))
+    return out.reshape(q.shape[0], *out.shape[2:])
 
 
 def scan_layers(layer, x, blocks):

@@ -33,6 +33,7 @@ def run_cell(cell, seed, seconds, trace, devices, rehearsal=False):
     """The cell's runner by the ``kind`` of its traffic file; returns the
     result object without ``device``."""
     from benchmark.harness import serve, train
+    from benchmark.harness.setup import setup_line
     from benchmark.harness.trace import breakdown
 
     runners = {"train": train.run, "serve_open": serve.run,
@@ -44,6 +45,7 @@ def run_cell(cell, seed, seconds, trace, devices, rehearsal=False):
         seconds = min(seconds, TRACE_SECONDS)
     res = runners[kind](cell, seed, seconds, trace, devices, rehearsal)
     res["end_to_end"]["setup_s"] = res["setup_done"] - _T0
+    print(setup_line(_T0, res["setup_marks"]), flush=True)
 
     out = {"correct": bool(res["correct"]), "attempted": int(res["attempted"]),
            "failed": int(res["failed"]), "metrics": {}}

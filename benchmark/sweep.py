@@ -50,12 +50,13 @@ def main(argv=None):
     from deepspeed_tpu.utils.xla_env import enable_compile_cache
 
     enable_compile_cache()
-    mix, devices = cell.traffic, jax.devices()
+    mix = cell.traffic
     model = train.build_model(cell, False)
     vocab, ctx = model.config.vocab_size, mix["engine"]["max_seq_len"]
+    dtype = jnp.dtype(cell.config["dtype"])
     engine = InferenceEngineV2(
-        model, train.seeded_weights(cell, model, args.seed, devices),
-        paged=True, dtype=jnp.dtype(cell.config["dtype"]), **mix["engine"])
+        model, train.seeded(cell, model, args.seed).tree_as(dtype),
+        paged=True, dtype=dtype, **mix["engine"])
     knee, secs = None, args.seconds
     with ContinuousBatchScheduler(engine) as sched:
         serve.warm_up(sched, mix, args.seed, vocab)

@@ -68,10 +68,11 @@ def test_reference_matches_the_programs_float32_forward():
     for name in ("gpt2-medium.train-seq1024", "pythia-1.4b.zero3-train-4chip"):
         cell = Cell(name)
         model = train.build_model(cell, True)
-        w = train.seeded_weights(cell, model, 5, jax.devices()[:1])
+        seeded = train.seeded(cell, model, 5)
+        w = seeded.tree()
         ids = np.arange(24, dtype=np.int32)[None] * 7 % 512
-        want = check.serve_reference(train.reference_config(cell, True), w, ids,
-                                     np.arange(24)[None])
+        want = check.serve_reference(train.reference_config(cell, True), seeded,
+                                     ids, np.arange(24)[None])
         with jax.default_matmul_precision("highest"):
             got = model.logits(w, jnp.asarray(ids))
         assert check.logits_rel_err(got, want) < 1e-4, name
