@@ -63,14 +63,15 @@ class DecodeDispatchHandle:
     the next ``decode_dispatch`` (the engine's scratch-reuse contract) and
     exactly once per dispatch."""
 
-    __slots__ = ("uids", "span", "_dev", "_out", "_eng")
+    __slots__ = ("uids", "span", "_dev", "_out", "_eng", "_disp")
 
-    def __init__(self, uids: List[int], dev, eng=None):
+    def __init__(self, uids: List[int], dev, eng=None, disp=None):
         self.uids = uids          # row order of the dispatched program
         self.span = 1             # cache positions each row advanced
         self._dev = dev           # device logits/token rows, unfetched
         self._out: Optional[Dict[int, int]] = None
         self._eng = eng           # owner: cleared of this handle at fetch
+        self._disp = disp         # the dispatch's span: gets the fetched counts
 
     def fetch(self) -> Dict[int, int]:
         """Block on the in-flight program and return its sampled tokens.
@@ -82,6 +83,8 @@ class DecodeDispatchHandle:
             with tracing.span("engine.fetch"):
                 lg = np.asarray(self._dev)  # dstpu-lint: ignore[DSTPU001]
             self._out = {uid: int(lg[i]) for i, uid in enumerate(self.uids)}
+            if self._eng is not None and self._disp is not None:
+                self._eng._note_moe_rows(self._disp, lg)
             self._dev = None
         if self._eng is not None:
             if self._eng._undrained_dispatch is self:
@@ -209,6 +212,7 @@ class InferenceEngineV2:
         self._bias_pool = None                        # lazy (max_seqs, V) f32
         self._bias_set_fn = None
         self._bias_zero: Optional[np.ndarray] = None
+        self._k_width, self._seg_tile, self._moe_stats = None, 1, False
         if paged:
             # paged-block pool (reference BlockedKVCache): total KV memory is
             # num_blocks*block_size tokens shared across sequences instead of
@@ -235,6 +239,24 @@ class InferenceEngineV2:
             self.block_mgr.demote_fn = self._demote_block
             self._bind_nvme_tier()
             self.kv = model.init_kv_pool(num_blocks, block_size, dtype=dtype)
+            #: where a pool row splits into its key and value parts
+            #: (``TransformerConfig.kv_row``; the block programs' payload
+            #: format follows from it)
+            self._k_width = self.cfg.kv_row[0]
+            #: rows of one chunk-segment tile of the ragged program (1: a
+            #: prefill chunk is one-token rows like any other). A model with
+            #: tiles lays a mixed step out as ``max_seqs`` one-token rows,
+            #: then the chunks' segments, each from a tile boundary
+            self._seg_tile = getattr(model, "segment_tile", 1)
+            #: the ragged program returns (rows, rows_max) of the held
+            #: experts behind its greedy tokens (``engine.dispatch`` attrs)
+            self._moe_stats = self.cfg.holds_experts
+            if self._seg_tile > 1 and (self.token_budget - max_seqs
+                                       < self._seg_tile):
+                raise ValueError(
+                    f"token_budget {self.token_budget} leaves no segment tile "
+                    f"of {self._seg_tile} rows beside the {max_seqs} "
+                    "one-token rows of a mixed step")
             #: device bytes of one block's K+V across all layers — the unit
             #: of every tier/swap byte counter and of the scheduler's
             #: swap-vs-recompute cost model
@@ -409,8 +431,13 @@ class InferenceEngineV2:
             # ids (T, 1): every row is its own length-1 "sequence" against the
             # shared pool; only the (max_seqs,) logit_rows are projected
             # through the vocab head (reference ragged_ops/logits_gather)
-            lg, pool = model.forward_paged(params, ids, pool, tables, starts,
-                                           logit_rows=logit_rows)
+            # a mixed step of a model with segment tiles: rows from max_seqs
+            # on are chunk segments (see _build_ragged_step)
+            segs = self._seg_tile > 1 and ids.shape[0] > self.max_seqs
+            lg, pool, *stats = model.forward_paged(
+                params, ids, pool, tables, starts, logit_rows=logit_rows,
+                **({"seg_from": self.max_seqs} if segs else {}),
+                **({"moe_stats": True} if self._moe_stats and greedy else {}))
             if greedy:
                 # device-side token selection: ship (R,) token ids instead of
                 # (R, V) fp32 logits — the host↔device transfer is the serving
@@ -419,8 +446,12 @@ class InferenceEngineV2:
                 # bit-identical to the legacy greedy program; a batch-level
                 # cond inside sample_or_argmax skips the sampling math when
                 # every row is greedy), so sampled traffic adds no trace.
-                return sample_or_argmax(lg + bias_pool[slots], seeds, poss,
-                                        temps, top_ks, top_ps), pool
+                toks = sample_or_argmax(lg + bias_pool[slots], seeds, poss,
+                                        temps, top_ks, top_ps)
+                # the expert counts ride behind the tokens: one array, one
+                # transfer
+                return (jnp.concatenate([toks, stats[0]]) if stats
+                        else toks), pool
             return lg, pool
 
         fn = audited_jit("engine_v2.ragged", ragged, max_traces=4,
@@ -475,7 +506,7 @@ class InferenceEngineV2:
         if self._tier_gather_fn is None:
 
             def gather(kv, src):  # a closure: this engine's own trace cache
-                return paged_attention.get_block(kv, src)
+                return paged_attention.get_block(kv, src, self._k_width)
 
             self._tier_gather_fn = audited_jit("engine_v2.tier_gather",
                                                gather)
@@ -505,7 +536,7 @@ class InferenceEngineV2:
         staging memory; larger batches go in chunks. The buffer itself lives
         in the TransferEngine's bounded pool (docs/TRANSFER.md)."""
         return ((self.block_mgr.max_blocks_per_seq,)
-                + paged_attention.payload_shape(self.kv))
+                + paged_attention.payload_shape(self.kv, self._k_width))
 
     def _bind_nvme_tier(self) -> None:
         """Wire the allocator's NVMe spill hooks to the TransferEngine's
@@ -1074,8 +1105,17 @@ class InferenceEngineV2:
             # device wait
             with tracing.span("engine.fetch"):
                 lg = np.asarray(lg)  # dstpu-lint: ignore[DSTPU001]
+            if greedy:
+                self._note_moe_rows(disp, lg)
             for i, d in enumerate(finals):
                 out[d.uid] = int(lg[i]) if greedy else lg[i]
+
+    def _note_moe_rows(self, disp, fetched) -> None:
+        """``moe_rows`` / ``moe_rows_max`` of a greedy ragged dispatch, read
+        from the two counts behind its tokens (no transfer of their own)."""
+        if self._moe_stats and disp.recording:
+            disp.set(moe_rows=int(fetched[self.max_seqs]),
+                     moe_rows_max=int(fetched[self.max_seqs + 1]))
 
     def _count_dispatch(self, disp, padded_rows: int, plan,
                         fused: bool = False) -> None:
@@ -1088,17 +1128,20 @@ class InferenceEngineV2:
         mgr = self.block_mgr
         marks = (mgr.allocations, mgr.stats["cow_copies"])
         if disp.recording:
-            rows = ctx = by_row = decode = 0
+            rows = ctx = by_row = decode = seg = 0
             for d, take in plan:
                 seen = d.seen_tokens if fused else d.seen_tokens - take
                 rows += take
+                if self._seg_tile > 1 and not fused and take > 1:
+                    seg += take
                 ctx += seen + take
                 by_row += take * seen + take * (take + 1) // 2
                 # one token pending: a decode step (or a prompt's last token)
                 if fused or take == 1:
                     decode += take
             disp.set(padded_rows=padded_rows, rows=rows, decode_rows=decode,
-                     prefill_tokens=rows - decode, seqs=len(plan),
+                     prefill_tokens=rows - decode, seg_tokens=seg,
+                     seqs=len(plan),
                      ctx_tokens=ctx, ctx_tokens_by_row=by_row,
                      blocks_allocated=max(0, marks[0] - self._count_marks[0]),
                      cow_copies=max(0, marks[1] - self._count_marks[1]))
@@ -1124,17 +1167,30 @@ class InferenceEngineV2:
             T = self.token_budget
         plan: List[Tuple] = []
         used = 0
+        tile = self._seg_tile if T > self.max_seqs else 1
+        # segment tiles: the first max_seqs rows are the one-token rows (there
+        # are never more sequences than that), the rest whole tiles
+        seg_rows = (T - self.max_seqs) // tile * tile
         for d in work:
-            if used >= T:
-                break
-            take = min(d.in_flight, self.prefill_chunk, T - used)
+            if tile > 1:
+                if d.in_flight == 1:
+                    take = 1
+                else:
+                    take = min(d.in_flight, self.prefill_chunk, seg_rows - used)
+                    if take <= 0:
+                        break
+                    used += -(-take // tile) * tile
+            else:
+                if used >= T:
+                    break
+                take = min(d.in_flight, self.prefill_chunk, T - used)
+                used += take
             if d.seen_tokens + take > self.max_seq_len:
                 raise ContextOverflowError(
                     f"uid {d.uid}: prompt exceeds context "
                     f"({d.seen_tokens}+{take} > {self.max_seq_len})",
                     uid=d.uid)
             plan.append((d, take))
-            used += take
         # allocate blocks for the WHOLE step before mutating any sequence
         # state. A row whose blocks cannot be allocated is DEFERRED (its
         # tokens stay pending for a later dispatch) rather than failing
@@ -1181,9 +1237,17 @@ class InferenceEngineV2:
              (M,), (M,), (M,), (M,), (M,), (M,), (M,)),
             dtypes=(np.int32,) * 8 + (np.float32, np.float32))
         finals = []
-        r = 0
+        r = singles = 0
+        seg_next = self.max_seqs       # next free segment tile's first row
         for d, take in plan:
             completes = take == d.in_flight
+            if tile > 1:
+                # one-token rows fill from row 0, a chunk's segment from the
+                # next tile boundary (the rows between stay padding)
+                if d.in_flight == 1:
+                    r, singles = singles, singles + 1
+                else:
+                    r, seg_next = seg_next, seg_next + -(-take // tile) * tile
             # fill the first row in place, then broadcast-copy it to the
             # sequence's remaining rows — no per-row temp allocation
             r0 = r
@@ -1693,7 +1757,8 @@ class InferenceEngineV2:
                 lg, self.kv = fn(*args)
         # no np.asarray and no register here — both are deferred: the
         # transfer to fetch(), the prefix-index publish to commit_step()
-        handle = DecodeDispatchHandle([d.uid for d in descs], lg, eng=self)
+        handle = DecodeDispatchHandle([d.uid for d in descs], lg, eng=self,
+                                      disp=disp)
         self._undrained_dispatch = handle
         return handle
 

@@ -25,6 +25,7 @@ Architecture choices driven by XLA/TPU:
   iota comparison (no materialised (S,S) bool tensor at peak memory).
 """
 
+import math
 import os
 from dataclasses import dataclass, field, replace
 from functools import partial
@@ -88,6 +89,42 @@ class TransformerConfig:
     # use_residual): dense MLP alongside the experts, learned 2-way softmax
     # coefficient blends the two outputs per token
     moe_use_residual: bool = False
+    # DeepSeek-V3-style routing (``moe_router="group_limited"``): sigmoid
+    # scores over ``moe_router_width`` outputs (0 = num_experts), a
+    # selection-only bias, ``moe_n_group`` groups of which the
+    # ``moe_topk_group`` best are kept, top-k inside them, weights normalised
+    # over the chosen and scaled by ``moe_score_scale``; no capacity, no drop.
+    # ``num_experts`` then counts the experts HELD HERE (their weights exist),
+    # the first being the router's output ``moe_expert_offset``: the share of
+    # an expert-parallel deployment (moe/layer.py ``held_experts_ffn``)
+    moe_router: str = "gshard"  # "gshard" | "group_limited"
+    moe_router_width: int = 0
+    moe_expert_offset: int = 0
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
+    moe_norm_topk: bool = True
+    moe_score_scale: float = 1.0
+    moe_shared_size: int = 0  # width of the shared expert beside the routed ones
+    # leading dense layers before the expert layers (two stacked groups:
+    # params["dense_blocks"] then params["blocks"]), at their own MLP width
+    num_dense_layers: int = 0
+    dense_intermediate_size: Optional[int] = None
+    # attention kind: "mha" (q/k/v heads, GQA by num_kv_heads) | "mla"
+    # (multi-head latent attention, DeepSeek-V2/V3: low-rank q and kv latents,
+    # a rope head shared by all heads; the cache holds [c_kv | k_rope])
+    attention: str = "mha"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rotary scaling (rope_factor 1 = plain rotary)
+    rope_factor: float = 1.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
     # progressive layer drop (PLD): stochastic depth driven by a per-step theta
     # injected as batch["pld_theta"] (reference progressive_layer_drop.py)
     progressive_layer_drop: bool = False
@@ -128,6 +165,42 @@ class TransformerConfig:
         return self.head_dim_override or (self.hidden_size // self.num_heads)
 
     @property
+    def is_mla(self) -> bool:
+        return self.attention == "mla"
+
+    @property
+    def kv_row(self) -> Tuple[int, int]:
+        """(key width, value width) of one token's row of the paged pool, a
+        layer and a pool head: ``[k | v]`` of one kv head, or the latent
+        ``[c_kv | k_rope]`` every head shares. THE place the pool's row width
+        is read from (``init_kv_pool``, the engine's block programs)."""
+        if self.is_mla:
+            from ..ops.transformer.paged_attention import latent_row
+
+            return latent_row(self.kv_lora_rank, self.qk_rope_head_dim)
+        return (self.head_dim, self.head_dim)
+
+    @property
+    def pool_heads(self) -> int:
+        """Heads the paged pool keeps rows for: one under latent attention."""
+        return 1 if self.is_mla else self.kv_heads
+
+    @property
+    def num_moe_layers(self) -> int:
+        return (self.num_layers - self.num_dense_layers
+                if self.num_experts > 0 else 0)
+
+    @property
+    def holds_experts(self) -> bool:
+        """The expert layers hold a share of the router's experts
+        (moe/layer.py ``held_experts_ffn``)."""
+        return self.num_experts > 0 and self.moe_router == "group_limited"
+
+    @property
+    def router_width(self) -> int:
+        return self.moe_router_width or self.num_experts
+
+    @property
     def mlp_dim(self) -> int:
         if self.intermediate_size is not None:
             return self.intermediate_size
@@ -138,40 +211,76 @@ class TransformerConfig:
         return 4 * self.hidden_size
 
     @property
-    def num_parameters(self) -> int:
-        H, L, V, I = self.hidden_size, self.num_layers, self.vocab_size, self.mlp_dim
+    def dense_mlp_dim(self) -> int:
+        return self.dense_intermediate_size or self.mlp_dim
+
+    def _mlp_params(self, width: int) -> int:
+        return (3 if self.activation in ("swiglu", "geglu") else 2) \
+            * self.hidden_size * width
+
+    @property
+    def _attn_params(self) -> int:
+        H = self.hidden_size
+        if self.is_mla:
+            nh, qr, kvr = self.num_heads, self.q_lora_rank, self.kv_lora_rank
+            nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                              self.v_head_dim)
+            return (H * qr + qr * nh * (nope + rope) + H * (kvr + rope)
+                    + kvr * nh * (nope + vd) + nh * vd * H
+                    + qr + kvr)  # the two latent norms
         qd = self.num_heads * self.head_dim
         kvd = self.kv_heads * self.head_dim
-        attn = H * qd + 2 * H * kvd + qd * H  # q, k, v, o
-        mlp = (3 if self.activation in ("swiglu", "geglu") else 2) * H * I
-        if self.num_experts > 0:
-            dense_mlp = mlp
-            mlp = mlp * self.num_experts + H * self.num_experts  # experts + router
-            if self.moe_use_residual:
-                mlp += dense_mlp + 2 * H + 2  # residual MLP + coefficient
+        return H * qd + 2 * H * kvd + qd * H  # q, k, v, o
+
+    @property
+    def num_parameters(self) -> int:
+        """Parameters of the tree ``init_params`` builds: with held experts
+        (``moe_router="group_limited"``) the experts held here, not the
+        router's width."""
+        H, L, V = self.hidden_size, self.num_layers, self.vocab_size
         n_ln = 1 if (self.parallel_block and self.parallel_shared_ln) else 2
         norms = n_ln * (1 if self.norm == "rmsnorm" else 2) * H
-        per_layer = attn + mlp + norms
+        mlp = self._mlp_params(self.mlp_dim)
+        dense_layer = self._attn_params + norms + self._mlp_params(
+            self.dense_mlp_dim)
+        if self.num_experts > 0:
+            moe = mlp * self.num_experts + H * self.router_width  # + router
+            if self.moe_router == "group_limited":
+                moe += self.router_width  # the selection bias
+            moe += self._mlp_params(self.moe_shared_size)
+            if self.moe_use_residual:
+                moe += mlp + 2 * H + 2  # residual MLP + coefficient
+            moe_layer = self._attn_params + norms + moe
+        else:
+            moe_layer = dense_layer
+        n_moe = self.num_moe_layers if self.num_experts > 0 else L
         emb = V * H + (0 if self.pos_embedding != "learned" else self.max_seq_len * H)
         head = 0 if self.tie_embeddings else V * H
-        return L * per_layer + emb + head + H
+        return ((L - n_moe) * dense_layer + n_moe * moe_layer
+                + emb + head + H)
 
     @property
     def num_active_parameters(self) -> int:
-        """Parameters touched per token (= num_parameters for dense; for MoE only
-        top-k of E experts are activated)."""
+        """Parameters touched per token (= num_parameters for dense; for MoE
+        only top-k experts are activated, of those held here at most all)."""
         if self.num_experts == 0:
             return self.num_parameters
-        H, L, I, E = self.hidden_size, self.num_layers, self.mlp_dim, self.num_experts
-        per_expert = (3 if self.activation == "swiglu" else 2) * H * I
-        inactive = L * (E - self.moe_top_k) * per_expert
+        E = self.num_experts
+        inactive = self.num_moe_layers * (E - min(self.moe_top_k, E)) \
+            * self._mlp_params(self.mlp_dim)
         return self.num_parameters - inactive
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
-        """Model FLOPs per token for one fwd+bwd (6·N_active + attention term)."""
+        """Model FLOPs per token for one fwd+bwd (6·N_active + attention term:
+        q·k and p·v over the heads' own widths)."""
         S = seq_len or self.max_seq_len
         n = self.num_active_parameters
-        attn_flops = 12 * self.num_layers * self.hidden_size * S  # fwd+bwd qk^T + av
+        if self.is_mla:
+            per_pos = self.num_heads * (self.qk_nope_head_dim
+                                        + self.qk_rope_head_dim + self.v_head_dim)
+        else:
+            per_pos = 2 * self.num_heads * self.head_dim
+        attn_flops = 6 * self.num_layers * per_pos * S  # fwd+bwd qk^T + av
         return 6 * n + attn_flops
 
 
@@ -278,6 +387,57 @@ def _rope(q, k, positions, head_dim, theta, rotary_dim=None):
     return rot(q), rot(k)
 
 
+def yarn_mscale(factor: float, m: float) -> float:
+    return 1.0 if factor <= 1 or m <= 0 else 0.1 * m * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: "TransformerConfig") -> np.ndarray:
+    """(rotary_dim/2,) rotary frequencies of the latent-attention rope head
+    under YaRN (Peng et al. 2023, as ``deepseek_v3`` applies it):
+    interpolated by ``rope_factor`` below the ``rope_beta_slow`` correction
+    dimension, kept above the ``rope_beta_fast`` one, a linear ramp between.
+    ``rope_factor`` 1 gives the plain frequencies."""
+    dim, theta = cfg.qk_rope_head_dim, cfg.rope_theta
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if cfg.rope_factor <= 1:
+        return f.astype(np.float32)
+
+    def correction(rotations):
+        return dim * math.log(cfg.rope_original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(correction(cfg.rope_beta_fast)), 0)
+    hi = min(math.ceil(correction(cfg.rope_beta_slow)), dim // 2 - 1)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return (f / cfg.rope_factor * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
+def _rope_interleaved(x, positions, cfg: "TransformerConfig"):
+    """Rotate the interleaved pairs ``(x[2i], x[2i+1])`` of (B, S, h, d) at
+    integer positions (B, S): the ``deepseek_v3`` pairing. cos and sin carry
+    ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)``."""
+    ang = positions[..., None].astype(jnp.float32) * yarn_inv_freq(cfg)
+    amp = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+           / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    cos = (jnp.cos(ang) * amp)[:, :, None, :]
+    sin = (jnp.sin(ang) * amp)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def mla_softmax_scale(cfg: "TransformerConfig") -> float:
+    """``(nope + rope)^-1/2`` times ``mscale(factor, mscale_all_dim)^2``."""
+    return ((cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+            * yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2)
+
+
+#: per-layer expert matrices: the paged program indexes them by (layer,
+#: expert) where they lie instead of slicing a layer out of the stack
+EXPERT_LEAVES = ("wi", "w_gate", "w_down")
+
+
 def alibi_slopes(n_heads: int) -> np.ndarray:
     """Per-head ALiBi slopes (geometric sequence, closest-power-of-2 rule —
     same formula as HF ``build_alibi_tensor`` used by the reference's BLOOM
@@ -308,10 +468,15 @@ class TransformerLM:
     def __init__(self, config: TransformerConfig, mesh_axes: Tuple[str, str] = ("model", "seq")):
         self.config = config
         self.model_axis, self.seq_axis = mesh_axes
+        if config.holds_experts and not config.is_mla:
+            raise ValueError("moe_router='group_limited' (held experts) is "
+                             "wired into attention='mla' blocks only")
 
     # ------------------------------------------------------------------
     def init_params(self, rng) -> Dict[str, Any]:
         cfg = self.config
+        if cfg.is_mla:
+            return self._init_params_mla(rng)
         H, L, V, I = cfg.hidden_size, cfg.num_layers, cfg.vocab_size, cfg.mlp_dim
         nh, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         dt = cfg.param_dtype
@@ -402,6 +567,78 @@ class TransformerLM:
                 params["lm_head_bias"] = jnp.zeros((V,), dt)
         return params
 
+    def _mla_shapes(self):
+        """{group: (layers, {leaf: per-layer shape})} and {top leaf: shape}
+        of a latent-attention model: ``dense_blocks`` (the leading dense
+        layers) before ``blocks`` (expert layers, or all layers of a model
+        without experts)."""
+        cfg = self.config
+        H, V, nh = cfg.hidden_size, cfg.vocab_size, cfg.num_heads
+        qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
+        nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        attn = {
+            "ln1_scale": (H,), "wq_a": (H, qr), "q_a_scale": (qr,),
+            "wq_b": (qr, nh * (nope + rope)), "wkv_a": (H, kvr + rope),
+            "kv_a_scale": (kvr,), "wkv_b": (kvr, nh * (nope + vd)),
+            "wo": (nh * vd, H), "ln2_scale": (H,),
+        }
+
+        def mlp(width):
+            return {"w_gate": (H, width), "w_up": (H, width),
+                    "w_down": (width, H)}
+
+        E, I = cfg.num_experts, cfg.mlp_dim
+        groups = {}
+        n_dense = cfg.num_dense_layers if E > 0 else 0
+        if n_dense:
+            groups["dense_blocks"] = (n_dense, {**attn,
+                                                **mlp(cfg.dense_mlp_dim)})
+        if E > 0:
+            moe = {"moe_wg": (H, cfg.router_width),
+                   "moe_bias": (cfg.router_width,),
+                   "wi": (E, H, I), "w_gate": (E, H, I), "w_down": (E, I, H)}
+            if cfg.moe_shared_size:
+                moe.update({"shared_" + k: v
+                            for k, v in mlp(cfg.moe_shared_size).items()})
+            groups["blocks"] = (cfg.num_layers - n_dense, {**attn, **moe})
+        else:
+            groups["blocks"] = (cfg.num_layers, {**attn, **mlp(cfg.dense_mlp_dim)})
+        top = {"wte": (V, H), "lnf_scale": (H,)}
+        if not cfg.tie_embeddings:
+            top["lm_head"] = (H, V)
+        return groups, top
+
+    def _init_params_mla(self, rng) -> Dict[str, Any]:
+        """{leaf, dense_blocks: {leaf}, blocks: {leaf}}: two stacked groups,
+        the dense one first. Residual projections are ``wo`` / ``w_down``,
+        norm scales ``*_scale``."""
+        cfg = self.config
+        if cfg.activation != "swiglu" or cfg.norm != "rmsnorm":
+            raise ValueError("attention='mla' models are rmsnorm + swiglu")
+        if cfg.num_experts > 0 and cfg.moe_router != "group_limited":
+            raise ValueError("attention='mla' models route with "
+                             "moe_router='group_limited'")
+        dt = cfg.param_dtype
+        groups, top = self._mla_shapes()
+        init = jax.nn.initializers.normal(0.02)
+        resid_init = jax.nn.initializers.normal(
+            0.02 / np.sqrt(2 * cfg.num_layers))
+        keys = iter(jax.random.split(rng, 64))
+
+        def leaf(name, shape):
+            if name.endswith("_scale"):
+                return jnp.ones(shape, dt)
+            if name == "moe_bias":
+                return jnp.zeros(shape, dt)
+            return (resid_init if name in ("wo", "w_down") else init)(
+                next(keys), shape, dt)
+
+        params = {k: leaf(k, shape) for k, shape in top.items()}
+        for group, (n, leaves) in groups.items():
+            params[group] = {k: leaf(k, (n,) + shape)
+                             for k, shape in leaves.items()}
+        return params
+
     # ------------------------------------------------------------------
     @property
     def tp_specs(self) -> Dict[str, Any]:
@@ -413,6 +650,8 @@ class TransformerLM:
         """
         cfg = self.config
         m = self.model_axis
+        if cfg.is_mla:
+            return self._tp_specs_mla()
         single_ln = cfg.parallel_block and cfg.parallel_shared_ln
         specs: Dict[str, Any] = {
             "wte": P(m, None),
@@ -485,6 +724,33 @@ class TransformerLM:
                 specs["lm_head_bias"] = P(m)
         return specs
 
+    def _tp_specs_mla(self) -> Dict[str, Any]:
+        """Latent attention: the per-head up-projections ``wq_b`` / ``wkv_b``
+        column-parallel and ``wo`` row-parallel over ``model``; the low-rank
+        down-projections, their norms and the router replicated; held experts
+        over ``expert``, their widths (and the shared expert's, and the dense
+        MLP's) over ``model``."""
+        m, e = self.model_axis, "expert"
+        col, row = P(None, None, m), P(None, m, None)
+        by_name = {
+            "wq_b": col, "wkv_b": col, "wo": row,
+            "w_gate": col, "w_up": col, "w_down": row,
+            "shared_w_gate": col, "shared_w_up": col, "shared_w_down": row,
+        }
+        expert = {"wi": P(None, e, None, m), "w_gate": P(None, e, None, m),
+                  "w_down": P(None, e, m, None)}
+        groups, top = self._mla_shapes()
+        specs: Dict[str, Any] = {
+            "wte": P(m, None), "lnf_scale": P(None), "lm_head": P(None, m)}
+        specs = {k: specs[k] for k in top}
+        for group, (_, leaves) in groups.items():
+            moe = "moe_wg" in leaves
+            specs[group] = {
+                k: (expert[k] if moe and k in expert else
+                    by_name.get(k, P(*([None] * (len(shape) + 1)))))
+                for k, shape in leaves.items()}
+        return specs
+
     # ------------------------------------------------------------------
     def _constraint(self, x, spec):
         """Sharding constraint if we are under a mesh; no-op otherwise."""
@@ -518,6 +784,15 @@ class TransformerLM:
         decode — reference ``inference/v2/ragged_ops/blocked_flash`` +
         ``kv_cache.py BlockedKVCache``)."""
         cfg = self.config
+        if cfg.is_mla:
+            if kv_cache is not None or attn_mask_bias is not None or (
+                    rng is not None and cfg.dropout > 0):
+                raise NotImplementedError(
+                    "attention='mla' has the full-sequence and the paged "
+                    "paths only: no slot cache, padding mask or dropout")
+            y, pool, _ = self._block_mla(x, blk, positions=positions,
+                                         paged=paged)
+            return y, pool, jnp.zeros((), jnp.float32)
         nh, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         B, S, H = x.shape
         # weight-only-quantized params (ops/quantizer/woq.py): dequant this
@@ -695,6 +970,140 @@ class TransformerLM:
             return x + attn_out + mlp_out, new_kv, aux
         return x + mlp_out, new_kv, aux
 
+    def _block_mla(self, x, blk, *, positions, paged=None, seg_from=None,
+                   experts=None, row_mask=None):
+        """One latent-attention block on (B, S, H): a dense layer, or an
+        expert layer where ``blk`` holds a router. Returns (y, new pool,
+        (rows, rows_max) of the expert layer's held experts or None).
+
+        Full sequence (``paged`` None): the latent is up-projected through
+        ``wkv_b`` and attended to causally, un-absorbed. ``paged`` (pool,
+        layer, tables): every token is a row (S = 1); its ``[c_kv | k_rope]``
+        is written to the latent pool and attention runs in the absorbed form
+        (``q_nope W_uk`` against ``c_kv``, the weighted latent through
+        ``W_uv``: both views of ``wkv_b``, taken here) over the pool where it
+        lies. Rows from ``seg_from`` on are chunk segments in tiles of
+        ``paged_attention.SEGMENT_TILE`` rows, each tile consecutive tokens
+        of one sequence (the tile's first row carries its table), so that a
+        tile streams its sequence's latent once; rows before it are one-token
+        rows of sequences of their own. ``experts``: (stacked expert leaves,
+        layer of the group) when the caller kept them out of ``blk``.
+        ``row_mask`` (B*S,) bool: the rows that are real tokens (padding rows
+        are routed to no expert)."""
+        from ..moe.layer import _gated_mlp, held_experts_ffn
+        from ..ops.transformer import paged_attention as pa
+
+        cfg = self.config
+        nh, rank = cfg.num_heads, cfg.kv_lora_rank
+        nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        B, S, H = x.shape
+        eps, dt = cfg.norm_eps, x.dtype
+        scale = mla_softmax_scale(cfg)
+        blk = _dequant_woq(blk, dt)
+        # a norm over a product reads the product twice (its mean square, then
+        # its values): the barrier keeps XLA from fusing the matmul into both
+        # reads, which streams the matrix twice (on the chip ``wo`` alone cost
+        # 0.2 ms a layer)
+        once = jax.lax.optimization_barrier
+
+        def rms(v, name):
+            return _norm(v, blk[name], None, "rmsnorm", eps)
+
+        new_pool = None
+        with jax.named_scope("attn"):
+            with jax.named_scope("mla_proj"):
+                h = rms(x, "ln1_scale")
+                c_q = rms(once(h @ blk["wq_a"].astype(dt)), "q_a_scale")
+                q = (c_q @ blk["wq_b"].astype(dt)).reshape(B, S, nh, nope + rope)
+                kv_a = once(h @ blk["wkv_a"].astype(dt))
+                c_kv = rms(kv_a[..., :rank], "kv_a_scale")          # (B, S, rank)
+                k_rope = _rope_interleaved(kv_a[..., None, rank:], positions, cfg)
+                q_nope = q[..., :nope]
+                q_rope = _rope_interleaved(q[..., nope:], positions, cfg)
+                wkv_b = blk["wkv_b"].astype(dt).reshape(rank, nh, nope + vd)
+                w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
+            if paged is None:
+                with jax.named_scope("mla_proj"):
+                    k_nope = jnp.einsum("bsr,rhd->bshd", c_kv, w_uk)
+                    v = jnp.einsum("bsr,rhd->bshd", c_kv, w_uv)
+                    k = jnp.concatenate(
+                        [k_nope, jnp.broadcast_to(k_rope, (B, S, nh, rope))], -1)
+                    qq = jnp.concatenate([q_nope, q_rope], -1)
+                if vd == nope + rope:
+                    attn = _attention_op(qq, k, v, causal=True, scale=scale)
+                else:
+                    from ..ops.transformer.attention import xla_attention
+
+                    attn = xla_attention(qq, k, v, causal=True, scale=scale)
+            else:
+                pool, layer, tables = paged
+                if S != 1:
+                    raise ValueError("the latent paged path takes one-token rows")
+                with jax.named_scope("kv_write"):
+                    pool = pa.write_rows(
+                        pool, layer, tables, positions, c_kv[:, :, None, :],
+                        jnp.pad(k_rope, ((0, 0),) * 3
+                                + ((0, cfg.kv_row[1] - rope),)))
+                new_pool = pool
+                with jax.named_scope("mla_proj"):
+                    q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
+                with jax.named_scope("paged_attn"):
+                    o_lat = self._mla_paged_attention(
+                        q_lat, q_rope[:, 0], pool, layer, tables,
+                        positions[:, 0] + 1, scale, seg_from)
+                with jax.named_scope("mla_proj"):
+                    attn = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv)[:, None]
+            attn_out = attn.reshape(B, S, nh * vd) @ blk["wo"].astype(dt)
+            attn_out = self._constraint(attn_out, self._act_spec(paged is None))
+        stats = None
+        with jax.named_scope("mlp"):
+            x = once(x + attn_out)
+            h2 = rms(x, "ln2_scale")
+            if "moe_wg" in blk:
+                big, layer_in_group = experts if experts is not None else (blk, None)
+                shared = tuple(blk["shared_" + k] for k in
+                               ("w_gate", "w_up", "w_down")) \
+                    if "shared_w_gate" in blk else None
+                y, stats = held_experts_ffn(
+                    h2.reshape(B * S, H), blk["moe_wg"], blk["moe_bias"],
+                    big["wi"], big["w_gate"], big["w_down"], shared,
+                    k=cfg.moe_top_k, n_group=cfg.moe_n_group,
+                    topk_group=cfg.moe_topk_group,
+                    normalize=cfg.moe_norm_topk, scale=cfg.moe_score_scale,
+                    first=cfg.moe_expert_offset, layer=layer_in_group,
+                    token_mask=row_mask)
+                mlp_out = y.reshape(B, S, H)
+            else:
+                mlp_out = _gated_mlp(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
+            mlp_out = self._constraint(mlp_out, self._act_spec(paged is None))
+        return x + mlp_out, new_pool, stats
+
+    def _mla_paged_attention(self, q_lat, q_rope, pool, layer, tables, limits,
+                             scale, seg_from):
+        """Absorbed attention of T one-token rows over the latent pool: rows
+        before ``seg_from`` one a sequence, rows from it on in segment tiles.
+        The Pallas kernel on a TPU (or forced, as the GPT-2 path's is), the
+        XLA gather off it."""
+        from ..ops.transformer import paged_attention as pa
+        from ..ops.transformer.attention import get_default_impl
+
+        use_kernel = get_default_impl() != "xla" and (
+            jax.default_backend() == "tpu"
+            or os.environ.get("DSTPU_FORCE_PAGED_KERNEL") == "1")
+        attend = pa.mla_decode if use_kernel else pa.mla_attend_xla
+        T = q_lat.shape[0]
+        cut = T if seg_from is None else seg_from
+        parts = []
+        if cut:
+            parts.append(attend(q_lat[:cut], q_rope[:cut], pool, layer,
+                                tables[:cut], limits[:cut], scale=scale))
+        if cut < T:
+            tile = pa.SEGMENT_TILE
+            parts.append(attend(q_lat[cut:], q_rope[cut:], pool, layer,
+                                tables[cut::tile], limits[cut:], scale=scale,
+                                q_tile=tile))
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
     def _moe_ffn(self, h, blk, train):
         """Routed expert FFN on (B,S,H) — delegates to the shared MoE core
         (reference ``moe/sharded_moe.py MOELayer``); one group per sequence."""
@@ -801,6 +1210,13 @@ class TransformerLM:
             return jax.checkpoint(fn, policy=policy[name])
         return jax.checkpoint(fn)
 
+    @staticmethod
+    def layer_groups(params):
+        """The stacked layer groups of ``params`` in forward order: the
+        leading ``dense_blocks`` (where the model has them) before
+        ``blocks``."""
+        return [g for g in ("dense_blocks", "blocks") if g in params]
+
     def _trunk(self, params, x, positions, rng, train, pld_theta=None,
                attn_mask_bias=None):
         """Run all blocks via scan (remat optional). With ``pld_theta``
@@ -810,6 +1226,9 @@ class TransformerLM:
         L = cfg.num_layers
         use_pld = pld_theta is not None and train
         use_rng = rng is not None and train and (cfg.dropout > 0 or use_pld)
+        if use_rng and len(self.layer_groups(params)) > 1:
+            raise NotImplementedError(
+                "dropout / progressive layer drop over two layer groups")
 
         if use_rng:
             rngs = jax.random.split(rng, L)
@@ -848,12 +1267,16 @@ class TransformerLM:
             block_fn = self._ckpt(body) if cfg.remat else body
             if not cfg.scan_layers:
                 aux_sum = jnp.zeros((), jnp.float32)
-                for i in range(L):
-                    blk = jax.tree.map(lambda a: a[i], params["blocks"])
-                    x, aux = block_fn(x, blk)
-                    aux_sum = aux_sum + aux
+                for group in self.layer_groups(params):
+                    for i in range(jax.tree.leaves(params[group])[0].shape[0]):
+                        blk = jax.tree.map(lambda a: a[i], params[group])
+                        x, aux = block_fn(x, blk)
+                        aux_sum = aux_sum + aux
                 return x, aux_sum
-            x, auxes = jax.lax.scan(block_fn, x, params["blocks"])
+            auxes = jnp.zeros((), jnp.float32)
+            for group in self.layer_groups(params):
+                x, aux = jax.lax.scan(block_fn, x, params[group])
+                auxes = auxes + jnp.sum(aux)
         return x, jnp.sum(auxes)
 
     def _trunk_ltd(self, params, x, positions, rng, keep: int, attn_mask=None):
@@ -1072,18 +1495,29 @@ class TransformerLM:
     # paged (blocked) KV cache — reference inference/v2 BlockedKVCache path
     # ------------------------------------------------------------------
     def init_kv_pool(self, num_blocks: int, block_size: int, dtype=jnp.bfloat16):
-        """The blocked KV pool: ONE array (L, kvh, NB, BS, 2*hd) whose rows are
-        ``[k_t | v_t]`` (layout and access: ``ops/transformer/
-        paged_attention.py``); block 0 is the reserved trash block that
-        masked/padded writes land in."""
+        """The blocked KV pool: ONE array (L, kvh, NB, BS, row) whose rows are
+        ``[k_t | v_t]`` or, under latent attention, ``[c_kv | k_rope]`` with
+        one pool head (layout and access: ``ops/transformer/
+        paged_attention.py``; the widths: ``TransformerConfig.kv_row``);
+        block 0 is the reserved trash block that masked/padded writes land
+        in."""
         from ..ops.transformer.paged_attention import init_pool
 
         cfg = self.config
-        return init_pool(cfg.num_layers, cfg.kv_heads, num_blocks, block_size,
-                         cfg.head_dim, dtype)
+        return init_pool(cfg.num_layers, cfg.pool_heads, num_blocks, block_size,
+                         cfg.kv_row, dtype)
+
+    @property
+    def segment_tile(self) -> int:
+        """Rows of one chunk-segment tile of the paged program (1: the model
+        takes a prefill chunk as one-token rows like any other)."""
+        from ..ops.transformer.paged_attention import SEGMENT_TILE
+
+        return SEGMENT_TILE if self.config.is_mla else 1
 
     def forward_paged(self, params, input_ids, kv_pool, tables, starts,
-                      n_valid=None, logit_rows=None):
+                      n_valid=None, logit_rows=None, seg_from=None,
+                      moe_stats=False):
         """Run a (B, S) segment against the blocked pool.
 
         tables: (B, MAXB) pool block ids per sequence (0-padded); starts: (B,)
@@ -1092,8 +1526,18 @@ class TransformerLM:
         int32), only those rows are projected through the vocab head —
         returns ((R, V), new pool) — so a ragged batch pays for R logits, not
         B (reference ``ragged_ops/logits_gather``).
+
+        ``seg_from`` (latent attention only; static): rows from it on are
+        chunk segments in tiles of ``segment_tile`` rows (:meth:`_block_mla`).
+        ``moe_stats``: also return (rows, rows_max) int32 (2,): the (token,
+        choice) pairs that landed on held experts summed over the layers, and
+        the busiest held expert's (``config.holds_experts`` only).
         """
         B, S = input_ids.shape
+        if self.config.is_mla:
+            return self._forward_paged_mla(params, input_ids, kv_pool, tables,
+                                           starts, logit_rows, seg_from,
+                                           moe_stats)
         positions = starts[:, None] + jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32), (B, S))
         dtype = kv_pool.dtype
@@ -1126,6 +1570,56 @@ class TransformerLM:
         with jax.named_scope("lm_head_loss"):
             lg = self._head(params, x_last[:, None])[:, 0]
         return lg, kv_pool
+
+    def _forward_paged_mla(self, params, input_ids, kv_pool, tables, starts,
+                           logit_rows, seg_from, moe_stats):
+        """:meth:`forward_paged` of a latent-attention model: one-token rows
+        (T, 1), the layer groups scanned in turn with the latent pool as the
+        carry. The stacked expert matrices stay out of the scanned leaves:
+        the grouped product indexes them by (layer, expert) where they lie,
+        so no layer of experts is sliced out of the stack."""
+        T, S = input_ids.shape
+        if S != 1:
+            raise ValueError("the latent paged path takes one-token rows")
+        positions = starts[:, None]
+        with jax.named_scope("embed"):
+            x = self._embed(params, input_ids, positions, kv_pool.dtype)
+        stats = jnp.zeros((2,), jnp.int32)
+        layer0 = 0
+        # a padding row carries the all-zero table (trash block 0, which no
+        # sequence ever holds): it is routed to no expert
+        row_mask = tables[:, 0] > 0
+        with jax.named_scope("kv_carry"):
+            for group in self.layer_groups(params):
+                leaves = params[group]
+                # (weight-only-quantized experts are code + scale leaves: they
+                # ride the scan and are dequantised a layer at a time)
+                moe = all(k in leaves for k in EXPERT_LEAVES + ("moe_wg",))
+                big = {k: leaves[k] for k in EXPERT_LEAVES} if moe else None
+                small = {k: v for k, v in leaves.items()
+                         if not (moe and k in EXPERT_LEAVES)}
+                n = jax.tree.leaves(small)[0].shape[0]
+
+                def body(carry, blk, big=big):
+                    h, pool, l, st = carry
+                    y, pool, s = self._block_mla(
+                        h, blk, positions=positions,
+                        paged=(pool, l + layer0, tables), seg_from=seg_from,
+                        experts=None if big is None else (big, l),
+                        row_mask=row_mask)
+                    if s is not None:
+                        st = jnp.stack([st[0] + s[0], jnp.maximum(st[1], s[1])])
+                    return (y, pool, l + 1, st), None
+
+                (x, kv_pool, _, stats), _ = jax.lax.scan(
+                    body, (x, kv_pool, jnp.int32(0), stats), small)
+                layer0 += n
+        x_last = x[:, 0]
+        if logit_rows is not None:
+            x_last = x_last[logit_rows]
+        with jax.named_scope("lm_head_loss"):
+            lg = self._head(params, x_last[:, None])[:, 0]
+        return (lg, kv_pool, stats) if moe_stats else (lg, kv_pool)
 
     def decode_paged_multi(self, params, kv_pool, toks, tables, starts, k: int,
                            sampling=None):

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sharded_moe import topk_gating
+from .sharded_moe import group_limited_gating, topk_gating
 
 
 def _constraint(x, spec):
@@ -67,6 +67,131 @@ def routed_ffn(x, wg, wi, wo, wgate=None, *, k: int = 1,
     expert_out = _constraint(expert_out, P(data_axes, expert_axis, None, None))
     y = jnp.einsum("gech,gsec->gsh", expert_out.astype(jnp.float32), combine)
     return y.astype(x.dtype), jnp.mean(l_aux).astype(jnp.float32)
+
+
+def _gated_mlp(x, w_gate, w_up, w_down):
+    return (jax.nn.silu(x @ w_gate.astype(x.dtype))
+            * (x @ w_up.astype(x.dtype))) @ w_down.astype(x.dtype)
+
+
+def _expert_tile(pairs: int, experts: int) -> int:
+    """Rows of one tile of the grouped product: about an expert's even share
+    of ``pairs``, a power of two in [8, 128]."""
+    share = max(1, pairs // max(experts, 1))
+    return min(128, max(8, 1 << (share - 1).bit_length()))
+
+
+def grouped_experts(x, chosen, weights, wi, w_gate, w_down, *, first: int = 0,
+                    layer=None):
+    """The held experts' part of a routed gated-SiLU FFN, by grouped matrix
+    products: no one-hot dispatch, no capacity, no dropped token.
+
+    x (T, H); ``chosen`` / ``weights`` (T, k): each token's expert ids over
+    the ROUTER's outputs and its combine weights. ``wi`` / ``w_gate`` /
+    ``w_down`` hold the experts ``first .. first + E - 1``: (E, H, I) /
+    (E, I, H), or with ``layer`` (an int32 scalar) the stacked
+    (L, E, ...) leaves, indexed by (layer, expert) where they lie. Returns
+    (y (T, H) in x's dtype, (rows, rows_max) int32: the (token, choice) pairs
+    that landed on held experts, and the busiest expert's).
+
+    The pairs that land here are sorted by expert and laid into a row buffer
+    in which every expert's rows start on a tile boundary, so that each tile
+    of ``tile`` rows belongs to one expert: one pass over the tiles multiplies
+    each by its expert's matrices (a tile no pair reached is skipped, so the
+    cost follows the rows that landed, and an expert no token chose is never
+    read). The weighted rows are gathered back per (token, choice) and
+    summed in float32. Differentiable (a scan of conds), so a model of held
+    experts trains through it; the GShard models' training still goes through
+    :func:`routed_ffn`'s one-hot dispatch."""
+    T, H = x.shape
+    k = chosen.shape[1]
+    stacked = layer is not None
+    E = wi.shape[1] if stacked else wi.shape[0]
+    pairs = T * k
+    k_here = min(k, E)
+    tile = _expert_tile(T * k_here, E)
+    # rows the buffer must hold: every landed pair, plus each expert's
+    # padding to a tile boundary
+    R = -(-T * k_here // tile) * tile + E * tile
+    with jax.named_scope("moe_route"):
+        local = chosen.astype(jnp.int32) - first
+        here = (local >= 0) & (local < E)
+        key = jnp.where(here, local, E).reshape(pairs)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        sorted_key = key[order]
+        counts = jnp.sum(key[:, None] == jnp.arange(E)[None, :], axis=0,
+                         dtype=jnp.int32)                       # (E,)
+        padded = -(-counts // tile) * tile
+        row0 = jnp.cumsum(padded) - padded      # first row of each expert
+        rank0 = jnp.cumsum(counts) - counts     # first sorted pair of each
+        e_c = jnp.minimum(sorted_key, E - 1)
+        dest = row0[e_c] + jnp.arange(pairs, dtype=jnp.int32) - rank0[e_c]
+        dest = jnp.where(sorted_key < E, dest, R)     # absent: the zero row
+        row_token = jnp.full((R,), T, jnp.int32).at[dest].set(
+            order // k, mode="drop", unique_indices=True)
+        pair_row = jnp.zeros((pairs,), jnp.int32).at[order].set(
+            dest, unique_indices=True).reshape(T, k)
+        xs = jnp.concatenate([x, jnp.zeros((1, H), x.dtype)])[row_token]
+        tile_row0 = jnp.arange(R // tile, dtype=jnp.int32) * tile
+        tile_e = jnp.searchsorted(row0 + padded, tile_row0,
+                                  side="right").astype(jnp.int32)
+        tile_ec = jnp.minimum(tile_e, E - 1)
+        live = (tile_e < E) & (tile_row0 < (row0 + counts)[tile_ec])
+
+    def matrices(e):
+        if not stacked:
+            return wi[e], w_gate[e], w_down[e]
+        return tuple(
+            jax.lax.dynamic_slice(a, (layer, e, 0, 0), (1, 1) + a.shape[2:])
+            .reshape(a.shape[2:]) for a in (wi, w_gate, w_down))
+
+    def one_tile(_, t):
+        e, is_live, xt = t
+
+        def run(xt):
+            up, gate, down = matrices(e)
+            return _gated_mlp(xt, gate, up, down)
+
+        return None, jax.lax.cond(is_live, run, jnp.zeros_like, xt)
+
+    with jax.named_scope("moe_experts"):
+        _, ys = jax.lax.scan(one_tile, None,
+                             (tile_ec, live, xs.reshape(R // tile, tile, H)))
+    with jax.named_scope("moe_route"):
+        ys = jnp.concatenate([ys.reshape(R, H), jnp.zeros((1, H), ys.dtype)])
+        y = jnp.einsum("tkh,tk->th", ys[pair_row].astype(jnp.float32),
+                       weights.astype(jnp.float32))
+    return y.astype(x.dtype), (jnp.sum(counts), jnp.max(counts))
+
+
+def held_experts_ffn(x, wg, bias, wi, w_gate, w_down, shared=None, *, k: int,
+                     n_group: int = 1, topk_group: int = 1,
+                     normalize: bool = True, scale: float = 1.0,
+                     first: int = 0, layer=None, token_mask=None):
+    """One routed layer that is told which experts it holds: the router
+    (``wg`` (H, E_all), selection ``bias`` (E_all,)) runs over ALL its
+    outputs in float32 (:func:`group_limited_gating`), the layer computes its
+    own experts' part for the tokens routed to them
+    (:func:`grouped_experts`; ``first`` is the router output of the first
+    held expert) and adds the ``shared`` expert ((w_gate, w_up, w_down),
+    which every chip of the deployment computes alike). What the absent
+    experts would have added is left out: there is no exchange here and
+    nothing stands in for one. x (T, H); ``token_mask`` (T,) bool: rows that
+    are padding route nowhere. Returns (y, (rows, rows_max))."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x.astype(jnp.float32), wg.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        chosen, weights = group_limited_gating(
+            logits, bias, k=k, n_group=n_group, topk_group=topk_group,
+            normalize=normalize, scale=scale)
+        if token_mask is not None:
+            chosen = jnp.where(token_mask[:, None], chosen, -1)
+    y, stats = grouped_experts(x, chosen, weights, wi, w_gate, w_down,
+                               first=first, layer=layer)
+    if shared is not None:
+        with jax.named_scope("moe_shared"):
+            y = y + _gated_mlp(x, *shared)
+    return y, stats
 
 
 def residual_mix(x, moe_out, mlp_wi, mlp_wo, coef_w, coef_b, *,

@@ -83,3 +83,35 @@ def topk_gating(
         "capacity": C,
     }
     return combine, dispatch, l_aux, meta
+
+
+def group_limited_gating(logits, bias=None, *, k: int, n_group: int = 1,
+                         topk_group: int = 1, normalize: bool = True,
+                         scale: float = 1.0):
+    """DeepSeek-V3 routing (arXiv:2412.19437 section 2.1.2, ``noaux_tc``):
+    every token picks ``k`` of the router's ``E`` outputs, no capacity and no
+    drop. Returns (chosen (T, k) int32 expert ids, weights (T, k) float32).
+
+    Scores ``s`` are the sigmoid of the float32 ``logits`` (T, E). ``bias`` (E,) is added for SELECTION only. The outputs form
+    ``n_group`` contiguous groups; a group scores the sum of its two largest
+    biased scores, the ``topk_group`` best groups are kept and the ``k``
+    largest biased scores are taken among their experts. The weights are the
+    unbiased ``s`` of the chosen, divided by their sum over all ``k`` (held
+    on this chip or not) and multiplied by ``scale``."""
+    T, E = logits.shape
+    logits = logits.astype(jnp.float32)
+    s = jax.nn.sigmoid(logits)
+    biased = s if bias is None else s + bias.astype(jnp.float32)
+    if n_group > 1:
+        by_group = biased.reshape(T, n_group, E // n_group)
+        group_score = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)
+        kept = lax.top_k(group_score, topk_group)[1]            # (T, topk_group)
+        group_ok = jnp.any(
+            kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+        biased = jnp.where(jnp.repeat(group_ok, E // n_group, axis=1),
+                           biased, -jnp.inf)
+    chosen = lax.top_k(biased, k)[1].astype(jnp.int32)
+    w = jnp.take_along_axis(s, chosen, axis=1)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen, w * scale

@@ -4,10 +4,21 @@ the Pallas paged-attention decode kernel (blocked KV pool + block tables).
 Reference: ``deepspeed/inference/v2/kernels/ragged_ops/blocked_flash`` — flash
 attention over paged KV blocks addressed through per-sequence block tables.
 
-The pool is ONE array ``(L, kvh, NB, BS, 2*hd)``: layer, kv head, pool block,
-token of the block, and a row ``[k_t | v_t]`` of that token's key beside its
-value. At head size 64 a row is exactly the TPU's 128 lanes, at 128 it is two
-tiles: no head size needs a re-laid-out view between the write and the read.
+The pool is ONE array ``(L, kvh, NB, BS, row)``: layer, kv head, pool block,
+token of the block, and a row of that token. Two row layouts live behind the
+same functions, told apart by the ``(key width, value width)`` the model's
+configuration gives (``TransformerConfig.kv_row``):
+
+- ``[k_t | v_t]``, ``row = 2*hd``: a kv head's key beside its value. At head
+  size 64 a row is exactly the TPU's 128 lanes, at 128 it is two tiles: no
+  head size needs a re-laid-out view between the write and the read.
+- ``[c_kv | k_rope | 0]``, ``kvh = 1`` (latent attention, DeepSeek-V2/V3):
+  one row a token a layer, shared by every head. The "key" part is the
+  normalised kv latent, the "value" part the rotated rope key, zero-padded so
+  that the row is a whole number of 128-lane tiles (:func:`latent_row`: HBM
+  pads the minor dimension to that anyway, and a DMA slice must cover it).
+  :func:`mla_decode` attends over it in the absorbed form.
+
 Every program touches it through the functions here: the model writes whole
 rows at computed row numbers of the pool viewed flat (:func:`write_rows`, in
 place on a donated buffer) and reads either through the kernel
@@ -30,6 +41,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+#: rows of one chunk-segment tile of the latent paged program: consecutive
+#: tokens of one sequence that stream its latent once (:func:`mla_decode`)
+SEGMENT_TILE = 16
+#: pool blocks the latent kernel fetches a step (256 cached tokens at the
+#: usual block of 64)
+MLA_KV_BLOCKS = 4
+
 
 def _interpret() -> bool:
     from ..pallas_utils import pallas_interpret
@@ -42,10 +60,23 @@ def _interpret() -> bool:
 # ----------------------------------------------------------------------
 def init_pool(num_layers, kv_heads, num_blocks, block_size, head_dim,
               dtype=jnp.bfloat16):
-    """The zeroed pool, ``(L, kvh, NB, BS, 2*hd)``; block 0 is the reserved
-    trash block that masked/padded writes land in."""
-    return jnp.zeros((num_layers, kv_heads, num_blocks, block_size,
-                      2 * head_dim), dtype)
+    """The zeroed pool, ``(L, kvh, NB, BS, row)``; block 0 is the reserved
+    trash block that masked/padded writes land in. ``head_dim``: one width
+    (``row = 2*hd``) or the (key width, value width) pair of a row."""
+    row = 2 * head_dim if isinstance(head_dim, int) else sum(head_dim)
+    return jnp.zeros((num_layers, kv_heads, num_blocks, block_size, row),
+                     dtype)
+
+
+def latent_row(rank: int, rope: int):
+    """(key width, value width) of a latent pool row ``[c_kv | k_rope | 0]``:
+    the rope key padded up to a whole number of 128-lane tiles."""
+    return rank, -(-(rank + rope) // 128) * 128 - rank
+
+
+def _k_width(pool, k_width):
+    """Where a row splits: ``k_width`` or, unsaid, its middle."""
+    return pool.shape[-1] // 2 if k_width is None else k_width
 
 
 def write_rows(pool, layer, tables, positions, k, v):
@@ -67,35 +98,43 @@ def write_rows(pool, layer, tables, positions, k, v):
     return pool.reshape(-1, row).at[rows].set(kv).reshape(pool.shape)
 
 
-def gather_context(pool, layer, tables):
+def gather_context(pool, layer, tables, k_width=None):
     """Layer ``layer``'s logical cache of each row's table, gathered by XLA:
-    (k, v) of shape (B, MAXB*BS, kvh, hd). One gather with the layer among
-    its indices; the whole-context copy it makes is what the kernel avoids."""
-    hd = pool.shape[-1] // 2
+    (k, v) of shape (B, MAXB*BS, kvh, width), the row split at ``k_width``
+    (its middle if unsaid). One gather with the layer among its indices; the
+    whole-context copy it makes is what the kernels avoid."""
+    row, hd = pool.shape[-1], _k_width(pool, k_width)
     B = tables.shape[0]
-    ctx = pool[layer, :, tables]  # (B, MAXB, kvh, BS, 2*hd)
-    ctx = jnp.swapaxes(ctx, 2, 3).reshape(B, -1, pool.shape[1], 2 * hd)
+    ctx = pool[layer, :, tables]  # (B, MAXB, kvh, BS, row)
+    ctx = jnp.swapaxes(ctx, 2, 3).reshape(B, -1, pool.shape[1], row)
     return ctx[..., :hd], ctx[..., hd:]
 
 
-def get_block(pool, block):
-    """Pool block ``block`` of every layer as the host-side payload
-    (2, L, kvh, BS, hd), K stacked on V: the format tiers, swaps, CRCs and
-    cross-engine hand-off keep whatever the pool's layout is."""
-    hd = pool.shape[-1] // 2
-    blk = pool[:, :, block]  # (L, kvh, BS, 2*hd)
+def get_block(pool, block, k_width=None):
+    """Pool block ``block`` of every layer as the host-side payload the
+    tiers, swaps, CRCs and cross-engine hand-off keep whatever the pool's
+    layout is: (2, L, kvh, BS, hd), K stacked on V, for a ``[k | v]`` row of
+    two equal parts; the whole rows (1, L, kvh, BS, row) where the parts
+    differ in width (the latent ``[c_kv | k_rope]``)."""
+    hd = _k_width(pool, k_width)
+    blk = pool[:, :, block]  # (L, kvh, BS, row)
+    if 2 * hd != pool.shape[-1]:
+        return blk[None]
     return jnp.stack((blk[..., :hd], blk[..., hd:]))
 
 
 def set_block(pool, block, payload):
     """Write a :func:`get_block` payload into pool block ``block``."""
-    return pool.at[:, :, block].set(
-        jnp.concatenate((payload[0], payload[1]), axis=-1).astype(pool.dtype))
+    rows = payload[0] if payload.shape[0] == 1 else jnp.concatenate(
+        (payload[0], payload[1]), axis=-1)
+    return pool.at[:, :, block].set(rows.astype(pool.dtype))
 
 
-def payload_shape(pool):
+def payload_shape(pool, k_width=None):
     """Shape of one block's :func:`get_block` payload."""
     L, kvh, _, BS, row = pool.shape
+    if 2 * _k_width(pool, k_width) != row:
+        return (1, L, kvh, BS, row)
     return (2, L, kvh, BS, row // 2)
 
 
@@ -302,3 +341,157 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lens, *, scale=None,
         name="paged_decode_grid",
     )(tables, lens, qg, k_pool, v_pool)
     return out.reshape(B, nh, hd)
+
+
+# ----------------------------------------------------------------------
+# latent attention (MLA) over the ``[c_kv | k_rope]`` pool, absorbed form
+# ----------------------------------------------------------------------
+def _mla_kernel(layer_ref, tables_ref, nblk_ref, ql_ref, qr_ref, lim_ref,
+                pool_ref, o_ref, buf, sem, m_ref, l_ref, acc_ref, *,
+                block_size, rank, scale):
+    """Grid (tiles,): ONE cell per query tile. The tile's ``Q`` rows (its
+    tokens times all heads) are the absorbed queries ``[q_nope W_uk | q_rope]``
+    of tokens of ONE sequence; the kernel streams that sequence's pool blocks
+    ``pool[layer, 0, tables[t, j]]`` from HBM, ``MLA_KV_BLOCKS`` at a time and
+    double-buffered, and contracts every head with each latent tile once:
+    scores ``q_lat . c_kv + q_rope . k_rope``, masked per row by the tokens it
+    may see, online softmax, and the weighted LATENT as the result (the
+    caller up-projects it through ``W_uv``)."""
+    t = pl.program_id(0)
+    layer = layer_ref[0]
+    kv_blocks = MLA_KV_BLOCKS
+    n_steps = nblk_ref[t]                   # groups of kv_blocks pool blocks
+    width = kv_blocks * block_size
+
+    def copies(j, slot):
+        return [pltpu.make_async_copy(
+            pool_ref.at[layer, 0, tables_ref[t, j * kv_blocks + i]],
+            buf.at[slot, pl.ds(i * block_size, block_size)], sem.at[slot, i])
+            for i in range(kv_blocks)]
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(n_steps > 0)
+    def _prologue():
+        for c in copies(0, 0):
+            c.start()
+
+    def body(j, _):
+        slot = jax.lax.rem(j, 2)
+
+        @pl.when(j + 1 < n_steps)
+        def _prefetch():
+            for c in copies(j + 1, 1 - slot):
+                c.start()
+
+        for c in copies(j, slot):
+            c.wait()
+        kv = buf[slot]                                    # (width, row)
+        c_kv, k_rope = kv[:, :rank], kv[:, rank:rank + qr_ref.shape[-1]]
+        dims = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(ql_ref[0], c_kv, dims,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr_ref[0], k_rope, dims,
+                                   preferred_element_type=jnp.float32)) * scale
+        kpos = j * width + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < lim_ref[0], s, NEG_INF)       # (Q, width)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(kv.dtype), c_kv, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return 0
+
+    jax.lax.fori_loop(0, n_steps, body, 0)
+    l = l_ref[...]
+    o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def mla_decode(q_lat, q_rope, pool, layer, tables, limits, *, scale,
+               q_tile: int = 1):
+    """Absorbed latent attention of layer ``layer`` against the stacked
+    latent pool, read where it lies.
+
+    q_lat (N, nh, rank): each row's queries through ``W_uk``; q_rope
+    (N, nh, rope): their rotated rope parts; pool (L, 1, NB, BS, rank + rope)
+    (padded: :func:`latent_row`) left in HBM whole; ``layer`` a scalar-prefetch operand the DMAs index the
+    pool by. The N rows come in tiles of ``q_tile``: a tile's rows are tokens
+    of ONE sequence, whose table is ``tables[t]`` (N / q_tile, MAXB); row
+    ``n`` may see the first ``limits[n]`` tokens of it (its position + 1; the
+    tile's own tokens are in the pool already). ``q_tile`` 1 is decode (one
+    row a sequence); a larger tile is a prefill chunk's segment, whose latent
+    is streamed once a tile, not once a token. Returns the weighted latent
+    (N, nh, rank) in q_lat's dtype."""
+    N, nh, rank = q_lat.shape
+    rope = q_rope.shape[-1]
+    _, _, _, BS, row = pool.shape
+    if row != sum(latent_row(rank, rope)):
+        raise ValueError(f"pool row {row} is not rank {rank} + rope {rope}, "
+                         "padded to 128 lanes")
+    tiles, Q, kv_blocks = N // q_tile, q_tile * nh, MLA_KV_BLOCKS
+    pad = -tables.shape[1] % kv_blocks
+    if pad:           # a whole number of block groups; the padding is masked
+        tables = jnp.pad(tables, ((0, 0), (0, pad)))
+    lim = limits.astype(jnp.int32)
+    steps = -(-jnp.max(lim.reshape(tiles, q_tile), axis=1) // (kv_blocks * BS))
+    lim_rows = jnp.repeat(lim, nh).reshape(tiles, Q, 1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # layer, tables, steps
+        grid=(tiles,),
+        in_specs=[
+            pl.BlockSpec((1, Q, rank), lambda t, *_: (t, 0, 0)),
+            pl.BlockSpec((1, Q, rope), lambda t, *_: (t, 0, 0)),
+            pl.BlockSpec((1, Q, 1), lambda t, *_: (t, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # the pool stays in HBM
+        ],
+        out_specs=pl.BlockSpec((1, Q, rank), lambda t, *_: (t, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, kv_blocks * BS, row), pool.dtype),  # double buffer
+            pltpu.SemaphoreType.DMA((2, kv_blocks)),
+            pltpu.VMEM((Q, 1), jnp.float32),      # m
+            pltpu.VMEM((Q, 1), jnp.float32),      # l
+            pltpu.VMEM((Q, rank), jnp.float32),   # acc
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_mla_kernel, block_size=BS, rank=rank, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((tiles, Q, rank), q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=_interpret(),
+        name="mla_decode" if q_tile == 1 else "mla_decode_segment",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables, steps.astype(jnp.int32),
+      q_lat.reshape(tiles, Q, rank), q_rope.reshape(tiles, Q, rope), lim_rows,
+      pool)
+    return out.reshape(N, nh, rank)
+
+
+def mla_attend_xla(q_lat, q_rope, pool, layer, tables, limits, *, scale,
+                   q_tile: int = 1):
+    """:func:`mla_decode` by XLA: each tile's latent context gathered once
+    (:func:`gather_context`) and attended to plainly, float32 softmax. The
+    path off the TPU, and the kernel's comparison."""
+    N, nh, rank = q_lat.shape
+    tiles = N // q_tile
+    c_kv, k_rope = gather_context(pool, layer, tables, rank)   # (tiles, T, 1, .)
+    k_rope = k_rope[..., :q_rope.shape[-1]]
+    ql = q_lat.reshape(tiles, q_tile, nh, rank)
+    qr = q_rope.reshape(tiles, q_tile, nh, -1)
+    s = (jnp.einsum("nqhr,ntr->nqht", ql, c_kv[:, :, 0],
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("nqhr,ntr->nqht", qr, k_rope[:, :, 0],
+                      preferred_element_type=jnp.float32)) * scale
+    seen = (jnp.arange(c_kv.shape[1])[None, None, :]
+            < limits.reshape(tiles, q_tile)[:, :, None])
+    p = jax.nn.softmax(jnp.where(seen[:, :, None, :], s, NEG_INF), axis=-1)
+    out = jnp.einsum("nqht,ntr->nqhr", p.astype(q_lat.dtype), c_kv[:, :, 0],
+                     preferred_element_type=jnp.float32)
+    return out.reshape(N, nh, rank).astype(q_lat.dtype)

@@ -258,6 +258,10 @@ _scope_cache: Dict[tuple, Dict[str, str]] = {}
 #: ``jax.named_scope`` names the program uses, by what they classify as
 MODEL_SCOPES = ("embed", "attn", "mlp", "lm_head_loss")
 SERVE_SCOPES = ("kv_write", "paged_attn", "sample")
+#: parts of a layer told apart inside a program without gradients: latent
+#: attention's projections, the expert layer's routing, grouped products and
+#: shared expert
+LAYER_SCOPES = ("mla_proj", "moe_route", "moe_experts", "moe_shared")
 
 
 def _abstract(x):
@@ -284,7 +288,9 @@ def classify(op_name: str) -> str:
     transpose(jvp(attn))/dot_general``): ``optimizer``; ``remat`` (the
     forward recomputed under the backward); ``bwd``; ``fwd`` (the
     differentiated forward); the serving scopes ``kv_write``, ``paged_attn``,
-    ``sample``; ``model`` (a model scope in a program without gradients);
+    ``sample``; a layer's own parts ``mla_proj``, ``moe_route``,
+    ``moe_experts``, ``moe_shared`` and else ``model`` (a model scope in a
+    program without gradients);
     ``kv_carry`` (the paged program's layer scan itself, which carries the
     stacked pool: whatever it does to the pool besides the layers' own
     in-place writes); else ``unscoped``."""
@@ -300,6 +306,9 @@ def classify(op_name: str) -> str:
         return "bwd"
     if "jvp" in parts:
         return "fwd"
+    for s in LAYER_SCOPES:
+        if s in parts:
+            return s
     if any(s in parts for s in MODEL_SCOPES):
         return "model"
     if "kv_carry" in parts:
