@@ -872,9 +872,13 @@ class TransformerLM:
                     if use_kernel:
                         # Pallas paged decode: the kernel streams this layer's
                         # blocks out of the stacked pool by layer index and block
-                        # table — no slice, no gathered copy (paged_attention.py)
+                        # table — no slice, no gathered copy (paged_attention.py).
+                        # A padding row's table names no block (block 0 is the
+                        # trash block no sequence holds): it is marked dead here,
+                        # lens 0, and the kernel fetches nothing for it
+                        lens = jnp.where(tables[:, 0] > 0, positions[:, 0] + 1, 0)
                         attn_out = pa.paged_decode(
-                            q[:, 0], pool, layer, tables, positions[:, 0] + 1)[:, None]
+                            q[:, 0], pool, layer, tables, lens)[:, None]
                     else:
                         gk, gv = pa.gather_context(pool, layer, tables)
                         T = gk.shape[1]

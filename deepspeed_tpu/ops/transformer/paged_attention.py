@@ -22,14 +22,21 @@ configuration gives (``TransformerConfig.kv_row``):
 Every program touches it through the functions here: the model writes whole
 rows at computed row numbers of the pool viewed flat (:func:`write_rows`, in
 place on a donated buffer) and reads either through the kernel
-(:func:`paged_decode`, which streams ``pool[layer, head, block]`` tiles from
+(:func:`paged_decode`, which streams ``pool[layer, heads, block]`` tiles from
 HBM by the scalar-prefetched layer and block table) or through one XLA gather
 with the layer among its indices (:func:`gather_context`); the engine's block
 programs (COW, tier demote/promote, swap) use :func:`get_block` /
 :func:`set_block`. No program slices a layer out of the pool.
 
 The kernel is decode only (one query token per row; a prefill chunk is rows of
-one token each); segments longer than one token keep the gather path.
+one token each); segments longer than one token keep the gather path. A cell
+of its grid is one row of the step over all its kv heads
+(:func:`heads_per_cell`: fewer only where the pool's blocks are too large to
+buffer), so cells number the rows, and a cell works as long as its row's
+``lens`` says. A step's rows are padded to a fixed count; WHO marks a row dead
+is the caller: the model gives ``lens`` 0 to a row whose table names no block
+(block 0 is the trash block), and such a cell fetches nothing and writes zeros.
+The kernel does not look at the table to decide it.
 """
 
 import functools
@@ -141,70 +148,51 @@ def payload_shape(pool, k_width=None):
 # ----------------------------------------------------------------------
 # the kernels
 # ----------------------------------------------------------------------
-def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, block_size, scale, max_blocks):
-    b, j = pl.program_id(0), pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    seq_len = lens_ref[b]
-    # tokens this block holds: positions [j*BS, j*BS + BS) ∩ [0, seq_len)
-    @pl.when(j * block_size < seq_len)
-    def _step():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale  # (g, hd)
-        k = k_ref[0, 0, :, :].astype(jnp.float32)          # (BS, hd)
-        v = v_ref[0, 0, :, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (g, BS)
-        kpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < seq_len, s, NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:, 0] = alpha * l_ref[:, 0] + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
-
-    @pl.when(j == max_blocks - 1)
-    def _finish():
-        l = l_ref[:, 0]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+#: bytes of VMEM the decode kernel's double buffer of pool blocks may take;
+#: the kernel's float32 temporaries are a few times one half of it
+DECODE_BUFFER_BYTES = 2 * 1024 * 1024
 
 
-def _decode_kernel_stream(layer_ref, tables_ref, lens_ref, q_ref, pool_ref,
-                          o_ref, buf, sem, *, block_size, scale):
-    """Grid (B, kvh): ONE cell per (sequence, kv head); the kernel itself
-    streams this sequence's ACTIVE pool blocks from HBM with double-buffered
-    DMA (prefetch j+1 while computing j). Versus the grid-per-block variant
-    this cuts grid cells by MAXB× and does work proportional to each
-    sequence's real length — the serving regime has mostly-short sequences
-    against a long max-context table.
+def heads_per_cell(pool) -> int:
+    """kv heads one cell of :func:`paged_decode` covers, from the pool's
+    shape and dtype alone: all ``kvh`` where the double buffer
+    ``(2, kvh, BS, row)`` fits :data:`DECODE_BUFFER_BYTES`, else the largest
+    divisor of ``kvh`` whose buffer does (at least one)."""
+    _, kvh, _, BS, row = pool.shape
+    fit = DECODE_BUFFER_BYTES // (2 * BS * row * pool.dtype.itemsize)
+    return max(d for d in range(1, kvh + 1) if kvh % d == 0 and d <= max(fit, 1))
+
+
+def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
+                   buf, sem, *, block_size, scale):
+    """Grid (B, kvh / hpc): ONE cell per sequence and group of ``hpc`` kv
+    heads (all of them wherever the buffer fits: :func:`heads_per_cell`). The
+    cell streams this sequence's ACTIVE pool blocks from HBM with
+    double-buffered DMA (prefetch j+1 while computing j) and computes every
+    head of the group from each: cells number the rows, not rows x heads x
+    table slots, and a cell's work follows its row's real length.
 
     The pool is the whole stacked pool as it lies in HBM (see
-    :func:`init_pool`): one DMA fetches block ``pool[layer, h, tables[b, j]]``,
-    a (BS, 2*hd) tile whose rows are ``[k_t | v_t]``; K and V are its lane
-    halves. The row is a multiple of 128 lanes for every supported head size,
-    which is what Mosaic asks of an HBM DMA slice."""
+    :func:`init_pool`): one DMA fetches ``pool[layer, h0:h0+hpc, tables[b, j]]``,
+    ``hpc`` tiles of (BS, 2*hd), each contiguous, whose rows are
+    ``[k_t | v_t]``; K and V are the lane halves. The row is a multiple of
+    128 lanes for every supported head size, which is what Mosaic asks of an
+    HBM DMA slice.
+
+    What ``lens`` says is what the cell does: ``lens[b] == 0`` starts no DMA,
+    runs no trip of the loop and writes zeros."""
     b = pl.program_id(0)
-    h = pl.program_id(1)
     layer = layer_ref[0]
     seq_len = lens_ref[b]
     nblk = (seq_len + block_size - 1) // block_size
-    g = q_ref.shape[2]
-    hd = q_ref.shape[3]
-    q = q_ref[0, 0, :, :].astype(jnp.float32) * scale  # (g, hd)
+    _, hpc, g, hd = q_ref.shape
+    heads = pl.ds(pl.program_id(1) * hpc, hpc)
+    q = q_ref[0].astype(jnp.float32) * scale  # (hpc, g, hd)
 
     def copy(j, slot):
-        return pltpu.make_async_copy(pool_ref.at[layer, h, tables_ref[b, j]],
-                                     buf.at[slot], sem.at[slot])
+        return pltpu.make_async_copy(
+            pool_ref.at[layer, heads, tables_ref[b, j]], buf.at[slot],
+            sem.at[slot])
 
     @pl.when(nblk > 0)
     def _prologue():
@@ -219,28 +207,27 @@ def _decode_kernel_stream(layer_ref, tables_ref, lens_ref, q_ref, pool_ref,
             copy(j + 1, 1 - slot).start()
 
         copy(j, slot).wait()
-        kv = buf[slot].astype(jnp.float32)  # (BS, 2*hd)
-        k, v = kv[:, :hd], kv[:, hd:]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        kv = buf[slot].astype(jnp.float32)  # (hpc, BS, 2*hd)
+        s = jax.lax.dot_general(          # every head of the group: (hpc, g, BS)
+            q, kv[..., :hd], (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
         kpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+            jnp.int32, s.shape, 2)
         s = jnp.where(kpos < seq_len, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot_general(
+            p, kv[..., hd:], (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((g,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g,), jnp.float32)
-    acc0 = jnp.zeros((g, hd), jnp.float32)
+    m0 = jnp.full((hpc, g, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((hpc, g, 1), jnp.float32)
+    acc0 = jnp.zeros((hpc, g, hd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, nblk, body, (m0, l0, acc0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0, :, :] = (acc / l_safe[:, None]).astype(o_ref.dtype)
+    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def paged_decode(q, pool, layer, tables, lens, *, scale=None):
@@ -252,25 +239,31 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None):
     scalar-prefetch operand the kernel's DMAs index the pool by, so no layer
     is ever sliced out; tables: (B, MAXB) int32 pool block ids (0-padded);
     lens: (B,) int32 valid token counts (position + 1). Returns (B, nh, hd)
-    in q's dtype."""
+    in q's dtype.
+
+    A row with ``lens`` 0 is dead: its cell fetches nothing and its output is
+    zeros. The CALLER decides which rows are dead (the model: a row whose
+    table names no block, since block 0 is the trash block no sequence
+    holds); the kernel never reads deadness out of the table, and a row with
+    ``lens`` 1 and an all-zero table attends to the trash block's first
+    token."""
     B, nh, hd = q.shape
     _, kvh, _, BS, _ = pool.shape
-    g = nh // kvh
+    g, hpc = nh // kvh, heads_per_cell(pool)
+    block = pl.BlockSpec((1, hpc, g, hd), lambda b, c, *_: (b, c, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, tables, lens
-        grid=(B, kvh),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, *_: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # the pool stays in HBM
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, *_: (b, h, 0, 0)),
+        grid=(B, kvh // hpc),
+        in_specs=[block,
+                  pl.BlockSpec(memory_space=pl.ANY)],  # the pool stays in HBM
+        out_specs=block,
         scratch_shapes=[
-            pltpu.VMEM((2,) + pool.shape[3:], pool.dtype),  # double buffer
+            pltpu.VMEM((2, hpc) + pool.shape[3:], pool.dtype),  # double buffer
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel_stream, block_size=BS,
+        functools.partial(_decode_kernel, block_size=BS,
                           scale=scale if scale is not None else hd ** -0.5),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kvh, g, hd), q.dtype),
@@ -283,64 +276,19 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None):
     return out.reshape(B, nh, hd)
 
 
-def paged_decode_attention(q, k_pool, v_pool, tables, lens, *, scale=None,
-                           stream: bool = True):
+def paged_decode_attention(q, k_pool, v_pool, tables, lens, *, scale=None):
     """One-token decode attention against ONE layer's blocked K and V pools:
     the kernel's numerical reference entry, not the model's (the model calls
-    :func:`paged_decode` on its stacked pool).
+    :func:`paged_decode` on its stacked pool). K and V are laid side by side
+    as a one-layer stacked pool (a copy, which is why the model does not come
+    this way) and go through the same kernel.
 
     q: (B, nh, hd) — this step's query per sequence.
     k_pool/v_pool: (kvh, NB, BS, hd); tables: (B, MAXB) int32 pool block ids
     (0-padded); lens: (B,) int32 valid token counts (position + 1).
-    Returns (B, nh, hd) in q's dtype.
-
-    ``stream=True`` (default) lays K and V side by side as a one-layer stacked
-    pool (a copy, which is why the model does not come this way) and runs the
-    same kernel as :func:`paged_decode`; ``stream=False`` keeps the
-    (B, kvh, MAXB)-grid variant whose block fetch rides the BlockSpec index
-    map (one grid cell per table slot — simpler, but cell count scales with
-    max context rather than actual lengths).
-    """
-    if stream:
-        pool = jnp.concatenate((k_pool, v_pool), axis=-1)[None]
-        return paged_decode(q, pool, 0, tables, lens, scale=scale)
-    B, nh, hd = q.shape
-    kvh, NB, BS, _ = k_pool.shape
-    MAXB = tables.shape[1]
-    g = nh // kvh
-    qg = q.reshape(B, kvh, g, hd)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # tables, lens
-        grid=(B, kvh, MAXB),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, j, tables, lens: (b, h, 0, 0)),
-            # THE paged trick: each grid step fetches pool block tables[b, j]
-            pl.BlockSpec((1, 1, BS, hd),
-                         lambda b, h, j, tables, lens: (h, tables[b, j], 0, 0)),
-            pl.BlockSpec((1, 1, BS, hd),
-                         lambda b, h, j, tables, lens: (h, tables[b, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda b, h, j, tables, lens: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),   # m
-            pltpu.VMEM((g, 1), jnp.float32),   # l
-            pltpu.VMEM((g, hd), jnp.float32),  # acc
-        ],
-    )
-    scale = scale if scale is not None else hd ** -0.5
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_size=BS, scale=scale,
-                          max_blocks=MAXB),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kvh, g, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-        name="paged_decode_grid",
-    )(tables, lens, qg, k_pool, v_pool)
-    return out.reshape(B, nh, hd)
+    Returns (B, nh, hd) in q's dtype."""
+    pool = jnp.concatenate((k_pool, v_pool), axis=-1)[None]
+    return paged_decode(q, pool, 0, tables, lens, scale=scale)
 
 
 # ----------------------------------------------------------------------

@@ -27,9 +27,8 @@ def oracle(q, kp, vp, tables, lens):
     return out.reshape(B, nh, hd).astype(q.dtype)
 
 
-@pytest.mark.parametrize("stream", [True, False])  # DMA-loop vs grid-per-block
 @pytest.mark.parametrize("kvh,nh", [(4, 4), (2, 8), (1, 8)])  # MHA, GQA, MQA
-def test_paged_decode_matches_oracle(kvh, nh, stream):
+def test_paged_decode_matches_oracle(kvh, nh):
     B, hd, BS, MAXB = 3, 64, 16, 5
     NB = 1 + B * MAXB
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -43,14 +42,15 @@ def test_paged_decode_matches_oracle(kvh, nh, stream):
         for j in range(-(-int(lens[b]) // BS)):
             tables[b, j] = nxt
             nxt += 1
-    out = paged_decode_attention(q, kp, vp, jnp.asarray(tables), lens,
-                                 stream=stream)
+    out = paged_decode_attention(q, kp, vp, jnp.asarray(tables), lens)
     ref = oracle(q, kp, vp, jnp.asarray(tables), lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
 def test_trash_rows_produce_finite_output():
-    """Inactive sequences (all-zero tables, len 0... clamped to 1) stay finite."""
+    """What ``lens`` says is what the kernel does: an all-zero table with a
+    ``lens`` of 1 attends to the trash block's first token, whose value comes
+    back (softmax over one position), finite."""
     B, nh, kvh, hd, BS, MAXB = 2, 4, 4, 64, 16, 3
     NB = 4
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
@@ -61,6 +61,9 @@ def test_trash_rows_produce_finite_output():
     lens = jnp.asarray([1, 1], jnp.int32)
     out = paged_decode_attention(q, kp, vp, tables, lens)
     assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(
+        np.asarray(out), np.broadcast_to(np.asarray(vp)[:, 0, 0], out.shape),
+        atol=1e-6)
 
 
 def test_engine_kernel_path_matches_xla_path(monkeypatch):
@@ -125,35 +128,115 @@ def test_paged_decode_long_context_8k():
 
 
 # ----------------------------------------------------------------------
-# the stacked pool: one array (L, kvh, NB, BS, 2*hd), read by layer index
+# one cell a sequence over its kv heads; a row with lens 0 is dead
 # ----------------------------------------------------------------------
-def _stacked_case(L, kvh, nh, hd, seed=2):
+def plain_attention(q, pool, layer, tables, lens):
+    """``gather_context`` + plain float32 attention: what the kernel must
+    give for every row, and zeros for a row with ``lens`` 0."""
     from deepspeed_tpu.ops.transformer import paged_attention as pa
 
-    B, BS, MAXB = 3, 16, 4
-    NB = 1 + B * MAXB
-    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
-    pool = pa.init_pool(L, kvh, NB, BS, hd, jnp.float32)
-    pool = jax.random.normal(ks[0], pool.shape)
+    gk, gv = pa.gather_context(pool, layer, tables)   # (B, T, kvh, hd)
+    B, T, kvh, hd = gk.shape
+    qg = q.astype(jnp.float32).reshape(B, kvh, -1, hd)
+    s = jnp.einsum("bhgd,bkhd->bhgk", qg, gk.astype(jnp.float32),
+                   precision="highest") * hd ** -0.5
+    seen = jnp.arange(T)[None, None, None] < lens[:, None, None, None]
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), -1) * seen
+    out = jnp.einsum("bhgk,bkhd->bhgd", p, gv.astype(jnp.float32),
+                     precision="highest")
+    return out.reshape(q.shape)
+
+
+# (kvh, nh, hd, BS, heads a cell): MHA, GQA, MQA, a wide head, and a pool
+# whose blocks are too large for one cell to buffer every head's
+SHAPES = [(16, 16, 64, 16, 16), (2, 8, 128, 16, 2), (1, 8, 64, 16, 1),
+          (4, 4, 256, 16, 4), (8, 8, 128, 512, 2)]
+#: tokens of each row by name, given (BS, MAXB): one token, a block boundary
+#: and one past it, the whole table
+LENS = {"mixed": lambda BS, MAXB: [1, BS, BS + 1, MAXB * BS],
+        "full": lambda BS, MAXB: [MAXB * BS, MAXB * BS - 1, 2 * BS, BS - 1]}
+
+
+def _case(kvh, nh, hd, BS, lens, L=2, MAXB=3, dead=0, seed=3):
+    """A stacked float32 pool of random rows, the live rows' tables in a
+    scrambled order, ``dead`` rows of ``lens`` 0 and an all-zero table
+    shuffled among them."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    rng = np.random.default_rng(seed)
+    B = len(lens) + dead
+    NB = 1 + len(lens) * MAXB
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    pool = jax.random.normal(
+        ks[0], pa.init_pool(L, kvh, NB, BS, hd, jnp.float32).shape)
     q = jax.random.normal(ks[1], (B, nh, hd))
-    lens = jnp.asarray([5, 16, 50], jnp.int32)
+    live = np.sort(rng.permutation(B)[:len(lens)])
+    row_lens = np.zeros(B, np.int32)
+    row_lens[live] = lens
     tables = np.zeros((B, MAXB), np.int32)
-    # blocks handed out in a scrambled order: the table, not the block id,
-    # says where a sequence's tokens are
-    ids = iter(np.random.default_rng(seed).permutation(np.arange(1, NB)))
-    for b in range(B):
-        for j in range(-(-int(lens[b]) // BS)):
+    ids = iter(rng.permutation(np.arange(1, NB)))
+    for b in live:
+        for j in range(-(-int(row_lens[b]) // BS)):
             tables[b, j] = next(ids)
-    return pa, pool, q, jnp.asarray(tables), lens
+    return pa, pool, q, jnp.asarray(tables), jnp.asarray(row_lens), live
 
 
+@pytest.mark.parametrize("lens", sorted(LENS))
+@pytest.mark.parametrize("kvh,nh,hd,BS,hpc", SHAPES)
+def test_folded_kernel_matches_gather_plus_plain_attention(kvh, nh, hd, BS,
+                                                           hpc, lens):
+    pa, pool, q, tables, row_lens, _ = _case(kvh, nh, hd, BS,
+                                             LENS[lens](BS, 3))
+    assert pa.heads_per_cell(pool) == hpc
+    out = pa.paged_decode(q, pool, jnp.int32(1), tables, row_lens)
+    ref = plain_attention(q, pool, jnp.int32(1), tables, row_lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+@pytest.mark.parametrize("dtype,hd,BS,hpc", [
+    (jnp.bfloat16, 64, 64, 16),    # gpt2-medium's pool: 2 x 256 KB
+    (jnp.bfloat16, 128, 64, 16),   # Pythia-1.4B's: 2 x 512 KB
+    (jnp.bfloat16, 256, 64, 16),   # the budget, to the byte
+    (jnp.float32, 256, 64, 8), (jnp.bfloat16, 128, 512, 4),
+    (jnp.float32, 256, 1024, 1),   # one head's block alone is over it
+])
+def test_heads_per_cell_follows_the_pools_shape(dtype, hd, BS, hpc):
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    pool = jax.ShapeDtypeStruct((24, 16, 832, BS, 2 * hd), dtype)
+    assert pa.heads_per_cell(pool) == hpc
+
+
+@pytest.mark.parametrize("kvh,nh,hd,BS,hpc", SHAPES[:2] + SHAPES[4:])
+def test_dead_rows_cost_nothing_and_change_nothing(kvh, nh, hd, BS, hpc):
+    """Most rows dead (``lens`` 0, an all-zero table): their outputs are
+    zeros, and the live rows' outputs are bit for bit those of the batch
+    without the dead rows."""
+    pa, pool, q, tables, row_lens, live = _case(
+        kvh, nh, hd, BS, LENS["mixed"](BS, 3)[:3], dead=13)
+    assert (np.asarray(row_lens) == 0).sum() == 13
+    out = np.asarray(pa.paged_decode(q, pool, jnp.int32(1), tables, row_lens))
+    dead = np.setdiff1d(np.arange(len(out)), live)
+    assert not out[dead].any()
+    alone = pa.paged_decode(q[live], pool, jnp.int32(1), tables[live],
+                            row_lens[live])
+    np.testing.assert_array_equal(out[live], np.asarray(alone))
+    np.testing.assert_allclose(
+        out, np.asarray(plain_attention(q, pool, jnp.int32(1), tables,
+                                        row_lens)), atol=3e-5)
+
+
+# ----------------------------------------------------------------------
+# the stacked pool: one array (L, kvh, NB, BS, 2*hd), read by layer index
+# ----------------------------------------------------------------------
 @pytest.mark.parametrize("hd,kvh,nh", [(64, 4, 4), (128, 2, 2), (256, 1, 2),
                                        (64, 2, 8), (128, 1, 4)])
 def test_stacked_kernel_reads_its_layer(hd, kvh, nh):
     """The kernel on the stacked pool at a non-zero layer == the per-layer
     entry on that layer's K and V == the XLA gather path."""
     L, layer = 3, 2
-    pa, pool, q, tables, lens = _stacked_case(L, kvh, nh, hd)
+    pa, pool, q, tables, lens, _ = _case(kvh, nh, hd, 16, [5, 16, 50], L=L,
+                                         MAXB=4)
     out = pa.paged_decode(q, pool, jnp.int32(layer), tables, lens)
     kp, vp = pool[layer, ..., :hd], pool[layer, ..., hd:]
     per_layer = paged_decode_attention(q, kp, vp, tables, lens)
@@ -175,7 +258,7 @@ def test_pool_block_payload_round_trip():
     """get_block / set_block: the (2, L, kvh, BS, hd) payload the tiers,
     swaps and hand-offs keep is K stacked on V whatever the pool's row
     layout, and writing it back touches that block only."""
-    pa, pool, _, _, _ = _stacked_case(2, 2, 4, 64)
+    pa, pool = _case(2, 4, 64, 16, [5, 16, 50], MAXB=4)[:2]
     hd = 64
     blk = pa.get_block(pool, jnp.int32(5))
     assert blk.shape == pa.payload_shape(pool) == (2, 2, 2, 16, hd)
@@ -191,6 +274,22 @@ def test_pool_block_payload_round_trip():
                                   np.asarray(pool)[:, :, keep])
 
 
+def _model_and_pool(L, kvh, hd, BS, NB, MAXB):
+    """A tiny llama with 4 heads of ``hd``, its parameters and a pool of
+    random rows (so that a row nobody wrote is told from one that was)."""
+    import deepspeed_tpu.comm.topology as topo_mod
+    from deepspeed_tpu.models import build_model
+
+    topo_mod.reset_topology()
+    m = build_model("llama-tiny", vocab_size=128, hidden_size=4 * hd,
+                    num_layers=L, num_heads=4, num_kv_heads=kvh,
+                    intermediate_size=128, max_seq_len=MAXB * BS)
+    params = m.init_params(jax.random.PRNGKey(0))
+    before = jax.random.normal(jax.random.PRNGKey(1),
+                               m.init_kv_pool(NB, BS, jnp.float32).shape)
+    return m, params, before
+
+
 @pytest.mark.parametrize("S", [1, 3])  # ragged rows of one token; a segment
 def test_forward_paged_writes_only_its_rows(monkeypatch, S):
     """A three-layer ``forward_paged`` writes exactly the rows of its tokens
@@ -198,17 +297,8 @@ def test_forward_paged_writes_only_its_rows(monkeypatch, S):
     for bit what it was, the trash block excepted. The kernel path and the
     gather path then hold the same pool (to float noise below layer 0, whose
     inputs are each path's own attention output) and give the same logits."""
-    import deepspeed_tpu.comm.topology as topo_mod
-    from deepspeed_tpu.models import build_model
-
-    topo_mod.reset_topology()
     L, kvh, hd, BS, NB, MAXB = 3, 2, 64, 8, 12, 3
-    m = build_model("llama-tiny", vocab_size=128, hidden_size=4 * hd,
-                    num_layers=L, num_heads=4, num_kv_heads=kvh,
-                    intermediate_size=128, max_seq_len=MAXB * BS)
-    params = m.init_params(jax.random.PRNGKey(0))
-    before = jax.random.normal(jax.random.PRNGKey(1),
-                               m.init_kv_pool(NB, BS, jnp.float32).shape)
+    m, params, before = _model_and_pool(L, kvh, hd, BS, NB, MAXB)
     assert before.shape == (L, kvh, NB, BS, 2 * hd)
     # rows: two live sequences and one padding row (all-zero table)
     tables = jnp.asarray([[7, 2, 0], [4, 9, 5], [0, 0, 0]], jnp.int32)
@@ -242,3 +332,67 @@ def test_forward_paged_writes_only_its_rows(monkeypatch, S):
         np.testing.assert_allclose(k[:, :, 1:], a[:, :, 1:], atol=3e-5)
         np.testing.assert_allclose(np.asarray(lg_k)[:2], np.asarray(lg)[:2],
                                    atol=3e-5)
+
+
+def test_forward_paged_marks_padding_rows_dead(monkeypatch):
+    """The model's call: a row whose table is all zero goes to the kernel
+    with ``lens`` 0. Through a three-layer ``forward_paged`` with most rows
+    padding, no block of the pool other than the trash block and the live
+    rows' own tokens differs, and the live rows' logits and pool rows are
+    those of the batch without the padding rows."""
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    BS, NB, MAXB = 8, 12, 3
+    m, params, before = _model_and_pool(3, 2, 64, BS, NB, MAXB)
+    live = np.asarray([2, 9])
+    tables = np.zeros((12, MAXB), np.int32)
+    tables[live] = [[7, 2, 0], [4, 9, 5]]
+    starts = np.zeros(12, np.int32)
+    starts[live] = [6, 17]
+    ids = jax.random.randint(jax.random.PRNGKey(2), (12, 1), 0, 128)
+
+    fwd = jax.jit(m.forward_paged)
+    lg, after = fwd(params, ids, before, jnp.asarray(tables), jnp.asarray(starts))
+    lg_live, after_live = fwd(params, ids[live], before, jnp.asarray(tables[live]),
+                              jnp.asarray(starts[live]))
+    a, b = np.asarray(after), np.asarray(before)
+    written = np.zeros((NB, BS), bool)
+    for r in live:                 # the one token each live row writes
+        written[tables[r, starts[r] // BS], starts[r] % BS] = True
+    same = ~written
+    same[0] = False                # the trash block takes the padding rows' writes
+    np.testing.assert_array_equal(a[:, :, same], b[:, :, same])
+    assert (a[:, :, written] != b[:, :, written]).all(axis=-1).all()
+    np.testing.assert_allclose(a[:, :, 1:], np.asarray(after_live)[:, :, 1:],
+                               atol=3e-5)
+    np.testing.assert_allclose(np.asarray(lg)[live], np.asarray(lg_live),
+                               atol=3e-5)
+    assert np.isfinite(np.asarray(lg)).all()
+
+
+def test_the_models_call_gives_padding_rows_lens_zero(monkeypatch):
+    """What the kernel is told: position + 1 for a row that holds a block,
+    0 for a row whose table is all zero."""
+    import deepspeed_tpu.comm.topology as topo_mod
+    from deepspeed_tpu.models import build_model
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    topo_mod.reset_topology()
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    m = build_model("llama-tiny", vocab_size=128, hidden_size=128,
+                    num_layers=1, num_heads=2, num_kv_heads=2,
+                    intermediate_size=128, max_seq_len=32)
+    params = m.init_params(jax.random.PRNGKey(0))
+    pool = m.init_kv_pool(6, 8, jnp.float32)
+    tables = jnp.asarray([[0, 0], [3, 0], [0, 0], [1, 4]], jnp.int32)
+    starts = jnp.asarray([0, 5, 0, 11], jnp.int32)
+    told = []
+    kernel = pa.paged_decode
+
+    def listen(q, pool, layer, tables, lens, **kw):
+        jax.debug.callback(lambda x: told.append(np.asarray(x)), lens)
+        return kernel(q, pool, layer, tables, lens, **kw)
+
+    monkeypatch.setattr(pa, "paged_decode", listen)
+    jax.block_until_ready(m.forward_paged(
+        params, jnp.zeros((4, 1), jnp.int32), pool, tables, starts))
+    assert told and all(t.tolist() == [0, 6, 0, 12] for t in told)

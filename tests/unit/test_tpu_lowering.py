@@ -75,7 +75,23 @@ class TestMosaicLowering:
             lambda q, k, v: flash_attention(q, k, v, causal=True,
                                             num_kv_groups=4), q, kv, kv)
 
-    def test_paged_decode(self):
+    @pytest.mark.parametrize("hd,blocks", [(64, 832), (128, 416)])
+    def test_paged_decode(self, hd, blocks):
+        """The folded kernel (one cell a sequence over all 16 kv heads) on
+        the stacked pool, at gpt2-medium's and Pythia-1.4B's shapes."""
+        from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+        L, B, nh, BS, MAXB = 24, 64, 16, 64, 16
+        pool = _aval((L, nh, blocks, BS, 2 * hd), jnp.bfloat16)
+        assert pa.heads_per_cell(pool) == nh
+        _export_tpu(
+            pa.paged_decode,
+            _aval((B, nh, hd), jnp.bfloat16), pool, _aval((), jnp.int32),
+            _aval((B, MAXB), jnp.int32), _aval((B,), jnp.int32))
+
+    def test_paged_decode_grouped_queries(self):
+        """Several queries a kv head (``g`` 2), a per-layer K and V through
+        the kernel's reference entry."""
         from deepspeed_tpu.ops.transformer.paged_attention import (
             paged_decode_attention,
         )

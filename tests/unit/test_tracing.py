@@ -354,17 +354,14 @@ def test_flash_kernels_are_named():
     assert kernel_names(jax.grad(loss), q) == ["flash_fwd", "flash_bwd"]
 
 
-@pytest.mark.parametrize("stream, name", [(True, "paged_decode"),
-                                          (False, "paged_decode_grid")])
-def test_paged_kernels_are_named(stream, name):
+def test_paged_kernel_is_named():
     from deepspeed_tpu.ops.transformer.paged_attention import paged_decode_attention
 
     pool = jnp.ones((2, 8, 16, 64))
     tables, lens = jnp.zeros((3, 4), jnp.int32), jnp.ones((3,), jnp.int32)
     assert kernel_names(
-        lambda q: paged_decode_attention(q, pool, pool, tables, lens,
-                                         stream=stream),
-        jnp.ones((3, 2, 64))) == [name]
+        lambda q: paged_decode_attention(q, pool, pool, tables, lens),
+        jnp.ones((3, 2, 64))) == ["paged_decode"]
 
 
 def test_fused_ce_kernels_are_named():
