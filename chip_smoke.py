@@ -6,8 +6,8 @@ hidden 1024, 24 layers, 16 heads of 64, vocab 50304, nothing cut), bf16, with
 random weights made from a seed:
 
 * trainer: ``deepspeed_tpu.initialize`` + ``engine.train_batch`` on the
-  ``bench.py`` job (seq 1024, micro-batch 8, AdamW, clipping 1.0, ZeRO stage 0),
-  one seeded batch repeated, so the loss has to fall;
+  ``gpt2-medium.train-seq1024`` job (seq 1024, micro-batch 8, AdamW, clipping
+  1.0, ZeRO stage 0), one seeded batch repeated, so the loss has to fall;
 * server: paged ``InferenceEngineV2`` under ``ContinuousBatchScheduler``, a
   handful of requests arriving in two waves so prefill chunks and decode rounds
   interleave, one request's greedy tokens checked against a plain full forward.
@@ -78,7 +78,7 @@ def peak_bytes():
 
 
 def _bench_job(micro_bs: int, zero_stage: int, mesh=None) -> dict:
-    """The ``bench.py`` training job."""
+    """The training job of the ``gpt2-medium.train-seq1024`` cell."""
     cfg = {
         "train_micro_batch_size_per_gpu": micro_bs,
         "gradient_accumulation_steps": 1,

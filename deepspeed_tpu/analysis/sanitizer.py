@@ -21,7 +21,7 @@ Violations raise :class:`SanitizerError` (an ``AssertionError`` subclass,
 so it can never be swallowed by the serving loop's typed ``RuntimeError``
 fault handling). With the env var unset everything here is dormant: the
 engine builds the plain cache, and the per-assignment state check is one
-dict lookup that short-circuits — BENCH_SERVE baselines stay within noise.
+dict lookup that short-circuits.
 
 This module imports nothing heavy at import time (no jax, no engine);
 the cache subclass is built lazily on first request so ``serve.request``

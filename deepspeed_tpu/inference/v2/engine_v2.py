@@ -5,21 +5,18 @@ runs prefill+decode of mixed requests in one forward over a ragged batch;
 ``engine_factory.py:67 build_hf_engine``; blocked-KV flash kernels.
 
 TPU re-design (SURVEY.md §7 "hard parts" #1): XLA needs static shapes, so the
-ragged batch becomes **bucketed static shapes**:
+ragged batch becomes **one fixed-shape program**:
 
-- KV cache, two layouts: dense per-sequence slots
-  (L, max_seqs, max_seq_len, kvh, hd), or ``paged=True`` blocked pool
-  (L, kvh, num_blocks, block_size, hd — kv-head-major for the Pallas
-  paged-decode kernel) with per-sequence block tables
-  (reference ``BlockedKVCache``) — total KV memory is shared across
-  sequences, so many short sequences fit where dedicated slots would not;
-  attention runs on the table-gathered logical cache with position masks.
-- prefill: prompts are padded to power-of-two length buckets and processed by a
-  per-bucket compiled program, vmapped over sequences with per-sequence cache
-  offsets (chunked split-fuse: long prompts go through in ``prefill_chunk``
-  pieces so decode latency stays bounded).
-- decode: ONE compiled step for up to ``max_seqs`` sequences (inactive slots
-  masked), each at its own position — the continuous batch.
+- KV cache: one blocked pool (L, kvh, num_blocks, block_size, row — kv-head-major
+  for the Pallas paged-decode kernel; ``ops/transformer/paged_attention.py`` owns
+  the layout) with per-sequence block tables (reference ``BlockedKVCache``) —
+  total KV memory is shared across sequences, so many short sequences fit where
+  dedicated slots would not.
+- every step is ONE compiled ragged forward over ``token_budget`` token-rows:
+  prefill-chunk tokens and decode tokens mixed, each row with its sequence's
+  block table and its own position (long prompts go through in ``prefill_chunk``
+  pieces so decode latency stays bounded); a pure decode round runs the same
+  program at ``max_seqs`` rows.
 
 ``put(uids, tokens)`` matches the reference surface: new sequences join, all
 live sequences advance one token, and per-uid last-token logits come back.
@@ -40,14 +37,7 @@ from ...resilience.errors import (ContextOverflowError, EngineUsageError,
                                   PoolExhaustedError)
 from ...utils.logging import log_dist
 from ..config import DeepSpeedInferenceConfig
-from .ragged_manager import DSStateManager
-
-
-def _bucket(n: int, lo: int = 16) -> int:
-    b = lo
-    while b < n:
-        b *= 2
-    return b
+from .ragged_manager import BlockedKVCache, DSStateManager
 
 
 class DecodeDispatchHandle:
@@ -98,29 +88,30 @@ class InferenceEngineV2:
 
     def __init__(self, model, params=None, *, max_seqs: Optional[int] = None,
                  max_seq_len: Optional[int] = None, prefill_chunk: int = 256,
-                 dtype=jnp.float32, paged: bool = False, block_size: int = 64,
+                 dtype=jnp.float32, paged: bool = True, block_size: int = 64,
                  num_blocks: Optional[int] = None, token_budget: int = 0,
                  prefix_cache: bool = True, decode_horizon: int = 1,
                  host_tier_blocks: int = 0, transfer_overlap: bool = True,
                  nvme_tier_blocks: int = 0,
                  nvme_tier_dir: Optional[str] = None):
+        if not paged:
+            raise ValueError(
+                "paged=False: the slot-pooled KV cache was removed (PR 32); "
+                "InferenceEngineV2 serves from the blocked pool only — drop "
+                "the argument")
         self.model = model
         self.cfg = model.config
-        # default serving width: paged mode shares one block pool so 32 slots
-        # cost little; the slot layout allocates max_seqs × max_ctx dedicated
-        # KV, so its default stays conservative
+        # the sequences share one block pool, so 32 slots cost little
         if max_seqs is None:
-            max_seqs = 32 if paged else 8
+            max_seqs = 32
         self.max_seqs = max_seqs
         self.max_seq_len = max_seq_len or model.config.max_seq_len
         self.prefill_chunk = prefill_chunk
         self.dtype = dtype
-        self.paged = paged
-        # paged mode: every engine step is ONE compiled ragged forward over
+        # every engine step is ONE compiled ragged forward over
         # exactly token_budget token-rows (prefill chunks and decodes mixed —
         # reference engine_v2.py:107 put); the budget is the latency knob.
         # Default: enough rows for a full decode round plus prefill headroom
-        # (bench_serve.py load-tests at 256)
         self.token_budget = token_budget or max(max_seqs, min(prefill_chunk, 256))
         # fused multi-token decode (docs/SERVING.md): the ONE extra horizon
         # the engine may compile besides 1 — horizons are restricted to
@@ -128,9 +119,6 @@ class InferenceEngineV2:
         # one shape (fixed-shape trace discipline, see fused_cache_size)
         if decode_horizon < 1:
             raise ValueError(f"decode_horizon must be >= 1, got {decode_horizon}")
-        if decode_horizon > 1 and not paged:
-            raise ValueError("decode_horizon > 1 is paged-mode only (the "
-                             "fused loop runs over the blocked pool)")
         self.decode_horizon = decode_horizon
         if params is None:
             params = model.init_params(jax.random.PRNGKey(0))
@@ -147,8 +135,7 @@ class InferenceEngineV2:
         self.plan_deferrals = 0
         #: block allocations / COW copies seen by the previous dispatch
         self._count_marks = (0, 0)
-        self._prefill_fns = {}
-        self._decode_fn = None
+        self._ragged_fn = None
         self._cow_fn = None
         self._fused_fn = None
         self._verify_fn = None
@@ -161,7 +148,7 @@ class InferenceEngineV2:
         self._scratch: Dict[Tuple, Tuple[np.ndarray, ...]] = {}
         #: the one un-fetched pipelined dispatch (scratch-reuse contract)
         self._undrained_dispatch: Optional[DecodeDispatchHandle] = None
-        self.prefix_cache = bool(prefix_cache) and paged
+        self.prefix_cache = bool(prefix_cache)
         # host-RAM KV tier (docs/PREFIX_CACHING.md "Two-tier cache"): spill
         # capacity in blocks under the device pool. 0 = single-tier (the
         # pre-tier behavior, byte-identical). Needs the prefix cache: the
@@ -212,73 +199,60 @@ class InferenceEngineV2:
         self._bias_pool = None                        # lazy (max_seqs, V) f32
         self._bias_set_fn = None
         self._bias_zero: Optional[np.ndarray] = None
-        self._k_width, self._seg_tile, self._moe_stats = None, 1, False
-        if paged:
-            # paged-block pool (reference BlockedKVCache): total KV memory is
-            # num_blocks*block_size tokens shared across sequences instead of
-            # max_seqs*max_seq_len dedicated slots
-            from .ragged_manager import BlockedKVCache
-
-            max_blocks_per_seq = -(-self.max_seq_len // block_size)
-            if num_blocks is None:
-                num_blocks = 1 + max_seqs * max_blocks_per_seq  # = slot capacity
-            if sanitize_enabled():
-                # checked mode (docs/ANALYSIS.md): the sanitizing cache
-                # re-verifies refcount conservation, COW exclusivity, and
-                # index↔pool consistency after every allocator op
-                self.block_mgr = checked_cache_cls()(
-                    num_blocks, block_size, max_blocks_per_seq,
-                    prefix_cache=self.prefix_cache,
-                    host_tier_blocks=self.host_tier_blocks,
-                    descs=lambda: self.state.seqs.values())
-            else:
-                self.block_mgr = BlockedKVCache(
-                    num_blocks, block_size, max_blocks_per_seq,
-                    prefix_cache=self.prefix_cache,
-                    host_tier_blocks=self.host_tier_blocks)
-            self.block_mgr.demote_fn = self._demote_block
-            self._bind_nvme_tier()
-            self.kv = model.init_kv_pool(num_blocks, block_size, dtype=dtype)
-            #: where a pool row splits into its key and value parts
-            #: (``TransformerConfig.kv_row``; the block programs' payload
-            #: format follows from it)
-            self._k_width = self.cfg.kv_row[0]
-            #: rows of one chunk-segment tile of the ragged program (1: a
-            #: prefill chunk is one-token rows like any other). A model with
-            #: tiles lays a mixed step out as ``max_seqs`` one-token rows,
-            #: then the chunks' segments, each from a tile boundary
-            self._seg_tile = getattr(model, "segment_tile", 1)
-            #: the ragged program returns (rows, rows_max) of the held
-            #: experts behind its greedy tokens (``engine.dispatch`` attrs)
-            self._moe_stats = self.cfg.holds_experts
-            if self._seg_tile > 1 and (self.token_budget - max_seqs
-                                       < self._seg_tile):
-                raise ValueError(
-                    f"token_budget {self.token_budget} leaves no segment tile "
-                    f"of {self._seg_tile} rows beside the {max_seqs} "
-                    "one-token rows of a mixed step")
-            #: device bytes of one block's K+V across all layers — the unit
-            #: of every tier/swap byte counter and of the scheduler's
-            #: swap-vs-recompute cost model
-            self.block_bytes = int(self.kv.nbytes) // num_blocks
-            log_dist(
-                f"InferenceEngineV2(paged): blocks={num_blocks}x{block_size} "
-                f"seqs<={max_seqs} ctx={self.max_seq_len} chunk={prefill_chunk} "
-                f"token_budget={self.token_budget} "
-                f"decode_horizon={self.decode_horizon} "
-                f"prefix_cache={'on' if self.prefix_cache else 'off'} "
-                f"host_tier_blocks={self.host_tier_blocks}",
-                ranks=[0],
-            )
+        # paged-block pool (reference BlockedKVCache): total KV memory is
+        # num_blocks*block_size tokens shared across sequences instead of
+        # max_seqs*max_seq_len dedicated slots
+        max_blocks_per_seq = -(-self.max_seq_len // block_size)
+        if num_blocks is None:
+            num_blocks = 1 + max_seqs * max_blocks_per_seq  # = slot capacity
+        if sanitize_enabled():
+            # checked mode (docs/ANALYSIS.md): the sanitizing cache
+            # re-verifies refcount conservation, COW exclusivity, and
+            # index↔pool consistency after every allocator op
+            self.block_mgr = checked_cache_cls()(
+                num_blocks, block_size, max_blocks_per_seq,
+                prefix_cache=self.prefix_cache,
+                host_tier_blocks=self.host_tier_blocks,
+                descs=lambda: self.state.seqs.values())
         else:
-            self.block_mgr = None
-            self.block_bytes = 0
-            # slot-pooled KV cache: (L, max_seqs, T, kvh, hd)
-            self.kv = model.init_kv_cache(max_seqs, self.max_seq_len, dtype=dtype)
-            log_dist(
-                f"InferenceEngineV2: slots={max_seqs} ctx={self.max_seq_len} "
-                f"chunk={prefill_chunk}", ranks=[0],
-            )
+            self.block_mgr = BlockedKVCache(
+                num_blocks, block_size, max_blocks_per_seq,
+                prefix_cache=self.prefix_cache,
+                host_tier_blocks=self.host_tier_blocks)
+        self.block_mgr.demote_fn = self._demote_block
+        self._bind_nvme_tier()
+        self.kv = model.init_kv_pool(num_blocks, block_size, dtype=dtype)
+        #: where a pool row splits into its key and value parts
+        #: (``TransformerConfig.kv_row``; the block programs' payload
+        #: format follows from it)
+        self._k_width = self.cfg.kv_row[0]
+        #: rows of one chunk-segment tile of the ragged program (1: a
+        #: prefill chunk is one-token rows like any other). A model with
+        #: tiles lays a mixed step out as ``max_seqs`` one-token rows,
+        #: then the chunks' segments, each from a tile boundary
+        self._seg_tile = getattr(model, "segment_tile", 1)
+        #: the ragged program returns (rows, rows_max) of the held
+        #: experts behind its greedy tokens (``engine.dispatch`` attrs)
+        self._moe_stats = self.cfg.holds_experts
+        if self._seg_tile > 1 and (self.token_budget - max_seqs
+                                   < self._seg_tile):
+            raise ValueError(
+                f"token_budget {self.token_budget} leaves no segment tile "
+                f"of {self._seg_tile} rows beside the {max_seqs} "
+                "one-token rows of a mixed step")
+        #: device bytes of one block's K+V across all layers — the unit
+        #: of every tier/swap byte counter and of the scheduler's
+        #: swap-vs-recompute cost model
+        self.block_bytes = int(self.kv.nbytes) // num_blocks
+        log_dist(
+            f"InferenceEngineV2(paged): blocks={num_blocks}x{block_size} "
+            f"seqs<={max_seqs} ctx={self.max_seq_len} chunk={prefill_chunk} "
+            f"token_budget={self.token_budget} "
+            f"decode_horizon={self.decode_horizon} "
+            f"prefix_cache={'on' if self.prefix_cache else 'off'} "
+            f"host_tier_blocks={self.host_tier_blocks}",
+            ranks=[0],
+        )
 
     def _cast_params(self, params):
         def cast(path, a):
@@ -309,104 +283,40 @@ class InferenceEngineV2:
                 "was computed under the old weights)")
         self.params = self._cast_params(params)
         self.weights_version = version
-        if self.paged:
-            # the prefix content index holds KV computed under the OLD
-            # weights — serving it to post-swap prompts would silently mix
-            # weight versions. flush_cache drops BOTH tiers: a host-tier
-            # survivor would promote stale old-weights KV straight back in.
-            self.block_mgr.flush_cache()
-            # swapped-out victims' KV is old-weights too: drop the payloads
-            # so re-admission replays their prompts under the new weights
-            # (cancelling their open tickets settles the byte ledger)
-            self._drop_swaps()
+        # the prefix content index holds KV computed under the OLD
+        # weights — serving it to post-swap prompts would silently mix
+        # weight versions. flush_cache drops BOTH tiers: a host-tier
+        # survivor would promote stale old-weights KV straight back in.
+        self.block_mgr.flush_cache()
+        # swapped-out victims' KV is old-weights too: drop the payloads
+        # so re-admission replays their prompts under the new weights
+        # (cancelling their open tickets settles the byte ledger)
+        self._drop_swaps()
 
     def prefix_probe(self, tokens) -> int:
         """Read-only placement probe: leading full blocks of ``tokens``
-        present in this engine's prefix content index (0 for slot engines
-        or with the prefix cache off). The router's affinity score."""
-        if not self.paged or not self.prefix_cache:
+        present in this engine's prefix content index (0 with the prefix
+        cache off). The router's affinity score."""
+        if not self.prefix_cache:
             return 0
         return self.block_mgr.probe(tokens)
 
     def set_kv_owner(self, uid: int, owner: str) -> None:
         """Tag ``uid``'s KV blocks with a tenant id so the block manager can
-        bill its cached prefixes against that tenant's quota. No-op on slot
-        engines — there is no shared cache to account."""
-        if self.paged:
-            self.block_mgr.set_seq_owner(uid, owner)
+        bill its cached prefixes against that tenant's quota."""
+        self.block_mgr.set_seq_owner(uid, owner)
 
     def set_kv_quota(self, owner: str, max_blocks) -> None:
         """Cap ``owner``'s at-rest prefix-cache blocks (``None`` lifts the
         cap). The scheduler re-pushes quotas after every rebuild — the fresh
         block manager starts with an empty ledger."""
-        if self.paged:
-            self.block_mgr.set_owner_quota(owner, max_blocks)
+        self.block_mgr.set_owner_quota(owner, max_blocks)
 
     # ------------------------------------------------------------------
     # compiled programs
     # ------------------------------------------------------------------
-    def _get_prefill(self, S: int):
-        """Per-bucket prefill: (n_seq, S) ids at per-seq offsets → last logits."""
-        if S in self._prefill_fns:
-            return self._prefill_fns[S]
-        model = self.model
-
-        def one(params, kv_slot, ids, start, n_valid):
-            # kv_slot: (L, T, kvh, hd) one sequence's cache; returns last VALID logit
-            logits_all, new_kv = model.forward_with_cache_all(
-                params, ids[None], (kv_slot[0][:, None], kv_slot[1][:, None]), start
-            )
-            lg = logits_all[0, jnp.clip(n_valid - 1, 0, S - 1)]
-            return lg, (new_kv[0][:, 0], new_kv[1][:, 0])
-
-        def prefill(params, kv, ids, slots, starts, n_valid):
-            # gather slots, run vmapped, scatter back
-            k, v = kv
-            ks = k[:, slots]  # (L, n, T, kvh, hd)
-            vs = v[:, slots]
-            lg, (nk, nv) = jax.vmap(one, in_axes=(None, ((1, 1)), 0, 0, 0))(
-                params, (ks, vs), ids, starts, n_valid
-            )
-            k = k.at[:, slots].set(nk.transpose(1, 0, 2, 3, 4))
-            v = v.at[:, slots].set(nv.transpose(1, 0, 2, 3, 4))
-            return lg, (k, v)
-
-        fn = audited_jit("engine_v2.prefill", prefill, max_traces=32,
-                         donate_argnums=(1,))
-        self._prefill_fns[S] = fn
-        return fn
-
-    def _get_decode(self):
-        """One decode step for the full slot pool (inactive slots masked)."""
-        if self._decode_fn is not None:
-            return self._decode_fn
-        model = self.model
-
-        def one(params, kv_slot, tok, pos):
-            logits, new_kv = model.forward_with_cache(
-                params, tok[None, None], (kv_slot[0][:, None], kv_slot[1][:, None]), pos
-            )
-            return logits[0], (new_kv[0][:, 0], new_kv[1][:, 0])
-
-        def decode(params, kv, toks, poss, active, greedy):
-            k, v = kv
-            lg, (nk, nv) = jax.vmap(one, in_axes=(None, ((1, 1)), 0, 0))(
-                params, (k, v), toks, poss
-            )
-            mask = active[None, :, None, None, None]
-            k = jnp.where(mask, nk.transpose(1, 0, 2, 3, 4), k)
-            v = jnp.where(mask, nv.transpose(1, 0, 2, 3, 4), v)
-            if greedy:  # ship (B,) token ids, not (B, V) logits
-                return jnp.argmax(lg, axis=-1).astype(jnp.int32), (k, v)
-            return lg, (k, v)
-
-        self._decode_fn = audited_jit("engine_v2.decode", decode,
-                                      max_traces=2, donate_argnums=(1,),
-                                      static_argnums=(5,))
-        return self._decode_fn
-
     def _get_ragged(self):
-        """THE paged-mode program: one fixed-shape ragged forward.
+        """THE serving program: one fixed-shape ragged forward.
 
         Each of the ``token_budget`` rows is one token of some sequence —
         prefill-chunk tokens and decode tokens mixed freely (the reference's
@@ -421,8 +331,8 @@ class InferenceEngineV2:
         mixing greedy and full-logit steps holds both variants of each shape:
         ≤ 4 compiled traces, still O(1) in the load.)
         """
-        if "ragged" in self._prefill_fns:
-            return self._prefill_fns["ragged"]
+        if self._ragged_fn is not None:
+            return self._ragged_fn
         model = self.model
 
         def ragged(params, pool, ids, tables, starts, logit_rows,
@@ -456,7 +366,7 @@ class InferenceEngineV2:
 
         fn = audited_jit("engine_v2.ragged", ragged, max_traces=4,
                          donate_argnums=(1,), static_argnums=(13,))
-        self._prefill_fns["ragged"] = fn
+        self._ragged_fn = fn
         return fn
 
     def lower_ragged(self, rows: int, greedy: bool = True):
@@ -737,8 +647,6 @@ class InferenceEngineV2:
         prefill, no uncommitted speculation, holding blocks). A False here
         is a deferral signal, never an error — the disaggregated pool
         re-checks next step."""
-        if not self.paged:
-            return False
         if uid in self._swaps:
             return True
         d = self.state.seqs.get(uid)
@@ -758,14 +666,12 @@ class InferenceEngineV2:
         drained and exported directly; a live at-rest sequence is gathered
         then flushed. Returns ``None`` — and leaves the engine unchanged,
         except that an unsettleable swap entry is dropped — when export
-        does not apply (non-paged, unknown uid, pending prefill,
+        does not apply (unknown uid, pending prefill,
         uncommitted speculation): the caller falls back to journal replay,
         so like the swap store itself this path is an optimization, never
         a source of truth."""
         from ...runtime.transfer_engine import blocks_crc32
 
-        if not self.paged:
-            return None
         entry = self._swaps.pop(uid, None)
         if entry is not None:
             self._swap_imports.discard(uid)
@@ -818,8 +724,6 @@ class InferenceEngineV2:
         from ...runtime.transfer_engine import (TransferCorruptError,
                                                 blocks_crc32)
 
-        if not self.paged:
-            raise EngineUsageError("import_swap is paged-mode only", uid=uid)
         if uid in self._swaps:
             raise EngineUsageError(
                 f"uid {uid}: double import — already swap-resident here",
@@ -961,10 +865,6 @@ class InferenceEngineV2:
         re-admission re-registers, which is what keeps every replay path's
         sampled continuation bitwise (the keys depend only on seed and
         absolute position, both replay-derived)."""
-        if not self.paged:
-            raise ValueError("set_sampling is paged-mode only (sampled "
-                             "selection rides the ragged/fused/verify "
-                             "programs)")
         if params is None:
             self._sampling.pop(uid, None)
             self._bias_rows.pop(uid, None)
@@ -1048,7 +948,7 @@ class InferenceEngineV2:
         decode-round shape) × two ``greedy`` modes (``greedy`` is a
         static_argnum of the same jit, so each mode holds its own traces).
         A workload using a single greedy mode stays <= 2."""
-        fn = self._prefill_fns.get("ragged")
+        fn = self._ragged_fn
         return 0 if fn is None else fn._cache_size()
 
     def _put_paged(self, out: Dict[int, np.ndarray], greedy: bool = False,
@@ -1285,11 +1185,11 @@ class InferenceEngineV2:
 
         For each uid: if new (or given fresh tokens), the tokens are prefilled
         (chunked); every live sequence then yields its next-token logits.
-        Returns {uid: (V,) numpy logits} — or, with ``greedy=True`` (paged
-        mode), {uid: int token} sampled on-device (argmax), which avoids
+        Returns {uid: (V,) numpy logits} — or, with ``greedy=True``,
+        {uid: int token} sampled on-device (argmax), which avoids
         shipping the full logit rows to the host.
 
-        ``max_steps`` (paged only) bounds the number of compiled dispatches:
+        ``max_steps`` bounds the number of compiled dispatches:
         ``None`` drains every pending token (the monolithic path), ``0``
         registers/extends sequences without dispatching (admission under
         chunked interleaved prefill — the prefix-cache lookup still runs),
@@ -1300,14 +1200,6 @@ class InferenceEngineV2:
         if do_checks and len(batch_uids) > self.state.max_seqs:
             raise EngineUsageError(
                 f"batch of {len(batch_uids)} exceeds {self.state.max_seqs} slots")
-        if greedy and not self.paged:
-            raise ValueError(
-                "put(greedy=True) is paged-mode only (the slot prefill path "
-                "returns logits; decode_step supports greedy in both modes)")
-        if max_steps is not None and not self.paged:
-            raise ValueError(
-                "put(max_steps=...) is paged-mode only (slot prefill has no "
-                "mixed ragged dispatch to bound)")
         # 1. register / extend sequences
         for uid, toks in zip(batch_uids, batch_tokens):
             desc = self.state.get_or_create_sequence(uid)
@@ -1331,50 +1223,8 @@ class InferenceEngineV2:
                         desc.seen_tokens = skipped
 
         out: Dict[int, np.ndarray] = {}
-        if self.paged:
-            # single compiled ragged program over a fixed token budget
-            self._put_paged(out, greedy=greedy, max_steps=max_steps)
-            return out
-        # 2. slot mode: chunked prefill for pending prompt tokens (split-fuse:
-        # bounded chunks, grouped by padded segment length). A sequence near
-        # the end of its slot gets an exact-fit segment (dynamic_update_slice
-        # clamps out-of-range starts, which would silently corrupt the cache).
-        while True:
-            work = [d for d in self.state.seqs.values() if d.in_flight > 0]
-            if not work:
-                break
-            groups: Dict[int, list] = {}
-            for d in work:
-                take = min(self.prefill_chunk, d.in_flight)
-                room = self.max_seq_len - d.seen_tokens
-                if room < take:
-                    raise ContextOverflowError(
-                        f"uid {d.uid}: prompt exceeds slot context "
-                        f"({d.seen_tokens}+{take} > {self.max_seq_len})",
-                        uid=d.uid)
-                seg = min(_bucket(take), room)
-                groups.setdefault(seg, []).append(d)
-            for S, grp in groups.items():
-                ids = np.zeros((len(grp), S), np.int32)
-                starts = np.zeros((len(grp),), np.int32)
-                slots = np.zeros((len(grp),), np.int32)
-                nval = np.zeros((len(grp),), np.int32)
-                for i, d in enumerate(grp):
-                    take = min(S, d.in_flight, self.prefill_chunk)
-                    ids[i, :take] = d.pending[:take]
-                    del d.pending[:take]
-                    starts[i] = d.seen_tokens
-                    slots[i] = d.slot
-                    nval[i] = take
-                    d.seen_tokens += take
-                fn = self._get_prefill(S)
-                lg, self.kv = fn(self.params, self.kv, jnp.asarray(ids),
-                                 jnp.asarray(slots), jnp.asarray(starts),
-                                 jnp.asarray(nval))
-                lg = np.asarray(lg)
-                for i, d in enumerate(grp):
-                    if d.in_flight == 0:  # prompt fully consumed → logits are live
-                        out[d.uid] = lg[i]
+        # single compiled ragged program over a fixed token budget
+        self._put_paged(out, greedy=greedy, max_steps=max_steps)
         return out
 
     def decode_step(self, tokens: Dict[int, int],
@@ -1382,54 +1232,25 @@ class InferenceEngineV2:
         """One continuous-batching decode step: feed each live uid its sampled
         token, get next-token logits for all of them (or, with
         ``greedy=True``, the on-device argmax token per uid)."""
-        if self.paged:
-            # all-or-nothing validation BEFORE any state is touched (matches
-            # slot mode): unknown uids KeyError rather than silently becoming
-            # new sequences; context-full or block-pool-exhausted raises with
-            # nothing enqueued, so the step can be retried verbatim after
-            # freeing capacity (blocks allocated here are used by the step)
-            for uid in tokens:
-                d = self.state.seqs[uid]
-                if d.seen_tokens + d.in_flight >= self.max_seq_len:
-                    raise ContextOverflowError(
-                        f"uid {uid}: context full ({d.seen_tokens} >= "
-                        f"{self.max_seq_len}); flush the sequence or raise "
-                        "max_seq_len", uid=uid)
-            for uid in tokens:
-                d = self.state.seqs[uid]
-                self.block_mgr.ensure(d, d.seen_tokens + d.in_flight + 1)
-            # decode tokens ride the same compiled ragged program as prefill —
-            # mixed arrivals and decodes in one step is the normal case
-            uids = list(tokens)
-            return self.put(uids, [[tokens[u]] for u in uids], greedy=greedy)
-        # per-shape reused scratch (zeroed in place): the slot-mode decode
-        # loop must not pay three fresh np.zeros per generated token
-        toks, poss, active = self._scratch_for(
-            ("decode_slot", self.max_seqs), ((self.max_seqs,),) * 3,
-            dtypes=(np.int32, np.int32, np.bool_))
-        by_slot: Dict[int, int] = {}
-        # validation for EVERY uid first: a raise here must leave all
-        # sequence state untouched (no half-advanced positions)
+        # all-or-nothing validation BEFORE any state is touched: unknown
+        # uids KeyError rather than silently becoming new sequences;
+        # context-full or block-pool-exhausted raises with nothing enqueued,
+        # so the step can be retried verbatim after freeing capacity (blocks
+        # allocated here are used by the step)
         for uid in tokens:
             d = self.state.seqs[uid]
-            if d.seen_tokens >= self.max_seq_len:
+            if d.seen_tokens + d.in_flight >= self.max_seq_len:
                 raise ContextOverflowError(
-                    f"uid {uid}: context full ({d.seen_tokens} >= {self.max_seq_len}); "
-                    "flush the sequence or raise max_seq_len", uid=uid)
-        for uid, tok in tokens.items():
+                    f"uid {uid}: context full ({d.seen_tokens} >= "
+                    f"{self.max_seq_len}); flush the sequence or raise "
+                    "max_seq_len", uid=uid)
+        for uid in tokens:
             d = self.state.seqs[uid]
-            toks[d.slot] = tok
-            poss[d.slot] = d.seen_tokens
-            active[d.slot] = True
-            by_slot[d.slot] = uid
-            d.seen_tokens += 1
-        lg, self.kv = self._get_decode()(
-            self.params, self.kv, jnp.asarray(toks), jnp.asarray(poss),
-            jnp.asarray(active), greedy,
-        )
-        lg = np.asarray(lg)
-        return {uid: (int(lg[slot]) if greedy else lg[slot])
-                for slot, uid in by_slot.items()}
+            self.block_mgr.ensure(d, d.seen_tokens + d.in_flight + 1)
+        # decode tokens ride the same compiled ragged program as prefill —
+        # mixed arrivals and decodes in one step is the normal case
+        uids = list(tokens)
+        return self.put(uids, [[tokens[u]] for u in uids], greedy=greedy)
 
     def decode_multi(self, tokens: Dict[int, int],
                      horizon: int) -> Dict[int, List[int]]:
@@ -1452,8 +1273,6 @@ class InferenceEngineV2:
         never covers discarded overrun tokens. Validation is all-or-nothing:
         a context/pool raise leaves every descriptor intact and the step can
         be retried verbatim."""
-        if not self.paged:
-            raise ValueError("decode_multi is paged-mode only")
         if horizon == 1:
             return {u: [t] for u, t in
                     self.decode_step(tokens, greedy=True).items()}
@@ -1568,8 +1387,6 @@ class InferenceEngineV2:
         context/pool raise leaves every descriptor intact so a faulted step
         retries verbatim. Blocks for the whole horizon are pre-allocated and
         shared blocks are copied-on-write before the segment lands."""
-        if not self.paged:
-            raise ValueError("verify_multi is paged-mode only")
         K = self.decode_horizon
         if K <= 1:
             raise EngineUsageError(
@@ -1674,8 +1491,6 @@ class InferenceEngineV2:
         the previous round's handle must be fetched before this call (the
         scratch-reuse contract — the scheduler's plan stage does exactly
         that, since the fetched tokens ARE the next round's feed)."""
-        if not self.paged:
-            raise ValueError("decode_dispatch is paged-mode only")
         if not tokens:
             raise EngineUsageError("decode_dispatch with an empty feed")
         if self._undrained_dispatch is not None:
@@ -1778,8 +1593,6 @@ class InferenceEngineV2:
         reads are length-masked, so a stale write to a re-used block's
         unread offsets is overwritten before any sequence ever reads it.
         Returns the number of block references released."""
-        if not self.paged:
-            raise ValueError("commit_step is paged-mode only")
         d = self.state.seqs.get(uid)
         if d is None:
             return 0
@@ -1823,8 +1636,6 @@ class InferenceEngineV2:
         consumer that saw them emitted. Such a request raises a typed
         :class:`EngineUsageError` instead of silently clamping at the block
         layer."""
-        if not self.paged:
-            raise ValueError("rollback is paged-mode only")
         d = self.state.seqs.get(uid)
         if d is None:
             return 0
@@ -1854,7 +1665,7 @@ class InferenceEngineV2:
         return freed
 
     def flush(self, uid: int):
-        """Release a sequence's slot and (paged) KV blocks. Explicitly
+        """Release a sequence's slot and KV blocks. Explicitly
         idempotent: flushing an unknown uid is a counted no-op — scheduler
         cancel/preempt/complete races must never double-free blocks (a
         second ``block_mgr.free`` of the same descriptor would corrupt
@@ -1880,8 +1691,7 @@ class InferenceEngineV2:
             log_dist(f"flush({uid}): unknown uid (no-op #{self.flush_noops})",
                      ranks=[0], level=10)  # DEBUG
             return
-        if self.paged:
-            self.block_mgr.free(self.state.seqs[uid])
+        self.block_mgr.free(self.state.seqs[uid])
         self.state.flush_sequence(uid)
 
     def preempt(self, uid: int) -> int:
@@ -1897,14 +1707,14 @@ class InferenceEngineV2:
 
     def _blocks_held(self, uid: int) -> int:
         desc = self.state.seqs.get(uid)
-        return len(desc.blocks) if (desc is not None and self.paged) else 0
+        return len(desc.blocks) if desc is not None else 0
 
     def rebuild(self) -> None:
         """Hot rebuild after engine loss (docs/RESILIENCE.md): replace every
         piece of per-incarnation state — sequence table, block pool
         bookkeeping, device KV pool — with fresh instances of **identical
         geometry**, and keep everything else. The compiled-program caches
-        (`_prefill_fns`/`_decode_fn`/`_fused_fn`/`_verify_fn`/`_cow_fn`)
+        (`_ragged_fn`/`_fused_fn`/`_verify_fn`/`_cow_fn`)
         survive deliberately: same shapes means the new pools re-enter the
         same traced programs, so the ragged/fused/verify bounds hold across
         incarnations with zero recompilation and a rebuild costs one pool
@@ -1932,15 +1742,6 @@ class InferenceEngineV2:
         self._bias_slots.clear()
         self._bias_pool = None
         self.rebuilds += 1
-        if not self.paged:
-            self.kv = self.model.init_kv_cache(self.max_seqs,
-                                               self.max_seq_len,
-                                               dtype=self.dtype)
-            log_dist(f"InferenceEngineV2.rebuild #{self.rebuilds}: slot pool "
-                     f"replaced ({self.max_seqs} slots)", ranks=[0])
-            return
-        from .ragged_manager import BlockedKVCache
-
         old = self.block_mgr
         if sanitize_enabled():
             self.block_mgr = checked_cache_cls()(
@@ -1973,17 +1774,15 @@ class InferenceEngineV2:
 
     # reference ``query``/``can_schedule`` surface
     def query(self) -> Tuple[int, int]:
-        """(free sequence slots, per-sequence token capacity). In paged mode
-        the token capacity is additionally bounded by the free block pool."""
+        """(free sequence slots, per-sequence token capacity): the context
+        length, bounded by the tokens the free block pool can still hold."""
         free_slots = self.state.max_seqs - self.state.n_active
-        if self.paged:
-            return free_slots, min(self.max_seq_len,
-                                   self.block_mgr.free_blocks
-                                   * self.block_mgr.block_size)
-        return free_slots, self.max_seq_len
+        return free_slots, min(self.max_seq_len,
+                               self.block_mgr.free_blocks
+                               * self.block_mgr.block_size)
 
     def prefix_cache_stats(self) -> Dict[str, float]:
-        """Prefix-cache effectiveness counters (paged mode): lookups, hits,
+        """Prefix-cache effectiveness counters: lookups, hits,
         hit_rate, hit_blocks, skipped_prefill_tokens, cow_copies,
         dedup_blocks, evicted_blocks, cached_blocks, free_blocks. Empty when
         the cache is off — dashboards can key on that."""
@@ -2023,11 +1822,9 @@ class InferenceEngineV2:
     def can_schedule(self, n_new: int = 1) -> bool:
         if not self.state.can_allocate(n_new):
             return False
-        if self.paged:
-            # admit only if every new sequence can get one prefill chunk of
-            # blocks (the reference consults KV block availability likewise,
-            # engine_v2.py:184 query / can_schedule:184)
-            per_seq = self.block_mgr.blocks_needed(
-                min(self.prefill_chunk, self.max_seq_len))
-            return self.block_mgr.free_blocks >= n_new * per_seq
-        return True
+        # admit only if every new sequence can get one prefill chunk of
+        # blocks (the reference consults KV block availability likewise,
+        # engine_v2.py:184 query / can_schedule:184)
+        per_seq = self.block_mgr.blocks_needed(
+            min(self.prefill_chunk, self.max_seq_len))
+        return self.block_mgr.free_blocks >= n_new * per_seq

@@ -72,8 +72,8 @@ class SequenceDescriptor:
     slot: int
     seen_tokens: int = 0  # tokens already in the KV cache
     pending: List[int] = field(default_factory=list)  # tokens not yet prefilled
-    blocks: List[int] = field(default_factory=list)  # paged mode: pool block ids
-    history: List[int] = field(default_factory=list)  # paged: tokens in cache order
+    blocks: List[int] = field(default_factory=list)  # pool block ids
+    history: List[int] = field(default_factory=list)  # tokens in cache order
     n_indexed: int = 0  # leading blocks registered in the prefix index
     #: cache positions advanced by the LAST fused/verify dispatch that have
     #: not been committed yet — ``rollback`` may truncate at most this many

@@ -1486,15 +1486,6 @@ class TransformerLM:
         x, (nk, nv) = jax.lax.scan(body, x, (params["blocks"], kv_cache[0], kv_cache[1]))
         return x, (nk, nv)
 
-    def forward_with_cache_all(self, params, input_ids, kv_cache, cache_index,
-                               positions=None):
-        """Run a (possibly length-1) segment against the cache; returns
-        (logits (B,S,V), new_cache). Used by v2 prefill, which reads a
-        per-sequence valid position from the full logits."""
-        x, new_kv = self._trunk_with_cache(params, input_ids, kv_cache,
-                                           cache_index, positions)
-        return self._head(params, x), new_kv
-
     # ------------------------------------------------------------------
     # paged (blocked) KV cache — reference inference/v2 BlockedKVCache path
     # ------------------------------------------------------------------
@@ -1741,8 +1732,9 @@ class TransformerLM:
         return ys
 
     def forward_with_cache(self, params, input_ids, kv_cache, cache_index, positions=None):
-        """Like ``forward_with_cache_all`` but projects only the LAST position
-        (B, V) — the decode/prefill hot path skips the (S, V) logits matmul."""
+        """Run a (possibly length-1) segment against the dense cache and
+        project only the LAST position (B, V) — the decode/prefill hot path
+        skips the (S, V) logits matmul. Returns (logits, new_cache)."""
         x, new_kv = self._trunk_with_cache(params, input_ids, kv_cache,
                                            cache_index, positions)
         return self._head(params, x[:, -1:, :])[:, 0, :], new_kv

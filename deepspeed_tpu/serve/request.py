@@ -6,7 +6,7 @@ A :class:`Request` is the unit of work the scheduler moves through
 with ``PREEMPTED -> QUEUED`` as the eviction edge: a preempted request
 re-enters the queue carrying its already-generated tokens appended to the
 prompt, so re-admission replays the whole committed history through
-``InferenceEngineV2.put`` — and, in paged mode, the block-level prefix cache
+``InferenceEngineV2.put`` — and the block-level prefix cache
 (docs/PREFIX_CACHING.md) maps the full blocks of that history straight back
 into the block table, making preemption cheap.
 

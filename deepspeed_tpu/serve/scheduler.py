@@ -9,7 +9,7 @@ The engine exposes the mechanism (``put`` / ``decode_step`` / ``flush`` /
   aged low-priority request always overtakes a *later-arriving* one — a
   steady stream of VIP traffic cannot starve the tail. Backpressure is a
   bounded queue: ``submit`` raises :class:`QueueFullError` when full.
-- **chunked interleaved prefill** (paged engines, default on): admission
+- **chunked interleaved prefill** (default on): admission
   only *registers* a request's prompt with the engine (the prefix-cache
   lookup runs immediately); the prompt's tokens then ride the per-step
   dispatch in budget-bounded chunks MIXED with the live decode rows — one
@@ -23,7 +23,7 @@ The engine exposes the mechanism (``put`` / ``decode_step`` / ``flush`` /
   blocks — bitwise-lossless under greedy), and rows whose KV blocks cannot
   be allocated are deferred by the engine rather than stalling the batch.
   ``chunked_prefill=False`` restores the monolithic drain-at-admission
-  path (the A/B baseline; slot engines always use it).
+  path (the A/B baseline).
 - **preemption under block-pool pressure**: when ``can_schedule`` fails for
   a higher-priority arrival (or the shared KV block pool runs dry mid-step),
   a victim is selected — lowest priority, then most blocks held, then least
@@ -31,7 +31,7 @@ The engine exposes the mechanism (``put`` / ``decode_step`` / ``flush`` /
   Admission-time eviction additionally requires the arrival to beat the
   victim's admission score, so age shields long-waiting requests.
   Re-admission replays ``prompt + generated`` through ``put``; with the
-  paged engine's prefix cache on, the victim's full blocks are still indexed
+  engine's prefix cache on, the victim's full blocks are still indexed
   (flush parks them in the LRU) so the replay maps them straight back into
   the block table at near-zero cost. Greedy decoding makes the round trip
   bitwise-lossless: the re-admitted request continues with exactly the
@@ -82,8 +82,7 @@ The engine exposes the mechanism (``put`` / ``decode_step`` / ``flush`` /
 - **graceful drain**: :meth:`close` rejects new admits, cancels
   never-admitted queued requests, finishes everything that was started
   (including preempted requests awaiting re-admission), and blocks on
-  outstanding device work before returning — the transfer-guard
-  discipline (``deepspeed_tpu/utils/transfer.py``): never abandon queued
+  outstanding device work before returning: never abandon queued
   transfers. With a watchdog ``drain_budget_s`` the drain is bounded:
   stragglers are cancelled rather than hanging shutdown forever.
 
@@ -95,8 +94,6 @@ fused-horizon program, ``fused_cache_size <= 1``, under any schedule).
 import time
 from collections import deque
 from typing import Callable, Deque, Dict, Iterator, List, Optional
-
-import numpy as np
 
 from ..analysis import sanitizer as _sanitizer
 from ..resilience.breaker import CircuitBreaker
@@ -162,7 +159,7 @@ class ContinuousBatchScheduler:
                  watchdog: Optional[StepWatchdog] = None,
                  sleep: Callable[[float], None] = time.sleep,
                  decode_horizon: Optional[int] = None,
-                 chunked_prefill: Optional[bool] = None,
+                 chunked_prefill: bool = True,
                  proposer: Optional[DraftProposer] = None,
                  journal: Optional[RequestJournal] = None,
                  recovery: Optional[RecoveryPolicy] = None,
@@ -190,17 +187,9 @@ class ContinuousBatchScheduler:
         #: pool routes them to cross-replica replay when survivors exist
         self.replica_id = replica_id
         self.escalate_losses = escalate_losses
-        # chunked interleaved prefill (docs/SERVING.md): the default for
-        # paged engines — admission registers the prompt, its chunks ride
-        # the per-step mixed dispatch. False = monolithic drain at _start
-        # (the A/B baseline). Slot engines have no mixed ragged program to
-        # interleave into, so they always run monolithic.
-        if chunked_prefill is None:
-            chunked_prefill = bool(getattr(engine, "paged", False))
-        elif chunked_prefill and not getattr(engine, "paged", False):
-            raise ValueError(
-                "chunked_prefill=True needs a paged engine (prefill chunks "
-                "interleave into the mixed ragged dispatch)")
+        # chunked interleaved prefill (docs/SERVING.md): admission registers
+        # the prompt, its chunks ride the per-step mixed dispatch. False =
+        # monolithic drain at _start (the A/B baseline).
         self.chunked_prefill = chunked_prefill
         #: fused dispatches run since prefill last progressed — the duty
         #: cycle _effective_horizon uses to trade K against backlog
@@ -235,9 +224,9 @@ class ContinuousBatchScheduler:
         # identical to non-speculative decode.
         self.spec: Optional[SpecPolicy] = None
         if proposer is not None:
-            if not getattr(engine, "paged", False) or self.decode_horizon <= 1:
+            if self.decode_horizon <= 1:
                 raise ValueError(
-                    "speculative decoding needs a paged engine compiled "
+                    "speculative decoding needs an engine compiled "
                     "with decode_horizon > 1 (the verify width K: drafts "
                     "are up to K-1 tokens, verified in one dispatch)")
             self.spec = (proposer if isinstance(proposer, SpecPolicy)
@@ -298,10 +287,6 @@ class ContinuousBatchScheduler:
         # in-flight successor back). ``False`` is the bitwise synchronous
         # twin, the same discipline as ``overlap=False`` on the
         # TransferEngine.
-        if pipelined and not getattr(engine, "paged", False):
-            raise ValueError(
-                "pipelined=True needs a paged engine (the deferred-sync "
-                "decode_dispatch rides the compiled ragged decode round)")
         self.pipelined = pipelined
         #: the one in-flight decode round: a dict with the engine's
         #: DecodeDispatchHandle, the per-uid staleness record
@@ -393,13 +378,6 @@ class ContinuousBatchScheduler:
                     "shed at admission", predicted_s=predicted,
                     remaining_s=remaining)
         if sampling is not None:
-            if sampling.needs_engine and not getattr(self.engine, "paged",
-                                                     False):
-                raise ValueError(
-                    "sampling with temperature / logit-bias / processors "
-                    "requires a paged engine; slot-mode engines only "
-                    "support greedy decoding (stop sequences alone are "
-                    "host-side and allowed)")
             if sampling.logit_bias:
                 vs = getattr(getattr(self.engine, "cfg", None),
                              "vocab_size", None)
@@ -614,12 +592,6 @@ class ContinuousBatchScheduler:
                           slo=getattr(entry, "slo", None))
             req.tokens = list(entry.tokens)
             entry.request = req
-        sp = getattr(req, "sampling", None)
-        if (sp is not None and sp.needs_engine
-                and not getattr(self.engine, "paged", False)):
-            raise ValueError(
-                f"uid {req.uid}: sampled request cannot be adopted by a "
-                f"slot-mode (non-paged) engine")
         if req.uid in self._all and not self._all[req.uid].finished:
             raise ValueError(f"uid {req.uid} is already in flight here")
         if (len(req.prompt) + req.max_new_tokens
@@ -653,7 +625,7 @@ class ContinuousBatchScheduler:
     # ------------------------------------------------------------------
     def _push_tenant_quota(self, tenant: str) -> None:
         """Push one tenant's prefix-cache block quota to the engine (the
-        ``set_kv_quota`` seam — silently absent on slot engines). Called
+        ``set_kv_quota`` seam). Called
         at submit/adopt so tenants registered after construction are still
         enforced before their first block is ever cached."""
         if self.tenancy is None:
@@ -1213,7 +1185,7 @@ class ContinuousBatchScheduler:
 
     def _engine_put(self, uids: List[int], token_lists: List[List[int]],
                     max_steps: Optional[int] = None
-                    ) -> Dict[int, np.ndarray]:
+                    ) -> Dict[int, int]:
         """``engine.put`` with full fault handling.
 
         - pool pressure: on exhaustion, evict a strictly-lower-priority
@@ -1236,9 +1208,8 @@ class ContinuousBatchScheduler:
         while True:
             try:
                 t0 = time.perf_counter()
-                kw = {"max_steps": max_steps} if self.engine.paged else {}
-                out = self.engine.put(uids, token_lists,
-                                      greedy=self.engine.paged, **kw)
+                out = self.engine.put(uids, token_lists, greedy=True,
+                                      max_steps=max_steps)
                 if max_steps != 0:
                     self._observe_engine_ok("prefill",
                                             time.perf_counter() - t0)
@@ -1327,14 +1298,13 @@ class ContinuousBatchScheduler:
                 self.metrics.observe_bias_refresh()
         return finished
 
-    def _absorb(self, out: Dict[int, np.ndarray], now: float) -> None:
+    def _absorb(self, out: Dict[int, int], now: float) -> None:
         for uid, val in out.items():
             req = self._live.get(uid)
             if req is None:  # cancelled between dispatch and absorb
                 self._engine_flush(uid)
                 continue
-            tok = int(val) if self.engine.paged else int(np.argmax(val))
-            if self._emit_token(req, tok, now):
+            if self._emit_token(req, int(val), now):
                 self._finish(req, now)
 
     def _absorb_multi(self, out: Dict[int, List[int]],
@@ -1401,8 +1371,6 @@ class ContinuousBatchScheduler:
     def _prefill_backlog(self) -> int:
         """Pending prompt tokens registered with the engine but not yet
         dispatched (the chunked-prefill backlog)."""
-        if not getattr(self.engine, "paged", False):
-            return 0
         return self.engine.prefill_backlog()
 
     def prefill_backlog_tokens(self) -> int:
@@ -1438,7 +1406,7 @@ class ContinuousBatchScheduler:
         the same duty cycle next step.
         """
         K = self.decode_horizon
-        if K <= 1 or not getattr(self.engine, "paged", False):
+        if K <= 1:
             return 1
         if self._stalled:
             return 1

@@ -48,8 +48,8 @@ DEFAULT_COMPILE_CACHE_DIR = os.path.join(
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for a script; returns its
-    directory. Entry points (``chip_smoke.py``, the benches, the examples)
-    call this before their first compile; library code never does.
+    directory. Entry points (``chip_smoke.py``, ``benchmark/run.py``, the
+    examples) call this before their first compile; library code never does.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX already reads it, nothing is set in
     code. Unset: ``DEFAULT_COMPILE_CACHE_DIR``. The minimum compile time for

@@ -1013,3 +1013,23 @@ def test_repo_gate_via_cli_exit_code():
 
     pkg = os.path.dirname(os.path.abspath(deepspeed_tpu.__file__))
     assert lint_main([pkg, "-q"]) == 0
+
+
+@pytest.mark.parametrize("name", [
+    "bench_serve.py", "bench_configs.py", "scaling_model.py",
+    "BENCH_SERVE.json", "BENCH_TRAIN.json", "SCALING_MODEL.json"])
+def test_docs_cite_no_deleted_bench(name):
+    """The programs and records from before the chip are gone (PR 32): the
+    README and ``docs/`` point at ``benchmark/`` and the tests, never at
+    them. (The histories — CHANGES.md, PERF.md, ROADMAP.md — may.)"""
+    import glob
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    assert not os.path.exists(os.path.join(repo, name))
+    docs = [os.path.join(repo, "README.md")] + sorted(
+        glob.glob(os.path.join(repo, "docs", "*.md")))
+    assert len(docs) > 5
+    citing = [os.path.relpath(p, repo) for p in docs
+              if name in open(p, encoding="utf-8").read()]
+    assert not citing, f"{name} still cited by {citing}"

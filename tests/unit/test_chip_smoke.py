@@ -133,7 +133,7 @@ def test_imports_initialise_no_backend():
     must not take it from the child it starts."""
     proc = _child(
         "import deepspeed_tpu, deepspeed_tpu.serve, deepspeed_tpu.inference.v2\n"
-        "import bench_serve, chip_smoke\n"
+        "import chip_smoke\n"
         "from jax._src import xla_bridge\n"
         "assert not xla_bridge.backends_are_initialized()\n"
         "print('clean')")

@@ -76,13 +76,6 @@ class TestEngineMaxSteps:
         assert out[7] == ref[7]
         assert_trace_bounds(eng)
 
-    def test_max_steps_is_paged_only(self, setup):
-        m, params = setup
-        eng = InferenceEngineV2(m, params, max_seqs=2, max_seq_len=64,
-                                paged=False)
-        with pytest.raises(ValueError, match="paged-mode only"):
-            eng.put([1], [[5, 6, 7]], max_steps=1)
-
 
 class TestInterleaving:
     def test_decode_tokens_between_prefill_chunks(self, setup):
@@ -95,7 +88,7 @@ class TestInterleaving:
         rng = np.random.default_rng(11)
         vt = [0.0]
         sched = ContinuousBatchScheduler(eng, clock=lambda: vt[0])
-        assert sched.chunked_prefill  # paged default
+        assert sched.chunked_prefill  # the default
         a = sched.submit(rng.integers(0, 128, 4).tolist(), max_new_tokens=12)
         while a.state is not RequestState.DECODE or len(a.tokens) < 1:
             sched.step()
@@ -147,15 +140,6 @@ class TestInterleaving:
         assert streams[True] == streams[False]
         assert metrics[True]["chunks"] > 0
         assert metrics[False]["chunks"] == 0  # monolithic path untouched
-
-    def test_chunked_prefill_rejected_on_slot_engine(self, setup):
-        m, params = setup
-        eng = InferenceEngineV2(m, params, max_seqs=2, max_seq_len=64,
-                                paged=False)
-        with pytest.raises(ValueError, match="paged engine"):
-            ContinuousBatchScheduler(eng, chunked_prefill=True)
-        sched = ContinuousBatchScheduler(eng)  # defaults to monolithic
-        assert not sched.chunked_prefill
 
 
 class TestMidPrefillPreemption:

@@ -91,8 +91,6 @@ class TestFusedEngine:
         assert_trace_bounds(eng)
         with pytest.raises(ValueError):
             _engine(m, params, decode_horizon=0)
-        with pytest.raises(ValueError, match="paged"):
-            InferenceEngineV2(m, None, paged=False, decode_horizon=4)
 
     def test_rollback_frees_blocks_and_indexes_only_kept(self, setup):
         """After a fused step, rollback(n) shrinks seen_tokens/history,

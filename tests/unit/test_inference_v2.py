@@ -35,6 +35,19 @@ class TestStateManager:
 
 
 class TestContinuousBatching:
+    def test_default_engine_is_the_pool(self, setup):
+        m, params = setup
+        eng = InferenceEngineV2(m, params)
+        assert eng.max_seqs == 32
+        assert eng.block_mgr.block_size == 64
+        # one context's worth of blocks a sequence, plus the trash block
+        assert eng.block_mgr.num_blocks == 1 + 32 * 2
+
+    def test_slot_mode_is_refused(self, setup):
+        m, params = setup
+        with pytest.raises(ValueError, match="paged=False"):
+            InferenceEngineV2(m, params, paged=False)
+
     def test_staggered_requests_match_oracle(self, setup):
         m, params = setup
         eng = InferenceEngineV2(m, params, max_seqs=4, max_seq_len=64, prefill_chunk=16)
@@ -66,13 +79,18 @@ class TestContinuousBatching:
 
     def test_flush_frees_capacity(self, setup):
         m, params = setup
-        eng = InferenceEngineV2(m, params, max_seqs=2, max_seq_len=32)
+        eng = InferenceEngineV2(m, params, max_seqs=2, max_seq_len=32,
+                                block_size=16)
         eng.put([1, 2], [[3, 4, 5], [6, 7]])
         assert not eng.can_schedule(1)
         eng.flush(1)
         assert eng.can_schedule(1)
-        free, ctx = eng.query()
-        assert free == 1 and ctx == 32
+        free, cap = eng.query()
+        # uid 2 still holds one 16-token block of the pool's four
+        assert free == 1 and cap == 32
+        assert eng.block_mgr.free_blocks == 3
+        eng.flush(2)
+        assert eng.query() == (2, 32) and eng.block_mgr.free_blocks == 4
 
     def test_context_overflow_raises(self, setup):
         m, params = setup
@@ -105,31 +123,26 @@ class TestPagedKV:
                 s = SequenceDescriptor(uid=3, slot=2)
                 mgr.ensure(s, 64)
 
-    def test_paged_matches_slot_engine(self, setup):
-        """Same staggered prefill+decode workload through paged and slot
-        engines produces identical logits (paged gather/scatter is exact)."""
+    def test_paged_matches_full_forward(self, setup):
+        """A staggered prefill+decode workload through the pool produces the
+        full forward's logits at every step (paged gather/scatter is
+        exact)."""
         m, params = setup
         rng = np.random.default_rng(1)
-        prompts = {1: rng.integers(0, 128, (5,)).tolist(),
-                   2: rng.integers(0, 128, (23,)).tolist()}
-
-        def run(paged):
-            eng = InferenceEngineV2(m, params, max_seqs=4, max_seq_len=64,
-                                    prefill_chunk=16, paged=paged, block_size=16)
-            out = eng.put([1, 2], [prompts[1], prompts[2]])
-            hist = [{u: np.asarray(v) for u, v in out.items()}]
-            for _ in range(5):
-                toks = {u: int(np.argmax(out[u])) for u in out}
-                out = eng.decode_step(toks)
-                hist.append({u: np.asarray(v) for u, v in out.items()})
-            return hist
-
-        slot_hist = run(False)
-        paged_hist = run(True)
-        for s, p in zip(slot_hist, paged_hist):
-            assert set(s) == set(p)
-            for u in s:
-                np.testing.assert_allclose(p[u], s[u], atol=2e-4)
+        seqs = {1: rng.integers(0, 128, (5,)).tolist(),
+                2: rng.integers(0, 128, (23,)).tolist()}
+        eng = InferenceEngineV2(m, params, max_seqs=4, max_seq_len=64,
+                                prefill_chunk=16, block_size=16)
+        out = eng.put([1, 2], [seqs[1], seqs[2]])
+        for _ in range(6):
+            assert set(out) == {1, 2}
+            for u in out:
+                ref = m.logits(params, jnp.asarray([seqs[u]], jnp.int32))[0, -1]
+                np.testing.assert_allclose(out[u], np.asarray(ref), atol=2e-4)
+            toks = {u: int(np.argmax(out[u])) for u in out}
+            for u, t in toks.items():
+                seqs[u].append(t)
+            out = eng.decode_step(toks)
 
     def test_paged_block_reuse_after_flush(self, setup):
         m, params = setup
@@ -239,7 +252,7 @@ class TestPagedKV:
 
 def test_greedy_on_device_sampling():
     """greedy=True returns on-device argmax tokens identical to host-side
-    argmax over the logits path, in both paged and slot modes."""
+    argmax over the logits path."""
     from deepspeed_tpu.models import TransformerConfig, TransformerLM
 
     cfg = TransformerConfig(vocab_size=128, hidden_size=32, num_layers=2,
@@ -249,20 +262,15 @@ def test_greedy_on_device_sampling():
     rng = np.random.default_rng(9)
     prompts = {1: rng.integers(0, 128, (9,)).tolist(),
                2: rng.integers(0, 128, (5,)).tolist()}
-    for paged in (True, False):
-        e_lg = InferenceEngineV2(m, params, max_seqs=4, max_seq_len=64,
-                                 prefill_chunk=16, paged=paged, block_size=16,
-                                 token_budget=16 if paged else 0)
-        e_gr = InferenceEngineV2(m, params, max_seqs=4, max_seq_len=64,
-                                 prefill_chunk=16, paged=paged, block_size=16,
-                                 token_budget=16 if paged else 0)
-        out_lg = e_lg.put([1, 2], [prompts[1], prompts[2]])
-        out_gr = e_gr.put([1, 2], [prompts[1], prompts[2]], greedy=paged)
-        for step in range(3):
-            toks = {u: int(np.argmax(v)) for u, v in out_lg.items()}
-            # out_gr holds scalar tokens after a greedy call, logits otherwise
-            toks_gr = {u: (int(v) if np.ndim(v) == 0 else int(np.argmax(v)))
-                       for u, v in out_gr.items()}
-            assert toks == toks_gr, (paged, step, toks, toks_gr)
-            out_lg = e_lg.decode_step(toks)
-            out_gr = e_gr.decode_step(toks, greedy=True)
+    e_lg, e_gr = (InferenceEngineV2(m, params, max_seqs=4, max_seq_len=64,
+                                    prefill_chunk=16, block_size=16,
+                                    token_budget=16) for _ in range(2))
+    out_lg = e_lg.put([1, 2], [prompts[1], prompts[2]])
+    out_gr = e_gr.put([1, 2], [prompts[1], prompts[2]], greedy=True)
+    for step in range(3):
+        toks = {u: int(np.argmax(v)) for u, v in out_lg.items()}
+        assert all(np.ndim(v) == 0 for v in out_gr.values())
+        toks_gr = {u: int(v) for u, v in out_gr.items()}
+        assert toks == toks_gr, (step, toks, toks_gr)
+        out_lg = e_lg.decode_step(toks)
+        out_gr = e_gr.decode_step(toks, greedy=True)

@@ -1,8 +1,8 @@
 """MoE serving through inference v2 (reference
 ``inference/v2/model_implementations/mixtral/`` +
 ``kernels/ragged_ops/{moe_gather,moe_scatter,top_k_gating}``): a routed-FFN
-model decodes through ``InferenceEngineV2`` in both slot and paged modes and
-matches the dense-recompute oracle."""
+model decodes through ``InferenceEngineV2`` and matches the dense-recompute
+oracle."""
 
 import jax
 import jax.numpy as jnp
@@ -61,20 +61,6 @@ class TestMoEServing:
         for u in (1, 2):
             expect = _oracle_continuation(m, params, prompts[u], n_gen + 1)
             assert seqs[u] == expect, f"uid {u} diverged from dense oracle"
-
-    def test_moe_decodes_slot(self, moe_setup):
-        m, params = moe_setup
-        eng = InferenceEngineV2(m, params, max_seqs=2, max_seq_len=64,
-                                prefill_chunk=16)
-        prompt = [3, 14, 15, 92, 6]
-        out = eng.put([7], [prompt])
-        seq = list(prompt)
-        for _ in range(4):
-            tok = int(np.argmax(out[7]))
-            seq.append(tok)
-            out = eng.decode_step({7: tok})
-        seq.append(int(np.argmax(out[7])))
-        assert seq == _oracle_continuation(m, params, prompt, 5)
 
     def test_moe_residual_decodes_paged(self):
         """PR-MoE (use_residual) also serves: the residual dense branch is

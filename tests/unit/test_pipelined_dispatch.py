@@ -84,13 +84,6 @@ def _twin(m, params, prompts, **kw):
 # ---------------------------------------------------------------------------
 
 class TestBitwiseTwins:
-    def test_pipelined_requires_paged(self, setup):
-        m, params = setup
-        eng = InferenceEngineV2(m, params, paged=False, max_seqs=4,
-                                max_seq_len=128)
-        with pytest.raises(ValueError, match="paged"):
-            ContinuousBatchScheduler(eng, pipelined=True)
-
     def test_plain_greedy(self, setup):
         """max_new_tokens finishes are PREDICTED at plan time (never fed to
         the successor round) — no rollback traffic on a plain workload."""

@@ -49,6 +49,18 @@ def _run_solo(m, params, prompt, max_new_tokens, sampling=None):
 
 
 class TestLifecycleAndStreaming:
+    def test_default_constructors_serve_through_chunked_prefill(self, setup):
+        """Engine and scheduler with no mode words are what the harness
+        builds: the pool under chunked interleaved prefill."""
+        m, params = setup
+        sched = ContinuousBatchScheduler(InferenceEngineV2(m, params))
+        assert sched.chunked_prefill is True
+        req = sched.submit(list(range(40)), max_new_tokens=3)
+        sched.run_until_complete()
+        assert req.state is RequestState.DONE and len(req.tokens) == 3
+        assert req.tokens == _run_solo(m, params, list(range(40)), 3)
+        assert sched.metrics.prefill["chunks"] > 0
+
     def test_smoke_submit_stream_drain(self, setup):
         """Tier-1 smoke: two requests end-to-end — callback streaming, pull
         streaming, lifecycle states, metrics, and the monitor fan-in."""
@@ -273,32 +285,3 @@ class TestEngineHooks:
         assert all(eng.block_mgr.refcount(b) == 0 for b in held)
         assert eng.preempt(3) == 0  # unknown uid preempt: 0 blocks, no raise
         assert eng.flush_noops == 3
-
-
-@pytest.mark.slow
-def test_priority_mix_load_mirrors_bench():
-    """Bench-derived (slow): the priority-mix workload from bench_serve.py on
-    a tiny model — overcommitted pool, mixed priorities, Poisson arrivals.
-    Every request must finish, preemption must actually occur, and the
-    fixed-shape bound must hold."""
-    import bench_serve
-
-    m = build_model("llama-tiny", vocab_size=128, hidden_size=64, num_layers=2,
-                    num_heads=4, num_kv_heads=2, intermediate_size=128,
-                    max_seq_len=256)
-    params = m.init_params(jax.random.PRNGKey(0))
-    rng = np.random.default_rng(11)
-    eng = InferenceEngineV2(m, params, paged=True, max_seqs=8, max_seq_len=256,
-                            prefill_chunk=32, block_size=16, token_budget=32,
-                            num_blocks=1 + 8 * 2)  # ~2 blocks/seq: overcommit
-    out = bench_serve.run_load(
-        eng, n_requests=24, arrival_rate=500.0,
-        rng=np.random.default_rng(12), prompt_lo=16, prompt_hi=40,
-        gen_lo=4, gen_hi=8, sync_each_step=True,
-        priorities=rng.integers(0, 3, 24))
-    assert out["preemptions"] > 0
-    assert out["generated_tokens"] > 0 and out["p50_token_ms"] >= 0
-    assert out["ttft_p95_ms"] >= out["ttft_p50_ms"] >= 0
-    assert_trace_bounds(eng)
-    assert not eng.state.seqs
-    eng.block_mgr.check_invariants([])

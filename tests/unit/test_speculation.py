@@ -218,8 +218,6 @@ class TestVerifyEngine:
         eng3.put([3], [[5, 6, 7]], greedy=True)
         with pytest.raises(EngineUsageError, match="decode_horizon"):
             eng3.verify_multi({3: 5}, {})
-        with pytest.raises(ValueError, match="paged"):
-            InferenceEngineV2(m, None, paged=False).verify_multi({}, {})
 
     def test_verify_trace_bound(self, setup):
         """The verification program compiles ONCE: any draft-length mix

@@ -171,6 +171,31 @@ class TestBitwiseParity:
         np.testing.assert_array_equal(l0, l3)
         _assert_params_equal(p0, _final_params(e3))
 
+    @pytest.mark.parametrize("arm", ["stage1", "overlap_off",
+                                     "overlap_on_nvme", "overlap_off_nvme"])
+    def test_offload_arm_matches_stage0_bitwise(self, arm, tmp_path):
+        """The arms of the cpu-offload family the sweep above leaves out:
+        stage 1, and stage 2 with the TransferEngine's overlap off (the
+        synchronous twin) and with the Adam moments on the NVMe tier — each
+        against stage 0, loss curve and final params bitwise."""
+        e0 = _mk_engine(0)
+        l0, p0 = _train(e0), _final_params(e0)
+        if arm == "stage1":
+            e = _mk_engine(1, pin_from=e0)
+        else:
+            off = {"device": "cpu"}
+            if arm.endswith("nvme"):
+                off["nvme_path"] = str(tmp_path)
+            e = _mk_engine(2, pin_from=e0, extra_zero={
+                "offload_optimizer": off,
+                "transfer_overlap": not arm.startswith("overlap_off")})
+            assert e._zero_tier is not None
+        np.testing.assert_array_equal(l0, _train(e))
+        _assert_params_equal(p0, _final_params(e))
+        if arm.endswith("nvme"):
+            c = e._transfer.nvme.counters
+            assert c["saves"] >= 1 and c["loads"] >= 1, c
+
     def test_bf16_stage2_matches_bf16_stage0_bitwise(self):
         e0 = _mk_engine(0, bf16=True)
         e2 = _mk_engine(2, bf16=True, pin_from=e0)

@@ -3,8 +3,7 @@ the HLO byte-count methodology applied to the qwZ/qgZ paths — quantized
 weight gathers and gradient reduction must shrink the measured wire bytes of
 the COMPILED stage-3 step, not just pass trajectory tests."""
 
-import os
-import sys
+import re
 
 import jax
 import jax.numpy as jnp
@@ -14,10 +13,53 @@ import deepspeed_tpu
 from deepspeed_tpu.comm import topology as topo_mod
 from deepspeed_tpu.models import TransformerLM, gpt2_config
 
-REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, REPO)
+DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4,
+               "s8": 1, "u8": 1, "pred": 1, "s64": 8, "u64": 8, "s16": 2,
+               "u16": 2, "f8e4m3fn": 1, "f8e5m2": 1}
 
-from scaling_model import parse_collectives  # noqa: E402  (repo-root module)
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
+               "collective-permute")
+
+
+def parse_collectives(hlo: str, n_devices: int = 8):
+    """Sum OUTPUT bytes per (collective kind, replica-group size) from an HLO
+    text dump. The model is profiled with scan_layers=False so per-layer
+    collectives appear once per layer in the text (a lax.scan would hide
+    L-1 of every in-loop collective from a static count)."""
+    totals = {}
+    counts = {}
+    op_pat = re.compile(r"=\s+(.*?)\s(" + "|".join(COLLECTIVES)
+                        + r")(?:-start|-done)?\(")
+    shape_pat = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
+    for line in hlo.splitlines():
+        m = op_pat.search(line)
+        if not m:
+            continue
+        result_types, kind = m.group(1), m.group(2)
+        if "-done(" in line:  # async pair: count only the -start
+            continue
+        # XLA COMBINES collectives: the result may be a tuple of many
+        # tensors — sum every element's bytes, not just the first
+        size = 0
+        for dt, dims in shape_pat.findall(result_types):
+            if dt not in DTYPE_BYTES:
+                continue
+            s = DTYPE_BYTES[dt]
+            if dims:
+                s *= int(np.prod([int(d) for d in dims.split(",")]))
+            size += s
+        if size == 0:
+            continue
+        gm = re.search(r"replica_groups=\{\{([^}]*)\}", line)
+        if gm:
+            gs = len(gm.group(1).split(","))
+        else:
+            gm = re.search(r"replica_groups=\[(\d+),(\d+)\]", line)
+            gs = int(gm.group(2)) if gm else n_devices
+        key = (kind, gs)
+        totals[key] = totals.get(key, 0) + size
+        counts[key] = counts.get(key, 0) + 1
+    return totals, counts
 
 
 _CACHE = {}
