@@ -31,13 +31,13 @@ class Rule:
 
 #: functions whose bodies are the steady-state serving hot path: one
 #: iteration ≈ one generated token. Host syncs and fresh allocations in
-#: here multiply by tokens/second. (``step``/``_absorb*``/``_decode_once``
+#: here multiply by tokens/second. (``step``/``_absorb*``/``_decode_sync``
 #: are the scheduler's per-token loop; ``_emit_token``/``commit``/
 #: ``record`` are the journal commit path riding inside it — one journal
 #: sync per emitted token; the rest are the engine's.)
 HOT_FUNCTIONS: FrozenSet[str] = frozenset({
     "decode_step", "decode_multi", "verify_multi", "_put_paged",
-    "_decode_once", "_absorb", "_absorb_multi", "_absorb_speculation",
+    "_absorb", "_absorb_multi", "_absorb_speculation",
     "step", "_collect_drafts", "propose",
     "_emit_token", "commit", "record",
     # pipelined dispatch (docs/SERVING.md "Pipelined dispatch"): the

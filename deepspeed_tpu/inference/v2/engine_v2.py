@@ -39,29 +39,39 @@ from ...utils.logging import log_dist
 from ..config import DeepSpeedInferenceConfig
 from .ragged_manager import BlockedKVCache, DSStateManager
 
+#: history placeholder of a position whose token was fed on the device from
+#: an unfetched round; the predecessor's ``fetch`` writes the token in
+FED_ON_DEVICE = -1
+
 
 class DecodeDispatchHandle:
     """One in-flight decode round (docs/SERVING.md pipelined dispatch):
     :meth:`InferenceEngineV2.decode_dispatch` returns this instead of host
-    tokens, deferring the device→host transfer so the caller can plan and
-    dispatch the NEXT round while this one executes — the TransferEngine
-    ticket discipline applied to the step loop. :meth:`fetch` is the drain
-    boundary: it blocks on the device result (the one designed transfer the
-    synchronous path pays inline) and yields ``{uid: int token}``.
+    tokens, deferring the device→host transfer so the caller can dispatch
+    the NEXT round — fed on the device from this one's result rows — before
+    it reads this one. :meth:`fetch` is the drain boundary: it blocks on the
+    device result (the one designed transfer the synchronous path pays
+    inline) and yields ``{uid: int token}``.
 
-    The handle is single-shot state, not a future registry: fetch it before
-    the next ``decode_dispatch`` (the engine's scratch-reuse contract) and
-    exactly once per dispatch."""
+    The handle is single-shot state, not a future registry: at most TWO may
+    be unfetched at once (the engine stages rounds from two alternating
+    scratch sets), and each is fetched exactly once per dispatch."""
 
-    __slots__ = ("uids", "span", "_dev", "_out", "_eng", "_disp")
+    __slots__ = ("uids", "span", "_dev", "_out", "_eng", "_disp", "_set",
+                 "_fed")
 
-    def __init__(self, uids: List[int], dev, eng=None, disp=None):
+    def __init__(self, uids: List[int], dev, eng=None, disp=None,
+                 scratch_set: int = 0):
         self.uids = uids          # row order of the dispatched program
         self.span = 1             # cache positions each row advanced
         self._dev = dev           # device logits/token rows, unfetched
         self._out: Optional[Dict[int, int]] = None
         self._eng = eng           # owner: cleared of this handle at fetch
         self._disp = disp         # the dispatch's span: gets the fetched counts
+        self._set = scratch_set   # which scratch set staged this round
+        #: (history, index, row) of each successor row fed on the device
+        #: from this round: its history entry is written at fetch
+        self._fed: List[Tuple[List[int], int, int]] = []
 
     def fetch(self) -> Dict[int, int]:
         """Block on the in-flight program and return its sampled tokens.
@@ -73,12 +83,17 @@ class DecodeDispatchHandle:
             with tracing.span("engine.fetch"):
                 lg = np.asarray(self._dev)  # dstpu-lint: ignore[DSTPU001]
             self._out = {uid: int(lg[i]) for i, uid in enumerate(self.uids)}
+            for history, at, row in self._fed:
+                # a rollback may have truncated the successor's position
+                if at < len(history) and history[at] == FED_ON_DEVICE:
+                    history[at] = int(lg[row])
+            self._fed = []
             if self._eng is not None and self._disp is not None:
                 self._eng._note_moe_rows(self._disp, lg)
             self._dev = None
         if self._eng is not None:
-            if self._eng._undrained_dispatch is self:
-                self._eng._undrained_dispatch = None
+            if self in self._eng._unfetched:
+                self._eng._unfetched.remove(self)
             self._eng = None
         return self._out
 
@@ -144,10 +159,13 @@ class InferenceEngineV2:
         # decode loop must not pay a fresh allocation per dispatch. Safe to
         # reuse even if jax aliases the host buffer: every step materializes
         # its outputs (np.asarray) before the next step refills the scratch,
-        # so the previous dispatch has fully consumed its inputs.
+        # so the previous dispatch has fully consumed its inputs
+        # (``decode_dispatch`` does not, and alternates between two sets).
         self._scratch: Dict[Tuple, Tuple[np.ndarray, ...]] = {}
-        #: the one un-fetched pipelined dispatch (scratch-reuse contract)
-        self._undrained_dispatch: Optional[DecodeDispatchHandle] = None
+        #: the unfetched ``decode_dispatch`` rounds, oldest first: at most
+        #: two, each staged from its own scratch set
+        self._unfetched: List[DecodeDispatchHandle] = []
+        self._feed_merge_fn = None
         self.prefix_cache = bool(prefix_cache)
         # host-RAM KV tier (docs/PREFIX_CACHING.md "Two-tier cache"): spill
         # capacity in blocks under the device pool. 0 = single-tier (the
@@ -982,7 +1000,8 @@ class InferenceEngineV2:
                      greedy: bool) -> None:
         """One compiled ragged dispatch of :meth:`_put_paged`: build the
         batch, enqueue the program, fetch its one result."""
-        with tracing.span("engine.dispatch", program="ragged") as disp:
+        with tracing.span("engine.dispatch", program="ragged",
+                          ahead=0) as disp:
             with tracing.span("engine.build"):
                 T, plan, finals, feed = self._build_ragged_step(work)
                 self._count_dispatch(disp, T, plan)
@@ -1301,7 +1320,8 @@ class InferenceEngineV2:
         # pre-allocate the WHOLE horizon's blocks before dispatch (positions
         # seen .. seen+K-1); a PoolExhaustedError here leaves seen_tokens/
         # history untouched — allocated blocks are used by the retried step
-        with tracing.span("engine.dispatch", program="fused") as disp:
+        with tracing.span("engine.dispatch", program="fused",
+                          ahead=0) as disp:
             with tracing.span("engine.build"):
                 self._drain_promotions()  # queued tier promotions land first
                 for uid in tokens:
@@ -1413,7 +1433,8 @@ class InferenceEngineV2:
                     f"uid {uid}: verify width {K} exceeds context "
                     f"({d.seen_tokens}+{K} > {self.max_seq_len}); collapse "
                     "to horizon 1 or flush the sequence", uid=uid)
-        with tracing.span("engine.dispatch", program="verify") as disp:
+        with tracing.span("engine.dispatch", program="verify",
+                          ahead=0) as disp:
             with tracing.span("engine.build"):
                 self._drain_promotions()  # queued tier promotions land first
                 for uid in tokens:
@@ -1470,7 +1491,24 @@ class InferenceEngineV2:
             out[d.uid] = [int(t) for t in ys[r, :len(row)]]
         return out
 
-    def decode_dispatch(self, tokens: Dict[int, int]) -> DecodeDispatchHandle:
+    def _get_feed_merge(self):
+        """The run-ahead feed: ``ids`` of a decode round, each row's taken
+        from the preceding round's unfetched result where ``src`` names one
+        of its rows, from the host array otherwise. One tiny program before
+        the ragged one, so that one keeps its signature."""
+        if self._feed_merge_fn is None:
+
+            def feed_merge(prev, ids, src):
+                fed = prev[jnp.maximum(src, 0)].astype(ids.dtype)
+                return jnp.where(src[:, None] >= 0, fed[:, None], ids)
+
+            self._feed_merge_fn = audited_jit("engine_v2.feed_merge",
+                                              feed_merge)
+        return self._feed_merge_fn
+
+    def decode_dispatch(self, tokens: Dict[int, Optional[int]],
+                        prev: Optional[DecodeDispatchHandle] = None
+                        ) -> DecodeDispatchHandle:
         """Dispatch ONE ragged decode round without syncing on its result
         (docs/SERVING.md pipelined dispatch). Semantically the step is
         ``decode_step(tokens, greedy=True)`` — one fed token per live uid,
@@ -1479,31 +1517,48 @@ class InferenceEngineV2:
         enqueued, handing back a :class:`DecodeDispatchHandle` whose
         :meth:`~DecodeDispatchHandle.fetch` is the deferred transfer.
 
+        A uid whose token is ``None`` is fed ON THE DEVICE from its row of
+        ``prev``, the unfetched handle of the round before: the host has not
+        seen that token yet and does not need to (the sampling keys are
+        seed and position). Its history entry is a placeholder until
+        ``prev`` is fetched.
+
         Host bookkeeping advances at dispatch: ``seen_tokens``/``history``
         grow by the fed token and ``uncommitted`` grows by 1 (STACKED — with
-        one step in flight a sequence can carry two provisional tokens), but
+        a round in flight a sequence carries two provisional tokens), but
         NOTHING is registered in the prefix-cache content index:
         :meth:`commit_step` publishes absorbed tokens once the scheduler has
         fetched the round and decided what is kept, so the index never
         covers a position a speculative-absorb rollback could truncate.
 
-        Validation is all-or-nothing (the ``decode_multi`` discipline) and
-        the previous round's handle must be fetched before this call (the
-        scratch-reuse contract — the scheduler's plan stage does exactly
-        that, since the fetched tokens ARE the next round's feed)."""
+        Validation is all-or-nothing (the ``decode_multi`` discipline). Two
+        rounds may be unfetched at once, each staged from its own scratch
+        set; a third dispatch raises."""
         if not tokens:
             raise EngineUsageError("decode_dispatch with an empty feed")
-        if self._undrained_dispatch is not None:
+        if len(self._unfetched) >= 2:
             raise EngineUsageError(
-                "decode_dispatch: the previous round's handle is unfetched "
-                "— drain it first (the ragged scratch arrays are reused "
-                "per round, so a second dispatch would corrupt the "
-                "in-flight feed)")
+                "decode_dispatch: two rounds are already unfetched — fetch "
+                "the older one first (a round is staged from one of two "
+                "scratch sets, and a third would overwrite a feed still in "
+                "flight)")
         if len(tokens) > self.max_seqs:
             raise EngineUsageError(
                 f"batch of {len(tokens)} exceeds {self.max_seqs} slots")
-        for uid in tokens:
+        rows_of_prev: Dict[int, int] = {}
+        if any(t is None for t in tokens.values()):
+            if prev is None or prev._dev is None:
+                raise EngineUsageError(
+                    "decode_dispatch: a token of None is fed from the "
+                    "unfetched handle of the preceding round, and there is "
+                    "none")
+            rows_of_prev = {u: i for i, u in enumerate(prev.uids)}
+        for uid, tok in tokens.items():
             d = self.state.seqs[uid]  # unknown uid: loud KeyError
+            if tok is None and uid not in rows_of_prev:
+                raise EngineUsageError(
+                    f"uid {uid}: fed from the preceding round, which has no "
+                    "row for it", uid=uid)
             if d.in_flight:
                 raise EngineUsageError(
                     f"uid {uid}: {d.in_flight} pending prefill tokens — "
@@ -1513,8 +1568,8 @@ class InferenceEngineV2:
                     f"uid {uid}: context full ({d.seen_tokens} >= "
                     f"{self.max_seq_len}); flush the sequence or raise "
                     "max_seq_len", uid=uid)
-        with tracing.span("engine.dispatch", program="ragged",
-                          deferred=True) as disp:
+        with tracing.span("engine.dispatch", program="ragged", deferred=True,
+                          ahead=int(bool(self._unfetched))) as disp:
             with tracing.span("engine.build"):
                 self._drain_promotions()  # queued tier promotions land first
                 for uid in tokens:
@@ -1537,21 +1592,33 @@ class InferenceEngineV2:
                 T = (self.max_seqs if self.token_budget > self.max_seqs
                      else self.token_budget)
                 M = self.max_seqs
+                # the set no unfetched round was staged from
+                scratch_set = (1 - self._unfetched[0]._set
+                               if self._unfetched else 0)
                 (ids, tables, starts, logit_rows, slots, seeds, poss, top_ks,
-                 temps, top_ps) = self._scratch_for(
-                    ("ragged", T),
+                 temps, top_ps, src_rows) = self._scratch_for(
+                    ("dispatch", T, scratch_set),
                     ((T, 1), (T, self.block_mgr.max_blocks_per_seq), (T,),
-                     (M,), (M,), (M,), (M,), (M,), (M,), (M,)),
-                    dtypes=(np.int32,) * 8 + (np.float32, np.float32))
+                     (M,), (M,), (M,), (M,), (M,), (M,), (M,), (T,)),
+                    dtypes=(np.int32,) * 8 + (np.float32, np.float32,
+                                              np.int32))
+                src_rows.fill(-1)
                 for r, d in enumerate(descs):
-                    tok = int(tokens[d.uid])
-                    ids[r, 0] = tok
+                    tok = tokens[d.uid]
+                    if tok is None:
+                        src_rows[r] = rows_of_prev[d.uid]
+                        tok = FED_ON_DEVICE
+                    else:
+                        ids[r, 0] = tok = int(tok)
                     self.block_mgr.fill_table_row(d, tables[r])  # in place, no temp
                     starts[r] = d.seen_tokens
                     logit_rows[r] = r  # every row is a final: one token per uid
                     self._fill_sampling(d, r, slots, seeds, temps, top_ks, top_ps,
                                         poss=poss, pos=d.seen_tokens + 1)
                     if self.prefix_cache:
+                        if tok == FED_ON_DEVICE:
+                            prev._fed.append((d.history, len(d.history),
+                                              rows_of_prev[d.uid]))
                         d.history.append(tok)
                     d.seen_tokens += 1
                     d.uncommitted += 1  # stacked: commit_step settles per absorb
@@ -1560,12 +1627,19 @@ class InferenceEngineV2:
             with tracing.span("engine.enqueue"):
                 # the whole feed rides ONE batched host→device staging call:
                 # at K=1 the per-call Python dispatch overhead of ten separate
-                # small transfers is itself a large slice of the host-bound
-                # round, and the dispatch stage exists to get off the device's
-                # critical path
-                args = (self.params, self.kv, *jax.device_put(
-                    (ids, tables, starts, logit_rows, slots, seeds, poss,
-                     temps, top_ks, top_ps)), self._bias(), True)
+                # small transfers is itself a large slice of the host's share
+                # of a round
+                feed = (ids, tables, starts, logit_rows, slots, seeds, poss,
+                        temps, top_ks, top_ps)
+                if rows_of_prev:
+                    ids_dev, *rest, src_dev = jax.device_put(
+                        feed + (src_rows,))
+                    ids_dev = self._get_feed_merge()(prev._dev, ids_dev,
+                                                     src_dev)
+                else:
+                    ids_dev, *rest = jax.device_put(feed)
+                args = (self.params, self.kv, ids_dev, *rest, self._bias(),
+                        True)
                 if disp.recording:
                     tracing.note_program("engine_v2.ragged", fn, args,
                                          key=(T, True))
@@ -1573,8 +1647,8 @@ class InferenceEngineV2:
         # no np.asarray and no register here — both are deferred: the
         # transfer to fetch(), the prefix-index publish to commit_step()
         handle = DecodeDispatchHandle([d.uid for d in descs], lg, eng=self,
-                                      disp=disp)
-        self._undrained_dispatch = handle
+                                      disp=disp, scratch_set=scratch_set)
+        self._unfetched.append(handle)
         return handle
 
     def commit_step(self, uid: int, drop: int = 0, retain: int = 0) -> int:
@@ -1731,7 +1805,7 @@ class InferenceEngineV2:
         self.state = DSStateManager(self.max_seqs, self.max_seq_len)
         # an in-flight dispatch died with the device: its handle can never
         # be fetched against the new incarnation
-        self._undrained_dispatch = None
+        self._unfetched = []
         self.transfer.cancel_all()
         self._drop_swaps()  # counts any orphaned handoff imports
         # sampling state is per-residency (slot bindings died with the state

@@ -89,18 +89,20 @@ class ServeMetrics:
             "acceptance_rate": 0.0, "draft_horizon": 0.0}
         #: pipelined-dispatch counters (docs/SERVING.md "Pipelined
         #: dispatch"), exported under ``serve/pipeline/*``: ``dispatches``
-        #: deferred-sync decode rounds put in flight, ``in_flight`` the
+        #: deferred-sync decode rounds put in flight, ``ahead_dispatches``
+        #: those enqueued while the round before was unfetched (the rest
+        #: restarted a dry pipe), ``in_flight`` the
         #: end-of-step in-flight row count (gauge — 0 whenever the pipe is
         #: drained), ``speculative_rollbacks`` in-flight successor positions
         #: dropped at absorb because the late token finished the request
-        #: (stop-sequence overrun), ``pipeline_stalls`` rounds that had to
+        #: (EOS or stop-sequence overrun), ``pipeline_stalls`` rounds that had to
         #: drain and fall back to the synchronous twin (fused/spec horizon,
         #: prefill backlog, dynamic sampling, admission stall), and the
         #: stage-timing split gauges ``host_plan_ms`` / ``device_wait_ms``
         #: / ``absorb_ms`` of the latest absorbed round — the one number
         #: ``observe_step`` used to conflate.
         self.pipeline: Dict[str, float] = {
-            "dispatches": 0, "in_flight": 0.0,
+            "dispatches": 0, "ahead_dispatches": 0, "in_flight": 0.0,
             "speculative_rollbacks": 0, "pipeline_stalls": 0,
             "host_plan_ms": 0.0, "device_wait_ms": 0.0, "absorb_ms": 0.0}
         #: multi-tenant QoS counters (docs/SERVING.md "Multi-tenant QoS"),
@@ -194,9 +196,12 @@ class ServeMetrics:
         if absorb_s is not None:
             self.pipeline["absorb_ms"] = round(absorb_s * 1000, 3)
 
-    def observe_pipeline_dispatch(self, batch: int) -> None:
-        """One deferred-sync decode round put in flight (``batch`` rows)."""
+    def observe_pipeline_dispatch(self, batch: int,
+                                  ahead: bool = False) -> None:
+        """One deferred-sync decode round put in flight (``batch`` rows);
+        ``ahead`` when the round before it was still unfetched."""
         self.pipeline["dispatches"] += 1
+        self.pipeline["ahead_dispatches"] += bool(ahead)
         self.pipeline["in_flight"] = float(batch)
 
     def observe_pipeline_in_flight(self, batch: int) -> None:

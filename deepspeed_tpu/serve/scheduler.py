@@ -167,7 +167,7 @@ class ContinuousBatchScheduler:
                  escalate_losses: bool = False,
                  swap_preemption: Optional[bool] = None,
                  deadline_guard: bool = False,
-                 pipelined: bool = False,
+                 pipelined: bool = True,
                  tenancy: Optional[TenantRegistry] = None):
         self.engine = engine
         #: multi-tenant QoS (docs/SERVING.md "Multi-tenant QoS"): when a
@@ -280,22 +280,24 @@ class ContinuousBatchScheduler:
         #: an admitted request's prefill hit pool exhaustion; its pending
         #: tokens sit inside the engine and must drain before it decodes
         self._stalled = False
-        # pipelined dispatch (docs/SERVING.md "Pipelined dispatch"): with
-        # ``pipelined=True`` the decode loop keeps ONE step in flight —
-        # plan/dispatch round N+1 while N executes on device, absorb N's
-        # tokens one step late (speculative: late stop detections roll the
-        # in-flight successor back). ``False`` is the bitwise synchronous
-        # twin, the same discipline as ``overlap=False`` on the
-        # TransferEngine.
+        # pipelined dispatch (docs/SERVING.md "Pipelined dispatch"): the
+        # decode loop runs ONE round ahead of what the host knows — round
+        # N+1 is planned and enqueued, fed on the device from N's result,
+        # before N is fetched; N's tokens are absorbed one step late
+        # (speculative: a finish the plan could not see rolls the in-flight
+        # successor back). ``False`` is the bitwise synchronous twin, the
+        # same discipline as ``overlap=False`` on the TransferEngine.
         self.pipelined = pipelined
-        #: the one in-flight decode round: a dict with the engine's
+        #: the newest in-flight decode round: a dict with the engine's
         #: DecodeDispatchHandle, the per-uid staleness record
-        #: ``{uid: (req, desc, emitted_len)}``, and dispatch timing.
+        #: ``{uid: (req, desc, emitted_len)}``, and when it was enqueued.
         #: None = the pipe is dry.
         self._inflight: Optional[Dict[str, object]] = None
-        #: absorb work staged by step_dispatch for step_absorb (the pool's
-        #: two-phase drive): (prev record, fetched tokens, timing)
+        #: the round before it, still unfetched, staged by step_dispatch
+        #: for step_absorb: (its record, the plan's timing)
         self._pending_absorb: Optional[Dict[str, object]] = None
+        #: when the last round was fetched (tracing.clock_ns)
+        self._fetched_ns = 0
         #: engine dispatches so far: a traced request's ``req.prefill`` event
         #: says how many its prompt rode (docs/TRACING.md)
         self._dispatches = 0
@@ -1446,22 +1448,6 @@ class ContinuousBatchScheduler:
             lambda uid: self._live[uid].prompt + self._live[uid].tokens,
             self.decode_horizon - 1)
 
-    def _decode_once(self, now: float) -> None:
-        """One decode iteration. ``pipelined=False``: the synchronous loop —
-        plan, dispatch, wait, absorb, all in this call
-        (:meth:`_decode_sync`). ``pipelined=True``: the plan/dispatch/absorb
-        stages run with ONE step in flight — this call fetches the previous
-        round, plans and dispatches the next from its tokens, and only then
-        absorbs the fetched round (:meth:`_pipeline_dispatch_stage` +
-        :meth:`_pipeline_absorb_stage`), so the device executes round N+1
-        through the whole host phase of round N."""
-        if self.pipelined:
-            staged = self._pipeline_dispatch_stage(now)
-            if staged is not None:
-                self._pipeline_absorb_stage(staged, now)
-            return
-        self._decode_sync(now)
-
     def _decode_sync(self, now: float) -> None:
         """One engine dispatch: the live decode feed plus — under chunked
         interleaved prefill — as many pending prefill-chunk rows as the
@@ -1627,13 +1613,15 @@ class ContinuousBatchScheduler:
 
     def _pipeline_dispatch_stage(self, now: float
                                  ) -> Optional[Dict[str, object]]:
-        """PLAN + DISPATCH with one step in flight. Fetches the previous
-        round's tokens (the deferred host sync — by now the device had the
-        whole intervening host phase to run), plans the next feed from
-        them, dispatches it, and returns the fetched round staged for
-        :meth:`_pipeline_absorb_stage` — which runs while the new dispatch
-        executes. Returns None when the round took the synchronous path
-        (pipeline barrier) or there was nothing to fetch."""
+        """PLAN + DISPATCH, one round ahead of what the host knows. Round
+        N+1 is planned from what can be known WITHOUT round N's tokens and
+        enqueued while N is still unfetched: a row riding N is fed again, on
+        the device, from N's result row, unless ``max_new_tokens`` ends it
+        (decidable by count). Returns round N staged for
+        :meth:`_pipeline_absorb_stage`, which fetches it — by then the
+        device has N+1 queued behind it — and absorbs it. Returns None when
+        the round took the synchronous path (pipeline barrier) or there is
+        nothing to fetch."""
         # the stage gauges and the spans share their clock readings
         with tracing.timed_span("sched.plan") as plan:
             backlog = self._prefill_backlog() if self.chunked_prefill else 0
@@ -1651,48 +1639,28 @@ class ContinuousBatchScheduler:
                 if d is not None and d.in_flight == 0:
                     cands[uid] = r.tokens[-1]
             barrier = self._pipeline_barrier(now, cands, backlog)
-            if not barrier:
-                prev = self._inflight
-                raw: Optional[Dict[int, int]] = None
-                wait_dt = 0.0
-                if prev is not None:
-                    # the device wait, inside the plan span: the plan gauge is
-                    # the plan span's self time
-                    with tracing.timed_span("sched.wait") as wait:
-                        try:
-                            raw = prev["handle"].fetch()
-                        except UnrecoverableEngineError:
-                            # the round died with the device: nothing of it was
-                            # absorbed, so journal replay regenerates its tokens
-                            # bitwise from the last committed state
-                            self._inflight = None
-                            raise
-                    wait_dt = wait.seconds
-                if cands or prev is not None:
-                    # plan the next feed. Rows riding the fetched round are fed their
-                    # brand-new token; predicted finishes (EOS / max_new_tokens —
-                    # decidable from the raw token alone) are NOT fed. Stop-sequence
-                    # finishes are NOT predicted (the scan is stateful): those rows
-                    # are fed speculatively and the successor token rolled back at
-                    # absorb — the speculative-absorb rule.
-                    next_feed: Dict[int, int] = {}
-                    for uid, last_tok in cands.items():
-                        r = self._live[uid]
-                        if prev is not None and raw is not None and uid in prev["rows"]:
-                            rec_req, rec_desc, rec_emitted = prev["rows"][uid]
-                            if (r is rec_req and len(r.tokens) == rec_emitted
-                                    and self.engine.state.seqs.get(uid) is rec_desc):
-                                tok = raw[uid]
-                                if (len(r.tokens) + 1 >= r.max_new_tokens
-                                        or (r.eos_token is not None
-                                            and tok == r.eos_token)):
-                                    continue  # finishes at absorb: never fed
-                                next_feed[uid] = tok
-                                continue
-                            # stale row (preempted/re-admitted since dispatch): its
-                            # in-flight token is discarded at absorb; feeding the
-                            # committed last token regenerates it bitwise
-                        next_feed[uid] = last_tok
+            prev = self._inflight
+            if not barrier and (cands or prev is not None):
+                # plan the next feed. A row riding the unfetched round is fed
+                # that round's token where it lies, on the device (None). A
+                # finish by max_new_tokens is decidable by count: not fed. EOS
+                # and stop sequences are not predictable without the token:
+                # those rows are fed speculatively and the successor position
+                # rolled back at absorb — the speculative-absorb rule.
+                next_feed: Dict[int, Optional[int]] = {}
+                for uid, last_tok in cands.items():
+                    r = self._live[uid]
+                    if prev is not None and uid in prev["rows"]:
+                        rec_req, rec_desc, rec_emitted = prev["rows"][uid]
+                        if (r is rec_req and len(r.tokens) == rec_emitted
+                                and self.engine.state.seqs.get(uid) is rec_desc):
+                            if len(r.tokens) + 1 < r.max_new_tokens:
+                                next_feed[uid] = None
+                            continue  # else it finishes at absorb: never fed
+                        # stale row (preempted/re-admitted since dispatch): its
+                        # in-flight token is discarded at absorb; feeding the
+                        # committed last token regenerates it bitwise
+                    next_feed[uid] = last_tok
         if barrier:
             if self._inflight is not None:
                 self.metrics.observe_pipeline_stall()
@@ -1701,18 +1669,17 @@ class ContinuousBatchScheduler:
             return None
         if not cands and prev is None:
             return None
-        plan_dt = plan.seconds - wait_dt
         handle = None
-        enqueue_dt = 0.0
         if next_feed:
+            unfetched = prev["handle"] if prev is not None else None
             attempt = 0
             while True:
                 disp = tracing.timed_span("sched.dispatch", kind="decode",
                                           rows=len(next_feed))
                 try:
                     with disp:
-                        handle = self.engine.decode_dispatch(next_feed)
-                    enqueue_dt = disp.seconds
+                        handle = self.engine.decode_dispatch(
+                            next_feed, prev=unfetched)
                     self._dispatches += 1
                     break
                 except TransientEngineError as e:
@@ -1723,12 +1690,12 @@ class ContinuousBatchScheduler:
                     if e.uid is None or e.uid not in self._all:
                         raise
                     self._contain(e.uid, e, now)
-                    break  # absorb the fetched round below (stale rows skip)
+                    break  # absorb the round in flight below (stale rows skip)
                 except PoolExhaustedError:
                     if not self.preemption:
                         raise
                     if prev is not None:
-                        # fed rows still carry the fetched round's
+                        # fed rows still carry the unfetched round's
                         # provisional position, so swap_out would decline
                         # every victim: let the pipe run dry, absorb (and
                         # commit) below, and re-plan next step against
@@ -1749,43 +1716,57 @@ class ContinuousBatchScheduler:
                                self.engine.state.seqs.get(uid),
                                len(self._live[uid].tokens))
                          for uid in handle.uids},
-                "enqueue_dt": enqueue_dt,
+                "enqueued_ns": disp.start,
             }
-            self.metrics.observe_pipeline_dispatch(len(handle.uids))
+            self.metrics.observe_pipeline_dispatch(len(handle.uids),
+                                                   ahead=prev is not None)
         else:
             self._inflight = None
             if next_feed:
                 self.metrics.observe_pipeline_stall()  # pipe ran dry
-        if prev is None or raw is None:
+        if prev is None:
             return None
-        return {"prev": prev, "raw": raw, "wait_dt": wait_dt,
-                "plan_dt": plan_dt}
+        return {"prev": prev, "plan_dt": plan.seconds}
 
     def _pipeline_absorb_stage(self, staged: Dict[str, object],
                                now: float) -> None:
-        """ABSORB one fetched round — one step late. Runs while the
-        successor dispatch executes on device. Per row: emit the token
-        (the journal's one commit point — in-flight tokens are never
-        journaled), then settle the engine's provisional positions via
-        ``commit_step``: a surviving row retains its successor's in-flight
-        position; a finishing row detected HERE (a stop sequence — the
-        speculative miss) drops the successor position it was speculatively
-        fed, counted as a speculative rollback; stale rows (preempted /
-        re-admitted / cancelled since dispatch) are skipped — their tokens
-        regenerate bitwise from committed state on replay."""
-        prev, raw = staged["prev"], staged["raw"]
+        """FETCH + ABSORB one round — one step late, with its successor
+        already queued on the device behind it. The fetch is the device
+        wait. Then per row: emit the token (the journal's one commit point —
+        in-flight tokens are never journaled), and settle the engine's
+        provisional positions via ``commit_step``: a surviving row retains
+        its successor's in-flight position; a row that finishes HERE on a
+        token the plan could not see (EOS, a stop sequence — the speculative
+        miss) drops the successor position it was speculatively fed, counted
+        as a speculative rollback; stale rows (preempted / re-admitted /
+        cancelled since dispatch) are skipped — their tokens regenerate
+        bitwise from committed state on replay."""
+        prev = staged["prev"]
+        with tracing.timed_span("sched.wait") as wait:
+            # an engine loss here takes both rounds with it: nothing of
+            # either was absorbed, so journal replay regenerates their
+            # tokens bitwise from the last committed state
+            raw = prev["handle"].fetch()
         cur = self._inflight
         with tracing.timed_span("sched.absorb") as absorb:
             absorbed = 0
             for uid, (req, desc, emitted) in prev["rows"].items():
                 r = self._live.get(uid)
-                if r is None:  # cancelled between dispatch and absorb
-                    self._engine_flush(uid)
-                    continue
-                if (r is not req or r.state is not RequestState.DECODE
+                if (r is None or r is not req
+                        or r.state is not RequestState.DECODE
                         or len(r.tokens) != emitted
                         or self.engine.state.seqs.get(uid) is not desc):
-                    continue  # stale: the in-flight token is discarded
+                    # cancelled, or stale: the in-flight token is discarded,
+                    # and with it the successor's row that was fed from it (a
+                    # row re-admitted before the plan was fed from the host,
+                    # under its new descriptor, and stands)
+                    c = cur["rows"].get(uid) if cur is not None else None
+                    if c is not None and (r is None or (c[0] is req
+                                                        and c[1] is desc)):
+                        del cur["rows"][uid]
+                    if r is None:  # cancelled between dispatch and absorb
+                        self._engine_flush(uid)
+                    continue
                 finished = self._emit_token(r, raw[uid], now)
                 absorbed += 1
                 drop = 0
@@ -1805,13 +1786,16 @@ class ContinuousBatchScheduler:
                 self._engine_commit(uid, drop, retain)
                 if finished:
                     self._finish(r, now)
-        absorb_dt = absorb.seconds
-        dt = prev["enqueue_dt"] + staged["wait_dt"]
+        # the round's share of the wall: from the later of its enqueue and
+        # the fetch before it, to its own fetch — the cadence of a full pipe,
+        # the whole latency of a restarted one
+        dt = (wait.end - max(prev["enqueued_ns"], self._fetched_ns)) / 1e9
+        self._fetched_ns = wait.end
         self._observe_engine_ok("decode", dt, scale=1.0)
         if absorbed:
             self.metrics.observe_step(
                 dt, absorbed, horizon=1, plan_s=staged["plan_dt"],
-                wait_s=staged["wait_dt"], absorb_s=absorb_dt)
+                wait_s=wait.seconds, absorb_s=absorb.seconds)
             self.metrics.observe_decode(1, fused=False)
             self._token_est_s = (dt if self._token_est_s == 0.0
                                  else 0.5 * self._token_est_s + 0.5 * dt)
@@ -1819,24 +1803,22 @@ class ContinuousBatchScheduler:
             len(cur["rows"]) if cur is not None else 0)
 
     def _drain_inflight(self, now: float) -> None:
-        """Drain boundary: fetch and absorb the in-flight round NOW. Every
+        """Drain boundary: fetch and absorb what is in flight NOW — the
+        round a two-phase drive has staged, then the one behind it. Every
         synchronous-path interaction (mixed prefill dispatch, fused or
         speculative rounds, migration detach, close) runs against an
         at-rest engine — the TransferEngine drain-at-boundary discipline."""
-        prev = self._inflight
-        if prev is None:
-            return
-        with tracing.timed_span("sched.wait") as wait:
-            try:
-                raw = prev["handle"].fetch()
-            except UnrecoverableEngineError:
-                self._inflight = None
-                raise
-        wait_dt = wait.seconds
-        self._inflight = None
-        self._pipeline_absorb_stage(
-            {"prev": prev, "raw": raw, "wait_dt": wait_dt, "plan_dt": 0.0},
-            now)
+        try:
+            staged, self._pending_absorb = self._pending_absorb, None
+            if staged is not None:
+                self._pipeline_absorb_stage(staged, now)
+            prev, self._inflight = self._inflight, None
+            if prev is not None:
+                self._pipeline_absorb_stage({"prev": prev, "plan_dt": 0.0},
+                                            now)
+        except UnrecoverableEngineError:
+            self._inflight = None
+            raise
 
     def _engine_commit(self, uid: int, drop: int, retain: int) -> None:
         """``engine.commit_step`` with the flush/preempt fault contract: an
@@ -1940,13 +1922,13 @@ class ContinuousBatchScheduler:
             return self.step_absorb()
 
     def step_dispatch(self) -> None:
-        """Pool phase 1 (docs/SERVING.md "Pipelined dispatch"): admission +
-        plan + dispatch WITHOUT waiting on the device, so a pool can start
-        every replica's round before absorbing any. A synchronous scheduler
-        waits on the device inside its one dispatch call, so for it phase 1
-        is a no-op and the whole classic step runs in :meth:`step_absorb` —
-        the two-phase drive degrades to the sequential loop, byte for
-        byte."""
+        """Phase 1 (docs/SERVING.md "Pipelined dispatch"): admission + plan
+        + dispatch WITHOUT waiting on the device — the round in flight is
+        not fetched here — so a pool can start every replica's round before
+        absorbing any. A synchronous scheduler waits on the device inside
+        its one dispatch call, so for it phase 1 is a no-op and the whole
+        classic step runs in :meth:`step_absorb` — the two-phase drive
+        degrades to the sequential loop, byte for byte."""
         if not self.pipelined:
             return
         now = self._clock()
@@ -1972,10 +1954,10 @@ class ContinuousBatchScheduler:
             self._recover(e, now)
 
     def step_absorb(self) -> bool:
-        """Pool phase 2: absorb what :meth:`step_dispatch` staged — while
-        the successor round executes on device — or, for a synchronous
-        scheduler, run the whole classic step; then close the step with
-        gauges, sanitizers, and the work-remaining verdict."""
+        """Phase 2: fetch and absorb the round :meth:`step_dispatch` staged
+        — its successor is queued on the device behind it — or, for a
+        synchronous scheduler, run the whole classic step; then close the
+        step with gauges, sanitizers, and the work-remaining verdict."""
         now = self._clock()
         if self.pipelined:
             staged, self._pending_absorb = self._pending_absorb, None
@@ -2004,7 +1986,7 @@ class ContinuousBatchScheduler:
                 self._admit(now)
             if self._stalled:
                 self._absorb(self._engine_put([], []), now)
-            self._decode_once(now)
+            self._decode_sync(now)
         except UnrecoverableEngineError as e:
             if self.escalate_losses:
                 # pool mode (docs/SERVING.md): the loss is the POOL's to

@@ -1,10 +1,12 @@
-"""Pipelined dispatch (docs/SERVING.md "Pipelined dispatch"): the
-``pipelined=True`` scheduler keeps one decode round in flight — plan N+1
-while N executes, absorb N while N+1 executes — and must stay BITWISE
-identical to the synchronous twin across the whole replay matrix: plain
-greedy, sampled, EOS / max_new / stop-sequence finishes (the
-speculative-absorb rollback), preemption churn, KV swap, mid-step engine
-loss, migration detach/adopt, and cancellation mid-flight. Plus: the
+"""Pipelined dispatch (docs/SERVING.md "Pipelined dispatch"): the default
+scheduler runs ONE decode round ahead of what the host knows — round N+1 is
+enqueued, fed on the device from N's result, before N is fetched — and must
+stay BITWISE identical to the synchronous twin (``pipelined=False``) across
+the whole replay matrix: plain greedy, sampled, EOS / stop-sequence finishes
+(the speculative-absorb rollback), ``max_new_tokens`` predicted by count,
+preemption churn, KV swap, mid-step engine loss, migration detach/adopt,
+and cancellation, preemption and engine loss with two rounds outstanding.
+Plus: the
 ``check_pipeline_coherence`` sanitizer's planted violations, the relaxed
 in-flight allowances on the existing checks, the per-replica heartbeat
 regression (fed at each replica's OWN absorb), and the two-phase pool
@@ -20,7 +22,8 @@ from deepspeed_tpu.analysis.sanitizer import (SanitizerError,
                                               checked_cache_cls)
 from deepspeed_tpu.inference.v2 import InferenceEngineV2
 from deepspeed_tpu.models import build_model
-from deepspeed_tpu.resilience.errors import EngineUsageError
+from deepspeed_tpu.resilience.errors import (DeviceLostError,
+                                             EngineUsageError)
 from deepspeed_tpu.resilience.recovery import RequestJournal
 from deepspeed_tpu.serve import (ContinuousBatchScheduler, EnginePool,
                                  FaultInjector, FaultSpec, HealthMonitor,
@@ -95,16 +98,19 @@ class TestBitwiseTwins:
         assert p["speculative_rollbacks"] == 0
 
     def test_eos_finish(self, setup):
-        """An EOS landing mid-stream is decidable from the raw token at
-        plan time: the row is not fed, finishes at its absorb, and the
-        remaining rows keep the pipe full."""
+        """An EOS is NOT predictable at plan time (the token is still on
+        the device): the row is fed speculatively, finishes at its absorb,
+        and its successor position is dropped there (``commit_step`` with
+        ``drop`` 1) — counted; the remaining rows keep the pipe full."""
         m, params = setup
         _, _, sync = _run(m, params, _prompts(), pipelined=False, gen=16)
         # pick an eos that fires mid-stream for at least one request
         eos = sync[0].tokens[7]
         sref, pipe, sched = _twin(m, params, _prompts(), gen=16, eos=eos)
-        assert any(len(r.tokens) < 16 for r in pipe)
-        assert sched.metrics.pipeline["speculative_rollbacks"] == 0
+        cut = sum(len(r.tokens) < 16 for r in pipe)
+        assert cut >= 1
+        # (a row whose EOS came out of a synchronous round had no successor)
+        assert 1 <= sched.metrics.pipeline["speculative_rollbacks"] <= cut
 
     def test_stop_sequence_speculative_rollback(self, setup):
         """A stop-sequence finish is NOT predictable at plan time (the scan
@@ -228,6 +234,117 @@ class TestBitwiseTwins:
         sched.close()
         assert not eng.state.seqs and not eng.block_mgr._ref
 
+    def test_max_new_tokens_predicted_by_count(self, setup):
+        """A finish by ``max_new_tokens`` needs no token: the plan counts.
+        No row is ever fed past its count, so nothing is rolled back, and
+        nearly every round is enqueued with its predecessor unfetched."""
+        m, params = setup
+        prompts = _prompts()
+        gens = [5, 9, 16]
+        _, _, sync = _run(m, params, prompts, pipelined=False, gen=16)
+        eng = _engine(m, params)
+        sched = ContinuousBatchScheduler(eng, sleep=lambda s: None)
+        assert sched.pipelined  # the run-ahead loop is the default
+        reqs = [sched.submit(p, max_new_tokens=g)
+                for p, g in zip(prompts, gens)]
+        limit = {r.uid: len(r.prompt) + r.max_new_tokens - 1 for r in reqs}
+        dispatch = eng.decode_dispatch
+
+        def spy(tokens, prev=None):
+            handle = dispatch(tokens, prev=prev)
+            for uid in handle.uids:
+                assert eng.state.seqs[uid].seen_tokens <= limit[uid], uid
+            return handle
+
+        eng.decode_dispatch = spy
+        sched.run_until_complete()
+        assert [r.tokens for r in reqs] == [r.tokens[:g]
+                                            for r, g in zip(sync, gens)]
+        p = sched.metrics.pipeline
+        assert p["speculative_rollbacks"] == 0
+        assert p["ahead_dispatches"] >= p["dispatches"] - 2
+        sched.close()
+        assert not eng.state.seqs and not eng.block_mgr._ref
+
+    def _two_outstanding(self, m, params, gen=12):
+        """A default scheduler stopped between its two phases: round N is
+        staged and unfetched, round N+1 is enqueued behind it."""
+        eng = _engine(m, params)
+        sched = ContinuousBatchScheduler(eng, sleep=lambda s: None,
+                                         retry=RetryPolicy(max_attempts=5))
+        reqs = [sched.submit(p, max_new_tokens=gen) for p in _prompts()]
+        for _ in range(30):
+            sched.step()
+            if (sched._inflight is not None
+                    and len(sched._inflight["rows"]) == len(reqs)):
+                break
+        sched.step_dispatch()
+        assert sched._pending_absorb is not None
+        assert len(eng._unfetched) == 2
+        for uid in (r.uid for r in reqs):
+            assert uid in sched._pending_absorb["prev"]["rows"]
+            assert uid in sched._inflight["rows"]
+            assert eng.state.seqs[uid].uncommitted == 2
+        return eng, sched, reqs
+
+    def test_cancel_with_two_rounds_outstanding(self, setup):
+        """A row cancelled while it rides BOTH outstanding rounds leaves
+        both at the first absorb; survivors are unperturbed."""
+        m, params = setup
+        _, _, sync = _run(m, params, _prompts(), pipelined=False, gen=12)
+        eng, sched, reqs = self._two_outstanding(m, params)
+        assert sched.cancel(reqs[1].uid)
+        assert sched.step_absorb()
+        assert reqs[1].uid not in sched._inflight["rows"]
+        sched.run_until_complete()
+        assert reqs[1].state is RequestState.CANCELLED
+        assert [reqs[0].tokens, reqs[2].tokens] == [sync[0].tokens,
+                                                    sync[2].tokens]
+        sched.close()
+        assert not eng.state.seqs and not eng.block_mgr._ref
+
+    def test_preempt_with_two_rounds_outstanding(self, setup):
+        """A row preempted while it rides both outstanding rounds: both its
+        in-flight tokens are discarded, and the replay from the committed
+        history regenerates them bitwise."""
+        m, params = setup
+        _, _, sync = _run(m, params, _prompts(), pipelined=False, gen=12)
+        eng, sched, reqs = self._two_outstanding(m, params)
+        emitted = len(reqs[0].tokens)
+        sched._preempt(reqs[0])
+        sched.step_absorb()
+        assert len(reqs[0].tokens) == emitted  # nothing absorbed for it
+        assert reqs[0].uid not in sched._inflight["rows"]
+        sched.run_until_complete()
+        assert reqs[0].preemptions == 1
+        assert [r.tokens for r in reqs] == [r.tokens for r in sync]
+        sched.close()
+        assert not eng.state.seqs and not eng.block_mgr._ref
+
+    def test_engine_loss_with_two_rounds_outstanding(self, setup):
+        """The device dies under two outstanding rounds (the fetch of the
+        older one raises): nothing of either was absorbed, so the journal
+        replay regenerates every token bitwise."""
+        m, params = setup
+        _, _, sync = _run(m, params, _prompts(), pipelined=False, gen=12)
+        eng, sched, reqs = self._two_outstanding(m, params)
+
+        class Lost:
+            uids = sched._pending_absorb["prev"]["handle"].uids
+
+            def fetch(self):
+                raise DeviceLostError("injected: lost under two rounds")
+
+        sched._pending_absorb["prev"]["handle"] = Lost()
+        sched.step_absorb()
+        assert eng.rebuilds == 1 and not eng._unfetched
+        assert sched._inflight is None and sched._pending_absorb is None
+        sched.run_until_complete()
+        assert all(r.state is RequestState.DONE for r in reqs)
+        assert [r.tokens for r in reqs] == [r.tokens for r in sync]
+        assert sched.metrics.faults["engine_losses"] == 1
+        sched.close()
+
     def test_stage_timing_split(self, setup):
         """observe_step's conflated number is split: the pipelined run
         populates the plan/wait/absorb gauges, the sync twin leaves them 0."""
@@ -268,16 +385,107 @@ class TestEngineSeam:
         assert eng.state.seqs[7].uncommitted == 0
         eng.flush(7)
 
-    def test_double_dispatch_same_uid_raises(self, setup):
+    def test_dispatch_fed_from_unfetched_handle_matches_decode_step(self,
+                                                                    setup):
+        """A round fed ON THE DEVICE from the unfetched round before it
+        equals ``decode_step`` fed from the host, token for token; the
+        history's placeholder is the real token once that round is
+        fetched."""
+        m, params = setup
+        prompt = _prompts(1)[0]
+        ref = _engine(m, params)
+        t0 = t = int(ref.put([1], [prompt], greedy=True)[1])
+        singles = []
+        for _ in range(8):
+            t = int(ref.decode_step({1: t}, greedy=True)[1])
+            singles.append(t)
+        eng = _engine(m, params)
+        assert int(eng.put([7], [prompt], greedy=True)[7]) == t0
+        d = eng.state.seqs[7]
+        got = []
+        h = eng.decode_dispatch({7: t0})
+        for _ in range(7):
+            nxt = eng.decode_dispatch({7: None}, prev=h)
+            assert d.uncommitted == 2 and d.history[-1] == -1
+            got.append(h.fetch()[7])
+            assert d.history[-1] == got[-1]
+            eng.commit_step(7, 0, retain=1)
+            h = nxt
+        got.append(h.fetch()[7])
+        eng.commit_step(7, 0, 0)
+        assert got == singles
+        assert d.history == prompt + [t0] + singles[:-1]
+        assert d.uncommitted == 0 and not eng._unfetched
+        eng.flush(7)
+
+    def test_device_feed_needs_a_row_of_an_unfetched_handle(self, setup):
         m, params = setup
         eng = _engine(m, params)
         t = int(eng.put([1], [_prompts(1)[0]], greedy=True)[1])
+        u = int(eng.put([2], [_prompts(2)[1]], greedy=True)[2])
+        with pytest.raises(EngineUsageError, match="there is none"):
+            eng.decode_dispatch({1: None})
         h = eng.decode_dispatch({1: t})
-        with pytest.raises(EngineUsageError, match="drain"):
-            eng.decode_dispatch({1: t})
+        with pytest.raises(EngineUsageError, match="no row for it"):
+            eng.decode_dispatch({1: None, 2: None}, prev=h)
         h.fetch()
+        with pytest.raises(EngineUsageError, match="there is none"):
+            eng.decode_dispatch({1: None}, prev=h)  # fetched: nothing to feed
+        eng.commit_step(1, 0, 0)
+        assert eng.state.seqs[1].uncommitted == 0  # the refusals fed nothing
+        assert eng.state.seqs[2].uncommitted == 0 and u >= 0
+        eng.flush(1)
+        eng.flush(2)
+
+    def test_third_dispatch_with_two_unfetched_raises(self, setup):
+        m, params = setup
+        eng = _engine(m, params)
+        t = int(eng.put([1], [_prompts(1)[0]], greedy=True)[1])
+        h1 = eng.decode_dispatch({1: t})
+        h2 = eng.decode_dispatch({1: None}, prev=h1)
+        seen = eng.state.seqs[1].seen_tokens
+        with pytest.raises(EngineUsageError, match="two rounds"):
+            eng.decode_dispatch({1: None}, prev=h2)
+        assert eng.state.seqs[1].seen_tokens == seen  # nothing advanced
+        h1.fetch()
+        h3 = eng.decode_dispatch({1: None}, prev=h2)  # one fetched: room
+        h2.fetch()
+        h3.fetch()
         eng.commit_step(1, 0, 0)
         eng.flush(1)
+
+    def test_scratch_sets_never_written_under_an_inflight_round(self, setup):
+        """Two alternating scratch sets: a dispatch stages from the set no
+        unfetched round was staged from, so the host arrays of a round in
+        flight are never rewritten."""
+        m, params = setup
+        eng = _engine(m, params)
+        t = int(eng.put([1], [_prompts(1)[0]], greedy=True)[1])
+        u = int(eng.put([2], [_prompts(2)[1]], greedy=True)[2])
+
+        def staged(handle):
+            key, = [k for k in eng._scratch
+                    if k[0] == "dispatch" and k[-1] == handle._set]
+            return eng._scratch[key]
+
+        h1 = eng.decode_dispatch({1: t, 2: u})
+        frozen1 = [a.copy() for a in staged(h1)]
+        h2 = eng.decode_dispatch({1: None, 2: None}, prev=h1)
+        assert h2._set != h1._set
+        assert all(np.array_equal(a, b)
+                   for a, b in zip(staged(h1), frozen1))
+        frozen2 = [a.copy() for a in staged(h2)]
+        h1.fetch()
+        h3 = eng.decode_dispatch({1: None, 2: None}, prev=h2)
+        assert h3._set == h1._set  # the fetched round's set is free again
+        assert all(np.array_equal(a, b)
+                   for a, b in zip(staged(h2), frozen2))
+        h2.fetch()
+        h3.fetch()
+        for uid in (1, 2):
+            eng.commit_step(uid, 0, 0)
+            eng.flush(uid)
+        assert not eng.block_mgr._ref
 
     def test_commit_drop_rolls_back_the_fed_position(self, setup):
         m, params = setup

@@ -200,10 +200,12 @@ class TestAdmissionPolicy:
         # monolithic prefill: the virtual-time math below counts one
         # admission+completion per step, which needs prefill+both decodes
         # inside a single step (chunked mode spreads them over dispatches;
-        # the admission *order* under test is identical either way)
+        # the admission *order* under test is identical either way), and the
+        # synchronous loop: the run-ahead loop absorbs a round one step late
         sched = ContinuousBatchScheduler(eng, age_weight=1.0,
                                          clock=lambda: vt[0],
-                                         chunked_prefill=False)
+                                         chunked_prefill=False,
+                                         pipelined=False)
         rng = np.random.default_rng(3)
         low = sched.submit(rng.integers(0, 128, 8).tolist(), priority=0,
                            max_new_tokens=2, arrival_time=0.0)

@@ -165,7 +165,8 @@ def test_an_exception_cannot_leave_inner_spans_open(session):
 # -- serving ---------------------------------------------------------------
 
 def test_scheduler_spans_nest_in_the_recorder_and_on_the_host_plane(lm, session):
-    _, sched, _ = serve(lm)
+    # the synchronous twin: its fetch lies inside its dispatch
+    _, sched, _ = serve(lm, pipelined=False)
     sched.run_until_complete()
     spans = tracing.snapshot()
     named = by_name(spans)
@@ -240,6 +241,7 @@ def test_dispatch_counts_are_measured_where_the_batch_is_built(lm, session):
 
 def test_pipelined_loop_uses_the_same_names_and_feeds_its_gauges(lm, session):
     _, sched, _ = serve(lm, pipelined=True)
+    warm_ahead = sched.metrics.pipeline["ahead_dispatches"]
     sched.run_until_complete()
     spans = tracing.snapshot()
     named = by_name(spans)
@@ -251,10 +253,28 @@ def test_pipelined_loop_uses_the_same_names_and_feeds_its_gauges(lm, session):
     assert deferred and all(
         not [c for c in tracing.descendants(spans, s.id)
              if c.name == "engine.fetch"] for s in deferred)
-    waits = [s for s in named["sched.wait"] if chain(spans, s)[1] == "sched.plan"]
-    assert waits and all(
+    # the device wait comes after the dispatch, at the head of the absorb
+    # phase, not inside the plan: a round is enqueued before the one ahead of
+    # it is fetched
+    waits = named["sched.wait"]
+    assert all(chain(spans, s)[1] == "sched.step" for s in waits)
+    assert all(
         [c.name for c in tracing.descendants(spans, s.id)] == ["engine.fetch"]
         for s in waits)
+    # ``ahead``: 1 on a round enqueued with its predecessor unfetched, 0 on
+    # a pipe restart and on every synchronous dispatch
+    assert all("ahead" in s.attrs for s in named["engine.dispatch"])
+    assert all(s.attrs["ahead"] == 0 for s in named["engine.dispatch"]
+               if not s.attrs.get("deferred"))
+    assert deferred[0].attrs["ahead"] == 0
+    ahead = [s for s in deferred if s.attrs["ahead"]]
+    assert ahead and len(ahead) == (
+        sched.metrics.pipeline["ahead_dispatches"] - warm_ahead)
+    for s in ahead:
+        # the fetch of the round before it begins after it was enqueued
+        later = [f for f in named["engine.fetch"] if f.start >= s.end]
+        assert later and not [f for f in named["engine.fetch"]
+                              if s.start < f.start < s.end]
     # the gauges are the spans' own clock readings, not a second pair
     last = [s for s in named["sched.absorb"] if chain(spans, s)[1] == "sched.step"][-1]
     assert sched.metrics.pipeline["absorb_ms"] == round(
