@@ -1031,10 +1031,14 @@ class InferenceEngineV2:
 
     def _note_moe_rows(self, disp, fetched) -> None:
         """``moe_rows`` / ``moe_rows_max`` of a greedy ragged dispatch, read
-        from the two counts behind its tokens (no transfer of their own)."""
+        from the two counts behind its tokens (no transfer of their own);
+        ``moe_zero_picks`` from a third, where the router has identity
+        experts."""
         if self._moe_stats and disp.recording:
             disp.set(moe_rows=int(fetched[self.max_seqs]),
                      moe_rows_max=int(fetched[self.max_seqs + 1]))
+            if self.cfg.moe_zero_experts:
+                disp.set(moe_zero_picks=int(fetched[self.max_seqs + 2]))
 
     def _count_dispatch(self, disp, padded_rows: int, plan,
                         fused: bool = False) -> None:

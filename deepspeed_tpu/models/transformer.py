@@ -27,6 +27,7 @@ Architecture choices driven by XLA/TPU:
 
 import math
 import os
+import re
 from dataclasses import dataclass, field, replace
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -97,7 +98,14 @@ class TransformerConfig:
     # ``num_experts`` then counts the experts HELD HERE (their weights exist),
     # the first being the router's output ``moe_expert_offset``: the share of
     # an expert-parallel deployment (moe/layer.py ``held_experts_ffn``)
-    moe_router: str = "gshard"  # "gshard" | "group_limited"
+    # ``moe_router="softmax_topk"`` (LongCat-Flash): softmax scores over the
+    # router's outputs, a selection-only bias in units of the uniform score
+    # ``1 / moe_router_width``, top-k with no groups, weights the unbiased
+    # scores times ``moe_score_scale`` (``moe_norm_topk`` False: not divided
+    # by their sum). The router's last ``moe_zero_experts`` outputs are
+    # identity (zero-compute) experts: a choice among them adds ``weight * h``
+    # and touches no matrix
+    moe_router: str = "gshard"  # "gshard" | "group_limited" | "softmax_topk"
     moe_router_width: int = 0
     moe_expert_offset: int = 0
     moe_n_group: int = 1
@@ -105,10 +113,18 @@ class TransformerConfig:
     moe_norm_topk: bool = True
     moe_score_scale: float = 1.0
     moe_shared_size: int = 0  # width of the shared expert beside the routed ones
+    moe_zero_experts: int = 0
     # leading dense layers before the expert layers (two stacked groups:
     # params["dense_blocks"] then params["blocks"]), at their own MLP width
     num_dense_layers: int = 0
     dense_intermediate_size: Optional[int] = None
+    # "single": one attention and one feed-forward a layer. "scmoe": the
+    # shortcut-connected double layer (LongCat-Flash; attention="mla" only):
+    # two attentions and two dense feed-forwards ``dense_intermediate_size``
+    # wide a layer, and one expert layer that branches off the first
+    # post-attention norm and rejoins the residual stream after the second
+    # feed-forward. ``num_layers`` counts double layers
+    layer_kind: str = "single"
     # attention kind: "mha" (q/k/v heads, GQA by num_kv_heads) | "mla"
     # (multi-head latent attention, DeepSeek-V2/V3: low-rank q and kv latents,
     # a rope head shared by all heads; the cache holds [c_kv | k_rope])
@@ -118,6 +134,9 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # the latent norms' outputs times sqrt(hidden_size / rank) (LongCat-Flash)
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     # YaRN rotary scaling (rope_factor 1 = plain rotary)
     rope_factor: float = 1.0
     rope_original_max: int = 0
@@ -186,6 +205,25 @@ class TransformerConfig:
         return 1 if self.is_mla else self.kv_heads
 
     @property
+    def sublayers(self) -> int:
+        """Attentions (and dense feed-forwards) of one layer."""
+        return 2 if self.layer_kind == "scmoe" else 1
+
+    @property
+    def pool_layers(self) -> int:
+        """Layers of the paged pool: one for every attention of the model.
+        THE place the pool's layer axis is read from."""
+        return self.sublayers * self.num_layers
+
+    @property
+    def mla_latent_scales(self) -> Tuple[float, float]:
+        """Factors on the outputs of the query and the kv latent norms."""
+        return ((self.hidden_size / self.q_lora_rank) ** 0.5
+                if self.mla_scale_q_lora else 1.0,
+                (self.hidden_size / self.kv_lora_rank) ** 0.5
+                if self.mla_scale_kv_lora else 1.0)
+
+    @property
     def num_moe_layers(self) -> int:
         return (self.num_layers - self.num_dense_layers
                 if self.num_experts > 0 else 0)
@@ -194,7 +232,8 @@ class TransformerConfig:
     def holds_experts(self) -> bool:
         """The expert layers hold a share of the router's experts
         (moe/layer.py ``held_experts_ffn``)."""
-        return self.num_experts > 0 and self.moe_router == "group_limited"
+        return self.num_experts > 0 and self.moe_router in (
+            "group_limited", "softmax_topk")
 
     @property
     def router_width(self) -> int:
@@ -235,8 +274,8 @@ class TransformerConfig:
     @property
     def num_parameters(self) -> int:
         """Parameters of the tree ``init_params`` builds: with held experts
-        (``moe_router="group_limited"``) the experts held here, not the
-        router's width."""
+        (``holds_experts``) the experts held here, not the router's
+        width."""
         H, L, V = self.hidden_size, self.num_layers, self.vocab_size
         n_ln = 1 if (self.parallel_block and self.parallel_shared_ln) else 2
         norms = n_ln * (1 if self.norm == "rmsnorm" else 2) * H
@@ -245,12 +284,16 @@ class TransformerConfig:
             self.dense_mlp_dim)
         if self.num_experts > 0:
             moe = mlp * self.num_experts + H * self.router_width  # + router
-            if self.moe_router == "group_limited":
+            if self.holds_experts:
                 moe += self.router_width  # the selection bias
             moe += self._mlp_params(self.moe_shared_size)
             if self.moe_use_residual:
                 moe += mlp + 2 * H + 2  # residual MLP + coefficient
             moe_layer = self._attn_params + norms + moe
+            if self.layer_kind == "scmoe":
+                # both sublayers' attention, norms and dense feed-forward
+                # beside the one expert layer
+                moe_layer = self.sublayers * dense_layer + moe
         else:
             moe_layer = dense_layer
         n_moe = self.num_moe_layers if self.num_experts > 0 else L
@@ -280,7 +323,7 @@ class TransformerConfig:
                                         + self.qk_rope_head_dim + self.v_head_dim)
         else:
             per_pos = 2 * self.num_heads * self.head_dim
-        attn_flops = 6 * self.num_layers * per_pos * S  # fwd+bwd qk^T + av
+        attn_flops = 6 * self.pool_layers * per_pos * S  # fwd+bwd qk^T + av
         return 6 * n + attn_flops
 
 
@@ -433,6 +476,23 @@ def mla_softmax_scale(cfg: "TransformerConfig") -> float:
             * yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2)
 
 
+def sublayer_prefix(i: int) -> str:
+    """Prefix of sublayer ``i``'s leaves in a double layer's ``blocks``."""
+    return f"s{i}_"
+
+
+def sublayer_leaf(name: str) -> str:
+    """A leaf's name without its sublayer prefix."""
+    return name[3:] if re.match(r"s\d_", name) else name
+
+
+def sublayer(blk, i: int):
+    """Sublayer ``i`` of a double layer's leaves, under a dense layer's own
+    names."""
+    prefix = sublayer_prefix(i)
+    return {k[len(prefix):]: v for k, v in blk.items() if k.startswith(prefix)}
+
+
 #: per-layer expert matrices: the paged program indexes them by (layer,
 #: expert) where they lie instead of slicing a layer out of the stack
 EXPERT_LEAVES = ("wi", "w_gate", "w_down")
@@ -469,8 +529,14 @@ class TransformerLM:
         self.config = config
         self.model_axis, self.seq_axis = mesh_axes
         if config.holds_experts and not config.is_mla:
-            raise ValueError("moe_router='group_limited' (held experts) is "
-                             "wired into attention='mla' blocks only")
+            raise ValueError(f"moe_router='{config.moe_router}' (held "
+                             "experts) is wired into attention='mla' blocks "
+                             "only")
+        if config.layer_kind == "scmoe" and not (
+                config.holds_experts and config.num_dense_layers == 0):
+            raise ValueError("layer_kind='scmoe' is a double layer of latent "
+                             "attention around held experts, with no leading "
+                             "dense layers")
 
     # ------------------------------------------------------------------
     def init_params(self, rng) -> Dict[str, Any]:
@@ -600,6 +666,11 @@ class TransformerLM:
             if cfg.moe_shared_size:
                 moe.update({"shared_" + k: v
                             for k, v in mlp(cfg.moe_shared_size).items()})
+            if cfg.layer_kind == "scmoe":
+                # a sublayer's leaves are a dense layer's, under its prefix
+                dense = {**attn, **mlp(cfg.dense_mlp_dim)}
+                attn = {sublayer_prefix(i) + k: v for i in range(cfg.sublayers)
+                        for k, v in dense.items()}
             groups["blocks"] = (cfg.num_layers - n_dense, {**attn, **moe})
         else:
             groups["blocks"] = (cfg.num_layers, {**attn, **mlp(cfg.dense_mlp_dim)})
@@ -615,23 +686,23 @@ class TransformerLM:
         cfg = self.config
         if cfg.activation != "swiglu" or cfg.norm != "rmsnorm":
             raise ValueError("attention='mla' models are rmsnorm + swiglu")
-        if cfg.num_experts > 0 and cfg.moe_router != "group_limited":
-            raise ValueError("attention='mla' models route with "
-                             "moe_router='group_limited'")
+        if cfg.num_experts > 0 and not cfg.holds_experts:
+            raise ValueError("attention='mla' models route with moe_router="
+                             "'group_limited' or 'softmax_topk'")
         dt = cfg.param_dtype
         groups, top = self._mla_shapes()
         init = jax.nn.initializers.normal(0.02)
         resid_init = jax.nn.initializers.normal(
             0.02 / np.sqrt(2 * cfg.num_layers))
-        keys = iter(jax.random.split(rng, 64))
+        keys = iter(jax.random.split(rng, 64 * cfg.sublayers))
 
         def leaf(name, shape):
             if name.endswith("_scale"):
                 return jnp.ones(shape, dt)
             if name == "moe_bias":
                 return jnp.zeros(shape, dt)
-            return (resid_init if name in ("wo", "w_down") else init)(
-                next(keys), shape, dt)
+            return (resid_init if sublayer_leaf(name) in ("wo", "w_down")
+                    else init)(next(keys), shape, dt)
 
         params = {k: leaf(k, shape) for k, shape in top.items()}
         for group, (n, leaves) in groups.items():
@@ -747,7 +818,8 @@ class TransformerLM:
             moe = "moe_wg" in leaves
             specs[group] = {
                 k: (expert[k] if moe and k in expert else
-                    by_name.get(k, P(*([None] * (len(shape) + 1)))))
+                    by_name.get(sublayer_leaf(k),
+                                P(*([None] * (len(shape) + 1)))))
                 for k, shape in leaves.items()}
         return specs
 
@@ -976,9 +1048,10 @@ class TransformerLM:
 
     def _block_mla(self, x, blk, *, positions, paged=None, seg_from=None,
                    experts=None, row_mask=None):
-        """One latent-attention block on (B, S, H): a dense layer, or an
-        expert layer where ``blk`` holds a router. Returns (y, new pool,
-        (rows, rows_max) of the expert layer's held experts or None).
+        """One latent-attention block on (B, S, H): a dense layer, an expert
+        layer where ``blk`` holds a router, or a shortcut-connected double
+        layer (``layer_kind="scmoe"``, :meth:`_block_scmoe`). Returns (y, new
+        pool, the expert layer's counts (``held_experts_ffn``) or None).
 
         Full sequence (``paged`` None): the latent is up-projected through
         ``wkv_b`` and attended to causally, un-absorbed. ``paged`` (pool,
@@ -994,7 +1067,94 @@ class TransformerLM:
         layer of the group) when the caller kept them out of ``blk``.
         ``row_mask`` (B*S,) bool: the rows that are real tokens (padding rows
         are routed to no expert)."""
-        from ..moe.layer import _gated_mlp, held_experts_ffn
+        from ..moe.layer import _gated_mlp
+
+        if self.config.layer_kind == "scmoe":
+            return self._block_scmoe(x, blk, positions=positions, paged=paged,
+                                     seg_from=seg_from, experts=experts,
+                                     row_mask=row_mask)
+        blk = _dequant_woq(blk, x.dtype)
+        attn_out, new_pool = self._mla_attention(
+            x, blk, positions=positions, paged=paged, seg_from=seg_from)
+        stats = None
+        with jax.named_scope("mlp"):
+            x = jax.lax.optimization_barrier(x + attn_out)
+            h2 = _norm(x, blk["ln2_scale"], None, "rmsnorm",
+                       self.config.norm_eps)
+            if "moe_wg" in blk:
+                mlp_out, stats = self._held_experts(h2, blk, experts, row_mask)
+            else:
+                mlp_out = _gated_mlp(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
+            mlp_out = self._constraint(mlp_out, self._act_spec(paged is None))
+        return x + mlp_out, new_pool, stats
+
+    def _block_scmoe(self, x, blk, *, positions, paged, seg_from, experts,
+                     row_mask):
+        """One shortcut-connected double layer (LongCat-Flash, ScMoE): two
+        sublayers of latent attention and a dense feed-forward, and one
+        expert layer computed from the first sublayer's post-attention norm
+        and added after the second feed-forward::
+
+            x1 = x  + A_0(N(x));   h = N(x1);   m = E(h)
+            x2 = x1 + F_0(h)
+            x3 = x2 + A_1(N(x2));  y = x3 + F_1(N(x3)) + m
+
+        Sublayer ``i`` attends over layer ``sublayers * layer + i`` of the pool
+        (``TransformerConfig.pool_layers``). Arguments and result as
+        :meth:`_block_mla`."""
+        from ..moe.layer import _gated_mlp
+
+        eps, n_sub = self.config.norm_eps, self.config.sublayers
+        blk = _dequant_woq(blk, x.dtype)
+        once = jax.lax.optimization_barrier
+        pool, layer, tables = paged if paged is not None else (None, 0, None)
+        m = stats = None
+        for i in range(n_sub):
+            sub = sublayer(blk, i)
+            attn_out, pool = self._mla_attention(
+                x, sub, positions=positions, seg_from=seg_from,
+                paged=None if paged is None
+                else (pool, n_sub * layer + i, tables))
+            with jax.named_scope("mlp"):
+                x = once(x + attn_out)
+                h = _norm(x, sub["ln2_scale"], None, "rmsnorm", eps)
+                if i == 0:
+                    m, stats = self._held_experts(h, blk, experts, row_mask)
+                with jax.named_scope("dense_ffn"):
+                    x = x + self._constraint(
+                        _gated_mlp(h, sub["w_gate"], sub["w_up"], sub["w_down"]),
+                        self._act_spec(paged is None))
+        with jax.named_scope("mlp"):
+            y = x + self._constraint(m, self._act_spec(paged is None))
+        return y, pool, stats
+
+    def _held_experts(self, h, blk, experts, row_mask):
+        """The expert layer of ``blk`` on the normed (B, S, H) ``h``: (its
+        output, its counts). ``experts``: see :meth:`_block_mla`."""
+        from ..moe.layer import held_experts_ffn
+
+        cfg = self.config
+        B, S, H = h.shape
+        big, layer_in_group = experts if experts is not None else (blk, None)
+        shared = tuple(blk["shared_" + k] for k in
+                       ("w_gate", "w_up", "w_down")) \
+            if "shared_w_gate" in blk else None
+        y, stats = held_experts_ffn(
+            h.reshape(B * S, H), blk["moe_wg"], blk["moe_bias"],
+            big["wi"], big["w_gate"], big["w_down"], shared,
+            k=cfg.moe_top_k, n_group=cfg.moe_n_group,
+            topk_group=cfg.moe_topk_group,
+            normalize=cfg.moe_norm_topk, scale=cfg.moe_score_scale,
+            first=cfg.moe_expert_offset, layer=layer_in_group,
+            token_mask=row_mask, router=cfg.moe_router,
+            zero_experts=cfg.moe_zero_experts)
+        return y.reshape(B, S, H), stats
+
+    def _mla_attention(self, x, blk, *, positions, paged=None, seg_from=None):
+        """Latent attention of one (sub)layer on the residual stream ``x``
+        (B, S, H), its input norm and output projection included: (the
+        attention's output, the new pool or None). ``blk``: the layer's
+        leaves; ``paged`` and ``seg_from`` as :meth:`_block_mla`."""
         from ..ops.transformer import paged_attention as pa
 
         cfg = self.config
@@ -1003,24 +1163,26 @@ class TransformerLM:
         B, S, H = x.shape
         eps, dt = cfg.norm_eps, x.dtype
         scale = mla_softmax_scale(cfg)
-        blk = _dequant_woq(blk, dt)
+        q_scale, kv_scale = cfg.mla_latent_scales
         # a norm over a product reads the product twice (its mean square, then
         # its values): the barrier keeps XLA from fusing the matmul into both
         # reads, which streams the matrix twice (on the chip ``wo`` alone cost
         # 0.2 ms a layer)
         once = jax.lax.optimization_barrier
 
-        def rms(v, name):
-            return _norm(v, blk[name], None, "rmsnorm", eps)
+        def rms(v, name, factor=1.0):
+            scale = blk[name] if factor == 1.0 \
+                else blk[name].astype(jnp.float32) * factor
+            return _norm(v, scale, None, "rmsnorm", eps)
 
         new_pool = None
         with jax.named_scope("attn"):
             with jax.named_scope("mla_proj"):
                 h = rms(x, "ln1_scale")
-                c_q = rms(once(h @ blk["wq_a"].astype(dt)), "q_a_scale")
+                c_q = rms(once(h @ blk["wq_a"].astype(dt)), "q_a_scale", q_scale)
                 q = (c_q @ blk["wq_b"].astype(dt)).reshape(B, S, nh, nope + rope)
                 kv_a = once(h @ blk["wkv_a"].astype(dt))
-                c_kv = rms(kv_a[..., :rank], "kv_a_scale")          # (B, S, rank)
+                c_kv = rms(kv_a[..., :rank], "kv_a_scale", kv_scale)  # (B, S, rank)
                 k_rope = _rope_interleaved(kv_a[..., None, rank:], positions, cfg)
                 q_nope = q[..., :nope]
                 q_rope = _rope_interleaved(q[..., nope:], positions, cfg)
@@ -1059,28 +1221,7 @@ class TransformerLM:
                     attn = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv)[:, None]
             attn_out = attn.reshape(B, S, nh * vd) @ blk["wo"].astype(dt)
             attn_out = self._constraint(attn_out, self._act_spec(paged is None))
-        stats = None
-        with jax.named_scope("mlp"):
-            x = once(x + attn_out)
-            h2 = rms(x, "ln2_scale")
-            if "moe_wg" in blk:
-                big, layer_in_group = experts if experts is not None else (blk, None)
-                shared = tuple(blk["shared_" + k] for k in
-                               ("w_gate", "w_up", "w_down")) \
-                    if "shared_w_gate" in blk else None
-                y, stats = held_experts_ffn(
-                    h2.reshape(B * S, H), blk["moe_wg"], blk["moe_bias"],
-                    big["wi"], big["w_gate"], big["w_down"], shared,
-                    k=cfg.moe_top_k, n_group=cfg.moe_n_group,
-                    topk_group=cfg.moe_topk_group,
-                    normalize=cfg.moe_norm_topk, scale=cfg.moe_score_scale,
-                    first=cfg.moe_expert_offset, layer=layer_in_group,
-                    token_mask=row_mask)
-                mlp_out = y.reshape(B, S, H)
-            else:
-                mlp_out = _gated_mlp(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
-            mlp_out = self._constraint(mlp_out, self._act_spec(paged is None))
-        return x + mlp_out, new_pool, stats
+        return attn_out, new_pool
 
     def _mla_paged_attention(self, q_lat, q_rope, pool, layer, tables, limits,
                              scale, seg_from):
@@ -1490,16 +1631,16 @@ class TransformerLM:
     # paged (blocked) KV cache — reference inference/v2 BlockedKVCache path
     # ------------------------------------------------------------------
     def init_kv_pool(self, num_blocks: int, block_size: int, dtype=jnp.bfloat16):
-        """The blocked KV pool: ONE array (L, kvh, NB, BS, row) whose rows are
-        ``[k_t | v_t]`` or, under latent attention, ``[c_kv | k_rope]`` with
-        one pool head (layout and access: ``ops/transformer/
-        paged_attention.py``; the widths: ``TransformerConfig.kv_row``);
-        block 0 is the reserved trash block that masked/padded writes land
-        in."""
+        """The blocked KV pool: ONE array (``pool_layers``, kvh, NB, BS, row)
+        whose rows are ``[k_t | v_t]`` or, under latent attention,
+        ``[c_kv | k_rope]`` with one pool head (layout and access:
+        ``ops/transformer/paged_attention.py``; the widths and the layer
+        count: ``TransformerConfig.kv_row``, ``pool_layers``); block 0 is the
+        reserved trash block that masked/padded writes land in."""
         from ..ops.transformer.paged_attention import init_pool
 
         cfg = self.config
-        return init_pool(cfg.num_layers, cfg.pool_heads, num_blocks, block_size,
+        return init_pool(cfg.pool_layers, cfg.pool_heads, num_blocks, block_size,
                          cfg.kv_row, dtype)
 
     @property
@@ -1526,7 +1667,9 @@ class TransformerLM:
         chunk segments in tiles of ``segment_tile`` rows (:meth:`_block_mla`).
         ``moe_stats``: also return (rows, rows_max) int32 (2,): the (token,
         choice) pairs that landed on held experts summed over the layers, and
-        the busiest held expert's (``config.holds_experts`` only).
+        the busiest held expert's (``config.holds_experts`` only); with
+        ``moe_zero_experts`` (3,): behind them the live rows' choices of
+        identity experts, summed over the layers.
         """
         B, S = input_ids.shape
         if self.config.is_mla:
@@ -1579,7 +1722,10 @@ class TransformerLM:
         positions = starts[:, None]
         with jax.named_scope("embed"):
             x = self._embed(params, input_ids, positions, kv_pool.dtype)
-        stats = jnp.zeros((2,), jnp.int32)
+        # (rows, rows_max) of the held experts, and behind them the picks of
+        # identity experts where the router has such
+        stats = jnp.zeros((3 if self.config.moe_zero_experts else 2,),
+                          jnp.int32)
         layer0 = 0
         # a padding row carries the all-zero table (trash block 0, which no
         # sequence ever holds): it is routed to no expert
@@ -1603,7 +1749,8 @@ class TransformerLM:
                         experts=None if big is None else (big, l),
                         row_mask=row_mask)
                     if s is not None:
-                        st = jnp.stack([st[0] + s[0], jnp.maximum(st[1], s[1])])
+                        st = jnp.stack([st[0] + s[0], jnp.maximum(st[1], s[1]),
+                                        *(st[i] + s[i] for i in range(2, len(s)))])
                     return (y, pool, l + 1, st), None
 
                 (x, kv_pool, _, stats), _ = jax.lax.scan(

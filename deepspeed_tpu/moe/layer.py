@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sharded_moe import group_limited_gating, topk_gating
+from .sharded_moe import (group_limited_gating, softmax_topk_gating,
+                          topk_gating)
 
 
 def _constraint(x, spec):
@@ -167,27 +168,48 @@ def grouped_experts(x, chosen, weights, wi, w_gate, w_down, *, first: int = 0,
 def held_experts_ffn(x, wg, bias, wi, w_gate, w_down, shared=None, *, k: int,
                      n_group: int = 1, topk_group: int = 1,
                      normalize: bool = True, scale: float = 1.0,
-                     first: int = 0, layer=None, token_mask=None):
+                     first: int = 0, layer=None, token_mask=None,
+                     router: str = "group_limited", zero_experts: int = 0):
     """One routed layer that is told which experts it holds: the router
     (``wg`` (H, E_all), selection ``bias`` (E_all,)) runs over ALL its
-    outputs in float32 (:func:`group_limited_gating`), the layer computes its
+    outputs in float32 (``router``: :func:`group_limited_gating` or
+    :func:`softmax_topk_gating`), the layer computes its
     own experts' part for the tokens routed to them
     (:func:`grouped_experts`; ``first`` is the router output of the first
     held expert) and adds the ``shared`` expert ((w_gate, w_up, w_down),
     which every chip of the deployment computes alike). What the absent
     experts would have added is left out: there is no exchange here and
     nothing stands in for one. x (T, H); ``token_mask`` (T,) bool: rows that
-    are padding route nowhere. Returns (y, (rows, rows_max))."""
+    are padding route nowhere. Returns (y, (rows, rows_max)).
+
+    The router's last ``zero_experts`` outputs are identity (zero-compute)
+    experts: a token's choices among them add ``(sum of their weights) * x``,
+    computed where the token lives (no matrix, no row of the grouped
+    product, no exchange in a deployment). The counts are then (rows,
+    rows_max, zero_picks): the live rows' choices that went to them."""
     with jax.named_scope("moe_route"):
         logits = jnp.dot(x.astype(jnp.float32), wg.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        chosen, weights = group_limited_gating(
-            logits, bias, k=k, n_group=n_group, topk_group=topk_group,
-            normalize=normalize, scale=scale)
+        if router == "softmax_topk":
+            chosen, weights = softmax_topk_gating(
+                logits, bias, k=k, normalize=normalize, scale=scale)
+        elif router == "group_limited":
+            chosen, weights = group_limited_gating(
+                logits, bias, k=k, n_group=n_group, topk_group=topk_group,
+                normalize=normalize, scale=scale)
+        else:
+            raise ValueError(f"held_experts_ffn: unknown router {router!r}")
         if token_mask is not None:
             chosen = jnp.where(token_mask[:, None], chosen, -1)
     y, stats = grouped_experts(x, chosen, weights, wi, w_gate, w_down,
                                first=first, layer=layer)
+    if zero_experts:
+        with jax.named_scope("moe_zero"):
+            to_zero = chosen >= wg.shape[1] - zero_experts
+            w0 = jnp.sum(jnp.where(to_zero, weights, 0.0), axis=1)
+            y = (y.astype(jnp.float32)
+                 + w0[:, None] * x.astype(jnp.float32)).astype(x.dtype)
+            stats = (*stats, jnp.sum(to_zero, dtype=jnp.int32))
     if shared is not None:
         with jax.named_scope("moe_shared"):
             y = y + _gated_mlp(x, *shared)

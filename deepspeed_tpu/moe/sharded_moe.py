@@ -115,3 +115,25 @@ def group_limited_gating(logits, bias=None, *, k: int, n_group: int = 1,
     if normalize:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return chosen, w * scale
+
+
+def softmax_topk_gating(logits, bias=None, *, k: int, normalize: bool = False,
+                        scale: float = 1.0):
+    """LongCat-Flash routing: every token picks ``k`` of the router's ``E``
+    outputs (real and identity experts alike), no groups, no capacity, no
+    drop. Returns (chosen (T, k) int32 output ids, weights (T, k) float32).
+
+    Scores ``p`` are the softmax of the float32 ``logits`` (T, E). ``bias``
+    (E,) is added for SELECTION only and is held in units of the uniform
+    score ``1 / E`` (the published bias is of the scores' own size: a loader
+    multiplies the checkpoint's by ``E``). The weights are the unbiased ``p``
+    of the chosen times ``scale``, divided by their sum only if
+    ``normalize``."""
+    E = logits.shape[1]
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    biased = p if bias is None else p + bias.astype(jnp.float32) / E
+    chosen = lax.top_k(biased, k)[1].astype(jnp.int32)
+    w = jnp.take_along_axis(p, chosen, axis=1)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen, w * scale

@@ -93,7 +93,7 @@ def xla_attention(q, k, v, *, causal=True, q_offset=0, num_kv_groups=1,
         logits = jnp.where(mask[None, None, None], logits, jnp.float32(-1e30))
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
-    return out.reshape(B, Sq, nh, hd).astype(q.dtype)
+    return out.reshape(B, Sq, nh, v.shape[-1]).astype(q.dtype)
 
 
 def attention(q, k, v, *, causal=True, q_offset=0, num_kv_groups=1,

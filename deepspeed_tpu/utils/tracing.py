@@ -259,9 +259,10 @@ _scope_cache: Dict[tuple, Dict[str, str]] = {}
 MODEL_SCOPES = ("embed", "attn", "mlp", "lm_head_loss")
 SERVE_SCOPES = ("kv_write", "paged_attn", "sample")
 #: parts of a layer told apart inside a program without gradients: latent
-#: attention's projections, the expert layer's routing, grouped products and
-#: shared expert
-LAYER_SCOPES = ("mla_proj", "moe_route", "moe_experts", "moe_shared")
+#: attention's projections, the expert layer's routing, grouped products,
+#: shared expert and identity experts, a double layer's dense feed-forwards
+LAYER_SCOPES = ("mla_proj", "moe_route", "moe_experts", "moe_shared",
+                "moe_zero", "dense_ffn")
 
 
 def _abstract(x):
@@ -289,8 +290,8 @@ def classify(op_name: str) -> str:
     forward recomputed under the backward); ``bwd``; ``fwd`` (the
     differentiated forward); the serving scopes ``kv_write``, ``paged_attn``,
     ``sample``; a layer's own parts ``mla_proj``, ``moe_route``,
-    ``moe_experts``, ``moe_shared`` and else ``model`` (a model scope in a
-    program without gradients);
+    ``moe_experts``, ``moe_shared``, ``moe_zero``, ``dense_ffn`` and else
+    ``model`` (a model scope in a program without gradients);
     ``kv_carry`` (the paged program's layer scan itself, which carries the
     stacked pool: whatever it does to the pool besides the layers' own
     in-place writes); else ``unscoped``."""
