@@ -364,6 +364,7 @@ class InferenceEngineV2:
             segs = self._seg_tile > 1 and ids.shape[0] > self.max_seqs
             lg, pool, *stats = model.forward_paged(
                 params, ids, pool, tables, starts, logit_rows=logit_rows,
+                rows_apart=self._rows_apart(ids.shape[0]),
                 **({"seg_from": self.max_seqs} if segs else {}),
                 **({"moe_stats": True} if self._moe_stats and greedy else {}))
             if greedy:
@@ -386,6 +387,17 @@ class InferenceEngineV2:
                          donate_argnums=(1,), static_argnums=(13,))
         self._ragged_fn = fn
         return fn
+
+    def _rows_apart(self, rows: int) -> bool:
+        """Is the ragged program of ``rows`` padded rows the decode round's,
+        in which no two rows write one pool block? The engine builds the
+        steps, so it is the one who knows (``paged_attention.write_rows``):
+        the ``max_seqs``-row shape is only ever given one row a sequence
+        (:meth:`_build_ragged_step`, :meth:`decode_dispatch`), and a
+        sequence's write block is its own, a shared prefix block being
+        copied on write before the dispatch. Where the budget leaves no
+        second shape, every step is built on the mixed one."""
+        return self.token_budget > self.max_seqs and rows == self.max_seqs
 
     def lower_ragged(self, rows: int, greedy: bool = True):
         """The paged program lowered at ``rows`` token-rows, as a
@@ -1047,7 +1059,9 @@ class InferenceEngineV2:
         ragged step counts after its descriptors advanced; a ``fused``
         K-position program (fused decode, verify) before, and all its rows
         are decode rows. Blocks and copies are those since the previous
-        dispatch."""
+        dispatch. ``live_write``: the step's KV write went row by row for
+        its live rows (1), or through the scatter over all its padded rows
+        (0)."""
         mgr = self.block_mgr
         marks = (mgr.allocations, mgr.stats["cow_copies"])
         if disp.recording:
@@ -1065,6 +1079,10 @@ class InferenceEngineV2:
             disp.set(padded_rows=padded_rows, rows=rows, decode_rows=decode,
                      prefill_tokens=rows - decode, seg_tokens=seg,
                      seqs=len(plan),
+                     live_write=int(not fused
+                                    and self._rows_apart(padded_rows)
+                                    and paged_attention.writes_live_rows(
+                                        self.kv)),
                      ctx_tokens=ctx, ctx_tokens_by_row=by_row,
                      blocks_allocated=max(0, marks[0] - self._count_marks[0]),
                      cow_copies=max(0, marks[1] - self._count_marks[1]))

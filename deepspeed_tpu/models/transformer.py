@@ -26,7 +26,6 @@ Architecture choices driven by XLA/TPU:
 """
 
 import math
-import os
 import re
 from dataclasses import dataclass, field, replace
 from functools import partial
@@ -841,7 +840,7 @@ class TransformerLM:
 
     # ------------------------------------------------------------------
     def _block(self, x, blk, *, positions, rng, train, kv_cache=None, cache_index=None,
-               paged=None, attn_mask_bias=None):
+               paged=None, attn_mask_bias=None, rows_apart=False):
         """One transformer block on (B, S, H). Returns (y, new_kv) where new_kv is
         the updated (k, v) when decoding with a cache.
 
@@ -854,7 +853,9 @@ class TransformerLM:
         for one-token rows or the table-gathered logical cache with a
         per-sequence position mask otherwise (covers chunked prefill AND
         decode — reference ``inference/v2/ragged_ops/blocked_flash`` +
-        ``kv_cache.py BlockedKVCache``)."""
+        ``kv_cache.py BlockedKVCache``). ``rows_apart`` (static): the
+        caller's promise that no two rows write one pool block
+        (``paged_attention.write_rows``)."""
         cfg = self.config
         if cfg.is_mla:
             if kv_cache is not None or attn_mask_bias is not None or (
@@ -863,7 +864,7 @@ class TransformerLM:
                     "attention='mla' has the full-sequence and the paged "
                     "paths only: no slot cache, padding mask or dropout")
             y, pool, _ = self._block_mla(x, blk, positions=positions,
-                                         paged=paged)
+                                         paged=paged, rows_apart=rows_apart)
             return y, pool, jnp.zeros((), jnp.float32)
         nh, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         B, S, H = x.shape
@@ -918,17 +919,14 @@ class TransformerLM:
                 pool, layer, tables = paged
                 BS = pool.shape[3]
                 with jax.named_scope("kv_write"):
-                    pool = pa.write_rows(pool, layer, tables, positions, kk, v)
+                    pool = pa.write_rows(pool, layer, tables, positions, kk, v,
+                                         rows_apart=rows_apart)
                 new_kv = pool
                 with jax.named_scope("paged_attn"):
-                    from ..ops.transformer.attention import get_default_impl
-
                     # NOTE: evaluated at TRACE time — the env override (used by tests
                     # to exercise this branch in interpret mode) and set_default_impl
                     # must be set before the engine compiles its decode program
-                    want_kernel = S == 1 and get_default_impl() != "xla" and (
-                        jax.default_backend() == "tpu"
-                        or os.environ.get("DSTPU_FORCE_PAGED_KERNEL") == "1")
+                    want_kernel = S == 1 and pa.kernels_wanted()
                     # what the kernel documents as unsupported; each gives way to the
                     # gather path below, and says so as the program is traced
                     gaps = [why for bad, why in (
@@ -1047,7 +1045,7 @@ class TransformerLM:
         return x + mlp_out, new_kv, aux
 
     def _block_mla(self, x, blk, *, positions, paged=None, seg_from=None,
-                   experts=None, row_mask=None):
+                   experts=None, row_mask=None, rows_apart=False):
         """One latent-attention block on (B, S, H): a dense layer, an expert
         layer where ``blk`` holds a router, or a shortcut-connected double
         layer (``layer_kind="scmoe"``, :meth:`_block_scmoe`). Returns (y, new
@@ -1066,16 +1064,17 @@ class TransformerLM:
         rows of sequences of their own. ``experts``: (stacked expert leaves,
         layer of the group) when the caller kept them out of ``blk``.
         ``row_mask`` (B*S,) bool: the rows that are real tokens (padding rows
-        are routed to no expert)."""
+        are routed to no expert). ``rows_apart``: as :meth:`_block`."""
         from ..moe.layer import _gated_mlp
 
         if self.config.layer_kind == "scmoe":
             return self._block_scmoe(x, blk, positions=positions, paged=paged,
                                      seg_from=seg_from, experts=experts,
-                                     row_mask=row_mask)
+                                     row_mask=row_mask, rows_apart=rows_apart)
         blk = _dequant_woq(blk, x.dtype)
         attn_out, new_pool = self._mla_attention(
-            x, blk, positions=positions, paged=paged, seg_from=seg_from)
+            x, blk, positions=positions, paged=paged, seg_from=seg_from,
+            rows_apart=rows_apart)
         stats = None
         with jax.named_scope("mlp"):
             x = jax.lax.optimization_barrier(x + attn_out)
@@ -1089,7 +1088,7 @@ class TransformerLM:
         return x + mlp_out, new_pool, stats
 
     def _block_scmoe(self, x, blk, *, positions, paged, seg_from, experts,
-                     row_mask):
+                     row_mask, rows_apart=False):
         """One shortcut-connected double layer (LongCat-Flash, ScMoE): two
         sublayers of latent attention and a dense feed-forward, and one
         expert layer computed from the first sublayer's post-attention norm
@@ -1113,7 +1112,7 @@ class TransformerLM:
             sub = sublayer(blk, i)
             attn_out, pool = self._mla_attention(
                 x, sub, positions=positions, seg_from=seg_from,
-                paged=None if paged is None
+                rows_apart=rows_apart, paged=None if paged is None
                 else (pool, n_sub * layer + i, tables))
             with jax.named_scope("mlp"):
                 x = once(x + attn_out)
@@ -1150,11 +1149,13 @@ class TransformerLM:
             zero_experts=cfg.moe_zero_experts)
         return y.reshape(B, S, H), stats
 
-    def _mla_attention(self, x, blk, *, positions, paged=None, seg_from=None):
+    def _mla_attention(self, x, blk, *, positions, paged=None, seg_from=None,
+                       rows_apart=False):
         """Latent attention of one (sub)layer on the residual stream ``x``
         (B, S, H), its input norm and output projection included: (the
         attention's output, the new pool or None). ``blk``: the layer's
-        leaves; ``paged`` and ``seg_from`` as :meth:`_block_mla`."""
+        leaves; ``paged``, ``seg_from`` and ``rows_apart`` as
+        :meth:`_block_mla`."""
         from ..ops.transformer import paged_attention as pa
 
         cfg = self.config
@@ -1209,7 +1210,8 @@ class TransformerLM:
                     pool = pa.write_rows(
                         pool, layer, tables, positions, c_kv[:, :, None, :],
                         jnp.pad(k_rope, ((0, 0),) * 3
-                                + ((0, cfg.kv_row[1] - rope),)))
+                                + ((0, cfg.kv_row[1] - rope),)),
+                        rows_apart=rows_apart)
                 new_pool = pool
                 with jax.named_scope("mla_proj"):
                     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
@@ -1230,12 +1232,8 @@ class TransformerLM:
         The Pallas kernel on a TPU (or forced, as the GPT-2 path's is), the
         XLA gather off it."""
         from ..ops.transformer import paged_attention as pa
-        from ..ops.transformer.attention import get_default_impl
 
-        use_kernel = get_default_impl() != "xla" and (
-            jax.default_backend() == "tpu"
-            or os.environ.get("DSTPU_FORCE_PAGED_KERNEL") == "1")
-        attend = pa.mla_decode if use_kernel else pa.mla_attend_xla
+        attend = pa.mla_decode if pa.kernels_wanted() else pa.mla_attend_xla
         T = q_lat.shape[0]
         cut = T if seg_from is None else seg_from
         parts = []
@@ -1653,7 +1651,7 @@ class TransformerLM:
 
     def forward_paged(self, params, input_ids, kv_pool, tables, starts,
                       n_valid=None, logit_rows=None, seg_from=None,
-                      moe_stats=False):
+                      moe_stats=False, rows_apart=False):
         """Run a (B, S) segment against the blocked pool.
 
         tables: (B, MAXB) pool block ids per sequence (0-padded); starts: (B,)
@@ -1665,6 +1663,10 @@ class TransformerLM:
 
         ``seg_from`` (latent attention only; static): rows from it on are
         chunk segments in tiles of ``segment_tile`` rows (:meth:`_block_mla`).
+        ``rows_apart`` (static): the builder of the step promises that no two
+        of its rows write one pool block (a decode round: one row a sequence,
+        shared blocks copied on write before the dispatch), which is what
+        lets ``paged_attention.write_rows`` write the live rows alone.
         ``moe_stats``: also return (rows, rows_max) int32 (2,): the (token,
         choice) pairs that landed on held experts summed over the layers, and
         the busiest held expert's (``config.holds_experts`` only); with
@@ -1675,7 +1677,7 @@ class TransformerLM:
         if self.config.is_mla:
             return self._forward_paged_mla(params, input_ids, kv_pool, tables,
                                            starts, logit_rows, seg_from,
-                                           moe_stats)
+                                           moe_stats, rows_apart)
         positions = starts[:, None] + jnp.broadcast_to(
             jnp.arange(S, dtype=jnp.int32), (B, S))
         dtype = kv_pool.dtype
@@ -1686,7 +1688,7 @@ class TransformerLM:
             h, pool, layer = carry
             y, pool, _ = self._block(
                 h, blk, positions=positions, rng=None, train=False,
-                paged=(pool, layer, tables),
+                paged=(pool, layer, tables), rows_apart=rows_apart,
             )
             return (y, pool, layer + 1), None
 
@@ -1710,7 +1712,7 @@ class TransformerLM:
         return lg, kv_pool
 
     def _forward_paged_mla(self, params, input_ids, kv_pool, tables, starts,
-                           logit_rows, seg_from, moe_stats):
+                           logit_rows, seg_from, moe_stats, rows_apart):
         """:meth:`forward_paged` of a latent-attention model: one-token rows
         (T, 1), the layer groups scanned in turn with the latent pool as the
         carry. The stacked expert matrices stay out of the scanned leaves:
@@ -1747,7 +1749,7 @@ class TransformerLM:
                         h, blk, positions=positions,
                         paged=(pool, l + layer0, tables), seg_from=seg_from,
                         experts=None if big is None else (big, l),
-                        row_mask=row_mask)
+                        row_mask=row_mask, rows_apart=rows_apart)
                     if s is not None:
                         st = jnp.stack([st[0] + s[0], jnp.maximum(st[1], s[1]),
                                         *(st[i] + s[i] for i in range(2, len(s)))])

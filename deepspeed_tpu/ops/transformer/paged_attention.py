@@ -20,8 +20,10 @@ configuration gives (``TransformerConfig.kv_row``):
   :func:`mla_decode` attends over it in the absorbed form.
 
 Every program touches it through the functions here: the model writes whole
-rows at computed row numbers of the pool viewed flat (:func:`write_rows`, in
-place on a donated buffer) and reads either through the kernel
+rows (:func:`write_rows`, in place on a donated buffer: a scatter at computed
+row numbers of the pool viewed flat, or, for a decode round, the kernel
+:func:`kv_write`, which writes the live rows alone) and reads either through
+the kernel
 (:func:`paged_decode`, which streams ``pool[layer, heads, block]`` tiles from
 HBM by the scalar-prefetched layer and block table) or through one XLA gather
 with the layer among its indices (:func:`gather_context`); the engine's block
@@ -40,6 +42,7 @@ The kernel does not look at the table to decide it.
 """
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -86,22 +89,43 @@ def _k_width(pool, k_width):
     return pool.shape[-1] // 2 if k_width is None else k_width
 
 
-def write_rows(pool, layer, tables, positions, k, v):
+def write_rows(pool, layer, tables, positions, k, v, *, rows_apart=False):
     """Write the (B, S) new tokens' keys and values of layer ``layer`` into
-    the pool: k, v (B, S, kvh, hd) land as whole ``[k | v]`` rows at
-    ``((layer*kvh + head)*NB + block)*BS + offset`` of the pool viewed flat
-    as (rows, 2*hd), which is a bitcast of the row-major pool, so the scatter
-    updates the carried buffer in place. Padding rows all land in trash
-    block 0, so the row numbers are not unique."""
+    the pool, in place on the carried buffer: k, v (B, S, kvh, hd) land as
+    whole ``[k | v]`` rows at ``pool[layer, head, block, offset]``.
+
+    Two forms, one result on every block a sequence holds:
+
+    - the XLA scatter: one index a (row, kv head) into the pool viewed flat
+      as (rows, 2*hd), a bitcast of the row-major pool, whatever the rows
+      hold. Padding rows all land in trash block 0, so the row numbers are
+      not unique.
+    - :func:`kv_write`, where the caller says ``rows_apart`` (static) and
+      :func:`writes_live_rows` takes the pool's shape: one copy a LIVE row
+      over all its kv heads, nothing for a row whose block is the trash
+      block. The kernel cannot write one token's row alone (Mosaic slices
+      the token dimension of an HBM array only by whole tiles of 8 rows, and
+      at bfloat16 two rows even share a 32-bit sublane); it reads the
+      aligned sub-tile of :data:`SUB_TILE` tokens that holds the row, sets
+      the row and writes the sub-tile back, which is exact only where NO TWO
+      ROWS OF THE STEP LAND IN ONE POOL BLOCK. That is what ``rows_apart``
+      promises, and only the builder of the step can: a decode round has one
+      row a sequence and a sequence's write block is its own (shared prefix
+      blocks are copied on write before the dispatch). A mixed step, whose
+      chunk rows are neighbours in one block, keeps the scatter."""
     L, kvh, NB, BS, row = pool.shape
+    blk = jnp.take_along_axis(tables, positions // BS, axis=1)  # (B, S)
+    kv = jnp.concatenate((k, v), axis=-1).astype(pool.dtype)
+    if rows_apart and writes_live_rows(pool):
+        if blk.shape[1] != 1:
+            raise ValueError("rows that are apart are one token each")
+        return kv_write(pool, layer, blk[:, 0], positions[:, 0] % BS, kv[:, 0])
     if L * kvh * NB * BS >= 2 ** 31:
         raise ValueError(f"pool {pool.shape} has more rows than int32 "
                          "row numbers reach")
-    blk = jnp.take_along_axis(tables, positions // BS, axis=1)  # (B, S)
     head0 = (layer * kvh + jnp.arange(kvh, dtype=jnp.int32)) * NB
     rows = ((head0[None, None, :] + blk[:, :, None]) * BS
             + (positions % BS)[:, :, None])  # (B, S, kvh)
-    kv = jnp.concatenate((k, v), axis=-1).astype(pool.dtype)
     return pool.reshape(-1, row).at[rows].set(kv).reshape(pool.shape)
 
 
@@ -161,6 +185,146 @@ def heads_per_cell(pool) -> int:
     _, kvh, _, BS, row = pool.shape
     fit = DECODE_BUFFER_BYTES // (2 * BS * row * pool.dtype.itemsize)
     return max(d for d in range(1, kvh + 1) if kvh % d == 0 and d <= max(fit, 1))
+
+
+def kernels_wanted() -> bool:
+    """Do the paged programs take the Pallas kernels here? On a TPU, or
+    forced (``DSTPU_FORCE_PAGED_KERNEL=1``: tests, interpreted on the CPU),
+    unless the attention implementation is pinned to XLA. Read as a program
+    is traced."""
+    from .attention import get_default_impl
+
+    return get_default_impl() != "xla" and (
+        jax.default_backend() == "tpu"
+        or os.environ.get("DSTPU_FORCE_PAGED_KERNEL") == "1")
+
+
+#: bytes of VMEM :func:`kv_write` may take for the sub-tiles in flight
+WRITE_BUFFER_BYTES = 2 * 1024 * 1024
+#: tokens of the aligned piece of a pool block that :func:`kv_write` reads
+#: and writes back around one row: the rows of one HBM tile, (8, 128)
+#: whatever the dtype (bfloat16 packs two rows to a 32-bit sublane inside
+#: it). On the chip 8 beat 16: 0.32 against 0.45 ms a round of 64 live rows
+SUB_TILE = 8
+
+
+def writes_live_rows(pool) -> bool:
+    """Does :func:`write_rows` take :func:`kv_write` for a step whose rows
+    are apart? Where the kernels are wanted and the pool's blocks are whole
+    sub-tiles of whole 128-lane rows."""
+    _, _, _, BS, row = pool.shape
+    return kernels_wanted() and BS % SUB_TILE == 0 and row % 128 == 0
+
+
+def _write_slots(pool) -> int:
+    """Sub-tiles :func:`kv_write` keeps in flight, from the pool's shape and
+    dtype alone (as :func:`heads_per_cell`): what fits
+    :data:`WRITE_BUFFER_BYTES`, between 2 and 16."""
+    _, kvh, _, _, row = pool.shape
+    slab = kvh * SUB_TILE * row * pool.dtype.itemsize
+    return max(2, min(16, WRITE_BUFFER_BYTES // slab))
+
+
+def _kv_write_kernel(layer_ref, blk_ref, off_ref, kv_ref, pool_in, pool_ref,
+                     buf, rsem, wsem, *, rows, sub, slots):
+    """One cell. Row ``b`` is live where ``blk[b] > 0``; for a live row the
+    aligned sub-tile ``pool[layer, :, blk[b], off[b] // sub * sub : +sub]``
+    (``kvh`` pieces of (sub, row), each contiguous) is fetched into a slot of
+    ``buf``, the row ``off[b] % sub`` of every head is set from ``kv[b]``,
+    and the slot is written back where it came from. A software pipeline
+    over the rows: row ``i``'s fetch starts ``slots // 2`` trips before it is
+    needed, and a slot is fetched into again once the write from it, started
+    ``slots`` rows ago, is done. ``blk`` and ``off`` are padded by ``slots``
+    dead rows, which drain the pipeline. A dead row starts nothing."""
+    del pool_in  # the same buffer as ``pool_ref``: input_output_aliases
+    layer = layer_ref[0]
+    ahead = slots // 2
+
+    def piece(b):
+        start = pl.multiple_of(off_ref[b] // sub * sub, sub)
+        return pool_ref.at[layer, :, blk_ref[b], pl.ds(start, sub)]
+
+    def slot_of(b):
+        return jax.lax.rem(b, slots)
+
+    def fetch(b):
+        return pltpu.make_async_copy(piece(b), buf.at[slot_of(b)],
+                                     rsem.at[slot_of(b)])
+
+    def store(b):
+        return pltpu.make_async_copy(buf.at[slot_of(b)], piece(b),
+                                     wsem.at[slot_of(b)])
+
+    def trip(i, _):
+        freed = jnp.maximum(i - slots, 0)
+
+        @pl.when((i >= slots) & (blk_ref[freed] > 0))
+        def _slot_is_free():
+            store(freed).wait()
+
+        @pl.when(blk_ref[i] > 0)
+        def _fetch():
+            fetch(i).start()
+
+        b = jnp.maximum(i - ahead, 0)
+
+        @pl.when((i >= ahead) & (blk_ref[b] > 0))
+        def _set_row():
+            fetch(b).wait()
+            slot = slot_of(b)
+            # through float32, which every bfloat16 survives unchanged: the
+            # select is on whole 32-bit sublanes
+            tile = buf[slot].astype(jnp.float32)           # (kvh, sub, row)
+            new = kv_ref[b].astype(jnp.float32)            # (kvh, 1, row)
+            at = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+            buf[slot] = jnp.where(at == jax.lax.rem(off_ref[b], sub), new,
+                                  tile).astype(buf.dtype)
+            store(b).start()
+
+        return 0
+
+    jax.lax.fori_loop(0, rows + slots, trip, 0)
+
+
+def kv_write(pool, layer, blk, off, kv):
+    """Set ``pool[layer, :, blk[b], off[b]] = kv[b]`` for every row ``b``
+    with ``blk[b] > 0``, in place: the pool is left in HBM whole and aliased
+    to the result, so a donated, carried buffer is written where it lies,
+    and a row whose block is trash block 0 writes nothing.
+
+    pool (L, kvh, NB, BS, row); layer: int32 scalar (traced or not); blk,
+    off (B,) int32: each row's pool block and its token's offset in it; kv
+    (B, kvh, row): the rows. NO TWO LIVE ROWS MAY NAME THE SAME BLOCK: a
+    row's write is a read-modify-write of its :data:`SUB_TILE` neighbours
+    (see :func:`write_rows`), and two in one sub-tile would race."""
+    B, kvh, row = kv.shape
+    slots = _write_slots(pool)
+    pad = jnp.zeros((slots,), jnp.int32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # layer, blk, off
+        grid=(1,),
+        in_specs=[pl.BlockSpec((B, kvh, 1, row), lambda *_: (0, 0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],  # the pool stays in HBM
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((slots, kvh, SUB_TILE, row), pool.dtype),
+            pltpu.SemaphoreType.DMA((slots,)),
+            pltpu.SemaphoreType.DMA((slots,)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_kv_write_kernel, rows=B, sub=SUB_TILE, slots=slots),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={4: 0},  # the pool, counting the scalar operands
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+        name="kv_write",
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      jnp.concatenate((blk.astype(jnp.int32), pad)),
+      jnp.concatenate((off.astype(jnp.int32), pad)),
+      kv.astype(pool.dtype)[:, :, None, :], pool)
 
 
 def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,

@@ -20,6 +20,7 @@ fixture: only the worker that runs this file loads libtpu, and every worker
 collects the same tests.
 """
 
+import functools
 import math
 import re
 
@@ -118,6 +119,33 @@ def test_paged_decode_compiles(one_chip, no_compile_cache, as_tpu, rows):
         aval(one_chip, (rows, MAXB), jnp.int32),
         aval(one_chip, (rows,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape,rows,dtype", [
+    ((24, NH, CELL_NB, BS, 2 * HD), CELL_SEQS, jnp.bfloat16),  # serve-chat
+    ((24, NH, 416, BS, 256), CELL_SEQS, jnp.bfloat16),   # Pythia-1.4B, hd 128
+    ((5, 1, 3072, BS, 640), 32, jnp.bfloat16),    # serve-longdoc's latent pool
+    ((8, 1, 2560, BS, 640), 96, jnp.bfloat16),    # serve-longout's
+    ((4, 4, NB, 16, 2 * HD), 8, jnp.float32),     # a float32 pool, blocks of 16
+])
+def test_kv_write_compiles(one_chip, no_compile_cache, as_tpu, shape, rows,
+                           dtype):
+    """The live-row write takes the sub-tile form Mosaic accepts (a one-row
+    slice of a bfloat16 pool's token dimension it refuses), and writes the
+    donated pool where it lies."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    pool = aval(one_chip, shape, dtype)
+    assert pa.writes_live_rows(pool)
+    i32 = functools.partial(aval, one_chip, dtype=jnp.int32)
+    compiled = jax.jit(pa.kv_write, donate_argnums=(0,)).lower(
+        pool, i32(()), i32((rows,)), i32((rows,)),
+        aval(one_chip, (rows, shape[1], shape[4]), dtype)).compile()
+    text = compiled.as_text()
+    assert re.search(r"%kv_write[.\d]* = \S+ custom-call\(", text)
+    assert pool_sized_movers(text, math.prod(shape) // shape[0]) == []
+    assert "may-alias" in text or "must-alias" in text, \
+        "the donated pool is no longer written in place"
 
 
 @pytest.mark.parametrize("rows", ROWS)
@@ -219,13 +247,19 @@ def test_serving_program_moves_no_pool(one_chip, no_compile_cache, as_tpu,
     params, pool, tables, starts = serving_avals(one_chip, model, rows)
 
     def ragged(params, pool, ids, tables, starts, logit_rows):
+        # as the engine calls it: the decode round's rows are apart
         return model.forward_paged(params, ids, pool, tables, starts,
-                                   logit_rows=logit_rows)
+                                   logit_rows=logit_rows,
+                                   rows_apart=rows == CELL_SEQS)
 
     compiled = jax.jit(ragged, donate_argnums=(1,)).lower(
         params, pool, aval(one_chip, (rows, 1), jnp.int32), tables, starts,
         aval(one_chip, (CELL_SEQS,), jnp.int32)).compile()
     assert_moves_no_pool(compiled, pool, layers)
+    # the decode round writes through the kernel, the mixed step scatters
+    kernel = re.search(r"%kv_write[.\d]* = \S+ custom-call\(",
+                       compiled.as_text())
+    assert bool(kernel) == (rows == CELL_SEQS)
 
 
 @pytest.mark.parametrize("program", ["fused", "verify"])

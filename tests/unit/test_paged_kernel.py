@@ -104,6 +104,58 @@ def test_engine_kernel_path_matches_xla_path(monkeypatch):
         np.testing.assert_allclose(a, b, atol=3e-5)
 
 
+def test_engine_live_write_matches_the_scatter(monkeypatch):
+    """Decode rounds go through ``kv_write`` (the engine says their rows are
+    apart), a mixed step through the scatter: a run of a prefill, decode
+    rounds, a second prompt admitted beside a live decode (a mixed step) and
+    more rounds gives the tokens and, on every block but the trash block, the
+    pool of the engine that scatters throughout. Both take the decode
+    kernel, so the pools are equal bit for bit."""
+    import deepspeed_tpu.comm.topology as topo_mod
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+    from deepspeed_tpu.models import build_model
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+    from deepspeed_tpu.utils import tracing
+
+    topo_mod.reset_topology()
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    m = build_model("llama-tiny", vocab_size=128, hidden_size=128, num_layers=2,
+                    num_heads=2, num_kv_heads=2, intermediate_size=128,
+                    max_seq_len=64)
+    params = m.init_params(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(11)
+    first, second = (rng.integers(0, 128, (n,)).tolist() for n in (9, 21))
+
+    def run(live_write):
+        if not live_write:
+            monkeypatch.setattr(pa, "writes_live_rows", lambda pool: False)
+        eng = InferenceEngineV2(m, params, max_seqs=4, max_seq_len=64,
+                                prefill_chunk=8, token_budget=12, paged=True,
+                                block_size=16, dtype=jnp.bfloat16)
+        assert eng._rows_apart(4) and not eng._rows_apart(12)
+        toks = [eng.put([1], [first], greedy=True)[1]]
+        for _ in range(3):                                   # decode rounds
+            toks.append(eng.decode_step({1: toks[-1]}, greedy=True)[1])
+        # the second prompt's first chunk rides with uid 1's decode: mixed
+        out = eng.put([1, 2], [[toks[-1]], second], greedy=True)
+        toks.append(out[1])
+        last = {1: out[1], 2: out[2]}
+        for _ in range(10):                                  # crosses a block
+            last = eng.decode_step(last, greedy=True)
+            toks += [last[1], last[2]]
+        held = sorted({b for d in eng.state.seqs.values() for b in d.blocks})
+        return toks, np.asarray(eng.kv.astype(jnp.float32)), held
+
+    toks_k, pool_k, held = run(True)
+    toks_s, pool_s, held_s = run(False)
+    assert toks_k == toks_s and held == held_s and 0 not in held
+    np.testing.assert_array_equal(pool_k[:, :, 1:], pool_s[:, :, 1:])
+    assert np.abs(pool_k[:, :, held]).sum() > 0
+    # the decode rounds' padding rows wrote nothing: what the trash block
+    # holds is what the mixed steps' scatter left there
+    assert np.abs(pool_s[:, :, 0]).sum() > 0
+
+
 def test_paged_decode_long_context_8k():
     """ctx >= 8k stays on the Pallas path: the kernel streams one pool block
     per grid step (no VMEM window over the whole context), so an 8192-token
@@ -290,6 +342,20 @@ def _model_and_pool(L, kvh, hd, BS, NB, MAXB):
     return m, params, before
 
 
+def _mostly_padding_step(BS, NB, MAXB=3):
+    """A three-layer model, a pool of random rows and a step of 12 one-token
+    rows of which rows 2 and 9 are live sequences (the rest carry the
+    all-zero table): (model, params, pool, live, tables, starts, ids)."""
+    m, params, before = _model_and_pool(3, 2, 64, BS, NB, MAXB)
+    live = np.asarray([2, 9])
+    tables = np.zeros((12, MAXB), np.int32)
+    tables[live] = [[7, 2, 0], [4, 9, 5]]
+    starts = np.zeros(12, np.int32)
+    starts[live] = [6, 17]
+    ids = jax.random.randint(jax.random.PRNGKey(2), (12, 1), 0, 128)
+    return m, params, before, live, tables, starts, ids
+
+
 @pytest.mark.parametrize("S", [1, 3])  # ragged rows of one token; a segment
 def test_forward_paged_writes_only_its_rows(monkeypatch, S):
     """A three-layer ``forward_paged`` writes exactly the rows of its tokens
@@ -341,14 +407,8 @@ def test_forward_paged_marks_padding_rows_dead(monkeypatch):
     rows' own tokens differs, and the live rows' logits and pool rows are
     those of the batch without the padding rows."""
     monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
-    BS, NB, MAXB = 8, 12, 3
-    m, params, before = _model_and_pool(3, 2, 64, BS, NB, MAXB)
-    live = np.asarray([2, 9])
-    tables = np.zeros((12, MAXB), np.int32)
-    tables[live] = [[7, 2, 0], [4, 9, 5]]
-    starts = np.zeros(12, np.int32)
-    starts[live] = [6, 17]
-    ids = jax.random.randint(jax.random.PRNGKey(2), (12, 1), 0, 128)
+    BS, NB = 8, 12
+    m, params, before, live, tables, starts, ids = _mostly_padding_step(BS, NB)
 
     fwd = jax.jit(m.forward_paged)
     lg, after = fwd(params, ids, before, jnp.asarray(tables), jnp.asarray(starts))
@@ -396,3 +456,151 @@ def test_the_models_call_gives_padding_rows_lens_zero(monkeypatch):
     jax.block_until_ready(m.forward_paged(
         params, jnp.zeros((4, 1), jnp.int32), pool, tables, starts))
     assert told and all(t.tolist() == [0, 6, 0, 12] for t in told)
+
+
+# ----------------------------------------------------------------------
+# kv_write: a decode round's live rows, one copy each over all kv heads
+# ----------------------------------------------------------------------
+#: which of 8 rows hold a block
+LIVE = {"none": [], "one": [0], "a-few": [0, 1, 2], "all": list(range(8)),
+        "not-a-prefix": [1, 4, 7]}
+#: (kv heads, head size or (key, value) widths of a latent row)
+LAYOUTS = {"kvh1-hd64": (1, 64), "kvh2-hd128": (2, 128),
+           "kvh16-hd256": (16, 256), "latent-640": (1, (512, 128))}
+
+
+def _write_case(kvh, hd, live, *, L=3, BS=32, MAXB=2, dtype=jnp.bfloat16,
+                seed=0):
+    """A pool of random rows, a step of 8 one-token rows of which ``live``
+    hold blocks (their own: no two rows share one), and the rows' k, v."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    rng = np.random.default_rng(seed)
+    B = 8
+    NB = 1 + B * MAXB
+    shape = pa.init_pool(L, kvh, NB, BS, hd, dtype).shape
+    pool = jnp.asarray(rng.standard_normal(shape), dtype)
+    tables = np.zeros((B, MAXB), np.int32)
+    pos = np.zeros((B, 1), np.int32)
+    blocks = rng.permutation(np.arange(1, NB)).reshape(B, MAXB)
+    for b in live:
+        tables[b] = blocks[b]
+        pos[b, 0] = rng.integers(0, MAXB * BS)
+    kw, vw = (hd, hd) if isinstance(hd, int) else hd
+    k = jnp.asarray(rng.standard_normal((B, 1, kvh, kw)), dtype)
+    v = jnp.asarray(rng.standard_normal((B, 1, kvh, vw)), dtype)
+    return pa, pool, jnp.asarray(tables), jnp.asarray(pos), k, v
+
+
+def _bits(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+@pytest.mark.parametrize("live", list(LIVE))
+def test_kv_write_equals_the_scatter_off_the_trash_block(monkeypatch, live,
+                                                         layout, layer):
+    """``write_rows`` of rows that are apart, through the kernel: every
+    block but block 0 is bit for bit the scatter's, block 0 is as it was (a
+    padding row writes nothing), and with no live row the whole pool is."""
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    kvh, hd = LAYOUTS[layout]
+    pa, pool, tables, pos, k, v = _write_case(kvh, hd, LIVE[live])
+    assert pa.writes_live_rows(pool)
+    l = jnp.int32(0 if layer == "first" else pool.shape[0] - 1)
+    scattered = pa.write_rows(pool, l, tables, pos, k, v)
+    written = jax.jit(
+        lambda *a: pa.write_rows(*a, rows_apart=True))(pool, l, tables, pos,
+                                                       k, v)
+    before, s, w = _bits(pool), _bits(scattered), _bits(written)
+    np.testing.assert_array_equal(w[:, :, 1:], s[:, :, 1:])
+    np.testing.assert_array_equal(w[:, :, 0], before[:, :, 0])
+    assert (w != before).any() == bool(LIVE[live])
+    if len(LIVE[live]) < 8:
+        assert (s[:, :, 0] != before[:, :, 0]).any(), \
+            "the scatter's padding rows land in the trash block"
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kv_write_keeps_a_neighbours_sub_tile(monkeypatch, dtype):
+    """Two live rows in neighbouring pool blocks, one at the last token of
+    its block and one at the first of the next: each row's read-modify-write
+    of its sub-tile leaves the other's alone, and every row of the pool but
+    the two is what it was, in every layer's turn and every head."""
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    BS = 32
+    pa, pool, _, _, k, v = _write_case(2, 64, [0, 1], BS=BS, dtype=dtype)
+    assert BS // pa.SUB_TILE >= 2
+    tables = np.zeros((8, 2), np.int32)
+    tables[0], tables[1] = [5, 9], [6, 10]
+    pos = np.zeros((8, 1), np.int32)
+    pos[0, 0], pos[1, 0] = BS - 1, 0
+    out = pool
+    for l in range(pool.shape[0]):
+        out = pa.write_rows(out, jnp.int32(l), jnp.asarray(tables),
+                            jnp.asarray(pos), k, v, rows_apart=True)
+    before, after = _bits(pool), _bits(out)
+    kv = _bits(jnp.concatenate((k, v), axis=-1))[:, 0]       # (B, kvh, row)
+    for r, (blk, off) in enumerate(((5, BS - 1), (6, 0))):
+        np.testing.assert_array_equal(
+            after[:, :, blk, off], np.broadcast_to(kv[r], after.shape[:2]
+                                                   + kv.shape[2:]))
+    untouched = np.ones(before.shape[2:4], bool)
+    untouched[5, BS - 1] = untouched[6, 0] = False
+    np.testing.assert_array_equal(after[:, :, untouched],
+                                  before[:, :, untouched])
+
+
+@pytest.mark.parametrize("BS,hd,dtype,forced,takes", [
+    (16, 64, jnp.bfloat16, True, True),
+    (8, 64, jnp.float32, True, True),
+    (4, 64, jnp.bfloat16, True, False),     # a block is half a sub-tile
+    (16, 32, jnp.bfloat16, True, False),    # a row of 64 lanes
+    (16, 64, jnp.bfloat16, False, False),   # no TPU and not forced
+])
+def test_write_rows_takes_the_kernel_where_the_pool_allows(monkeypatch, BS, hd,
+                                                           dtype, forced,
+                                                           takes):
+    """``writes_live_rows`` reads the pool's shape and the same rule that
+    chooses ``paged_decode``; where it says no, rows that are apart go
+    through the scatter, trash block and all."""
+    if forced:
+        monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    else:
+        monkeypatch.delenv("DSTPU_FORCE_PAGED_KERNEL", raising=False)
+    pa, pool, tables, pos, k, v = _write_case(2, hd, [0, 3], BS=BS, MAXB=1,
+                                              dtype=dtype)
+    assert pa.writes_live_rows(pool) == takes
+    called = []
+    kernel = pa.kv_write
+    monkeypatch.setattr(pa, "kv_write",
+                        lambda *a: called.append(1) or kernel(*a))
+    apart = pa.write_rows(pool, 1, tables, pos, k, v, rows_apart=True)
+    assert bool(called) == takes
+    scattered = pa.write_rows(pool, 1, tables, pos, k, v)
+    assert not called[1:]
+    if takes:
+        np.testing.assert_array_equal(_bits(apart)[:, :, 1:],
+                                      _bits(scattered)[:, :, 1:])
+    else:
+        np.testing.assert_array_equal(_bits(apart), _bits(scattered))
+
+
+def test_forward_paged_rows_apart_writes_the_live_rows_alone(monkeypatch):
+    """The model's call: ``rows_apart`` reaches ``write_rows`` in every
+    layer. Through a three-layer ``forward_paged`` with most rows padding the
+    trash block is untouched, the live rows' tokens and the logits are those
+    of the scattering program."""
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    m, params, before, live, tables, starts, ids = _mostly_padding_step(8, 12)
+    args = (params, ids, before, jnp.asarray(tables), jnp.asarray(starts))
+    lg_s, after_s = jax.jit(m.forward_paged)(*args)
+    lg_k, after_k = jax.jit(
+        lambda *a: m.forward_paged(*a, rows_apart=True))(*args)
+    k, s, b = np.asarray(after_k), np.asarray(after_s), np.asarray(before)
+    np.testing.assert_array_equal(k[:, :, 0], b[:, :, 0])
+    assert (s[:, :, 0] != b[:, :, 0]).any()
+    np.testing.assert_array_equal(k[:, :, 1:], s[:, :, 1:])
+    np.testing.assert_array_equal(np.asarray(lg_k)[live],
+                                  np.asarray(lg_s)[live])

@@ -89,6 +89,21 @@ class TestMosaicLowering:
             _aval((B, nh, hd), jnp.bfloat16), pool, _aval((), jnp.int32),
             _aval((B, MAXB), jnp.int32), _aval((B,), jnp.int32))
 
+    @pytest.mark.parametrize("shape,rows", [
+        ((24, 16, 832, 64, 128), 64),      # serve-chat's pool and decode round
+        ((5, 1, 3072, 64, 640), 32),       # serve-longdoc's latent pool
+    ])
+    def test_kv_write(self, shape, rows):
+        """The live-row write: the pool in place, a sub-tile a live row."""
+        from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+        pool = _aval(shape, jnp.bfloat16)
+        exp = _export_tpu(
+            pa.kv_write, pool, _aval((), jnp.int32),
+            _aval((rows,), jnp.int32), _aval((rows,), jnp.int32),
+            _aval((rows, shape[1], shape[4]), jnp.bfloat16))
+        assert "kv_write" in exp.mlir_module()
+
     def test_paged_decode_grouped_queries(self):
         """Several queries a kv head (``g`` 2), a per-layer K and V through
         the kernel's reference entry."""
