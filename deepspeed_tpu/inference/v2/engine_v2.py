@@ -135,6 +135,17 @@ class InferenceEngineV2:
         if decode_horizon < 1:
             raise ValueError(f"decode_horizon must be >= 1, got {decode_horizon}")
         self.decode_horizon = decode_horizon
+        #: some layers keep a state slot a sequence beside the pool
+        #: (``TransformerConfig.cache_kinds``): the second kind of cache
+        self._stateful = bool(getattr(model.config, "holds_state", False))
+        if self._stateful and (prefix_cache or decode_horizon > 1):
+            raise ValueError(
+                "a model with state-slot layers "
+                f"({model.config.cache_kinds}) is served with "
+                "prefix_cache=False and decode_horizon=1: a prefix hit hands "
+                "out KV blocks without the other layers' state at that "
+                "position, and a rolled-back draft token cannot be taken out "
+                "of a state")
         if params is None:
             params = model.init_params(jax.random.PRNGKey(0))
         self.params = self._cast_params(params)
@@ -231,15 +242,22 @@ class InferenceEngineV2:
                 num_blocks, block_size, max_blocks_per_seq,
                 prefix_cache=self.prefix_cache,
                 host_tier_blocks=self.host_tier_blocks,
+                state_slots=max_seqs if self._stateful else 0,
                 descs=lambda: self.state.seqs.values())
         else:
             self.block_mgr = BlockedKVCache(
                 num_blocks, block_size, max_blocks_per_seq,
                 prefix_cache=self.prefix_cache,
-                host_tier_blocks=self.host_tier_blocks)
+                host_tier_blocks=self.host_tier_blocks,
+                state_slots=max_seqs if self._stateful else 0)
         self.block_mgr.demote_fn = self._demote_block
         self._bind_nvme_tier()
         self.kv = model.init_kv_pool(num_blocks, block_size, dtype=dtype)
+        #: the slot arrays of a stateful model (``init_state_cache``: a slot
+        #: a sequence, row 0 the trash slot), donated to and returned by the
+        #: ragged program beside the pool
+        self.slot_cache = model.init_state_cache(
+            max_seqs, self.max_seq_len, dtype=dtype) if self._stateful else None
         #: where a pool row splits into its key and value parts
         #: (``TransformerConfig.kv_row``; the block programs' payload
         #: format follows from it)
@@ -249,9 +267,11 @@ class InferenceEngineV2:
         #: tiles lays a mixed step out as ``max_seqs`` one-token rows,
         #: then the chunks' segments, each from a tile boundary
         self._seg_tile = getattr(model, "segment_tile", 1)
-        #: the ragged program returns (rows, rows_max) of the held
-        #: experts behind its greedy tokens (``engine.dispatch`` attrs)
-        self._moe_stats = self.cfg.holds_experts
+        #: the counts the ragged program returns behind its greedy tokens,
+        #: by name (``engine.dispatch`` attrs): (rows, rows_max) of the held
+        #: experts, the chosen and the context blocks of sparse attention
+        self._step_counts = tuple(getattr(model, "step_counts", ()))
+        self._moe_stats = bool(self._step_counts)
         if self._seg_tile > 1 and (self.token_budget - max_seqs
                                    < self._seg_tile):
             raise ValueError(
@@ -355,18 +375,25 @@ class InferenceEngineV2:
 
         def ragged(params, pool, ids, tables, starts, logit_rows,
                    slots, seeds, poss, temps, top_ks, top_ps, bias_pool,
-                   greedy):
+                   greedy, slot_cache=None, row_slots=None):
             # ids (T, 1): every row is its own length-1 "sequence" against the
             # shared pool; only the (max_seqs,) logit_rows are projected
             # through the vocab head (reference ragged_ops/logits_gather)
             # a mixed step of a model with segment tiles: rows from max_seqs
             # on are chunk segments (see _build_ragged_step)
+            # a stateful model's slot arrays ride beside the pool, each row
+            # naming its sequence's slot: (logits, pool, slot arrays[, counts])
             segs = self._seg_tile > 1 and ids.shape[0] > self.max_seqs
             lg, pool, *stats = model.forward_paged(
                 params, ids, pool, tables, starts, logit_rows=logit_rows,
                 rows_apart=self._rows_apart(ids.shape[0]),
                 **({"seg_from": self.max_seqs} if segs else {}),
-                **({"moe_stats": True} if self._moe_stats and greedy else {}))
+                **({"moe_stats": True} if self._moe_stats and greedy else {}),
+                **({"state": slot_cache, "row_slots": row_slots}
+                   if self._stateful else {}))
+            if self._stateful:
+                slot_cache, *stats = stats
+                pool = (pool, slot_cache)
             if greedy:
                 # device-side token selection: ship (R,) token ids instead of
                 # (R, V) fp32 logits — the host↔device transfer is the serving
@@ -384,7 +411,8 @@ class InferenceEngineV2:
             return lg, pool
 
         fn = audited_jit("engine_v2.ragged", ragged, max_traces=4,
-                         donate_argnums=(1,), static_argnums=(13,))
+                         donate_argnums=(1, 14) if self._stateful else (1,),
+                         static_argnums=(13,))
         self._ragged_fn = fn
         return fn
 
@@ -415,7 +443,8 @@ class InferenceEngineV2:
             self.params, self.kv, i32(rows, 1),
             i32(rows, self.block_mgr.max_blocks_per_seq), i32(rows),
             i32(M), i32(M), i32(M), i32(M), f32, i32(M), f32,  # see ragged()
-            self._bias(), greedy)
+            self._bias(), greedy,
+            *((self.slot_cache, i32(rows)) if self._stateful else ()))
 
     def _get_cow(self):
         """Single fixed-shape block-copy program for copy-on-write: duplicate
@@ -606,8 +635,8 @@ class InferenceEngineV2:
         to plain flush-preemption + journal replay. The swap store is a
         cache, never a source of truth: re-admission works identically if
         the entry has vanished."""
-        if not self.host_tier_blocks:
-            return False
+        if not self.host_tier_blocks or self._stateful:
+            return False    # a state slot is not swapped: recompute
         d = self.state.seqs.get(uid)
         if d is None or not d.at_rest:
             return False
@@ -677,6 +706,8 @@ class InferenceEngineV2:
         prefill, no uncommitted speculation, holding blocks). A False here
         is a deferral signal, never an error — the disaggregated pool
         re-checks next step."""
+        if self._stateful:
+            return False    # a state slot is not exported
         if uid in self._swaps:
             return True
         d = self.state.seqs.get(uid)
@@ -797,6 +828,10 @@ class InferenceEngineV2:
         INSIDE the scan (docs/SAMPLING.md) — all-zero rows select argmax,
         bit-identical to the legacy greedy program, and no second trace
         ever exists."""
+        if self._stateful:
+            raise EngineUsageError(
+                "fused multi-token decode and speculative verification are "
+                "not wired for a model with state-slot layers")
         if self._fused_fn is None:
             model = self.model
             K = self.decode_horizon
@@ -823,6 +858,8 @@ class InferenceEngineV2:
         every draft position (rejection sampling's deterministic
         specialization, docs/SAMPLING.md); all-zero sampling rows select
         argmax, bit-identical to the legacy program."""
+        if self._stateful:
+            return self._get_fused()    # raises: no rollback out of a state
         if self._verify_fn is None:
             model = self.model
 
@@ -1019,12 +1056,14 @@ class InferenceEngineV2:
                 self._count_dispatch(disp, T, plan)
             fn = self._get_ragged()
             with tracing.span("engine.enqueue"):
+                *feed, row_slots = feed
                 args = (self.params, self.kv,
-                        *(jnp.asarray(a) for a in feed), self._bias(), greedy)
+                        *(jnp.asarray(a) for a in feed), self._bias(), greedy,
+                        *self._slot_args(row_slots))
                 if disp.recording:
                     tracing.note_program("engine_v2.ragged", fn, args,
                                          key=(T, greedy))
-                lg, self.kv = fn(*args)
+                lg = self._keep_caches(fn(*args))
             if self.prefix_cache:
                 # the step's writes are dispatched: every block it filled now
                 # holds valid prefix content — publish to the content index
@@ -1041,16 +1080,32 @@ class InferenceEngineV2:
             for i, d in enumerate(finals):
                 out[d.uid] = int(lg[i]) if greedy else lg[i]
 
+    def _slot_args(self, row_slots):
+        """The ragged program's trailing arguments of a stateful model: its
+        slot arrays and each row's slot (nothing for any other model)."""
+        if not self._stateful:
+            return ()
+        return self.slot_cache, jnp.asarray(row_slots)
+
+    def _keep_caches(self, out):
+        """Take the ragged program's donated caches back (the pool; of a
+        stateful model also its slot arrays); returns its first result."""
+        lg, caches = out
+        if self._stateful:
+            self.kv, self.slot_cache = caches
+        else:
+            self.kv = caches
+        return lg
+
     def _note_moe_rows(self, disp, fetched) -> None:
-        """``moe_rows`` / ``moe_rows_max`` of a greedy ragged dispatch, read
-        from the two counts behind its tokens (no transfer of their own);
-        ``moe_zero_picks`` from a third, where the router has identity
-        experts."""
+        """The counts of a greedy ragged dispatch that ride behind its
+        tokens (no transfer of their own), as attrs under the names the
+        model gives them (``step_counts``): ``moe_rows`` / ``moe_rows_max``
+        / ``moe_zero_picks`` of held experts, ``sel_blocks`` /
+        ``ctx_blocks`` of sparse attention."""
         if self._moe_stats and disp.recording:
-            disp.set(moe_rows=int(fetched[self.max_seqs]),
-                     moe_rows_max=int(fetched[self.max_seqs + 1]))
-            if self.cfg.moe_zero_experts:
-                disp.set(moe_zero_picks=int(fetched[self.max_seqs + 2]))
+            disp.set(**{name: int(fetched[self.max_seqs + i])
+                        for i, name in enumerate(self._step_counts)})
 
     def _count_dispatch(self, disp, padded_rows: int, plan,
                         fused: bool = False) -> None:
@@ -1086,6 +1141,8 @@ class InferenceEngineV2:
                      ctx_tokens=ctx, ctx_tokens_by_row=by_row,
                      blocks_allocated=max(0, marks[0] - self._count_marks[0]),
                      cow_copies=max(0, marks[1] - self._count_marks[1]))
+            if mgr.slots is not None:
+                disp.set(state_slots=mgr.slots.in_use)
         self._count_marks = marks
 
     def _build_ragged_step(self, work):
@@ -1172,11 +1229,11 @@ class InferenceEngineV2:
                             self.kv, jnp.int32(src), jnp.int32(dst))
         M = self.max_seqs
         (ids, tables, starts, logit_rows, slots, seeds, poss, top_ks,
-         temps, top_ps) = self._scratch_for(
+         temps, top_ps, row_slots) = self._scratch_for(
             ("ragged", T),
             ((T, 1), (T, self.block_mgr.max_blocks_per_seq), (T,),
-             (M,), (M,), (M,), (M,), (M,), (M,), (M,)),
-            dtypes=(np.int32,) * 8 + (np.float32, np.float32))
+             (M,), (M,), (M,), (M,), (M,), (M,), (M,), (T,)),
+            dtypes=(np.int32,) * 8 + (np.float32, np.float32, np.int32))
         finals = []
         r = singles = 0
         seg_next = self.max_seqs       # next free segment tile's first row
@@ -1195,6 +1252,9 @@ class InferenceEngineV2:
             self.block_mgr.fill_table_row(d, tables[r0])
             if take > 1:
                 tables[r0 + 1:r0 + take] = tables[r0]
+            if self._stateful:
+                row_slots[r0:r0 + take] = self.block_mgr.slots.begin(
+                    d.uid, d.seen_tokens)
             for j in range(take):
                 ids[r, 0] = d.pending[j]
                 starts[r] = d.seen_tokens + j
@@ -1213,7 +1273,7 @@ class InferenceEngineV2:
             del d.pending[:take]
             d.seen_tokens += take
         return T, plan, finals, (ids, tables, starts, logit_rows, slots, seeds,
-                                 poss, temps, top_ks, top_ps)
+                                 poss, temps, top_ks, top_ps, row_slots)
 
     # ------------------------------------------------------------------
     # reference surface
@@ -1244,6 +1304,8 @@ class InferenceEngineV2:
         # 1. register / extend sequences
         for uid, toks in zip(batch_uids, batch_tokens):
             desc = self.state.get_or_create_sequence(uid)
+            if self._stateful:
+                self.block_mgr.slots.take(uid)
             if self._bias_rows:
                 # (re-)bind any pending logit-bias row to this uid's slot —
                 # covers fresh admission, preempt→re-admit, and post-rebuild
@@ -1618,12 +1680,12 @@ class InferenceEngineV2:
                 scratch_set = (1 - self._unfetched[0]._set
                                if self._unfetched else 0)
                 (ids, tables, starts, logit_rows, slots, seeds, poss, top_ks,
-                 temps, top_ps, src_rows) = self._scratch_for(
+                 temps, top_ps, src_rows, row_slots) = self._scratch_for(
                     ("dispatch", T, scratch_set),
                     ((T, 1), (T, self.block_mgr.max_blocks_per_seq), (T,),
-                     (M,), (M,), (M,), (M,), (M,), (M,), (M,), (T,)),
+                     (M,), (M,), (M,), (M,), (M,), (M,), (M,), (T,), (T,)),
                     dtypes=(np.int32,) * 8 + (np.float32, np.float32,
-                                              np.int32))
+                                              np.int32, np.int32))
                 src_rows.fill(-1)
                 for r, d in enumerate(descs):
                     tok = tokens[d.uid]
@@ -1634,6 +1696,9 @@ class InferenceEngineV2:
                         ids[r, 0] = tok = int(tok)
                     self.block_mgr.fill_table_row(d, tables[r])  # in place, no temp
                     starts[r] = d.seen_tokens
+                    if self._stateful:
+                        row_slots[r] = self.block_mgr.slots.begin(
+                            d.uid, d.seen_tokens)
                     logit_rows[r] = r  # every row is a final: one token per uid
                     self._fill_sampling(d, r, slots, seeds, temps, top_ks, top_ps,
                                         poss=poss, pos=d.seen_tokens + 1)
@@ -1653,19 +1718,20 @@ class InferenceEngineV2:
                 # of a round
                 feed = (ids, tables, starts, logit_rows, slots, seeds, poss,
                         temps, top_ks, top_ps)
+                # one staging call: the feed, then the optional arrays
+                ids_dev, *rest = jax.device_put(
+                    feed + ((src_rows,) if rows_of_prev else ())
+                    + ((row_slots,) if self._stateful else ()))
+                slots_dev = rest.pop() if self._stateful else None
                 if rows_of_prev:
-                    ids_dev, *rest, src_dev = jax.device_put(
-                        feed + (src_rows,))
                     ids_dev = self._get_feed_merge()(prev._dev, ids_dev,
-                                                     src_dev)
-                else:
-                    ids_dev, *rest = jax.device_put(feed)
+                                                     rest.pop())
                 args = (self.params, self.kv, ids_dev, *rest, self._bias(),
-                        True)
+                        True, *self._slot_args(slots_dev))
                 if disp.recording:
                     tracing.note_program("engine_v2.ragged", fn, args,
                                          key=(T, True))
-                lg, self.kv = fn(*args)
+                lg = self._keep_caches(fn(*args))
         # no np.asarray and no register here — both are deferred: the
         # transfer to fetch(), the prefix-index publish to commit_step()
         handle = DecodeDispatchHandle([d.uid for d in descs], lg, eng=self,
@@ -1844,12 +1910,14 @@ class InferenceEngineV2:
                 old.num_blocks, old.block_size, old.max_blocks_per_seq,
                 prefix_cache=self.prefix_cache,
                 host_tier_blocks=self.host_tier_blocks,
+                state_slots=self.max_seqs if self._stateful else 0,
                 descs=lambda: self.state.seqs.values())
         else:
             self.block_mgr = BlockedKVCache(
                 old.num_blocks, old.block_size, old.max_blocks_per_seq,
                 prefix_cache=self.prefix_cache,
-                host_tier_blocks=self.host_tier_blocks)
+                host_tier_blocks=self.host_tier_blocks,
+                state_slots=self.max_seqs if self._stateful else 0)
         if self.nvme_tier_blocks:
             for hid in list(getattr(old, "_nvme", ())):
                 self._drop_block(hid)
@@ -1857,6 +1925,9 @@ class InferenceEngineV2:
         self._bind_nvme_tier()
         self.kv = self.model.init_kv_pool(old.num_blocks, old.block_size,
                                           dtype=self.dtype)
+        if self._stateful:
+            self.slot_cache = self.model.init_state_cache(
+                self.max_seqs, self.max_seq_len, dtype=self.dtype)
         log_dist(
             f"InferenceEngineV2.rebuild #{self.rebuilds}: block pool "
             f"replaced ({old.num_blocks}x{old.block_size}, prefix cache "
@@ -1917,6 +1988,10 @@ class InferenceEngineV2:
 
     def can_schedule(self, n_new: int = 1) -> bool:
         if not self.state.can_allocate(n_new):
+            return False
+        # a model with state-slot layers admits by state slots too
+        slots = self.block_mgr.slots
+        if slots is not None and slots.free_slots < n_new:
             return False
         # admit only if every new sequence can get one prefill chunk of
         # blocks (the reference consults KV block availability likewise,

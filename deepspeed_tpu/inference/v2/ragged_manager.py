@@ -110,7 +110,17 @@ class BlockedKVCache:
 
     def __init__(self, num_blocks: int, block_size: int, max_blocks_per_seq: int,
                  prefix_cache: bool = False, host_tier_blocks: int = 0,
-                 nvme_blocks: int = 0):
+                 nvme_blocks: int = 0, state_slots: int = 0):
+        if state_slots and prefix_cache:
+            raise ValueError(
+                "prefix_cache with state slots: a prefix hit hands out KV "
+                "blocks but not the other layers' state at that position, "
+                "which is a wrong answer; serve such a model with "
+                "prefix_cache=False")
+        #: the second kind of cache: a slot a sequence (:class:`StateSlots`),
+        #: None for a model whose every layer keeps KV blocks
+        self.slots: Optional[StateSlots] = \
+            StateSlots(state_slots) if state_slots else None
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.max_blocks_per_seq = max_blocks_per_seq
@@ -630,6 +640,8 @@ class BlockedKVCache:
         return freed
 
     def free(self, desc: SequenceDescriptor):
+        if self.slots is not None:
+            self.slots.free(desc.uid)
         for b in desc.blocks:
             self._decref(b)
         desc.blocks = []
@@ -841,6 +853,8 @@ class BlockedKVCache:
         assert rest == self._owner_rest, (
             f"per-tenant at-rest ledger {self._owner_rest} != recount {rest}")
         descs = list(descs)
+        if self.slots is not None:
+            self.slots.check_invariants(d.uid for d in descs)
         if descs:
             counted: Dict[int, int] = {}
             for d in descs:
@@ -848,6 +862,88 @@ class BlockedKVCache:
                     counted[b] = counted.get(b, 0) + 1
             assert counted == self._ref, (
                 f"refcounts {self._ref} != descriptor holdings {counted}")
+
+
+class StateSlots:
+    """The second kind of cache beside the blocks: a **state slot** a
+    sequence, for models some of whose layers keep a fixed-size state and no
+    growing cache (``TransformerConfig.cache_kinds``: kind ``state_slot``).
+    Slot ``s`` is row ``1 + s`` of the model's slot arrays (row 0 is the trash
+    slot of padding rows). A slot is taken when its sequence is registered,
+    has one owner, and is free again after the sequence's flush, which is also
+    what a preemption does: the victim recomputes from its prompt.
+
+    Nothing copies or zeroes a slot. A sequence's first token (position 0)
+    starts from a zero state in the program itself, whatever the slot held, so
+    a slot is **clean** from the first step of its owner on; ``begin`` is
+    where the engine says that step was built, and a step for a slot that is
+    not clean must start at position 0 (checked)."""
+
+    def __init__(self, n_slots: int):
+        self.n_slots = n_slots
+        self._free: List[int] = list(range(n_slots))[::-1]
+        self._owner: Dict[int, int] = {}     # slot -> uid
+        self._slot: Dict[int, int] = {}      # uid -> slot
+        self._clean: set = set()             # slots whose owner has begun
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._free)
+
+    @property
+    def in_use(self) -> int:
+        return len(self._owner)
+
+    def slot_of(self, uid: int) -> Optional[int]:
+        return self._slot.get(uid)
+
+    def take(self, uid: int) -> int:
+        """``uid``'s slot, taken now if it holds none."""
+        if uid in self._slot:
+            return self._slot[uid]
+        if not self._free:
+            raise PoolExhaustedError(
+                f"no free state slot for uid {uid} ({self.n_slots} slots)",
+                uid=uid)
+        slot = self._free.pop()
+        self._owner[slot], self._slot[uid] = uid, slot
+        return slot
+
+    def begin(self, uid: int, position: int) -> int:
+        """The row index of ``uid``'s slot for a step whose first token of
+        the sequence is at ``position``. The owner's first step must start at
+        position 0: that is what resets the slot."""
+        slot = self._slot[uid]
+        if slot not in self._clean:
+            if position != 0:
+                raise AssertionError(
+                    f"uid {uid}: first step on state slot {slot} starts at "
+                    f"position {position}, so the last owner's state would "
+                    "be read")
+            self._clean.add(slot)
+        return 1 + slot
+
+    def free(self, uid: int) -> None:
+        """Release ``uid``'s slot (a no-op for a uid that holds none)."""
+        slot = self._slot.pop(uid, None)
+        if slot is not None:
+            del self._owner[slot]
+            self._clean.discard(slot)
+            self._free.append(slot)
+
+    def check_invariants(self, uids: Iterable[int] = ()) -> None:
+        free, held = set(self._free), set(self._owner)
+        assert len(free) == len(self._free), "duplicate slot in the free list"
+        assert not (free & held), "state slot both free and owned"
+        assert free | held == set(range(self.n_slots)), "phantom state slot"
+        assert {u: s for s, u in self._owner.items()} == self._slot, \
+            "slot owners and holders disagree"
+        assert self._clean <= held, "a free state slot is marked clean"
+        uids = set(uids)
+        if uids:
+            assert set(self._slot) == uids, (
+                f"state slots held by {sorted(self._slot)} != live "
+                f"sequences {sorted(uids)}")
 
 
 class DSStateManager:

@@ -42,6 +42,10 @@ from ..ops.transformer.attention import attention as _attention_op
 from ..utils.logging import logger
 
 
+#: the mixers a ``layer_types`` model may name
+LAYER_TYPES = ("sparse_attn", "linear_attn")
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 50304  # padded to a multiple of 128 (MXU lane width)
@@ -136,6 +140,37 @@ class TransformerConfig:
     # the latent norms' outputs times sqrt(hidden_size / rank) (LongCat-Flash)
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    # a mixer a layer (``layer_types``; None = every layer the one block
+    # above): "sparse_attn" (block-sparse attention over the paged pool:
+    # GQA, no positional term, the ``sparse_*`` sizes
+    # of ops/transformer/sparse_attention.py; the pool's block is the
+    # selector's) | "linear_attn" (lightning attention: a fixed float32 state
+    # a sequence and head, rotary, an output norm; ops/transformer/
+    # linear_attention.py). A run of equal types is one stacked group
+    # ``params["blocks_<i>"]``, scanned. Both mixers take ``qk_norm`` (RMSNorm
+    # of each q and k head) and ``attn_output_gate`` (``sigmoid(W h)`` on the
+    # attention's output before ``wo``). Serving only
+    layer_types: Optional[Tuple[str, ...]] = None
+    qk_norm: bool = False
+    attn_output_gate: bool = False
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_window: int = 2048       # tokens; whole blocks ending at the query's
+    sparse_init_blocks: int = 1
+    sparse_topk: int = 64
+    sparse_dense_len: int = 8192
+    # the block a sparse layer scores and picks by: the model's own (its
+    # published ``sparse_config.block_size``), and so the only pool block it
+    # can be served from (``init_kv_pool`` refuses another)
+    sparse_block_size: int = 64
+    linear_chunk: int = 128         # rows of a prefill tile of a linear layer
+    # muP (MiniCPM): the residual branches times ``scale_depth /
+    # sqrt(scale_depth_layers or num_layers)`` (the published depth, where
+    # the model is a slice of it), the head's input times ``dim_model_base /
+    # hidden_size``; 0 = off. ``embed_scale`` is the third scalar
+    scale_depth: float = 0.0
+    scale_depth_layers: int = 0
+    dim_model_base: int = 0
     # YaRN rotary scaling (rope_factor 1 = plain rotary)
     rope_factor: float = 1.0
     rope_original_max: int = 0
@@ -174,9 +209,75 @@ class TransformerConfig:
     logit_softcap: float = 0.0
     name: str = "transformer"
 
+    def __post_init__(self):
+        if self.layer_types is not None:
+            types = tuple(self.layer_types)
+            object.__setattr__(self, "layer_types", types)
+            if len(types) != self.num_layers or set(types) - set(LAYER_TYPES):
+                raise ValueError(
+                    f"layer_types {types}: one of {LAYER_TYPES} for each of "
+                    f"the {self.num_layers} layers")
+
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def type_runs(self) -> Tuple[Tuple[str, str, int], ...]:
+        """(group key, layer type, layers) of each run of equal
+        ``layer_types``, in forward order: the stacked groups of the tree."""
+        runs = []
+        for t in self.layer_types or ():
+            if runs and runs[-1][1] == t:
+                runs[-1][2] += 1
+            else:
+                runs.append([f"blocks_{len(runs)}", t, 1])
+        return tuple(tuple(r) for r in runs)
+
+    def layers_of(self, layer_type: str) -> int:
+        return sum(t == layer_type for t in self.layer_types or ())
+
+    @property
+    def residual_scale(self) -> float:
+        if not self.scale_depth:
+            return 1.0
+        return self.scale_depth / math.sqrt(self.scale_depth_layers
+                                            or self.num_layers)
+
+    @property
+    def sparse_spec(self):
+        from ..ops.transformer.sparse_attention import SparseSpec
+
+        return SparseSpec(
+            block=self.sparse_block_size, kernel=self.sparse_kernel_size,
+            stride=self.sparse_kernel_stride,
+            window_blocks=self.sparse_window // self.sparse_block_size,
+            init_blocks=self.sparse_init_blocks, topk=self.sparse_topk,
+            dense_len=self.sparse_dense_len).check()
+
+    @property
+    def cache_kinds(self) -> Dict[str, Tuple[Tuple[str, int], ...]]:
+        """What each layer type keeps a sequence, the model's declaration to
+        the engine: {layer type: ((kind, bytes), ...)} with kind ``kv_blocks``
+        (bytes a token a layer, in the paged pool at 2 bytes a value) or
+        ``state_slot`` (bytes a sequence a layer, whatever its length: a
+        lightning layer's float32 state; a sparse layer's compressed keys for
+        ``max_seq_len`` tokens, which lie by slot beside its KV blocks)."""
+        kv = ("kv_blocks", 2 * self.pool_heads * sum(self.kv_row))
+        if self.layer_types is None:
+            return {"attn": (kv,)}
+        nh, hd = self.num_heads, self.head_dim
+        keys = self.sparse_spec.max_keys(self.max_seq_len)
+        kinds = {"sparse_attn": (kv, ("state_slot",
+                                      2 * keys * self.kv_heads * hd)),
+                 "linear_attn": (("state_slot", 4 * nh * hd * hd),)}
+        return {t: kinds[t] for t in dict.fromkeys(self.layer_types)}
+
+    @property
+    def holds_state(self) -> bool:
+        """Some layer keeps a state slot a sequence beside the paged pool."""
+        return any(kind == "state_slot" for kept in self.cache_kinds.values()
+                   for kind, _ in kept)
 
     @property
     def head_dim(self) -> int:
@@ -210,8 +311,10 @@ class TransformerConfig:
 
     @property
     def pool_layers(self) -> int:
-        """Layers of the paged pool: one for every attention of the model.
-        THE place the pool's layer axis is read from."""
+        """Layers of the paged pool: one for every attention of the model
+        that keeps KV blocks. THE place the pool's layer axis is read from."""
+        if self.layer_types is not None:
+            return self.layers_of("sparse_attn")
         return self.sublayers * self.num_layers
 
     @property
@@ -270,12 +373,26 @@ class TransformerConfig:
         kvd = self.kv_heads * self.head_dim
         return H * qd + 2 * H * kvd + qd * H  # q, k, v, o
 
+    def _mixer_params(self, layer_type: str) -> int:
+        """Parameters of one ``layer_types`` mixer, its head norms, output
+        norm and gate included."""
+        H, qd = self.hidden_size, self.num_heads * self.head_dim
+        extra = (2 * self.head_dim if self.qk_norm else 0) \
+            + (H * qd if self.attn_output_gate else 0)
+        if layer_type == "sparse_attn":
+            return self._attn_params + extra
+        return 4 * H * qd + qd + extra     # q, k, v, o at full width; out norm
+
     @property
     def num_parameters(self) -> int:
         """Parameters of the tree ``init_params`` builds: with held experts
         (``holds_experts``) the experts held here, not the router's
         width."""
         H, L, V = self.hidden_size, self.num_layers, self.vocab_size
+        if self.layer_types is not None:
+            layers = sum(self._mixer_params(t) for t in self.layer_types) \
+                + L * (2 * H + self._mlp_params(self.mlp_dim))
+            return layers + V * H + (0 if self.tie_embeddings else V * H) + H
         n_ln = 1 if (self.parallel_block and self.parallel_shared_ln) else 2
         norms = n_ln * (1 if self.norm == "rmsnorm" else 2) * H
         mlp = self._mlp_params(self.mlp_dim)
@@ -317,6 +434,15 @@ class TransformerConfig:
         q·k and p·v over the heads' own widths)."""
         S = seq_len or self.max_seq_len
         n = self.num_active_parameters
+        if self.layer_types is not None:
+            # a sparse layer scores at most topk blocks (the whole context
+            # under dense_len); a linear layer's state costs the same at any
+            # length: k^T v and q S, a head
+            nh, hd = self.num_heads, self.head_dim
+            ctx = S if S <= self.sparse_dense_len else min(
+                S, self.sparse_topk * self.sparse_block_size)
+            return (6 * n + 6 * self.layers_of("sparse_attn") * 2 * nh * hd * ctx
+                    + 6 * self.layers_of("linear_attn") * 2 * nh * hd * hd)
         if self.is_mla:
             per_pos = self.num_heads * (self.qk_nope_head_dim
                                         + self.qk_rope_head_dim + self.v_head_dim)
@@ -542,6 +668,8 @@ class TransformerLM:
         cfg = self.config
         if cfg.is_mla:
             return self._init_params_mla(rng)
+        if cfg.layer_types is not None:
+            return self._init_params_typed(rng)
         H, L, V, I = cfg.hidden_size, cfg.num_layers, cfg.vocab_size, cfg.mlp_dim
         nh, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         dt = cfg.param_dtype
@@ -630,6 +758,57 @@ class TransformerLM:
             params["lm_head"] = init(k[9], (H, V), dt)
             if cfg.lm_head_bias:
                 params["lm_head_bias"] = jnp.zeros((V,), dt)
+        return params
+
+    def _typed_shapes(self):
+        """As :meth:`_mla_shapes`, of a ``layer_types`` model: one group a
+        run of equal types (``TransformerConfig.type_runs``)."""
+        cfg = self.config
+        H, V, I = cfg.hidden_size, cfg.vocab_size, cfg.mlp_dim
+        qd, kvd, hd = cfg.num_heads * cfg.head_dim, \
+            cfg.kv_heads * cfg.head_dim, cfg.head_dim
+        shared = {"ln1_scale": (H,), "wq": (H, qd), "wo": (qd, H),
+                  "ln2_scale": (H,), "w_gate": (H, I), "w_up": (H, I),
+                  "w_down": (I, H)}
+        if cfg.qk_norm:
+            shared.update(q_norm_scale=(hd,), k_norm_scale=(hd,))
+        if cfg.attn_output_gate:
+            shared["w_ogate"] = (H, qd)
+        mixer = {"sparse_attn": {"wk": (H, kvd), "wv": (H, kvd)},
+                 "linear_attn": {"wk": (H, qd), "wv": (H, qd),
+                                 "o_norm_scale": (qd,)}}
+        groups = {key: (n, {**shared, **mixer[kind]})
+                  for key, kind, n in cfg.type_runs}
+        top = {"wte": (V, H), "lnf_scale": (H,)}
+        if not cfg.tie_embeddings:
+            top["lm_head"] = (H, V)
+        return groups, top
+
+    def _init_params_typed(self, rng) -> Dict[str, Any]:
+        """{leaf, blocks_0: {leaf}, blocks_1: {leaf}, ...}: one stacked
+        group a run of equal ``layer_types``."""
+        cfg = self.config
+        if (cfg.activation != "swiglu" or cfg.norm != "rmsnorm"
+                or cfg.pos_embedding != "rope" or cfg.num_experts):
+            raise ValueError("layer_types models are rmsnorm + swiglu + rope, "
+                             "with a dense feed-forward")
+        dt = cfg.param_dtype
+        groups, top = self._typed_shapes()
+        init = jax.nn.initializers.normal(0.02)
+        resid_init = jax.nn.initializers.normal(
+            0.02 / np.sqrt(2 * cfg.num_layers))
+        keys = iter(jax.random.split(rng, 16 * (len(groups) + 1)))
+
+        def leaf(name, shape):
+            if name.endswith("_scale"):
+                return jnp.ones(shape, dt)
+            return (resid_init if name in ("wo", "w_down") else init)(
+                next(keys), shape, dt)
+
+        params = {k: leaf(k, shape) for k, shape in top.items()}
+        for group, (n, leaves) in groups.items():
+            params[group] = {k: leaf(k, (n,) + shape)
+                             for k, shape in leaves.items()}
         return params
 
     def _mla_shapes(self):
@@ -1357,8 +1536,11 @@ class TransformerLM:
     def layer_groups(params):
         """The stacked layer groups of ``params`` in forward order: the
         leading ``dense_blocks`` (where the model has them) before
-        ``blocks``."""
-        return [g for g in ("dense_blocks", "blocks") if g in params]
+        ``blocks``; of a ``layer_types`` model ``blocks_0``, ``blocks_1``,
+        ... (one a run of equal types)."""
+        return [g for g in ("dense_blocks", "blocks") if g in params] \
+            or sorted((g for g in params if re.fullmatch(r"blocks_\d+", g)),
+                      key=lambda g: int(g[7:]))
 
     def _trunk(self, params, x, positions, rng, train, pld_theta=None,
                attn_mask_bias=None):
@@ -1498,6 +1680,8 @@ class TransformerLM:
         if cfg.norm_position != "post":  # post-LN trunks end already normalized
             x = _norm(x, params["lnf_scale"], params.get("lnf_bias"),
                       cfg.norm, cfg.norm_eps, cfg.norm_weight_offset)
+        if cfg.dim_model_base:  # muP: the head reads N(x) * base / width
+            x = x * jnp.asarray(cfg.dim_model_base / cfg.hidden_size, x.dtype)
         w = params["wte"].T if cfg.tie_embeddings else params["lm_head"]
         out = x @ w.astype(x.dtype)  # (B,S,V)
         if "lm_head_bias" in params:
@@ -1508,6 +1692,11 @@ class TransformerLM:
     def _logits_aux(self, params, input_ids, positions=None, train=False, rng=None,
                     pld_theta=None, ltd_keep=None, attention_mask=None,
                     token_type_ids=None):
+        if self.config.layer_types is not None:
+            raise NotImplementedError(
+                "a layer_types model is served from the paged pool and its "
+                "state slots (forward_paged): it has no full-sequence or "
+                "training path yet")
         B, S = input_ids.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
@@ -1638,6 +1827,12 @@ class TransformerLM:
         from ..ops.transformer.paged_attention import init_pool
 
         cfg = self.config
+        if "sparse_attn" in (cfg.layer_types or ()) \
+                and block_size != cfg.sparse_block_size:
+            raise ValueError(
+                f"a pool block of {block_size} tokens is not the block the "
+                f"model's sparse layers select by ({cfg.sparse_block_size}: "
+                "sparse_block_size, the published sparse_config.block_size)")
         return init_pool(cfg.pool_layers, cfg.pool_heads, num_blocks, block_size,
                          cfg.kv_row, dtype)
 
@@ -1647,11 +1842,49 @@ class TransformerLM:
         takes a prefill chunk as one-token rows like any other)."""
         from ..ops.transformer.paged_attention import SEGMENT_TILE
 
+        if self.config.layer_types is not None:
+            return self.config.linear_chunk
         return SEGMENT_TILE if self.config.is_mla else 1
+
+    @property
+    def step_counts(self) -> Tuple[str, ...]:
+        """Names of the int32 counts ``forward_paged(moe_stats=True)`` returns
+        behind its logits: they become attrs of ``engine.dispatch``."""
+        cfg = self.config
+        if cfg.layer_types is not None:
+            return ("sel_blocks", "ctx_blocks") \
+                if "sparse_attn" in cfg.layer_types else ()
+        if not cfg.holds_experts:
+            return ()
+        return ("moe_rows", "moe_rows_max") + (
+            ("moe_zero_picks",) if cfg.moe_zero_experts else ())
+
+    def init_state_cache(self, max_seqs: int, max_seq_len: int,
+                         dtype=jnp.bfloat16) -> Dict[str, Any]:
+        """What the model keeps a sequence beside the paged pool: one slot
+        array a layer group (``TransformerConfig.type_runs``), a slot a
+        sequence and slot 0 the trash slot. A group of linear layers: its
+        lightning states (layers, 1 + max_seqs, heads, hd, hd) float32
+        (ops/transformer/linear_attention.py); a group of sparse layers: the
+        selector's compressed keys (layers, 1 + max_seqs, kv heads, keys,
+        hd) (ops/transformer/sparse_attention.py). Empty for a model whose
+        every layer keeps KV blocks and nothing else."""
+        from ..ops.transformer import linear_attention as la
+        from ..ops.transformer import sparse_attention as sa
+
+        cfg = self.config
+        nh, hd = cfg.num_heads, cfg.head_dim
+        make = {
+            "linear_attn": lambda n: la.init_state(n, max_seqs, nh, hd, hd),
+            "sparse_attn": lambda n: sa.init_keys(
+                n, max_seqs, cfg.kv_heads,
+                cfg.sparse_spec.max_keys(max_seq_len), hd, dtype)}
+        return {key: make[kind](n) for key, kind, n in cfg.type_runs}
 
     def forward_paged(self, params, input_ids, kv_pool, tables, starts,
                       n_valid=None, logit_rows=None, seg_from=None,
-                      moe_stats=False, rows_apart=False):
+                      moe_stats=False, rows_apart=False, state=None,
+                      row_slots=None):
         """Run a (B, S) segment against the blocked pool.
 
         tables: (B, MAXB) pool block ids per sequence (0-padded); starts: (B,)
@@ -1672,8 +1905,17 @@ class TransformerLM:
         the busiest held expert's (``config.holds_experts`` only); with
         ``moe_zero_experts`` (3,): behind them the live rows' choices of
         identity experts, summed over the layers.
+
+        ``state``, ``row_slots`` (``layer_types`` models): the slot arrays
+        of :meth:`init_state_cache` and each row's slot in them ((B,) int32, 0
+        for a padding row); the new ``state`` is returned behind the pool,
+        and with ``moe_stats`` the counts :attr:`step_counts` names.
         """
         B, S = input_ids.shape
+        if self.config.layer_types is not None:
+            return self._forward_paged_typed(
+                params, input_ids, kv_pool, state, tables, starts, row_slots,
+                logit_rows, seg_from, moe_stats, rows_apart)
         if self.config.is_mla:
             return self._forward_paged_mla(params, input_ids, kv_pool, tables,
                                            starts, logit_rows, seg_from,
@@ -1764,6 +2006,186 @@ class TransformerLM:
         with jax.named_scope("lm_head_loss"):
             lg = self._head(params, x_last[:, None])[:, 0]
         return (lg, kv_pool, stats) if moe_stats else (lg, kv_pool)
+
+    # ------------------------------------------------------------------
+    # ``layer_types`` models: one small mixer a type, the rest shared
+    # ------------------------------------------------------------------
+    def _forward_paged_typed(self, params, input_ids, kv_pool, state, tables,
+                             starts, row_slots, logit_rows, seg_from,
+                             moe_stats, rows_apart):
+        """:meth:`forward_paged` of a ``layer_types`` model: one-token rows
+        (T, 1); rows from ``seg_from`` on are chunk segments in tiles of
+        ``segment_tile`` rows, each tile consecutive tokens of one sequence
+        (its first row carries the table, the position and the slot; its
+        valid rows are a prefix), rows before it one a sequence; what is left
+        behind the last whole tile is padding. The groups
+        are scanned in turn with the pool and the slot arrays as the carry.
+        Returns (logits, pool, state[, counts])."""
+        cfg = self.config
+        T, S = input_ids.shape
+        if S != 1:
+            raise ValueError("a layer_types model's paged path takes "
+                             "one-token rows")
+        if state is None or row_slots is None:
+            raise ValueError("a layer_types model's paged path needs its "
+                             "slot arrays (init_state_cache) and row_slots")
+        cut = T if seg_from is None else seg_from
+        tile = self.segment_tile
+        end = cut + (T - cut) // tile * tile
+        live = tables[:, 0] > 0
+        rows = {"tables": tables, "starts": starts, "slots": row_slots,
+                "live": live, "cut": cut, "end": end, "tile": tile,
+                # a tile's valid rows are a prefix of it
+                "tile_counts": jnp.sum(live[cut:end].reshape(-1, tile), axis=1,
+                                       dtype=jnp.int32)}
+        with jax.named_scope("embed"):
+            x = self._embed(params, input_ids, starts[:, None], kv_pool.dtype)
+        # a group's scan carries its own slot array, and the pool if its
+        # layers keep KV blocks, and nothing else: an array carried through a
+        # scan that does not touch it, or sliced by a constant layer, was
+        # copied whole by XLA, once a dispatch
+        mixers = {"sparse_attn": self._sparse_mixer,
+                  "linear_attn": self._linear_mixer}
+        state = dict(state)
+        counts = jnp.zeros((len(self.step_counts),), jnp.int32)
+        pool_layer = 0                        # pool layers before this group
+        with jax.named_scope("kv_carry"):
+            for key, kind, n in cfg.type_runs:
+                held = {"own": state[key]}
+                if kind == "sparse_attn":
+                    held["pool"] = kv_pool
+
+                def body(carry, blk, mixer=mixers[kind], base=pool_layer):
+                    h, held, l, st = carry
+                    y, held, s = self._typed_layer(h, blk, mixer, held, l,
+                                                   base + l, rows, rows_apart)
+                    return (y, held, l + 1, st if s is None else st + s), None
+
+                (x, held, _, counts), _ = jax.lax.scan(
+                    body, (x, held, jnp.int32(0), counts), params[key])
+                state[key] = held["own"]
+                if kind == "sparse_attn":
+                    kv_pool, pool_layer = held["pool"], pool_layer + n
+        x_last = x[:, 0]
+        if logit_rows is not None:
+            x_last = x_last[logit_rows]
+        with jax.named_scope("lm_head_loss"):
+            lg = self._head(params, x_last[:, None])[:, 0]
+        out = (lg, kv_pool, state)
+        return out + (counts,) if moe_stats else out
+
+    def _typed_layer(self, x, blk, mixer, caches, layer, pool_layer, rows,
+                     rows_apart):
+        """One layer of a ``layer_types`` model on (T, 1, H): ``x + a M(N(x))``
+        then ``+ a F(N(.))``, ``a`` the muP residual scale; ``mixer`` is the
+        layer type's (:meth:`_sparse_mixer`, :meth:`_linear_mixer`), the
+        norms, the gate, ``wo`` and the feed-forward are shared. ``caches``:
+        the group's slot array (``own``) and, where its layers keep KV blocks,
+        the pool; ``layer`` counts the group's layers, ``pool_layer`` the
+        pool's. Returns (y, caches, counts or None)."""
+        from ..moe.layer import _gated_mlp
+
+        cfg = self.config
+        T, dt, a = x.shape[0], x.dtype, cfg.residual_scale
+        nh, hd = cfg.num_heads, cfg.head_dim
+        blk = _dequant_woq(blk, dt)
+        once = jax.lax.optimization_barrier
+
+        def heads(w, name=None):
+            """``h @ w`` as heads, each RMS-normed under ``name`` (the
+            barrier: a norm over a product reads it twice, and XLA would
+            stream the matrix for each, :meth:`_mla_attention`; it also keeps
+            the product's layout from being chosen by its consumer, which
+            cost a transposed copy of the matrix a layer)."""
+            y = once(h @ blk[w].astype(dt)).reshape(T, -1, hd)
+            return _norm(y, blk[name], None, "rmsnorm", cfg.norm_eps) \
+                if name and cfg.qk_norm else y
+
+        with jax.named_scope("attn"):
+            h = _norm(x[:, 0], blk["ln1_scale"], None, "rmsnorm", cfg.norm_eps)
+            q, k = heads("wq", "q_norm_scale"), heads("wk", "k_norm_scale")
+            o, caches, counts = mixer(q, k, heads("wv"), blk, caches, layer,
+                                      pool_layer, rows, rows_apart)
+            o = o.reshape(T, nh * hd).astype(dt)
+            if cfg.attn_output_gate:
+                o = o * jax.nn.sigmoid(once(h @ blk["w_ogate"].astype(dt)))
+            x = once(x + a * (o @ blk["wo"].astype(dt))[:, None])
+        with jax.named_scope("mlp"):
+            h2 = _norm(x, blk["ln2_scale"], None, "rmsnorm", cfg.norm_eps)
+            x = x + a * _gated_mlp(h2, blk["w_gate"], blk["w_up"],
+                                   blk["w_down"])
+        return x, caches, counts
+
+    def _sparse_mixer(self, q, k, v, blk, caches, layer, pool_layer, rows,
+                      rows_apart):
+        """Block-sparse attention (ops/transformer/sparse_attention.py) of
+        this step's rows: their keys and values into the pool, the compressed
+        keys they complete into the sequence's slot, then each one-token row
+        over its chosen blocks through the decode kernel and each tile by
+        masked attention over its sequence's context."""
+        from ..ops.transformer import paged_attention as pa
+        from ..ops.transformer import sparse_attention as sa
+
+        cfg = self.config
+        pool, ck = caches["pool"], caches["own"]
+        spec, scale = cfg.sparse_spec, cfg.head_dim ** -0.5
+        tables, starts, slots = rows["tables"], rows["starts"], rows["slots"]
+        cut, end, tile, live = (rows[k] for k in ("cut", "end", "tile", "live"))
+        T, nh, hd = q.shape
+        tiles = slice(cut, end, tile)       # the first row of each tile
+        with jax.named_scope("kv_write"):
+            pool = pa.write_rows(pool, pool_layer, tables, starts[:, None],
+                                 k[:, None], v[:, None], rows_apart=rows_apart)
+        with jax.named_scope("sparse_select"):
+            ck = sa.write_keys(ck, pool, pool_layer, layer, tables[:cut],
+                               slots[:cut], starts[:cut],
+                               live[:cut].astype(jnp.int32), spec, 1)
+            if cut < end:
+                ck = sa.write_keys(ck, pool, pool_layer, layer, tables[tiles],
+                                   slots[tiles], starts[tiles],
+                                   rows["tile_counts"], spec, tile)
+        n = jnp.where(live, starts + 1, 0)
+        o, counts = sa.decode_rows(q[:cut], pool, pool_layer, tables[:cut],
+                                   ck[layer, slots[:cut]], n[:cut], spec, scale)
+        if cut < end:
+            o2 = sa.tile_rows(q[cut:end].reshape(-1, tile, nh, hd), pool,
+                              pool_layer, tables[tiles],
+                              ck[layer, slots[tiles]], starts[tiles], spec,
+                              scale)
+            o = jnp.concatenate([o, o2.reshape(-1, nh, hd)])
+        o = jnp.pad(o, ((0, T - o.shape[0]), (0, 0), (0, 0)))
+        return o, {"pool": pool, "own": ck}, counts
+
+    def _linear_mixer(self, q, k, v, blk, caches, layer, pool_layer, rows,
+                      rows_apart):
+        """Lightning attention (ops/transformer/linear_attention.py) of this
+        step's rows on the sequences' state slots: rotary on q and k, the
+        recurrence row by row for the one-token rows and in its blocked form
+        for the tiles, then the output norm over all heads' values."""
+        from ..ops.transformer import linear_attention as la
+
+        cfg = self.config
+        lin = caches["own"]
+        starts, slots = rows["starts"], rows["slots"]
+        cut, end, tile = rows["cut"], rows["end"], rows["tile"]
+        T, nh, hd = q.shape
+        tiles = slice(cut, end, tile)
+        q, k = (a[:, 0] for a in _rope(q[:, None], k[:, None],
+                                       starts[:, None], hd, cfg.rope_theta))
+        q = q * jnp.asarray(hd ** -0.5, q.dtype)
+        fresh = starts == 0
+        o, lin = la.decode_rows(lin, layer, slots[:cut], q[:cut], k[:cut],
+                                v[:cut], fresh[:cut])
+        if cut < end:
+            tiled = [a[cut:end].reshape(-1, tile, nh, hd) for a in (q, k, v)]
+            o2, lin = la.chunk_tiles(lin, layer, slots[tiles],
+                                     rows["tile_counts"], *tiled, fresh[tiles])
+            o = jnp.concatenate([o, o2.reshape(-1, nh, hd)])
+        o = jnp.pad(o, ((0, T - o.shape[0]), (0, 0), (0, 0)))
+        with jax.named_scope("linear_attn"):
+            o = _norm(o.reshape(T, nh * hd), blk["o_norm_scale"], None,
+                      "rmsnorm", cfg.norm_eps)
+        return o, {"own": lin}, None
 
     def decode_paged_multi(self, params, kv_pool, toks, tables, starts, k: int,
                            sampling=None):

@@ -19,6 +19,7 @@ Packed int4 stores two codes per int8 byte (lo/hi nibble, sign-extended on
 unpack with arithmetic shifts).
 """
 
+import re
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -123,25 +124,28 @@ def quantize_param_tree(params: Dict, num_bits: int = 8, group_size: int = 128,
                         targets=DEFAULT_TARGETS) -> Dict:
     """Quantize the matmul weights in a TransformerLM param tree.
 
-    Only ``blocks`` leaves named in ``targets`` (>=2-D, floating) are
+    Only leaves of ``blocks`` (of a ``layer_types`` model: of every
+    ``blocks_<i>`` group) named in ``targets`` (>=2-D, floating) are
     converted; everything else passes through unchanged.
     """
     if num_bits not in (4, 6, 8):
         raise ValueError(f"num_bits must be 4, 6 or 8, got {num_bits}")
     out = dict(params)
-    blocks = params.get("blocks")
-    if blocks is None:
+    groups = [g for g in params
+              if g == "blocks" or re.fullmatch(r"blocks_\d+", g)]
+    if not groups:
         raise ValueError("expected a TransformerLM param tree with 'blocks'")
-    new_blocks = {}
-    for k, v in blocks.items():
-        if k in targets and hasattr(v, "ndim") and v.ndim >= 2 \
-                and jnp.issubdtype(jnp.asarray(v).dtype, jnp.floating):
-            codes, scale = quantize_leaf(v, num_bits, group_size)
-            new_blocks[f"{k}::q{num_bits}"] = codes
-            new_blocks[f"{k}::scale"] = scale
-        else:
-            new_blocks[k] = v
-    out["blocks"] = new_blocks
+    for group in groups:
+        new_blocks = {}
+        for k, v in params[group].items():
+            if k in targets and hasattr(v, "ndim") and v.ndim >= 2 \
+                    and jnp.issubdtype(jnp.asarray(v).dtype, jnp.floating):
+                codes, scale = quantize_leaf(v, num_bits, group_size)
+                new_blocks[f"{k}::q{num_bits}"] = codes
+                new_blocks[f"{k}::scale"] = scale
+            else:
+                new_blocks[k] = v
+        out[group] = new_blocks
     return out
 
 

@@ -187,6 +187,26 @@ def heads_per_cell(pool) -> int:
     return max(d for d in range(1, kvh + 1) if kvh % d == 0 and d <= max(fit, 1))
 
 
+#: bytes one trip of the decode kernel's loop fetches, at least, where a
+#: row has that many blocks: a trip's fixed cost (the DMA's wait, two small
+#: products, the running softmax) is ~0.35 us whatever the block holds
+DECODE_TRIP_BYTES = 256 * 1024
+#: pool blocks a trip may fetch at most
+DECODE_TRIP_BLOCKS = 8
+
+
+def blocks_per_trip(pool) -> int:
+    """Pool blocks one trip of :func:`paged_decode`'s loop fetches and
+    attends together, from the pool's shape and dtype alone (as
+    :func:`heads_per_cell`): as many as make :data:`DECODE_TRIP_BYTES` of a
+    cell's heads, at most :data:`DECODE_TRIP_BLOCKS`. One wherever a cell's
+    block is that large already (16 kv heads of 64: 256 KB); eight for a pool
+    read one kv head a cell (sparse attention's view, 32 KB a block)."""
+    _, _, _, BS, row = pool.shape
+    block = heads_per_cell(pool) * BS * row * pool.dtype.itemsize
+    return max(1, min(DECODE_TRIP_BLOCKS, DECODE_TRIP_BYTES // block))
+
+
 def kernels_wanted() -> bool:
     """Do the paged programs take the Pallas kernels here? On a TPU, or
     forced (``DSTPU_FORCE_PAGED_KERNEL=1``: tests, interpreted on the CPU),
@@ -328,20 +348,24 @@ def kv_write(pool, layer, blk, off, kv):
 
 
 def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
-                   buf, sem, *, block_size, scale):
+                   buf, sem, *, block_size, scale, bpt):
     """Grid (B, kvh / hpc): ONE cell per sequence and group of ``hpc`` kv
     heads (all of them wherever the buffer fits: :func:`heads_per_cell`). The
     cell streams this sequence's ACTIVE pool blocks from HBM with
-    double-buffered DMA (prefetch j+1 while computing j) and computes every
-    head of the group from each: cells number the rows, not rows x heads x
-    table slots, and a cell's work follows its row's real length.
+    double-buffered DMA (prefetch trip j+1 while computing trip j), ``bpt``
+    blocks a trip (:func:`blocks_per_trip`), and computes every head of the
+    group from each: cells number the rows, not rows x heads x table slots,
+    and a cell's work follows its row's real length.
 
     The pool is the whole stacked pool as it lies in HBM (see
     :func:`init_pool`): one DMA fetches ``pool[layer, h0:h0+hpc, tables[b, j]]``,
     ``hpc`` tiles of (BS, 2*hd), each contiguous, whose rows are
     ``[k_t | v_t]``; K and V are the lane halves. The row is a multiple of
     128 lanes for every supported head size, which is what Mosaic asks of an
-    HBM DMA slice.
+    HBM DMA slice. A trip's blocks lie one behind the other along the
+    buffer's token axis; where the row's blocks end inside a trip, its last
+    block is fetched again in their place (finite values, masked like the
+    tokens past ``lens`` in any last block).
 
     What ``lens`` says is what the cell does: ``lens[b] == 0`` starts no DMA,
     runs no trip of the loop and writes zeros."""
@@ -349,33 +373,40 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
     layer = layer_ref[0]
     seq_len = lens_ref[b]
     nblk = (seq_len + block_size - 1) // block_size
+    ntrip = (nblk + bpt - 1) // bpt
     _, hpc, g, hd = q_ref.shape
     heads = pl.ds(pl.program_id(1) * hpc, hpc)
     q = q_ref[0].astype(jnp.float32) * scale  # (hpc, g, hd)
 
-    def copy(j, slot):
-        return pltpu.make_async_copy(
-            pool_ref.at[layer, heads, tables_ref[b, j]], buf.at[slot],
-            sem.at[slot])
+    def copies(j, slot):
+        return [pltpu.make_async_copy(
+            pool_ref.at[layer, heads,
+                        tables_ref[b, jnp.minimum(j * bpt + i, nblk - 1)
+                                   if bpt > 1 else j]],
+            buf.at[slot, :, pl.ds(i * block_size, block_size)],
+            sem.at[slot, i]) for i in range(bpt)]
 
-    @pl.when(nblk > 0)
+    @pl.when(ntrip > 0)
     def _prologue():
-        copy(0, 0).start()
+        for c in copies(0, 0):
+            c.start()
 
     def body(j, carry):
         m, l, acc = carry
         slot = jax.lax.rem(j, 2)
 
-        @pl.when(j + 1 < nblk)
+        @pl.when(j + 1 < ntrip)
         def _prefetch():
-            copy(j + 1, 1 - slot).start()
+            for c in copies(j + 1, 1 - slot):
+                c.start()
 
-        copy(j, slot).wait()
-        kv = buf[slot].astype(jnp.float32)  # (hpc, BS, 2*hd)
-        s = jax.lax.dot_general(          # every head of the group: (hpc, g, BS)
+        for c in copies(j, slot):
+            c.wait()
+        kv = buf[slot].astype(jnp.float32)  # (hpc, bpt*BS, 2*hd)
+        s = jax.lax.dot_general(          # every head of the group: (hpc, g, T)
             q, kv[..., :hd], (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        kpos = j * block_size + jax.lax.broadcasted_iota(
+        kpos = j * (bpt * block_size) + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 2)
         s = jnp.where(kpos < seq_len, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -390,7 +421,7 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
     m0 = jnp.full((hpc, g, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((hpc, g, 1), jnp.float32)
     acc0 = jnp.zeros((hpc, g, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, nblk, body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(0, ntrip, body, (m0, l0, acc0))
     o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
@@ -413,7 +444,7 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None):
     token."""
     B, nh, hd = q.shape
     _, kvh, _, BS, _ = pool.shape
-    g, hpc = nh // kvh, heads_per_cell(pool)
+    g, hpc, bpt = nh // kvh, heads_per_cell(pool), blocks_per_trip(pool)
     block = pl.BlockSpec((1, hpc, g, hd), lambda b, c, *_: (b, c, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, tables, lens
@@ -422,12 +453,12 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None):
                   pl.BlockSpec(memory_space=pl.ANY)],  # the pool stays in HBM
         out_specs=block,
         scratch_shapes=[
-            pltpu.VMEM((2, hpc) + pool.shape[3:], pool.dtype),  # double buffer
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((2, hpc, bpt * BS, pool.shape[4]), pool.dtype),
+            pltpu.SemaphoreType.DMA((2, bpt)),     # the double buffer's
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_size=BS,
+        functools.partial(_decode_kernel, block_size=BS, bpt=bpt,
                           scale=scale if scale is not None else hd ** -0.5),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kvh, g, hd), q.dtype),

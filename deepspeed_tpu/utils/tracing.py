@@ -260,9 +260,11 @@ MODEL_SCOPES = ("embed", "attn", "mlp", "lm_head_loss")
 SERVE_SCOPES = ("kv_write", "paged_attn", "sample")
 #: parts of a layer told apart inside a program without gradients: latent
 #: attention's projections, the expert layer's routing, grouped products,
-#: shared expert and identity experts, a double layer's dense feed-forwards
+#: shared expert and identity experts, a double layer's dense feed-forwards,
+#: a lightning layer's recurrence (decay, update, read-out, output norm), a
+#: sparse layer's selector (compressed keys, scores, top-k, compacted tables)
 LAYER_SCOPES = ("mla_proj", "moe_route", "moe_experts", "moe_shared",
-                "moe_zero", "dense_ffn")
+                "moe_zero", "dense_ffn", "linear_attn", "sparse_select")
 
 
 def _abstract(x):
@@ -290,12 +292,16 @@ def classify(op_name: str) -> str:
     forward recomputed under the backward); ``bwd``; ``fwd`` (the
     differentiated forward); the serving scopes ``kv_write``, ``paged_attn``,
     ``sample``; a layer's own parts ``mla_proj``, ``moe_route``,
-    ``moe_experts``, ``moe_shared``, ``moe_zero``, ``dense_ffn`` and else
+    ``moe_experts``, ``moe_shared``, ``moe_zero``, ``dense_ffn``,
+    ``linear_attn``, ``sparse_select`` and else
     ``model`` (a model scope in a program without gradients);
     ``kv_carry`` (the paged program's layer scan itself, which carries the
     stacked pool: whatever it does to the pool besides the layers' own
-    in-place writes); else ``unscoped``."""
-    parts = re.split(r"[/()]", op_name)
+    in-place writes); else ``unscoped``. The name's last component is the
+    primitive itself and marks nothing: a ``transpose`` of an array, in a
+    serving program or in a differentiated forward, is not a backward pass,
+    and a name of one component has no scope."""
+    parts = re.split(r"[/()]", op_name.rpartition("/")[0])
     if "optimizer" in parts:
         return "optimizer"
     for s in SERVE_SCOPES:

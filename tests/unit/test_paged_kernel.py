@@ -604,3 +604,30 @@ def test_forward_paged_rows_apart_writes_the_live_rows_alone(monkeypatch):
     np.testing.assert_array_equal(k[:, :, 1:], s[:, :, 1:])
     np.testing.assert_array_equal(np.asarray(lg_k)[live],
                                   np.asarray(lg_s)[live])
+
+
+@pytest.mark.parametrize("shape,dtype,bpt", [
+    ((24, 16, 832, 64, 128), jnp.bfloat16, 1),     # gpt2-medium's: 256 KB
+    ((24, 16, 832, 64, 256), jnp.bfloat16, 1),     # Pythia-1.4B's: 512 KB
+    ((2, 1, 27136, 64, 256), jnp.bfloat16, 8),     # sparse attention's view
+    ((2, 2, 13568, 64, 256), jnp.bfloat16, 4),
+    ((5, 1, 3072, 64, 640), jnp.bfloat16, 3),
+    ((1, 1, 64, 16, 128), jnp.float32, 8),         # never more than eight
+])
+def test_blocks_per_trip_follows_the_pools_shape(shape, dtype, bpt):
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    assert pa.blocks_per_trip(jax.ShapeDtypeStruct(shape, dtype)) == bpt
+
+
+@pytest.mark.parametrize("lens", [(19 * 16, 8 * 16 + 1, 1), (24 * 16 - 5, 16)])
+def test_trips_of_several_blocks_match_plain_attention(lens):
+    """Rows of more blocks than one trip fetches: whole trips, a trip that
+    the row's blocks end inside (its last block fetched again in their
+    place and masked), a row shorter than one trip."""
+    pa, pool, q, tables, row_lens, _ = _case(1, 4, 64, 16, list(lens),
+                                             MAXB=24, dead=2)
+    assert pa.blocks_per_trip(pool) == 8
+    out = pa.paged_decode(q, pool, jnp.int32(1), tables, row_lens)
+    ref = plain_attention(q, pool, jnp.int32(1), tables, row_lens)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)

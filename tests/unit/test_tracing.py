@@ -413,6 +413,19 @@ def test_fused_ce_kernels_are_named():
      "kv_write"),
     ("jit(ragged)/kv_carry/while/body/closed_call/mlp/dot_general", "model"),
     ("jit(step)/optimizers/sub", "unscoped"),
+    # the last component is the primitive and marks nothing: an array's
+    # transpose is no backward pass, in a serving program or a forward
+    ("jit(ragged)/kv_carry/while/body/closed_call/attn/linear_attn/transpose",
+     "linear_attn"),
+    ("jit(ragged)/while/body/attn/transpose", "model"),
+    ("jit(fused_step)/jvp(attn)/transpose", "fwd"),
+    ("jit(fused_step)/transpose(jvp(attn))/transpose", "bwd"),
+    ("jit(s)/transpose(jvp())/while/body/closed_call/checkpoint/"
+     "rematted_computation/attn/transpose", "remat"),
+    ("jit(ragged)/transpose", "unscoped"),
+    ("transpose", "unscoped"),
+    ("optimizer", "unscoped"),
+    ("", "unscoped"),
 ])
 def test_classify(op_name, scope):
     assert tracing.classify(op_name) == scope
