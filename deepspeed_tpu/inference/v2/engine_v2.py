@@ -22,7 +22,7 @@ ragged batch becomes **one fixed-shape program**:
 live sequences advance one token, and per-uid last-token logits come back.
 """
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +42,59 @@ from .ragged_manager import BlockedKVCache, DSStateManager
 #: history placeholder of a position whose token was fed on the device from
 #: an unfetched round; the predecessor's ``fetch`` writes the token in
 FED_ON_DEVICE = -1
+
+
+def feed_layout(rows: int, max_seqs: int, max_blocks_per_seq: int,
+                stateful: bool):
+    """Where each field of a ragged step's feed lies in its ONE int32
+    buffer (docs/SERVING.md "One packed feed"): ``({name: (slice, shape,
+    dtype)}, length)``, a function of shapes the engine knows and nothing
+    else. Every field is 32 bits wide; the two float32 sampling arrays are
+    views of the same words on the host (``.view``) and bitcasts of them in
+    the program. ``src_rows`` names, for a row fed on the device, its row of
+    the preceding round's result (-1: the host's ``ids``); ``row_slots`` (a
+    stateful model's) is empty for any other model."""
+    T, M = rows, max_seqs
+    i32, f32 = np.int32, np.float32
+    fields = (("ids", (T, 1), i32), ("tables", (T, max_blocks_per_seq), i32),
+              ("starts", (T,), i32), ("logit_rows", (M,), i32),
+              ("slots", (M,), i32), ("seeds", (M,), i32), ("poss", (M,), i32),
+              ("temps", (M,), f32), ("top_ks", (M,), i32),
+              ("top_ps", (M,), f32), ("src_rows", (T,), i32),
+              ("row_slots", (T if stateful else 0,), i32))
+    layout, at = {}, 0
+    for name, shape, dtype in fields:
+        end = at + int(np.prod(shape))
+        layout[name] = (slice(at, end), shape, dtype)
+        at = end
+    return layout, at
+
+
+class PackedFeed(NamedTuple):
+    """A ragged step's feed on the host: the fields the engine fills, in
+    :func:`feed_layout`'s order, each a view into ``buf``, the one int32
+    array that is transferred."""
+    ids: np.ndarray
+    tables: np.ndarray
+    starts: np.ndarray
+    logit_rows: np.ndarray
+    slots: np.ndarray
+    seeds: np.ndarray
+    poss: np.ndarray
+    temps: np.ndarray
+    top_ks: np.ndarray
+    top_ps: np.ndarray
+    src_rows: np.ndarray
+    row_slots: np.ndarray
+    buf: np.ndarray
+
+
+def unpack_feed(feed, layout):
+    """The fields of a packed feed inside the program: static slices of the
+    one int32 array, the float fields bitcast back bit for bit."""
+    return {name: (jax.lax.bitcast_convert_type(feed[words], jnp.float32)
+                   if dtype == np.float32 else feed[words]).reshape(shape)
+            for name, (words, shape, dtype) in layout.items()}
 
 
 class DecodeDispatchHandle:
@@ -176,7 +229,14 @@ class InferenceEngineV2:
         #: the unfetched ``decode_dispatch`` rounds, oldest first: at most
         #: two, each staged from its own scratch set
         self._unfetched: List[DecodeDispatchHandle] = []
-        self._feed_merge_fn = None
+        #: what the ragged program reads as the preceding round's result
+        #: where there is none (a mixed step, a pipe restart): zeros, cached
+        self._no_prev = None
+        #: host arrays transferred and compiled programs called, counted
+        #: where the calls are made; ``engine.dispatch`` carries what a step
+        #: added since the step before (``feed_arrays``, ``launches``)
+        self._feeds = self._launches = 0
+        self._call_marks = (0, 0)
         self.prefix_cache = bool(prefix_cache)
         # host-RAM KV tier (docs/PREFIX_CACHING.md "Two-tier cache"): spill
         # capacity in blocks under the device pool. 0 = single-tier (the
@@ -373,9 +433,18 @@ class InferenceEngineV2:
             return self._ragged_fn
         model = self.model
 
-        def ragged(params, pool, ids, tables, starts, logit_rows,
-                   slots, seeds, poss, temps, top_ks, top_ps, bias_pool,
-                   greedy, slot_cache=None, row_slots=None):
+        def ragged(params, pool, feed, prev, bias_pool, greedy,
+                   slot_cache=None):
+            # the step's whole feed is ONE int32 array (feed_layout), and the
+            # run-ahead merge is the program's first line: a row fed on the
+            # device takes its token from ``prev``, the preceding round's
+            # unfetched result (zeros, and every src -1, where there is none)
+            f = unpack_feed(feed, self._feed_layout(
+                self._feed_rows(feed.shape[0]))[0])
+            src = f["src_rows"][:, None]
+            ids = jnp.where(src >= 0,
+                            prev[jnp.maximum(src, 0)].astype(jnp.int32),
+                            f["ids"])
             # ids (T, 1): every row is its own length-1 "sequence" against the
             # shared pool; only the (max_seqs,) logit_rows are projected
             # through the vocab head (reference ragged_ops/logits_gather)
@@ -385,11 +454,12 @@ class InferenceEngineV2:
             # naming its sequence's slot: (logits, pool, slot arrays[, counts])
             segs = self._seg_tile > 1 and ids.shape[0] > self.max_seqs
             lg, pool, *stats = model.forward_paged(
-                params, ids, pool, tables, starts, logit_rows=logit_rows,
+                params, ids, pool, f["tables"], f["starts"],
+                logit_rows=f["logit_rows"],
                 rows_apart=self._rows_apart(ids.shape[0]),
                 **({"seg_from": self.max_seqs} if segs else {}),
                 **({"moe_stats": True} if self._moe_stats and greedy else {}),
-                **({"state": slot_cache, "row_slots": row_slots}
+                **({"state": slot_cache, "row_slots": f["row_slots"]}
                    if self._stateful else {}))
             if self._stateful:
                 slot_cache, *stats = stats
@@ -402,8 +472,9 @@ class InferenceEngineV2:
                 # bit-identical to the legacy greedy program; a batch-level
                 # cond inside sample_or_argmax skips the sampling math when
                 # every row is greedy), so sampled traffic adds no trace.
-                toks = sample_or_argmax(lg + bias_pool[slots], seeds, poss,
-                                        temps, top_ks, top_ps)
+                toks = sample_or_argmax(lg + bias_pool[f["slots"]], f["seeds"],
+                                        f["poss"], f["temps"], f["top_ks"],
+                                        f["top_ps"])
                 # the expert counts ride behind the tokens: one array, one
                 # transfer
                 return (jnp.concatenate([toks, stats[0]]) if stats
@@ -411,10 +482,28 @@ class InferenceEngineV2:
             return lg, pool
 
         fn = audited_jit("engine_v2.ragged", ragged, max_traces=4,
-                         donate_argnums=(1, 14) if self._stateful else (1,),
-                         static_argnums=(13,))
+                         donate_argnums=(1, 6) if self._stateful else (1,),
+                         static_argnums=(5,))
         self._ragged_fn = fn
         return fn
+
+    def _feed_layout(self, rows: int):
+        """:func:`feed_layout` of this engine's ragged step of ``rows``."""
+        return feed_layout(rows, self.max_seqs,
+                           self.block_mgr.max_blocks_per_seq, self._stateful)
+
+    def _feed_rows(self, length: int) -> int:
+        """The rows of the step whose packed feed is ``length`` words: the
+        ragged program has two shapes, so a feed has one of two lengths."""
+        for rows in (self.max_seqs, self.token_budget):
+            if self._feed_layout(rows)[1] == length:
+                return rows
+        raise ValueError(f"no ragged step has a feed of {length} words")
+
+    def _prev_shape(self) -> Tuple[int]:
+        """Shape of a greedy decode round's result, which the next round
+        reads as ``prev``: a token a row, then the step's counts."""
+        return (self.max_seqs + len(self._step_counts),)
 
     def _rows_apart(self, rows: int) -> bool:
         """Is the ragged program of ``rows`` padded rows the decode round's,
@@ -433,18 +522,13 @@ class InferenceEngineV2:
         prefill+decode shape, ``max_seqs`` rows the decode round. ``.compile()``
         ``.as_text()`` shows whether the paged-decode kernel is in it. Nothing
         runs and the pool is not donated."""
-        M = self.max_seqs
-
         def i32(*shape):
             return jax.ShapeDtypeStruct(shape, jnp.int32)
 
-        f32 = jax.ShapeDtypeStruct((M,), jnp.float32)
         return self._get_ragged().lower(
-            self.params, self.kv, i32(rows, 1),
-            i32(rows, self.block_mgr.max_blocks_per_seq), i32(rows),
-            i32(M), i32(M), i32(M), i32(M), f32, i32(M), f32,  # see ragged()
-            self._bias(), greedy,
-            *((self.slot_cache, i32(rows)) if self._stateful else ()))
+            self.params, self.kv, i32(self._feed_layout(rows)[1]),
+            i32(*self._prev_shape()), self._bias(), greedy,
+            *((self.slot_cache,) if self._stateful else ()))
 
     def _get_cow(self):
         """Single fixed-shape block-copy program for copy-on-write: duplicate
@@ -551,7 +635,7 @@ class InferenceEngineV2:
         (``submit_d2h`` → ``copy_to_host_async``), so demotion never blocks
         the decode dispatch behind it. The payload (an open TransferTicket)
         materializes lazily at promotion/spill time via ``drain_before``."""
-        blk = self._get_tier_gather()(self.kv, jnp.int32(block))
+        blk = self._launch(self._get_tier_gather(), self.kv, jnp.int32(block))
         return self.transfer.submit_d2h(blk)
 
     def _scatter_blocks(self, payloads, dsts) -> None:
@@ -577,9 +661,10 @@ class InferenceEngineV2:
                 for i, v in enumerate(vals):
                     buf[i] = v
                 batch = te.submit_h2d(buf).value
+                self._feeds += 1
                 for i, j in enumerate(chunk):
-                    self.kv = scatter(self.kv, batch, jnp.int32(i),
-                                      jnp.int32(dsts[j]))
+                    self.kv = self._launch(scatter, self.kv, batch,
+                                           jnp.int32(i), jnp.int32(dsts[j]))
         finally:
             te.release_staging(buf)
 
@@ -990,6 +1075,64 @@ class InferenceEngineV2:
                 a.fill(0)
         return bufs
 
+    def _feed_scratch(self, key: Tuple, rows: int) -> PackedFeed:
+        """The packed feed of a ragged step of ``rows`` (:func:`feed_layout`),
+        zeroed in place: its fields, each a view into the one int32 buffer
+        that is transferred (the float fields as ``.view(np.float32)``), so
+        filling a field fills the buffer and nothing is copied on the host."""
+        feed = self._scratch.get(key)
+        if feed is None:
+            layout, length = self._feed_layout(rows)
+            buf = np.zeros(length, np.int32)
+            feed = self._scratch[key] = PackedFeed(buf=buf, **{
+                name: buf[words].view(dtype).reshape(shape)
+                for name, (words, shape, dtype) in layout.items()})
+        else:
+            feed.buf.fill(0)
+        feed.src_rows.fill(-1)   # no row is fed on the device
+        return feed
+
+    def _enqueue_ragged(self, disp, rows: int, feed: np.ndarray, prev,
+                        greedy: bool):
+        """Hand one ragged step to the device: ONE transfer (the packed
+        feed) and ONE program launch, for the decode round and the mixed
+        step alike. ``prev``: the preceding round's unfetched result, which
+        the program merges the fed tokens from (None: there is none).
+        Returns the program's first result, still on the device."""
+        fn = self._get_ragged()
+        with tracing.span("engine.enqueue"):
+            if prev is None:
+                if self._no_prev is None:
+                    self._no_prev = jnp.zeros(self._prev_shape(), jnp.int32)
+                prev = self._no_prev
+            self._feeds += 1
+            args = (self.params, self.kv, jax.device_put(feed), prev,
+                    self._bias(), greedy,
+                    *((self.slot_cache,) if self._stateful else ()))
+            if disp.recording:
+                tracing.note_program("engine_v2.ragged", fn, args,
+                                     key=(rows, greedy))
+            out = self._keep_caches(self._launch(fn, *args))
+        self._count_calls(disp)
+        return out
+
+    def _launch(self, fn, *args):
+        """Call a compiled program of a step, counted for ``launches``."""
+        self._launches += 1
+        return fn(*args)
+
+    def _copy_on_write(self, d, first: int, last: int) -> None:
+        """Detach a private copy of every block ``first..last`` of ``d``'s
+        table that another sequence also references, before a write lands
+        in it: shared blocks are immutable, a fresh ``ensure()``-allocated
+        block has refcount 1 and is skipped. One fixed-shape program a copy
+        (:meth:`_get_cow`)."""
+        for j in range(first, last + 1):
+            if self.block_mgr.refcount(d.blocks[j]) > 1:
+                src, dst = self.block_mgr.copy_on_write(d, j)
+                self.kv = self._launch(self._get_cow(), self.kv,
+                                       jnp.int32(src), jnp.int32(dst))
+
     @property
     def fused_cache_size(self) -> int:
         """Number of compiled traces of the fused multi-step decode program.
@@ -1054,16 +1197,7 @@ class InferenceEngineV2:
             with tracing.span("engine.build"):
                 T, plan, finals, feed = self._build_ragged_step(work)
                 self._count_dispatch(disp, T, plan)
-            fn = self._get_ragged()
-            with tracing.span("engine.enqueue"):
-                *feed, row_slots = feed
-                args = (self.params, self.kv,
-                        *(jnp.asarray(a) for a in feed), self._bias(), greedy,
-                        *self._slot_args(row_slots))
-                if disp.recording:
-                    tracing.note_program("engine_v2.ragged", fn, args,
-                                         key=(T, greedy))
-                lg = self._keep_caches(fn(*args))
+            lg = self._enqueue_ragged(disp, T, feed.buf, None, greedy)
             if self.prefix_cache:
                 # the step's writes are dispatched: every block it filled now
                 # holds valid prefix content — publish to the content index
@@ -1079,13 +1213,6 @@ class InferenceEngineV2:
                 self._note_moe_rows(disp, lg)
             for i, d in enumerate(finals):
                 out[d.uid] = int(lg[i]) if greedy else lg[i]
-
-    def _slot_args(self, row_slots):
-        """The ragged program's trailing arguments of a stateful model: its
-        slot arrays and each row's slot (nothing for any other model)."""
-        if not self._stateful:
-            return ()
-        return self.slot_cache, jnp.asarray(row_slots)
 
     def _keep_caches(self, out):
         """Take the ragged program's donated caches back (the pool; of a
@@ -1106,6 +1233,18 @@ class InferenceEngineV2:
         if self._moe_stats and disp.recording:
             disp.set(**{name: int(fetched[self.max_seqs + i])
                         for i, name in enumerate(self._step_counts)})
+
+    def _count_calls(self, disp) -> None:
+        """``feed_arrays`` / ``launches`` of ``engine.dispatch``: the host
+        arrays transferred and the compiled programs called since the step
+        before (the step's own feed and program, copy-on-write copies, tier
+        promotions and demotions that landed for it), and ``one_feed``: the
+        step went to the device as one buffer and one launch."""
+        feeds = self._feeds - self._call_marks[0]
+        launches = self._launches - self._call_marks[1]
+        self._call_marks = (self._feeds, self._launches)
+        disp.set(feed_arrays=feeds, launches=launches,
+                 one_feed=int(feeds == 1 and launches == 1))
 
     def _count_dispatch(self, disp, padded_rows: int, plan,
                         fused: bool = False) -> None:
@@ -1148,10 +1287,10 @@ class InferenceEngineV2:
     def _build_ragged_step(self, work):
         """Plan one ragged step over ``work`` (the sequences with pending
         tokens), allocate its blocks, copy shared ones on write and fill the
-        host arrays. Returns (padded rows, [(descriptor, tokens taken)],
-        the sequences that yield an output, the program's array arguments in
-        call order). Sequence state is advanced here; a raise leaves every
-        descriptor intact."""
+        packed feed. Returns (padded rows, [(descriptor, tokens taken)],
+        the sequences that yield an output, the step's :class:`PackedFeed`).
+        Sequence state is advanced here; a raise leaves every descriptor
+        intact."""
         work.sort(key=lambda d: (d.in_flight, d.slot))
         # decode-round fast path: when every pending item is a single
         # token and they fit in max_seqs rows, use the small compiled
@@ -1212,28 +1351,17 @@ class InferenceEngineV2:
             raise pool_exhausted
         plan = ready
         if self.prefix_cache:
-            # copy-on-write: a write landing inside a block some OTHER
-            # sequence also references (a full-prompt cache hit recomputes
-            # its final token inside the last shared block) must first
-            # detach a private copy — shared blocks are immutable. Fresh
-            # ensure()-allocated blocks have refcount 1 and are skipped.
+            # a write landing inside a block some OTHER sequence also
+            # references (a full-prompt cache hit recomputes its final token
+            # inside the last shared block) first detaches a private copy
+            bs = self.block_mgr.block_size
             for d, take in plan:
-                bs = self.block_mgr.block_size
-                first = d.seen_tokens // bs
-                last = min((d.seen_tokens + take - 1) // bs,
-                           len(d.blocks) - 1)
-                for j in range(first, last + 1):
-                    if self.block_mgr.refcount(d.blocks[j]) > 1:
-                        src, dst = self.block_mgr.copy_on_write(d, j)
-                        self.kv = self._get_cow()(
-                            self.kv, jnp.int32(src), jnp.int32(dst))
-        M = self.max_seqs
-        (ids, tables, starts, logit_rows, slots, seeds, poss, top_ks,
-         temps, top_ps, row_slots) = self._scratch_for(
-            ("ragged", T),
-            ((T, 1), (T, self.block_mgr.max_blocks_per_seq), (T,),
-             (M,), (M,), (M,), (M,), (M,), (M,), (M,), (T,)),
-            dtypes=(np.int32,) * 8 + (np.float32, np.float32, np.int32))
+                self._copy_on_write(
+                    d, d.seen_tokens // bs,
+                    min((d.seen_tokens + take - 1) // bs, len(d.blocks) - 1))
+        feed = self._feed_scratch(("ragged", T), T)
+        (ids, tables, starts, logit_rows, slots, seeds, poss, temps, top_ks,
+         top_ps, _, row_slots, _) = feed
         finals = []
         r = singles = 0
         seg_next = self.max_seqs       # next free segment tile's first row
@@ -1272,8 +1400,7 @@ class InferenceEngineV2:
                 d.history.extend(d.pending[:take])
             del d.pending[:take]
             d.seen_tokens += take
-        return T, plan, finals, (ids, tables, starts, logit_rows, slots, seeds,
-                                 poss, temps, top_ks, top_ps, row_slots)
+        return T, plan, finals, feed
 
     # ------------------------------------------------------------------
     # reference surface
@@ -1414,17 +1541,13 @@ class InferenceEngineV2:
                 descs = sorted((self.state.seqs[u] for u in tokens),
                                key=lambda d: d.slot)
                 if self.prefix_cache:
-                    # copy-on-write for every block the K writes can land in —
-                    # shared blocks are immutable (same discipline as _put_paged)
+                    # every block the K writes can land in
                     bs = self.block_mgr.block_size
                     for d in descs:
-                        first = d.seen_tokens // bs
-                        last = min((d.seen_tokens + K - 1) // bs, len(d.blocks) - 1)
-                        for j in range(first, last + 1):
-                            if self.block_mgr.refcount(d.blocks[j]) > 1:
-                                src, dst = self.block_mgr.copy_on_write(d, j)
-                                self.kv = self._get_cow()(
-                                    self.kv, jnp.int32(src), jnp.int32(dst))
+                        self._copy_on_write(
+                            d, d.seen_tokens // bs,
+                            min((d.seen_tokens + K - 1) // bs,
+                                len(d.blocks) - 1))
                 B = self.max_seqs
                 toks, tables, starts, slots, seeds, top_ks, temps, top_ps = \
                     self._scratch_for(
@@ -1460,11 +1583,13 @@ class InferenceEngineV2:
         and fetch its (max_seqs, K) result: ONE designed transfer per K
         tokens, the same budget as the ragged step's."""
         with tracing.span("engine.enqueue"):
+            self._feeds += len(feed)
             args = (self.params, self.kv,
                     *(jnp.asarray(a) for a in feed), self._bias())
             if disp.recording:
                 tracing.note_program("engine_v2." + program, fn, args)
-            ys, self.kv = fn(*args)
+            ys, self.kv = self._launch(fn, *args)
+        self._count_calls(disp)
         with tracing.span("engine.fetch"):
             return np.asarray(ys)  # dstpu-lint: ignore[DSTPU001]
 
@@ -1527,17 +1652,13 @@ class InferenceEngineV2:
                 descs = sorted((self.state.seqs[u] for u in tokens),
                                key=lambda d: d.slot)
                 if self.prefix_cache:
-                    # copy-on-write for every block the K writes can land in —
-                    # shared blocks are immutable (same discipline as decode_multi)
+                    # every block the K writes can land in
                     bs = self.block_mgr.block_size
                     for d in descs:
-                        first = d.seen_tokens // bs
-                        last = min((d.seen_tokens + K - 1) // bs, len(d.blocks) - 1)
-                        for j in range(first, last + 1):
-                            if self.block_mgr.refcount(d.blocks[j]) > 1:
-                                src, dst = self.block_mgr.copy_on_write(d, j)
-                                self.kv = self._get_cow()(
-                                    self.kv, jnp.int32(src), jnp.int32(dst))
+                        self._copy_on_write(
+                            d, d.seen_tokens // bs,
+                            min((d.seen_tokens + K - 1) // bs,
+                                len(d.blocks) - 1))
                 B = self.max_seqs
                 segs, tables, starts, slots, seeds, top_ks, temps, top_ps = \
                     self._scratch_for(
@@ -1574,21 +1695,6 @@ class InferenceEngineV2:
             # padding — meaningless, never returned
             out[d.uid] = [int(t) for t in ys[r, :len(row)]]
         return out
-
-    def _get_feed_merge(self):
-        """The run-ahead feed: ``ids`` of a decode round, each row's taken
-        from the preceding round's unfetched result where ``src`` names one
-        of its rows, from the host array otherwise. One tiny program before
-        the ragged one, so that one keeps its signature."""
-        if self._feed_merge_fn is None:
-
-            def feed_merge(prev, ids, src):
-                fed = prev[jnp.maximum(src, 0)].astype(ids.dtype)
-                return jnp.where(src[:, None] >= 0, fed[:, None], ids)
-
-            self._feed_merge_fn = audited_jit("engine_v2.feed_merge",
-                                              feed_merge)
-        return self._feed_merge_fn
 
     def decode_dispatch(self, tokens: Dict[int, Optional[int]],
                         prev: Optional[DecodeDispatchHandle] = None
@@ -1662,31 +1768,21 @@ class InferenceEngineV2:
                 descs = sorted((self.state.seqs[u] for u in tokens),
                                key=lambda d: d.slot)
                 if self.prefix_cache:
-                    # copy-on-write for the block the single write lands in —
-                    # shared blocks are immutable (same discipline as _put_paged)
+                    # the block the single write lands in
                     bs = self.block_mgr.block_size
                     for d in descs:
                         j = min(d.seen_tokens // bs, len(d.blocks) - 1)
-                        if self.block_mgr.refcount(d.blocks[j]) > 1:
-                            src, dst = self.block_mgr.copy_on_write(d, j)
-                            self.kv = self._get_cow()(
-                                self.kv, jnp.int32(src), jnp.int32(dst))
+                        self._copy_on_write(d, j, j)
                 # the decode-round fast shape of the ragged program (see _put_paged):
                 # a pure single-token round never pays the prefill budget's padding
                 T = (self.max_seqs if self.token_budget > self.max_seqs
                      else self.token_budget)
-                M = self.max_seqs
                 # the set no unfetched round was staged from
                 scratch_set = (1 - self._unfetched[0]._set
                                if self._unfetched else 0)
-                (ids, tables, starts, logit_rows, slots, seeds, poss, top_ks,
-                 temps, top_ps, src_rows, row_slots) = self._scratch_for(
-                    ("dispatch", T, scratch_set),
-                    ((T, 1), (T, self.block_mgr.max_blocks_per_seq), (T,),
-                     (M,), (M,), (M,), (M,), (M,), (M,), (M,), (T,), (T,)),
-                    dtypes=(np.int32,) * 8 + (np.float32, np.float32,
-                                              np.int32, np.int32))
-                src_rows.fill(-1)
+                feed = self._feed_scratch(("dispatch", T, scratch_set), T)
+                (ids, tables, starts, logit_rows, slots, seeds, poss, temps,
+                 top_ks, top_ps, src_rows, row_slots, _) = feed
                 for r, d in enumerate(descs):
                     tok = tokens[d.uid]
                     if tok is None:
@@ -1710,28 +1806,8 @@ class InferenceEngineV2:
                     d.seen_tokens += 1
                     d.uncommitted += 1  # stacked: commit_step settles per absorb
                 self._count_dispatch(disp, T, [(d, 1) for d in descs])
-            fn = self._get_ragged()
-            with tracing.span("engine.enqueue"):
-                # the whole feed rides ONE batched host→device staging call:
-                # at K=1 the per-call Python dispatch overhead of ten separate
-                # small transfers is itself a large slice of the host's share
-                # of a round
-                feed = (ids, tables, starts, logit_rows, slots, seeds, poss,
-                        temps, top_ks, top_ps)
-                # one staging call: the feed, then the optional arrays
-                ids_dev, *rest = jax.device_put(
-                    feed + ((src_rows,) if rows_of_prev else ())
-                    + ((row_slots,) if self._stateful else ()))
-                slots_dev = rest.pop() if self._stateful else None
-                if rows_of_prev:
-                    ids_dev = self._get_feed_merge()(prev._dev, ids_dev,
-                                                     rest.pop())
-                args = (self.params, self.kv, ids_dev, *rest, self._bias(),
-                        True, *self._slot_args(slots_dev))
-                if disp.recording:
-                    tracing.note_program("engine_v2.ragged", fn, args,
-                                         key=(T, True))
-                lg = self._keep_caches(fn(*args))
+            lg = self._enqueue_ragged(
+                disp, T, feed.buf, prev._dev if rows_of_prev else None, True)
         # no np.asarray and no register here — both are deferred: the
         # transfer to fetch(), the prefix-index publish to commit_step()
         handle = DecodeDispatchHandle([d.uid for d in descs], lg, eng=self,
@@ -1903,6 +1979,7 @@ class InferenceEngineV2:
         self._bias_rows.clear()
         self._bias_slots.clear()
         self._bias_pool = None
+        self._no_prev = None
         self.rebuilds += 1
         old = self.block_mgr
         if sanitize_enabled():
