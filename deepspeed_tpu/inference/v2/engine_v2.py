@@ -133,8 +133,10 @@ class DecodeDispatchHandle:
             # THE deferred transfer: the synchronous twin pays this same
             # np.asarray inline inside _put_paged; here it lands only after
             # the next round was dispatched, so the device never idles on it
-            with tracing.span("engine.fetch"):
+            with tracing.span("engine.fetch") as fetched:
                 lg = np.asarray(self._dev)  # dstpu-lint: ignore[DSTPU001]
+            if self._eng is not None:
+                self._eng._mark_fetched(fetched)
             self._out = {uid: int(lg[i]) for i, uid in enumerate(self.uids)}
             for history, at, row in self._fed:
                 # a rollback may have truncated the successor's position
@@ -232,6 +234,11 @@ class InferenceEngineV2:
         #: what the ragged program reads as the preceding round's result
         #: where there is none (a mixed step, a pipe restart): zeros, cached
         self._no_prev = None
+        #: when the last fetch returned (tracing.clock_ns; 0: unrecorded)
+        #: and why the device will have waited for the next launch, where
+        #: the scheduler said (note_idle): the ends of an ``engine.bubble``
+        self._fetched_ns = 0
+        self._idle_cause: Optional[str] = None
         #: host arrays transferred and compiled programs called, counted
         #: where the calls are made; ``engine.dispatch`` carries what a step
         #: added since the step before (``feed_arrays``, ``launches``)
@@ -1114,12 +1121,45 @@ class InferenceEngineV2:
                                      key=(rows, greedy))
             out = self._keep_caches(self._launch(fn, *args))
         self._count_calls(disp)
+        self._note_launch(disp)
         return out
 
     def _launch(self, fn, *args):
         """Call a compiled program of a step, counted for ``launches``."""
         self._launches += 1
         return fn(*args)
+
+    def note_idle(self, cause: str) -> None:
+        """The scheduler's word on why the next launch will find the device
+        with nothing: a barrier's reason, or ``empty`` when no request is
+        live or queued, which no later reason replaces (the device had
+        nothing to be given). The launch that ends the gap takes it."""
+        if self._idle_cause != "empty":
+            self._idle_cause = cause
+
+    def _mark_fetched(self, fetched) -> None:
+        """Where an ``engine.fetch`` span ends: a bubble can start here."""
+        self._fetched_ns = fetched.end if fetched.recording else 0
+
+    def _note_launch(self, disp) -> None:
+        """Right after a step's launch returned, outside ``engine.enqueue``
+        and only while recording (docs/TRACING.md). A round launched behind
+        an unfetched predecessor (``ahead`` 1) says whether that one had
+        already finished: ``starved`` 1, the device went dry waiting for the
+        host. A launch with no step unfetched ends an ``engine.bubble``: from
+        the return of the last fetch to now the engine had handed the device
+        nothing. Its cause is the scheduler's (:meth:`note_idle`), else
+        ``restart``: the pipe starts again with no barrier on this round."""
+        if not disp.recording:
+            return
+        if self._unfetched:
+            if disp.attrs.get("ahead"):
+                disp.set(starved=int(self._unfetched[-1]._dev.is_ready()))
+            return
+        cause, self._idle_cause = self._idle_cause or "restart", None
+        if self._fetched_ns:
+            tracing.event("engine.bubble", self._fetched_ns,
+                          tracing.clock_ns(), cause=cause)
 
     def _copy_on_write(self, d, first: int, last: int) -> None:
         """Detach a private copy of every block ``first..last`` of ``d``'s
@@ -1207,8 +1247,9 @@ class InferenceEngineV2:
             # THE step's one designed transfer (ships the whole batch's
             # results at once; everything above is dispatch-only): the
             # device wait
-            with tracing.span("engine.fetch"):
+            with tracing.span("engine.fetch") as fetched:
                 lg = np.asarray(lg)  # dstpu-lint: ignore[DSTPU001]
+            self._mark_fetched(fetched)
             if greedy:
                 self._note_moe_rows(disp, lg)
             for i, d in enumerate(finals):
@@ -1590,8 +1631,11 @@ class InferenceEngineV2:
                 tracing.note_program("engine_v2." + program, fn, args)
             ys, self.kv = self._launch(fn, *args)
         self._count_calls(disp)
-        with tracing.span("engine.fetch"):
-            return np.asarray(ys)  # dstpu-lint: ignore[DSTPU001]
+        self._note_launch(disp)
+        with tracing.span("engine.fetch") as fetched:
+            ys = np.asarray(ys)  # dstpu-lint: ignore[DSTPU001]
+        self._mark_fetched(fetched)
+        return ys
 
     def verify_multi(self, tokens: Dict[int, int],
                      drafts: Dict[int, Sequence[int]]) -> Dict[int, List[int]]:
@@ -1968,8 +2012,9 @@ class InferenceEngineV2:
         deleted so the store never serves a previous incarnation's KV."""
         self.state = DSStateManager(self.max_seqs, self.max_seq_len)
         # an in-flight dispatch died with the device: its handle can never
-        # be fetched against the new incarnation
+        # be fetched against the new incarnation (nor end a bubble)
         self._unfetched = []
+        self._fetched_ns = 0
         self.transfer.cancel_all()
         self._drop_swaps()  # counts any orphaned handoff imports
         # sampling state is per-residency (slot bindings died with the state

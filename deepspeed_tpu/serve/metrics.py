@@ -97,7 +97,8 @@ class ServeMetrics:
         #: dropped at absorb because the late token finished the request
         #: (EOS or stop-sequence overrun), ``pipeline_stalls`` rounds that had to
         #: drain and fall back to the synchronous twin (fused/spec horizon,
-        #: prefill backlog, dynamic sampling, admission stall), and the
+        #: prefill backlog, dynamic sampling, admission stall; each also
+        #: counted by its reason under ``barriers/<reason>``), and the
         #: stage-timing split gauges ``host_plan_ms`` / ``device_wait_ms``
         #: / ``absorb_ms`` of the latest absorbed round — the one number
         #: ``observe_step`` used to conflate.
@@ -214,9 +215,14 @@ class ServeMetrics:
         ``serve/decode/rollback_tokens`` by the engine commit)."""
         self.pipeline["speculative_rollbacks"] += n_tokens
 
-    def observe_pipeline_stall(self) -> None:
-        """A round drained the pipe and fell back to the synchronous twin."""
+    def observe_pipeline_stall(self, reason: Optional[str] = None) -> None:
+        """A round drained the pipe and fell back to the synchronous twin
+        for ``reason`` (the barrier's, ``barriers/<reason>``), or the pipe
+        ran dry with nothing to enqueue behind it (None)."""
         self.pipeline["pipeline_stalls"] += 1
+        if reason is not None:
+            key = "barriers/" + reason
+            self.pipeline[key] = self.pipeline.get(key, 0) + 1
 
     def observe_decode(self, horizon: int, fused: bool) -> None:
         self.decode["horizon"] = float(horizon)

@@ -1448,7 +1448,8 @@ class ContinuousBatchScheduler:
             lambda uid: self._live[uid].prompt + self._live[uid].tokens,
             self.decode_horizon - 1)
 
-    def _decode_sync(self, now: float) -> None:
+    def _decode_sync(self, now: float,
+                     barrier: Optional[str] = None) -> None:
         """One engine dispatch: the live decode feed plus — under chunked
         interleaved prefill — as many pending prefill-chunk rows as the
         token budget holds, in ONE compiled ragged program. Pure decode
@@ -1458,7 +1459,8 @@ class ContinuousBatchScheduler:
         ``verify_multi`` dispatch and the accepted prefix (+1 bonus token)
         is committed, the rest rolled back — the same all-or-nothing
         K-position shape as the fused path, so retries, containment, and
-        the duty cycle treat both identically."""
+        the duty cycle treat both identically. ``barrier``: why the
+        run-ahead loop sent this round here (:meth:`_pipeline_barrier`)."""
         with tracing.span("sched.plan"):
             backlog = self._prefill_backlog() if self.chunked_prefill else 0
             if not backlog:
@@ -1499,6 +1501,11 @@ class ContinuousBatchScheduler:
             # one pair of clock readings: the span's are the gauges'
             disp = tracing.timed_span("sched.dispatch", kind=kind,
                                       rows=len(feed))
+            if barrier and disp.recording:
+                # the round says why it is synchronous, and the engine
+                # files the device's wait for it under the same reason
+                disp.set(barrier=barrier)
+                self.engine.note_idle(barrier)
             try:
                 with disp:
                     if drafts:
@@ -1588,28 +1595,34 @@ class ContinuousBatchScheduler:
     # pipelined dispatch (docs/SERVING.md "Pipelined dispatch")
     # ------------------------------------------------------------------
     def _pipeline_barrier(self, now: float, feed: Dict[int, int],
-                          backlog: int) -> bool:
-        """True when THIS round cannot run with a step in flight and must
-        take the synchronous path (after draining the pipe):
+                          backlog: int) -> Optional[str]:
+        """Why THIS round cannot run with a step in flight and must take
+        the synchronous path (after draining the pipe), or None:
 
-        - a chunked-prefill backlog: prompt chunks ride the mixed ragged
-          dispatch, whose host sync is inherent;
-        - a stalled monolithic prefill draining;
-        - speculation configured, or the adaptive horizon choosing a fused
-          round: both commit/rollback against their absorb the SAME step;
-        - a fed request with a dynamic logit processor: its bias row must
-          be refreshed from the absorbed token BEFORE the next dispatch
-          samples it — a one-late absorb would sample under a stale mask.
+        - ``backlog``: a chunked-prefill backlog: prompt chunks ride the
+          mixed ragged dispatch, whose host sync is inherent;
+        - ``stalled``: a stalled monolithic prefill draining;
+        - ``speculation`` configured, or the adaptive ``horizon`` choosing
+          a fused round: both commit/rollback against their absorb the SAME
+          step;
+        - ``dynamic``: a fed request with a dynamic logit processor: its
+          bias row must be refreshed from the absorbed token BEFORE the next
+          dispatch samples it — a one-late absorb would sample under a stale
+          mask.
         """
-        if backlog or self._stalled or self.spec is not None:
-            return True
+        if backlog:
+            return "backlog"
+        if self._stalled:
+            return "stalled"
+        if self.spec is not None:
+            return "speculation"
         if feed and self._effective_horizon(now, feed) > 1:
-            return True
+            return "horizon"
         for uid in feed:
             sp = self._live[uid].sampling
             if sp is not None and sp.dynamic:
-                return True
-        return False
+                return "dynamic"
+        return None
 
     def _pipeline_dispatch_stage(self, now: float
                                  ) -> Optional[Dict[str, object]]:
@@ -1663,9 +1676,9 @@ class ContinuousBatchScheduler:
                     next_feed[uid] = last_tok
         if barrier:
             if self._inflight is not None:
-                self.metrics.observe_pipeline_stall()
+                self.metrics.observe_pipeline_stall(barrier)
                 self._drain_inflight(now)
-            self._decode_sync(now)
+            self._decode_sync(now, barrier)
             return None
         if not cands and prev is None:
             return None
@@ -2003,6 +2016,12 @@ class ContinuousBatchScheduler:
         """End-of-step bookkeeping shared by both drive modes: gauges and
         (under ``DSTPU_SANITIZE``) the between-steps invariant sweep."""
         self.metrics.observe_gauges(len(self._queue), len(self._live))
+        if (tracing.enabled() and self._inflight is None
+                and not (self._queue or self._live)):
+            # nothing live or queued: the device's wait from here on is an
+            # empty server's, whatever the round that ends it is (the
+            # engine's ``engine.bubble``, docs/TRACING.md)
+            self.engine.note_idle("empty")
         self.metrics.observe_prefill_backlog(self._prefill_backlog())
         self.metrics.observe_resilience(self.breaker, self.watchdog)
         self.metrics.faults["journal_live"] = float(len(self.journal))

@@ -21,7 +21,8 @@ While on, a ``jax.monitoring`` listener records every backend compile, and
 every load of a program from the compile cache, as a ``compile`` span, and
 :func:`note_program` remembers which jitted programs ran, so that
 :func:`device_scopes` can map the device's instruction names to the
-``jax.named_scope`` they were traced under — after the window, never in it.
+``jax.named_scope`` they were traced under, and :func:`device_programs` to
+the program variants that hold them — after the window, never in it.
 
 No other module of ``deepspeed_tpu`` calls ``jax.profiler`` annotations
 directly.
@@ -32,7 +33,7 @@ import itertools
 import re
 import threading
 import time
-from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Set
 
 import jax
 from jax._src.lib import _profiler
@@ -188,29 +189,6 @@ def clear() -> None:
 
 # -- reading spans ---------------------------------------------------------
 
-def _covered(intervals: Iterable[Tuple[int, int]], lo: int, hi: int) -> int:
-    """Length of the union of ``intervals`` clipped to [lo, hi]."""
-    total, edge = 0, lo
-    for s, e in sorted(intervals):
-        s, e = max(s, edge), min(e, hi)
-        if e > s:
-            total += e - s
-            edge = e
-    return total
-
-
-def self_time(spans: Iterable[Record]) -> Dict[int, int]:
-    """{span id: ns} of each span's duration minus the part of its interval
-    that its child spans cover (children may overlap; the part is their
-    union)."""
-    spans = list(spans)
-    kids: Dict[int, List[Tuple[int, int]]] = {}
-    for s in spans:
-        kids.setdefault(s.parent, []).append((s.start, s.end))
-    return {s.id: (s.end - s.start)
-            - _covered(kids.get(s.id, ()), s.start, s.end) for s in spans}
-
-
 def descendants(spans: Iterable[Record], root: int) -> List[Record]:
     """Every span below ``root`` (children, their children...)."""
     spans = list(spans)
@@ -354,16 +332,37 @@ def scopes_of_hlo(text: str) -> Dict[str, str]:
     return out
 
 
-def device_scopes() -> Dict[str, str]:
-    """{op_key: scope} for the programs noted while recording, from their
-    optimized HLO (``lower().compile().as_text()`` on the noted shapes: a
-    compile-cache hit). Call it after the traced window. An instruction two
-    programs put under different scopes reads ``mixed``."""
-    merged: Dict[str, str] = {}
+def _scope_tables() -> Dict[tuple, Dict[str, str]]:
+    """{(name, key): {op_key: scope}} of every program noted while recording,
+    from its optimized HLO (``lower().compile().as_text()`` on the noted
+    shapes: one compile-cache hit a variant, kept)."""
     for ident, (fn, args, kwargs) in list(_programs.items()):
         if ident not in _scope_cache:
             text = fn.lower(*args, **kwargs).compile().as_text()
             _scope_cache[ident] = scopes_of_hlo(text)
-        for k, scope in _scope_cache[ident].items():
+    return _scope_cache
+
+
+def device_scopes() -> Dict[str, str]:
+    """{op_key: scope} for the programs noted while recording, from their
+    optimized HLO. Call it after the traced window. An instruction two
+    programs put under different scopes reads ``mixed``."""
+    merged: Dict[str, str] = {}
+    for table in _scope_tables().values():
+        for k, scope in table.items():
             merged[k] = scope if merged.get(k, scope) == scope else "mixed"
     return merged
+
+
+def device_programs() -> Dict[str, Set[tuple]]:
+    """{op_key: {(name, key), ...}}: the noted programs whose optimized HLO
+    holds that instruction and shape, from the same tables as
+    :func:`device_scopes`. A key in one program's set alone is device time
+    that program spent (a decode round's rows against a mixed step's); one
+    that two variants hold (a weight-shaped slice, the head's copies) is in
+    both sets, and its time is *shared*, never guessed."""
+    held: Dict[str, Set[tuple]] = {}
+    for ident, table in _scope_tables().items():
+        for k in table:
+            held.setdefault(k, set()).add(ident)
+    return held

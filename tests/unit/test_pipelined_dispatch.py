@@ -29,6 +29,7 @@ from deepspeed_tpu.serve import (ContinuousBatchScheduler, EnginePool,
                                  FaultInjector, FaultSpec, HealthMonitor,
                                  Request, RequestState, RetryPolicy,
                                  SamplingParams)
+from deepspeed_tpu.utils import tracing
 
 
 @pytest.fixture(scope="module")
@@ -710,3 +711,204 @@ class TestPoolTwoPhase:
             assert r.state is RequestState.DONE
             assert r.tokens == ref[r.uid], f"uid {r.uid} diverged"
         pool.close()
+
+
+# ---------------------------------------------------------------------------
+# a step accounted by its kind (docs/TRACING.md): the barrier's reason, the
+# engine's bubbles, a round the device had to wait for
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def session(tmp_path):
+    """A real profiler session: the recorder is on exactly while it is."""
+    tracing.clear()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        yield
+    finally:
+        if tracing.enabled():
+            jax.profiler.stop_trace()
+        tracing.clear()
+
+
+def _decoding(m, params, gen=24):
+    """A warm scheduler (both shapes ran) with one request in steady decode:
+    the pipe is full, a round is unfetched."""
+    sched = ContinuousBatchScheduler(_engine(m, params), sleep=lambda s: None)
+    sched.submit(_prompts()[2], max_new_tokens=3)
+    sched.run_until_complete()
+    sched.submit(_prompts()[1], max_new_tokens=gen)
+    while sched._inflight is None:
+        sched.step()
+    return sched
+
+
+def _named(name):
+    return [s for s in tracing.snapshot() if s.name == name]
+
+
+def _unfetched_intervals(spans):
+    """[(launch returned, fetch returned)] of every engine step recorded:
+    the end of its ``engine.enqueue`` to the end of its ``engine.fetch`` (a
+    deferred round's fetch sits under the ``sched.wait`` of a later step: it
+    is the next fetch in time that no earlier launch took)."""
+    launches = sorted(s.end for s in spans if s.name == "engine.enqueue")
+    fetches = sorted(s.end for s in spans if s.name == "engine.fetch")
+    return list(zip(launches, fetches))
+
+
+class TestStepAccounting:
+    def test_backlog_barrier_names_itself_and_its_bubbles(self, setup,
+                                                          session):
+        """A prompt arriving under a full pipe: the round that carries its
+        first chunk says ``barrier="backlog"`` on ``sched.dispatch``, the
+        drain before it ends in an ``engine.bubble`` of that cause (under the
+        mixed step's ``engine.dispatch``), and the first round after the run
+        of chunk steps restarts the pipe: cause ``restart``."""
+        m, params = setup
+        sched = _decoding(m, params)
+        tracing.clear()
+        sched.submit(_prompts()[0], max_new_tokens=4)      # 33 tokens: chunks
+        sched.run_until_complete()
+        spans = tracing.snapshot()
+        by_id = {s.id: s for s in spans}
+        sync = [s for s in _named("sched.dispatch") if "barrier" in s.attrs]
+        assert sync and {s.attrs["barrier"] for s in sync} == {"backlog"}
+        assert all(s.attrs["kind"] in ("mixed", "prefill") for s in sync)
+        assert not [s for s in _named("sched.dispatch")
+                    if s.attrs["kind"] == "decode" and "barrier" in s.attrs]
+        bubbles = _named("engine.bubble")
+        causes = [b.attrs["cause"] for b in sorted(bubbles,
+                                                   key=lambda b: b.start)]
+        # one bubble a chunk step, then the restart; nothing else in between
+        assert causes[:len(sync)] == ["backlog"] * len(sync)
+        assert causes[len(sync)] == "restart"
+        for b in bubbles:
+            parent = by_id[b.parent]
+            assert parent.name == "engine.dispatch" and b.end > b.start
+            assert parent.start <= b.end <= parent.end
+            assert parent.attrs["ahead"] == 0 and "starved" not in parent.attrs
+        first = by_id[min(bubbles, key=lambda b: b.start).parent]
+        assert by_id[first.parent].attrs["barrier"] == "backlog"
+
+    def test_bubbles_never_overlap_an_unfetched_round(self, setup, session):
+        """A bubble starts where a fetch returned with nothing unfetched and
+        ends where the next launch returned: no instant of it lies between a
+        launch and its fetch."""
+        m, params = setup
+        sched = _decoding(m, params)
+        sched.submit(_prompts()[0], max_new_tokens=6)
+        sched.run_until_complete()
+        spans = tracing.snapshot()
+        bubbles = _named("engine.bubble")
+        flights = _unfetched_intervals(spans)
+        assert len(bubbles) >= 3 and len(flights) > len(bubbles)
+        for b in bubbles:
+            # the launch that ends the bubble returns just before its clock
+            # reading: that flight alone may begin inside the last instants
+            assert not [(lo, hi) for lo, hi in flights
+                        if lo < b.end - 50_000 and hi > b.start]
+        # and they follow each other: no two bubbles overlap either
+        ends = sorted((b.start, b.end) for b in bubbles)
+        assert all(a[1] <= b[0] for a, b in zip(ends, ends[1:]))
+
+    def test_an_idle_schedulers_gap_reads_empty(self, setup, session):
+        """Nothing live or queued at the end of a step: whatever round ends
+        the gap (here one with a backlog), the gap is the empty server's."""
+        m, params = setup
+        sched = _decoding(m, params, gen=5)
+        sched.run_until_complete()
+        assert not sched.queue_depth and not sched.live_count
+        tracing.clear()
+        sched.submit(_prompts()[0], max_new_tokens=3)
+        sched.run_until_complete()
+        bubbles = sorted(_named("engine.bubble"), key=lambda b: b.start)
+        assert bubbles[0].attrs["cause"] == "empty"
+        assert "empty" not in [b.attrs["cause"] for b in bubbles[1:]]
+        sync = [s for s in _named("sched.dispatch") if "barrier" in s.attrs]
+        assert sync[0].attrs["barrier"] == "backlog"    # the round says its own
+
+    def test_barrier_reasons_count_with_tracing_off(self, setup):
+        """``serve/pipeline/barriers/<reason>``: the one always-on addition,
+        one dict increment a drain."""
+        m, params = setup
+        assert not tracing.enabled()
+        sched = _decoding(m, params)
+        assert "barriers/backlog" not in sched.metrics.pipeline
+        stalls = sched.metrics.pipeline["pipeline_stalls"]
+        sched.submit(_prompts()[0], max_new_tokens=4)
+        sched.run_until_complete()
+        p = sched.metrics.pipeline
+        assert p["barriers/backlog"] == 1
+        assert p["pipeline_stalls"] == stalls + 1
+        events = {k: v for k, v, _ in sched.metrics.events()}
+        assert events["serve/pipeline/barriers/backlog"] == 1.0
+        assert tracing.snapshot() == []
+
+    @pytest.mark.parametrize("reason", ["backlog", "stalled", "speculation",
+                                        "horizon", "dynamic", None])
+    def test_the_barrier_says_why(self, setup, reason):
+        m, params = setup
+        sched = ContinuousBatchScheduler(_engine(m, params),
+                                         sleep=lambda s: None)
+        req = sched.submit(_prompts()[2], max_new_tokens=8,
+                           sampling=SamplingParams(temperature=0.7, seed=1))
+        while req.state is not RequestState.DECODE:
+            sched.step()
+        feed = {req.uid: req.tokens[-1]}
+        if reason == "stalled":
+            sched._stalled = True
+        elif reason == "speculation":
+            sched.spec = object()
+        elif reason == "horizon":
+            sched._effective_horizon = lambda now, feed: 4
+        elif reason == "dynamic":
+            def mask(tokens, vocab):
+                return None
+            mask.dynamic = True
+            req.sampling = SamplingParams(temperature=0.7, seed=1,
+                                          processors=(mask,))
+        assert sched._pipeline_barrier(0.0, feed,
+                                       7 if reason == "backlog" else 0) == reason
+
+    def test_every_run_ahead_round_says_whether_it_was_starved(self, setup,
+                                                               session):
+        m, params = setup
+        sched = _decoding(m, params)
+        sched.submit(_prompts()[0], max_new_tokens=6)
+        sched.run_until_complete()
+        rounds = _named("engine.dispatch")
+        ahead = [s for s in rounds if s.attrs["ahead"] == 1]
+        assert len(ahead) >= 10
+        assert all(s.attrs["starved"] in (0, 1) for s in ahead)
+        assert not [s for s in rounds
+                    if s.attrs["ahead"] == 0 and "starved" in s.attrs]
+
+    def test_off_no_span_no_bubble_and_no_is_ready(self, setup, monkeypatch):
+        """Tracing off: ``span()`` is the shared no-op, the engine keeps no
+        fetch time, and nobody asks the device whether a round finished."""
+        m, params = setup
+        assert not tracing.enabled()
+        assert tracing.span("engine.dispatch") is tracing.NO_SPAN
+        asked = []
+
+        def note(disp, eng=None, real=InferenceEngineV2._note_launch):
+            assert disp is tracing.NO_SPAN
+            for h in eng._unfetched:
+                monkeypatch.setattr(
+                    type(h._dev), "is_ready",
+                    lambda self: asked.append(1) or True, raising=False)
+            return real(eng, disp)
+
+        sched = ContinuousBatchScheduler(_engine(m, params),
+                                         sleep=lambda s: None)
+        eng = sched.engine
+        monkeypatch.setattr(eng, "_note_launch",
+                            lambda disp: note(disp, eng), raising=False)
+        sched.submit(_prompts()[0], max_new_tokens=8)
+        sched.run_until_complete()
+        assert sched.metrics.pipeline["ahead_dispatches"] > 0
+        assert not asked and eng._fetched_ns == 0 and eng._idle_cause is None
+        assert tracing.snapshot() == []

@@ -139,16 +139,14 @@ def test_span_records_parent_attrs_and_step_marker(session):
     assert not tracing.enabled() and tracing.span("x") is tracing.NO_SPAN
 
 
-def test_self_time_is_duration_minus_what_children_cover():
+def test_descendants_are_everything_below_a_span():
     R = tracing.Record
     spans = [R(1, "step", 0, 100, 0, {}), R(2, "a", 10, 40, 1, {}),
-             R(3, "b", 30, 60, 1, {}),        # overlaps a: union is 10..60
-             R(4, "leaf", 35, 38, 3, {}), R(5, "late", 90, 130, 1, {})]
-    own = tracing.self_time(spans)
-    assert own[1] == 100 - 50 - 10          # 'late' counts up to the parent's end
-    assert own[2] == 30 and own[3] == 30 - 3 and own[4] == 3
+             R(3, "b", 30, 60, 1, {}), R(4, "leaf", 35, 38, 3, {}),
+             R(5, "late", 90, 130, 1, {})]
     assert sorted(s.id for s in tracing.descendants(spans, 1)) == [2, 3, 4, 5]
     assert [s.id for s in tracing.descendants(spans, 3)] == [4]
+    assert not hasattr(tracing, "self_time")    # no reader ever asked (PR 40)
 
 
 def test_an_exception_cannot_leave_inner_spans_open(session):
@@ -302,6 +300,63 @@ def test_serving_scopes_reach_the_device_scope_map(lm, session):
     jax.profiler.stop_trace()
     found = set(tracing.device_scopes().values())
     assert {"kv_write", "paged_attn", "model", "kv_carry"} <= found
+
+
+# -- the by-program table ---------------------------------------------------
+
+@pytest.fixture
+def two_variants(session):
+    """One jitted function noted under two keys, as the ragged program's
+    decode round and mixed step are: rows 4 and rows 16 over one weight."""
+    @jax.jit
+    def step(w, x):
+        with jax.named_scope("embed"):
+            z = jnp.cumsum(w, axis=0)           # the weight's shape: both
+        with jax.named_scope("mlp"):
+            y = jnp.tanh(x) @ z                 # the rows' shape: one
+        return y, z
+
+    w = jnp.ones((8, 8), jnp.float32)
+    texts = {}
+    for rows in (4, 16):
+        x = jnp.ones((rows, 8), jnp.float32)
+        tracing.note_program("step", step, (w, x), key=(rows, True))
+        step(w, x)
+        texts["step", (rows, True)] = step.lower(w, x).compile().as_text()
+    jax.profiler.stop_trace()
+    return texts
+
+
+def test_device_programs_says_which_variant_holds_an_op(two_variants):
+    small, large = ("step", (4, True)), ("step", (16, True))
+    held = tracing.device_programs()
+    of_small = [k for k in held if k.endswith(" f32[4,8]")]
+    of_large = [k for k in held if k.endswith(" f32[16,8]")]
+    of_both = [k for k in held if k.endswith(" f32[8,8]")]
+    assert of_small and of_large and of_both
+    assert all(held[k] == {small} for k in of_small)
+    assert all(held[k] == {large} for k in of_large)
+    # the same instruction over the weight in both: shared, never guessed
+    assert all(held[k] == {small, large} for k in of_both)
+    scopes = tracing.device_scopes()
+    assert set(held) == set(scopes)
+    assert {scopes[k] for k in of_both} >= {"model"}
+
+
+def test_device_scopes_is_what_it_was_beside_the_by_program_table(two_variants):
+    """Extending the table builder moved nothing in ``device_scopes()``: it
+    is the merge of ``scopes_of_hlo`` over each noted program's text, an
+    instruction two programs scope differently reading ``mixed``."""
+    expected = {}
+    for text in two_variants.values():
+        for k, scope in tracing.scopes_of_hlo(text).items():
+            expected[k] = scope if expected.get(k, scope) == scope else "mixed"
+    before = tracing.device_scopes()
+    tracing.device_programs()
+    assert before == expected == tracing.device_scopes()
+    assert set(tracing._scope_cache) == set(two_variants)   # one compile each
+    tracing.clear()
+    assert tracing.device_programs() == {} == tracing.device_scopes()
 
 
 # -- training --------------------------------------------------------------
