@@ -1073,6 +1073,14 @@ class TransformerLM:
                 q = q + blk["wq_bias"].astype(h.dtype)
                 kk = kk + blk["wk_bias"].astype(h.dtype)
                 v = v + blk["wv_bias"].astype(h.dtype)
+            if paged is not None:
+                # served: each product is whole before it is split into heads.
+                # Without the barrier the TPU compiler pushes the split into
+                # the matrix: it slices the layer's (H, H) out of the stack,
+                # relays a transposed copy of it and contracts that, a layer
+                # a dispatch (0.43 of serve-chat's 2.1 ms round). Behind it
+                # the product reads the stack where it lies, as wo's does
+                q, kk, v = (jax.lax.optimization_barrier(a) for a in (q, kk, v))
             q = q.reshape(B, S, nh, hd)
             kk = kk.reshape(B, S, kvh, hd)
             v = v.reshape(B, S, kvh, hd)
@@ -1360,12 +1368,21 @@ class TransformerLM:
             with jax.named_scope("mla_proj"):
                 h = rms(x, "ln1_scale")
                 c_q = rms(once(h @ blk["wq_a"].astype(dt)), "q_a_scale", q_scale)
-                q = (c_q @ blk["wq_b"].astype(dt)).reshape(B, S, nh, nope + rope)
+                q = c_q @ blk["wq_b"].astype(dt)
+                if paged is not None:
+                    # whole before the split into heads, or the compiler
+                    # relays a transposed copy of the matrix (:meth:`_block`)
+                    q = once(q)
+                q = q.reshape(B, S, nh, nope + rope)
                 kv_a = once(h @ blk["wkv_a"].astype(dt))
                 c_kv = rms(kv_a[..., :rank], "kv_a_scale", kv_scale)  # (B, S, rank)
                 k_rope = _rope_interleaved(kv_a[..., None, rank:], positions, cfg)
                 q_nope = q[..., :nope]
                 q_rope = _rope_interleaved(q[..., nope:], positions, cfg)
+                # the two views feed products batched by head; the compiler
+                # wants that head major and in the stored (rank, nh * (nope +
+                # vd)) it lies inside the minor dimension, so this one matrix
+                # is relaid a layer whatever form the pair takes (PERF.md 5)
                 wkv_b = blk["wkv_b"].astype(dt).reshape(rank, nh, nope + vd)
                 w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
             if paged is None:
@@ -1935,8 +1952,10 @@ class TransformerLM:
             return (y, pool, layer + 1), None
 
         # the pool rides the layer scan as a carry, one donated buffer from
-        # entry to exit, written and read where it lies: "kv_carry" is what
-        # the scan's own plumbing still costs on the device
+        # entry to exit, written and read where it lies. "kv_carry" is the
+        # scan's own ops on the device: what it does to its carried arrays
+        # and to the stacked leaves it slices (a layer's matrix materialised
+        # out of the stack and relaid reads here, not under the layer)
         with jax.named_scope("kv_carry"):
             (x, kv_pool, _), _ = jax.lax.scan(
                 body, (x, kv_pool, jnp.int32(0)), params["blocks"])

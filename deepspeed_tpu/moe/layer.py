@@ -127,7 +127,7 @@ def grouped_experts(x, chosen, weights, wi, w_gate, w_down, *, first: int = 0,
         rank0 = jnp.cumsum(counts) - counts     # first sorted pair of each
         e_c = jnp.minimum(sorted_key, E - 1)
         dest = row0[e_c] + jnp.arange(pairs, dtype=jnp.int32) - rank0[e_c]
-        dest = jnp.where(sorted_key < E, dest, R)     # absent: the zero row
+        dest = jnp.where(sorted_key < E, dest, R)     # absent: past the end
         row_token = jnp.full((R,), T, jnp.int32).at[dest].set(
             order // k, mode="drop", unique_indices=True)
         pair_row = jnp.zeros((pairs,), jnp.int32).at[order].set(
@@ -159,9 +159,13 @@ def grouped_experts(x, chosen, weights, wi, w_gate, w_down, *, first: int = 0,
         _, ys = jax.lax.scan(one_tile, None,
                              (tile_ec, live, xs.reshape(R // tile, tile, H)))
     with jax.named_scope("moe_route"):
-        ys = jnp.concatenate([ys.reshape(R, H), jnp.zeros((1, H), ys.dtype)])
-        y = jnp.einsum("tkh,tk->th", ys[pair_row].astype(jnp.float32),
-                       weights.astype(jnp.float32))
+        # a pair that landed nowhere adds nothing: it reads row 0 at weight
+        # zero (a zero row appended for it to read was a copy of the whole
+        # (R, H) buffer, 88 MB a layer in a 512-row step)
+        landed = pair_row < R
+        rows = ys.reshape(R, H)[jnp.where(landed, pair_row, 0)]
+        y = jnp.einsum("tkh,tk->th", rows.astype(jnp.float32),
+                       jnp.where(landed, weights.astype(jnp.float32), 0.0))
     return y.astype(x.dtype), (jnp.sum(counts), jnp.max(counts))
 
 

@@ -273,9 +273,10 @@ def classify(op_name: str) -> str:
     ``moe_experts``, ``moe_shared``, ``moe_zero``, ``dense_ffn``,
     ``linear_attn``, ``sparse_select`` and else
     ``model`` (a model scope in a program without gradients);
-    ``kv_carry`` (the paged program's layer scan itself, which carries the
-    stacked pool: whatever it does to the pool besides the layers' own
-    in-place writes); else ``unscoped``. The name's last component is the
+    ``kv_carry`` (the paged program's layer scan itself: the ops that belong
+    to the loop and to no layer scope, which is what it does to the arrays it
+    carries, the stacked pool first, and to the stacked leaves it slices a
+    layer out of); else ``unscoped``. The name's last component is the
     primitive itself and marks nothing: a ``transpose`` of an array, in a
     serving program or in a differentiated forward, is not a backward pass,
     and a name of one component has no scope."""
