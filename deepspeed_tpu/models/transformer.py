@@ -601,6 +601,14 @@ def mla_softmax_scale(cfg: "TransformerConfig") -> float:
             * yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2)
 
 
+def paged_limits(tables, positions):
+    """The pool tokens each one-token row of a paged step attends over:
+    its position + 1, and 0 for a padding row, whose table names no block
+    (block 0 is the trash block no sequence holds). A row with 0 is dead for
+    the decode kernels (``paged_attention.paged_decode``, ``mla_decode``)."""
+    return jnp.where(tables[:, 0] > 0, positions + 1, 0)
+
+
 def sublayer_prefix(i: int) -> str:
     """Prefix of sublayer ``i``'s leaves in a double layer's ``blocks``."""
     return f"s{i}_"
@@ -1133,7 +1141,7 @@ class TransformerLM:
                         # A padding row's table names no block (block 0 is the
                         # trash block no sequence holds): it is marked dead here,
                         # lens 0, and the kernel fetches nothing for it
-                        lens = jnp.where(tables[:, 0] > 0, positions[:, 0] + 1, 0)
+                        lens = paged_limits(tables, positions[:, 0])
                         attn_out = pa.paged_decode(
                             q[:, 0], pool, layer, tables, lens)[:, None]
                     else:
@@ -1232,7 +1240,8 @@ class TransformerLM:
         return x + mlp_out, new_kv, aux
 
     def _block_mla(self, x, blk, *, positions, paged=None, seg_from=None,
-                   experts=None, row_mask=None, rows_apart=False):
+                   experts=None, row_mask=None, rows_apart=False,
+                   limits=None):
         """One latent-attention block on (B, S, H): a dense layer, an expert
         layer where ``blk`` holds a router, or a shortcut-connected double
         layer (``layer_kind="scmoe"``, :meth:`_block_scmoe`). Returns (y, new
@@ -1251,17 +1260,20 @@ class TransformerLM:
         rows of sequences of their own. ``experts``: (stacked expert leaves,
         layer of the group) when the caller kept them out of ``blk``.
         ``row_mask`` (B*S,) bool: the rows that are real tokens (padding rows
-        are routed to no expert). ``rows_apart``: as :meth:`_block`."""
+        are routed to no expert). ``rows_apart``: as :meth:`_block`.
+        ``limits`` (B*S,) int32: the pool tokens each row may see, 0 for a
+        padding row (:meth:`_mla_attention`)."""
         from ..moe.layer import _gated_mlp
 
         if self.config.layer_kind == "scmoe":
             return self._block_scmoe(x, blk, positions=positions, paged=paged,
                                      seg_from=seg_from, experts=experts,
-                                     row_mask=row_mask, rows_apart=rows_apart)
+                                     row_mask=row_mask, rows_apart=rows_apart,
+                                     limits=limits)
         blk = _dequant_woq(blk, x.dtype)
         attn_out, new_pool = self._mla_attention(
             x, blk, positions=positions, paged=paged, seg_from=seg_from,
-            rows_apart=rows_apart)
+            rows_apart=rows_apart, limits=limits)
         stats = None
         with jax.named_scope("mlp"):
             x = jax.lax.optimization_barrier(x + attn_out)
@@ -1275,7 +1287,7 @@ class TransformerLM:
         return x + mlp_out, new_pool, stats
 
     def _block_scmoe(self, x, blk, *, positions, paged, seg_from, experts,
-                     row_mask, rows_apart=False):
+                     row_mask, rows_apart=False, limits=None):
         """One shortcut-connected double layer (LongCat-Flash, ScMoE): two
         sublayers of latent attention and a dense feed-forward, and one
         expert layer computed from the first sublayer's post-attention norm
@@ -1299,7 +1311,8 @@ class TransformerLM:
             sub = sublayer(blk, i)
             attn_out, pool = self._mla_attention(
                 x, sub, positions=positions, seg_from=seg_from,
-                rows_apart=rows_apart, paged=None if paged is None
+                rows_apart=rows_apart, limits=limits,
+                paged=None if paged is None
                 else (pool, n_sub * layer + i, tables))
             with jax.named_scope("mlp"):
                 x = once(x + attn_out)
@@ -1337,12 +1350,14 @@ class TransformerLM:
         return y.reshape(B, S, H), stats
 
     def _mla_attention(self, x, blk, *, positions, paged=None, seg_from=None,
-                       rows_apart=False):
+                       rows_apart=False, limits=None):
         """Latent attention of one (sub)layer on the residual stream ``x``
         (B, S, H), its input norm and output projection included: (the
         attention's output, the new pool or None). ``blk``: the layer's
         leaves; ``paged``, ``seg_from`` and ``rows_apart`` as
-        :meth:`_block_mla`."""
+        :meth:`_block_mla`. ``limits`` (B,) int32, paged only: the pool
+        tokens each row attends over, what :func:`paged_limits` gives (the
+        step computes it once for all its layers; computed here if None)."""
         from ..ops.transformer import paged_attention as pa
 
         cfg = self.config
@@ -1412,9 +1427,11 @@ class TransformerLM:
                 with jax.named_scope("mla_proj"):
                     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
                 with jax.named_scope("paged_attn"):
+                    if limits is None:
+                        limits = paged_limits(tables, positions[:, 0])
                     o_lat = self._mla_paged_attention(
-                        q_lat, q_rope[:, 0], pool, layer, tables,
-                        positions[:, 0] + 1, scale, seg_from)
+                        q_lat, q_rope[:, 0], pool, layer, tables, limits,
+                        scale, seg_from)
                 with jax.named_scope("mla_proj"):
                     attn = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv)[:, None]
             attn_out = attn.reshape(B, S, nh * vd) @ blk["wo"].astype(dt)
@@ -1426,7 +1443,8 @@ class TransformerLM:
         """Absorbed attention of T one-token rows over the latent pool: rows
         before ``seg_from`` one a sequence, rows from it on in segment tiles.
         The Pallas kernel on a TPU (or forced, as the GPT-2 path's is), the
-        XLA gather off it."""
+        XLA gather off it. A row with ``limits`` 0 is dead: the kernel
+        fetches nothing for a one-token row or a tile of such rows."""
         from ..ops.transformer import paged_attention as pa
 
         attend = pa.mla_decode if pa.kernels_wanted() else pa.mla_attend_xla
@@ -1993,6 +2011,8 @@ class TransformerLM:
         # a padding row carries the all-zero table (trash block 0, which no
         # sequence ever holds): it is routed to no expert
         row_mask = tables[:, 0] > 0
+        # and dead for attention: it sees no token, in every layer
+        limits = paged_limits(tables, starts)
         with jax.named_scope("kv_carry"):
             for group in self.layer_groups(params):
                 leaves = params[group]
@@ -2010,7 +2030,8 @@ class TransformerLM:
                         h, blk, positions=positions,
                         paged=(pool, l + layer0, tables), seg_from=seg_from,
                         experts=None if big is None else (big, l),
-                        row_mask=row_mask, rows_apart=rows_apart)
+                        row_mask=row_mask, rows_apart=rows_apart,
+                        limits=limits)
                     if s is not None:
                         st = jnp.stack([st[0] + s[0], jnp.maximum(st[1], s[1]),
                                         *(st[i] + s[i] for i in range(2, len(s)))])

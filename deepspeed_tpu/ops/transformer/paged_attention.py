@@ -54,9 +54,6 @@ NEG_INF = -1e30
 #: rows of one chunk-segment tile of the latent paged program: consecutive
 #: tokens of one sequence that stream its latent once (:func:`mla_decode`)
 SEGMENT_TILE = 16
-#: pool blocks the latent kernel fetches a step (256 cached tokens at the
-#: usual block of 64)
-MLA_KV_BLOCKS = 4
 
 
 def _interpret() -> bool:
@@ -489,56 +486,99 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lens, *, scale=None):
 # ----------------------------------------------------------------------
 # latent attention (MLA) over the ``[c_kv | k_rope]`` pool, absorbed form
 # ----------------------------------------------------------------------
+#: pool blocks a trip of the latent kernel's loop fetches at most
+MLA_TRIP_BLOCKS = 16
+#: bytes the float32 scores of one trip (query rows x the trip's tokens) may
+#: take: past it a trip is bound by its products, not by its fixed cost
+MLA_SCORE_BYTES = 2 * 1024 * 1024
+
+
+def mla_blocks_per_trip(q_rows: int, pool) -> int:
+    """Pool blocks one trip of :func:`mla_decode`'s loop fetches at most, from
+    the query rows of a cell (``q_tile`` x heads) and the pool's shape alone
+    (as :func:`blocks_per_trip` for :func:`paged_decode`): as many as keep a
+    trip's scores within :data:`MLA_SCORE_BYTES`, at most
+    :data:`MLA_TRIP_BLOCKS`. Over blocks of 64 tokens: sixteen for a
+    one-token row of 64 heads (a trip's fixed cost is as long as four such
+    blocks take to arrive, and the scores of 64 query rows are small), eight
+    for a segment tile of 16 such rows, whose trip is bound by its products
+    (measured on the chip, PERF.md 5: four, eight, twelve and sixteen)."""
+    BS = pool.shape[3]
+    return max(1, min(MLA_TRIP_BLOCKS, MLA_SCORE_BYTES // (q_rows * BS * 4)))
+
+
 def _mla_kernel(layer_ref, tables_ref, nblk_ref, ql_ref, qr_ref, lim_ref,
-                pool_ref, o_ref, buf, sem, m_ref, l_ref, acc_ref, *,
-                block_size, rank, scale):
+                pool_ref, o_ref, buf, sem, m_ref, l_ref, acc_ref, slot_ref, *,
+                block_size, rank, scale, kv_blocks):
     """Grid (tiles,): ONE cell per query tile. The tile's ``Q`` rows (its
     tokens times all heads) are the absorbed queries ``[q_nope W_uk | q_rope]``
     of tokens of ONE sequence; the kernel streams that sequence's pool blocks
-    ``pool[layer, 0, tables[t, j]]`` from HBM, ``MLA_KV_BLOCKS`` at a time and
+    ``pool[layer, 0, tables[t, j]]`` from HBM, up to ``kv_blocks`` a trip and
     double-buffered, and contracts every head with each latent tile once:
     scores ``q_lat . c_kv + q_rope . k_rope``, masked per row by the tokens it
     may see, online softmax, and the weighted LATENT as the result (the
-    caller up-projects it through ``W_uv``)."""
+    caller up-projects it through ``W_uv``).
+
+    What ``nblk`` says is what the cell does. ``nblk[t] == 0`` (every row of
+    the tile has ``limits`` 0) starts no DMA of its own, runs no trip of the
+    loop and writes zeros. A live cell fetches its ``nblk[t]`` blocks and no
+    other: a trip takes what the row has left, at most ``kv_blocks``, and is
+    computed at half the buffer's width where that holds it (the buffer
+    behind the fetched blocks is masked; it is zeroed once a call, so what
+    lies there is zeros or older pool blocks, finite either way).
+
+    The double buffer is handed from cell to cell (the grid runs in order and
+    the scratch outlives a cell): before a cell computes its last trip, and a
+    dead cell at once, it starts the first trip of row ``t + 1`` into the
+    slot that is free, so only the first cell of a call waits for a fetch
+    with nothing to hide it. ``slot_ref`` carries the slot of the cell's
+    first trip."""
     t = pl.program_id(0)
     layer = layer_ref[0]
-    kv_blocks = MLA_KV_BLOCKS
-    n_steps = nblk_ref[t]                   # groups of kv_blocks pool blocks
-    width = kv_blocks * block_size
+    nblk = nblk_ref[t]
+    n_trips = (nblk + kv_blocks - 1) // kv_blocks
+    nblk_next = nblk_ref[t + 1]          # 0 behind the last cell
+    half = kv_blocks // 2
 
-    def copies(j, slot):
-        return [pltpu.make_async_copy(
-            pool_ref.at[layer, 0, tables_ref[t, j * kv_blocks + i]],
-            buf.at[slot, pl.ds(i * block_size, block_size)], sem.at[slot, i])
-            for i in range(kv_blocks)]
+    def each_copy(row, blocks, j, slot, act):
+        """``act`` on the copies of trip ``j`` of ``row``, which has
+        ``blocks`` blocks in all: one a block the row has left."""
+        def one(i, _):
+            at = pl.multiple_of(i * block_size, block_size)
+            act(pltpu.make_async_copy(
+                pool_ref.at[layer, 0, tables_ref[row, j * kv_blocks + i]],
+                buf.at[slot, pl.ds(at, block_size)], sem.at[slot, i]))
+            return 0
 
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
-    acc_ref[...] = jnp.zeros_like(acc_ref)
+        jax.lax.fori_loop(
+            0, jnp.minimum(blocks - j * kv_blocks, kv_blocks), one, 0)
 
-    @pl.when(n_steps > 0)
-    def _prologue():
-        for c in copies(0, 0):
-            c.start()
+    def start(row, blocks, j, slot):
+        each_copy(row, blocks, j, slot, lambda c: c.start())
 
-    def body(j, _):
-        slot = jax.lax.rem(j, 2)
+    @pl.when(t == 0)
+    def _first_cell():
+        buf[...] = jnp.zeros_like(buf)
+        slot_ref[0] = 0
 
-        @pl.when(j + 1 < n_steps)
-        def _prefetch():
-            for c in copies(j + 1, 1 - slot):
-                c.start()
+        @pl.when(nblk > 0)
+        def _cold():
+            start(t, nblk, 0, 0)
 
-        for c in copies(j, slot):
-            c.wait()
-        kv = buf[slot]                                    # (width, row)
+    slot0 = slot_ref[0]
+
+    def attend(j, slot, w):
+        """Trip ``j``'s first ``w`` blocks of buffer ``slot`` into the
+        running softmax."""
+        kv = buf[slot, :w * block_size]                   # (width, row)
         c_kv, k_rope = kv[:, :rank], kv[:, rank:rank + qr_ref.shape[-1]]
         dims = (((1,), (1,)), ((), ()))
         s = (jax.lax.dot_general(ql_ref[0], c_kv, dims,
                                  preferred_element_type=jnp.float32)
              + jax.lax.dot_general(qr_ref[0], k_rope, dims,
                                    preferred_element_type=jnp.float32)) * scale
-        kpos = j * width + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        kpos = j * (kv_blocks * block_size) + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
         s = jnp.where(kpos < lim_ref[0], s, NEG_INF)       # (Q, width)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -549,11 +589,52 @@ def _mla_kernel(layer_ref, tables_ref, nblk_ref, ql_ref, qr_ref, lim_ref,
             p.astype(kv.dtype), c_kv, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
+
+    def body(j, _):
+        slot = jax.lax.rem(slot0 + j, 2)
+
+        @pl.when(j + 1 < n_trips)
+        def _prefetch():
+            start(t, nblk, j + 1, 1 - slot)
+
+        @pl.when((j + 1 == n_trips) & (nblk_next > 0))
+        def _hand_over():
+            start(t + 1, nblk_next, 0, 1 - slot)
+
+        each_copy(t, nblk, j, slot, lambda c: c.wait())
+        if not half:
+            attend(j, slot, kv_blocks)
+            return 0
+        fits_half = nblk - j * kv_blocks <= half
+
+        @pl.when(fits_half)
+        def _narrow():
+            attend(j, slot, half)
+
+        @pl.when(jnp.logical_not(fits_half))
+        def _wide():
+            attend(j, slot, kv_blocks)
+
         return 0
 
-    jax.lax.fori_loop(0, n_steps, body, 0)
-    l = l_ref[...]
-    o_ref[0] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+    @pl.when(n_trips == 0)
+    def _dead():
+        @pl.when(nblk_next > 0)
+        def _hand_over():
+            start(t + 1, nblk_next, 0, slot0)
+
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when(n_trips > 0)
+    def _live():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        jax.lax.fori_loop(0, n_trips, body, 0)
+        # a live tile's row may see nothing (a padding row behind the
+        # chunk's last token): its l is the count of masked keys, not 0
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        slot_ref[0] = jax.lax.rem(slot0 + n_trips, 2)
 
 
 def mla_decode(q_lat, q_rope, pool, layer, tables, limits, *, scale,
@@ -570,22 +651,33 @@ def mla_decode(q_lat, q_rope, pool, layer, tables, limits, *, scale,
     tile's own tokens are in the pool already). ``q_tile`` 1 is decode (one
     row a sequence); a larger tile is a prefill chunk's segment, whose latent
     is streamed once a tile, not once a token. Returns the weighted latent
-    (N, nh, rank) in q_lat's dtype."""
+    (N, nh, rank) in q_lat's dtype.
+
+    A row with ``limits`` 0 is dead, and a tile all of whose rows are dead is
+    a dead cell: it fetches nothing and its output is zeros. A dead row
+    inside a live tile sees nothing and gets a finite value nobody reads. As
+    for :func:`paged_decode` the CALLER decides which rows are dead (the
+    model: a row whose table names no block); the kernel never reads deadness
+    out of the table, and a row with ``limits`` 1 and an all-zero table
+    attends to the trash block's first token. A live cell fetches the blocks
+    its largest ``limits`` reaches, :func:`mla_blocks_per_trip` a trip, and
+    never the table's padding."""
     N, nh, rank = q_lat.shape
     rope = q_rope.shape[-1]
     _, _, _, BS, row = pool.shape
     if row != sum(latent_row(rank, rope)):
         raise ValueError(f"pool row {row} is not rank {rank} + rope {rope}, "
                          "padded to 128 lanes")
-    tiles, Q, kv_blocks = N // q_tile, q_tile * nh, MLA_KV_BLOCKS
+    tiles, Q = N // q_tile, q_tile * nh
+    kv_blocks = mla_blocks_per_trip(Q, pool)
     pad = -tables.shape[1] % kv_blocks
-    if pad:           # a whole number of block groups; the padding is masked
+    if pad:           # a whole number of trips; the padding is never fetched
         tables = jnp.pad(tables, ((0, 0), (0, pad)))
     lim = limits.astype(jnp.int32)
-    steps = -(-jnp.max(lim.reshape(tiles, q_tile), axis=1) // (kv_blocks * BS))
+    nblk = -(-jnp.max(lim.reshape(tiles, q_tile), axis=1) // BS)
     lim_rows = jnp.repeat(lim, nh).reshape(tiles, Q, 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # layer, tables, steps
+        num_scalar_prefetch=3,  # layer, tables, each tile's blocks
         grid=(tiles,),
         in_specs=[
             pl.BlockSpec((1, Q, rank), lambda t, *_: (t, 0, 0)),
@@ -600,10 +692,12 @@ def mla_decode(q_lat, q_rope, pool, layer, tables, limits, *, scale,
             pltpu.VMEM((Q, 1), jnp.float32),      # m
             pltpu.VMEM((Q, 1), jnp.float32),      # l
             pltpu.VMEM((Q, rank), jnp.float32),   # acc
+            pltpu.SMEM((1,), jnp.int32),          # the next trip's slot
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_mla_kernel, block_size=BS, rank=rank, scale=scale),
+        functools.partial(_mla_kernel, block_size=BS, rank=rank, scale=scale,
+                          kv_blocks=kv_blocks),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tiles, Q, rank), q_lat.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -611,7 +705,8 @@ def mla_decode(q_lat, q_rope, pool, layer, tables, limits, *, scale,
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=_interpret(),
         name="mla_decode" if q_tile == 1 else "mla_decode_segment",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), tables, steps.astype(jnp.int32),
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables,
+      jnp.pad(nblk, (0, 1)),       # the cell behind the last has no blocks
       q_lat.reshape(tiles, Q, rank), q_rope.reshape(tiles, Q, rope), lim_rows,
       pool)
     return out.reshape(N, nh, rank)
