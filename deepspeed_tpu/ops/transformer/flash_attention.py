@@ -1,19 +1,39 @@
-"""Pallas flash attention for TPU (forward + backward).
+"""Pallas flash attention for TPU (forward + one fused backward).
 
 TPU-native replacement for the reference's fused attention CUDA kernels
 (``csrc/transformer/softmax_kernels.cu`` training softmax,
 ``csrc/transformer/inference/csrc/softmax.cu`` and the blocked flash kernels in
 ``deepspeed/inference/v2/kernels/ragged_ops/blocked_flash``). Flash-attention-2
-style: online softmax over KV blocks, logsumexp residuals, separate dq and dk/dv
-backward kernels. Designed for the MXU: all matmuls are (128×hd)·(hd×128)-shaped
-with fp32 accumulation; causal blocks beyond the diagonal are skipped by bounding
-the KV loop with the query block's position (dynamic fori_loop trip count).
+style: online softmax over the keys, a log-sum-exp residual, and ONE backward
+kernel that produces dk/dv for its KV block and accumulates dq for the whole
+sequence in a float32 VMEM scratch, written once in the model's dtype.
 
-Layout: kernels run on (B, heads, S, hd) so the trailing two block dims are the
-MXU-aligned (seq_block, head_dim); the public entry transposes from the model's
-(B, S, heads, hd). GQA is handled in the BlockSpec index maps (kv head =
-q head // groups) for forward/dq; dk/dv are produced per-q-head and group-summed
-by the caller.
+Layout: the kernels address q, k, v, o, ``do`` and write o, dq, dk, dv where
+the model keeps them, ``(B, S, heads * head_dim)`` (a free reshape of the
+``(B, S, heads, head_dim)`` the model hands ``attention()``): no transpose on
+either side of a kernel. A grid cell holds a whole lane tile of heads,
+``max(head_dim, 128)`` lanes wide: two heads of 64, one of 128, one of 256. A
+pair of 64 is walked by the innermost grid axis, which revisits the tile: the
+other head's lanes are zeroed in ONE operand of each product (the contraction
+then runs over 128 lanes at the MXU cost a padded 64 has) and a product that
+comes out 128 lanes wide keeps its own head's half with a select. GQA follows
+the same form: the K/V tile and half are those of ``(q head) // groups``, a q
+head whose K/V sits in the other half is lined up with one lane roll a cell,
+and dk/dv come out a q head and are summed over the group by the caller.
+
+Walk: a grid cell's block (up to ``BLOCK`` positions: a whole training
+sequence) is taken a chunk of queries (``FWD_CHUNK``; backward: of keys,
+``BWD_CHUNK``) at a time in straight code, because the chip overlaps one
+chunk's vector work with the next one's products there and not across the
+iterations of a loop. Under a causal mask a chunk meets only the keys up to
+its own (backward: the queries from its own on): what lies above the diagonal
+is never computed, and only the chunk-sized corner on the diagonal is masked.
+Blocks before (after) the own one, which exist when a sequence is longer than
+``BLOCK``, take a loop with no mask.
+``scale`` goes onto the q (backward: k) tile once where that is exact (a power
+of two: head 64 and 256) and onto the float32 scores otherwise. The backward
+works on transposed scores (keys, queries), so lse and delta are lane-dense
+rows, delta never leaves VMEM, and only dq contracts over the sublanes.
 
 Gives way to the XLA path in ``attention.py`` for bias, softcap and q_offset
 (cache decode) with ``UnsupportedFeature``, and refuses shapes it cannot tile
@@ -21,10 +41,12 @@ or fit with ``UnsupportedShape``, which ``attention()`` logs.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from jax.sharding import PartitionSpec as P
 
@@ -33,6 +55,15 @@ from ..pallas_utils import open_mesh_axes
 from .attention import UnsupportedFeature, UnsupportedShape, register_impl
 
 NEG_INF = -1e30
+LANES = 128
+BLOCK = 2048        # positions a grid cell: a whole training sequence
+FWD_CHUNK = 512     # queries of them a straight-line step of the forward
+BWD_CHUNK = 256     # keys of them a step of the backward (PERF.md 5)
+VMEM = 16 * 1024 * 1024   # what the compiler gives a kernel unasked
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T: contract the lanes of both
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b: contract the sublanes of both
 
 
 def _interpret() -> bool:
@@ -41,190 +72,339 @@ def _interpret() -> bool:
     return pallas_interpret()
 
 
+def _dot(a, b, dims):
+    # operands stay in their storage dtype (bf16): the MXU multiplies bf16 at
+    # full rate and accumulates in float32
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _exact(scale) -> bool:
+    """Is multiplying by ``scale`` exact in any float dtype (a power of two)?"""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _half(shape, hd, h):
+    """The lanes of head ``h`` of a tile's ``shape[-1] // hd`` heads."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return (lane >= h * hd) & (lane < (h + 1) * hd)
+
+
+def _other_half(x, hd):
+    """``x`` with the two heads of its 128 lanes swapped (Mosaic rotates
+    32-bit lanes only)."""
+    return pltpu.roll(x.astype(jnp.float32), hd, 1).astype(x.dtype)
+
+
+def _as_row(col):
+    """A (n, 1) float32 column as a lane-dense (1, n) row."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], LANES)))[:1]
+
+
+def _row(rows, h):
+    """Row ``h`` of the one or two (a row a head of the tile) of ``rows``."""
+    return rows if rows.shape[0] == 1 else jnp.where(h == 0, rows[:1], rows[1:])
+
+
+def _tile_width(heads, hd):
+    """Lanes a grid cell reads: a whole lane tile of heads, or the one head
+    there is."""
+    return max(hd, min(LANES, heads * hd))
+
+
+def _fits(block, sq, skv, width, item):
+    """Does a cell of ``block`` positions fit the VMEM the compiler gives a
+    kernel unasked? The kernels ask for no more: a ``vmem_limit_bytes`` of 64
+    MiB crashed XLA's memory-space repacker on the four-chip step (PERF.md 6).
+    Forward: the full-length K and V windows and the q and o blocks, double-
+    buffered, and a chunk's scores (float32 s and p, and p in a narrower
+    dtype); backward: the full-length q, o, do and dq windows and dq's float32
+    accumulator, the k, v, dk, dv blocks and their accumulators, and a chunk's
+    scores. The scores' factors and the 12 KiB a lane are fitted to what the
+    TPU compiler takes and refuses over head sizes, dtypes, sequences and
+    blocks (114 compiles at batch 4, PERF.md 7)."""
+    narrow = item if item < 4 else 0
+    fwd = ((4 * skv + 4 * block) * width * item
+           + min(FWD_CHUNK, block) * block * (8 + narrow))
+    bwd = (sq * width * (8 * item + 4) + block * width * (8 * item + 8)
+           + min(BWD_CHUNK, block) * block * 13 // 2)
+    return max(fwd, bwd) + 12 * 1024 * width <= VMEM
+
+
+def _kv_head(t, h, per_tile, g):
+    """(tile, half) of the K/V head that q head ``h`` of q tile ``t`` reads."""
+    kv = (t * per_tile + h) // g
+    return kv // per_tile, kv % per_tile
+
+
+def _causal(s, n, queries):
+    """Scores whose ``n`` x ``n`` corner starts on the causal diagonal (its
+    first query and first key are the same position), that corner masked
+    above the diagonal. ``queries`` is the dimension the queries run along:
+    0 with the corner at the last ``n`` keys (forward), 1 with it at the
+    first ``n`` queries (transposed scores, backward)."""
+    corner = s[:, -n:] if queries == 0 else s[:, :n]
+    at = functools.partial(jax.lax.broadcasted_iota, jnp.int32, corner.shape)
+    corner = jnp.where(at(queries) >= at(1 - queries), corner, NEG_INF)
+    if s.shape[1] == n:
+        return corner
+    return jnp.concatenate(
+        [s[:, :-n], corner] if queries == 0 else [corner, s[:, n:]], axis=1)
+
+
 # ----------------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k, causal, scale):
-    qi = pl.program_id(2)
-    # keep matmul inputs in their storage dtype (bf16): the MXU multiplies
-    # bf16 at full rate with fp32 accumulation; casting to fp32 first would
-    # run the MXU at a fraction of peak
-    q = q_ref[0, 0, :, :]  # (BQ, hd)
-    skv = k_ref.shape[2]
-    hd = q.shape[-1]
-
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, hd), jnp.float32)
-
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, hd, g, block_k, chunk,
+                causal, scale, inside):
+    """One head's attention for this q block, a chunk of queries at a time in
+    straight code, each with an online softmax of its own over the keys it
+    meets."""
+    t, qi, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    block_q, width = q_ref.shape[1], q_ref.shape[2]
+    per_tile = width // hd
+    skv = k_ref.shape[1]
     q_start = qi * block_q
-    if causal:
-        # only KV blocks whose start is <= the last query row
-        num_kv = jnp.minimum((q_start + block_q + block_k - 1) // block_k,
-                             skv // block_k)
-    else:
-        num_kv = skv // block_k
 
-    def body(j, carry):
+    q = q_ref[0]                                     # (BQ, width)
+    if per_tile > 1:
+        _, kh = _kv_head(t, h, per_tile, g)
+        if g > 1:   # this head's K/V may sit in the other half: line q up
+            q = jnp.where(kh != h, _other_half(q, hd), q)
+        q = jnp.where(_half(q.shape, hd, kh), q, jnp.zeros_like(q))
+    if _exact(scale):
+        q = q * scale
+
+    def step(qc, carry, start, size, diagonal=False):
+        """Online softmax of the chunk ``qc`` over the keys [start, start +
+        size); with ``diagonal`` the last ``chunk`` of them start at its
+        first query."""
         m, l, acc = carry
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # (BQ, BK) fp32
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        keys = pl.ds(pl.multiple_of(start, LANES), size)
+        k, v = k_ref[0, keys, :], v_ref[0, keys, :]
+        s = _dot(qc, k, _NT)                         # (chunk, size) float32
+        if not _exact(scale):
+            s = s * scale
+        if diagonal:
+            s = _causal(s, chunk, 0)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc_new
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + _dot(p.astype(v.dtype), v, _NN)
+        return m_new, l, acc
 
-    m, l, acc = jax.lax.fori_loop(0, num_kv, body, (m0, l0, acc0))
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0, :, :] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0, 0, :, 0] = m + jnp.log(l_safe)
+    lse = []
+    for c in range(0, block_q, chunk):
+        rows = slice(c, c + chunk)
+        carry = (jnp.full((chunk, 1), NEG_INF, jnp.float32),
+                 jnp.zeros((chunk, 1), jnp.float32),
+                 jnp.zeros((chunk, width), jnp.float32))
+        # causal (block_q == block_k): the key blocks before this q block,
+        # then its own keys up to the chunk's
+        before = jnp.minimum(q_start, skv) if causal else skv
+        carry = jax.lax.fori_loop(
+            0, before // block_k,
+            lambda j, x, rows=rows: step(q[rows], x, j * block_k, block_k),
+            carry)
+        if causal:
+            own = functools.partial(step, q[rows], start=q_start,
+                                    size=c + chunk, diagonal=True)
+            # with Sq > Skv a q block may lie past the last key
+            carry = own(carry) if inside else jax.lax.cond(
+                q_start < skv, own, lambda x: x, carry)
+        m, l, acc = carry
+        l = jnp.where(l == 0.0, 1.0, l)
+        out = acc / l
+        if per_tile > 1:
+            if g > 1:
+                out = jnp.where(kh != h, _other_half(out, hd), out)
+            # the other head's half: what its own visit wrote, or will overwrite
+            out = jnp.where(_half(out.shape, hd, h), out,
+                            o_ref[0, rows, :].astype(jnp.float32))
+        o_ref[0, rows, :] = out.astype(o_ref.dtype)
+        lse.append(m + jnp.log(l))
+    lse = _as_row(jnp.concatenate(lse, 0))
+    if per_tile > 1:   # the other head's row stays
+        head = jax.lax.broadcasted_iota(jnp.int32, (per_tile, block_q), 0)
+        lse = jnp.where(head == h, lse, lse_ref[0, 0])
+    lse_ref[0, 0] = lse
 
 
 def _fwd(q, k, v, *, causal, num_kv_groups, scale, block_q, block_k):
-    """q: (B, nh, Sq, hd); k/v: (B, kvh, Skv, hd) → out (B, nh, Sq, hd), lse (B, nh, Sq)."""
-    B, nh, Sq, hd = q.shape
-    Skv = k.shape[2]
-    grid = (B, nh, Sq // block_q)
+    """q: (B, Sq, nh, hd); k/v: (B, Skv, kvh, hd) → out like q, lse (B, nh, Sq)."""
+    B, Sq, nh, hd = q.shape
+    Skv, kvh = k.shape[1], k.shape[2]
+    width = _tile_width(nh, hd)
+    per_tile = width // hd
     g = num_kv_groups
 
+    def kv_tile(b, t, i, h):
+        return b, 0, _kv_head(t, h, per_tile, g)[0]
+
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale),
-        grid=grid,
+        functools.partial(_fwd_kernel, hd=hd, g=g, block_k=block_k,
+                          chunk=math.gcd(FWD_CHUNK, block_q), causal=causal,
+                          scale=scale, inside=Sq <= Skv),
+        grid=(B, nh // per_tile, Sq // block_q, per_tile),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, Skv, hd), lambda b, h, i: (b, h // g, 0, 0)),
-            pl.BlockSpec((1, 1, Skv, hd), lambda b, h, i: (b, h // g, 0, 0)),
+            pl.BlockSpec((1, block_q, width), lambda b, t, i, h: (b, i, t)),
+            pl.BlockSpec((1, Skv, width), kv_tile),
+            pl.BlockSpec((1, Skv, width), kv_tile),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, block_q, width), lambda b, t, i, h: (b, i, t)),
+            pl.BlockSpec((1, 1, per_tile, block_q),
+                         lambda b, t, i, h: (b, t, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((B, nh, Sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Sq, nh * hd), q.dtype),
+            jax.ShapeDtypeStruct((B, nh // per_tile, per_tile, Sq), jnp.float32),
         ],
         interpret=_interpret(),
         name="flash_fwd",
-    )(q, k, v)
-    return out, lse
+    )(q.reshape(B, Sq, nh * hd), k.reshape(B, Skv, kvh * hd),
+      v.reshape(B, Skv, kvh * hd))
+    return out.reshape(q.shape), lse.reshape(B, nh, Sq)
 
 
 # ----------------------------------------------------------------------------
 # backward
 # ----------------------------------------------------------------------------
 
-def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, *, block_q, block_k, causal, scale):
-    """One pass producing dk/dv for this KV block AND accumulating this
-    block's dq contributions. The QK^T, exp and do·v^T work is computed once
-    instead of once per backward kernel; dq is a REVISITED fp32 output (same
-    block for every ki — TPU grids run sequentially, so the accumulator
-    stays resident in VMEM across the kv sweep)."""
-    ki = pl.program_id(2)
-    k = k_ref[0, 0, :, :]  # (BK, hd) bf16: MXU inputs stay in storage dtype
-    v = v_ref[0, 0, :, :]
-    sq = q_ref.shape[2]
-    hd = k.shape[-1]
+def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, delta_acc, dk_acc, dv_acc, *,
+                hd, g, block_q, chunk, causal, scale, inside):
+    """dk/dv of this KV block and this block's share of dq, on transposed
+    scores (keys, queries): QK^T, exp and do.V^T are computed once for all
+    three. dq gathers in ``dq_acc`` over the KV sweep and the heads of the tile
+    (the TPU walks its grid in order) and is written with the last of them;
+    delta (the row sums of do * o) is made on a tile's first visit and never
+    leaves VMEM."""
+    t, ki, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    last = (ki == pl.num_programs(2) - 1) & (h == pl.num_programs(3) - 1)
+    block_k, width = k_ref.shape[1], k_ref.shape[2]
+    per_tile = width // hd
+    sq = q_ref.shape[1]
     k_start = ki * block_k
 
-    @pl.when(ki == 0)
-    def _zero_dq():
-        dq_ref[0, 0, :, :] = jnp.zeros((sq, hd), jnp.float32)
+    k, v = k_ref[0], v_ref[0]                        # (BK, width)
+    if per_tile > 1:
+        if g > 1:   # bring this head's K/V to the half its q, do, dq lie in
+            _, kh = _kv_head(t, h, per_tile, g)
+            k = jnp.where(kh != h, _other_half(k, hd), k)
+            v = jnp.where(kh != h, _other_half(v, hd), v)
+        mine = _half(k.shape, hd, h)
+        k = jnp.where(mine, k, jnp.zeros_like(k))
+        v = jnp.where(mine, v, jnp.zeros_like(v))
+    k_s = k * scale if _exact(scale) else k
 
-    # first q block that can see this kv block
-    start_q = (k_start // block_q) if causal else 0
-    num_q = sq // block_q
+    @pl.when((ki == 0) & (h == 0))
+    def _first_visit():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        for c in range(0, sq, block_q):
+            prod = (do_ref[0, c:c + block_q, :].astype(jnp.float32)
+                    * o_ref[0, c:c + block_q, :].astype(jnp.float32))
+            for head in range(per_tile):
+                own = prod if per_tile == 1 else jnp.where(
+                    _half(prod.shape, hd, head), prod, 0.0)
+                delta_acc[head:head + 1, c:c + block_q] = _as_row(
+                    jnp.sum(own, axis=-1, keepdims=True))
 
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        do = do_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q), 0]
-        delta = delta_ref[0, 0, pl.ds(i * block_q, block_q), 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale  # (BQ, BK)
-        if causal:
-            qpos = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dv_new = dv + jax.lax.dot_general(p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta[:, None]) * scale).astype(q.dtype)
-        dk_new = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
-        dq_blk = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-        sl = pl.ds(i * block_q, block_q)
-        dq_ref[0, 0, sl, :] = dq_ref[0, 0, sl, :] + dq_blk
-        return dk_new, dv_new
+    dk_acc[...] = jnp.zeros_like(dk_acc)
+    dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    init = (jnp.zeros((block_k, hd), jnp.float32), jnp.zeros((block_k, hd), jnp.float32))
-    dk, dv = jax.lax.fori_loop(start_q, num_q, body, init)
-    dk_ref[0, 0, :, :] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0, :, :] = dv.astype(dv_ref.dtype)
+    def step(keys, start, size, diagonal=False):
+        """The block's ``keys`` against the queries [start, start + size);
+        with ``diagonal`` the first ``chunk`` of them start at the first key."""
+        rows = pl.ds(pl.multiple_of(start, LANES), size)
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        lse = _row(lse_ref[0, 0, :, rows], h)        # (1, size)
+        delta = _row(delta_acc[:, rows], h)
+        st = _dot(k_s[keys], q, _NT)                 # (chunk, size) float32
+        if not _exact(scale):
+            st = st * scale
+        if diagonal:
+            st = _causal(st, chunk, 1)
+        pt = jnp.exp(st - lse)
+        dv_acc[keys] += _dot(pt.astype(do.dtype), do, _NN)
+        dpt = _dot(v[keys], do, _NT)
+        dst = (pt * (dpt - delta)).astype(q.dtype)   # scale: on dk, dq at the end
+        dk_acc[keys] += _dot(dst, q, _NN)
+        dq_acc[rows, :] += _dot(dst, k[keys], _TN)   # this head's lanes only
+
+    for c in range(0, block_k, chunk):
+        keys = slice(c, c + chunk)
+        if causal:   # block_q == block_k
+            # the queries at the KV block's own positions from the chunk's on,
+            # then the q blocks after it
+            own = functools.partial(step, keys, k_start + c, block_k - c, True)
+            if inside:
+                own()
+            else:   # with Skv > Sq a KV block may lie past the last query
+                pl.when(k_start < sq)(own)
+        jax.lax.fori_loop(
+            ki + 1 if causal else 0, sq // block_q,
+            lambda i, _, keys=keys: step(keys, i * block_q, block_q), None)
+
+    dk, dv = dk_acc[...] * scale, dv_acc[...]
+    if per_tile > 1:   # each product holds the pair's other head's lanes too
+        dk = jnp.where(mine, dk, dk_ref[0].astype(jnp.float32))
+        dv = jnp.where(mine, dv, dv_ref[0].astype(jnp.float32))
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+    @pl.when(last)
+    def _write_dq():
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _bwd(causal, num_kv_groups, scale, block_q, block_k, res, do):
-    q, k, v, out, lse = res  # (B, nh, Sq, hd) layout
-    B, nh, Sq, hd = q.shape
-    kvh, Skv = k.shape[1], k.shape[2]
+    q, k, v, out, lse = res  # (B, S, heads, hd) as the model holds them
+    B, Sq, nh, hd = q.shape
+    Skv, kvh = k.shape[1], k.shape[2]
+    width = _tile_width(nh, hd)
+    per_tile = width // hd
+    tiles = nh // per_tile
     g = num_kv_groups
 
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[..., None]  # (B,nh,Sq,1)
+    def kv_block(b, t, i, h):
+        return b, i, _kv_head(t, h, per_tile, g)[0]
 
-    # ONE fused kernel: dk/dv per kv block + dq accumulated into a revisited
-    # fp32 output across the kv sweep (sequential TPU grid) — halves the
-    # QK^T/exp/do·v^T recompute of the former split dq / dkv kernels
+    seq = pl.BlockSpec((1, Sq, width), lambda b, t, i, h: (b, 0, t))
+    row = pl.BlockSpec((1, 1, per_tile, Sq), lambda b, t, i, h: (b, t, 0, 0))
+    dkv = pl.BlockSpec((1, block_k, width), lambda b, t, i, h: (b, i, t))
+
     dq, dkh, dvh = pl.pallas_call(
-        functools.partial(_bwd_fused_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, scale=scale),
-        grid=(B, nh, Skv // block_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, Sq, hd), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i: (b, h // g, i, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i: (b, h // g, i, 0)),
-            pl.BlockSpec((1, 1, Sq, hd), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Sq, 1), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Sq, 1), lambda b, h, i: (b, h, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, Sq, hd), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i: (b, h, i, 0)),
-        ],
+        functools.partial(_bwd_kernel, hd=hd, g=g, block_q=block_q,
+                          chunk=math.gcd(BWD_CHUNK, block_k), causal=causal,
+                          scale=scale, inside=Skv <= Sq),
+        grid=(B, tiles, Skv // block_k, per_tile),
+        in_specs=[seq, pl.BlockSpec((1, block_k, width), kv_block),
+                  pl.BlockSpec((1, block_k, width), kv_block), seq, seq, row],
+        out_specs=[seq, dkv, dkv],
         out_shape=[
-            jax.ShapeDtypeStruct((B, nh, Sq, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B, nh, Skv, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, nh, Skv, hd), q.dtype),
+            jax.ShapeDtypeStruct((B, Sq, nh * hd), q.dtype),
+            jax.ShapeDtypeStruct((B, Skv, nh * hd), q.dtype),
+            jax.ShapeDtypeStruct((B, Skv, nh * hd), q.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((Sq, width), jnp.float32),
+                        pltpu.VMEM((per_tile, Sq), jnp.float32),
+                        pltpu.VMEM((block_k, width), jnp.float32),
+                        pltpu.VMEM((block_k, width), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd",
-    )(q, k, v, do, lse, delta)
-    dq = dq.astype(q.dtype)
+    )(q.reshape(B, Sq, nh * hd), k.reshape(B, Skv, kvh * hd),
+      v.reshape(B, Skv, kvh * hd), out.reshape(B, Sq, nh * hd),
+      do.reshape(B, Sq, nh * hd), lse.reshape(B, tiles, per_tile, Sq))
 
-    if g > 1:
-        dk = dkh.reshape(B, kvh, g, Skv, hd).astype(jnp.float32).sum(axis=2).astype(k.dtype)
-        dv = dvh.reshape(B, kvh, g, Skv, hd).astype(jnp.float32).sum(axis=2).astype(v.dtype)
-    else:
-        dk, dv = dkh.astype(k.dtype), dvh.astype(v.dtype)
-    return dq, dk, dv
+    def per_kv_head(d, like):
+        d = d.reshape(B, Skv, kvh, g, hd)
+        if g > 1:
+            d = d.astype(jnp.float32).sum(axis=3)
+        return d.reshape(like.shape).astype(like.dtype)
+
+    return dq.reshape(q.shape), per_kv_head(dkh, k), per_kv_head(dvh, v)
 
 
 # ----------------------------------------------------------------------------
@@ -251,71 +431,74 @@ def _flash_fwd(q, k, v, causal, num_kv_groups, scale, block_q, block_k):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, num_kv_groups, scale, block_q, block_k, res, do):
-    return _bwd(causal, num_kv_groups, scale, block_q, block_k, res, do)
-
-
-_flash.defvjp(_flash_fwd, _flash_bwd)
+_flash.defvjp(_flash_fwd, _bwd)
 
 
 @register_impl("pallas_flash")
 def flash_attention(q, k, v, *, causal=True, q_offset=0, num_kv_groups=1,
-                    softcap=0.0, bias=None, scale=None, block_q=512, block_k=512):
+                    softcap=0.0, bias=None, scale=None, block_q=BLOCK,
+                    block_k=BLOCK):
     """Flash attention entry (same (B,S,h,d) surface as ``attention.xla_attention``).
 
-    Default 512-blocks: measured 1.5× faster than 128-blocks on v5e (the MXU
-    starves below ~512×hd work per grid cell)."""
+    ``block_q`` / ``block_k`` are upper bounds: each shrinks until it divides
+    its sequence and the cell fits VMEM (``_fits``); under a causal mask both
+    kernels take the smaller of the two, so the diagonal crosses square
+    blocks."""
     if bias is not None or (softcap and softcap > 0.0) or (
             not isinstance(q_offset, int)) or q_offset != 0:
         # a TRACED q_offset (KV-cache decode under jit/vmap) must also fall
         # back — comparing it would raise TracerBoolConversionError
         raise UnsupportedFeature("flash kernel: bias/softcap/q_offset unsupported")
     B, Sq, nh, hd = q.shape
-    Skv = k.shape[1]
+    Skv, kvh = k.shape[1], k.shape[2]
 
-    def fit(block, n):
-        # largest power-of-two block <= requested that divides n (>= 128)
-        b = min(block, n)
-        while b >= 128 and n % b:
-            b //= 2
-        return b
-
-    block_q = fit(block_q, Sq)
-    block_k = fit(block_k, Skv)
-    if block_q < 128 or block_k < 128 or hd not in (64, 128, 256):
+    if hd not in (64, 128, 256):
         raise UnsupportedShape(
-            f"flash kernel needs sequence lengths that are multiples of 128 "
-            f"(got {Sq}, {Skv}) and head_dim in (64, 128, 256) (got {hd})")
-    # VMEM budget guard (long-context should use ring attention): the forward
-    # stages a full-length K/V window per grid cell; the fused backward
-    # additionally holds full-length q/do windows PLUS the revisited fp32 dq
-    # accumulator (Sq*hd*(2+2+4) bytes)
-    fwd_bytes = 2 * Skv * hd * k.dtype.itemsize
-    bwd_bytes = Sq * hd * 8 + 2 * 512 * hd * k.dtype.itemsize
-    if max(fwd_bytes, bwd_bytes) > 12 * 1024 * 1024:
-        raise UnsupportedShape(
-            f"flash kernel: a {max(fwd_bytes, bwd_bytes)}-byte K/V or q/dq "
-            "window exceeds the 12 MiB VMEM budget")
-    scale = scale if scale is not None else hd ** -0.5
-
-    def local(q, k, v):
-        qt = jnp.transpose(q, (0, 2, 1, 3))
-        kt = jnp.transpose(k, (0, 2, 1, 3))
-        vt = jnp.transpose(v, (0, 2, 1, 3))
-        out = _flash(qt, kt, vt, causal, num_kv_groups, scale, block_q, block_k)
-        return jnp.transpose(out, (0, 2, 1, 3))
-
+            f"flash kernel needs head_dim in (64, 128, 256) (got {hd})")
     mesh, axes = open_mesh_axes()
-    if not axes:
-        return local(q, k, v)
+
+    def over(names):
+        return tuple(a for a in names if a in axes) or None
 
     # under a mesh: every device runs the kernel on its own batch rows and
     # heads (the model's Ulysses layout: batch over the DP axes, heads over
     # seq x model); attention needs nothing from another device
-    def over(names):
-        return tuple(a for a in names if a in axes) or None
-
     spec = P(over(ZERO_AXES), None, over((SEQ_AXIS, MODEL_AXIS)), None)
+    sizes = (jax.sharding.get_abstract_mesh() if mesh is None else mesh).shape
+    ways = math.prod(sizes[a] for a in spec[2] or ())
+    width = _tile_width(nh // ways, hd)
+    if (nh // ways * hd) % width or (kvh // ways * hd) % width:
+        raise UnsupportedShape(
+            f"flash kernel reads whole lane tiles of heads: heads x head_dim "
+            f"must be a multiple of {width} on every device (got q "
+            f"{nh // ways} x {hd}, k/v {kvh // ways} x {hd})")
+
+    def fit(block, n):
+        # largest block <= requested that divides n and fits VMEM (a power of
+        # two from the second try on)
+        b = min(block, n)
+        while b >= 128 and (n % b or not _fits(b, Sq, Skv, width,
+                                               q.dtype.itemsize)):
+            b = 1 << (b - 1).bit_length() - 1
+        return b
+
+    if causal:   # one block size, so the diagonal crosses square blocks
+        block_q = block_k = fit(min(block_q, block_k), math.gcd(Sq, Skv))
+    else:
+        block_q, block_k = fit(block_q, Sq), fit(block_k, Skv)
+    if block_q < 128 or block_k < 128:
+        raise UnsupportedShape(
+            f"flash kernel needs sequence lengths that are multiples of 128 "
+            f"(got {Sq}, {Skv}) whose full-length windows (K and V; q, o, do "
+            f"and dq) fit {VMEM} bytes of VMEM beside a block of 128: long "
+            f"contexts take ring attention")
+    scale = scale if scale is not None else hd ** -0.5
+
+    def local(q, k, v):
+        return _flash(q, k, v, causal, num_kv_groups, scale, block_q, block_k)
+
+    if not axes:
+        return local(q, k, v)
     return jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, axis_names=frozenset(axes),
                          check_vma=False)(q, k, v)

@@ -81,12 +81,50 @@ _CORE = (
 )
 
 
+# The files that take longest (100 s and more each of the tier-1 command's
+# ~5,500 CPU-seconds, measured under 6 workers at PR 47), longest first.
+# `--dist loadfile` hands a worker its next file in collection order, so in
+# alphabetical order test_zero_sharded.py (300 s) or test_train_resilience.py
+# (180 s) can start when the others are nearly done, and five workers wait
+# for one: these start first and the short files fill in behind them. Order
+# within a file is untouched.
+_LONGEST_FIRST = (
+    "tests/benchmark/test_cells.py",
+    "tests/unit/test_zero_sharded.py",
+    "tests/unit/test_paged_kernel.py",
+    "tests/unit/test_pipelined_dispatch.py",
+    "tests/unit/test_train_resilience.py",
+    "tests/unit/test_aux.py",
+    "tests/benchmark/test_deepseek_v3.py",
+    "tests/unit/test_disagg.py",
+    "tests/unit/test_speculation.py",
+    "tests/unit/test_chip_smoke.py",
+    "tests/unit/test_served_weight_reads.py",
+    "tests/benchmark/test_longcat_flash.py",
+    "tests/unit/test_pool.py",
+    "tests/benchmark/test_minicpm_sala.py",
+    "tests/unit/test_extras.py",
+    "tests/unit/test_flash_layout.py",
+    "tests/unit/test_pipe.py",
+    "tests/unit/test_minicpm_sala.py",
+)
+
+
+def _file_rank(item):
+    path = item.nodeid.split("::", 1)[0]
+    for rank, name in enumerate(_LONGEST_FIRST):
+        if path.endswith(name):
+            return rank
+    return len(_LONGEST_FIRST)
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         if any(pat in item.nodeid for pat in _SMOKE):
             item.add_marker(pytest.mark.smoke)
         if any(pat in item.nodeid for pat in _CORE):
             item.add_marker(pytest.mark.core)
+    items.sort(key=_file_rank)      # stable: the rest keep their order
 
 
 # Serving/inference test modules run under the runtime sanitizer

@@ -107,6 +107,67 @@ def test_flash_attention_compiles(one_chip, no_compile_cache, as_tpu, via,
         "flash attention gave way to the XLA path"
 
 
+#: a ``copy`` or ``transpose`` of the compiled program (at the top level or
+#: inside a fusion) and its result's dimensions
+LAYOUT_MOVE = re.compile(r"= \w+\[([\d,]+)\]\S* (?:copy|transpose)\(")
+
+
+@pytest.mark.parametrize("shape", [(B, S, NH, HD), (4, 2048, 16, 128)],
+                         ids=["gpt2-medium", "pythia-1.4b"])
+def test_flash_moves_no_head_layout(one_chip, no_compile_cache, as_tpu, shape):
+    """The kernels address attention where the model keeps it: q, k, v arrive
+    as ``(B, S, heads * head_dim)`` (a product's output), ``attention()`` gets
+    the free 4-D view of them, and forward and backward compile to the two
+    custom calls with no ``copy`` or ``transpose`` of an array the size of q
+    anywhere in the program (the parent transposed q, k, v, o, do and dq, dk,
+    dv to ``(B, heads, S, head_dim)`` and back: twelve a layer)."""
+    from deepspeed_tpu.ops.transformer.attention import attention
+
+    b, s, nh, hd = shape
+
+    def loss(q, k, v):
+        out = attention(*(x.reshape(shape) for x in (q, k, v)), causal=True)
+        return jnp.sum(out.reshape(b, s, nh * hd).astype(jnp.float32) ** 2)
+
+    x = aval(one_chip, (b, s, nh * hd), jnp.bfloat16)
+    text = compile_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    moved = [dims for dims in LAYOUT_MOVE.findall(text)
+             if math.prod(map(int, dims.split(","))) == math.prod(shape)]
+    assert not moved, moved
+
+
+def test_a_gpt2_layer_holds_no_head_layout_copy(one_chip, no_compile_cache,
+                                                as_tpu):
+    """One layer of the ``gpt2-medium.train-seq1024`` job (q/k/v biases, remat
+    ``dots``, layers unrolled, as the cell's files say) under
+    ``value_and_grad``: the compiled step holds no ``copy bf16[8,1024,16,64]``.
+    The parent held twelve a layer: q, k, v into the kernel's
+    ``(B, heads, S, head_dim)`` and the result back, the same again under
+    remat, ``do`` in and dq, dk, dv back."""
+    from deepspeed_tpu.models import TransformerLM, gpt2_config
+
+    model = TransformerLM(gpt2_config(
+        "350m", num_layers=1, qkv_bias=True, remat=True, remat_policy="dots",
+        scan_layers=False))
+    params = jax.tree.map(
+        lambda a: aval(one_chip, a.shape, jnp.bfloat16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+
+    def loss(params, batch):
+        out = model.apply(params, batch, train=True)
+        return out[0] if isinstance(out, tuple) else out
+
+    text = compile_text(
+        jax.value_and_grad(loss), params,
+        {"input_ids": aval(one_chip, (B, S), jnp.int32)})
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    per_head = f"= bf16[{B},{S},{NH},{HD}]"
+    copies = [line.strip()[:120] for line in text.splitlines()
+              if per_head in line and " copy(" in line]
+    assert not copies, copies
+
+
 @pytest.mark.parametrize("rows", ROWS)
 def test_paged_decode_compiles(one_chip, no_compile_cache, as_tpu, rows):
     from deepspeed_tpu.ops.transformer.paged_attention import \

@@ -285,8 +285,8 @@ class TestRandomLTD:
         batch = {"input_ids": ids, "ltd_keep": 16}
         loss = m.apply(p, batch, train=True, rng=jax.random.PRNGKey(1))
         assert jnp.isfinite(loss)
-        g = jax.grad(lambda pp: m.apply(pp, batch, train=True,
-                                        rng=jax.random.PRNGKey(1)))(p)
+        g = jax.jit(jax.grad(lambda pp: m.apply(
+            pp, batch, train=True, rng=jax.random.PRNGKey(1))))(p)
         assert all(bool(jnp.all(jnp.isfinite(x))) for x in jax.tree.leaves(g))
         # full-keep is exactly the plain trunk
         full = m.apply(p, {"input_ids": ids, "ltd_keep": 32}, train=True, rng=None)

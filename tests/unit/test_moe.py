@@ -54,7 +54,7 @@ class TestMoELayer:
             y, aux = layer.apply(p, x)
             return jnp.sum(y ** 2) + 0.01 * aux
 
-        g = jax.grad(loss)(p)
+        g = jax.jit(jax.grad(loss))(p)
         assert all(bool(jnp.all(jnp.isfinite(v))) for v in jax.tree.leaves(g))
         # router must receive gradient through the combine weights
         assert float(jnp.max(jnp.abs(g["wg"]))) > 0

@@ -67,7 +67,7 @@ class TestResidualMoELayer:
             y, aux = res.apply(p, x)
             return jnp.sum(y ** 2) + 0.01 * aux
 
-        g = jax.grad(loss)(p)
+        g = jax.jit(jax.grad(loss))(p)
         for k in ("mlp_wi", "mlp_wo", "coef_w", "coef_b", "wg", "wi", "wo"):
             assert float(jnp.max(jnp.abs(g[k]))) > 0, f"no grad into {k}"
 

@@ -49,10 +49,12 @@ class TestSpmdPipeline:
         plm.num_micro = 4
         pp = plm.init_params(jax.random.PRNGKey(0))
         ld = float(base.apply(p_dense, {"input_ids": ids}))
-        lp = float(plm.apply(pp, {"input_ids": ids}))
+        # jitted: eagerly, every operation of the pipelined program is
+        # compiled and dispatched over the eight devices by itself
+        lp = float(jax.jit(plm.apply)(pp, {"input_ids": ids}))
         assert abs(ld - lp) < 1e-4
         gd = jax.grad(lambda p: base.apply(p, {"input_ids": ids}))(p_dense)
-        gp = jax.grad(lambda p: plm.apply(p, {"input_ids": ids}))(pp)
+        gp = jax.jit(jax.grad(lambda p: plm.apply(p, {"input_ids": ids})))(pp)
         a, b = np.asarray(gd["wte"]), np.asarray(gp["wte"])
         assert np.max(np.abs(a - b)) / (np.max(np.abs(a)) + 1e-9) < 1e-4
 
@@ -65,7 +67,7 @@ class TestSpmdPipeline:
             plm = PipelinedLM(base, topology=pipe_mesh)
             plm.num_micro = M
             pp = plm.init_params(jax.random.PRNGKey(0))
-            losses.append(float(plm.apply(pp, {"input_ids": ids})))
+            losses.append(float(jax.jit(plm.apply)(pp, {"input_ids": ids})))
         assert abs(losses[0] - losses[1]) < 1e-4
 
 
@@ -167,7 +169,7 @@ class TestHeterogeneousPipeline:
         lp = float(mod.apply(params, (ids, labels)))
         assert abs(ld - lp) < 1e-5
         gd = jax.grad(dense)(params)
-        gp = jax.grad(lambda p: mod.apply(p, (ids, labels)))(params)
+        gp = jax.jit(jax.grad(lambda p: mod.apply(p, (ids, labels))))(params)
         for a, b in zip(jax.tree.leaves(gd), jax.tree.leaves(gp)):
             scale = np.abs(np.asarray(a)).max() + 1e-9
             np.testing.assert_allclose(np.asarray(b), np.asarray(a),
@@ -342,7 +344,7 @@ class TestStageShardedHeterogeneous:
         lp_ = float(mod.apply(params, (ids, labels)))
         assert abs(ld - lp_) < 1e-5
         gd = jax.grad(dense)(params)
-        gp = jax.grad(lambda p: mod.apply(p, (ids, labels)))(params)
+        gp = jax.jit(jax.grad(lambda p: mod.apply(p, (ids, labels))))(params)
         for a, b in zip(jax.tree.leaves(gd), jax.tree.leaves(gp)):
             scale = np.abs(np.asarray(a)).max() + 1e-9
             np.testing.assert_allclose(np.asarray(b), np.asarray(a),

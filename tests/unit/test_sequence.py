@@ -47,8 +47,8 @@ class TestUlysses:
         def loss_r(q, k, v):
             return jnp.sum(xla_attention(q, k, v, causal=True) ** 2)
 
-        gd = jax.grad(loss_d, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
+        gd = jax.jit(jax.grad(loss_d, argnums=(0, 1, 2)))(q, k, v)
+        gr = jax.jit(jax.grad(loss_r, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(gd, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3)
 
@@ -69,10 +69,12 @@ class TestRingAttention:
 
     def test_backward_matches(self, seq_mesh):
         q, k, v = _qkv()
-        gr = jax.grad(lambda *a: jnp.sum(xla_attention(*a, causal=True) ** 2),
-                      argnums=(0, 1, 2))(q, k, v)
-        gf = jax.grad(lambda *a: jnp.sum(ring_attention(*a, causal=True) ** 2),
-                      argnums=(0, 1, 2))(q, k, v)
+        gr = jax.jit(jax.grad(
+            lambda *a: jnp.sum(xla_attention(*a, causal=True) ** 2),
+            argnums=(0, 1, 2)))(q, k, v)
+        gf = jax.jit(jax.grad(
+            lambda *a: jnp.sum(ring_attention(*a, causal=True) ** 2),
+            argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(gr, gf):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3)
 
