@@ -32,13 +32,15 @@ programs (COW, tier demote/promote, swap) use :func:`get_block` /
 
 The kernel is decode only (one query token per row; a prefill chunk is rows of
 one token each); segments longer than one token keep the gather path. A cell
-of its grid is one row of the step over all its kv heads
-(:func:`heads_per_cell`: fewer only where the pool's blocks are too large to
-buffer), so cells number the rows, and a cell works as long as its row's
+of its grid is several rows of the step (:func:`rows_per_cell`, from the row
+count alone) over all their kv heads (:func:`heads_per_cell`: fewer only where
+the pool's blocks are too large to buffer), and a row works as long as its
 ``lens`` says. A step's rows are padded to a fixed count; WHO marks a row dead
 is the caller: the model gives ``lens`` 0 to a row whose table names no block
-(block 0 is the trash block), and such a cell fetches nothing and writes zeros.
-The kernel does not look at the table to decide it.
+(block 0 is the trash block), and such a row fetches nothing, keeps the zeros
+its cell's output starts as and costs the cell one step of a scalar loop, not
+the grid a step. The kernel does not look at the table to decide it. A cell's live rows hand the double buffer on: the next
+live row's first blocks arrive while the row before it computes its last.
 """
 
 import functools
@@ -204,6 +206,22 @@ def blocks_per_trip(pool) -> int:
     return max(1, min(DECODE_TRIP_BLOCKS, DECODE_TRIP_BYTES // block))
 
 
+#: rows of a step one cell of the decode kernel covers at most (on the chip,
+#: ms a round of 24 layers at 4 | 8 | 16 | 32 rows a cell, PERF.md 5: one live
+#: row of 64 0.174 | 0.167 | 0.162 | 0.160, 256 live rows 21.2 | 20.8 | 20.5 |
+#: 20.4, the parent's row a cell 0.277 and 24.0)
+DECODE_CELL_ROWS = 32
+
+
+def rows_per_cell(rows: int) -> int:
+    """Rows of the step one cell of :func:`paged_decode` covers, from the
+    step's row count alone: its largest divisor that is at most
+    :data:`DECODE_CELL_ROWS` (32 of a round of 64, of a mixed step's 256 and
+    of the sparse view's 96; a count with no such divisor but one keeps a
+    row a cell)."""
+    return max(d for d in range(1, DECODE_CELL_ROWS + 1) if rows % d == 0)
+
+
 def kernels_wanted() -> bool:
     """Do the paged programs take the Pallas kernels here? On a TPU, or
     forced (``DSTPU_FORCE_PAGED_KERNEL=1``: tests, interpreted on the CPU),
@@ -345,14 +363,25 @@ def kv_write(pool, layer, blk, off, kv):
 
 
 def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
-                   buf, sem, *, block_size, scale, bpt):
-    """Grid (B, kvh / hpc): ONE cell per sequence and group of ``hpc`` kv
-    heads (all of them wherever the buffer fits: :func:`heads_per_cell`). The
-    cell streams this sequence's ACTIVE pool blocks from HBM with
-    double-buffered DMA (prefetch trip j+1 while computing trip j), ``bpt``
-    blocks a trip (:func:`blocks_per_trip`), and computes every head of the
-    group from each: cells number the rows, not rows x heads x table slots,
-    and a cell's work follows its row's real length.
+                   buf, sem, live_ref, *, block_size, scale, bpt):
+    """Grid (B / rpc, kvh / hpc): ONE cell per ``rpc`` rows of the step
+    (:func:`rows_per_cell`) and group of ``hpc`` kv heads (all of them
+    wherever the buffer fits: :func:`heads_per_cell`). The cell first sorts
+    its rows by what ``lens`` says in a loop of scalar steps (the live rows'
+    numbers go to ``live_ref`` in order), starts the first live row's first
+    fetch, and zeroes its output block while that is on its way: a row with
+    ``lens[b] == 0`` is dead, keeps those zeros and costs its compare and
+    nothing else. Then the cell walks its live rows. A row streams
+    its ACTIVE pool blocks from HBM with double-buffered DMA (prefetch trip
+    j+1 while computing trip j), ``bpt`` blocks a trip
+    (:func:`blocks_per_trip`), and computes every head of the group from
+    each: a row's work follows its real length, and what it computes does not
+    depend on its neighbours.
+
+    The double buffer is handed from row to row: while a row computes its
+    last trip the first trip of the cell's next live row is fetched into the
+    slot that is free, so only the first live row of a cell waits for a fetch
+    with nothing to hide it.
 
     The pool is the whole stacked pool as it lies in HBM (see
     :func:`init_pool`): one DMA fetches ``pool[layer, h0:h0+hpc, tables[b, j]]``,
@@ -362,64 +391,91 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
     HBM DMA slice. A trip's blocks lie one behind the other along the
     buffer's token axis; where the row's blocks end inside a trip, its last
     block is fetched again in their place (finite values, masked like the
-    tokens past ``lens`` in any last block).
-
-    What ``lens`` says is what the cell does: ``lens[b] == 0`` starts no DMA,
-    runs no trip of the loop and writes zeros."""
-    b = pl.program_id(0)
+    tokens past ``lens`` in any last block)."""
+    rpc, hpc, g, hd = q_ref.shape
+    row0 = pl.program_id(0) * rpc
     layer = layer_ref[0]
-    seq_len = lens_ref[b]
-    nblk = (seq_len + block_size - 1) // block_size
-    ntrip = (nblk + bpt - 1) // bpt
-    _, hpc, g, hd = q_ref.shape
     heads = pl.ds(pl.program_id(1) * hpc, hpc)
-    q = q_ref[0].astype(jnp.float32) * scale  # (hpc, g, hd)
 
-    def copies(j, slot):
+    def blocks(b):
+        return (lens_ref[b] + block_size - 1) // block_size
+
+    def sort_row(r, n_live):
+        # a dead row's number is overwritten by the next live row's
+        live_ref[n_live] = r
+        return n_live + (lens_ref[row0 + r] > 0).astype(jnp.int32)
+
+    n_live = jax.lax.fori_loop(0, rpc, sort_row, 0)
+
+    def copies(r, j, slot):
+        """Trip ``j`` of the cell's row ``r`` into buffer ``slot``."""
+        b = row0 + r
+        last = blocks(b) - 1
         return [pltpu.make_async_copy(
             pool_ref.at[layer, heads,
-                        tables_ref[b, jnp.minimum(j * bpt + i, nblk - 1)
+                        tables_ref[b, jnp.minimum(j * bpt + i, last)
                                    if bpt > 1 else j]],
             buf.at[slot, :, pl.ds(i * block_size, block_size)],
             sem.at[slot, i]) for i in range(bpt)]
 
-    @pl.when(ntrip > 0)
-    def _prologue():
-        for c in copies(0, 0):
+    @pl.when(n_live > 0)
+    def _cold():
+        for c in copies(live_ref[0], 0, 0):
             c.start()
 
-    def body(j, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(j, 2)
+    # every row's output starts as zeros, which is what a dead row keeps
+    # (stored while the first fetch is on its way)
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-        @pl.when(j + 1 < ntrip)
-        def _prefetch():
-            for c in copies(j + 1, 1 - slot):
-                c.start()
+    def attend(k, slot0):
+        """The cell's ``k``-th live row, whose first trip is in flight into
+        buffer ``slot0``; returns the slot of the next row's first trip."""
+        r = live_ref[k]
+        seq_len = lens_ref[row0 + r]
+        ntrip = (blocks(row0 + r) + bpt - 1) // bpt
+        r_next = live_ref[jnp.minimum(k + 1, rpc - 1)]
+        q = q_ref[r].astype(jnp.float32) * scale  # (hpc, g, hd)
 
-        for c in copies(j, slot):
-            c.wait()
-        kv = buf[slot].astype(jnp.float32)  # (hpc, bpt*BS, 2*hd)
-        s = jax.lax.dot_general(          # every head of the group: (hpc, g, T)
-            q, kv[..., :hd], (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        kpos = j * (bpt * block_size) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 2)
-        s = jnp.where(kpos < seq_len, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p, kv[..., hd:], (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        def body(j, carry):
+            m, l, acc = carry
+            slot = jax.lax.rem(slot0 + j, 2)
 
-    m0 = jnp.full((hpc, g, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((hpc, g, 1), jnp.float32)
-    acc0 = jnp.zeros((hpc, g, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, ntrip, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+            @pl.when(j + 1 < ntrip)
+            def _prefetch():
+                for c in copies(r, j + 1, 1 - slot):
+                    c.start()
+
+            @pl.when((j + 1 == ntrip) & (k + 1 < n_live))
+            def _hand_over():
+                for c in copies(r_next, 0, 1 - slot):
+                    c.start()
+
+            for c in copies(r, j, slot):
+                c.wait()
+            kv = buf[slot].astype(jnp.float32)  # (hpc, bpt*BS, 2*hd)
+            s = jax.lax.dot_general(          # every head of the group: (hpc, g, T)
+                q, kv[..., :hd], (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            kpos = j * (bpt * block_size) + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 2)
+            s = jnp.where(kpos < seq_len, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+            acc_new = acc * alpha + jax.lax.dot_general(
+                p, kv[..., hd:], (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            return m_new, l_new, acc_new
+
+        m0 = jnp.full((hpc, g, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((hpc, g, 1), jnp.float32)
+        acc0 = jnp.zeros((hpc, g, hd), jnp.float32)
+        _, l, acc = jax.lax.fori_loop(0, ntrip, body, (m0, l0, acc0))
+        o_ref[r] = (acc / l).astype(o_ref.dtype)  # a live row sees a key
+        return jax.lax.rem(slot0 + ntrip, 2)
+
+    jax.lax.fori_loop(0, n_live, attend, 0)
 
 
 def paged_decode(q, pool, layer, tables, lens, *, scale=None):
@@ -433,25 +489,32 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None):
     lens: (B,) int32 valid token counts (position + 1). Returns (B, nh, hd)
     in q's dtype.
 
-    A row with ``lens`` 0 is dead: its cell fetches nothing and its output is
-    zeros. The CALLER decides which rows are dead (the model: a row whose
-    table names no block, since block 0 is the trash block no sequence
-    holds); the kernel never reads deadness out of the table, and a row with
-    ``lens`` 1 and an all-zero table attends to the trash block's first
-    token."""
+    The grid is ``(B / rpc, kvh / hpc)``: a cell takes ``rpc`` rows
+    (:func:`rows_per_cell`, from ``B`` alone) over ``hpc`` kv heads.
+
+    A row with ``lens`` 0 is dead: it fetches nothing, its output is zeros,
+    and it costs its cell one step of a scalar loop, not the grid a step. The
+    CALLER decides
+    which rows are dead (the model: a row whose table names no block, since
+    block 0 is the trash block no sequence holds); the kernel never reads
+    deadness out of the table, and a row with ``lens`` 1 and an all-zero
+    table attends to the trash block's first token. A live row's output is
+    bit for bit what the row gives alone, whatever shares its cell."""
     B, nh, hd = q.shape
     _, kvh, _, BS, _ = pool.shape
     g, hpc, bpt = nh // kvh, heads_per_cell(pool), blocks_per_trip(pool)
-    block = pl.BlockSpec((1, hpc, g, hd), lambda b, c, *_: (b, c, 0, 0))
+    rpc = rows_per_cell(B)
+    block = pl.BlockSpec((rpc, hpc, g, hd), lambda b, c, *_: (b, c, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, tables, lens
-        grid=(B, kvh // hpc),
+        grid=(B // rpc, kvh // hpc),
         in_specs=[block,
                   pl.BlockSpec(memory_space=pl.ANY)],  # the pool stays in HBM
         out_specs=block,
         scratch_shapes=[
             pltpu.VMEM((2, hpc, bpt * BS, pool.shape[4]), pool.dtype),
             pltpu.SemaphoreType.DMA((2, bpt)),     # the double buffer's
+            pltpu.SMEM((rpc,), jnp.int32),         # the cell's live rows
         ],
     )
     out = pl.pallas_call(

@@ -168,16 +168,28 @@ def test_a_gpt2_layer_holds_no_head_layout_copy(one_chip, no_compile_cache,
     assert not copies, copies
 
 
-@pytest.mark.parametrize("rows", ROWS)
-def test_paged_decode_compiles(one_chip, no_compile_cache, as_tpu, rows):
-    from deepspeed_tpu.ops.transformer.paged_attention import \
-        paged_decode_attention
+# (rows, query heads, kv heads, pool blocks, head size, table width, kv heads
+# a cell, blocks a trip): the chat server's decode round and mixed step, and
+# serve-doc16k's sparse view (a (row, kv head) pair a row of the pool with its
+# kv heads folded into its blocks, 64 chosen 32 KB blocks a row)
+PAGED_CALLS = [(rows, NH, NH, NB, HD, MAXB, NH, 1) for rows in ROWS] + [
+    (96, 16, 1, 27136, 128, 64, 1, 8)]
 
-    pool = aval(one_chip, (NH, NB, BS, HD), jnp.bfloat16)
+
+@pytest.mark.parametrize("rows,nh,kvh,nb,hd,maxb,hpc,bpt", PAGED_CALLS)
+def test_paged_decode_compiles(one_chip, no_compile_cache, as_tpu, rows, nh,
+                               kvh, nb, hd, maxb, hpc, bpt):
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    pool = aval(one_chip, (kvh, nb, BS, hd), jnp.bfloat16)
+    stacked = jax.ShapeDtypeStruct((1, kvh, nb, BS, 2 * hd), jnp.bfloat16)
+    assert (pa.heads_per_cell(stacked), pa.blocks_per_trip(stacked)) == (
+        hpc, bpt)
+    assert pa.rows_per_cell(rows) == min(rows, 32)
     text = compile_text(
-        paged_decode_attention,
-        aval(one_chip, (rows, NH, HD), jnp.bfloat16), pool, pool,
-        aval(one_chip, (rows, MAXB), jnp.int32),
+        pa.paged_decode_attention,
+        aval(one_chip, (rows, nh, hd), jnp.bfloat16), pool, pool,
+        aval(one_chip, (rows, maxb), jnp.int32),
         aval(one_chip, (rows,), jnp.int32))
     assert "tpu_custom_call" in text
 

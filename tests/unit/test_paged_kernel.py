@@ -3,6 +3,8 @@
 numerics). Interpret mode on the CPU mesh; the identical code path lowers via
 Mosaic on TPU (validated on-chip)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -209,10 +211,10 @@ LENS = {"mixed": lambda BS, MAXB: [1, BS, BS + 1, MAXB * BS],
         "full": lambda BS, MAXB: [MAXB * BS, MAXB * BS - 1, 2 * BS, BS - 1]}
 
 
-def _case(kvh, nh, hd, BS, lens, L=2, MAXB=3, dead=0, seed=3):
+def _case(kvh, nh, hd, BS, lens, L=2, MAXB=3, dead=0, seed=3, at=None):
     """A stacked float32 pool of random rows, the live rows' tables in a
     scrambled order, ``dead`` rows of ``lens`` 0 and an all-zero table
-    shuffled among them."""
+    shuffled among them (or the live rows at the row numbers ``at``)."""
     from deepspeed_tpu.ops.transformer import paged_attention as pa
 
     rng = np.random.default_rng(seed)
@@ -222,7 +224,7 @@ def _case(kvh, nh, hd, BS, lens, L=2, MAXB=3, dead=0, seed=3):
     pool = jax.random.normal(
         ks[0], pa.init_pool(L, kvh, NB, BS, hd, jnp.float32).shape)
     q = jax.random.normal(ks[1], (B, nh, hd))
-    live = np.sort(rng.permutation(B)[:len(lens)])
+    live = np.sort(rng.permutation(B)[:len(lens)] if at is None else at)
     row_lens = np.zeros(B, np.int32)
     row_lens[live] = lens
     tables = np.zeros((B, MAXB), np.int32)
@@ -233,6 +235,16 @@ def _case(kvh, nh, hd, BS, lens, L=2, MAXB=3, dead=0, seed=3):
     return pa, pool, q, jnp.asarray(tables), jnp.asarray(row_lens), live
 
 
+@functools.lru_cache(maxsize=None)
+def _jitted(name, *static):
+    """``paged_attention``'s function ``name`` under one ``jax.jit``: cases
+    of one shape share a compile (an eager call of an interpreted kernel
+    compiles it anew every time)."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    return jax.jit(getattr(pa, name), static_argnames=static)
+
+
 @pytest.mark.parametrize("lens", sorted(LENS))
 @pytest.mark.parametrize("kvh,nh,hd,BS,hpc", SHAPES)
 def test_folded_kernel_matches_gather_plus_plain_attention(kvh, nh, hd, BS,
@@ -240,7 +252,7 @@ def test_folded_kernel_matches_gather_plus_plain_attention(kvh, nh, hd, BS,
     pa, pool, q, tables, row_lens, _ = _case(kvh, nh, hd, BS,
                                              LENS[lens](BS, 3))
     assert pa.heads_per_cell(pool) == hpc
-    out = pa.paged_decode(q, pool, jnp.int32(1), tables, row_lens)
+    out = _jitted("paged_decode")(q, pool, jnp.int32(1), tables, row_lens)
     ref = plain_attention(q, pool, jnp.int32(1), tables, row_lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
@@ -259,23 +271,82 @@ def test_heads_per_cell_follows_the_pools_shape(dtype, hd, BS, hpc):
     assert pa.heads_per_cell(pool) == hpc
 
 
-@pytest.mark.parametrize("kvh,nh,hd,BS,hpc", SHAPES[:2] + SHAPES[4:])
-def test_dead_rows_cost_nothing_and_change_nothing(kvh, nh, hd, BS, hpc):
-    """Most rows dead (``lens`` 0, an all-zero table): their outputs are
-    zeros, and the live rows' outputs are bit for bit those of the batch
-    without the dead rows."""
-    pa, pool, q, tables, row_lens, live = _case(
-        kvh, nh, hd, BS, LENS["mixed"](BS, 3)[:3], dead=13)
-    assert (np.asarray(row_lens) == 0).sum() == 13
-    out = np.asarray(pa.paged_decode(q, pool, jnp.int32(1), tables, row_lens))
-    dead = np.setdiff1d(np.arange(len(out)), live)
-    assert not out[dead].any()
-    alone = pa.paged_decode(q[live], pool, jnp.int32(1), tables[live],
-                            row_lens[live])
-    np.testing.assert_array_equal(out[live], np.asarray(alone))
-    np.testing.assert_allclose(
-        out, np.asarray(plain_attention(q, pool, jnp.int32(1), tables,
-                                        row_lens)), atol=3e-5)
+@pytest.mark.parametrize("rows,rpc", [(64, 32), (256, 32), (96, 32), (3, 3),
+                                      (48, 24), (37, 1), (1, 1)])
+def test_rows_per_cell_follows_the_row_count(rows, rpc):
+    """A round of 64, a mixed step's 256, the sparse view's 96: 32 rows a
+    cell; a count with no divisor up to 32 but one keeps a row a cell."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    assert pa.rows_per_cell(rows) == rpc
+    assert rows % rpc == 0
+
+
+#: each row's tokens by name, 0 a dead row, for a pool of blocks of 16 and
+#: tables three wide, at EIGHT rows a cell at most (``DECODE_CELL_ROWS``
+#: lowered for the test's size). CELLS: one cell each, run as ONE batch (a
+#: cell's work does not depend on its neighbours: one compile for all);
+#: BATCHES: batches of their own
+CELLS = {
+    "dead_first": [0, 0, 0, 0, 0, 5, 17, 48],
+    "dead_last": [5, 17, 48, 0, 0, 0, 0, 0],
+    "a_cell_of_dead_rows": [0] * 8,
+    "dead_scattered": [0, 5, 0, 0, 17, 0, 48, 0],
+    "one_live_last_of_its_cell": [0] * 7 + [33],
+}
+BATCHES = {
+    "a_batch_of_dead_rows": [0] * 8,
+    "three_rows": [5, 0, 17],                                  # one cell
+    "thirteen_rows": [0, 0, 31, 0, 0, 0, 2, 48, 0, 0, 0, 0, 16],  # a row a cell
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _dead_and_alone(kvh, nh, hd, BS, rows):
+    """The batch ``rows`` names through the kernel, whole and its live rows
+    alone, and plainly: (lens, out, alone, plain). ``13_among_16``: three
+    live rows shuffled among 13 dead; ``cells``: every cell of CELLS."""
+    if rows == "13_among_16":
+        pa, pool, q, tables, lens, live = _case(
+            kvh, nh, hd, BS, LENS["mixed"](BS, 3)[:3], dead=13)
+        assert (np.asarray(lens) == 0).sum() == 13
+    else:
+        want = BATCHES[rows] if rows in BATCHES else sum(CELLS.values(), [])
+        live = np.flatnonzero(want)
+        pa, pool, q, tables, lens, _ = _case(
+            kvh, nh, hd, BS, [want[b] for b in live],
+            dead=len(want) - len(live), at=live)
+        np.testing.assert_array_equal(np.asarray(lens), want)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pa, "DECODE_CELL_ROWS", 8)
+        out = np.asarray(pa.paged_decode(q, pool, jnp.int32(1), tables, lens))
+        alone = np.zeros_like(out)
+        if len(live):
+            alone[live] = np.asarray(pa.paged_decode(
+                q[live], pool, jnp.int32(1), tables[live], lens[live]))
+    plain = np.asarray(plain_attention(q, pool, jnp.int32(1), tables, lens))
+    return np.asarray(lens), out, alone, plain
+
+
+@pytest.mark.parametrize("kvh,nh,hd,BS,rows", [
+    (kvh, nh, hd, BS, "13_among_16") for kvh, nh, hd, BS, _ in
+    SHAPES[:2] + SHAPES[4:]] + [
+    (2, 4, 64, 16, rows) for rows in list(CELLS) + list(BATCHES)])
+def test_dead_rows_cost_nothing_and_change_nothing(kvh, nh, hd, BS, rows):
+    """Rows dead (``lens`` 0, an all-zero table) first, last and scattered in
+    a cell, a cell and a batch of them alone, row counts of one cell and of a
+    row a cell, grouped queries: their outputs are zeros, and the live rows'
+    outputs are bit for bit those of the batch without the dead rows."""
+    lens, out, alone, plain = _dead_and_alone(
+        kvh, nh, hd, BS, "cells" if rows in CELLS else rows)
+    mine = slice(None)
+    if rows in CELLS:
+        first = 8 * list(CELLS).index(rows)
+        mine = slice(first, first + 8)
+        np.testing.assert_array_equal(lens[mine], CELLS[rows])
+    assert not out[mine][lens[mine] == 0].any()
+    np.testing.assert_array_equal(out[mine], alone[mine])
+    np.testing.assert_allclose(out[mine], plain[mine], atol=3e-5)
 
 
 # ----------------------------------------------------------------------
@@ -289,7 +360,7 @@ def test_stacked_kernel_reads_its_layer(hd, kvh, nh):
     L, layer = 3, 2
     pa, pool, q, tables, lens, _ = _case(kvh, nh, hd, 16, [5, 16, 50], L=L,
                                          MAXB=4)
-    out = pa.paged_decode(q, pool, jnp.int32(layer), tables, lens)
+    out = _jitted("paged_decode")(q, pool, jnp.int32(layer), tables, lens)
     kp, vp = pool[layer, ..., :hd], pool[layer, ..., hd:]
     per_layer = paged_decode_attention(q, kp, vp, tables, lens)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(per_layer))
@@ -302,7 +373,7 @@ def test_stacked_kernel_reads_its_layer(hd, kvh, nh):
                                np.asarray(oracle(q, kp, vp, tables, lens)),
                                atol=3e-5)
     # and it is that layer it read: another layer's answer differs
-    other = pa.paged_decode(q, pool, jnp.int32(0), tables, lens)
+    other = _jitted("paged_decode")(q, pool, jnp.int32(0), tables, lens)
     assert not np.allclose(np.asarray(out), np.asarray(other), atol=1e-3)
 
 
@@ -510,9 +581,8 @@ def test_kv_write_equals_the_scatter_off_the_trash_block(monkeypatch, live,
     assert pa.writes_live_rows(pool)
     l = jnp.int32(0 if layer == "first" else pool.shape[0] - 1)
     scattered = pa.write_rows(pool, l, tables, pos, k, v)
-    written = jax.jit(
-        lambda *a: pa.write_rows(*a, rows_apart=True))(pool, l, tables, pos,
-                                                       k, v)
+    written = _jitted("write_rows", "rows_apart")(
+        pool, l, tables, pos, k, v, rows_apart=True)
     before, s, w = _bits(pool), _bits(scattered), _bits(written)
     np.testing.assert_array_equal(w[:, :, 1:], s[:, :, 1:])
     np.testing.assert_array_equal(w[:, :, 0], before[:, :, 0])
