@@ -223,16 +223,33 @@ class TransformerConfig:
         return self.num_kv_heads or self.num_heads
 
     @property
-    def type_runs(self) -> Tuple[Tuple[str, str, int], ...]:
-        """(group key, layer type, layers) of each run of equal
-        ``layer_types``, in forward order: the stacked groups of the tree."""
-        runs = []
-        for t in self.layer_types or ():
-            if runs and runs[-1][1] == t:
-                runs[-1][2] += 1
-            else:
-                runs.append([f"blocks_{len(runs)}", t, 1])
-        return tuple(tuple(r) for r in runs)
+    def type_runs(self) -> Tuple[Tuple[str, str, int, int], ...]:
+        """The model's layers as the stacked groups of its tree, in forward
+        order: (params key, kind, layers, pool layers a layer). THE place the
+        layer pattern is read from (the tree's groups, the pool's layer axis,
+        the slot arrays, the paged forward's loop). ``kind`` names the layer
+        function that serves the group (``TransformerLM.forward_paged``):
+        ``full`` attention, ``latent`` attention (a leading dense group
+        before the expert layers, where the model has both), the ``scmoe``
+        double layer with a pool layer for each of its attentions, or a
+        ``layer_types`` mixer, a run of equal types one group."""
+        if self.layer_types is not None:
+            runs = []
+            for t in self.layer_types:
+                if runs and runs[-1][1] == t:
+                    runs[-1][2] += 1
+                else:
+                    runs.append([f"blocks_{len(runs)}", t, 1,
+                                 int(t == "sparse_attn")])
+            return tuple(tuple(r) for r in runs)
+        if not self.is_mla:
+            return (("blocks", "full", self.num_layers, 1),)
+        if self.layer_kind == "scmoe":
+            return (("blocks", "scmoe", self.num_layers, self.sublayers),)
+        dense = self.num_dense_layers if self.num_experts > 0 else 0
+        return tuple(g for g in (
+            ("dense_blocks", "latent", dense, 1),
+            ("blocks", "latent", self.num_layers - dense, 1)) if g[2])
 
     def layers_of(self, layer_type: str) -> int:
         return sum(t == layer_type for t in self.layer_types or ())
@@ -264,14 +281,15 @@ class TransformerConfig:
         lightning layer's float32 state; a sparse layer's compressed keys for
         ``max_seq_len`` tokens, which lie by slot beside its KV blocks)."""
         kv = ("kv_blocks", 2 * self.pool_heads * sum(self.kv_row))
-        if self.layer_types is None:
+        kinds = dict.fromkeys(kind for _, kind, _, _ in self.type_runs)
+        if not set(kinds) & set(LAYER_TYPES):
             return {"attn": (kv,)}
         nh, hd = self.num_heads, self.head_dim
         keys = self.sparse_spec.max_keys(self.max_seq_len)
-        kinds = {"sparse_attn": (kv, ("state_slot",
-                                      2 * keys * self.kv_heads * hd)),
-                 "linear_attn": (("state_slot", 4 * nh * hd * hd),)}
-        return {t: kinds[t] for t in dict.fromkeys(self.layer_types)}
+        kept = {"sparse_attn": (kv, ("state_slot",
+                                     2 * keys * self.kv_heads * hd)),
+                "linear_attn": (("state_slot", 4 * nh * hd * hd),)}
+        return {t: kept[t] for t in kinds}
 
     @property
     def holds_state(self) -> bool:
@@ -313,9 +331,7 @@ class TransformerConfig:
     def pool_layers(self) -> int:
         """Layers of the paged pool: one for every attention of the model
         that keeps KV blocks. THE place the pool's layer axis is read from."""
-        if self.layer_types is not None:
-            return self.layers_of("sparse_attn")
-        return self.sublayers * self.num_layers
+        return sum(n * per for _, _, n, per in self.type_runs)
 
     @property
     def mla_latent_scales(self) -> Tuple[float, float]:
@@ -609,6 +625,48 @@ def paged_limits(tables, positions):
     return jnp.where(tables[:, 0] > 0, positions + 1, 0)
 
 
+@jax.tree_util.register_dataclass
+@dataclass(frozen=True)
+class PagedStep:
+    """What one paged step tells each of its layers, built once a call
+    (:meth:`of`). Its T rows are one-token rows: those before ``cut`` one a
+    sequence; those from ``cut`` to ``end`` chunk segments in tiles of
+    ``tile`` rows, each tile consecutive tokens of one sequence (its first
+    row carries the table, the position and the slot; its valid rows are a
+    prefix); what is left behind the last whole tile is padding. A padding
+    row carries the all-zero table (trash block 0, which no sequence holds)."""
+    tables: Any        # (T, MAXB) pool block ids, 0-padded
+    starts: Any        # (T,) each row's (first) position
+    positions: Any     # (T, S)
+    limits: Any        # (T,) pool tokens a row attends over (paged_limits)
+    live: Any          # (T,) bool: a real token (routed, counted, written)
+    slots: Any         # (T,) each row's state slot, or None
+    tile_counts: Any   # valid rows of each tile, or None (no tile)
+    cut: int = field(metadata=dict(static=True))
+    end: int = field(metadata=dict(static=True))
+    tile: int = field(metadata=dict(static=True))
+    #: the builder's promise that no two rows write one pool block (a decode
+    #: round: one row a sequence, shared blocks copied on write before the
+    #: dispatch), which lets ``paged_attention.write_rows`` write the live
+    #: rows alone
+    rows_apart: bool = field(metadata=dict(static=True))
+
+    @classmethod
+    def of(cls, tables, starts, width=1, *, seg_from=None, tile=1,
+           rows_apart=False, slots=None):
+        T = tables.shape[0]
+        cut = T if seg_from is None else seg_from
+        end = cut + (T - cut) // tile * tile
+        live = tables[:, 0] > 0
+        positions = starts[:, None]
+        if width > 1:
+            positions = positions + jnp.arange(width, dtype=jnp.int32)
+        tile_counts = jnp.sum(live[cut:end].reshape(-1, tile), axis=1,
+                              dtype=jnp.int32) if cut < end else None
+        return cls(tables, starts, positions, paged_limits(tables, starts),
+                   live, slots, tile_counts, cut, end, tile, rows_apart)
+
+
 def sublayer_prefix(i: int) -> str:
     """Prefix of sublayer ``i``'s leaves in a double layer's ``blocks``."""
     return f"s{i}_"
@@ -786,7 +844,7 @@ class TransformerLM:
                  "linear_attn": {"wk": (H, qd), "wv": (H, qd),
                                  "o_norm_scale": (qd,)}}
         groups = {key: (n, {**shared, **mixer[kind]})
-                  for key, kind, n in cfg.type_runs}
+                  for key, kind, n, _ in cfg.type_runs}
         top = {"wte": (V, H), "lnf_scale": (H,)}
         if not cfg.tie_embeddings:
             top["lm_head"] = (H, V)
@@ -840,11 +898,11 @@ class TransformerLM:
                     "w_down": (width, H)}
 
         E, I = cfg.num_experts, cfg.mlp_dim
+        layers = {key: n for key, _, n, _ in cfg.type_runs}
         groups = {}
-        n_dense = cfg.num_dense_layers if E > 0 else 0
-        if n_dense:
-            groups["dense_blocks"] = (n_dense, {**attn,
-                                                **mlp(cfg.dense_mlp_dim)})
+        if "dense_blocks" in layers:
+            groups["dense_blocks"] = (layers["dense_blocks"],
+                                      {**attn, **mlp(cfg.dense_mlp_dim)})
         if E > 0:
             moe = {"moe_wg": (H, cfg.router_width),
                    "moe_bias": (cfg.router_width,),
@@ -857,9 +915,9 @@ class TransformerLM:
                 dense = {**attn, **mlp(cfg.dense_mlp_dim)}
                 attn = {sublayer_prefix(i) + k: v for i in range(cfg.sublayers)
                         for k, v in dense.items()}
-            groups["blocks"] = (cfg.num_layers - n_dense, {**attn, **moe})
+            groups["blocks"] = (layers["blocks"], {**attn, **moe})
         else:
-            groups["blocks"] = (cfg.num_layers, {**attn, **mlp(cfg.dense_mlp_dim)})
+            groups["blocks"] = (layers["blocks"], {**attn, **mlp(cfg.dense_mlp_dim)})
         top = {"wte": (V, H), "lnf_scale": (H,)}
         if not cfg.tie_embeddings:
             top["lm_head"] = (H, V)
@@ -1027,22 +1085,21 @@ class TransformerLM:
 
     # ------------------------------------------------------------------
     def _block(self, x, blk, *, positions, rng, train, kv_cache=None, cache_index=None,
-               paged=None, attn_mask_bias=None, rows_apart=False):
+               paged=None, attn_mask_bias=None, step=None):
         """One transformer block on (B, S, H). Returns (y, new_kv) where new_kv is
         the updated (k, v) when decoding with a cache.
 
         ``paged``: (pool, layer, tables) for the blocked KV pool — the WHOLE
         stacked pool (``ops/transformer/paged_attention.py`` owns its layout),
-        this block's layer index (traced), tables (B, MAXB) of pool block ids
+        this block's pool layer (traced), tables (B, MAXB) of pool block ids
         (0 = reserved trash block); new_kv is then the updated pool. Tokens
         write at their ``positions`` as whole rows of the pool, in place;
         attention reads the pool where it lies, through the Pallas kernel
         for one-token rows or the table-gathered logical cache with a
         per-sequence position mask otherwise (covers chunked prefill AND
         decode — reference ``inference/v2/ragged_ops/blocked_flash`` +
-        ``kv_cache.py BlockedKVCache``). ``rows_apart`` (static): the
-        caller's promise that no two rows write one pool block
-        (``paged_attention.write_rows``)."""
+        ``kv_cache.py BlockedKVCache``). ``step``: the :class:`PagedStep`
+        of the call (what the tables and positions alone say, if None)."""
         cfg = self.config
         if cfg.is_mla:
             if kv_cache is not None or attn_mask_bias is not None or (
@@ -1051,7 +1108,7 @@ class TransformerLM:
                     "attention='mla' has the full-sequence and the paged "
                     "paths only: no slot cache, padding mask or dropout")
             y, pool, _ = self._block_mla(x, blk, positions=positions,
-                                         paged=paged, rows_apart=rows_apart)
+                                         paged=paged, step=step)
             return y, pool, jnp.zeros((), jnp.float32)
         nh, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         B, S, H = x.shape
@@ -1112,10 +1169,12 @@ class TransformerLM:
                 from ..ops.transformer import paged_attention as pa
 
                 pool, layer, tables = paged
+                if step is None:
+                    step = PagedStep.of(tables, positions[:, 0])
                 BS = pool.shape[3]
                 with jax.named_scope("kv_write"):
                     pool = pa.write_rows(pool, layer, tables, positions, kk, v,
-                                         rows_apart=rows_apart)
+                                         rows_apart=step.rows_apart)
                 new_kv = pool
                 with jax.named_scope("paged_attn"):
                     # NOTE: evaluated at TRACE time — the env override (used by tests
@@ -1138,12 +1197,10 @@ class TransformerLM:
                         # Pallas paged decode: the kernel streams this layer's
                         # blocks out of the stacked pool by layer index and block
                         # table — no slice, no gathered copy (paged_attention.py).
-                        # A padding row's table names no block (block 0 is the
-                        # trash block no sequence holds): it is marked dead here,
-                        # lens 0, and the kernel fetches nothing for it
-                        lens = paged_limits(tables, positions[:, 0])
+                        # A padding row is dead (limit 0): the kernel fetches
+                        # nothing for it
                         attn_out = pa.paged_decode(
-                            q[:, 0], pool, layer, tables, lens)[:, None]
+                            q[:, 0], pool, layer, tables, step.limits)[:, None]
                     else:
                         gk, gv = pa.gather_context(pool, layer, tables)
                         T = gk.shape[1]
@@ -1239,9 +1296,8 @@ class TransformerLM:
             return x + attn_out + mlp_out, new_kv, aux
         return x + mlp_out, new_kv, aux
 
-    def _block_mla(self, x, blk, *, positions, paged=None, seg_from=None,
-                   experts=None, row_mask=None, rows_apart=False,
-                   limits=None):
+    def _block_mla(self, x, blk, *, positions, paged=None, step=None,
+                   experts=None):
         """One latent-attention block on (B, S, H): a dense layer, an expert
         layer where ``blk`` holds a router, or a shortcut-connected double
         layer (``layer_kind="scmoe"``, :meth:`_block_scmoe`). Returns (y, new
@@ -1253,41 +1309,30 @@ class TransformerLM:
         is written to the latent pool and attention runs in the absorbed form
         (``q_nope W_uk`` against ``c_kv``, the weighted latent through
         ``W_uv``: both views of ``wkv_b``, taken here) over the pool where it
-        lies. Rows from ``seg_from`` on are chunk segments in tiles of
-        ``paged_attention.SEGMENT_TILE`` rows, each tile consecutive tokens
-        of one sequence (the tile's first row carries its table), so that a
-        tile streams its sequence's latent once; rows before it are one-token
-        rows of sequences of their own. ``experts``: (stacked expert leaves,
-        layer of the group) when the caller kept them out of ``blk``.
-        ``row_mask`` (B*S,) bool: the rows that are real tokens (padding rows
-        are routed to no expert). ``rows_apart``: as :meth:`_block`.
-        ``limits`` (B*S,) int32: the pool tokens each row may see, 0 for a
-        padding row (:meth:`_mla_attention`)."""
+        lies, a tile of the ``step`` (:class:`PagedStep`) streaming its
+        sequence's latent once. ``experts``: (stacked expert leaves, layer of
+        the group) when the caller kept them out of ``blk``."""
         from ..moe.layer import _gated_mlp
 
         if self.config.layer_kind == "scmoe":
             return self._block_scmoe(x, blk, positions=positions, paged=paged,
-                                     seg_from=seg_from, experts=experts,
-                                     row_mask=row_mask, rows_apart=rows_apart,
-                                     limits=limits)
+                                     step=step, experts=experts)
         blk = _dequant_woq(blk, x.dtype)
         attn_out, new_pool = self._mla_attention(
-            x, blk, positions=positions, paged=paged, seg_from=seg_from,
-            rows_apart=rows_apart, limits=limits)
+            x, blk, positions=positions, paged=paged, step=step)
         stats = None
         with jax.named_scope("mlp"):
             x = jax.lax.optimization_barrier(x + attn_out)
             h2 = _norm(x, blk["ln2_scale"], None, "rmsnorm",
                        self.config.norm_eps)
             if "moe_wg" in blk:
-                mlp_out, stats = self._held_experts(h2, blk, experts, row_mask)
+                mlp_out, stats = self._held_experts(h2, blk, experts, step)
             else:
                 mlp_out = _gated_mlp(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
             mlp_out = self._constraint(mlp_out, self._act_spec(paged is None))
         return x + mlp_out, new_pool, stats
 
-    def _block_scmoe(self, x, blk, *, positions, paged, seg_from, experts,
-                     row_mask, rows_apart=False, limits=None):
+    def _block_scmoe(self, x, blk, *, positions, paged, step, experts):
         """One shortcut-connected double layer (LongCat-Flash, ScMoE): two
         sublayers of latent attention and a dense feed-forward, and one
         expert layer computed from the first sublayer's post-attention norm
@@ -1297,9 +1342,9 @@ class TransformerLM:
             x2 = x1 + F_0(h)
             x3 = x2 + A_1(N(x2));  y = x3 + F_1(N(x3)) + m
 
-        Sublayer ``i`` attends over layer ``sublayers * layer + i`` of the pool
-        (``TransformerConfig.pool_layers``). Arguments and result as
-        :meth:`_block_mla`."""
+        Sublayer ``i`` attends over pool layer ``layer + i``, ``layer`` the
+        handle's: the double layer's first (``TransformerConfig.type_runs``).
+        Arguments and result as :meth:`_block_mla`."""
         from ..moe.layer import _gated_mlp
 
         eps, n_sub = self.config.norm_eps, self.config.sublayers
@@ -1310,15 +1355,13 @@ class TransformerLM:
         for i in range(n_sub):
             sub = sublayer(blk, i)
             attn_out, pool = self._mla_attention(
-                x, sub, positions=positions, seg_from=seg_from,
-                rows_apart=rows_apart, limits=limits,
-                paged=None if paged is None
-                else (pool, n_sub * layer + i, tables))
+                x, sub, positions=positions, step=step,
+                paged=None if paged is None else (pool, layer + i, tables))
             with jax.named_scope("mlp"):
                 x = once(x + attn_out)
                 h = _norm(x, sub["ln2_scale"], None, "rmsnorm", eps)
                 if i == 0:
-                    m, stats = self._held_experts(h, blk, experts, row_mask)
+                    m, stats = self._held_experts(h, blk, experts, step)
                 with jax.named_scope("dense_ffn"):
                     x = x + self._constraint(
                         _gated_mlp(h, sub["w_gate"], sub["w_up"], sub["w_down"]),
@@ -1327,9 +1370,10 @@ class TransformerLM:
             y = x + self._constraint(m, self._act_spec(paged is None))
         return y, pool, stats
 
-    def _held_experts(self, h, blk, experts, row_mask):
+    def _held_experts(self, h, blk, experts, step):
         """The expert layer of ``blk`` on the normed (B, S, H) ``h``: (its
-        output, its counts). ``experts``: see :meth:`_block_mla`."""
+        output, its counts). ``experts``: see :meth:`_block_mla`. A padding
+        row of the ``step`` is routed to no expert."""
         from ..moe.layer import held_experts_ffn
 
         cfg = self.config
@@ -1345,19 +1389,17 @@ class TransformerLM:
             topk_group=cfg.moe_topk_group,
             normalize=cfg.moe_norm_topk, scale=cfg.moe_score_scale,
             first=cfg.moe_expert_offset, layer=layer_in_group,
-            token_mask=row_mask, router=cfg.moe_router,
+            token_mask=None if step is None else step.live,
+            router=cfg.moe_router,
             zero_experts=cfg.moe_zero_experts)
         return y.reshape(B, S, H), stats
 
-    def _mla_attention(self, x, blk, *, positions, paged=None, seg_from=None,
-                       rows_apart=False, limits=None):
+    def _mla_attention(self, x, blk, *, positions, paged=None, step=None):
         """Latent attention of one (sub)layer on the residual stream ``x``
         (B, S, H), its input norm and output projection included: (the
         attention's output, the new pool or None). ``blk``: the layer's
-        leaves; ``paged``, ``seg_from`` and ``rows_apart`` as
-        :meth:`_block_mla`. ``limits`` (B,) int32, paged only: the pool
-        tokens each row attends over, what :func:`paged_limits` gives (the
-        step computes it once for all its layers; computed here if None)."""
+        leaves; ``paged`` and ``step`` as :meth:`_block_mla` (without a
+        step: rows one a sequence, as the tables and positions say)."""
         from ..ops.transformer import paged_attention as pa
 
         cfg = self.config
@@ -1417,48 +1459,45 @@ class TransformerLM:
                 pool, layer, tables = paged
                 if S != 1:
                     raise ValueError("the latent paged path takes one-token rows")
+                if step is None:
+                    step = PagedStep.of(tables, positions[:, 0])
                 with jax.named_scope("kv_write"):
                     pool = pa.write_rows(
                         pool, layer, tables, positions, c_kv[:, :, None, :],
                         jnp.pad(k_rope, ((0, 0),) * 3
                                 + ((0, cfg.kv_row[1] - rope),)),
-                        rows_apart=rows_apart)
+                        rows_apart=step.rows_apart)
                 new_pool = pool
                 with jax.named_scope("mla_proj"):
                     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
                 with jax.named_scope("paged_attn"):
-                    if limits is None:
-                        limits = paged_limits(tables, positions[:, 0])
                     o_lat = self._mla_paged_attention(
-                        q_lat, q_rope[:, 0], pool, layer, tables, limits,
-                        scale, seg_from)
+                        q_lat, q_rope[:, 0], pool, layer, step, scale)
                 with jax.named_scope("mla_proj"):
                     attn = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv)[:, None]
             attn_out = attn.reshape(B, S, nh * vd) @ blk["wo"].astype(dt)
             attn_out = self._constraint(attn_out, self._act_spec(paged is None))
         return attn_out, new_pool
 
-    def _mla_paged_attention(self, q_lat, q_rope, pool, layer, tables, limits,
-                             scale, seg_from):
-        """Absorbed attention of T one-token rows over the latent pool: rows
-        before ``seg_from`` one a sequence, rows from it on in segment tiles.
-        The Pallas kernel on a TPU (or forced, as the GPT-2 path's is), the
-        XLA gather off it. A row with ``limits`` 0 is dead: the kernel
-        fetches nothing for a one-token row or a tile of such rows."""
+    def _mla_paged_attention(self, q_lat, q_rope, pool, layer, step, scale):
+        """Absorbed attention of the ``step``'s T one-token rows over the
+        latent pool: rows before its ``cut`` one a sequence, rows from it on
+        in segment tiles. The Pallas kernel on a TPU (or forced, as the GPT-2
+        path's is), the XLA gather off it. A row with ``limits`` 0 is dead:
+        the kernel fetches nothing for a one-token row or a tile of such
+        rows."""
         from ..ops.transformer import paged_attention as pa
 
         attend = pa.mla_decode if pa.kernels_wanted() else pa.mla_attend_xla
-        T = q_lat.shape[0]
-        cut = T if seg_from is None else seg_from
+        tables, limits, cut = step.tables, step.limits, step.cut
         parts = []
         if cut:
             parts.append(attend(q_lat[:cut], q_rope[:cut], pool, layer,
                                 tables[:cut], limits[:cut], scale=scale))
-        if cut < T:
-            tile = pa.SEGMENT_TILE
+        if cut < q_lat.shape[0]:
             parts.append(attend(q_lat[cut:], q_rope[cut:], pool, layer,
-                                tables[cut::tile], limits[cut:], scale=scale,
-                                q_tile=tile))
+                                tables[cut::step.tile], limits[cut:],
+                                scale=scale, q_tile=step.tile))
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
     def _moe_ffn(self, h, blk, train):
@@ -1567,16 +1606,6 @@ class TransformerLM:
             return jax.checkpoint(fn, policy=policy[name])
         return jax.checkpoint(fn)
 
-    @staticmethod
-    def layer_groups(params):
-        """The stacked layer groups of ``params`` in forward order: the
-        leading ``dense_blocks`` (where the model has them) before
-        ``blocks``; of a ``layer_types`` model ``blocks_0``, ``blocks_1``,
-        ... (one a run of equal types)."""
-        return [g for g in ("dense_blocks", "blocks") if g in params] \
-            or sorted((g for g in params if re.fullmatch(r"blocks_\d+", g)),
-                      key=lambda g: int(g[7:]))
-
     def _trunk(self, params, x, positions, rng, train, pld_theta=None,
                attn_mask_bias=None):
         """Run all blocks via scan (remat optional). With ``pld_theta``
@@ -1586,7 +1615,7 @@ class TransformerLM:
         L = cfg.num_layers
         use_pld = pld_theta is not None and train
         use_rng = rng is not None and train and (cfg.dropout > 0 or use_pld)
-        if use_rng and len(self.layer_groups(params)) > 1:
+        if use_rng and len(cfg.type_runs) > 1:
             raise NotImplementedError(
                 "dropout / progressive layer drop over two layer groups")
 
@@ -1627,14 +1656,14 @@ class TransformerLM:
             block_fn = self._ckpt(body) if cfg.remat else body
             if not cfg.scan_layers:
                 aux_sum = jnp.zeros((), jnp.float32)
-                for group in self.layer_groups(params):
+                for group, *_ in cfg.type_runs:
                     for i in range(jax.tree.leaves(params[group])[0].shape[0]):
                         blk = jax.tree.map(lambda a: a[i], params[group])
                         x, aux = block_fn(x, blk)
                         aux_sum = aux_sum + aux
                 return x, aux_sum
             auxes = jnp.zeros((), jnp.float32)
-            for group in self.layer_groups(params):
+            for group, *_ in cfg.type_runs:
                 x, aux = jax.lax.scan(block_fn, x, params[group])
                 auxes = auxes + jnp.sum(aux)
         return x, jnp.sum(auxes)
@@ -1862,7 +1891,7 @@ class TransformerLM:
         from ..ops.transformer.paged_attention import init_pool
 
         cfg = self.config
-        if "sparse_attn" in (cfg.layer_types or ()) \
+        if "sparse_attn" in cfg.cache_kinds \
                 and block_size != cfg.sparse_block_size:
             raise ValueError(
                 f"a pool block of {block_size} tokens is not the block the "
@@ -1914,215 +1943,153 @@ class TransformerLM:
             "sparse_attn": lambda n: sa.init_keys(
                 n, max_seqs, cfg.kv_heads,
                 cfg.sparse_spec.max_keys(max_seq_len), hd, dtype)}
-        return {key: make[kind](n) for key, kind, n in cfg.type_runs}
+        return {key: make[kind](n) for key, kind, n, _ in cfg.type_runs
+                if kind in make}
 
     def forward_paged(self, params, input_ids, kv_pool, tables, starts,
-                      n_valid=None, logit_rows=None, seg_from=None,
-                      moe_stats=False, rows_apart=False, state=None,
-                      row_slots=None):
-        """Run a (B, S) segment against the blocked pool.
+                      logit_rows=None, seg_from=None, moe_stats=False,
+                      rows_apart=False, state=None, row_slots=None):
+        """Run T rows against the blocked pool: the one paged forward of
+        every served architecture. Embed; then, for each layer group of the
+        model (``TransformerConfig.type_runs``), one scan of the group's
+        kind over its stacked leaves; then the head.
 
-        tables: (B, MAXB) pool block ids per sequence (0-padded); starts: (B,)
-        first logical position of the segment. Returns ((B, V) logits at each
-        sequence's LAST VALID position, new pool). With ``logit_rows`` ((R,)
-        int32), only those rows are projected through the vocab head —
-        returns ((R, V), new pool) — so a ragged batch pays for R logits, not
-        B (reference ``ragged_ops/logits_gather``).
+        tables: (T, MAXB) pool block ids per row (0-padded); starts: (T,)
+        each row's position. Rows are one token each, (T, 1) (a ``full``
+        attention model also takes (T, S) segments, the last position's
+        logits returned). Returns ((T, V) logits, new pool). With
+        ``logit_rows`` ((R,) int32), only those rows are projected through
+        the vocab head — returns ((R, V), new pool) — so a ragged batch pays
+        for R logits, not T (reference ``ragged_ops/logits_gather``).
 
-        ``seg_from`` (latent attention only; static): rows from it on are
-        chunk segments in tiles of ``segment_tile`` rows (:meth:`_block_mla`).
-        ``rows_apart`` (static): the builder of the step promises that no two
-        of its rows write one pool block (a decode round: one row a sequence,
-        shared blocks copied on write before the dispatch), which is what
-        lets ``paged_attention.write_rows`` write the live rows alone.
-        ``moe_stats``: also return (rows, rows_max) int32 (2,): the (token,
-        choice) pairs that landed on held experts summed over the layers, and
-        the busiest held expert's (``config.holds_experts`` only); with
-        ``moe_zero_experts`` (3,): behind them the live rows' choices of
-        identity experts, summed over the layers.
+        ``seg_from`` (static; models with a ``segment_tile``): rows from it
+        on are chunk segments in tiles, and ``rows_apart`` (static) the
+        builder's promise about its rows: both as :class:`PagedStep` says,
+        which is built here, once, and is what every layer reads the step
+        from. ``state``, ``row_slots`` (``layer_types`` models): the slot
+        arrays of :meth:`init_state_cache` and each row's slot in them ((T,)
+        int32, 0 for a padding row); the new ``state`` is returned behind the
+        pool. ``moe_stats``: also return, last, the int32 counts
+        :attr:`step_counts` names, where it names any: of held experts
+        (rows, rows_max): the (token, choice) pairs that landed on held
+        experts summed over the layers, and the busiest held expert's; with
+        ``moe_zero_experts`` behind them the live rows' choices of identity
+        experts, summed over the layers.
 
-        ``state``, ``row_slots`` (``layer_types`` models): the slot arrays
-        of :meth:`init_state_cache` and each row's slot in them ((B,) int32, 0
-        for a padding row); the new ``state`` is returned behind the pool,
-        and with ``moe_stats`` the counts :attr:`step_counts` names.
-        """
-        B, S = input_ids.shape
-        if self.config.layer_types is not None:
-            return self._forward_paged_typed(
-                params, input_ids, kv_pool, state, tables, starts, row_slots,
-                logit_rows, seg_from, moe_stats, rows_apart)
-        if self.config.is_mla:
-            return self._forward_paged_mla(params, input_ids, kv_pool, tables,
-                                           starts, logit_rows, seg_from,
-                                           moe_stats, rows_apart)
-        positions = starts[:, None] + jnp.broadcast_to(
-            jnp.arange(S, dtype=jnp.int32), (B, S))
-        dtype = kv_pool.dtype
+        A kind is a function ``(h, blk, caches, layer, pool_layer, step,
+        experts) -> (y, caches, counts or None)``: ``caches`` holds the pool
+        (``pool``) if the group's layers keep KV blocks and the group's slot
+        array (``own``) if they keep a state slot, ``layer`` counts the
+        group's layers and ``pool_layer`` the pool's. A new kind is such a
+        function, its ``cache_kinds`` and its line in ``type_runs``."""
+        cfg = self.config
+        S = input_ids.shape[1]
+        if S != 1 and (cfg.is_mla or cfg.layer_types is not None):
+            raise ValueError("the paged path of a latent-attention or a "
+                             "layer_types model takes one-token rows")
+        if cfg.holds_state and (state is None or row_slots is None):
+            raise ValueError("a layer_types model's paged path needs its "
+                             "slot arrays (init_state_cache) and row_slots")
+        step = PagedStep.of(tables, starts, S, seg_from=seg_from,
+                            tile=self.segment_tile, rows_apart=rows_apart,
+                            slots=row_slots)
         with jax.named_scope("embed"):
-            x = self._embed(params, input_ids, positions, dtype)
-
-        def body(carry, blk):
-            h, pool, layer = carry
-            y, pool, _ = self._block(
-                h, blk, positions=positions, rng=None, train=False,
-                paged=(pool, layer, tables), rows_apart=rows_apart,
-            )
-            return (y, pool, layer + 1), None
-
-        # the pool rides the layer scan as a carry, one donated buffer from
-        # entry to exit, written and read where it lies. "kv_carry" is the
-        # scan's own ops on the device: what it does to its carried arrays
-        # and to the stacked leaves it slices (a layer's matrix materialised
-        # out of the stack and relaid reads here, not under the layer)
+            x = self._embed(params, input_ids, step.positions, kv_pool.dtype)
+        kinds = {"full": self._full_layer, "latent": self._latent_layer,
+                 "scmoe": self._latent_layer,
+                 "sparse_attn": partial(self._typed_layer, self._sparse_mixer),
+                 "linear_attn": partial(self._typed_layer, self._linear_mixer)}
+        state = dict(state or {})
+        names = self.step_counts
+        tally = jnp.zeros((len(names),), jnp.int32) if names else None
+        pool_layer = 0                        # pool layers before this group
+        # a group's scan carries the pool if its layers keep KV blocks, its
+        # own slot array if they keep a state slot, and nothing else: an
+        # array carried through a scan that does not touch it, or sliced by
+        # a constant layer, was copied whole by XLA, once a dispatch. The
+        # pool rides as a carry, one donated buffer from entry to exit,
+        # written and read where it lies. "kv_carry" is the scans' own ops on
+        # the device: what they do to their carried arrays and to the stacked
+        # leaves they slice (a layer's matrix materialised out of the stack
+        # and relaid reads here, not under the layer)
         with jax.named_scope("kv_carry"):
-            (x, kv_pool, _), _ = jax.lax.scan(
-                body, (x, kv_pool, jnp.int32(0)), params["blocks"])
-        # project only each sequence's last VALID position — skips the
-        # (S, V) vocab matmul over the rest of the chunk
-        if n_valid is None:
-            last = jnp.full((B,), S - 1, jnp.int32)
-        else:
-            last = jnp.clip(n_valid - 1, 0, S - 1)
-        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # (B,H)
-        if logit_rows is not None:
-            x_last = x_last[logit_rows]  # (R,H)
-        with jax.named_scope("lm_head_loss"):
-            lg = self._head(params, x_last[:, None])[:, 0]
-        return lg, kv_pool
+            for key, kind, n, per in cfg.type_runs:
+                leaves = params[key]
+                # held experts stay out of the scanned leaves: the grouped
+                # product indexes them by (layer, expert) where they lie, so
+                # no layer of experts is sliced out of the stack (weight-only-
+                # quantized experts are code + scale leaves: they ride the
+                # scan and are dequantised a layer at a time)
+                experts = None
+                if cfg.holds_experts and all(k in leaves for k in EXPERT_LEAVES):
+                    experts = {k: leaves[k] for k in EXPERT_LEAVES}
+                    leaves = {k: v for k, v in leaves.items()
+                              if k not in EXPERT_LEAVES}
+                caches = {"own": state[key]} if key in state else {}
+                if per:
+                    caches["pool"] = kv_pool
 
-    def _forward_paged_mla(self, params, input_ids, kv_pool, tables, starts,
-                           logit_rows, seg_from, moe_stats, rows_apart):
-        """:meth:`forward_paged` of a latent-attention model: one-token rows
-        (T, 1), the layer groups scanned in turn with the latent pool as the
-        carry. The stacked expert matrices stay out of the scanned leaves:
-        the grouped product indexes them by (layer, expert) where they lie,
-        so no layer of experts is sliced out of the stack."""
-        T, S = input_ids.shape
-        if S != 1:
-            raise ValueError("the latent paged path takes one-token rows")
-        positions = starts[:, None]
-        with jax.named_scope("embed"):
-            x = self._embed(params, input_ids, positions, kv_pool.dtype)
-        # (rows, rows_max) of the held experts, and behind them the picks of
-        # identity experts where the router has such
-        stats = jnp.zeros((3 if self.config.moe_zero_experts else 2,),
-                          jnp.int32)
-        layer0 = 0
-        # a padding row carries the all-zero table (trash block 0, which no
-        # sequence ever holds): it is routed to no expert
-        row_mask = tables[:, 0] > 0
-        # and dead for attention: it sees no token, in every layer
-        limits = paged_limits(tables, starts)
-        with jax.named_scope("kv_carry"):
-            for group in self.layer_groups(params):
-                leaves = params[group]
-                # (weight-only-quantized experts are code + scale leaves: they
-                # ride the scan and are dequantised a layer at a time)
-                moe = all(k in leaves for k in EXPERT_LEAVES + ("moe_wg",))
-                big = {k: leaves[k] for k in EXPERT_LEAVES} if moe else None
-                small = {k: v for k, v in leaves.items()
-                         if not (moe and k in EXPERT_LEAVES)}
-                n = jax.tree.leaves(small)[0].shape[0]
+                def body(carry, blk, fn=kinds[kind], base=pool_layer, per=per,
+                         experts=experts):
+                    h, caches, l, tally = carry
+                    y, caches, counts = fn(h, blk, caches, l, base + per * l,
+                                           step, experts)
+                    return (y, caches, l + 1, self._tally(tally, counts)), None
 
-                def body(carry, blk, big=big):
-                    h, pool, l, st = carry
-                    y, pool, s = self._block_mla(
-                        h, blk, positions=positions,
-                        paged=(pool, l + layer0, tables), seg_from=seg_from,
-                        experts=None if big is None else (big, l),
-                        row_mask=row_mask, rows_apart=rows_apart,
-                        limits=limits)
-                    if s is not None:
-                        st = jnp.stack([st[0] + s[0], jnp.maximum(st[1], s[1]),
-                                        *(st[i] + s[i] for i in range(2, len(s)))])
-                    return (y, pool, l + 1, st), None
-
-                (x, kv_pool, _, stats), _ = jax.lax.scan(
-                    body, (x, kv_pool, jnp.int32(0), stats), small)
-                layer0 += n
-        x_last = x[:, 0]
+                (x, caches, _, tally), _ = jax.lax.scan(
+                    body, (x, caches, jnp.int32(0), tally), leaves)
+                if "own" in caches:
+                    state[key] = caches["own"]
+                if per:
+                    kv_pool, pool_layer = caches["pool"], pool_layer + per * n
+        # only the last position is projected (and of those rows, only
+        # ``logit_rows``)
+        x_last = x[:, S - 1]
         if logit_rows is not None:
             x_last = x_last[logit_rows]
         with jax.named_scope("lm_head_loss"):
             lg = self._head(params, x_last[:, None])[:, 0]
-        return (lg, kv_pool, stats) if moe_stats else (lg, kv_pool)
+        out = (lg, kv_pool, state) if cfg.holds_state else (lg, kv_pool)
+        return out + (tally,) if moe_stats and names else out
+
+    def _tally(self, total, counts):
+        """The step's counts with one layer's taken in: a count named
+        ``*_max`` is the largest any layer saw, the others are sums over the
+        layers."""
+        if counts is None:
+            return total
+        peak = [name.endswith("_max") for name in self.step_counts]
+        if not any(peak):
+            return total + counts
+        return jnp.stack([jnp.maximum(total[i], counts[i]) if p
+                          else total[i] + counts[i]
+                          for i, p in enumerate(peak)])
+
+    def _full_layer(self, h, blk, caches, layer, pool_layer, step, experts):
+        """The ``full`` kind: :meth:`_block`'s paged branch."""
+        y, pool, _ = self._block(
+            h, blk, positions=step.positions, rng=None, train=False,
+            paged=(caches["pool"], pool_layer, step.tables), step=step)
+        return y, {"pool": pool}, None
+
+    def _latent_layer(self, h, blk, caches, layer, pool_layer, step, experts):
+        """The ``latent`` and ``scmoe`` kinds: :meth:`_block_mla`."""
+        y, pool, counts = self._block_mla(
+            h, blk, positions=step.positions, step=step,
+            paged=(caches["pool"], pool_layer, step.tables),
+            experts=None if experts is None else (experts, layer))
+        return y, {"pool": pool}, counts
 
     # ------------------------------------------------------------------
     # ``layer_types`` models: one small mixer a type, the rest shared
     # ------------------------------------------------------------------
-    def _forward_paged_typed(self, params, input_ids, kv_pool, state, tables,
-                             starts, row_slots, logit_rows, seg_from,
-                             moe_stats, rows_apart):
-        """:meth:`forward_paged` of a ``layer_types`` model: one-token rows
-        (T, 1); rows from ``seg_from`` on are chunk segments in tiles of
-        ``segment_tile`` rows, each tile consecutive tokens of one sequence
-        (its first row carries the table, the position and the slot; its
-        valid rows are a prefix), rows before it one a sequence; what is left
-        behind the last whole tile is padding. The groups
-        are scanned in turn with the pool and the slot arrays as the carry.
-        Returns (logits, pool, state[, counts])."""
-        cfg = self.config
-        T, S = input_ids.shape
-        if S != 1:
-            raise ValueError("a layer_types model's paged path takes "
-                             "one-token rows")
-        if state is None or row_slots is None:
-            raise ValueError("a layer_types model's paged path needs its "
-                             "slot arrays (init_state_cache) and row_slots")
-        cut = T if seg_from is None else seg_from
-        tile = self.segment_tile
-        end = cut + (T - cut) // tile * tile
-        live = tables[:, 0] > 0
-        rows = {"tables": tables, "starts": starts, "slots": row_slots,
-                "live": live, "cut": cut, "end": end, "tile": tile,
-                # a tile's valid rows are a prefix of it
-                "tile_counts": jnp.sum(live[cut:end].reshape(-1, tile), axis=1,
-                                       dtype=jnp.int32)}
-        with jax.named_scope("embed"):
-            x = self._embed(params, input_ids, starts[:, None], kv_pool.dtype)
-        # a group's scan carries its own slot array, and the pool if its
-        # layers keep KV blocks, and nothing else: an array carried through a
-        # scan that does not touch it, or sliced by a constant layer, was
-        # copied whole by XLA, once a dispatch
-        mixers = {"sparse_attn": self._sparse_mixer,
-                  "linear_attn": self._linear_mixer}
-        state = dict(state)
-        counts = jnp.zeros((len(self.step_counts),), jnp.int32)
-        pool_layer = 0                        # pool layers before this group
-        with jax.named_scope("kv_carry"):
-            for key, kind, n in cfg.type_runs:
-                held = {"own": state[key]}
-                if kind == "sparse_attn":
-                    held["pool"] = kv_pool
-
-                def body(carry, blk, mixer=mixers[kind], base=pool_layer):
-                    h, held, l, st = carry
-                    y, held, s = self._typed_layer(h, blk, mixer, held, l,
-                                                   base + l, rows, rows_apart)
-                    return (y, held, l + 1, st if s is None else st + s), None
-
-                (x, held, _, counts), _ = jax.lax.scan(
-                    body, (x, held, jnp.int32(0), counts), params[key])
-                state[key] = held["own"]
-                if kind == "sparse_attn":
-                    kv_pool, pool_layer = held["pool"], pool_layer + n
-        x_last = x[:, 0]
-        if logit_rows is not None:
-            x_last = x_last[logit_rows]
-        with jax.named_scope("lm_head_loss"):
-            lg = self._head(params, x_last[:, None])[:, 0]
-        out = (lg, kv_pool, state)
-        return out + (counts,) if moe_stats else out
-
-    def _typed_layer(self, x, blk, mixer, caches, layer, pool_layer, rows,
-                     rows_apart):
+    def _typed_layer(self, mixer, x, blk, caches, layer, pool_layer, step,
+                     experts=None):
         """One layer of a ``layer_types`` model on (T, 1, H): ``x + a M(N(x))``
         then ``+ a F(N(.))``, ``a`` the muP residual scale; ``mixer`` is the
         layer type's (:meth:`_sparse_mixer`, :meth:`_linear_mixer`), the
-        norms, the gate, ``wo`` and the feed-forward are shared. ``caches``:
-        the group's slot array (``own``) and, where its layers keep KV blocks,
-        the pool; ``layer`` counts the group's layers, ``pool_layer`` the
-        pool's. Returns (y, caches, counts or None)."""
+        norms, the gate, ``wo`` and the feed-forward are shared. Behind
+        ``mixer`` a kind of :meth:`forward_paged`."""
         from ..moe.layer import _gated_mlp
 
         cfg = self.config
@@ -2145,7 +2112,7 @@ class TransformerLM:
             h = _norm(x[:, 0], blk["ln1_scale"], None, "rmsnorm", cfg.norm_eps)
             q, k = heads("wq", "q_norm_scale"), heads("wk", "k_norm_scale")
             o, caches, counts = mixer(q, k, heads("wv"), blk, caches, layer,
-                                      pool_layer, rows, rows_apart)
+                                      pool_layer, step)
             o = o.reshape(T, nh * hd).astype(dt)
             if cfg.attn_output_gate:
                 o = o * jax.nn.sigmoid(once(h @ blk["w_ogate"].astype(dt)))
@@ -2156,8 +2123,7 @@ class TransformerLM:
                                    blk["w_down"])
         return x, caches, counts
 
-    def _sparse_mixer(self, q, k, v, blk, caches, layer, pool_layer, rows,
-                      rows_apart):
+    def _sparse_mixer(self, q, k, v, blk, caches, layer, pool_layer, step):
         """Block-sparse attention (ops/transformer/sparse_attention.py) of
         this step's rows: their keys and values into the pool, the compressed
         keys they complete into the sequence's slot, then each one-token row
@@ -2169,24 +2135,25 @@ class TransformerLM:
         cfg = self.config
         pool, ck = caches["pool"], caches["own"]
         spec, scale = cfg.sparse_spec, cfg.head_dim ** -0.5
-        tables, starts, slots = rows["tables"], rows["starts"], rows["slots"]
-        cut, end, tile, live = (rows[k] for k in ("cut", "end", "tile", "live"))
+        tables, starts, slots = step.tables, step.starts, step.slots
+        cut, end, tile = step.cut, step.end, step.tile
         T, nh, hd = q.shape
         tiles = slice(cut, end, tile)       # the first row of each tile
         with jax.named_scope("kv_write"):
-            pool = pa.write_rows(pool, pool_layer, tables, starts[:, None],
-                                 k[:, None], v[:, None], rows_apart=rows_apart)
+            pool = pa.write_rows(pool, pool_layer, tables, step.positions,
+                                 k[:, None], v[:, None],
+                                 rows_apart=step.rows_apart)
         with jax.named_scope("sparse_select"):
             ck = sa.write_keys(ck, pool, pool_layer, layer, tables[:cut],
                                slots[:cut], starts[:cut],
-                               live[:cut].astype(jnp.int32), spec, 1)
+                               step.live[:cut].astype(jnp.int32), spec, 1)
             if cut < end:
                 ck = sa.write_keys(ck, pool, pool_layer, layer, tables[tiles],
                                    slots[tiles], starts[tiles],
-                                   rows["tile_counts"], spec, tile)
-        n = jnp.where(live, starts + 1, 0)
+                                   step.tile_counts, spec, tile)
         o, counts = sa.decode_rows(q[:cut], pool, pool_layer, tables[:cut],
-                                   ck[layer, slots[:cut]], n[:cut], spec, scale)
+                                   ck[layer, slots[:cut]], step.limits[:cut],
+                                   spec, scale)
         if cut < end:
             o2 = sa.tile_rows(q[cut:end].reshape(-1, tile, nh, hd), pool,
                               pool_layer, tables[tiles],
@@ -2196,8 +2163,7 @@ class TransformerLM:
         o = jnp.pad(o, ((0, T - o.shape[0]), (0, 0), (0, 0)))
         return o, {"pool": pool, "own": ck}, counts
 
-    def _linear_mixer(self, q, k, v, blk, caches, layer, pool_layer, rows,
-                      rows_apart):
+    def _linear_mixer(self, q, k, v, blk, caches, layer, pool_layer, step):
         """Lightning attention (ops/transformer/linear_attention.py) of this
         step's rows on the sequences' state slots: rotary on q and k, the
         recurrence row by row for the one-token rows and in its blocked form
@@ -2206,12 +2172,12 @@ class TransformerLM:
 
         cfg = self.config
         lin = caches["own"]
-        starts, slots = rows["starts"], rows["slots"]
-        cut, end, tile = rows["cut"], rows["end"], rows["tile"]
+        starts, slots = step.starts, step.slots
+        cut, end, tile = step.cut, step.end, step.tile
         T, nh, hd = q.shape
         tiles = slice(cut, end, tile)
         q, k = (a[:, 0] for a in _rope(q[:, None], k[:, None],
-                                       starts[:, None], hd, cfg.rope_theta))
+                                       step.positions, hd, cfg.rope_theta))
         q = q * jnp.asarray(hd ** -0.5, q.dtype)
         fresh = starts == 0
         o, lin = la.decode_rows(lin, layer, slots[:cut], q[:cut], k[:cut],
@@ -2219,7 +2185,7 @@ class TransformerLM:
         if cut < end:
             tiled = [a[cut:end].reshape(-1, tile, nh, hd) for a in (q, k, v)]
             o2, lin = la.chunk_tiles(lin, layer, slots[tiles],
-                                     rows["tile_counts"], *tiled, fresh[tiles])
+                                     step.tile_counts, *tiled, fresh[tiles])
             o = jnp.concatenate([o, o2.reshape(-1, nh, hd)])
         o = jnp.pad(o, ((0, T - o.shape[0]), (0, 0), (0, 0)))
         with jax.named_scope("linear_attn"):

@@ -11,13 +11,34 @@ from deepspeed_tpu.inference.v2 import DSStateManager, InferenceEngineV2
 from deepspeed_tpu.models import build_model
 
 
-@pytest.fixture
+@pytest.fixture(scope="module")
 def setup():
     topo_mod.reset_topology()
     m = build_model("llama-tiny", vocab_size=128, hidden_size=64, num_layers=2,
                     num_heads=4, num_kv_heads=2, intermediate_size=128, max_seq_len=128)
     params = m.init_params(jax.random.PRNGKey(0))
     return m, params
+
+
+def dense_logits_of(m, params, width):
+    """``f(tokens)``: the full forward's logits behind ``tokens``. The model
+    is causal (and an expert layer that drops no token routes each alone), so
+    the padding behind the last token moves nothing at it, and every length
+    runs the one jitted program of ``width`` tokens (eagerly each length
+    compiled every operation anew: most of this file's time)."""
+    logits = jax.jit(m.logits)
+
+    def at_the_end(tokens):
+        ids = np.zeros((1, width), np.int32)
+        ids[0, :len(tokens)] = tokens
+        return logits(params, jnp.asarray(ids))[0, len(tokens) - 1]
+
+    return at_the_end
+
+
+@pytest.fixture(scope="module")
+def dense_logits(setup):
+    return dense_logits_of(*setup, width=64)    # the engines' max_seq_len
 
 
 class TestStateManager:
@@ -48,7 +69,7 @@ class TestContinuousBatching:
         with pytest.raises(ValueError, match="paged=False"):
             InferenceEngineV2(m, params, paged=False)
 
-    def test_staggered_requests_match_oracle(self, setup):
+    def test_staggered_requests_match_oracle(self, setup, dense_logits):
         m, params = setup
         eng = InferenceEngineV2(m, params, max_seqs=4, max_seq_len=64, prefill_chunk=16)
         rng = np.random.default_rng(0)
@@ -70,12 +91,10 @@ class TestContinuousBatching:
                 out.update(out3)
             out = eng.decode_step(toks)
         for u in (1, 2, 3):
-            cur = jnp.asarray(np.array(prompts[u])[None], jnp.int32)
-            n_gen = len(seqs[u]) - len(prompts[u])
-            for _ in range(n_gen):
-                nxt = int(jnp.argmax(m.logits(params, cur)[0, -1]))
-                cur = jnp.concatenate([cur, jnp.asarray([[nxt]], jnp.int32)], axis=1)
-            assert list(np.asarray(cur[0])) == seqs[u]
+            cur = list(prompts[u])
+            for _ in range(len(seqs[u]) - len(prompts[u])):
+                cur.append(int(jnp.argmax(dense_logits(cur))))
+            assert cur == seqs[u]
 
     def test_flush_frees_capacity(self, setup):
         m, params = setup
@@ -123,7 +142,7 @@ class TestPagedKV:
                 s = SequenceDescriptor(uid=3, slot=2)
                 mgr.ensure(s, 64)
 
-    def test_paged_matches_full_forward(self, setup):
+    def test_paged_matches_full_forward(self, setup, dense_logits):
         """A staggered prefill+decode workload through the pool produces the
         full forward's logits at every step (paged gather/scatter is
         exact)."""
@@ -137,8 +156,8 @@ class TestPagedKV:
         for _ in range(6):
             assert set(out) == {1, 2}
             for u in out:
-                ref = m.logits(params, jnp.asarray([seqs[u]], jnp.int32))[0, -1]
-                np.testing.assert_allclose(out[u], np.asarray(ref), atol=2e-4)
+                np.testing.assert_allclose(
+                    out[u], np.asarray(dense_logits(seqs[u])), atol=2e-4)
             toks = {u: int(np.argmax(out[u])) for u in out}
             for u, t in toks.items():
                 seqs[u].append(t)
@@ -198,7 +217,8 @@ class TestPagedKV:
             np.testing.assert_allclose(np.asarray(out[1]), np.asarray(ref[1]),
                                        atol=2e-4)
 
-    def test_ragged_one_program_mixed_arrivals_and_decodes(self, setup):
+    def test_ragged_one_program_mixed_arrivals_and_decodes(self, setup,
+                                                           dense_logits):
         """The FastGen core property: arrivals + decodes every step run through
         ONE compiled fixed-shape ragged program (no per-(n_seq, S) retraces),
         and the generated trajectories match the unbatched oracle."""
@@ -234,9 +254,7 @@ class TestPagedKV:
         for u in (1, 2, 3):
             n_prompt = len(prompts[u])
             for i, lg in enumerate(hist[u]):
-                prefix = seqs[u][: n_prompt + i]
-                ref = np.asarray(m.logits(
-                    params, jnp.asarray(np.array(prefix)[None], jnp.int32))[0, -1])
+                ref = np.asarray(dense_logits(seqs[u][: n_prompt + i]))
                 np.testing.assert_allclose(lg, ref, atol=2e-4)
 
     def test_can_schedule_consults_block_pool(self, setup):

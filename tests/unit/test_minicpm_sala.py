@@ -62,6 +62,26 @@ def engine(model, params, **kw):
                              **{**ENGINE, **kw})
 
 
+@pytest.fixture(scope="module")
+def spare_engines(model, params):
+    """``take(n)``: n engines of the default ``ENGINE`` with no sequence in
+    them, built once a module and flushed between uses (an engine compiles
+    its own programs: five tests' oracles share three). Their slots keep
+    what the last sequence left, which is what a slot is allowed to hold."""
+    built = []
+
+    def take(n):
+        while len(built) < n:
+            built.append(engine(model, params))
+        for eng in built[:n]:
+            for uid in list(eng.state.seqs):
+                eng.flush(uid)
+            assert eng.block_mgr.slots.in_use == 0
+        return built[:n]
+
+    return take
+
+
 # -- (a) lightning attention ------------------------------------------------
 
 def recurrence(q, k, v, state=None):
@@ -275,12 +295,13 @@ def test_the_block_cache_refuses_a_prefix_index_beside_state_slots():
     assert BlockedKVCache(8, 16, 4).slots is None
 
 
-def test_the_engine_refuses_what_a_state_cannot_do(model, params):
+def test_the_engine_refuses_what_a_state_cannot_do(model, params,
+                                                   spare_engines):
     with pytest.raises(ValueError, match="prefix_cache=False"):
         engine(model, params, prefix_cache=True)
     with pytest.raises(ValueError, match="decode_horizon=1"):
         engine(model, params, decode_horizon=4)
-    eng = engine(model, params)
+    eng, = spare_engines(1)
     with pytest.raises(EngineUsageError, match="state-slot"):
         eng._get_fused()
     with pytest.raises(EngineUsageError, match="state-slot"):
@@ -323,7 +344,8 @@ def decode(eng, uid, prompt, forced):
     return np.stack(rows)
 
 
-def test_a_second_sequence_never_reads_the_firsts_state(model, params):
+def test_a_second_sequence_never_reads_the_firsts_state(model, params,
+                                                        spare_engines):
     """Two sequences through one slot, one after the other, and a third that
     is preempted half way and recomputed: each equals a fresh engine's."""
     rng = np.random.default_rng(5)
@@ -344,7 +366,7 @@ def test_a_second_sequence_never_reads_the_firsts_state(model, params):
         second, decode(engine(model, params, max_seqs=1), 7, b, forced),
         rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(
-        first, decode(engine(model, params), 8, a, forced), rtol=1e-5,
+        first, decode(spare_engines(1)[0], 8, a, forced), rtol=1e-5,
         atol=1e-5)
     # preemption frees the slot; the victim recomputes from its prompt
     eng.preempt(2)
@@ -354,16 +376,15 @@ def test_a_second_sequence_never_reads_the_firsts_state(model, params):
     eng.block_mgr.check_invariants(eng.state.seqs.values())
 
 
-def test_rows_of_two_sequences_in_one_step_keep_their_own_state(model, params):
+def test_rows_of_two_sequences_in_one_step_keep_their_own_state(spare_engines):
     """A decode row and another sequence's prefill chunk in the same mixed
     step, and two decode rows in one round, against engines of their own."""
     rng = np.random.default_rng(6)
     a, b = (rng.integers(0, 256, n).tolist() for n in (80, 70))
-    eng = engine(model, params)
+    eng, solo_a, solo_b = spare_engines(3)
     eng.put([1], [a])
     eng.put([2], [b], max_steps=1)              # b's first chunk alone
     both = eng.put([1, 2], [[9], None])         # a decodes while b prefills
-    solo_a, solo_b = engine(model, params), engine(model, params)
     solo_a.put([1], [a])
     np.testing.assert_allclose(both[1], solo_a.decode_step({1: 9})[1],
                                rtol=1e-5, atol=1e-5)
@@ -399,11 +420,11 @@ def test_the_greedy_program_counts_chosen_and_context_blocks(model, params):
 
 def test_the_config_counts_both_mixers(model, params):
     cfg = model.config
-    assert cfg.type_runs == (("blocks_0", "sparse_attn", 1),
-                             ("blocks_1", "linear_attn", 2),
-                             ("blocks_2", "sparse_attn", 1))
-    assert TransformerLM.layer_groups(params) == ["blocks_0", "blocks_1",
-                                                  "blocks_2"]
+    assert cfg.type_runs == (("blocks_0", "sparse_attn", 1, 1),
+                             ("blocks_1", "linear_attn", 2, 0),
+                             ("blocks_2", "sparse_attn", 1, 1))
+    assert sorted(k for k in params if k.startswith("blocks")) == [
+        "blocks_0", "blocks_1", "blocks_2"]
     assert cfg.num_parameters == cfg.num_active_parameters \
         == sum(a.size for a in jax.tree.leaves(params))
     assert cfg.pool_layers == 2 and cfg.holds_state

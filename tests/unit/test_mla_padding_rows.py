@@ -116,7 +116,7 @@ def test_every_mla_decode_call_gets_limit_zero_for_a_padding_row(
     jaxpr = jax.make_jaxpr(run)(*padded).jaxpr
     per_body = model.config.pool_layers // model.config.num_layers
     scans = list(layer_scans(jaxpr))
-    assert len(scans) == len(list(TransformerLM.layer_groups(padded[0])))
+    assert len(scans) == len(model.config.type_runs)
     for scan in scans:
         body = scan.params["jaxpr"].jaxpr
         calls = [e for e in body.eqns if e.primitive.name == "pallas_call"

@@ -397,9 +397,11 @@ def test_pool_block_payload_round_trip():
                                   np.asarray(pool)[:, :, keep])
 
 
+@functools.lru_cache(maxsize=None)
 def _model_and_pool(L, kvh, hd, BS, NB, MAXB):
     """A tiny llama with 4 heads of ``hd``, its parameters and a pool of
-    random rows (so that a row nobody wrote is told from one that was)."""
+    random rows (so that a row nobody wrote is told from one that was).
+    Built once a shape: four tests step the same one."""
     import deepspeed_tpu.comm.topology as topo_mod
     from deepspeed_tpu.models import build_model
 
@@ -411,6 +413,14 @@ def _model_and_pool(L, kvh, hd, BS, NB, MAXB):
     before = jax.random.normal(jax.random.PRNGKey(1),
                                m.init_kv_pool(NB, BS, jnp.float32).shape)
     return m, params, before
+
+
+@functools.lru_cache(maxsize=None)
+def _forward(m, kernel, rows_apart=False):
+    """``m.forward_paged`` jitted, one program a shape for the tests that
+    step the same model. ``kernel`` only keys the cache: the caller has set
+    (or cleared) DSTPU_FORCE_PAGED_KERNEL, which is read as a call traces."""
+    return jax.jit(lambda *a: m.forward_paged(*a, rows_apart=rows_apart))
 
 
 def _mostly_padding_step(BS, NB, MAXB=3):
@@ -447,7 +457,7 @@ def test_forward_paged_writes_only_its_rows(monkeypatch, S):
             monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
         else:
             monkeypatch.delenv("DSTPU_FORCE_PAGED_KERNEL", raising=False)
-        return jax.jit(m.forward_paged)(params, ids, before, tables, starts)
+        return _forward(m, kernel)(params, ids, before, tables, starts)
 
     lg, after = run(kernel=False)
     written = np.zeros((NB, BS), bool)
@@ -481,7 +491,7 @@ def test_forward_paged_marks_padding_rows_dead(monkeypatch):
     BS, NB = 8, 12
     m, params, before, live, tables, starts, ids = _mostly_padding_step(BS, NB)
 
-    fwd = jax.jit(m.forward_paged)
+    fwd = _forward(m, True)
     lg, after = fwd(params, ids, before, jnp.asarray(tables), jnp.asarray(starts))
     lg_live, after_live = fwd(params, ids[live], before, jnp.asarray(tables[live]),
                               jnp.asarray(starts[live]))
@@ -665,9 +675,8 @@ def test_forward_paged_rows_apart_writes_the_live_rows_alone(monkeypatch):
     monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
     m, params, before, live, tables, starts, ids = _mostly_padding_step(8, 12)
     args = (params, ids, before, jnp.asarray(tables), jnp.asarray(starts))
-    lg_s, after_s = jax.jit(m.forward_paged)(*args)
-    lg_k, after_k = jax.jit(
-        lambda *a: m.forward_paged(*a, rows_apart=True))(*args)
+    lg_s, after_s = _forward(m, True)(*args)
+    lg_k, after_k = _forward(m, True, rows_apart=True)(*args)
     k, s, b = np.asarray(after_k), np.asarray(after_s), np.asarray(before)
     np.testing.assert_array_equal(k[:, :, 0], b[:, :, 0])
     assert (s[:, :, 0] != b[:, :, 0]).any()

@@ -164,9 +164,7 @@ def scanned_leaves(model, args, kw):
     params = args[0]
     jaxpr = jax.make_jaxpr(
         lambda *a: model.forward_paged(*a, **kw))(*args).jaxpr
-    groups = [key for key, _, _ in model.config.type_runs] \
-        if model.config.layer_types is not None \
-        else list(TransformerLM.layer_groups(params))
+    groups = [key for key, *_ in model.config.type_runs]
     scans = list(layer_scans(jaxpr))
     assert len(scans) == len(groups), (len(scans), groups)
     out = []
