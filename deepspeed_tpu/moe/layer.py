@@ -75,11 +75,59 @@ def _gated_mlp(x, w_gate, w_up, w_down):
             * (x @ w_up.astype(x.dtype))) @ w_down.astype(x.dtype)
 
 
+#: The most rows one tile of the grouped product holds; a step of no more
+#: rows than this IS one tile (:func:`grouped_experts`).
+EXPERT_TILE_ROWS = 128
+
+
 def _expert_tile(pairs: int, experts: int) -> int:
     """Rows of one tile of the grouped product: about an expert's even share
-    of ``pairs``, a power of two in [8, 128]."""
+    of ``pairs``, a power of two in [8, ``EXPERT_TILE_ROWS``]."""
     share = max(1, pairs // max(experts, 1))
-    return min(128, max(8, 1 << (share - 1).bit_length()))
+    return min(EXPERT_TILE_ROWS, max(8, 1 << (share - 1).bit_length()))
+
+
+def _expert_matrices(ws, e, layer):
+    """Expert ``e``'s (up, gate, down) of ``ws`` = (wi, w_gate, w_down);
+    stacked leaves are indexed by (``layer``, e) where they lie."""
+    if layer is None:
+        return tuple(a[e] for a in ws)
+    return tuple(
+        jax.lax.dynamic_slice(a, (layer, e, 0, 0), (1, 1) + a.shape[2:])
+        .reshape(a.shape[2:]) for a in ws)
+
+
+def _touched_sum(live_trips_only, x, c, on, touched, n_live, ws, layer):
+    """float32 (T, H): the sum over the first ``n_live`` experts ``e`` of
+    ``touched`` of ``c[:, e] * expert_e(x)``, every row through every such
+    expert. The loop runs the live trips alone, or (differentiable) is a
+    scan over all of ``touched`` whose trips past ``n_live`` hand ``y`` on."""
+    def trip(i, y):
+        e = jax.lax.dynamic_index_in_dim(touched, i, keepdims=False)
+        up, gate, down = _expert_matrices(ws, e, layer)
+        out = _gated_mlp(x, gate, up, down).astype(jnp.float32)
+        # a row that did not choose e adds nothing, whatever came out of e's
+        # product for it (0 * inf is not 0)
+        return y + jnp.where(jax.lax.dynamic_slice_in_dim(on, e, 1, axis=1),
+                             jax.lax.dynamic_slice_in_dim(c, e, 1, axis=1)
+                             * out, 0.0)
+
+    y = jnp.zeros(x.shape, jnp.float32)
+    if live_trips_only:
+        return jax.lax.fori_loop(0, n_live, trip, y)
+    return jax.lax.scan(
+        lambda y, i: (jax.lax.cond(i < n_live, partial(trip, i),
+                                   lambda y: y, y), None),
+        y, jnp.arange(touched.shape[0], dtype=jnp.int32))[0]
+
+
+# forward: a loop of n_live trips (on the chip a dead trip of the scan costs
+# 1.4-2.3 us and its cond 5-13 us a LIVE trip, the carry copied in and out);
+# backward: the scan's, which reverse mode can differentiate
+_touched_experts = jax.custom_vjp(partial(_touched_sum, True))
+_touched_experts.defvjp(
+    lambda *args: (_touched_sum(True, *args), args),
+    lambda args, g: jax.vjp(partial(_touched_sum, False), *args)[1](g))
 
 
 def grouped_experts(x, chosen, weights, wi, w_gate, w_down, *, first: int = 0,
@@ -95,19 +143,46 @@ def grouped_experts(x, chosen, weights, wi, w_gate, w_down, *, first: int = 0,
     (y (T, H) in x's dtype, (rows, rows_max) int32: the (token, choice) pairs
     that landed on held experts, and the busiest expert's).
 
+    One algorithm: each expert's matrices times the rows that chose it, a
+    tile of rows at a time, the weighted results summed per token in float32.
     The pairs that land here are sorted by expert and laid into a row buffer
     in which every expert's rows start on a tile boundary, so that each tile
     of ``tile`` rows belongs to one expert: one pass over the tiles multiplies
     each by its expert's matrices (a tile no pair reached is skipped, so the
     cost follows the rows that landed, and an expert no token chose is never
     read). The weighted rows are gathered back per (token, choice) and
-    summed in float32. Differentiable (a scan of conds), so a model of held
-    experts trains through it; the GShard models' training still goes through
-    :func:`routed_ffn`'s one-hot dispatch."""
+    summed in float32.
+
+    Where one tile holds the whole step (``T <= EXPERT_TILE_ROWS``: a decode
+    round) the tiling degenerates: the step's rows ARE each touched expert's
+    tile, so nothing is sorted over the pairs, laid into a buffer or
+    gathered back. One loop over the touched experts multiplies each one's
+    matrices by all ``T`` rows (the weights' bytes set what such a product
+    costs, not its rows) and adds the result at the (T, E) combine weight,
+    masked where a row did not choose the expert. The shape alone decides.
+
+    Differentiable either way, so a model of held experts trains through
+    it; the GShard models' training still goes through :func:`routed_ffn`'s
+    one-hot dispatch."""
     T, H = x.shape
+    ws = (wi, w_gate, w_down)
+    E = wi.shape[0] if layer is None else wi.shape[1]
+    if T <= EXPERT_TILE_ROWS:
+        with jax.named_scope("moe_route"):
+            hit = (chosen.astype(jnp.int32)[:, :, None] - first
+                   == jnp.arange(E))                               # (T, k, E)
+            # a token that picks one expert twice adds both weights
+            c = jnp.sum(jnp.where(hit, weights.astype(jnp.float32)[:, :, None],
+                                  0.0), axis=1)                    # (T, E)
+            counts = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)    # (E,)
+            touched = jnp.argsort(counts == 0, stable=True).astype(jnp.int32)
+        with jax.named_scope("moe_experts"):
+            y = _touched_experts(x, c, jnp.any(hit, axis=1), touched,
+                                 jnp.sum(counts > 0, dtype=jnp.int32), ws,
+                                 layer)
+        return y.astype(x.dtype), (jnp.sum(counts), jnp.max(counts))
+
     k = chosen.shape[1]
-    stacked = layer is not None
-    E = wi.shape[1] if stacked else wi.shape[0]
     pairs = T * k
     k_here = min(k, E)
     tile = _expert_tile(T * k_here, E)
@@ -139,18 +214,11 @@ def grouped_experts(x, chosen, weights, wi, w_gate, w_down, *, first: int = 0,
         tile_ec = jnp.minimum(tile_e, E - 1)
         live = (tile_e < E) & (tile_row0 < (row0 + counts)[tile_ec])
 
-    def matrices(e):
-        if not stacked:
-            return wi[e], w_gate[e], w_down[e]
-        return tuple(
-            jax.lax.dynamic_slice(a, (layer, e, 0, 0), (1, 1) + a.shape[2:])
-            .reshape(a.shape[2:]) for a in (wi, w_gate, w_down))
-
     def one_tile(_, t):
         e, is_live, xt = t
 
         def run(xt):
-            up, gate, down = matrices(e)
+            up, gate, down = _expert_matrices(ws, e, layer)
             return _gated_mlp(xt, gate, up, down)
 
         return None, jax.lax.cond(is_live, run, jnp.zeros_like, xt)
@@ -179,7 +247,8 @@ def held_experts_ffn(x, wg, bias, wi, w_gate, w_down, shared=None, *, k: int,
     outputs in float32 (``router``: :func:`group_limited_gating` or
     :func:`softmax_topk_gating`), the layer computes its
     own experts' part for the tokens routed to them
-    (:func:`grouped_experts`; ``first`` is the router output of the first
+    (:func:`grouped_experts`, whose form follows from T: a decode round's
+    rows are one tile; ``first`` is the router output of the first
     held expert) and adds the ``shared`` expert ((w_gate, w_up, w_down),
     which every chip of the deployment computes alike). What the absent
     experts would have added is left out: there is no exchange here and

@@ -279,7 +279,8 @@ def serving_model(head_dim, layers):
         parallel_block=True, tie_embeddings=False, qkv_bias=True))
 
 
-def serving_avals(one_chip, model, rows):
+def serving_avals(one_chip, model, rows, blocks=CELL_NB, block_size=BS,
+                  tables=MAXB):
     """(params, pool, tables, starts) of the cell's engine, as shapes."""
     def on_chip(tree, dtype=None):
         return jax.tree.map(
@@ -288,8 +289,8 @@ def serving_avals(one_chip, model, rows):
     params = on_chip(jax.eval_shape(model.init_params, jax.random.PRNGKey(0)),
                      jnp.bfloat16)
     pool = on_chip(jax.eval_shape(
-        lambda: model.init_kv_pool(CELL_NB, BS, dtype=jnp.bfloat16)))
-    return (params, pool, aval(one_chip, (rows, MAXB), jnp.int32),
+        lambda: model.init_kv_pool(blocks, block_size, dtype=jnp.bfloat16)))
+    return (params, pool, aval(one_chip, (rows, tables), jnp.int32),
             aval(one_chip, (rows,), jnp.int32))
 
 
@@ -357,6 +358,50 @@ def test_multi_step_programs_move_no_pool(one_chip, no_compile_cache, as_tpu,
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pool, toks, tables, starts).compile()
     assert_moves_no_pool(compiled, pool, layers)
+
+
+#: (configuration, traffic) -> ``temp_size_in_bytes`` of the decode round of
+#: the configuration cut to two layers, at commit d93b478 (PR 49); PR 52's
+#: one-tile form read 3,113,472 and 4,086,272
+PARENT_ROUND_TEMPS = {
+    ("gigachat3.1-702b-a36b", "serve-longdoc"): 9_792_000,
+    ("longcat-flash-chat", "serve-longout"): 29_088_768,
+}
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT_ROUND_TEMPS), ids=lambda c: c[0])
+def test_an_expert_models_round_holds_no_row_buffer(one_chip, no_compile_cache,
+                                                    as_tpu, cell):
+    """The decode round of each latent cell at the cell's own shapes (32 and
+    96 one-token rows, every width as published, two layers): it compiles,
+    its expert layer sorts no (row, pick) pairs, and without the row buffer
+    its temporaries are no larger than they were with it."""
+    from benchmark.harness.cell import load_json
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    model_cfg = load_json("configs", cell[0] + ".json")["model"]
+    engine = load_json("traffic", cell[1] + ".json")["engine"]
+    model = TransformerLM(TransformerConfig(**{**model_cfg, "num_layers": 2}))
+    rows = engine["max_seqs"]
+    params, pool, tables, starts = serving_avals(
+        one_chip, model, rows, engine["num_blocks"], engine["block_size"],
+        engine["max_seq_len"] // engine["block_size"])
+
+    def program(params, ids, pool, tables, starts, logit_rows):
+        return model.forward_paged(params, ids, pool, tables, starts,
+                                   logit_rows=logit_rows, moe_stats=True)
+
+    compiled = jax.jit(program, donate_argnums=(2,)).lower(
+        params, aval(one_chip, (rows, 1), jnp.int32), pool, tables, starts,
+        aval(one_chip, (rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert sorts and not any(f"[{rows * model.config.moe_top_k}]" in line
+                             for line in sorts), sorts
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps <= PARENT_ROUND_TEMPS[cell], temps
 
 
 def test_flash_refusal_is_loud(as_tpu, monkeypatch):
