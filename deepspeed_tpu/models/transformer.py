@@ -1172,28 +1172,42 @@ class TransformerLM:
                 if step is None:
                     step = PagedStep.of(tables, positions[:, 0])
                 BS = pool.shape[3]
-                with jax.named_scope("kv_write"):
-                    pool = pa.write_rows(pool, layer, tables, positions, kk, v,
-                                         rows_apart=step.rows_apart)
-                new_kv = pool
+                # NOTE: evaluated at TRACE time — the env override (used by tests
+                # to exercise this branch in interpret mode) and set_default_impl
+                # must be set before the engine compiles its decode program
+                want_kernel = S == 1 and pa.kernels_wanted()
+                # what the kernel documents as unsupported; each gives way to the
+                # gather path below, and says so as the program is traced
+                gaps = [why for bad, why in (
+                    (cfg.pos_embedding == "alibi", "ALiBi bias"),
+                    (bool(cfg.logit_softcap), "logit softcap"),
+                    (hd not in (64, 128, 256), f"head_dim {hd}"),
+                    (BS % 8 != 0, f"block size {BS} % 8 != 0"),
+                ) if bad]
+                use_kernel = want_kernel and not gaps
+                if want_kernel and gaps:
+                    logger.warning("paged decode takes the XLA gather path, not "
+                                   f"the Pallas kernel: {', '.join(gaps)}")
+                # a decode round (its rows apart) on a pool that takes the
+                # live rows' write: the attention kernel writes them itself
+                fold = (use_kernel and step.rows_apart
+                        and pa.writes_live_rows(pool))
+                if not fold:
+                    with jax.named_scope("kv_write"):
+                        pool = pa.write_rows(pool, layer, tables, positions,
+                                             kk, v, rows_apart=step.rows_apart)
                 with jax.named_scope("paged_attn"):
-                    # NOTE: evaluated at TRACE time — the env override (used by tests
-                    # to exercise this branch in interpret mode) and set_default_impl
-                    # must be set before the engine compiles its decode program
-                    want_kernel = S == 1 and pa.kernels_wanted()
-                    # what the kernel documents as unsupported; each gives way to the
-                    # gather path below, and says so as the program is traced
-                    gaps = [why for bad, why in (
-                        (cfg.pos_embedding == "alibi", "ALiBi bias"),
-                        (bool(cfg.logit_softcap), "logit softcap"),
-                        (hd not in (64, 128, 256), f"head_dim {hd}"),
-                        (BS % 8 != 0, f"block size {BS} % 8 != 0"),
-                    ) if bad]
-                    use_kernel = want_kernel and not gaps
-                    if want_kernel and gaps:
-                        logger.warning("paged decode takes the XLA gather path, not "
-                                       f"the Pallas kernel: {', '.join(gaps)}")
-                    if use_kernel:
+                    if fold:
+                        # ONE call a layer: q, the new k and v and the result
+                        # as (rows, heads * hd), where the products leave and
+                        # ``wo`` takes them; the kernel sets each live row's
+                        # [k | v] in the block it fetches last and writes the
+                        # sub-tile back (paged_attention.py)
+                        attn_out, pool = pa.paged_decode(
+                            q.reshape(B, nh * hd), pool, layer, tables,
+                            step.limits, new_rows=(kk.reshape(B, kvh * hd),
+                                                   v.reshape(B, kvh * hd)))
+                    elif use_kernel:
                         # Pallas paged decode: the kernel streams this layer's
                         # blocks out of the stacked pool by layer index and block
                         # table — no slice, no gathered copy (paged_attention.py).
@@ -1213,6 +1227,7 @@ class TransformerLM:
                             q, gk, gv, causal=False, num_kv_groups=nh // kvh,
                             softcap=cfg.logit_softcap, bias=bias,
                         )
+                new_kv = pool
             elif kv_cache is not None:
                 ck, cv = kv_cache  # (B, T, kvh, hd)
                 ck = jax.lax.dynamic_update_slice(ck, kk.astype(ck.dtype), (0, cache_index, 0, 0))

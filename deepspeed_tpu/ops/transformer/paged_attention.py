@@ -30,6 +30,15 @@ with the layer among its indices (:func:`gather_context`); the engine's block
 programs (COW, tier demote/promote, swap) use :func:`get_block` /
 :func:`set_block`. No program slices a layer out of the pool.
 
+WHO WRITES A DECODE ROUND'S ROWS (a step whose rows are apart, on a pool
+:func:`writes_live_rows` takes): in a full-attention layer :func:`paged_decode`
+itself, handed the round's new rows: it sets each live row in the block it
+fetches last and writes the sub-tile back, one call a layer. The latent pools'
+rounds and the sparse layers' (their attention reads a view of chosen blocks)
+go through :func:`write_rows` to :func:`kv_write`, and every other step (a
+mixed step's chunk rows are neighbours in one block) through
+:func:`write_rows`' scatter.
+
 The kernel is decode only (one query token per row; a prefill chunk is rows of
 one token each); segments longer than one token keep the gather path. A cell
 of its grid is several rows of the step (:func:`rows_per_cell`, from the row
@@ -102,7 +111,10 @@ def write_rows(pool, layer, tables, positions, k, v, *, rows_apart=False):
     - :func:`kv_write`, where the caller says ``rows_apart`` (static) and
       :func:`writes_live_rows` takes the pool's shape: one copy a LIVE row
       over all its kv heads, nothing for a row whose block is the trash
-      block. The kernel cannot write one token's row alone (Mosaic slices
+      block. (A full-attention layer's decode round does not come here: the
+      model hands such rows to :func:`paged_decode`, which writes them the
+      same way from the block it has fetched.) The kernel cannot write one
+      token's row alone (Mosaic slices
       the token dimension of an HBM array only by whole tiles of 8 rows, and
       at bfloat16 two rows even share a 32-bit sublane); it reads the
       aligned sub-tile of :data:`SUB_TILE` tokens that holds the row, sets
@@ -244,11 +256,16 @@ SUB_TILE = 8
 
 
 def writes_live_rows(pool) -> bool:
-    """Does :func:`write_rows` take :func:`kv_write` for a step whose rows
-    are apart? Where the kernels are wanted and the pool's blocks are whole
-    sub-tiles of whole 128-lane rows."""
-    _, _, _, BS, row = pool.shape
-    return kernels_wanted() and BS % SUB_TILE == 0 and row % 128 == 0
+    """Are the rows of a step whose rows are apart written by the kernels
+    (:func:`kv_write` from :func:`write_rows`, :func:`paged_decode` handed
+    the new rows), the live rows alone? Where the kernels are wanted, the
+    pool's blocks are whole sub-tiles of whole 128-lane rows, and a cell's
+    heads are whole lane tiles of the model's ``(rows, heads * hd)`` arrays
+    (not so only where one kv head of 64 is all a cell can buffer)."""
+    _, kvh, _, BS, row = pool.shape
+    hpc = heads_per_cell(pool)
+    return (kernels_wanted() and BS % SUB_TILE == 0 and row % 128 == 0
+            and (hpc == kvh or hpc * (row // 2) % 128 == 0))
 
 
 def _write_slots(pool) -> int:
@@ -327,6 +344,9 @@ def kv_write(pool, layer, blk, off, kv):
     to the result, so a donated, carried buffer is written where it lies,
     and a row whose block is trash block 0 writes nothing.
 
+    Its callers are the rounds of the latent pools and of the sparse
+    layers; a full-attention layer's round is written by :func:`paged_decode`.
+
     pool (L, kvh, NB, BS, row); layer: int32 scalar (traced or not); blk,
     off (B,) int32: each row's pool block and its token's offset in it; kv
     (B, kvh, row): the rows. NO TWO LIVE ROWS MAY NAME THE SAME BLOCK: a
@@ -362,8 +382,8 @@ def kv_write(pool, layer, blk, off, kv):
       kv.astype(pool.dtype)[:, :, None, :], pool)
 
 
-def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
-                   buf, sem, live_ref, *, block_size, scale, bpt):
+def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
+                   bpt, hd, write):
     """Grid (B / rpc, kvh / hpc): ONE cell per ``rpc`` rows of the step
     (:func:`rows_per_cell`) and group of ``hpc`` kv heads (all of them
     wherever the buffer fits: :func:`heads_per_cell`). The cell first sorts
@@ -391,8 +411,32 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
     HBM DMA slice. A trip's blocks lie one behind the other along the
     buffer's token axis; where the row's blocks end inside a trip, its last
     block is fetched again in their place (finite values, masked like the
-    tokens past ``lens`` in any last block)."""
-    rpc, hpc, g, hd = q_ref.shape
+    tokens past ``lens`` in any last block).
+
+    ``write`` (static: the caller handed the step's new rows) is the decode
+    round's form. q, the new k and v and the result are blocks of the
+    model's ``(rows, heads * hd)`` arrays, staged through float32 scratch
+    once a cell with a live row (Mosaic reads no single row out of a packed
+    bfloat16 block) and cut into heads a live row at a time. The pool is the
+    call's aliased OUTPUT, read and written where it lies: a live row's last
+    trip holds the block of its new token (``lens`` counts it), so once that
+    trip has arrived the row ``[k | v]`` of every head of the group is set
+    in the buffer at token ``(lens - 1) % BS`` of that block, before the
+    trip's products, and the aligned :data:`SUB_TILE` tokens around it go
+    back to ``pool[layer, heads, block]`` by one async copy out of ``wbuf``,
+    which is waited for before the next live row fills ``wbuf`` again and at
+    the end of the cell. The copies of the last block that fill the trip
+    behind it have arrived before the store starts and are masked; no other
+    row of the step names that block (the caller's promise), and a dead row
+    starts nothing."""
+    if write:
+        (q_ref, k_ref, v_ref, _, o_ref, pool_ref, buf, sem, live_ref,
+         q32, k32, v32, o32, wbuf, wsem) = refs
+        rpc, hpc = q_ref.shape[0], k_ref.shape[1] // hd
+        g = q_ref.shape[1] // (hpc * hd)
+    else:
+        q_ref, pool_ref, o_ref, buf, sem, live_ref = refs
+        rpc, hpc, g, _ = q_ref.shape
     row0 = pl.program_id(0) * rpc
     layer = layer_ref[0]
     heads = pl.ds(pl.program_id(1) * hpc, hpc)
@@ -418,6 +462,43 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
             buf.at[slot, :, pl.ds(i * block_size, block_size)],
             sem.at[slot, i]) for i in range(bpt)]
 
+    def store(b):
+        """``wbuf`` to the sub-tile of row ``b``'s new token."""
+        start = (lens_ref[b] - 1) % block_size // SUB_TILE * SUB_TILE
+        return pltpu.make_async_copy(
+            wbuf, pool_ref.at[layer, heads, tables_ref[b, blocks(b) - 1],
+                              pl.ds(pl.multiple_of(start, SUB_TILE), SUB_TILE)],
+            wsem.at[0])
+
+    def heads_of(ref, r, n):
+        """Row ``r`` of a staged ``(rpc, n * hd)`` block, a head a piece."""
+        flat = ref[pl.ds(r, 1), :]
+        return [flat[:, i * hd:(i + 1) * hd] for i in range(n)]
+
+    def set_row(k, r, j, slot):
+        """The new ``[k | v]`` row of the cell's ``k``-th live row ``r`` into
+        its last block, which trip ``j`` has brought into buffer ``slot``,
+        and the sub-tile around it on its way back to the pool."""
+        b = row0 + r
+        tok = ((blocks(b) - 1 - j * bpt) * block_size
+               + (lens_ref[b] - 1) % block_size)
+        at = pl.multiple_of(tok // SUB_TILE * SUB_TILE, SUB_TILE)
+        new = jnp.stack([jnp.concatenate(kv, axis=-1) for kv in zip(
+            heads_of(k32, r, hpc), heads_of(v32, r, hpc))])  # (hpc, 1, 2*hd)
+        # through float32, which every bfloat16 survives unchanged: the
+        # select is on whole 32-bit sublanes
+        tile = buf[slot, :, pl.ds(at, SUB_TILE), :].astype(jnp.float32)
+        token = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+        tile = jnp.where(token == tok - at, new, tile).astype(buf.dtype)
+        buf[slot, :, pl.ds(at, SUB_TILE), :] = tile
+
+        @pl.when(k > 0)
+        def _wbuf_is_free():
+            store(b).wait()   # the row's before: same bytes, same semaphore
+
+        wbuf[...] = tile
+        store(b).start()
+
     @pl.when(n_live > 0)
     def _cold():
         for c in copies(live_ref[0], 0, 0):
@@ -434,7 +515,12 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
         seq_len = lens_ref[row0 + r]
         ntrip = (blocks(row0 + r) + bpt - 1) // bpt
         r_next = live_ref[jnp.minimum(k + 1, rpc - 1)]
-        q = q_ref[r].astype(jnp.float32) * scale  # (hpc, g, hd)
+        if write:
+            qh = heads_of(q32, r, hpc * g)
+            q = jnp.stack([jnp.concatenate(qh[i * g:(i + 1) * g], axis=0)
+                           for i in range(hpc)]) * scale  # (hpc, g, hd)
+        else:
+            q = q_ref[r].astype(jnp.float32) * scale  # (hpc, g, hd)
 
         def body(j, carry):
             m, l, acc = carry
@@ -452,6 +538,11 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
 
             for c in copies(r, j, slot):
                 c.wait()
+            if write:
+                @pl.when(j + 1 == ntrip)
+                def _new_token():
+                    set_row(k, r, j, slot)
+
             kv = buf[slot].astype(jnp.float32)  # (hpc, bpt*BS, 2*hd)
             s = jax.lax.dot_general(          # every head of the group: (hpc, g, T)
                 q, kv[..., :hd], (((2,), (2,)), ((0,), (0,))),
@@ -472,15 +563,34 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, q_ref, pool_ref, o_ref,
         l0 = jnp.zeros((hpc, g, 1), jnp.float32)
         acc0 = jnp.zeros((hpc, g, hd), jnp.float32)
         _, l, acc = jax.lax.fori_loop(0, ntrip, body, (m0, l0, acc0))
-        o_ref[r] = (acc / l).astype(o_ref.dtype)  # a live row sees a key
+        out = acc / l                              # a live row sees a key
+        if write:
+            o32[pl.ds(r, 1), :] = jnp.concatenate(
+                [out[i, n:n + 1] for i in range(hpc) for n in range(g)],
+                axis=-1)
+        else:
+            o_ref[r] = out.astype(o_ref.dtype)
         return jax.lax.rem(slot0 + ntrip, 2)
 
-    jax.lax.fori_loop(0, n_live, attend, 0)
+    if not write:
+        jax.lax.fori_loop(0, n_live, attend, 0)
+        return
+
+    @pl.when(n_live > 0)
+    def _live_cell():
+        for staged, ref in ((q32, q_ref), (k32, k_ref), (v32, v_ref)):
+            staged[...] = ref[...].astype(jnp.float32)
+        o32[...] = jnp.zeros(o32.shape, o32.dtype)
+        jax.lax.fori_loop(0, n_live, attend, 0)
+        o_ref[...] = o32[...].astype(o_ref.dtype)
+        store(row0 + live_ref[n_live - 1]).wait()
 
 
-def paged_decode(q, pool, layer, tables, lens, *, scale=None):
+def paged_decode(q, pool, layer, tables, lens, *, scale=None, new_rows=None):
     """One-token decode attention of layer ``layer`` against the stacked pool,
-    read where it lies.
+    read where it lies; with ``new_rows``, the decode round's whole attention
+    sublayer in one call: the rows' new keys and values written into the pool
+    on the way.
 
     q: (B, nh, hd); pool: (L, kvh, NB, BS, 2*hd) as :func:`init_pool` lays it
     out, left in HBM whole; layer: int32 scalar (traced or not), a
@@ -488,6 +598,19 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None):
     is ever sliced out; tables: (B, MAXB) int32 pool block ids (0-padded);
     lens: (B,) int32 valid token counts (position + 1). Returns (B, nh, hd)
     in q's dtype.
+
+    ``new_rows``: (k, v), each (B, kvh * hd), the step's new token of every
+    row as the projections leave it; ``lens`` counts it. q is then
+    (B, nh * hd) too, the result comes back in that layout with the pool,
+    ``(out, pool)``, and the pool is aliased from input to output (donated
+    and carried, it is written where it lies). A live row's ``[k | v]`` lands
+    at ``pool[layer, :, block, (lens - 1) % BS]`` of its last block, in the
+    block the kernel has fetched to attend over, and the :data:`SUB_TILE`
+    tokens around it are written back: what :func:`kv_write` followed by the
+    read-only call gives, bit for bit, pool and result. As for
+    :func:`kv_write`, NO TWO LIVE ROWS MAY NAME THE SAME LAST BLOCK
+    (``rows_apart``, the step's builder's promise) and the pool must be one
+    that :func:`writes_live_rows` takes; a dead row writes nothing.
 
     The grid is ``(B / rpc, kvh / hpc)``: a cell takes ``rpc`` rows
     (:func:`rows_per_cell`, from ``B`` alone) over ``hpc`` kv heads.
@@ -500,35 +623,53 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None):
     deadness out of the table, and a row with ``lens`` 1 and an all-zero
     table attends to the trash block's first token. A live row's output is
     bit for bit what the row gives alone, whatever shares its cell."""
-    B, nh, hd = q.shape
-    _, kvh, _, BS, _ = pool.shape
+    write = new_rows is not None
+    _, kvh, _, BS, row = pool.shape
+    B, hd = q.shape[0], row // 2 if write else q.shape[2]
+    nh = q.shape[1] // hd if write else q.shape[1]
     g, hpc, bpt = nh // kvh, heads_per_cell(pool), blocks_per_trip(pool)
     rpc = rows_per_cell(B)
-    block = pl.BlockSpec((rpc, hpc, g, hd), lambda b, c, *_: (b, c, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # layer, tables, lens
-        grid=(B // rpc, kvh // hpc),
-        in_specs=[block,
-                  pl.BlockSpec(memory_space=pl.ANY)],  # the pool stays in HBM
-        out_specs=block,
-        scratch_shapes=[
-            pltpu.VMEM((2, hpc, bpt * BS, pool.shape[4]), pool.dtype),
-            pltpu.SemaphoreType.DMA((2, bpt)),     # the double buffer's
-            pltpu.SMEM((rpc,), jnp.int32),         # the cell's live rows
-        ],
-    )
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)     # the pool stays in HBM
+    scratch = [
+        pltpu.VMEM((2, hpc, bpt * BS, row), pool.dtype),
+        pltpu.SemaphoreType.DMA((2, bpt)),     # the double buffer's
+        pltpu.SMEM((rpc,), jnp.int32),         # the cell's live rows
+    ]
+    if write:
+        def lanes(n):
+            return pl.BlockSpec((rpc, n * hd), lambda b, c, *_: (b, c))
+
+        operands = (q, *(a.astype(pool.dtype) for a in new_rows), pool)
+        in_specs = [lanes(hpc * g), lanes(hpc), lanes(hpc), in_hbm]
+        out_specs = [lanes(hpc * g), in_hbm]
+        out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype),
+                     jax.ShapeDtypeStruct(pool.shape, pool.dtype)]
+        scratch += [pltpu.VMEM((rpc, hpc * n * hd), jnp.float32)
+                    for n in (g, 1, 1, g)]       # q, k, v and the result
+        scratch += [pltpu.VMEM((hpc, SUB_TILE, row), pool.dtype),
+                    pltpu.SemaphoreType.DMA((1,))]
+    else:
+        block = pl.BlockSpec((rpc, hpc, g, hd), lambda b, c, *_: (b, c, 0, 0))
+        operands = (q.reshape(B, kvh, g, hd), pool)
+        in_specs, out_specs = [block, in_hbm], block
+        out_shape = jax.ShapeDtypeStruct((B, kvh, g, hd), q.dtype)
     out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_size=BS, bpt=bpt,
+        functools.partial(_decode_kernel, block_size=BS, bpt=bpt, hd=hd,
+                          write=write,
                           scale=scale if scale is not None else hd ** -0.5),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kvh, g, hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # layer, tables, lens
+            grid=(B // rpc, kvh // hpc),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        # the pool, counting the scalar operands
+        input_output_aliases={6: 1} if write else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
         name="paged_decode",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), tables, lens,
-      q.reshape(B, kvh, g, hd), pool)
-    return out.reshape(B, nh, hd)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables, lens, *operands)
+    return tuple(out) if write else out.reshape(B, nh, hd)
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lens, *, scale=None):

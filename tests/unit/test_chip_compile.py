@@ -221,6 +221,38 @@ def test_kv_write_compiles(one_chip, no_compile_cache, as_tpu, shape, rows,
         "the donated pool is no longer written in place"
 
 
+@pytest.mark.parametrize("shape,rows,g,dtype", [
+    ((24, NH, CELL_NB, BS, 2 * HD), CELL_SEQS, 1, jnp.bfloat16),  # serve-chat
+    ((24, NH, 416, BS, 256), CELL_SEQS, 1, jnp.bfloat16),  # Pythia-1.4B
+    ((2, 8, NB, BS, 512), 256, 1, jnp.bfloat16),    # heads of 256
+    ((4, 2, NB, 16, 2 * HD), 8, 4, jnp.bfloat16),   # grouped queries
+    ((4, 4, NB, 16, 256), 8, 4, jnp.float32),       # a float32 pool
+])
+def test_paged_decode_with_new_rows_compiles(one_chip, no_compile_cache,
+                                             as_tpu, shape, rows, g, dtype):
+    """The decode round's one call: Mosaic takes the heads cut out of the
+    lane layout, the row set in the fetched block and the sub-tile's store,
+    and the donated pool is written where it lies."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    pool = aval(one_chip, shape, dtype)
+    assert pa.writes_live_rows(pool)
+    kvh, hd = shape[1], shape[4] // 2
+
+    def call(pool, q, k, v, layer, tables, lens):
+        return pa.paged_decode(q, pool, layer, tables, lens, new_rows=(k, v))
+
+    lanes = lambda n: aval(one_chip, (rows, n * hd), jnp.bfloat16)
+    i32 = functools.partial(aval, one_chip, dtype=jnp.int32)
+    text = jax.jit(call, donate_argnums=(0,)).lower(
+        pool, lanes(kvh * g), lanes(kvh), lanes(kvh), i32(()),
+        i32((rows, MAXB)), i32((rows,))).compile().as_text()
+    assert re.search(r"%paged_decode[.\d]* = \S+ \S+ custom-call\(", text)
+    assert pool_sized_movers(text, math.prod(shape) // shape[0]) == []
+    assert "may-alias" in text or "must-alias" in text, \
+        "the donated pool is no longer written in place"
+
+
 @pytest.mark.parametrize("rows", ROWS)
 def test_paged_branch_of_the_model_compiles(one_chip, no_compile_cache, as_tpu,
                                             rows):
@@ -309,13 +341,47 @@ def assert_moves_no_pool(compiled, pool, layers):
     assert "tpu_custom_call" in text
 
 
+def sublayer_lines(text, scopes=("kv_write", "paged_attn")):
+    """The instructions of optimized HLO that were traced under ``scopes``,
+    sorted, without what a change elsewhere in the program moves: the
+    instructions' numbering, their metadata (source lines) and a kernel's
+    serialised body (it holds source lines too)."""
+    lines = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        if not name or not any(f"/{s}/" in name.group(1) for s in scopes):
+            continue
+        line = re.sub(r"backend_config=.*", "", line)
+        line = re.sub(r", metadata=\{[^}]*\}", "", line)
+        lines.append(re.sub(r"%([A-Za-z_\-]+)[.\d]*", r"%\1", line).strip())
+    return sorted(lines)
+
+
+#: sha256 of the mixed step's attention sublayer (``sublayer_lines`` joined
+#: by newlines) at commit d279223 (PR 52), by head size: the scatter of
+#: ``write_rows``, the relaid q, ``paged_decode`` on ``(rows, kvh, g, hd)``
+#: and the result's way back. PR 53 folded the decode round's write into its
+#: attention kernel and left this step to the PR that takes the scatter out
+#: of it (``ROADMAP.md`` S1): that PR re-pins these
+MIXED_SUBLAYER_SHA = {
+    64: "744de346a7e044cc9ae0b1ac58e29e95d1e70b2129899098314e3bed7b2550d7",
+    128: "fe7193fbc1de6f1ca6c864e8428f037607180af39695310c59a5aabf02971951",
+}
+
+
 @pytest.mark.parametrize("head_dim,layers,rows", [
     (64, 4, CELL_SEQS), (64, 2, 256), (128, 2, CELL_SEQS), (128, 3, 256)])
 def test_serving_program_moves_no_pool(one_chip, no_compile_cache, as_tpu,
                                        head_dim, layers, rows):
     """The ragged program (``forward_paged`` over one-token rows, only the
     sequences' rows projected, pool donated) at the cell's pool, for both
-    row counts and for heads of 64 and of 128."""
+    row counts and for heads of 64 and of 128. The decode round's attention
+    sublayer is ONE kernel a layer body, which takes q and gives its result
+    as ``(rows, heads * head_dim)``: no q relaid a (row, head) a tile, no
+    ``reduce`` over the unit head-group axis behind the call, no
+    ``kv_write``. The mixed step's sublayer is what it was."""
+    import hashlib
+
     model = serving_model(head_dim, layers)
     assert model.config.head_dim == head_dim
     params, pool, tables, starts = serving_avals(one_chip, model, rows)
@@ -330,10 +396,23 @@ def test_serving_program_moves_no_pool(one_chip, no_compile_cache, as_tpu,
         params, pool, aval(one_chip, (rows, 1), jnp.int32), tables, starts,
         aval(one_chip, (CELL_SEQS,), jnp.int32)).compile()
     assert_moves_no_pool(compiled, pool, layers)
-    # the decode round writes through the kernel, the mixed step scatters
-    kernel = re.search(r"%kv_write[.\d]* = \S+ custom-call\(",
-                       compiled.as_text())
-    assert bool(kernel) == (rows == CELL_SEQS)
+    text = compiled.as_text()
+    # the round's rows ride in ``paged_decode``, the mixed step scatters
+    assert not re.search(r"%kv_write[.\d]* = \S+ custom-call\(", text)
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    if rows != CELL_SEQS:
+        digest = hashlib.sha256(
+            "\n".join(sublayer_lines(text)).encode()).hexdigest()
+        assert digest == MIXED_SUBLAYER_SHA[head_dim]
+        return
+    per_head = rf"\[{rows},{NH},1,{head_dim}\]\{{[^}}]*T\(2,128\)"
+    assert not re.findall(per_head, text)
+    ungrouped = [line.strip()[:120] for line in text.splitlines()
+                 if re.search(rf"= \w+\[{rows},{NH},{head_dim}\]\S* reduce\(",
+                              line)]
+    assert not ungrouped, ungrouped
+    call = re.search(r"%paged_decode[.\d]* = \((\w+\[[\d,]+\])", text)
+    assert call and call.group(1) == f"bf16[{rows},{NH * head_dim}]"
 
 
 @pytest.mark.parametrize("program", ["fused", "verify"])

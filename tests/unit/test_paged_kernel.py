@@ -107,11 +107,11 @@ def test_engine_kernel_path_matches_xla_path(monkeypatch):
 
 
 def test_engine_live_write_matches_the_scatter(monkeypatch):
-    """Decode rounds go through ``kv_write`` (the engine says their rows are
-    apart), a mixed step through the scatter: a run of a prefill, decode
-    rounds, a second prompt admitted beside a live decode (a mixed step) and
-    more rounds gives the tokens and, on every block but the trash block, the
-    pool of the engine that scatters throughout. Both take the decode
+    """Decode rounds write their rows inside ``paged_decode`` (the engine
+    says their rows are apart), a mixed step through the scatter: a run of a
+    prefill, decode rounds, a second prompt admitted beside a live decode (a
+    mixed step) and more rounds gives the tokens and, on every block but the
+    trash block, the pool of the engine that scatters throughout. Both take the decode
     kernel, so the pools are equal bit for bit."""
     import deepspeed_tpu.comm.topology as topo_mod
     from deepspeed_tpu.inference.v2 import InferenceEngineV2
@@ -668,8 +668,9 @@ def test_write_rows_takes_the_kernel_where_the_pool_allows(monkeypatch, BS, hd,
 
 
 def test_forward_paged_rows_apart_writes_the_live_rows_alone(monkeypatch):
-    """The model's call: ``rows_apart`` reaches ``write_rows`` in every
-    layer. Through a three-layer ``forward_paged`` with most rows padding the
+    """The model's call: ``rows_apart`` reaches every layer, whose one
+    ``paged_decode`` call writes the live rows. Through a three-layer
+    ``forward_paged`` with most rows padding the
     trash block is untouched, the live rows' tokens and the logits are those
     of the scattering program."""
     monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
@@ -710,3 +711,189 @@ def test_trips_of_several_blocks_match_plain_attention(lens):
     out = pa.paged_decode(q, pool, jnp.int32(1), tables, row_lens)
     ref = plain_attention(q, pool, jnp.int32(1), tables, row_lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
+
+
+# ----------------------------------------------------------------------
+# the folded write: a decode round's attention sublayer in one call
+# ----------------------------------------------------------------------
+def _round_case(kvh, g, hd, rows, lens_by_row, *, BS=16, MAXB=3, L=2,
+                dtype=jnp.bfloat16, seed=0):
+    """A decode round of ``rows`` one-token rows, ``lens_by_row`` {row:
+    tokens, the new one counted} live (each on blocks of its own) and the
+    rest padding: (pool of random rows, q, k, v as the projections leave
+    them, tables, lens)."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    rng = np.random.default_rng(seed)
+    NB = 1 + len(lens_by_row) * MAXB + 2
+    pool = jnp.asarray(rng.standard_normal(
+        pa.init_pool(L, kvh, NB, BS, hd, dtype).shape), dtype)
+    q, k, v = (jnp.asarray(rng.standard_normal((rows, n * hd)), dtype)
+               for n in (kvh * g, kvh, kvh))
+    tables = np.zeros((rows, MAXB), np.int32)
+    lens = np.zeros(rows, np.int32)
+    ids = iter(rng.permutation(np.arange(1, NB)))
+    for b, n in sorted(lens_by_row.items()):
+        lens[b] = n
+        for j in range(-(-n // BS)):
+            tables[b, j] = next(ids)
+    return pool, q, k, v, jnp.asarray(tables), jnp.asarray(lens)
+
+
+@functools.lru_cache(maxsize=None)
+def _round_forms():
+    """(the parent's two calls, the one call), each under one ``jax.jit``:
+    ``write_rows`` of rows that are apart (``kv_write``) then the read-only
+    ``paged_decode`` on q as heads, against ``paged_decode`` handed the new
+    rows, everything in the model's ``(rows, heads * hd)`` layout."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    def two_calls(q, k, v, pool, layer, tables, lens):
+        B, (_, kvh, _, _, row) = q.shape[0], pool.shape
+        heads = lambda a: a.reshape(B, 1, -1, row // 2)
+        assert pa.writes_live_rows(pool)
+        pool = pa.write_rows(pool, layer, tables,
+                             jnp.maximum(lens - 1, 0)[:, None], heads(k),
+                             heads(v), rows_apart=True)
+        out = pa.paged_decode(heads(q)[:, 0], pool, layer, tables, lens)
+        return out.reshape(q.shape), pool
+
+    def one_call(q, k, v, pool, layer, tables, lens):
+        return pa.paged_decode(q, pool, layer, tables, lens, new_rows=(k, v))
+
+    return jax.jit(two_calls), jax.jit(one_call)
+
+
+def _assert_the_one_call_is_the_two(case, layer=1):
+    """Result and pool of the one call are bit for bit the two calls', and
+    the pool differs from what it was in the live rows' new tokens alone."""
+    pool, q, k, v, tables, lens = case
+    two, one = (f(q, k, v, pool, jnp.int32(layer), tables, lens)
+                for f in _round_forms())
+    np.testing.assert_array_equal(_bits(one[0]), _bits(two[0]))
+    np.testing.assert_array_equal(_bits(one[1]), _bits(two[1]))
+    BS = pool.shape[3]
+    written = np.zeros(pool.shape[:1] + pool.shape[2:4], bool)  # (L, NB, BS)
+    for b in np.flatnonzero(np.asarray(lens)):
+        n = int(lens[b]) - 1
+        written[layer, int(tables[b, n // BS]), n % BS] = True
+    # heads first, so that one (L, NB, BS) mask picks every head's tokens
+    before, after = (np.moveaxis(_bits(a), 1, 0) for a in (pool, one[1]))
+    np.testing.assert_array_equal(after[:, ~written], before[:, ~written])
+    assert (after[:, written] != before[:, written]).any(axis=-1).all()
+    assert not _bits(one[0])[np.asarray(lens) == 0].any()
+    return one
+
+
+#: where a round's live rows sit among its padding rows, given the row count
+PLACES = {
+    "first": lambda rows: [0, 1, 2],
+    "last": lambda rows: [rows - 3, rows - 2, rows - 1],
+    "scattered": lambda rows: sorted({1, rows // 3, rows // 2 + 1, rows - 2}),
+}
+
+
+@pytest.mark.parametrize("place", list(PLACES))
+@pytest.mark.parametrize("rows", [8, 64, 256])
+@pytest.mark.parametrize("g", [1, 4])
+@pytest.mark.parametrize("hd", [64, 128, 256])
+def test_the_folded_write_is_kv_write_then_paged_decode(monkeypatch, hd, g,
+                                                        rows, place):
+    """Head sizes, grouped queries and row counts of one cell, two and
+    eight, the live rows first, last and scattered among dead ones."""
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    live = PLACES[place](rows)
+    lens = dict(zip(live, (5, 32, 33, 48)))
+    _assert_the_one_call_is_the_two(_round_case(
+        2, g, hd, rows, lens, seed=rows + hd + g,
+        dtype=jnp.float32 if hd == 128 else jnp.bfloat16))
+
+
+#: a live row's tokens (the new one counted) by the edge it stands for, over
+#: blocks of 16 (two sub-tiles) fetched eight a trip
+EDGES = {
+    "offset_0_of_a_fresh_block": 2 * 16 + 1,
+    "last_offset_of_a_block": 2 * 16,
+    "first_sub_tile": 16 + 3,
+    "last_sub_tile": 16 + 15,
+    "blocks_end_inside_a_trip": 10 * 16 + 5,
+    "last_block_of_a_whole_trip": 16 * 16,
+    "context_of_one_token": 1,
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _edges():
+    """Every edge a row of ONE round (a row's work does not depend on its
+    neighbours), a padding row between them: (lens, result, pool)."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+        case = _round_case(1, 4, 64, 8, dict(zip((0, 1, 2, 3, 5, 6, 7),
+                                                 EDGES.values())),
+                           MAXB=16, dtype=jnp.bfloat16, seed=5)
+        from deepspeed_tpu.ops.transformer import paged_attention as pa
+        assert pa.blocks_per_trip(case[0]) == 8
+        out, pool = _assert_the_one_call_is_the_two(case)
+    return np.asarray(case[5]), _bits(out), _bits(pool), case
+
+
+@pytest.mark.parametrize("edge", list(EDGES))
+def test_the_folded_write_at_the_edges(edge):
+    """The new token at offset 0 of a fresh block, at a block's last offset,
+    in the first and the last sub-tile, in a row whose blocks end inside a
+    trip or fill it, alone in its context: the row's ``[k | v]`` lies at its
+    place in every head and the row attends over it."""
+    lens, out, pool, (before, q, k, v, tables, _) = _edges()
+    b = (0, 1, 2, 3, 5, 6, 7)[list(EDGES).index(edge)]
+    n = EDGES[edge] - 1
+    assert lens[b] == n + 1 and lens[4] == 0
+    hd = before.shape[-1] // 2
+    np.testing.assert_array_equal(
+        pool[1, :, int(tables[b, n // 16]), n % 16],
+        np.concatenate((_bits(k)[b].reshape(-1, hd),
+                        _bits(v)[b].reshape(-1, hd)), axis=-1))
+    alone = plain_attention(
+        q[b:b + 1].reshape(1, -1, hd), jnp.asarray(pool), jnp.int32(1),
+        tables[b:b + 1], jnp.asarray(lens[b:b + 1]))
+    np.testing.assert_allclose(out[b], np.asarray(alone).reshape(-1),
+                               atol=2e-2)     # bfloat16 result
+    if edge == "context_of_one_token":        # softmax over the new token
+        np.testing.assert_array_equal(
+            out[b].reshape(-1, 4, hd), np.broadcast_to(
+                _bits(v)[b].reshape(-1, 1, hd), (1, 4, hd)))
+
+
+def test_the_folded_write_of_a_round_of_dead_rows(monkeypatch):
+    """Every row dead: no store starts, the pool is bit for bit what it was
+    and the result is zeros."""
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    case = _round_case(1, 4, 64, 8, {}, MAXB=16, seed=6)
+    out, pool = _assert_the_one_call_is_the_two(case)
+    np.testing.assert_array_equal(_bits(pool), _bits(case[0]))
+    assert not _bits(out).any()
+
+
+def test_the_models_decode_round_is_one_call(monkeypatch):
+    """``_block`` hands a round whose rows are apart to ``paged_decode`` with
+    its new rows, in the model's layout, and calls no ``kv_write``; a step
+    whose rows are not apart keeps ``write_rows`` and the read-only call."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    m, params, before, live, tables, starts, ids = _mostly_padding_step(8, 12)
+    calls = []
+    kernel, writer = pa.paged_decode, pa.kv_write
+
+    def listen(q, *a, new_rows=None, **kw):
+        calls.append(("paged_decode", q.ndim, new_rows is not None))
+        return kernel(q, *a, new_rows=new_rows, **kw)
+
+    monkeypatch.setattr(pa, "paged_decode", listen)
+    monkeypatch.setattr(pa, "kv_write",
+                        lambda *a: calls.append(("kv_write",)) or writer(*a))
+    args = (params, ids, before, jnp.asarray(tables), jnp.asarray(starts))
+    jax.eval_shape(lambda *a: m.forward_paged(*a, rows_apart=True), *args)
+    assert calls == [("paged_decode", 2, True)]      # one scanned layer body
+    del calls[:]
+    jax.eval_shape(m.forward_paged, *args)
+    assert calls == [("paged_decode", 3, False)]
