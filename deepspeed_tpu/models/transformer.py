@@ -39,6 +39,7 @@ from jax.sharding import PartitionSpec as P
 from ..comm.topology import ZERO_AXES
 from ..ops.quantizer.woq import dequant_params as _dequant_woq
 from ..ops.transformer.attention import attention as _attention_op
+from ..ops.transformer.fused_ce import head_nll
 from ..utils.logging import logger
 
 
@@ -1693,7 +1694,7 @@ class TransformerLM:
         cfg = self.config
         L, skip = cfg.num_layers, cfg.random_ltd_skip_ends
         use_drop = cfg.dropout > 0
-        rngs = jax.random.split(rng, L)  # rng is never None here (_logits_aux)
+        rngs = jax.random.split(rng, L)  # rng is never None here (_hidden_aux)
         aux_total = jnp.zeros((), jnp.float32)
 
         def mask_bias_of(m):
@@ -1741,7 +1742,10 @@ class TransformerLM:
             aux_total = aux_total + aux
         return x, aux_total
 
-    def _head(self, params, x):
+    def _head_operands(self, params, x):
+        """``(x as the head reads it, weight, bias or None, vocab_major)``:
+        the weight where it lies, ``(V, H)`` (the tied table, ``vocab_major``)
+        or an untied ``lm_head``'s ``(H, V)``."""
         cfg = self.config
         if cfg.mlm_head:
             # BERT prediction head: dense + act + LN, then the tied decoder
@@ -1754,23 +1758,28 @@ class TransformerLM:
                 x = jax.nn.gelu(x, approximate=cfg.activation != "gelu_exact")
             x = _norm(x, params["mlm_ln_scale"], params["mlm_ln_bias"],
                       "layernorm", cfg.norm_eps)
-            out = x @ params["wte"].T.astype(x.dtype)
-            return out + params["mlm_bias"].astype(x.dtype)
+            return x, params["wte"], params["mlm_bias"], True
         if cfg.norm_position != "post":  # post-LN trunks end already normalized
             x = _norm(x, params["lnf_scale"], params.get("lnf_bias"),
                       cfg.norm, cfg.norm_eps, cfg.norm_weight_offset)
         if cfg.dim_model_base:  # muP: the head reads N(x) * base / width
             x = x * jnp.asarray(cfg.dim_model_base / cfg.hidden_size, x.dtype)
-        w = params["wte"].T if cfg.tie_embeddings else params["lm_head"]
-        out = x @ w.astype(x.dtype)  # (B,S,V)
-        if "lm_head_bias" in params:
-            out = out + params["lm_head_bias"].astype(x.dtype)
+        if cfg.tie_embeddings:
+            return x, params["wte"], params.get("lm_head_bias"), True
+        return x, params["lm_head"], params.get("lm_head_bias"), False
+
+    def _head(self, params, x):
+        x, w, bias, vocab_major = self._head_operands(params, x)
+        out = x @ (w.T if vocab_major else w).astype(x.dtype)  # (B,S,V)
+        if bias is not None:
+            out = out + bias.astype(x.dtype)
         return out
 
     # ------------------------------------------------------------------
-    def _logits_aux(self, params, input_ids, positions=None, train=False, rng=None,
+    def _hidden_aux(self, params, input_ids, positions=None, train=False, rng=None,
                     pld_theta=None, ltd_keep=None, attention_mask=None,
                     token_type_ids=None):
+        """The trunk's output, which the head reads, and the auxiliary loss."""
         if self.config.layer_types is not None:
             raise NotImplementedError(
                 "a layer_types model is served from the paged pool and its "
@@ -1803,14 +1812,15 @@ class TransformerLM:
         else:
             x, aux = self._trunk(params, x, positions, rng, train,
                                  pld_theta=pld_theta, attn_mask_bias=mask_bias)
-        with jax.named_scope("lm_head_loss"):
-            return self._head(params, x), aux
+        return x, aux
 
     def logits(self, params, input_ids, positions=None, train=False, rng=None,
                attention_mask=None, token_type_ids=None):
-        return self._logits_aux(params, input_ids, positions, train, rng,
+        x, _ = self._hidden_aux(params, input_ids, positions, train, rng,
                                 attention_mask=attention_mask,
-                                token_type_ids=token_type_ids)[0]
+                                token_type_ids=token_type_ids)
+        with jax.named_scope("lm_head_loss"):
+            return self._head(params, x)
 
     def apply(self, params, batch, train=True, rng=None):
         """Next-token LM loss over the batch (engine protocol).
@@ -1840,11 +1850,11 @@ class TransformerLM:
         if isinstance(batch, dict):
             attention_mask = batch.get("attention_mask")
             token_type_ids = batch.get("token_type_ids")
-        lg, aux = self._logits_aux(params, input_ids, positions=positions,
-                                   train=train, rng=rng, pld_theta=pld_theta,
-                                   ltd_keep=ltd_keep,
-                                   attention_mask=attention_mask,
-                                   token_type_ids=token_type_ids)
+        x, aux = self._hidden_aux(params, input_ids, positions=positions,
+                                  train=train, rng=rng, pld_theta=pld_theta,
+                                  ltd_keep=ltd_keep,
+                                  attention_mask=attention_mask,
+                                  token_type_ids=token_type_ids)
         if labels is None:
             if not self.config.causal:
                 raise ValueError(
@@ -1854,12 +1864,10 @@ class TransformerLM:
                 [input_ids[:, 1:], jnp.full_like(input_ids[:, :1], -100)], axis=1
             )
         with jax.named_scope("lm_head_loss"):
-            lg = lg.astype(jnp.float32)
+            x, w, bias, vocab_major = self._head_operands(params, x)
             mask = labels != -100
-            safe = jnp.where(mask, labels, 0)
-            logz = jax.scipy.special.logsumexp(lg, axis=-1)
-            gold = jnp.take_along_axis(lg, safe[..., None], axis=-1)[..., 0]
-            nll = (logz - gold) * mask
+            nll = head_nll(x, w, jnp.where(mask, labels, 0), bias,
+                           vocab_major=vocab_major) * mask
             loss = jnp.sum(nll) / jnp.maximum(jnp.sum(mask), 1)
         if self.config.num_experts > 0:
             loss = loss + self.config.moe_aux_loss_coef * aux

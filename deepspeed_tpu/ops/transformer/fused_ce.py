@@ -1,236 +1,320 @@
-"""Fused linear-cross-entropy (vocab head + softmax CE) Pallas kernels.
+"""The vocabulary head and its loss as one unit with its own backward.
 
-TPU-native replacement for the reference's fused logits/loss path (the CUDA
-softmax in ``csrc/transformer/softmax_kernels.cu`` and the fused
-``logits_gather`` of ``deepspeed/inference/v2/kernels/ragged_ops``): computes
-``nll = logsumexp(x @ W^T) - (x @ W^T)[label]`` without ever re-reading the
-(N, V) logits from HBM for the reductions, and a backward that forms
-``dlogits = softmax - onehot`` tile-by-tile in VMEM, feeding the dX / dW
-matmuls directly — the (N, V) fp32 dlogits tensor of the naive path is never
-materialized.
+``head_nll(x, w, labels)`` is ``logsumexp(x @ w^T) - (x @ w^T)[label]`` a
+token (TPU-native counterpart of the reference's fused logits/loss path, the
+CUDA softmax of ``csrc/transformer/softmax_kernels.cu`` and the
+``logits_gather`` of ``deepspeed/inference/v2/kernels/ragged_ops``). Under
+its ``jax.custom_vjp`` the only ``[tokens, vocab]`` array is the logits in the
+activations' dtype, the residual the step saves anyway: the label's logit is
+gathered from them (no float32 copy is written for a gather), and the
+backward forms ``softmax - onehot`` from them, cast to the activations' dtype
+before the two products as autodiff's own backward does.
 
-Layout: W is (V, H) — the embedding-table layout — so the tied-embedding head
-needs no transpose in either direction and dW comes out ready to accumulate
-with the embedding gradient.
+Two paths, chosen from the shapes and the mesh alone (``_plan``):
 
-Forward grid: (N/R rows outer, V/Vb inner); the running max / sum-exp / gold
-accumulators live in revisited output blocks whose index map ignores the vocab
-axis (consecutive revisits stay VMEM-resident on the sequential TPU grid).
-The logits tile is written once (bf16) as the backward's residual — the same
-bytes the engine's "dots" remat policy would have saved.
+* plain: the expressions above as XLA compiles them, for every shape, a head
+  bias and a head sharded over the vocabulary;
+* fused: two Pallas kernels over a grid of (vocabulary tile, row tile), the
+  vocabulary outermost, so the weight is streamed once a direction.
+  ``fused_ce_fwd`` writes each logits tile once and keeps a running maximum
+  and a rescaled running sum of exponentials a row, both taken over the
+  *rounded* tile, the values that are saved; ``fused_ce_bwd`` forms
+  ``softmax - onehot`` once a tile and feeds both products with it: the
+  weight's gradient tile stays in VMEM across the row axis, the activations'
+  gradient is one float32 accumulator in VMEM for the whole call.
 
-Backward grid: (N/R outer, V/Vb inner): dX accumulates in a revisited block;
-dW is produced as N/R partial sums (one per row block) and reduced by XLA —
-O(N/R · V · H) extra bytes but no non-consecutive output revisiting, which
-Pallas TPU does not guarantee.
+The weight is read where it lies: ``(V, H)`` (the tied embedding table,
+``vocab_major``) or ``(H, V)`` (an untied head), never through a transposed
+copy.
+
+Status (my chip runs, PR 56, TPU v5e, ``.bench_scratch/kbench.py``: the unit's
+``value_and_grad`` behind the final norm, eight calls in one jitted loop,
+device time a call from the profiler; PERF.md 5 has every form tried). At
+``(8192, 1024) x (50304, 1024)`` tied: autodiff of the float32 loss 16.64 ms;
+the plain path here 16.10 (no float32 logits); fused 14.18, ``fused_ce_fwd``
+4.53 for XLA's 4.43 + 1.33 (product, then a pass for the sum of
+exponentials) and ``fused_ce_bwd`` 8.85 for XLA's 5.25 + 4.74. A grid step
+that is one product behind ``pl.when`` branches lost to XLA (5.6-6.1 and
+10.0-10.9): what won is the step as straight code over row chunks. At
+``(8192, 2048) x (2048, 50304)`` untied: 32.48, 31.47 and, forward kernel
+alone, 30.02 (8.85 for 8.88 + 1.33). The kernels of before PR 56 (rows
+outermost, the weight's gradient as a partial sum a row block) do not fit
+VMEM at the first shape and were never in a step.
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from ...comm.topology import MODEL_AXIS, SEQ_AXIS, ZERO_AXES
+from ...utils import tracing
+from ..pallas_utils import open_mesh_axes, pallas_interpret
+
+LANES = 128
+#: VMEM the kernels ask for, of the v5e's 128 MiB; a call that would need more
+#: stays XLA's (the backward at a width of 2048 and 8192 tokens a chip)
+VMEM_LIMIT = 100 * 1024 * 1024
+#: rows a product in a grid step's straight code: the next chunk's product
+#: runs under this chunk's exponentials (PERF.md 5: 128 | 256 | 512 | whole)
+CHUNK = 256
+LSE, G, LABEL = 0, 1, 2   # lanes of the backward's one (rows, 128) row table
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=VMEM_LIMIT)
 
 
-def _interpret() -> bool:
-    from ..pallas_utils import pallas_interpret
-
-    return pallas_interpret()
+def _product(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
 # ----------------------------------------------------------------------------
-# forward
+# forward: logits tile, running maximum and sum of exponentials
 # ----------------------------------------------------------------------------
 
-def _fwd_kernel(x_ref, w_ref, lab_ref, *out_refs, block_v, write_lg):
-    if write_lg:
-        lg_ref, m_ref, l_ref, gold_ref = out_refs
-    else:
-        m_ref, l_ref, gold_ref = out_refs
-    j = pl.program_id(1)
-    x = x_ref[0, :, :]              # (R, H) bf16
-    w = w_ref[0, :, :]              # (Vb, H) bf16
-    s = jax.lax.dot_general(x, w, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (R, Vb)
-    if write_lg:
-        lg_ref[0, :, :] = s.astype(lg_ref.dtype)
-
-    tile_max = jnp.max(s, axis=-1)                     # (R,)
-    lab = lab_ref[0, :, 0]                             # (R,) int32
-    col = lab - j * block_v
-    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    hit = cols == col[:, None]
-    tile_gold = jnp.sum(jnp.where(hit, s, 0.0), axis=-1)
+def _fwd_kernel(x_ref, w_ref, lg_ref, stat_ref, *, vocab_major, chunk):
+    j, i = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
-    def _init():
-        m_ref[0, :, 0] = tile_max
-        l_ref[0, :, 0] = jnp.sum(jnp.exp(s - tile_max[:, None]), axis=-1)
-        gold_ref[0, :, 0] = tile_gold
+    def _first():
+        lane = jax.lax.broadcasted_iota(jnp.int32, stat_ref.shape[1:], 1)
+        stat_ref[i] = jnp.where(lane == 0, -jnp.inf, 0.0)
 
-    @pl.when(j > 0)
-    def _update():
-        m = m_ref[0, :, 0]
-        m_new = jnp.maximum(m, tile_max)
-        alpha = jnp.exp(m - m_new)
-        l_ref[0, :, 0] = (l_ref[0, :, 0] * alpha
-                          + jnp.sum(jnp.exp(s - m_new[:, None]), axis=-1))
-        m_ref[0, :, 0] = m_new
-        gold_ref[0, :, 0] = gold_ref[0, :, 0] + tile_gold
+    # straight code, a chunk of rows at a time: the next chunk's product runs
+    # under this chunk's exponentials, which a grid step's would not
+    w = w_ref[...]
+    for c in range(0, x_ref.shape[1], chunk):
+        rows = slice(c, c + chunk)
+        s = _product(x_ref[i, rows], w, (1, 1 if vocab_major else 0))
+        rounded = s.astype(lg_ref.dtype)
+        lg_ref[rows] = rounded
+        s = rounded.astype(jnp.float32)        # the values that are saved
+        m = stat_ref[i, rows, 0:1]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        stat_ref[i, rows, 1:2] = (
+            stat_ref[i, rows, 1:2] * jnp.exp(m - m_new)
+            + jnp.sum(jnp.exp(s - m_new), axis=-1, keepdims=True))
+        stat_ref[i, rows, 0:1] = m_new
 
 
-def _ce_fwd_impl(x, w, labels, block_r, block_v, write_lg=True):
+def _whole(shape):
+    """A block that is the whole array, fetched once and kept: one buffer."""
+    return pl.BlockSpec(shape, lambda j, i: (0,) * len(shape),
+                        pipeline_mode=pl.Buffered(1))
+
+
+def _w_spec(H, block_v, vocab_major):
+    if vocab_major:
+        return pl.BlockSpec((block_v, H), lambda j, i: (j, 0))
+    return pl.BlockSpec((H, block_v), lambda j, i: (0, j))
+
+
+def _fwd_call(x, w, vocab_major, block_r, block_v):
+    """(logits (N, V) in x's dtype, lse (N,) float32)."""
     N, H = x.shape
-    V = w.shape[0]
-    grid = (N // block_r, V // block_v)
-    small = pl.BlockSpec((1, block_r, 1), lambda i, j: (0, i, 0))
-    out_specs = [small, small, small]
-    out_shape = [jax.ShapeDtypeStruct((1, N, 1), jnp.float32)] * 3
-    if write_lg:
-        out_specs = [pl.BlockSpec((1, block_r, block_v),
-                                  lambda i, j: (0, i, j))] + out_specs
-        out_shape = [jax.ShapeDtypeStruct((1, N, V), x.dtype)] + out_shape
-    outs = pl.pallas_call(
-        functools.partial(_fwd_kernel, block_v=block_v, write_lg=write_lg),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_r, H), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((1, block_v, H), lambda i, j: (0, j, 0)),
-            pl.BlockSpec((1, block_r, 1), lambda i, j: (0, i, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=_interpret(),
+    V = w.shape[0 if vocab_major else 1]
+    nr = N // block_r
+    lg, stat = pl.pallas_call(
+        functools.partial(_fwd_kernel, vocab_major=vocab_major,
+                          chunk=min(CHUNK, block_r)),
+        grid=(V // block_v, nr),
+        in_specs=[_whole((nr, block_r, H)), _w_spec(H, block_v, vocab_major)],
+        out_specs=[pl.BlockSpec((block_r, block_v), lambda j, i: (i, j)),
+                   _whole((nr, block_r, LANES))],
+        out_shape=[jax.ShapeDtypeStruct((N, V), x.dtype),
+                   jax.ShapeDtypeStruct((nr, block_r, LANES), jnp.float32)],
+        compiler_params=_PARAMS,
+        interpret=pallas_interpret(),
         name="fused_ce_fwd",
-    )(x[None], w[None], labels[None, :, None])
-    lg, (m, l, gold) = (outs[0][0], outs[1:]) if write_lg else (None, outs)
-    lse = m[0, :, 0] + jnp.log(l[0, :, 0])
-    return lg, lse, gold[0, :, 0]
+    )(x.reshape(nr, block_r, H), w)
+    stat = stat.reshape(N, LANES)
+    return lg, stat[:, 0] + jnp.log(stat[:, 1])
 
 
 # ----------------------------------------------------------------------------
-# backward
+# backward: softmax - onehot once a tile, into both products
 # ----------------------------------------------------------------------------
 
-def _bwd_kernel(lg_ref, lse_ref, lab_ref, g_ref, x_ref, w_ref,
-                dx_ref, dwp_ref, *, block_v):
-    j = pl.program_id(1)
-    lg = lg_ref[0, :, :].astype(jnp.float32)           # (R, Vb)
-    lse = lse_ref[0, :, 0]                             # (R,)
-    g = g_ref[0, :, 0]                                 # (R,) upstream d(nll)
-    lab = lab_ref[0, :, 0]
-    p = jnp.exp(lg - lse[:, None])
-    col = lab - j * block_v
-    cols = jax.lax.broadcasted_iota(jnp.int32, lg.shape, 1)
-    onehot = (cols == col[:, None]).astype(jnp.float32)
-    dlg = ((p - onehot) * g[:, None]).astype(x_ref.dtype)   # (R, Vb) bf16
-
-    x = x_ref[0, :, :]                                 # (R, H)
-    w = w_ref[0, :, :]                                 # (Vb, H)
-    dwp_ref[0, :, :] = jax.lax.dot_general(
-        dlg, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dwp_ref.dtype)  # (Vb, H)
-    dx_blk = jax.lax.dot_general(
-        dlg, w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (R, H)
+def _bwd_kernel(lg_ref, row_ref, xt_ref, w_ref, dx_ref, dw_ref, acc_ref, *,
+                vocab_major, block_v, chunk):
+    j, i = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
-    def _init():
-        dx_ref[0, :, :] = dx_blk
+    def _dx_first():
+        dx_ref[i] = jnp.zeros(dx_ref.shape[1:], dx_ref.dtype)
 
-    @pl.when(j > 0)
-    def _acc():
-        dx_ref[0, :, :] = dx_ref[0, :, :] + dx_blk
+    @pl.when(i == 0)
+    def _dw_first():
+        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+
+    w = w_ref[...]
+    dwt = None
+    for c in range(0, lg_ref.shape[0], chunk):
+        rows = slice(c, c + chunk)
+        lg = lg_ref[rows].astype(jnp.float32)                  # (chunk, Vb)
+        row = row_ref[i, rows]                                 # (chunk, 128)
+        col = row[:, LABEL:LABEL + 1].astype(jnp.int32) - j * block_v
+        p = jnp.exp(lg - row[:, LSE:LSE + 1])
+        hit = jax.lax.broadcasted_iota(jnp.int32, lg.shape, 1) == col
+        dlg = (jnp.where(hit, p - 1.0, p) * row[:, G:G + 1]).astype(
+            xt_ref.dtype)
+        dx_ref[i, rows] += _product(dlg, w, (1, 0 if vocab_major else 1))
+        part = _product(xt_ref[i, :, rows], dlg, (1, 0))       # (H, Vb)
+        dwt = part if dwt is None else dwt + part
+    acc_ref[...] += dwt
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _dw_out():
+        acc = acc_ref[...]
+        dw_ref[...] = (acc.T if vocab_major else acc).astype(dw_ref.dtype)
 
 
-def _ce_bwd_impl(lg, lse, labels, g, x, w, block_r, block_v):
+def _bwd_call(lg, lse, labels, g, x, w, vocab_major, block_r, block_v):
+    """(dx (N, H), dw as w) from the saved logits."""
     N, H = x.shape
-    V = w.shape[0]
-    ni = N // block_r
-    grid = (ni, V // block_v)
-    dx, dwp = pl.pallas_call(
-        functools.partial(_bwd_kernel, block_v=block_v),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_r, block_v), lambda i, j: (0, i, j)),
-            pl.BlockSpec((1, block_r, 1), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((1, block_r, 1), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((1, block_r, 1), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((1, block_r, H), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((1, block_v, H), lambda i, j: (0, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_r, H), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((1, block_v, H), lambda i, j: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, N, H), jnp.float32),
-            jax.ShapeDtypeStruct((ni, V, H), x.dtype),
-        ],
-        interpret=_interpret(),
+    V = lg.shape[1]
+    nr = N // block_r
+    rows = jnp.pad(jnp.stack([lse, g, labels.astype(jnp.float32)], axis=1),
+                   ((0, 0), (0, LANES - 3)))
+    xt = x.reshape(nr, block_r, H).swapaxes(1, 2)          # (nr, H, R)
+    dx, dw = pl.pallas_call(
+        functools.partial(_bwd_kernel, vocab_major=vocab_major,
+                          block_v=block_v, chunk=min(CHUNK, block_r)),
+        grid=(V // block_v, nr),
+        in_specs=[pl.BlockSpec((block_r, block_v), lambda j, i: (i, j)),
+                  _whole((nr, block_r, LANES)),
+                  _whole((nr, H, block_r)),
+                  _w_spec(H, block_v, vocab_major)],
+        out_specs=[_whole((nr, block_r, H)),
+                   _w_spec(H, block_v, vocab_major)],
+        out_shape=[jax.ShapeDtypeStruct((nr, block_r, H), jnp.float32),
+                   jax.ShapeDtypeStruct(w.shape, w.dtype)],
+        scratch_shapes=[pltpu.VMEM((H, block_v), jnp.float32)],
+        compiler_params=_PARAMS,
+        interpret=pallas_interpret(),
         name="fused_ce_bwd",
-    )(lg[None], lse[None, :, None], labels[None, :, None],
-      g[None, :, None], x[None], w[None])
-    dw = dwp.astype(jnp.float32).sum(axis=0) if ni > 1 else dwp[0].astype(jnp.float32)
-    return dx[0].astype(x.dtype), dw.astype(w.dtype)
+    )(lg, rows.reshape(nr, block_r, LANES), xt, w)
+    return dx.reshape(N, H).astype(x.dtype), dw
 
 
 # ----------------------------------------------------------------------------
-# public entry (custom VJP)
+# the plain path
 # ----------------------------------------------------------------------------
 
-def _pick_blocks(N, V, H):
-    # VMEM guard: the backward holds an (R, H) fp32 dx accumulator + (R, H)
-    # bf16 x tile + (R, Vb) tiles; keep the dominant R*H buffers under ~8 MB
-    r_cap = max(128, (8 * 1024 * 1024) // (6 * H))
-    block_r = next((r for r in (2048, 1024, 512, 256, 128)
-                    if r <= r_cap and N % r == 0), None)
+def _plain_logits(x, w, bias, vocab_major):
+    lg = x @ (w.T if vocab_major else w).astype(x.dtype)
+    return lg if bias is None else lg + bias.astype(x.dtype)
+
+
+def _plain_bwd(lg, lse, labels, g, x, w, bias, vocab_major):
+    p = jnp.exp(lg.astype(jnp.float32) - lse[:, None])
+    hit = jax.lax.broadcasted_iota(jnp.int32, lg.shape, 1) == labels[:, None]
+    dlg = (jnp.where(hit, p - 1.0, p) * g[:, None]).astype(x.dtype)
+    wx = w.astype(x.dtype)
+    dx = dlg @ (wx if vocab_major else wx.T)
+    dw = dlg.T @ x if vocab_major else x.T @ dlg
+    db = None if bias is None else jnp.sum(dlg, axis=0).astype(bias.dtype)
+    return dx, dw.astype(w.dtype), db
+
+
+# ----------------------------------------------------------------------------
+# the unit
+# ----------------------------------------------------------------------------
+
+def _plan(N, H, V, x_dtype, w_dtype, bias):
+    """``(blocks of the forward kernel, blocks of the backward kernel)``,
+    each ``(block_r, block_v)`` or None where XLA takes that direction. The
+    kernels take vocabulary and width multiples of 128, tokens a multiple of
+    the row tile, one dtype, no head bias and what fits ``VMEM_LIMIT``: the
+    backward where its float32 accumulator of the activations' gradient fits
+    beside the rest."""
+    block_r = next((r for r in (4096, 2048, 1024, 512, 256, 128)
+                    if N % r == 0), None)
     block_v = next((v for v in (512, 384, 256, 128) if V % v == 0), None)
-    return block_r, block_v
+    if (bias is not None or block_r is None or block_v is None or H % LANES
+            or x_dtype != w_dtype or V > 1 << 24):
+        return None, None
+    item = jnp.dtype(x_dtype).itemsize
+    tiles = (4 * block_v * H * item          # w in, dw out, two buffers each
+             + 2 * block_r * block_v * item  # a logits tile, two buffers
+             + 4 * min(CHUNK, block_r) * (block_v + H) * 4  # a chunk's values
+             + 2 * block_v * H * 4)          # dw's sums
+    rows = N * LANES * 4
+    fits = VMEM_LIMIT * 7 // 8
+    if N * H * item + rows + tiles > fits:
+        return None, None
+    blocks = (block_r, block_v)
+    return blocks, blocks if N * H * (item + 4) + rows + tiles <= fits else None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _fused_ce(x, w, labels, block_r, block_v):
-    # no-grad primal: skip the (N, V) logits residual entirely — it is only
-    # needed by the backward, and the pallas_call is opaque to XLA DCE
-    _, lse, gold = _ce_fwd_impl(x, w, labels, block_r, block_v, write_lg=False)
-    return lse - gold
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _head_nll(x, w, bias, labels, vocab_major, fwd_blocks, bwd_blocks):
+    return _head_nll_fwd(x, w, bias, labels, vocab_major, fwd_blocks,
+                         bwd_blocks)[0]
 
 
-def _fused_ce_fwd(x, w, labels, block_r, block_v):
-    lg, lse, gold = _ce_fwd_impl(x, w, labels, block_r, block_v)
-    return lse - gold, (lg, lse, labels, x, w)
+def _head_nll_fwd(x, w, bias, labels, vocab_major, fwd_blocks, bwd_blocks):
+    if fwd_blocks is None:
+        lg = _plain_logits(x, w, bias, vocab_major)
+        lse = jax.scipy.special.logsumexp(lg.astype(jnp.float32), axis=-1)
+    else:
+        lg, lse = _fwd_call(x, w, vocab_major, *fwd_blocks)
+    gold = jnp.take_along_axis(lg, labels[:, None], axis=-1)[:, 0]
+    return lse - gold.astype(jnp.float32), (lg, lse, labels, x, w, bias)
 
 
-def _fused_ce_bwd(block_r, block_v, res, g):
-    lg, lse, labels, x, w = res
-    dx, dw = _ce_bwd_impl(lg, lse, labels, g, x, w, block_r, block_v)
-    return dx, dw, None
+def _head_nll_bwd(vocab_major, fwd_blocks, bwd_blocks, res, g):
+    lg, lse, labels, x, w, bias = res
+    if bwd_blocks is None:
+        return (*_plain_bwd(lg, lse, labels, g, x, w, bias, vocab_major), None)
+    dx, dw = _bwd_call(lg, lse, labels, g, x, w, vocab_major, *bwd_blocks)
+    return dx, dw, None, None
 
 
-_fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
+_head_nll.defvjp(_head_nll_fwd, _head_nll_bwd)
 
 
-def fused_ce_loss(x, w, labels):
-    """Per-row ``logsumexp(x @ w^T) - (x @ w^T)[label]`` (f32), fused.
+def head_nll(x, w, labels, bias=None, *, vocab_major):
+    """``logsumexp(x @ w^T + bias) - (x @ w^T + bias)[label]`` a token, float32.
 
-    ``x``: (N, H) activations; ``w``: (V, H) vocab table (embedding layout);
-    ``labels``: (N,) int32 — must be valid indices (mask outside; rows whose
-    label is out of range still produce a finite lse-based value).
-    Returns (N,) f32. Raises ``NotImplementedError`` for shapes the kernel
-    does not cover — catch it and use the unfused logsumexp/gather path.
+    ``x``: (B, S, H) activations as the head reads them (normed, scaled);
+    ``w``: (V, H) if ``vocab_major`` (the tied embedding table) else (H, V);
+    ``labels``: (B, S) valid indices (mask outside); ``bias``: (V,) or None.
+    Under a ``kernel_mesh`` of several devices the fused path maps itself
+    over the mesh: a device's own tokens against the whole weight, whose
+    gradient is then the devices' partial sums, reduced as any gradient is.
+    A mesh with a ``model`` axis shards the head over the vocabulary, which
+    XLA partitions and the kernels do not: plain."""
+    B, S, H = x.shape
+    V = w.shape[0 if vocab_major else 1]
+    mesh, axes = open_mesh_axes()
+    sizes = (jax.sharding.get_abstract_mesh() if mesh is None else mesh).shape
 
-    Status: opt-in op, not wired into ``TransformerLM.apply`` — measured
-    XLA-competitive (not faster) at GPT-2 shapes on v5e, where XLA already
-    fuses the reduction passes; it exists for fusion-hostile shapes and as
-    the ragged-logits building block (reference
-    ``inference/v2/kernels/ragged_ops/logits_gather``).
-    """
-    N, H = x.shape
-    V, H2 = w.shape
-    if H != H2:
-        raise ValueError(f"x H={H} vs w H={H2}")
-    block_r, block_v = _pick_blocks(N, V, H)
-    if block_r is None or block_v is None or H % 128 or H > 8192:
-        raise NotImplementedError(f"fused_ce: unsupported shape N={N} V={V} H={H}")
-    return _fused_ce(x, w, labels.astype(jnp.int32), block_r, block_v)
+    # a device's own tokens: the batch over the ZeRO axes, a sequence over seq
+    over = [tuple(a for a in names if a in axes)
+            for names in (ZERO_AXES, (SEQ_AXIS,))]
+    ways = [math.prod(sizes[a] for a in names) for names in over]
+    spec = P(*(names or None for names in over))
+    fwd_blocks = bwd_blocks = None
+    if sizes.get(MODEL_AXIS, 1) == 1 and not (B % ways[0] or S % ways[1]):
+        fwd_blocks, bwd_blocks = _plan(B * S // (ways[0] * ways[1]), H, V,
+                                       x.dtype, w.dtype, bias)
+    tracing.set_program_attr(head_loss=(
+        "plain" if fwd_blocks is None else
+        "fused_fwd" if bwd_blocks is None else "fused"))
+
+    def local(x, w, labels):
+        nll = _head_nll(x.reshape(-1, H), w, bias, labels.reshape(-1),
+                        vocab_major, fwd_blocks, bwd_blocks)
+        return nll.reshape(labels.shape)
+
+    if fwd_blocks is None or not axes:
+        return local(x, w, labels)
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=(P(*spec, None), P(), spec),
+        out_specs=spec, axis_names=frozenset(axes), check_vma=False,
+    )(x, w, labels)

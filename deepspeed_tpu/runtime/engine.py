@@ -1868,13 +1868,16 @@ class DeepSpeedEngine:
 
     def _under_mesh(self, apply_fn):
         """``apply_fn`` traced under ``kernel_mesh``: Pallas kernels in the
-        model map themselves over the engine's mesh (ops/pallas_utils.py)."""
+        model map themselves over the engine's mesh (ops/pallas_utils.py).
+        What the model says of the path a shape took (``head_loss``) lands in
+        ``_program_attrs`` and from there on the ``engine.enqueue`` spans."""
         from ..ops.pallas_utils import kernel_mesh
 
         mesh = self.topology.mesh
+        self._program_attrs = {}
 
         def apply(*args, **kwargs):
-            with kernel_mesh(mesh):
+            with kernel_mesh(mesh), tracing.program_attrs(self._program_attrs):
                 return apply_fn(*args, **kwargs)
 
         return apply
@@ -1908,6 +1911,7 @@ class DeepSpeedEngine:
                                      args, key=_batch_key(args[4]))
             (new_lp, new_master, new_opt, new_scaler, loss, gnorm, overflow) = \
                 self._fused_step_fn(*args)
+            sp.set(**self._program_attrs)     # known once the step is traced
         self.params = new_lp
         if self._mixed:
             self.master_params = new_master

@@ -29,6 +29,8 @@ directly.
 """
 
 import collections
+import contextlib
+import contextvars
 import itertools
 import re
 import threading
@@ -172,6 +174,30 @@ def event(name: str, start: int, end: int, **attrs) -> None:
         stack = _stack()
         _buf.append(Record(next(_ids), name, int(start), int(end),
                            stack[-1] if stack else 0, attrs))
+
+
+_PROGRAM_ATTRS = contextvars.ContextVar("dstpu_program_attrs", default=None)
+
+
+@contextlib.contextmanager
+def program_attrs(attrs: dict):
+    """Collect into ``attrs`` what the code traced inside says of the program
+    it becomes (:func:`set_program_attr`): which of its paths a shape took.
+    The engine that builds a step opens this around the model's apply and
+    puts ``attrs`` on the step's ``engine.enqueue`` spans."""
+    token = _PROGRAM_ATTRS.set(attrs)
+    try:
+        yield
+    finally:
+        _PROGRAM_ATTRS.reset(token)
+
+
+def set_program_attr(**attrs) -> None:
+    """Called while a program is traced, by the code that chose; nothing
+    outside a :func:`program_attrs`."""
+    into = _PROGRAM_ATTRS.get()
+    if into is not None:
+        into.update(attrs)
 
 
 def snapshot() -> List[Record]:

@@ -137,14 +137,11 @@ def test_flash_moves_no_head_layout(one_chip, no_compile_cache, as_tpu, shape):
     assert not moved, moved
 
 
-def test_a_gpt2_layer_holds_no_head_layout_copy(one_chip, no_compile_cache,
-                                                as_tpu):
+@pytest.fixture(scope="module")
+def gpt2_layer_step(one_chip, no_compile_cache):
     """One layer of the ``gpt2-medium.train-seq1024`` job (q/k/v biases, remat
     ``dots``, layers unrolled, as the cell's files say) under
-    ``value_and_grad``: the compiled step holds no ``copy bf16[8,1024,16,64]``.
-    The parent held twelve a layer: q, k, v into the kernel's
-    ``(B, heads, S, head_dim)`` and the result back, the same again under
-    remat, ``do`` in and dq, dk, dv back."""
+    ``value_and_grad``, compiled for the described chip: its text."""
     from deepspeed_tpu.models import TransformerLM, gpt2_config
 
     model = TransformerLM(gpt2_config(
@@ -158,14 +155,92 @@ def test_a_gpt2_layer_holds_no_head_layout_copy(one_chip, no_compile_cache,
         out = model.apply(params, batch, train=True)
         return out[0] if isinstance(out, tuple) else out
 
-    text = compile_text(
-        jax.value_and_grad(loss), params,
-        {"input_ids": aval(one_chip, (B, S), jnp.int32)})
-    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    with pytest.MonkeyPatch.context() as patch:   # as_tpu, at module scope
+        patch.delenv("DSTPU_PALLAS_INTERPRET", raising=False)
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return compile_text(
+            jax.value_and_grad(loss), params,
+            {"input_ids": aval(one_chip, (B, S), jnp.int32)})
+
+
+def test_a_gpt2_layer_holds_no_head_layout_copy(gpt2_layer_step):
+    """The compiled step holds no ``copy bf16[8,1024,16,64]``. The parent of
+    PR 47 held twelve a layer: q, k, v into the kernel's
+    ``(B, heads, S, head_dim)`` and the result back, the same again under
+    remat, ``do`` in and dq, dk, dv back. Its four kernel calls are
+    ``flash_fwd`` and ``flash_bwd`` of the one layer and the head's
+    ``fused_ce_fwd`` and ``fused_ce_bwd``."""
+    text = gpt2_layer_step
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    for kernel in ("flash_fwd", "flash_bwd", "fused_ce_fwd", "fused_ce_bwd"):
+        assert f"/{kernel}/pallas_call" in text, kernel
     per_head = f"= bf16[{B},{S},{NH},{HD}]"
     copies = [line.strip()[:120] for line in text.splitlines()
               if per_head in line and " copy(" in line]
     assert not copies, copies
+
+
+VOCAB = 50304
+
+
+def vocab_sized(text, tokens=B * S, vocab=VOCAB):
+    """``(instruction, dtype)`` of every array of tokens x vocabulary elements
+    that an instruction of the entry computation writes (a fusion's inner
+    instructions write none; a ``get-tuple-element`` or ``bitcast`` names one
+    that is there)."""
+    found = []
+    for line in text[text.index("ENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z\-]+)\(", line)
+        if not m or m.group(3) in ("get-tuple-element", "bitcast", "parameter"):
+            continue
+        found += [(m.group(1), dtype)
+                  for dtype, dims in re.findall(r"([a-z0-9]+)\[([0-9,]+)\]",
+                                                m.group(2))
+                  if math.prod(map(int, dims.split(","))) == tokens * vocab]
+    return found
+
+
+def test_a_gpt2_step_holds_one_vocabulary_sized_array(gpt2_layer_step):
+    """The head's loss keeps one ``[tokens, vocab]`` array, the bf16 logits:
+    the parent wrote them in float32 too, 1.65 GB a step, for a gather of
+    8,192 numbers."""
+    arrays = vocab_sized(gpt2_layer_step)
+    assert [dtype for _, dtype in arrays] == ["bf16"], arrays
+
+
+def test_the_head_leaves_the_layers_activations_where_they_lie(gpt2_layer_step):
+    """At most two ``copy bf16[8,1024,1024]`` in the one-layer step (the saved
+    ``o`` to ``{1,2,0}`` for ``wo``'s weight gradient and back for
+    ``flash_bwd``). The parent held seven, and 76 + 24 + 24 copies in the 24
+    layers of the cell's step for 48: autodiff's head took the activations
+    three-dimensional, its weight product asked for them transposed, and
+    layout assignment carried that choice into every layer's backward; that,
+    not the head's own 3.9 ms, was most of what PR 56 gained (PERF.md 5)."""
+    copies = re.findall(rf"= bf16\[{B},{S},{NH * HD}\]\S* copy\(",
+                        gpt2_layer_step)
+    assert len(copies) <= 2, copies
+
+
+@pytest.mark.parametrize("tokens, width, vocab_major, kernels", [
+    ((8, 1024), 1024, True, 2),     # gpt2-medium.train-seq1024: both kernels
+    ((4, 2048), 2048, False, 1),    # pythia-1.4b, lm_head (2048, 50304): the
+])                                  # backward's accumulator does not fit VMEM
+def test_the_heads_unit_compiles(one_chip, no_compile_cache, as_tpu, tokens,
+                                 width, vocab_major, kernels):
+    from deepspeed_tpu.ops.transformer.fused_ce import head_nll
+
+    def loss(x, w, labels):
+        return jnp.mean(head_nll(x, w, labels, vocab_major=vocab_major))
+
+    text = compile_text(
+        jax.value_and_grad(loss, argnums=(0, 1)),
+        aval(one_chip, (*tokens, width), jnp.bfloat16),
+        aval(one_chip, (VOCAB, width) if vocab_major else (width, VOCAB),
+             jnp.bfloat16),
+        aval(one_chip, tokens, jnp.int32))
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    arrays = vocab_sized(text, math.prod(tokens))
+    assert [dtype for _, dtype in arrays] == ["bf16"], arrays
 
 
 # (rows, query heads, kv heads, pool blocks, head size, table width, kv heads

@@ -141,31 +141,119 @@ class TestFlashAttention:
         assert out.shape == q.shape
 
 
-def test_fused_ce_matches_reference():
-    """Fused linear-CE kernel (interpret mode on CPU): forward + both grads
-    match the unfused logsumexp/gather formulation."""
-    from deepspeed_tpu.ops.transformer.fused_ce import fused_ce_loss
+def head_model(tied=True, vocab=256, seq=64, **kw):
+    """Shapes the head's kernels take: width and vocabulary multiples of 128,
+    2 x 64 tokens a row tile."""
+    return TransformerLM(gpt2_config(
+        "125m", vocab_size=vocab, hidden_size=128, num_layers=1, num_heads=2,
+        max_seq_len=seq, tie_embeddings=tied, **kw))
 
-    N, H, V = 256, 128, 768
-    x = jax.random.normal(jax.random.PRNGKey(0), (N, H), jnp.float32)
-    w = jax.random.normal(jax.random.PRNGKey(1), (V, H), jnp.float32) * 0.1
-    lab = jax.random.randint(jax.random.PRNGKey(2), (N,), 0, V)
 
-    def ref(x, w):
-        lg = (x @ w.T).astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(lg, axis=-1)
-        gold = jnp.take_along_axis(lg, lab[:, None], axis=-1)[:, 0]
-        return lse - gold
+def plain_loss(model, params, batch, labels=None):
+    """The loss written out: ``logsumexp`` and ``take_along_axis`` on float32
+    logits, ignored rows (-100) out of the mean."""
+    ids = batch["input_ids"]
+    if labels is None:
+        labels = jnp.concatenate(
+            [ids[:, 1:], jnp.full_like(ids[:, :1], -100)], axis=1)
+    lg = model.logits(params, ids, train=True).astype(jnp.float32)
+    mask = labels != -100
+    safe = jnp.where(mask, labels, 0)
+    nll = (jax.scipy.special.logsumexp(lg, axis=-1)
+           - jnp.take_along_axis(lg, safe[..., None], axis=-1)[..., 0]) * mask
+    return jnp.sum(nll) / jnp.maximum(jnp.sum(mask), 1)
 
-    np.testing.assert_allclose(np.asarray(ref(x, w)),
-                               np.asarray(fused_ce_loss(x, w, lab)),
-                               rtol=1e-5, atol=1e-5)
-    g = jax.random.normal(jax.random.PRNGKey(3), (N,), jnp.float32)
-    dr = jax.grad(lambda x, w: jnp.sum(ref(x, w) * g), argnums=(0, 1))(x, w)
-    df = jax.grad(lambda x, w: jnp.sum(fused_ce_loss(x, w, lab) * g),
-                  argnums=(0, 1))(x, w)
-    np.testing.assert_allclose(np.asarray(dr[0]), np.asarray(df[0]), rtol=1e-4, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(dr[1]), np.asarray(df[1]), rtol=1e-4, atol=1e-5)
+
+def head_labels(kind, batch, vocab):
+    ids = np.asarray(batch["input_ids"])
+    if kind == "absent":
+        return None
+    if kind == "all_ignored":
+        return jnp.full(ids.shape, -100, jnp.int32)
+    labels = np.random.default_rng(1).integers(0, vocab, ids.shape,
+                                               dtype=np.int32)
+    labels[1], labels[0, ::3] = -100, -100
+    return jnp.asarray(labels)
+
+
+HEAD_CASES = [
+    (dict(tied=tied), labels, "fused")
+    for tied in (True, False)
+    for labels in ("absent", "some_ignored", "all_ignored")
+] + [
+    (dict(vocab=200), "absent", "plain"),           # no multiple of 128
+    (dict(tied=False, lm_head_bias=True), "some_ignored", "plain"),
+    (dict(dim_model_base=32), "absent", "fused"),   # muP: the head reads x / 4
+    (dict(tied=False, dim_model_base=32), "some_ignored", "fused"),
+]
+
+
+@pytest.mark.parametrize(
+    "kw, labels, path", HEAD_CASES,
+    ids=["-".join([*map(str, kw.values()), labels]) for kw, labels, _ in HEAD_CASES])
+def test_head_loss_matches_plain_expression(kw, labels, path):
+    """``apply``'s loss and every gradient, of the parameters below the head
+    and of the head's weight, against the plain expression; the head's unit
+    says which of its paths the shape took."""
+    from deepspeed_tpu.utils import tracing
+
+    model = head_model(**kw)
+    params = model.init_params(jax.random.PRNGKey(0))
+    if "lm_head_bias" in params:
+        params["lm_head_bias"] = 0.1 * jax.random.normal(
+            jax.random.PRNGKey(3), params["lm_head_bias"].shape)
+    batch = batch_of(model, B=2)
+    labels = head_labels(labels, batch, model.config.vocab_size)
+    if labels is not None:
+        batch["labels"] = labels
+    took = {}
+    with tracing.program_attrs(took):
+        got, dgot = jax.jit(jax.value_and_grad(
+            lambda p: model.apply(p, batch, train=True)))(params)
+    want, dwant = jax.jit(jax.value_and_grad(
+        lambda p: plain_loss(model, p, batch, labels)))(params)
+    assert took == {"head_loss": path}
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-6)
+    for (name, a), b in zip(jax.tree.leaves_with_path(dgot),
+                            jax.tree.leaves(dwant)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5, err_msg=jax.tree_util.keystr(name))
+
+
+def test_head_loss_under_zero3_data4_matches_stage0():
+    """The kernels map themselves over the engine's mesh (``shard_map`` over
+    ``data=4`` x ``hpz=2``, a sequence a device, the weight whole on every
+    device and its gradient the devices' partial sums): ZeRO stage 3 gives
+    the losses and the parameters of the plain expression at stage 0."""
+    from deepspeed_tpu.comm.topology import reset_topology
+
+    model = head_model(seq=128)
+    batches = [batch_of(model, B=8, seed=s) for s in range(3)]
+
+    def run(stage, apply):
+        reset_topology()
+        engine, *_ = deepspeed_tpu.initialize(
+            model=(model.init_params(jax.random.PRNGKey(0)), apply),
+            config={"train_micro_batch_size_per_gpu": 1,
+                    "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+                    "zero_optimization": {
+                        "stage": stage,
+                        "zero_hpz_partition_size": 2 if stage == 3 else 1},
+                    "mesh": {"data": 4, "hpz": 2} if stage == 3
+                    else {"data": 8}})
+        data = iter(batches)
+        losses = [float(engine.train_batch(data)) for _ in batches]
+        return (losses, jax.tree.map(np.asarray, engine.params),
+                engine._program_attrs)
+
+    one, p_one, took = run(0, lambda p, b, **kw: plain_loss(model, p, b))
+    assert took == {}
+    four, p_four, took = run(3, model.apply)
+    assert took == {"head_loss": "fused"}
+    reset_topology()
+    np.testing.assert_allclose(four, one, rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(p_four), jax.tree.leaves(p_one)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
 def test_dots_ln_remat_policy_matches_dots():

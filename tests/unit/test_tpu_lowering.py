@@ -136,14 +136,16 @@ class TestMosaicLowering:
             _aval((1, S, H, hd), jnp.bfloat16))
 
     def test_fused_ce(self):
-        from deepspeed_tpu.ops.transformer.fused_ce import fused_ce_loss
+        from deepspeed_tpu.ops.transformer.fused_ce import head_nll
 
-        # x (N,H), w (V,H) embedding layout, labels (N,)
-        _export_tpu(
-            lambda x, w, lab: fused_ce_loss(x, w, lab),
-            _aval((2048, 512), jnp.bfloat16),
+        # x (B,S,H), w (V,H) embedding layout, labels (B,S); both kernels
+        exp = _export_tpu(
+            jax.grad(lambda x, w, lab: jnp.sum(
+                head_nll(x, w, lab, vocab_major=True)), argnums=(0, 1)),
+            _aval((2, 1024, 512), jnp.bfloat16),
             _aval((32000, 512), jnp.bfloat16),
-            _aval((2048,), jnp.int32))
+            _aval((2, 1024), jnp.int32))
+        assert exp.mlir_module().count("tpu_custom_call") >= 2
 
     def test_streaming_paged_decode_8k_context(self):
         """The serving engine's production shape class: long-context pool."""

@@ -440,13 +440,35 @@ def test_paged_kernel_is_named():
 
 
 def test_fused_ce_kernels_are_named():
-    from deepspeed_tpu.ops.transformer.fused_ce import fused_ce_loss
+    from deepspeed_tpu.ops.transformer.fused_ce import head_nll
 
-    x, w = jnp.ones((128, 128)), jnp.ones((256, 128))
-    labels = jnp.zeros((128,), jnp.int32)
+    x, w = jnp.ones((1, 128, 128)), jnp.ones((256, 128))
+    labels = jnp.zeros((1, 128), jnp.int32)
     names = kernel_names(
-        jax.grad(lambda a: jnp.sum(fused_ce_loss(a, w, labels))), x)
+        jax.grad(lambda a: jnp.sum(head_nll(a, w, labels, vocab_major=True))),
+        x)
     assert names == ["fused_ce_fwd", "fused_ce_bwd"]
+
+
+@pytest.mark.parametrize("vocab, path", [(256, "fused"), (200, "plain")])
+def test_the_train_step_says_which_head_loss_it_took(vocab, path, session):
+    """``head_loss`` on the step's ``engine.enqueue`` spans: ``fused`` where
+    the head's shapes fit its kernels, ``plain`` where they do not (here a
+    vocabulary that is no multiple of 128)."""
+    topo_mod.reset_topology()
+    model = TransformerLM(gpt2_config(
+        "125m", vocab_size=vocab, hidden_size=128, num_layers=1, num_heads=2,
+        max_seq_len=128))
+    engine = deepspeed_tpu.initialize(model=model, config={
+        "train_batch_size": 8, "steps_per_print": 0,
+        "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+        "mesh": {"data": 8}})[0]
+    batch = {"input_ids": jnp.zeros((8, 128), jnp.int32)}
+    for _ in range(2):       # the second step is not traced anew and says it too
+        engine.train_batch(iter([batch]))
+    topo_mod.reset_topology()
+    enqueue = by_name(tracing.snapshot())["engine.enqueue"]
+    assert [s.attrs["head_loss"] for s in enqueue] == [path, path]
 
 
 @pytest.mark.parametrize("op_name, scope", [

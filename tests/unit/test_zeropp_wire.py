@@ -109,12 +109,19 @@ def _gather_bytes(totals):
 
 def test_qwz_halves_stage3_weight_gather_wire():
     """zero_quantized_weights: the stage-3 parameter gathers move int8 codes
-    + scales instead of bf16 — ~2x fewer all-gather wire bytes on a ~40M-param
-    trunk (h=1024), measured from the compiled HLO."""
-    base = _collective_bytes({})
-    qwz = _collective_bytes({"zero_quantized_weights": True})
+    + scales instead of bf16 — over 2x fewer all-gather wire bytes on a
+    ~40M-param trunk (h=1024), measured from the compiled HLO of the step
+    that writes its gathers out (the qgZ ``shard_map`` program,
+    ``gather_params_tree``), with and without qwZ. The GSPMD step cannot show
+    it on the CPU: there the partitioner gathers float32 values above the
+    rounding, and how often it gathers a parameter is its own choice (three
+    times before PR 56, once since: 503 MB then, 168 MB now, against qwZ's
+    169 MB)."""
+    base = _collective_bytes({"zero_quantized_gradients": True})
+    qwz = _collective_bytes({"zero_quantized_gradients": True,
+                             "zero_quantized_weights": True})
     gb, gq = _gather_bytes(base), _gather_bytes(qwz)
-    assert gq < 0.65 * gb, (gb, gq)  # ~0.5x + scales/headroom
+    assert gq < 0.65 * gb, (gb, gq)  # ~0.4x: the gradients' int8 hop is in both
 
 
 def test_qgz_qwz_step_wire_under_half_of_unquantized():
