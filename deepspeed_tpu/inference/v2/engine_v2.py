@@ -169,6 +169,9 @@ class InferenceEngineV2:
                 "paged=False: the slot-pooled KV cache was removed (PR 32); "
                 "InferenceEngineV2 serves from the blocked pool only — drop "
                 "the argument")
+        # the constructor's own account (docs/TRACING.md "Set-up and
+        # recompiles"): one always-on `engine.init` record, three phases
+        init = tracing.Phases("engine.init", engine="serve")
         self.model = model
         self.cfg = model.config
         # the sequences share one block pool, so 32 slots cost little
@@ -204,6 +207,7 @@ class InferenceEngineV2:
         if params is None:
             params = model.init_params(jax.random.PRNGKey(0))
         self.params = self._cast_params(params)
+        init.mark("weights")
         #: rolling-weight-update tag (docs/SERVING.md engine pool): opaque
         #: label of the weights currently served, set by ``load_params``
         self.weights_version = None
@@ -319,6 +323,7 @@ class InferenceEngineV2:
                 state_slots=max_seqs if self._stateful else 0)
         self.block_mgr.demote_fn = self._demote_block
         self._bind_nvme_tier()
+        init.mark("state")
         self.kv = model.init_kv_pool(num_blocks, block_size, dtype=dtype)
         #: the slot arrays of a stateful model (``init_state_cache``: a slot
         #: a sequence, row 0 the trash slot), donated to and returned by the
@@ -358,6 +363,8 @@ class InferenceEngineV2:
             f"host_tier_blocks={self.host_tier_blocks}",
             ranks=[0],
         )
+        init.mark("pool")
+        init.close()
 
     def _cast_params(self, params):
         def cast(path, a):
@@ -2010,6 +2017,8 @@ class InferenceEngineV2:
         cancelled wholesale (settling them is impossible), and orphaned
         NVMe-tier files (their bookkeeping dies with the block manager) are
         deleted so the store never serves a previous incarnation's KV."""
+        init = tracing.Phases("engine.init", engine="serve",
+                              rebuild=self.rebuilds + 1)
         self.state = DSStateManager(self.max_seqs, self.max_seq_len)
         # an in-flight dispatch died with the device: its handle can never
         # be fetched against the new incarnation (nor end a bubble)
@@ -2045,6 +2054,7 @@ class InferenceEngineV2:
                 self._drop_block(hid)
         self.block_mgr.demote_fn = self._demote_block
         self._bind_nvme_tier()
+        init.mark("state")
         self.kv = self.model.init_kv_pool(old.num_blocks, old.block_size,
                                           dtype=self.dtype)
         if self._stateful:
@@ -2054,6 +2064,8 @@ class InferenceEngineV2:
             f"InferenceEngineV2.rebuild #{self.rebuilds}: block pool "
             f"replaced ({old.num_blocks}x{old.block_size}, prefix cache "
             f"cold), compiled programs retained", ranks=[0])
+        init.mark("pool")
+        init.close()
 
     def prefill_backlog(self) -> int:
         """Pending (registered but undispatched) tokens across all resident

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...utils import tracing
 from .woq import unpack6
 
 
@@ -81,7 +82,7 @@ def woq_matmul(x, codes, scale, num_bits: int, *, block_out: int = 512):
     while Out % bo:
         bo -= 1
     grid = (Out // bo, ng)
-    return pl.pallas_call(
+    return tracing.pallas_call(
         functools.partial(_kernel, num_bits=num_bits, group=group),
         grid=grid,
         in_specs=[

@@ -20,6 +20,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...utils import tracing
+
 NEG_INF = -1e30
 
 
@@ -178,7 +180,7 @@ def _grid_spec(n_scalar, grid, in_specs, out_specs):
 def _sparse_fwd(q, k, v, kcnt, kidx, *, causal, g, scale, block):
     B, nh, Sq, hd = q.shape
     Skv = k.shape[2]
-    out, lse = pl.pallas_call(
+    out, lse = tracing.pallas_call(
         functools.partial(_fwd_kernel, block=block, causal=causal, scale=scale),
         grid_spec=_grid_spec(
             2, (B, nh, Sq // block),
@@ -209,7 +211,7 @@ def _sparse_bwd(kcnt, kidx, qcnt, qidx, causal, g, scale, block, res, do):
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[..., None]
 
-    dq = pl.pallas_call(
+    dq = tracing.pallas_call(
         functools.partial(_bwd_dq_kernel, block=block, causal=causal, scale=scale),
         grid_spec=_grid_spec(
             2, (B, nh, Sq // block),
@@ -228,7 +230,7 @@ def _sparse_bwd(kcnt, kidx, qcnt, qidx, causal, g, scale, block, res, do):
         name="block_sparse_dq",
     )(kcnt, kidx, q, k, v, do, lse, delta)
 
-    dkh, dvh = pl.pallas_call(
+    dkh, dvh = tracing.pallas_call(
         functools.partial(_bwd_dkv_kernel, block=block, causal=causal, scale=scale),
         grid_spec=_grid_spec(
             2, (B, nh, Skv // block),

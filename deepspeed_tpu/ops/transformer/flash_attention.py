@@ -51,6 +51,7 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from ...comm.topology import MODEL_AXIS, SEQ_AXIS, ZERO_AXES
+from ...utils import tracing
 from ..pallas_utils import open_mesh_axes
 from .attention import UnsupportedFeature, UnsupportedShape, register_impl
 
@@ -242,7 +243,7 @@ def _fwd(q, k, v, *, causal, num_kv_groups, scale, block_q, block_k):
     def kv_tile(b, t, i, h):
         return b, 0, _kv_head(t, h, per_tile, g)[0]
 
-    out, lse = pl.pallas_call(
+    out, lse = tracing.pallas_call(
         functools.partial(_fwd_kernel, hd=hd, g=g, block_k=block_k,
                           chunk=math.gcd(FWD_CHUNK, block_q), causal=causal,
                           scale=scale, inside=Sq <= Skv),
@@ -375,7 +376,7 @@ def _bwd(causal, num_kv_groups, scale, block_q, block_k, res, do):
     row = pl.BlockSpec((1, 1, per_tile, Sq), lambda b, t, i, h: (b, t, 0, 0))
     dkv = pl.BlockSpec((1, block_k, width), lambda b, t, i, h: (b, i, t))
 
-    dq, dkh, dvh = pl.pallas_call(
+    dq, dkh, dvh = tracing.pallas_call(
         functools.partial(_bwd_kernel, hd=hd, g=g, block_q=block_q,
                           chunk=math.gcd(BWD_CHUNK, block_k), causal=causal,
                           scale=scale, inside=Skv <= Sq),

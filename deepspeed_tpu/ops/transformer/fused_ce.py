@@ -118,7 +118,7 @@ def _fwd_call(x, w, vocab_major, block_r, block_v):
     N, H = x.shape
     V = w.shape[0 if vocab_major else 1]
     nr = N // block_r
-    lg, stat = pl.pallas_call(
+    lg, stat = tracing.pallas_call(
         functools.partial(_fwd_kernel, vocab_major=vocab_major,
                           chunk=min(CHUNK, block_r)),
         grid=(V // block_v, nr),
@@ -181,7 +181,7 @@ def _bwd_call(lg, lse, labels, g, x, w, vocab_major, block_r, block_v):
     rows = jnp.pad(jnp.stack([lse, g, labels.astype(jnp.float32)], axis=1),
                    ((0, 0), (0, LANES - 3)))
     xt = x.reshape(nr, block_r, H).swapaxes(1, 2)          # (nr, H, R)
-    dx, dw = pl.pallas_call(
+    dx, dw = tracing.pallas_call(
         functools.partial(_bwd_kernel, vocab_major=vocab_major,
                           block_v=block_v, chunk=min(CHUNK, block_r)),
         grid=(V // block_v, nr),

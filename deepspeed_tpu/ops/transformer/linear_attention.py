@@ -45,6 +45,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...utils import tracing
+
 _HI = jax.lax.Precision.HIGHEST
 
 #: heads of one cell of :func:`linear_decode`: a state block of 8 x 128 x 128
@@ -141,7 +143,7 @@ def linear_decode(state, layer, slots, q, k, v, fresh):
         out_specs=[row, slot],
     )
     with jax.named_scope("linear_attn"):
-        o, state = pl.pallas_call(
+        o, state = tracing.pallas_call(
             functools.partial(_decode_kernel, heads=hb, n_heads=H),
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((R, H // hb, hb, dv), jnp.float32),

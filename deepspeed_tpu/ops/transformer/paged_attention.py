@@ -60,6 +60,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...utils import tracing
+
 NEG_INF = -1e30
 
 #: rows of one chunk-segment tile of the latent paged program: consecutive
@@ -367,7 +369,7 @@ def kv_write(pool, layer, blk, off, kv):
             pltpu.SemaphoreType.DMA((slots,)),
         ],
     )
-    return pl.pallas_call(
+    return tracing.pallas_call(
         functools.partial(_kv_write_kernel, rows=B, sub=SUB_TILE, slots=slots),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
@@ -653,7 +655,7 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None, new_rows=None):
         operands = (q.reshape(B, kvh, g, hd), pool)
         in_specs, out_specs = [block, in_hbm], block
         out_shape = jax.ShapeDtypeStruct((B, kvh, g, hd), q.dtype)
-    out = pl.pallas_call(
+    out = tracing.pallas_call(
         functools.partial(_decode_kernel, block_size=BS, bpt=bpt, hd=hd,
                           write=write,
                           scale=scale if scale is not None else hd ** -0.5),
@@ -899,7 +901,7 @@ def mla_decode(q_lat, q_rope, pool, layer, tables, limits, *, scale,
             pltpu.SMEM((1,), jnp.int32),          # the next trip's slot
         ],
     )
-    out = pl.pallas_call(
+    out = tracing.pallas_call(
         functools.partial(_mla_kernel, block_size=BS, rank=rank, scale=scale,
                           kv_blocks=kv_blocks),
         grid_spec=grid_spec,

@@ -282,6 +282,9 @@ class DeepSpeedEngine:
         model_params=None,
         dont_change_device: bool = False,
     ):
+        # the constructor's own account (docs/TRACING.md "Set-up and
+        # recompiles"): one always-on `engine.init` record, five phases
+        init = tracing.Phases("engine.init", engine="train")
         self.config = config
         self.module = model
         self.topology = topology or dist.get_topology()
@@ -441,6 +444,7 @@ class DeepSpeedEngine:
                     "data_efficiency.data_sampling (DeepSpeedDataSampler)")
             self._curriculum = CurriculumScheduler(cl)
 
+        init.mark("model")
         # ---- sharding rules per ZeRO stage ----
         stage = config.zero_config.stage
         self.zero_stage = stage
@@ -502,6 +506,7 @@ class DeepSpeedEngine:
             else:
                 self.master_params = None
 
+        init.mark("params")
         # ---- optimizer ----
         self.client_optimizer = optimizer
         if optimizer is not None:
@@ -530,7 +535,10 @@ class DeepSpeedEngine:
             master_like = self.master_params if self._mixed else self.params
             opt_state = self.optimizer.init(master_like)
             # moments shard like the master/opt specs; step counter replicated
+            # (placed as the step returns it: a counter left uncommitted made
+            # the second step a second program, traced, lowered and compiled)
             self.opt_state = opt_state._replace(
+                step=jax.device_put(opt_state.step, self._replicated),
                 m=None if opt_state.m is None else jax.device_put(opt_state.m, self._opt_shardings),
                 v=None if opt_state.v is None else jax.device_put(opt_state.v, self._opt_shardings),
             )
@@ -589,8 +597,10 @@ class DeepSpeedEngine:
                     "schedule's fake-quant forward"
                 )
 
+        init.mark("optimizer")
         # ---- compiled fns ----
         self._build_compiled_fns()
+        init.mark("functions")
 
         # reference compile() / is_compiled surface (runtime/compiler.py):
         # the step IS whole-program compiled; this records/validates the block
@@ -606,6 +616,8 @@ class DeepSpeedEngine:
             f"{config.train_micro_batch_size_per_gpu},{config.gradient_accumulation_steps})",
             ranks=[0],
         )
+        init.mark("preflight")
+        init.close()
 
     def _memory_preflight(self) -> None:
         """OOM guard (reference analogue: the autotuner's memory model,

@@ -17,12 +17,19 @@ The flag is ``TraceMe.is_enabled()`` of the installed jaxlib's profiler
 bindings (``jax._src.lib._profiler``), the same flag ``TraceAnnotation``
 itself consults; ``enabled`` is the only name bound to it.
 
-While on, a ``jax.monitoring`` listener records every backend compile, and
-every load of a program from the compile cache, as a ``compile`` span, and
-:func:`note_program` remembers which jitted programs ran, so that
-:func:`device_scopes` can map the device's instruction names to the
-``jax.named_scope`` they were traced under, and :func:`device_programs` to
-the program variants that hold them — after the window, never in it.
+One thing is kept whether or not a profiler records: what it cost to make a
+program runnable. A ``jax.monitoring`` listener, which runs only when JAX
+builds a program, folds the program's trace, its lowering and its backend
+compile (or its load from the compile cache) into one ``compile`` record
+with the package function that made the call (:func:`builds`); every kernel
+is bound through :func:`pallas_call`, which puts the tracing of its body on
+that record by name; and an engine's constructor writes one ``engine.init``
+record (:class:`Phases`, :func:`inits`). While on, the same ``compile``
+record is also a span, and :func:`note_program` remembers which jitted
+programs ran, so that :func:`device_scopes` can map the device's instruction
+names to the ``jax.named_scope`` they were traced under, and
+:func:`device_programs` to the program variants that hold them — after the
+window, never in it.
 
 No other module of ``deepspeed_tpu`` calls ``jax.profiler`` annotations
 directly.
@@ -33,6 +40,7 @@ import contextlib
 import contextvars
 import itertools
 import re
+import sys
 import threading
 import time
 from typing import Dict, Iterable, List, NamedTuple, Optional, Set
@@ -59,7 +67,13 @@ class Record(NamedTuple):
     attrs: dict
 
 
+#: ``compile`` and ``engine.init`` records kept while the profiler is off too
+#: (a set-up leaves a few hundred)
+MAX_KEPT = 1 << 12
+
 _buf: "collections.deque[Record]" = collections.deque(maxlen=MAX_SPANS)
+#: the always-on store: what was built and constructed, oldest first
+_kept: "collections.deque[Record]" = collections.deque(maxlen=MAX_KEPT)
 _ids = itertools.count(1)
 _local = threading.local()
 
@@ -207,7 +221,8 @@ def snapshot() -> List[Record]:
 
 def clear() -> None:
     """Forget the recorded spans and the programs noted for
-    :func:`device_scopes`."""
+    :func:`device_scopes`. The always-on records (:func:`builds`,
+    :func:`inits`) stay: a set-up is not the window's to forget."""
     _buf.clear()
     _programs.clear()
     _scope_cache.clear()
@@ -229,28 +244,219 @@ def descendants(spans: Iterable[Record], root: int) -> List[Record]:
     return out
 
 
-# -- compiles --------------------------------------------------------------
+# -- builds: what it cost to make each program runnable ---------------------
 
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILE = "/jax/core/compile/backend_compile_duration"
 _CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
 
+#: a build shorter than this that no package function asked for (the caller's
+#: one-primitive eager programs) is summed by caller module, not kept
+SMALL_BUILD_S = 0.010
+
+#: {caller module: [builds, trace_s, lower_s, load_s]} of those
+_small: Dict[str, List[float]] = {}
+#: ns spent inside the listener, over the process: the account's own cost
+_listener_ns = [0]
+
+#: frames that are no caller: JAX's own and the wrappers it runs under
+_NOT_CALLER = ("jax.", "jaxlib.", "contextlib", "functools")
+#: the package's own wrapper of ``jax.jit`` (``audited_jit``): the call was
+#: made by whoever called through it
+_JIT_WRAPPER = "deepspeed_tpu.analysis.program_audit"
+
+
+def _pending() -> list:
+    """This thread's trace, lowering and kernel-bind intervals that no
+    finished build has claimed yet: (kind, name, start, end), by ``end``."""
+    try:
+        return _local.pending
+    except AttributeError:
+        _local.pending = []
+        return _local.pending
+
+
+def _site(frame):
+    """(site, caller) of the build reported from ``frame``: the innermost
+    function of this package on the stack, ``module.qualname`` ("": none, a
+    program of the caller's own), and the module of the innermost frame that
+    is not JAX's."""
+    caller = ""
+    while frame is not None:
+        mod = frame.f_globals.get("__name__", "")
+        if mod.startswith("deepspeed_tpu.") and mod != _JIT_WRAPPER \
+                and frame.f_code is not _Kernel.__call__.__code__:
+            return f"{mod}.{frame.f_code.co_qualname}", caller or mod
+        if not caller and mod != "jax" and not mod.startswith(_NOT_CALLER):
+            caller = mod
+        frame = frame.f_back
+    return "", caller
+
+
+def _close_build(program: str, load_s: float, end: int, frame) -> None:
+    """The backend compile (or the cache load) of ``program`` just ended on
+    this thread: claim its lowering and its own trace from the pending
+    intervals, with the kernels bound inside that trace, and keep one
+    record. Inner jits' traces lie inside the program's own and are dropped
+    with it, not added."""
+    cached = getattr(_local, "cache_read", False)
+    _local.cache_read = False
+    pend = _pending()
+    lower = trace = None
+    for i in range(len(pend) - 1, -1, -1):
+        kind, name = pend[i][0], pend[i][1]
+        if lower is None and kind == "lower" and name == program:
+            lower = i
+        elif kind == "trace" and program.endswith(f"({name})"):
+            trace = i
+            break
+    trace_s = lower_s = 0.0
+    kernels: Dict[str, list] = {}
+    cut = lower
+    if lower is not None:
+        lower_s = (pend[lower][3] - pend[lower][2]) / 1e9
+    if trace is not None:
+        _, _, start, stop = pend[trace]
+        trace_s = (stop - start) / 1e9
+        cut = trace
+        while cut and pend[cut - 1][3] >= start:
+            cut -= 1
+            kind, name, k_start, k_stop = pend[cut]
+            if kind == "kernel":
+                into = kernels.setdefault(name, [0, 0.0])
+                into[0] += 1
+                into[1] += (k_stop - k_start) / 1e9
+    if cut is not None:
+        del pend[cut:]
+    site, caller = _site(frame)
+    big = bool(site) or trace_s + lower_s + load_s >= SMALL_BUILD_S
+    if not big:
+        into = _small.setdefault(caller, [0, 0.0, 0.0, 0.0])
+        for i, x in enumerate((1, trace_s, lower_s, load_s)):
+            into[i] += x
+    keep("compile", end - int(load_s * 1e9), end, kept=big,
+         program=program, cached=cached, site=site, caller=caller,
+         trace_s=trace_s, lower_s=lower_s, load_s=load_s,
+         kernels={k: tuple(v) for k, v in kernels.items()})
+
 
 def _on_duration(name: str, seconds: float, **kw) -> None:
-    """JAX reports ``_COMPILE`` once per program it had to make runnable,
-    compiled or loaded from the compile cache; a load reports ``_CACHE_READ``
-    first, from inside it, on the same thread."""
-    if name == _CACHE_READ:
+    """JAX reports, on the thread that makes a program runnable: ``_TRACE``
+    for every jitted function it traces (an inner one's from inside the
+    outer's), ``_LOWER`` for the program's lowering, and ``_COMPILE`` once
+    it is compiled or loaded from the compile cache; a load reports
+    ``_CACHE_READ`` first, from inside it. Nothing of this runs when a
+    program that exists is called."""
+    now = clock_ns()
+    if name == _TRACE or name == _LOWER:
+        pend = _pending()
+        if len(pend) > 2048:        # traces nobody compiled (eval_shape)
+            del pend[:1024]
+        pend.append(("trace" if name == _TRACE else "lower",
+                     kw.get("fun_name", ""), now - int(seconds * 1e9), now))
+    elif name == _CACHE_READ:
         _local.cache_read = True
     elif name == _COMPILE:
-        cached = getattr(_local, "cache_read", False)
-        _local.cache_read = False
-        if enabled():
-            end = clock_ns()
-            event("compile", end - int(seconds * 1e9), end,
-                  program=kw.get("fun_name", ""), cached=cached)
+        _close_build(kw.get("fun_name", ""), seconds, now, sys._getframe(1))
+    else:
+        return
+    _listener_ns[0] += clock_ns() - now
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def keep(name: str, start: int, end: int, kept: bool = True, **attrs) -> None:
+    """A record of the always-on store (``kept``), on :data:`clock_ns`; while
+    on, the same record is a span of the window too."""
+    on = enabled()
+    if not (kept or on):
+        return
+    stack = _stack() if on else ()
+    rec = Record(next(_ids), name, int(start), int(end),
+                 stack[-1] if stack else 0, attrs)
+    if kept:
+        _kept.append(rec)
+    if on:
+        _buf.append(rec)
+
+
+def builds() -> List[Record]:
+    """Every program this process made runnable, oldest first, profiler or
+    no profiler: ``compile`` records whose span is the backend compile or
+    the cache load and whose attributes are ``program`` (JAX's name of it),
+    ``site``, ``caller``, ``trace_s``, ``lower_s``, ``load_s``, ``cached``
+    and ``kernels`` ({name: (binds, seconds)}). A live process that
+    recompiles shows it here. Short builds of the caller's own are in
+    :func:`small_builds` instead."""
+    return [r for r in _kept if r.name == "compile"]
+
+
+def small_builds() -> Dict[str, tuple]:
+    """{caller module: (builds, trace_s, lower_s, load_s)} of the builds
+    under :data:`SMALL_BUILD_S` that no function of this package asked for."""
+    return {k: tuple(v) for k, v in _small.items()}
+
+
+def inits() -> List[Record]:
+    """The ``engine.init`` records: one an engine constructed (or rebuilt)."""
+    return [r for r in _kept if r.name == "engine.init"]
+
+
+def listener_seconds() -> float:
+    """What the account has cost this process: seconds spent inside the
+    ``jax.monitoring`` listener, the stack walks included."""
+    return _listener_ns[0] / 1e9
+
+
+class _Kernel:
+    """What :func:`pallas_call` returns: the kernel's bind, timed under a
+    trace."""
+    __slots__ = ("name", "call")
+
+    def __init__(self, name: str, call):
+        self.name, self.call = name, call
+
+    def __call__(self, *args):
+        if not any(isinstance(a, jax.core.Tracer) for a in args):
+            return self.call(*args)     # an eager call: a program of its own
+        start = clock_ns()
+        try:
+            return self.call(*args)
+        finally:
+            _pending().append(("kernel", self.name, start, clock_ns()))
+
+
+def pallas_call(kernel, **kwargs):
+    """``pl.pallas_call`` for every kernel of the package. Binding a kernel
+    traces its body's Python, in every process and for every program that
+    holds it, compile cache or none: under a trace the bind is timed and
+    lands, by the kernel's ``name``, on the record of the program being
+    traced on this thread. It runs when a program is traced and never when
+    it executes."""
+    from jax.experimental import pallas as pl
+
+    return _Kernel(kwargs["name"], pl.pallas_call(kernel, **kwargs))
+
+
+class Phases:
+    """A constructor's own account: opened where it starts, ``mark(phase)``
+    where a phase ends, ``close()`` writes one always-on record whose
+    attributes are the phases' seconds (``<phase>_s``). Once an engine."""
+    __slots__ = ("name", "attrs", "start", "_last")
+
+    def __init__(self, name: str, **attrs):
+        self.name, self.attrs = name, attrs
+        self.start = self._last = clock_ns()
+
+    def mark(self, phase: str) -> None:
+        now = clock_ns()
+        self.attrs[phase + "_s"] = (now - self._last) / 1e9
+        self._last = now
+
+    def close(self) -> None:
+        keep(self.name, self.start, clock_ns(), **self.attrs)
 
 
 # -- device time by scope --------------------------------------------------

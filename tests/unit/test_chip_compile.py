@@ -107,6 +107,32 @@ def test_flash_attention_compiles(one_chip, no_compile_cache, as_tpu, via,
         "flash attention gave way to the XLA path"
 
 
+def test_a_kernel_programs_build_record_names_its_kernels(
+        one_chip, no_compile_cache, as_tpu):
+    """The always-on account (docs/TRACING.md "Set-up and recompiles") of a
+    program lowered and compiled for the described chip: one record, made by
+    the test (no ``site``), with both flash kernels by name, how often each
+    was bound while the program was traced and what tracing their bodies
+    cost; lowering them to Mosaic is inside ``lower_s``."""
+    from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
+    from deepspeed_tpu.utils import tracing
+
+    def kernel_program(q, k, v):
+        return sq_loss(lambda *a: flash_attention(*a, causal=True))(q, k, v)
+
+    mark = tracing.clock_ns()
+    q = aval(one_chip, (2, S, NH, HD), jnp.bfloat16)
+    compile_text(jax.grad(kernel_program, argnums=(0, 1, 2)), q, q, q)
+    rec, = [r for r in tracing.builds()
+            if r.end > mark and "kernel_program" in r.attrs["program"]]
+    a = rec.attrs
+    assert a["site"] == "" and not a["cached"]
+    assert a["trace_s"] > 0 and a["lower_s"] > 0 and a["load_s"] > 0
+    assert set(a["kernels"]) == {"flash_fwd", "flash_bwd"}
+    for calls, seconds in a["kernels"].values():
+        assert calls == 1 and 0 < seconds < a["trace_s"]
+
+
 #: a ``copy`` or ``transpose`` of the compiled program (at the top level or
 #: inside a fusion) and its result's dimensions
 LAYOUT_MOVE = re.compile(r"= \w+\[([\d,]+)\]\S* (?:copy|transpose)\(")

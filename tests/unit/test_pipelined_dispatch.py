@@ -807,9 +807,15 @@ class TestStepAccounting:
         assert len(bubbles) >= 3 and len(flights) > len(bubbles)
         for b in bubbles:
             # the launch that ends the bubble returns just before its clock
-            # reading: that flight alone may begin inside the last instants
+            # reading: that flight alone, the one enqueued under the bubble's
+            # own ``engine.dispatch``, may begin inside the last instants
+            # (how many is the host's to say: 50 us was a guess a busy
+            # sandbox broke)
+            own = {s.end for s in spans
+                   if s.name == "engine.enqueue" and s.parent == b.parent}
+            assert own
             assert not [(lo, hi) for lo, hi in flights
-                        if lo < b.end - 50_000 and hi > b.start]
+                        if lo not in own and lo < b.end and hi > b.start]
         # and they follow each other: no two bubbles overlap either
         ends = sorted((b.start, b.end) for b in bubbles)
         assert all(a[1] <= b[0] for a, b in zip(ends, ends[1:]))
