@@ -40,6 +40,7 @@ from ..comm.topology import ZERO_AXES
 from ..ops.quantizer.woq import dequant_params as _dequant_woq
 from ..ops.transformer.attention import attention as _attention_op
 from ..ops.transformer.fused_ce import head_nll
+from ..ops.transformer.gelu_exact import gelu_exact
 from ..utils.logging import logger
 
 
@@ -64,7 +65,9 @@ class TransformerConfig:
     mlm_head: bool = False  # BERT MLM head: dense+act+LN before the tied decoder
     pos_embedding: str = "learned"  # "learned" | "rope" | "alibi" | "none"
     norm: str = "layernorm"  # "layernorm" | "rmsnorm"
-    activation: str = "gelu"  # "gelu" (tanh) | "gelu_exact" | "relu" | "swiglu" | "geglu"
+    # "gelu" (tanh) | "gelu_exact" (erf: ops/transformer/gelu_exact.py, one unit
+    # with its own backward, evaluated once a pass) | "relu" | "swiglu" | "geglu"
+    activation: str = "gelu"
     tie_embeddings: bool = True
     qkv_bias: bool = False  # GPT-2-style biases on q/k/v projections
     attn_out_bias: bool = False  # bias on the attention out-proj even under rmsnorm (InternLM)
@@ -1294,8 +1297,10 @@ class TransformerLM:
                     up = checkpoint_name(up, "mlp_up")
                     if cfg.activation == "relu":
                         inter = jax.nn.relu(up)
+                    elif cfg.activation == "gelu_exact":
+                        inter = gelu_exact(up)
                     else:
-                        inter = jax.nn.gelu(up, approximate=cfg.activation != "gelu_exact")
+                        inter = jax.nn.gelu(up, approximate=True)
                 inter = checkpoint_name(inter, "mlp_act")
                 mlp_out = inter @ blk["w_down"].astype(h.dtype)
             if "mlp_bias" in blk:
@@ -1754,8 +1759,10 @@ class TransformerLM:
                 + params["mlm_dense_bias"].astype(x.dtype)
             if cfg.activation == "relu":  # transform act follows hidden_act
                 x = jax.nn.relu(x)
+            elif cfg.activation == "gelu_exact":
+                x = gelu_exact(x)
             else:
-                x = jax.nn.gelu(x, approximate=cfg.activation != "gelu_exact")
+                x = jax.nn.gelu(x, approximate=True)
             x = _norm(x, params["mlm_ln_scale"], params["mlm_ln_bias"],
                       "layernorm", cfg.norm_eps)
             return x, params["wte"], params["mlm_bias"], True

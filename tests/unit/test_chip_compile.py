@@ -247,6 +247,108 @@ def test_the_head_leaves_the_layers_activations_where_they_lie(gpt2_layer_step):
     assert len(copies) <= 2, copies
 
 
+#: sha256 of the one-layer GPT-2 step's feed-forward (``sublayer_lines`` of
+#: the ``mlp`` scope, joined by newlines) at commit 30574ee (PR 57): the tanh
+#: form's products, forward, saved and backward. PR 58 gave ``"gelu_exact"``
+#: its own unit and left this expression letter for letter
+TANH_MLP_SHA = ("a2fb595041280b2f89d7700e64a49e0e"
+                "ab4b2c86b46f5315c479ad846381c512")
+
+
+def test_the_tanh_layer_compiles_to_what_it_was(gpt2_layer_step):
+    import hashlib
+
+    lines = sublayer_lines(gpt2_layer_step, scopes=("mlp",))
+    assert any(" tanh(" in line for line in lines)
+    digest = hashlib.sha256("\n".join(lines).encode()).hexdigest()
+    assert digest == TANH_MLP_SHA
+
+
+PYTHIA_MLP = (4, 2048, 8192)    # a chip's tokens of the four-chip cell x 4H
+
+
+def computations(text):
+    """``{name: [instruction, ...]}`` of optimized HLO: every fused
+    computation (an operand's prologue is one of its own, called from the
+    product's) and the entry."""
+    found, lines = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            lines = found.setdefault(head.group(1), [])
+        elif lines is not None:
+            lines.append(line.strip())
+    return found
+
+
+def activation_evaluations(text, shape=PYTHIA_MLP):
+    """``[(has a product, arrays of ``shape`` it returns)]`` for every
+    computation that holds an ``exponential`` or a ``divide`` over an array
+    of ``shape``: where the exact GELU is evaluated."""
+    dims = ",".join(map(str, shape))
+    found = []
+    for lines in computations(text).values():
+        costly = rf"= f32\[{dims}\]\S* (exponential|divide)\("
+        if not any(re.search(costly, line) for line in lines):
+            continue
+        returns = next(re.match(r"ROOT \S+ = (.*?) [a-z\-]+\(", line).group(1)
+                       for line in lines if line.startswith("ROOT "))
+        found.append((any(" convolution(" in line for line in lines),
+                      len(re.findall(rf"bf16\[{dims}\]", returns))))
+    return sorted(found)
+
+
+@pytest.fixture(scope="module")
+def pythia_block(one_chip, no_compile_cache):
+    """One block at Pythia-1.4B's widths over a chip's tokens of the four-chip
+    cell: ``(forward's text, text of jax.grad of the checkpointed block)``."""
+    model = serving_model(128, 1)
+    blk = jax.tree.map(
+        lambda a: aval(one_chip, a.shape[1:], jnp.bfloat16),
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0))["blocks"])
+    x = aval(one_chip, PYTHIA_MLP[:2] + (model.config.hidden_size,),
+             jnp.bfloat16)
+
+    def block(blk, x):
+        positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+        return model._block(x, blk, positions=positions, rng=None,
+                            train=True)[0]
+
+    def loss(blk, x, d):
+        return jnp.sum((jax.checkpoint(block)(blk, x) * d).astype(jnp.float32))
+
+    with pytest.MonkeyPatch.context() as patch:   # as_tpu, at module scope
+        patch.delenv("DSTPU_PALLAS_INTERPRET", raising=False)
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        return (compile_text(block, blk, x),
+                compile_text(jax.grad(loss, argnums=(0, 1)), blk, x, x))
+
+
+def test_the_exact_gelu_is_evaluated_once_a_pass(pythia_block):
+    """``exponential`` and ``divide`` over ``[4,2048,8192]`` stand in the
+    fusion that makes ``h`` (``x @ w_up + b``, the activation its epilogue)
+    and nowhere else: once in the forward, which returns ``a`` alone, and
+    once in the backward's rematerialised forward, which returns ``a`` and
+    ``g`` (``jax.grad`` of a loss linear in the block's output keeps no
+    other forward). The products that read them (``a @ w_down``,
+    ``w_down``'s gradient, ``d_out @ w_down^T``) hold neither. The parent
+    evaluated ``erfc``'s expansion in the prologue of the first two and the
+    epilogue of the third: 59-65% of peak on the chip for their neighbours'
+    91-95%."""
+    forward, backward = pythia_block
+    assert activation_evaluations(forward) == [(True, 1)]
+    assert activation_evaluations(backward) == [(True, 2)]
+
+
+def test_the_forward_writes_one_array_of_the_mlps_width(pythia_block):
+    """No derivative and no second ``[tokens, 4H]`` array in the forward:
+    the entry computation writes ``a`` and nothing else of that size (``h``
+    stays inside the product's fusion)."""
+    arrays = vocab_sized(pythia_block[0], tokens=math.prod(PYTHIA_MLP[:2]),
+                         vocab=PYTHIA_MLP[2])
+    assert [dtype for _, dtype in arrays] == ["bf16"], arrays
+
+
 @pytest.mark.parametrize("tokens, width, vocab_major, kernels", [
     ((8, 1024), 1024, True, 2),     # gpt2-medium.train-seq1024: both kernels
     ((4, 2048), 2048, False, 1),    # pythia-1.4b, lm_head (2048, 50304): the
