@@ -98,7 +98,7 @@ def serve_readings(cell, seed, devices, control, rehearsal, state):
     served = w.tree_as(dtype)
     if engine is None:
         engine = state["engine"] = InferenceEngineV2(
-            model, served, paged=True, dtype=dtype, **mix["engine"])
+            model, served, dtype=dtype, **mix["engine"])
 
     def readings():
         return {"weights_mismatch_share": check.weights_mismatch_share(

@@ -203,8 +203,7 @@ def run(cell, seed, seconds, trace, devices, rehearsal=False):
     dtype = jnp.dtype(cell.config["dtype"])
     served = weights.tree_as(dtype)
     setup.mark("weights", served)
-    engine = InferenceEngineV2(model, served, paged=True, dtype=dtype,
-                               **mix["engine"])
+    engine = InferenceEngineV2(model, served, dtype=dtype, **mix["engine"])
     del served
     setup.mark("engine", engine.kv)
     verdict = check.Verdict(cell.config["tolerances"]["serve"])

@@ -1,11 +1,18 @@
 """Profiler trace -> device busy/idle, an operation table, idle gaps by host
-annotation.
+annotation, and the part of the window the device's events cover.
 
 ``Tracer`` wraps the measured window of a ``--trace 1`` run in
 ``jax.profiler`` and marks the benchmark's own host spans with
 ``TraceAnnotation`` so that they land on the trace's clock. ``reduce_xplane``
 is the reduction; it reads the ``.xplane.pb`` with ``jax.profiler.ProfileData``
 and nothing else, and is tested against ``fixtures/``.
+
+The profiler can lose the device events of part of a window (the check of
+PR 58 read 40.8% idle in a cell that idles 4%: the events covered five of the
+eight seconds) while the host's spans and counters cover all of it. So the
+reduction returns the covered interval, and the tracer an anchor that puts it
+on the host's clock: ``readers/covered.py`` pairs device seconds with the
+host-side work of the same interval.
 """
 
 import bisect
@@ -20,6 +27,9 @@ import time
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute", "collective-broadcast")
 _NULL = contextlib.nullcontext()
+#: the annotation the tracer writes at each edge of the window, its instant
+#: read on the host's clock beside it
+ANCHOR = "bench.clock"
 
 
 _TEXT = re.compile(r"^%(\S+) = (.*)$", re.S)
@@ -108,7 +118,17 @@ def _host_spans(profile, names):
     return sorted(out, key=lambda s: s[1])
 
 
-def reduce_xplane(path, span_names=(), window_s=None):
+def clock_offset(found, anchor_ns):
+    """Profiler clock minus host clock, in ns: from the ``ANCHOR`` annotation
+    among the host spans ``found`` and the host-clock instant ``anchor_ns``
+    the tracer read as it wrote it. None where the trace holds no anchor."""
+    mine = [s for name, s, _ in found if name == ANCHOR]
+    if not mine or anchor_ns is None:
+        return None
+    return mine[0] - anchor_ns
+
+
+def reduce_xplane(path, span_names=(), window_s=None, anchor_ns=None):
     """The numbers the per-layer readers use, from one ``.xplane.pb``.
 
     ``busy_s``: union of operation intervals per device, averaged over
@@ -116,6 +136,10 @@ def reduce_xplane(path, span_names=(), window_s=None):
     count). ``idle_by_span``: the first device's idle seconds attributed to the
     host annotation open at each gap's midpoint (``none`` where there is none).
     ``window_s``: given, or first operation start to last operation end.
+    ``covered_ns``: the first device's first operation start and last operation
+    end, on the profiler's clock. ``clock_offset_ns``: what to take from a
+    profiler instant to get the host clock's (``anchor_ns``: the host-clock
+    instant of the tracer's ``ANCHOR`` annotation), None without an anchor.
     """
     from jax.profiler import ProfileData
 
@@ -135,7 +159,8 @@ def reduce_xplane(path, span_names=(), window_s=None):
         sec, cnt = ops.get(name, (0.0, 0))
         ops[name] = (sec + dur / 1e9, cnt + 1)
     span_s = (merged[-1][1] - merged[0][0]) / 1e9 if merged else 0.0
-    spans = _host_spans(profile, set(span_names))
+    found = _host_spans(profile, set(span_names) | {ANCHOR})
+    spans = [s for s in found if s[0] != ANCHOR]
     idle = {}
     starts = [s for _, s, _ in spans]
     for (_, a), (b, _) in zip(merged, merged[1:]):
@@ -153,6 +178,8 @@ def reduce_xplane(path, span_names=(), window_s=None):
         "busy_s": sum(busy) / len(busy),
         "busy_first_s": busy[0],
         "window_s": window_s if window_s is not None else span_s,
+        "covered_ns": (merged[0][0], merged[-1][1]) if merged else None,
+        "clock_offset_ns": clock_offset(found, anchor_ns),
         "ops": ops,
         "idle_by_span": idle,
     }
@@ -181,6 +208,7 @@ class Tracer:
         self.spans = {}
         self._dir = None
         self._t0 = None
+        self.window_ns = None       # the window's edges on the host's clock
 
     def start(self):
         if not self.on:
@@ -193,6 +221,13 @@ class Tracer:
         opts.host_tracer_level = 2
         jax.profiler.start_trace(self._dir, profiler_options=opts)
         self._t0 = time.perf_counter()
+        # the anchor: one annotation, with the host's clock read as it opens.
+        # time.monotonic_ns is the clock the program's recorder stamps its
+        # spans with (tracing.clock_ns), which the covered interval is
+        # compared with
+        self.window_ns = (time.monotonic_ns(), None)
+        with jax.profiler.TraceAnnotation(ANCHOR):
+            pass
 
     @contextlib.contextmanager
     def _span(self, name):
@@ -211,6 +246,7 @@ class Tracer:
         if self.on:
             import jax
 
+            self.window_ns = (self.window_ns[0], time.monotonic_ns())
             self.window_s = time.perf_counter() - self._t0
             jax.profiler.stop_trace()
 
@@ -227,7 +263,11 @@ class Tracer:
             keep = os.environ.get("BENCH_KEEP_TRACE")   # how fixtures/ is recorded
             if keep:
                 shutil.copy(found[0], keep)
-            return reduce_xplane(found[0], self.spans.keys(),
-                                 window_s=self.window_s)
+            out = reduce_xplane(found[0], self.spans.keys(),
+                                window_s=self.window_s,
+                                anchor_ns=self.window_ns[0])
+            if out is not None:
+                out["window_ns"] = self.window_ns
+            return out
         finally:
             shutil.rmtree(self._dir, ignore_errors=True)

@@ -3,6 +3,7 @@ its operation line. That line is serial: while a collective (or the ``-done``
 half of an asynchronous one) occupies it, no compute operation runs."""
 
 from benchmark.harness.trace import is_collective
+from benchmark.readers import covered
 
 
 def read(ctx):
@@ -10,4 +11,4 @@ def read(ctx):
     if not t or not t["window_s"]:
         return None
     secs = sum(s for name, (s, _) in t["ops"].items() if is_collective(name))
-    return 100.0 * secs / t["window_s"]
+    return 100.0 * secs / covered.accounted_s(ctx)

@@ -1,20 +1,24 @@
 """The train step's attention kernels against their roofline: the least time
 the chip could take (the larger of FLOPs over peak FLOP/s and bytes over peak
 bytes/s, from ``kernels/flash_attention.py``) over the summed device time of
-the step's custom calls (flash forward and fused backward are its only two
-kinds)."""
+the kernels the program names ``flash_*`` (forward and fused backward). The
+step holds other kernels too (the head's ``fused_ce_*``): they are not
+attention and are left out."""
 
-from benchmark.harness.trace import op_kind
 from benchmark.kernels import flash_attention
+from benchmark.readers import covered
+from benchmark.readers.trace_kernel_ms import kernel_seconds
+
+#: the family name the program gives its flash-attention kernels
+KERNEL = "flash_"
 
 
 def read(ctx):
     t, c, peak = ctx["trace"], ctx["counters"], ctx["peak"]
     if not t or peak is None:
         return None
-    secs = sum(s for name, (s, _) in t["ops"].items()
-               if op_kind(name) == "kernel")
-    steps = c.get("steps")
+    secs = kernel_seconds(t, KERNEL)
+    steps = covered.per(ctx, "steps")
     if not secs or not steps:
         return None
     flops, nbytes = flash_attention.train_step(

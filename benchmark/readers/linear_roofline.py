@@ -5,6 +5,7 @@ from ``decode_rows`` of the ``engine.dispatch`` spans) over the summed device
 time of the kernels the program names ``linear_decode*``."""
 
 from benchmark.kernels import linear_attention
+from benchmark.readers.covered import inside
 from benchmark.readers.program_spans import spans
 from benchmark.readers.trace_kernel_ms import kernel_seconds
 
@@ -13,7 +14,7 @@ KERNEL = "linear_decode"
 
 def read(ctx):
     trace, peak = ctx["trace"], ctx["peak"]
-    found = spans("engine.dispatch")
+    found = inside(ctx, spans("engine.dispatch"))
     if not trace or peak is None or not found:
         return None
     secs = kernel_seconds(trace, KERNEL)

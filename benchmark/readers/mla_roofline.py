@@ -5,6 +5,7 @@ counts the engine's ``engine.dispatch`` spans carry) over the summed device
 time of the kernels the program names ``mla_decode*``."""
 
 from benchmark.kernels import mla_attention
+from benchmark.readers.covered import inside
 from benchmark.readers.program_spans import spans
 from benchmark.readers.trace_kernel_ms import kernel_seconds
 
@@ -14,7 +15,7 @@ KERNEL = "mla_decode"
 
 def read(ctx):
     trace, peak = ctx["trace"], ctx["peak"]
-    found = spans("engine.dispatch")
+    found = inside(ctx, spans("engine.dispatch"))
     if not trace or peak is None or not found:
         return None
     secs = kernel_seconds(trace, KERNEL)

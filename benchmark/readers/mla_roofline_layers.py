@@ -7,6 +7,7 @@ half the kernels' share). The counts, the kernel family and the peaks are
 ``mla_roofline``'s."""
 
 from benchmark.kernels import mla_attention
+from benchmark.readers.covered import inside
 from benchmark.readers.mla_roofline import KERNEL
 from benchmark.readers.program_spans import spans
 from benchmark.readers.trace_kernel_ms import kernel_seconds
@@ -14,7 +15,7 @@ from benchmark.readers.trace_kernel_ms import kernel_seconds
 
 def read(ctx, layers):
     trace, peak = ctx["trace"], ctx["peak"]
-    found = spans("engine.dispatch")
+    found = inside(ctx, spans("engine.dispatch"))
     if not trace or peak is None or not found:
         return None
     secs = kernel_seconds(trace, KERNEL)

@@ -4,9 +4,10 @@ peak FLOP/s and bytes over peak bytes/s, ``kernels/paged_attention.py``, from
 the counts the engine's ``engine.dispatch`` spans carry) over the summed
 device time of the kernels the program names ``paged_decode*``."""
 
-from benchmark.harness.trace import parse_op
 from benchmark.kernels import paged_attention
+from benchmark.readers.covered import inside
 from benchmark.readers.program_spans import spans
+from benchmark.readers.trace_kernel_ms import kernel_seconds
 
 
 #: the family name the program gives its paged decode kernels
@@ -15,14 +16,10 @@ KERNEL = "paged_decode"
 
 def read(ctx):
     trace, peak = ctx["trace"], ctx["peak"]
-    found = spans("engine.dispatch")
+    found = inside(ctx, spans("engine.dispatch"))
     if not trace or peak is None or not found:
         return None
-    secs = 0.0
-    for name, (s, _) in trace["ops"].items():
-        instr, opcode = parse_op(name)
-        if opcode == "kernel" and instr.startswith(KERNEL):
-            secs += s
+    secs = kernel_seconds(trace, KERNEL)
     if not secs:
         return None
     model = ctx["cell"].config["model"]

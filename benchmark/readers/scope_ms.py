@@ -11,6 +11,7 @@ as in the breakdown. The first call prints every class's share of the first
 device's busy time, ``unscoped`` among them."""
 
 from benchmark.harness.trace import CONTAINERS, label, parse_op
+from benchmark.readers import covered
 
 #: the one trace's table, computed once: (trace, {scope: seconds} or None)
 _last = (None, None)
@@ -54,7 +55,7 @@ def _table(trace):
 
 
 def read(ctx, scope, per):
-    trace, n = ctx["trace"], ctx["counters"].get(per)
+    trace, n = ctx["trace"], covered.per(ctx, per)
     if not trace or not n:
         return None
     by_scope = table(trace)

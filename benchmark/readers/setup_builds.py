@@ -6,7 +6,7 @@ and the kernels bound while it was traced, and each engine's constructor.
 
 *Before the window* are the records that end before the first instant the
 recorder wrote at (the earliest end among ``tracing.snapshot()``: the same
-clock); a build inside the window is ``engine.compiles.*``'s. ``what``:
+clock). ``what``:
 
 - ``init_s``: summed duration of the ``engine.init`` records;
 - ``trace_s`` / ``lower_s`` / ``load_s``: that field summed over the records

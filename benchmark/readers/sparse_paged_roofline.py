@@ -9,6 +9,7 @@ out: the count errs low, the share with it. Over the summed device time of
 the kernels the program names ``paged_decode*``."""
 
 from benchmark.kernels import paged_attention
+from benchmark.readers.covered import inside
 from benchmark.readers.program_spans import spans
 from benchmark.readers.trace_kernel_ms import kernel_seconds
 
@@ -17,7 +18,7 @@ KERNEL = "paged_decode"
 
 def read(ctx):
     trace, peak = ctx["trace"], ctx["peak"]
-    found = spans("engine.dispatch")
+    found = inside(ctx, spans("engine.dispatch"))
     if not trace or peak is None or not found:
         return None
     secs = kernel_seconds(trace, KERNEL)

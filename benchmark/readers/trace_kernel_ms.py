@@ -4,6 +4,7 @@ kernel of the program; this one tells a family apart by the name the program
 gives its ``pallas_call``."""
 
 from benchmark.harness.trace import parse_op
+from benchmark.readers import covered
 
 
 def kernel_seconds(trace, prefix):
@@ -17,7 +18,7 @@ def kernel_seconds(trace, prefix):
 
 
 def read(ctx, prefix, per):
-    t, n = ctx["trace"], ctx["counters"].get(per)
+    t, n = ctx["trace"], covered.per(ctx, per)
     if not t or not n:
         return None
     secs = kernel_seconds(t, prefix)

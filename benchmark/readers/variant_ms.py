@@ -20,6 +20,7 @@ they add up to the busy time, the shared ops' share of it with the largest of
 them, and for every scope over 5% of a variant its three largest ops."""
 
 from benchmark.harness.trace import CONTAINERS, label, parse_op
+from benchmark.readers.covered import inside
 from benchmark.readers.program_spans import spans
 
 RAGGED = "engine_v2.ragged"
@@ -73,7 +74,7 @@ def _account(ctx):
     if not hasattr(tracing, "device_programs"):
         return None
     runs = {}
-    for s in spans("engine.dispatch") or ():
+    for s in inside(ctx, spans("engine.dispatch")) or ():
         if s.attrs.get("program") == "ragged" and "padded_rows" in s.attrs:
             rows = s.attrs["padded_rows"]
             runs[rows] = runs.get(rows, 0) + 1

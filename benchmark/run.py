@@ -8,7 +8,10 @@ the checkout, makes weights and inputs from ``--seed``, warms the cell's own
 shapes, measures for ``--seconds`` and prints as its last line one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
 ``breakdown``. ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1``
-its per-layer metrics over a window of at most ``TRACE_SECONDS``.
+its per-layer metrics over a window of at most ``TRACE_SECONDS``, with one
+``[trace]`` line that says how much of it the device's events cover
+(``device.window_s`` is the part the trace accounts for; ``device.covered_s``,
+``device.traced_s`` and ``device.anchor`` say the same to the ledger).
 """
 
 import time
@@ -35,6 +38,7 @@ def run_cell(cell, seed, seconds, trace, devices, rehearsal=False):
     from benchmark.harness import serve, train
     from benchmark.harness.setup import setup_line
     from benchmark.harness.trace import breakdown
+    from benchmark.readers import covered
 
     runners = {"train": train.run, "serve_open": serve.run,
                "serve_closed": serve.run}
@@ -58,6 +62,10 @@ def run_cell(cell, seed, seconds, trace, devices, rehearsal=False):
     ctx = {"cell": cell, "counters": res["counters"], "spans": res["spans"],
            "trace": summary, "peak": cell.peak(devices[0].device_kind)
            if devices[0].platform == "tpu" else None}
+    if summary is not None:
+        # the device's seconds are a share of what the trace accounts for
+        print(covered.line(ctx), flush=True)
+        summary["accounted_s"] = covered.accounted_s(ctx)
     for m in cell.per_layer:
         read, args = cell.reader(m["name"])
         value = read(ctx, **args)
@@ -66,6 +74,19 @@ def run_cell(cell, seed, seconds, trace, devices, rehearsal=False):
     if summary is not None:
         out["breakdown"] = breakdown(summary)
     return out, summary
+
+
+def device_account(summary):
+    """The traced run's part of the result line's ``device``. ``busy_s`` and
+    ``window_s`` are the driver's (the window as far as the trace accounts
+    for it); the rest is for the ledger's reader, to tell a cut trace from an
+    idle device: the seconds the device's events span, the traced window, and
+    whether the clock anchor was found. Without it every reader took the
+    window whole, and a cut trace reads too much work for its time."""
+    lo, hi = summary["covered_ns"] or (0, 0)
+    return {"busy_s": summary["busy_s"], "window_s": summary["accounted_s"],
+            "covered_s": (hi - lo) / 1e9, "traced_s": summary["window_s"],
+            "anchor": summary["clock_offset_ns"] is not None}
 
 
 def main(argv=None):
@@ -90,8 +111,7 @@ def main(argv=None):
     out, summary = run_cell(cell, args.seed, args.seconds, args.trace, devices)
     device["memory_peak_bytes"] = memory_peak_bytes(devices)
     if summary is not None:
-        device["busy_s"] = summary["busy_s"]
-        device["window_s"] = summary["window_s"]
+        device.update(device_account(summary))
     out["device"] = device
     print(json.dumps(out), flush=True)
 

@@ -56,7 +56,7 @@ def main(argv=None):
     dtype = jnp.dtype(cell.config["dtype"])
     engine = InferenceEngineV2(
         model, train.seeded(cell, model, args.seed).tree_as(dtype),
-        paged=True, dtype=dtype, **mix["engine"])
+        dtype=dtype, **mix["engine"])
     knee, secs = None, args.seconds
     with ContinuousBatchScheduler(engine) as sched:
         serve.warm_up(sched, mix, args.seed, vocab)

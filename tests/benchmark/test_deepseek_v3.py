@@ -146,7 +146,7 @@ def test_chunked_prefill_then_decode_through_the_latent_pool(
     if kernel:
         monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
     served = jax.tree.map(lambda a: a.astype(dtype), tree)
-    eng = InferenceEngineV2(model, served, paged=True, dtype=dtype, max_seqs=4,
+    eng = InferenceEngineV2(model, served, dtype=dtype, max_seqs=4,
                             max_seq_len=256, block_size=16, token_budget=36,
                             prefill_chunk=32, num_blocks=40)
     rng = np.random.default_rng(1)
@@ -170,7 +170,7 @@ def test_a_mixed_step_lays_segments_on_tile_boundaries(model, tree):
     whole tiles hold."""
     from deepspeed_tpu.inference.v2 import InferenceEngineV2
 
-    eng = InferenceEngineV2(model, tree, paged=True, dtype=jnp.float32,
+    eng = InferenceEngineV2(model, tree, dtype=jnp.float32,
                             max_seqs=4, max_seq_len=256, block_size=16,
                             token_budget=52, prefill_chunk=32, num_blocks=40)
     eng.put([1], [[5, 6, 7]], greedy=True)               # 1 is decoding now

@@ -166,7 +166,7 @@ def test_chunked_prefill_then_decode_through_the_latent_pool(
     if kernel:
         monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
     served = jax.tree.map(lambda a: a.astype(dtype), tree)
-    eng = InferenceEngineV2(model, served, paged=True, dtype=dtype, max_seqs=4,
+    eng = InferenceEngineV2(model, served, dtype=dtype, max_seqs=4,
                             max_seq_len=256, block_size=16, token_budget=36,
                             prefill_chunk=32, num_blocks=40)
     assert eng.kv.shape[0] == model.config.pool_layers == 4
@@ -192,7 +192,7 @@ def test_the_greedy_program_counts_identity_picks(model, tree):
     expert layers."""
     from deepspeed_tpu.inference.v2 import InferenceEngineV2
 
-    eng = InferenceEngineV2(model, tree, paged=True, dtype=jnp.float32,
+    eng = InferenceEngineV2(model, tree, dtype=jnp.float32,
                             max_seqs=4, max_seq_len=256, block_size=16,
                             token_budget=36, prefill_chunk=32, num_blocks=40)
     ids = jnp.asarray(np.arange(36).reshape(36, 1) % 256, jnp.int32)

@@ -64,7 +64,7 @@ def test_chunked_prefill_then_decode_through_pool_and_slots(
     if kernel:
         monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
     w = seeded(model)
-    eng = InferenceEngineV2(model, w.tree_as(dtype), paged=True, dtype=dtype,
+    eng = InferenceEngineV2(model, w.tree_as(dtype), dtype=dtype,
                             **cell.mix(True)["engine"])
     assert eng.kv.shape[0] == 2 and sorted(eng.slot_cache) == [
         "blocks_0", "blocks_1", "blocks_2"]
@@ -216,10 +216,10 @@ def test_the_cells_rehearsal_reports_every_metric_but_the_peak_shares(cell):
     got = traced["metrics"]
     listed = {m["name"] for m in cell.per_layer}
     assert {n for n in listed if "roofline" not in n} <= set(got)
-    assert 0 < got["cache.selected_block_share.doc16k"]["value"] < 100
-    assert 0 < got["cache.state_slot_fill.doc16k"]["value"] <= 100
-    assert got["engine.compiles.doc16k"]["value"] == 0
-    assert got["sched.segment_step_share.doc16k"]["value"] > 0
+    assert 0 < got["cache.selected_block_share"]["value"] < 100
+    assert 0 < got["cache.state_slot_fill"]["value"] <= 100
+    assert got["engine.compiles.serve"]["value"] == 0
+    assert got["sched.segment_step_share"]["value"] > 0
 
 
 def test_slot_fill_reads_the_slots_in_use_over_the_cells_max_seqs(

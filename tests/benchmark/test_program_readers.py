@@ -7,8 +7,9 @@ import pytest
 
 from benchmark.harness.cell import Cell, load_spec
 from benchmark.kernels import paged_attention
-from benchmark.readers import (paged_roofline, program_span, program_spans,
-                               scope_ms, span_attr_ratio, span_count)
+from benchmark.readers import (flash_roofline, paged_roofline, program_span,
+                               program_spans, scope_ms, span_attr_ratio,
+                               span_count)
 from deepspeed_tpu.utils import tracing
 
 R = tracing.Record
@@ -146,17 +147,58 @@ def test_paged_roofline_share(recorded, capsys):
                                    cell=cell)) is None
 
 
-@pytest.mark.parametrize("m", [m for m in load_spec()["per_layer"]
-                               if m["name"] in (
-    "sched.host_ms.chat", "sched.queue_wait_ms.chat", "engine.build_ms.chat",
-    "engine.row_fill.chat", "engine.kv_write_ms.chat", "engine.kv_carry_ms.chat",
-    "kernel.paged_roofline_share.chat", "engine.compiles.chat",
-    "engine.compiles.train", "model.fwd_ms", "model.bwd_ms", "model.remat_ms",
-    "engine.optimizer_ms")], ids=lambda m: m["name"])
-def test_new_metrics_name_a_reader_whose_arguments_fit(m):
+FUSED_CE = ('%fused_ce_fwd.1 = (f32[8192]{0}, bf16[8192,50304]{1,0}) custom-call(%h), '
+            'custom_call_target="tpu_custom_call"')
+
+
+def flash(name, shape="bf16[8,1024,1024]"):
+    return (f'%{name} = {shape}{{2,1,0}} custom-call(%q), '
+            'custom_call_target="tpu_custom_call"')
+
+
+def test_flash_roofline_ignores_a_fused_ce_custom_call(capsys):
+    """The train step holds the head's kernels beside the attention's (since
+    PR 56); the share is of the kernels named ``flash_*`` alone."""
+    counters = {"steps": 10, "global_batch": 8, "chips": 1, "seq_len": 1024,
+                "n_heads": 16, "head_dim": 64, "n_layers": 24}
+    ops = {flash("flash_fwd.3"): (0.10, 240), flash("flash_bwd.5"): (0.16, 240)}
+    alone = flash_roofline.read(ctx(ops=ops, counters=counters))
+    ops[FUSED_CE] = (0.134, 10)
+    ops['%fused_ce_bwd.1 = bf16[8192,1024]{1,0} custom-call(%g), '
+        'custom_call_target="tpu_custom_call"'] = (0.09, 10)
+    assert flash_roofline.read(ctx(ops=ops, counters=counters)) == alone
+    assert 0 < alone < 100 and "kernels 26.000 ms a step" in capsys.readouterr().out
+    # a step with no flash kernel (the CPU's XLA attention) reads nothing
+    assert flash_roofline.read(ctx(ops={FUSED_CE: (0.1, 10)},
+                                   counters=counters)) is None
+
+
+def test_paged_decode_ms_is_the_paged_decode_kernels_alone():
+    """``kernel.paged_decode_ms`` counted every custom call, ``kv_write``
+    among them since PR 35; it reads the family ``paged_decode*`` by name."""
+    read, args = Cell("gpt2-medium.serve-chat").reader("kernel.paged_decode_ms")
+    ops = {KERNEL: (0.050, 48),
+           '%kv_write.13 = bf16[8]{0} custom-call(%a), '
+           'custom_call_target="tpu_custom_call"': (0.5, 48),
+           "%fusion.7 = bf16[64,1024]{1,0} fusion(%p)": (1.0, 48)}
+    assert read(ctx(ops=ops, counters={"dispatches": 2}), **args) == \
+        pytest.approx(25.0)
+
+
+NAMED = ("sched.host_ms", "engine.row_fill", "engine.kv_write_ms",
+         "engine.kv_carry_ms", "kernel.paged_roofline_share.chat",
+         "model.fwd_ms", "model.bwd_ms", "model.remat_ms", "engine.optimizer_ms",
+         "engine.compiles.train", "engine.compiles.serve")
+
+
+@pytest.mark.parametrize(
+    "m,cell", [(m, cell) for m in load_spec()["per_layer"]
+               if m["name"] in NAMED for cell in m["workloads"]],
+    ids=lambda v: v["name"] if isinstance(v, dict) else v)
+def test_new_metrics_name_a_reader_whose_arguments_fit(m, cell):
     import inspect
 
-    read, args = Cell(m["workloads"][0], load_spec()).reader(m["name"])
+    read, args = Cell(cell, load_spec()).reader(m["name"])
     inspect.signature(read).bind({}, **args)
     assert m["layer"] in ("Serving scheduler", "Serving engine", "Kernels",
                           "Training engine", "Model")

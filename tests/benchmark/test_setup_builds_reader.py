@@ -129,21 +129,29 @@ def test_metric_file_names_a_reader_whose_arguments_fit(name, account):
         assert read({"spans": {}}, **entry["args"]) == 2000.0
 
 
-def test_the_entries_the_benchmark_has_room_for():
-    """``per_layer`` may hold 128 metrics and held 125: three of the twelve
-    are entries, the ones that cover all six cells (PERF.md section 7)."""
+def test_the_entries_of_the_set_up_account():
+    """All twelve are entries since the per-layer list holds one entry a
+    metric: eleven move ``setup_s`` (a ``.train`` and a ``.serve`` entry
+    differ in ``layer``), ``engine.host_ms.train`` the training rate."""
     spec = load_spec()
-    assert len(spec["per_layer"]) <= 128
-    mine = {m["name"]: m for m in spec["per_layer"] if m["moves"] == "setup_s"}
-    assert set(mine) == {"engine.setup_trace_s.train",
-                         "engine.setup_trace_s.serve", "kernel.setup_trace_s"}
+    entered = {m["name"]: m for m in spec["per_layer"]}
+    assert set(SETUP_METRICS) <= set(entered)
+    mine = {n: m for n, m in entered.items() if m["moves"] == "setup_s"}
+    assert set(mine) == set(SETUP_METRICS) - {"engine.host_ms.train"}
     cells = {w["name"] for w in spec["workloads"]}
+    train = {c for c in cells if "train" in c}
     assert set(mine["kernel.setup_trace_s"]["workloads"]) == cells
-    assert (set(mine["engine.setup_trace_s.train"]["workloads"])
-            | set(mine["engine.setup_trace_s.serve"]["workloads"])) == cells
     for name, m in mine.items():
         assert m["source"] == "program_counter" and m["better"] == "lower"
-        assert m["unit"] == "s"
+        assert m["unit"] == ("count" if "programs" in name else "s")
+        if name.endswith(".train"):
+            assert set(m["workloads"]) == train and m["layer"] == "Training engine"
+        if name.endswith(".serve"):
+            assert set(m["workloads"]) == cells - train
+            assert m["layer"] == "Serving engine"
         for cell in m["workloads"]:
             read, args = Cell(cell, spec).reader(name)
             assert read.__module__ == "benchmark.readers.setup_builds"
+    host = entered["engine.host_ms.train"]
+    assert set(host["workloads"]) == train
+    assert host["moves"] == "train_tokens_per_s_per_chip"

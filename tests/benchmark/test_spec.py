@@ -7,13 +7,21 @@ import re
 
 import pytest
 
-from benchmark.harness.cell import REPO, ROOT, BenchmarkError, Cell, load_spec
+from benchmark.harness.cell import (REPO, ROOT, BenchmarkError, Cell,
+                                    load_json, load_spec)
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SPEC = load_spec()
 STAGED = load_spec(staged=True)
 METRICS = SPEC["end_to_end"] + SPEC["per_layer"]
+#: one case a (per-layer metric, cell that lists it): a cell joins a metric
+#: by one more name in its ``workloads``, and is tested like an entry of its own
+PAIRS = [(m, cell) for m in STAGED["per_layer"] for cell in m["workloads"]]
+
+
+def pair_id(pair):
+    return f"{pair[0]['name']}-{pair[1]}"
 
 
 def test_top_level_keys_and_limits():
@@ -27,10 +35,7 @@ def test_top_level_keys_and_limits():
                for m in SPEC["end_to_end"])
 
 
-@pytest.mark.parametrize(
-    "entry", STAGED["end_to_end"] + STAGED["per_layer"] + STAGED["workloads"]
-    + SPEC["configs"], ids=lambda e: e["name"])
-def test_names_units_and_text(entry):
+def names_units_and_text(entry):
     assert NAME.match(entry["name"])
     for key in ("config", "traffic"):
         if key in entry:
@@ -46,6 +51,24 @@ def test_names_units_and_text(entry):
         assert 0.01 <= entry["bound"] <= 0.1
 
 
+@pytest.mark.parametrize(
+    "entry", STAGED["end_to_end"] + STAGED["workloads"] + SPEC["configs"],
+    ids=lambda e: e["name"])
+def test_names_units_and_text(entry):
+    names_units_and_text(entry)
+
+
+@pytest.mark.parametrize("pair", PAIRS, ids=pair_id)
+def test_per_layer_names_units_and_text(pair):
+    m, cell = pair
+    names_units_and_text(m)
+    assert set(m) == {"name", "unit", "better", "source", "layer", "moves",
+                      "workloads"}
+    assert cell in {w["name"] for w in STAGED["workloads"]}
+    assert m["workloads"].count(cell) == 1
+    assert "reader" in load_json("metrics", m["name"] + ".json")
+
+
 def test_names_are_unique():
     for group in (METRICS, SPEC["workloads"], SPEC["configs"]):
         names = [e["name"] for e in group]
@@ -54,14 +77,56 @@ def test_names_are_unique():
     assert len(pairs) == len(set(pairs))
 
 
-@pytest.mark.parametrize("m", STAGED["per_layer"], ids=lambda m: m["name"])
-def test_per_layer_metric_moves_what_its_cells_report(m):
+@pytest.mark.parametrize("pair", PAIRS, ids=pair_id)
+def test_per_layer_metric_moves_what_its_cells_report(pair):
+    m, cell = pair
     moved = next(e for e in STAGED["end_to_end"] if e["name"] == m["moves"])
     cells = {w["name"] for w in STAGED["workloads"]}
-    reporting = set(moved.get("workloads", cells))
-    assert set(m["workloads"]) <= reporting <= cells
+    assert cell in set(moved.get("workloads", cells)) <= cells
+    assert m in Cell(cell, STAGED).per_layer
     assert m["source"] in ("device_trace", "program_span", "program_counter",
                            "host_clock")
+
+
+def test_per_layer_has_one_entry_a_metric_and_room_left():
+    """A cell joins a metric it shares by its name in ``workloads``; an entry
+    of its own is for a reader or arguments of its own. So no two entries
+    agree in reader, arguments, layer and what they move: that rule keeps
+    the list short. The one ceiling held is the contract's, here alone, so
+    that a configuration can bring its entries without editing a test."""
+    assert len(SPEC["per_layer"]) <= 128
+    seen = {}
+    for m in STAGED["per_layer"]:
+        entry = load_json("metrics", m["name"] + ".json")
+        key = (entry["reader"], json.dumps(entry.get("args", {}), sort_keys=True),
+               m["layer"], m["moves"])
+        assert key not in seen, f"{m['name']} repeats {seen[key]}"
+        seen[key] = m["name"]
+    # and no file under metrics/ without an entry
+    listed = {m["name"] + ".json" for m in STAGED["per_layer"]}
+    assert set(os.listdir(os.path.join(ROOT, "metrics"))) == listed
+
+
+def test_the_names_claims_are_bounded_by_are_as_they_were():
+    names = {m["name"]: m["workloads"] for m in SPEC["per_layer"]}
+    for name, cells in (
+            ("kernel.mla_decode_roofline_share.longdoc", ["serve-longdoc"]),
+            ("kernel.mla_decode_roofline_share.longout", ["serve-longout"]),
+            ("kernel.paged_roofline_share.chat", ["serve-chat"]),
+            ("kernel.paged_roofline_share.doc16k", ["serve-doc16k"]),
+            ("kernel.linear_decode_roofline_share.doc16k", ["serve-doc16k"]),
+            ("kernel.flash_roofline_share", ["train-seq1024",
+                                             "zero3-train-4chip"]),
+            ("model.mfu", ["train-seq1024", "zero3-train-4chip"])):
+        assert [w["traffic"] for w in SPEC["workloads"]
+                if w["name"] in names[name]] == cells
+    assert sorted(n for n in names if "roofline_share" in n or "mfu" in n) \
+        == sorted(["kernel.mla_decode_roofline_share.longdoc",
+                   "kernel.mla_decode_roofline_share.longout",
+                   "kernel.paged_roofline_share.chat",
+                   "kernel.paged_roofline_share.doc16k",
+                   "kernel.linear_decode_roofline_share.doc16k",
+                   "kernel.flash_roofline_share", "model.mfu"])
 
 
 @pytest.mark.parametrize("w", STAGED["workloads"], ids=lambda w: w["name"])
