@@ -98,24 +98,70 @@ def serve_reference(config, seeded, ids, rows, ein=ein_f32):
     applied by the once-compiled layer to the hidden states of all K
     sequences (one sequence at a time), and dropped before the next is
     drawn: the host waits for each layer, so that no queue of drawn layers
-    stands on the chip."""
+    stands on the chip.
+
+    Where the configuration says ``"router_bias": "balanced"``, an expert
+    layer's selection bias is not drawn but fitted, on the way, to this
+    pass's own tokens (every position up to a sequence's last row), so that
+    they choose every router output equally often (``arch.balanced_bias``),
+    and left in ``seeded.made``: the served tree, its comparison and a
+    later pass (the control's) then hold that same bias."""
     arch = architecture(config)
     ids, rows = jnp.asarray(ids), jnp.asarray(rows)
+    balanced = config.get("router_bias") == "balanced"
+    if config.get("router_bias") not in (None, "balanced"):
+        raise ValueError(f"reference: router_bias {config['router_bias']!r}")
 
     def over(f):
         return jax.jit(lambda w, *xs: jax.lax.map(lambda a: f(w, *a), xs))
 
     x = over(lambda w, seq: arch.embed(w, seq, config))(seeded.unstacked(), ids)
     layer = over(lambda b, x: arch.layer(x, b, config, ein))
+    if balanced:
+        attend = over(lambda b, x: arch.attend(x, b, config, ein))
+        feed = over(lambda b, x: arch.feed(x, b, config, ein))
+        # the sequences' lengths are an argument: one program for every seed
+        fit = jax.jit(lambda b, x, last: _balanced_bias(arch, config, b, x,
+                                                        last))
     for group, n in arch.groups(config):
         if n != seeded.layers(group):
             raise ValueError(f"reference: '{group}' has {seeded.layers(group)} "
                              f"layers of weights, the configuration {n}")
         for l in range(n):
-            x = layer(seeded.layer(group, l), x).block_until_ready()
+            b = seeded.layer(group, l)
+            if balanced and "moe_bias" in b and (group, l) not in seeded.made:
+                x = attend(b, x)
+                made = seeded.made[group, l] = {
+                    "moe_bias": fit(b, x, rows[:, -1])}
+                x = feed({**b, **made}, x).block_until_ready()
+            else:
+                x = layer(b, x).block_until_ready()
+            del b               # before the next layer is drawn
     head = over(lambda w, x, pos: arch.logits(
         w, arch.final(w, x, config)[pos], ein))
     return np.asarray(head(seeded.unstacked(), x, rows))
+
+
+#: the most positions a selection bias is fitted to: the fit's passes cost
+#: by the position, and 8192 of them put each of 256 outputs' share within a
+#: few per cent
+FIT_POSITIONS = 8192
+
+
+def _balanced_bias(arch, config, b, x, last):
+    """The balanced selection bias of layer ``b``'s router over the positions
+    up to ``last`` (K,) of the hidden states ``x`` (K, T, H) as ``attend``
+    left them, every n-th of them where they are more than ``FIT_POSITIONS``;
+    the scores in float32 at ``highest``, whatever pass fits."""
+    s = jax.lax.map(lambda a: arch.router_scores(a, b, config, ein_f32), x)
+    s = s.reshape(-1, s.shape[-1])
+    valid = (jnp.arange(x.shape[1])[None, :] <= last[:, None]).reshape(-1)
+    if len(s) > FIT_POSITIONS:
+        every = -(-jnp.sum(valid) // FIT_POSITIONS)
+        valid &= (jnp.cumsum(valid) - 1) % every == 0
+        kept = jnp.argsort(~valid, stable=True)[:FIT_POSITIONS]
+        s, valid = s[kept], valid[kept]
+    return arch.balanced_bias(s, valid, config)
 
 
 def logits_rel_err(got, want):

@@ -73,7 +73,12 @@ def _snapshot(metrics):
 def open_recs(mix, rate_rps, seed, ramp, seconds, vocab, ctx):
     """The open loop's requests: Poisson due times over the ramp, then over
     the window. The two are drawn apart, so every seed puts the same number of
-    requests, with the same lengths, inside the window."""
+    requests, with the same lengths, inside the window. A mix that says
+    ``"cycle"`` also sends them in the same order at the same times
+    (``traffic.cycle``)."""
+    if "cycle" in mix:
+        return [Rec(*r) for r in traffic.cycle(
+            mix, rate_rps, [int(seed), 5], ramp, seconds, vocab, ctx)]
     recs = []
     for part, (start, length) in enumerate(((0.0, ramp), (ramp, seconds))):
         dues = start + traffic.poisson_dues(rate_rps, [int(seed), 1, part], length)

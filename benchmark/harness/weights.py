@@ -126,6 +126,12 @@ class Seeded:
         for i, p in enumerate(paths):
             (self.top if len(p) == 1 else self.groups.setdefault(p[0], {}))[
                 p[-1]] = i
+        #: {(group, layer): {leaf name: float32 value}}: leaves that are not
+        #: drawn but made from the seed's other weights and its inputs (a
+        #: fitted selection bias: ``check.serve_reference``). ``layer`` and
+        #: ``tree_as`` hand them out in the drawn ones' place; ``tree`` does
+        #: not know them
+        self.made = {}
 
     def tree(self, shardings=None):
         """The whole float32 tree, one jitted call. ``shardings``: optional
@@ -144,8 +150,13 @@ class Seeded:
         ``dtype``."""
         index = self.groups[group]
         rules = tuple(self.rules[i] for i in index.values())
-        return self._as(dict(zip(index, _parts_fn(rules, True)(
-            *self.seed, np.int32(l)))), dtype)
+        drawn = dict(zip(index, _parts_fn(rules, True)(*self.seed, np.int32(l))))
+        for k, x in self.made.get((group, l), {}).items():
+            if x.shape != drawn[k].shape or x.dtype != jnp.float32:
+                raise ValueError(f"weights: made leaf '{group}.{k}' of layer "
+                                 f"{l} is {x.dtype}{x.shape}")
+            drawn[k] = x
+        return self._as(drawn, dtype)
 
     def unstacked(self, dtype=jnp.float32):
         """The leaves that sit at the top of the tree, outside every stacked

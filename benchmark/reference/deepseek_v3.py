@@ -112,13 +112,12 @@ def gated_mlp(h, w_gate, w_up, w_down, ein):
                * ein("sh,hi->si", h, w_up), w_down)
 
 
-def route(h, b, cfg, ein):
-    """(S, E_all) float32 combine weights: zero where an expert was not
-    chosen. Selection by ``s + bias``, weights from ``s``."""
+def picks(biased, cfg):
+    """(S, k) chosen outputs of the (S, E_all) selection scores ``biased``:
+    a group scores the sum of its two largest, the ``topk_group`` best groups
+    are kept and the ``num_experts_per_tok`` largest are taken among them."""
     groups, keep, k = cfg["n_group"], cfg["topk_group"], cfg["num_experts_per_tok"]
-    s = jax.nn.sigmoid(ein("sh,he->se", h, b["moe_wg"]))
-    n, e_all = s.shape
-    biased = s + b["moe_bias"]
+    n, e_all = biased.shape
     by_group = biased.reshape(n, groups, e_all // groups)
     group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)   # (S, groups)
     kept = jax.lax.top_k(group_score, keep)[1]
@@ -126,13 +125,57 @@ def route(h, b, cfg, ein):
         jnp.arange(n)[:, None], kept].set(True)
     masked = jnp.where(jnp.repeat(group_ok, e_all // groups, axis=1), biased,
                        -jnp.inf)
-    chosen = jax.lax.top_k(masked, k)[1]                            # (S, k)
+    return jax.lax.top_k(masked, k)[1]
+
+
+def scores(h, b, ein):
+    """(S, E_all) float32 router scores of the normed ``h``."""
+    return jax.nn.sigmoid(ein("sh,he->se", h, b["moe_wg"]))
+
+
+def route(h, b, cfg, ein):
+    """(S, E_all) float32 combine weights: zero where an expert was not
+    chosen. Selection by ``s + bias``, weights from ``s``."""
+    s = scores(h, b, ein)
+    n, e_all = s.shape
+    chosen = picks(s + b["moe_bias"], cfg)                          # (S, k)
     picked = jnp.zeros((n, e_all), bool).at[
         jnp.arange(n)[:, None], chosen].set(True)
     w = jnp.where(picked, s, 0.0)
     if cfg.get("norm_topk_prob", True):
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return w * cfg["routed_scaling_factor"]
+
+
+#: the fit of ``balanced_bias``: steps, and the first and last step size in
+#: units of the scores (which lie in (0, 1))
+BALANCE_STEPS, BALANCE_FIRST, BALANCE_LAST = 40, 0.1, 0.002
+
+
+def balanced_bias(s, valid, cfg):
+    """The selection bias (E_all,) under which the tokens ``valid`` (N,) of
+    the scores ``s`` (N, E_all) choose every router output equally often: what
+    the published model's ``e_score_correction_bias`` is trained to do
+    (arXiv:2412.19437 section 2.1.2: after each step an overloaded expert's
+    bias goes down by a step and an underloaded one's up), run here on the
+    seed's own tokens with a step that shrinks geometrically. The result has
+    mean zero and is rounded to bfloat16, so that the served tree holds the
+    reference's values exactly. Deterministic: sums of 0/1 counts."""
+    e_all = s.shape[1]
+    valid = valid.astype(jnp.float32)
+    target = jnp.sum(valid) * cfg["num_experts_per_tok"] / e_all
+
+    def step(t, bias):
+        size = BALANCE_FIRST * (BALANCE_LAST / BALANCE_FIRST) ** (
+            t / (BALANCE_STEPS - 1))
+        hit = picks(s + bias, cfg)[:, :, None] == jnp.arange(e_all)
+        load = jnp.sum(jnp.any(hit, axis=1) * valid[:, None], axis=0)
+        bias = bias - size * jnp.clip(load / target - 1.0, -1.0, 1.0)
+        return bias - jnp.mean(bias)
+
+    bias = jax.lax.fori_loop(0, BALANCE_STEPS, step,
+                             jnp.zeros((e_all,), jnp.float32))
+    return bias.astype(jnp.bfloat16).astype(jnp.float32)
 
 
 def experts(h, b, cfg, ein):
@@ -163,15 +206,30 @@ def embed(w, ids, cfg):
     return w["wte"][ids].astype(jnp.float32)
 
 
-def layer(x, b, cfg, ein):
-    """One layer over ``b``, its leaves: an expert layer where ``b`` holds a
-    router, a dense one otherwise."""
-    eps = cfg["rms_norm_eps"]
-    x = x + attention(rms_norm(x, b["ln1_scale"], eps), b, cfg, ein)
-    h2 = rms_norm(x, b["ln2_scale"], eps)
+def attend(x, b, cfg, ein):
+    """The first half of a layer: ``x`` plus its attention."""
+    return x + attention(rms_norm(x, b["ln1_scale"], cfg["rms_norm_eps"]), b,
+                         cfg, ein)
+
+
+def router_scores(x, b, cfg, ein):
+    """(S, E_all) scores of an expert layer's router on ``x`` as ``attend``
+    returned it: what ``balanced_bias`` is fitted to."""
+    return scores(rms_norm(x, b["ln2_scale"], cfg["rms_norm_eps"]), b, ein)
+
+
+def feed(x, b, cfg, ein):
+    """The second half: ``x`` plus its experts where ``b`` holds a router,
+    plus its dense MLP otherwise."""
+    h2 = rms_norm(x, b["ln2_scale"], cfg["rms_norm_eps"])
     if "moe_wg" in b:
         return x + experts(h2, b, cfg, ein)
     return x + gated_mlp(h2, b["w_gate"], b["w_up"], b["w_down"], ein)
+
+
+def layer(x, b, cfg, ein):
+    """One layer over ``b``, its leaves: ``feed`` after ``attend``."""
+    return feed(attend(x, b, cfg, ein), b, cfg, ein)
 
 
 def final(w, x, cfg):

@@ -17,12 +17,11 @@ from jax.profiler import ProfileData
 
 from benchmark.harness import trace
 from benchmark import run
-from benchmark.harness.cell import ROOT, BenchmarkError, Cell
-from benchmark.readers import (covered, linear_roofline, mla_roofline,
-                               mla_roofline_layers, paged_roofline, scope_ms,
-                               sparse_paged_roofline, trace_idle,
-                               trace_kernel_ms, trace_op_ms, variant_ms)
+from benchmark.harness.cell import ROOT, BenchmarkError, Cell, load_spec
+from benchmark.readers import (covered, scope_ms, trace_idle, trace_kernel_ms,
+                               trace_op_ms, variant_ms)
 from deepspeed_tpu.utils import tracing
+from tests.benchmark import rules
 from tests.benchmark.test_trace import FIXTURE
 
 R = tracing.Record
@@ -30,6 +29,14 @@ PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 OFFSET = 10 ** 12            # profiler clock minus the recorder's, ns
 US = 1000
 WHOLE = trace._device_op_lines
+#: every share of a roofline that moves ``itl_p50_ms``, from the spec and the
+#: metric files: kernel (the reader's ``KERNEL``), reader, its arguments, the
+#: entry's first cell. An entry that arrives is one more case of both tests
+#: below; ``kernel.flash_roofline_share`` counts by ``steps`` and is held by
+#: ``test_program_readers.py`` and ``covered.per``'s tests here
+SHARES = [pytest.param(kernel, reader, args, cell, id=case)
+          for case, kernel, reader, args, cell
+          in rules.roofline_cases(load_spec(staged=True), ROOT)]
 
 
 def bursts(events):
@@ -105,17 +112,22 @@ def test_reduce_xplane_returns_the_covered_interval(monkeypatch):
     assert "no clock anchor" in covered.line(c)
 
 
-@pytest.mark.parametrize("kernel,reader,cell", [
-    ("mla_decode", mla_roofline, "gigachat3.1-702b-a36b.serve-longdoc"),
-    ("mla_decode", mla_roofline_layers, "longcat-flash-chat.serve-longout"),
-    ("paged_decode", paged_roofline, "gpt2-medium.serve-chat"),
-    ("paged_decode", sparse_paged_roofline, "minicpm-sala.serve-doc16k"),
-    ("linear_decode", linear_roofline, "minicpm-sala.serve-doc16k"),
-], ids=lambda v: v if isinstance(v, str) else v.__name__.rsplit(".", 1)[1])
-def test_a_cut_trace_reads_the_same_roofline_share(monkeypatch, kernel, reader,
-                                                   cell):
-    cell = Cell(cell)
-    args = {"layers": 8} if reader is mla_roofline_layers else {}
+def test_the_cases_are_the_specs_serving_shares():
+    """Read from the spec, not listed here; the five of PR 59 are among them,
+    each with its reader, so a filter that finds nothing cannot pass."""
+    assert {"mla_decode-mla_roofline-gigachat3.1-702b-a36b.serve-longdoc",
+            "mla_decode-mla_roofline_layers-longcat-flash-chat.serve-longout",
+            "paged_decode-paged_roofline-gpt2-medium.serve-chat",
+            "paged_decode-sparse_paged_roofline-minicpm-sala.serve-doc16k",
+            "linear_decode-linear_roofline-minicpm-sala.serve-doc16k"} \
+        <= {p.id for p in SHARES}
+    layers = next(p for p in SHARES if "mla_roofline_layers" in p.id)
+    assert layers.values[2] == {"layers": 8}      # the metric file's ``args``
+
+
+def same_share_of_a_cut_trace(monkeypatch, kernel, reader, args, cell):
+    """``cell`` a ``Cell``. The tiny fixture whole and without the device
+    events of its last step read the same share, to 2%."""
     whole = reader.read(ctx(summary(monkeypatch, kernel, cut=False)[0], cell),
                         **args)
     cut_summary, _ = summary(monkeypatch, kernel, cut=True)
@@ -125,6 +137,12 @@ def test_a_cut_trace_reads_the_same_roofline_share(monkeypatch, kernel, reader,
     cut_summary["clock_offset_ns"] = None
     assert reader.read(ctx(cut_summary, cell), **args) == pytest.approx(
         1.5 * whole, rel=0.02)
+
+
+@pytest.mark.parametrize("kernel,reader,args,cell", SHARES)
+def test_a_cut_trace_reads_the_same_roofline_share(monkeypatch, kernel, reader,
+                                                   args, cell):
+    same_share_of_a_cut_trace(monkeypatch, kernel, reader, args, Cell(cell))
 
 
 def test_a_cut_trace_reads_the_same_time_a_dispatch(monkeypatch):
@@ -201,13 +219,7 @@ def test_a_counter_without_a_span_cannot_divide_device_time(monkeypatch):
     with pytest.raises(BenchmarkError, match="decode_steps"):
         trace_kernel_ms.read(c, prefix="mla_decode", per="decode_steps")
     # every metric file names a counter that has one
-    for folder, names in (("metrics", os.listdir(os.path.join(ROOT, "metrics"))),
-                          ("", ["staged.json"])):
-        for name in names:
-            text = open(os.path.join(ROOT, folder, name)).read()
-            for entry in json.loads("[" + text + "]"):
-                per = json.dumps(entry).split('"per": "')[1:]
-                assert {p.split('"')[0] for p in per} <= set(covered.COUNTED_BY)
+    rules.counters_have_spans(ROOT)
 
 
 def test_the_result_line_says_what_the_trace_covers(monkeypatch):
@@ -322,28 +334,17 @@ def test_the_recorded_spans_lead_their_device_work_by_a_step(monkeypatch):
     assert set(behind) == {1}
 
 
-@pytest.mark.parametrize("lead", [LEAD, 0], ids=["as-recorded", "a-step-behind"])
-@pytest.mark.parametrize("cut", CUTS)
-@pytest.mark.parametrize("kernel,reader,cell", [
-    ("mla_decode", mla_roofline, "gigachat3.1-702b-a36b.serve-longdoc"),
-    ("mla_decode", mla_roofline_layers, "longcat-flash-chat.serve-longout"),
-    ("paged_decode", paged_roofline, "gpt2-medium.serve-chat"),
-    ("paged_decode", sparse_paged_roofline, "minicpm-sala.serve-doc16k"),
-    ("linear_decode", linear_roofline, "minicpm-sala.serve-doc16k"),
-], ids=lambda v: v if isinstance(v, str) else v.__name__.rsplit(".", 1)[1])
-def test_a_cut_at_any_instant_reads_the_share_of_what_is_left(
-        monkeypatch, kernel, reader, cell, cut, lead):
-    """The rule is *a span that touches the covered interval counts*. Under
-    a loop one step ahead it admits a dispatch too many or too few at each
-    cut edge (the span of the step after the last whose device work is left;
-    the first step's, which ended before its work began): at most 2 of the
-    116-173 dispatches left here, and the reading is held to 2% of the
-    truth, the clocks as recorded or the device's work a step behind its
-    span. (Three and a half steps behind, the count is off by up to 4.)
-    Taking the window whole, as the parent did, reads the window over the
-    part: 1.6 times the share at the first cut."""
-    cell = Cell(cell)
-    args = {"layers": 8} if reader is mla_roofline_layers else {}
+def share_of_what_a_cut_leaves(monkeypatch, kernel, reader, args, cell, cut,
+                               lead):
+    """``cell`` a ``Cell``. The rule is *a span that touches the covered
+    interval counts*. Under a loop one step ahead it admits a dispatch too
+    many or too few at each cut edge (the span of the step after the last
+    whose device work is left; the first step's, which ended before its work
+    began): at most 2 of the 116-173 dispatches left here, and the reading is
+    held to 2% of the truth, the clocks as recorded or the device's work a
+    step behind its span. (Three and a half steps behind, the count is off by
+    up to 4.) Taking the window whole, as the parent did, reads the window
+    over the part: 1.6 times the share at the first cut."""
     summary_, steps = pipelined(monkeypatch, kernel, *CUTS[cut], lead=lead)
     assert 90 < len(steps) < 0.8 * STEPS
     got = reader.read(ctx(summary_, cell, {"dispatches": STEPS}), **args)
@@ -353,6 +354,15 @@ def test_a_cut_at_any_instant_reads_the_share_of_what_is_left(
     want = reader.read(truth(monkeypatch, summary_, steps, cell), **args)
     assert want > 0 and got == pytest.approx(want, rel=0.02)
     assert parent > 1.25 * want
+
+
+@pytest.mark.parametrize("lead", [LEAD, 0], ids=["as-recorded", "a-step-behind"])
+@pytest.mark.parametrize("cut", CUTS)
+@pytest.mark.parametrize("kernel,reader,args,cell", SHARES)
+def test_a_cut_at_any_instant_reads_the_share_of_what_is_left(
+        monkeypatch, kernel, reader, args, cell, cut, lead):
+    share_of_what_a_cut_leaves(monkeypatch, kernel, reader, args, Cell(cell),
+                               cut, lead)
 
 
 @pytest.mark.parametrize("lead", [LEAD, 0], ids=["as-recorded", "a-step-behind"])

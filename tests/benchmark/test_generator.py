@@ -41,6 +41,53 @@ def test_same_seed_same_schedule_and_any_seed_same_work():
     assert len(serve.open_recs(MIX, 2.0, 7, 0.0, 15.0, 50304, 1024)) == 30
 
 
+def test_a_cycle_sends_every_seed_the_same_round_at_the_same_times():
+    mix = {**MIX, "cycle": {"order": 3}}
+    big = 2**31 + 12345
+
+    def recs(seed, window=45.0, order=3):
+        return serve.open_recs({**mix, "cycle": {"order": order}}, 2.0, seed,
+                               15.0, window, 50304, 1024)
+
+    def shape(r):
+        return (r.due, len(r.prompt), r.out_len)
+
+    a, b = recs(big), recs(7)
+    assert [(r.due, r.prompt) for r in a] == [(r.due, r.prompt) for r in recs(big)]
+    # the same lengths at the same times, other token ids
+    assert [shape(r) for r in a] == [shape(r) for r in b]
+    assert all(x.prompt != y.prompt for x, y in zip(a, b))
+    dues = np.array([r.due for r in a])
+    assert dues[0] >= 0.0 and np.all(np.diff(dues) > 0) and dues[-1] < 60.0
+    inside = [r for r in a if r.due >= 15.0]
+    pre = a[:len(a) - 90]
+    assert len(inside) == 90 and 15 <= len(pre) <= 45
+    # the window's requests: the work of ``requests`` and ``poisson_dues``
+    want = traffic.requests(MIX, 1, 90, 50304, 1024)
+    assert sorted(len(r.prompt) for r in inside) == sorted(
+        len(r["prompt"]) for r in want)
+    assert sorted(r.out_len for r in inside) == sorted(r["out_len"] for r in want)
+    gaps = np.diff([r.due for r in a])
+    first = 2 * (inside[0].due - 15.0)      # half of it lies in the window
+    b_dues = traffic.poisson_dues(2.0, 1, 45.0)
+    assert np.allclose(np.sort(np.append(gaps[-89:], first)),
+                       np.sort(np.diff(b_dues, prepend=-b_dues[0])))
+    # the ramp is the round run backwards: its last request is the round's
+    # last, at the distance of the round's first gap
+    assert [shape(r)[1:] for r in pre] == [shape(r)[1:] for r in inside[-len(pre):]]
+    assert np.allclose(gaps[:len(pre) - 1], gaps[-(len(pre) - 1):])
+    assert np.isclose(inside[0].due - pre[-1].due, first)
+    # a shorter window (a traced run's) is a round of its own length, and the
+    # ramp goes around it more than once
+    short = recs(7, window=8.0)
+    assert sum(15.0 <= r.due < 23.0 for r in short) == 16 and len(short) > 40
+    # the order is the mix's
+    other = recs(big, order=4)
+    assert [shape(r) for r in other] != [shape(r) for r in a]
+    assert sorted(len(r.prompt) for r in other[-90:]) == sorted(
+        len(r.prompt) for r in inside)
+
+
 class _State:
     def __init__(self):
         self.finished = False

@@ -11,9 +11,10 @@ import sys
 
 import pytest
 
-from benchmark.harness.cell import REPO, Cell, load_spec
+from benchmark.harness.cell import REPO, ROOT, Cell, load_spec
 from benchmark.readers import bubble_share, starved_share, variant_ms
 from deepspeed_tpu.utils import tracing
+from tests.benchmark import rules
 
 R = tracing.Record
 MS = 1_000_000
@@ -179,18 +180,10 @@ def test_the_parent_reads_nothing(monkeypatch):
 
 
 def test_every_new_metric_names_a_reader_and_its_cell():
-    spec = load_spec()
-    new = [m for m in spec["per_layer"] if m["name"] in (
-        "engine.round_device_ms", "engine.mixed_device_ms", "model.mixed_attn_ms",
-        "engine.mixed_kv_write_ms", "moe.mixed_experts_ms",
-        "sched.bubble_share", "sched.starved_share")]
-    assert len(new) == 7 and sum(len(m["workloads"]) for m in new) == 22
-    for m in new:
-        assert m["moves"] == "itl_p50_ms"
-        for cell in m["workloads"]:
-            read, args = Cell(cell).reader(m["name"])
-            assert read.__module__.rsplit(".", 1)[1] in (
-                "variant_ms", "bubble_share", "starved_share")
+    """``rules.round_entries``: the five every serving cell steers by list
+    every cell that reports ``itl_p50_ms`` (a fifth serving cell joins them),
+    the two of one scope only cells whose configuration has it."""
+    rules.round_entries(load_spec(), ROOT)
 
 
 def test_the_readers_on_the_rehearsal_of_serve_chat():
