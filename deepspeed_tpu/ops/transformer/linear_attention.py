@@ -22,8 +22,14 @@ Two forms of the same recurrence:
 
 - :func:`decode_rows`: rows of one token each, one sequence a row: the
   Pallas kernel :func:`linear_decode` where the paged programs take kernels
-  (``paged_attention.kernels_wanted``), which reads and writes the state of
-  the live rows alone, else the same arithmetic in XLA over all rows;
+  (``paged_attention.kernels_wanted``), else the same arithmetic in XLA over
+  all rows. The kernel's work follows the rows that hold a slot: a cell
+  lists its live rows with a loop of scalar steps and walks them, each
+  one's state block fetched, updated and written back by the kernel's own
+  overlapped copies from the slot array left in HBM; a padding row costs
+  its compare and touches no slot, the trash slot included. It takes q, k, v
+  and gives o as the model's ``(rows, heads * d)`` rows, a head a lane tile:
+  on a chip a model whose heads are not 128 wide keeps the XLA form;
 - :func:`chunk_tiles`: tiles of ``C`` consecutive tokens of one sequence (a
   prefill chunk's segment), the blocked form
 
@@ -50,8 +56,17 @@ from ...utils import tracing
 _HI = jax.lax.Precision.HIGHEST
 
 #: heads of one cell of :func:`linear_decode`: a state block of 8 x 128 x 128
-#: float32 is 512 KiB, so the pipeline's two buffers each way take 2 MiB
+#: float32 is 512 KiB, and the kernel's three buffers take 1.5 MiB (on the
+#: chip, us a call over 48 rows of 32 heads at 0 | 11 | 48 live rows, PERF.md
+#: 5: 4 heads 1.7 | 86.0 | 373.4, 8 heads 1.4 | 72.9 | 311.0, 16 heads 1.4 |
+#: 71.7 | 307.3, 32 heads 1.0 | 70.7 | 307.3, the parent's row a cell 41.3 |
+#: 104.4 | 309.2: from 8 heads on a live row takes what its 4.19 MB take at
+#: 650 GB/s, and 3 to 6 buffers read alike)
 DECODE_HEADS = 8
+#: rows of a step one cell of :func:`linear_decode` covers at most (us a call
+#: at 11 live rows of 48, a prefix | scattered: 16 rows a cell 73.7 | 75.4,
+#: 24 rows 73.3 | 74.1, all 48 in one cell 72.9 | 72.9)
+DECODE_CELL_ROWS = 64
 
 
 def head_decay_rates(n_heads: int) -> np.ndarray:
@@ -72,9 +87,12 @@ def decode_rows(state, layer, slots, q, k, v, fresh):
     (traced or not); ``slots`` (R,) int32, 0 for a padding row; q, k (R, h,
     dk), v (R, h, dv), q already scaled; ``fresh`` (R,) bool: the row is its
     sequence's first token. Returns (o (R, h, dv) float32, new state)."""
-    from .paged_attention import kernels_wanted
+    from .paged_attention import _interpret, kernels_wanted
 
-    if kernels_wanted() and q.shape[1] % DECODE_HEADS == 0:
+    # Mosaic takes rows whose heads are whole lane tiles; the interpreter any
+    lane_tiles = q.shape[2] == v.shape[2] == 128
+    if (kernels_wanted() and q.shape[1] % DECODE_HEADS == 0
+            and (lane_tiles or _interpret())):
         return linear_decode(state, layer, slots, q, k, v, fresh)
     rates = jnp.asarray(head_decay_rates(q.shape[1]))
     with jax.named_scope("linear_attn"):
@@ -89,74 +107,219 @@ def decode_rows(state, layer, slots, q, k, v, fresh):
     return o, state
 
 
-def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref,
-                   s_ref, o_ref, s_out, *, heads, n_heads):
-    """One (head block, row) cell: q_ref, k_ref (1, 1, dk, heads) hold the
-    row's q and k as columns, v_ref (1, 1, heads, dv) its v as rows, s_ref /
-    s_out (1, 1, heads, dk, dv) the state block of the row's slot."""
-    c, r = pl.program_id(0), pl.program_id(1)
-    live = slots_ref[r] > 0
+def rows_per_cell(rows: int) -> int:
+    """Rows of the step one cell of :func:`linear_decode` covers, from the
+    step's row count alone (``paged_attention.rows_per_cell``'s rule): its
+    largest divisor that is at most :data:`DECODE_CELL_ROWS` (all 48 of the
+    ``serve-doc16k`` round and of its mixed step's one-token rows; a count
+    with no such divisor but one keeps a row a cell)."""
+    return max(d for d in range(1, DECODE_CELL_ROWS + 1) if rows % d == 0)
 
-    @pl.when(live)
-    def _():
-        started = fresh_ref[r] == 0
-        for h in range(heads):
-            # lambda_h as a row of lanes: exp(-2^(-8 (h + 1) / n_heads))
-            head = (c * heads + h + 1).astype(jnp.float32)
-            rate = jnp.exp(jnp.full((1, s_ref.shape[-1]), head, jnp.float32)
-                           * (-8.0 * np.log(2.0) / n_heads))
-            keep = jnp.where(started, jnp.exp(-rate), 0.0)
-            new = (keep * s_ref[0, 0, h]
-                   + k_ref[0, 0, :, h:h + 1] * v_ref[0, 0, h:h + 1, :])
-            s_out[0, 0, h] = new
-            o_ref[0, 0, h:h + 1, :] = jnp.sum(q_ref[0, 0, :, h:h + 1] * new,
-                                              axis=0, keepdims=True)
 
-    @pl.when(jnp.logical_not(live))
-    def _():
-        s_out[...] = s_ref[...]
-        o_ref[...] = jnp.zeros_like(o_ref)
+def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref, _,
+                   o_ref, s_ref, buf, rsem, wsem, live_ref, q32, k32, v32, *,
+                   n_heads):
+    """Grid (R / rpc, heads / hb): ONE cell per ``rpc`` rows of the step
+    (:func:`rows_per_cell`) and block of ``hb`` heads (:data:`DECODE_HEADS`).
+    q_ref, k_ref (rpc, hb * dk) and v_ref, o_ref (rpc, hb * dv) are blocks of
+    the model's lane-dense rows; ``s_ref`` is the whole slot array where it
+    lies in HBM, the call's aliased OUTPUT, read and written by the kernel's
+    own copies through ``buf`` (3, hb, dk, dv).
+
+    The first head block of a row cell lists the cell's live rows
+    (``slots > 0``) in ``live_ref`` with a loop of scalar steps and leaves
+    their count behind them; every head block of the cell walks that list. A
+    dead row costs its compare and nothing else: no copy names its slot (not
+    the trash slot either) and its ``o`` row keeps the zeros the cell starts
+    from.
+
+    The walk is one pipeline over the row cell's (head block, live row)
+    pairs, ``g`` counting them across its head blocks, pair ``g`` in buffer
+    ``g % 3``: while pair ``g`` is computed where it lies in its buffer, the
+    block of pair ``g + 1`` (the next live row, or the first one of the next
+    head block: the hand-over) is on its way in and the write-back of pair
+    ``g - 1`` on its way out; the buffer of pair ``g + 1`` is free once the
+    write-back of pair ``g - 2`` is done. Only the first pair of a row cell
+    waits for a fetch with nothing to hide it, and only its last head block
+    waits for the write-backs to drain."""
+    del _  # the same buffer as ``s_ref``: input_output_aliases
+    rpc, (nbuf, hb, dk, dv) = q_ref.shape[0], buf.shape
+    c, n_blocks = pl.program_id(1), pl.num_programs(1)
+    row0 = pl.program_id(0) * rpc
+    layer = layer_ref[0]
+
+    @pl.when(c == 0)
+    def _sort():
+        def sort_row(r, n_live):
+            # a dead row's number is overwritten by the next live row's
+            live_ref[n_live] = r
+            return n_live + (slots_ref[row0 + r] > 0).astype(jnp.int32)
+
+        live_ref[rpc] = jax.lax.fori_loop(0, rpc, sort_row, 0)
+
+    n_live = live_ref[rpc]
+
+    def block(c, k):
+        """The state of head block ``c`` of the cell's ``k``-th live row."""
+        return s_ref.at[layer, slots_ref[row0 + live_ref[k]],
+                        pl.ds(c * hb, hb)]
+
+    def fetch(c, k, slot):
+        return pltpu.make_async_copy(block(c, k), buf.at[slot], rsem.at[slot])
+
+    def store(c, k, slot):
+        return pltpu.make_async_copy(buf.at[slot], block(c, k), wsem.at[slot])
+
+    @pl.when((c == 0) & (n_live > 0))
+    def _cold():
+        fetch(0, 0, 0).start()
+
+    # every row's output starts as zeros, which is what a dead row keeps
+    # (stored while the first fetch is on its way)
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    # the kernel's Python runs once a program in every process, compile
+    # cache or none (``kernel.setup_trace_s``): a row's and a head's pieces
+    # are cut and spread by ``lax`` primitives, which bind an equation each,
+    # where ``jnp``'s indexing and operators trace a jitted function each
+    def piece(x, axis, h, n=1):
+        """``x[h * n:(h + 1) * n]`` along ``axis`` of a 2-D value."""
+        lo, hi = [0, 0], list(x.shape)
+        lo[axis], hi[axis] = h * n, (h + 1) * n
+        return jax.lax.slice(x, lo, hi)
+
+    def spread(x):
+        return jax.lax.broadcast_in_dim(x, (dk, dv), (0, 1))
+
+    def heads_of(staged, r, d):
+        """Row ``r`` of a staged ``(rpc, hb * d)`` block as (hb, d)."""
+        flat = staged[pl.ds(r, 1), :]
+        return jax.lax.concatenate([piece(flat, 1, h, d) for h in range(hb)],
+                                   0)
+
+    def update(k, decay):
+        """The cell's ``k``-th live row, its block in flight or arrived;
+        ``decay`` (hb, dv): lambda_h a row of lanes, a head a row."""
+        g = c * n_live + k
+        slot, free = jax.lax.rem(g, nbuf), jax.lax.rem(g + 1, nbuf)
+        last = k + 1 == n_live
+
+        @pl.when(g + 1 >= nbuf)
+        def _buffer_is_free():
+            # pair g + 1 - nbuf's: same bytes, same semaphore
+            store(c, k, free).wait()
+
+        @pl.when(jnp.logical_not(last) | (c + 1 < n_blocks))
+        def _prefetch():
+            fetch(jnp.where(last, c + 1, c), jnp.where(last, 0, k + 1),
+                  free).start()
+
+        r = live_ref[k]
+        started = fresh_ref[row0 + r] == 0
+        # q and k a head a column, (dk, hb); v a head a row
+        q_col, k_col = heads_of(q32, r, dk).T, heads_of(k32, r, dk).T
+        v_row = heads_of(v32, r, dv)
+        keep = jnp.where(started, decay, 0.0)
+        fetch(c, k, slot).wait()
+        out = []
+        for h in range(hb):
+            new = jax.lax.add(
+                jax.lax.mul(spread(piece(keep, 0, h)), buf[slot, h]),
+                jax.lax.mul(spread(piece(k_col, 1, h)),
+                            spread(piece(v_row, 0, h))))
+            buf[slot, h] = new
+            out.append(jnp.sum(jax.lax.mul(spread(piece(q_col, 1, h)), new),
+                               axis=0, keepdims=True))
+        o_ref[pl.ds(r, 1), :] = jax.lax.concatenate(out, 1)
+        store(c, k, slot).start()
+        return decay
+
+    @pl.when(n_live > 0)
+    def _live_cell():
+        # Mosaic reads no single row out of a packed bfloat16 block
+        for staged, ref in ((q32, q_ref), (k32, k_ref), (v32, v_ref)):
+            staged[...] = ref[...].astype(jnp.float32)
+        # exp(-2^(-8 (h + 1) / n_heads))
+        head = c * hb + 1 + jax.lax.broadcasted_iota(jnp.int32, (hb, dv), 0)
+        jax.lax.fori_loop(0, n_live, update, jnp.exp(-jnp.exp(
+            head.astype(jnp.float32) * (-8.0 * np.log(2.0) / n_heads))))
+
+        @pl.when(c + 1 == n_blocks)
+        def _drain():
+            pairs = n_blocks * n_live
+
+            def wait(g, _):
+                store(c, 0, jax.lax.rem(g, nbuf)).wait()
+                return 0
+
+            jax.lax.fori_loop(jnp.maximum(pairs - nbuf + 1, 0), pairs, wait, 0)
 
 
 def linear_decode(state, layer, slots, q, k, v, fresh):
     """:func:`decode_rows` as a Pallas kernel, in place on the slot array
-    (aliased to its result): a cell is ``DECODE_HEADS`` heads of one row, and
-    the grid walks the rows inside the head blocks, so that the padding rows,
-    which all name slot 0 and follow the live ones, fetch the trash slot's
-    block once a head block and not once a row. A live row's state is read
-    once and written once; no two live rows may name one slot."""
+    (left in HBM whole and aliased to the result). q, k, v go in and o comes
+    out as the model holds them, ``(R, heads * d)`` lane-dense rows in their
+    own dtype (o float32): the column forms of q and k that the outer product
+    and the read-out need are made in the kernel, a live row at a time. The
+    grid is ``(R / rpc, heads / DECODE_HEADS)``; a cell lists its live rows
+    (``slots > 0``) and walks them, moving each one's state block in, updating
+    it where it lies in VMEM and moving it back with its own overlapped
+    copies (:func:`_decode_kernel`). A live row's state is read once and
+    written once; a dead row reads and writes nothing of the slot array, the
+    trash slot included, and costs a scalar step, not a grid step. No two
+    live rows may name one slot."""
     from .paged_attention import _interpret
 
     R, H, dk = q.shape
-    dv, hb = v.shape[-1], DECODE_HEADS
-    cols = lambda a: a.astype(jnp.float32).reshape(  # noqa: E731
-        R, H // hb, hb, -1).transpose(0, 1, 3, 2)          # (R, H/hb, d, hb)
-    col = pl.BlockSpec((1, 1, dk, hb), lambda c, r, *_: (r, c, 0, 0))
-    row = pl.BlockSpec((1, 1, hb, dv), lambda c, r, *_: (r, c, 0, 0))
-    slot = pl.BlockSpec((1, 1, hb, dk, dv),
-                        lambda c, r, layer, slots, _: (layer[0], slots[r], c,
-                                                       0, 0))
+    o, state = _decode_call(
+        jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
+        fresh.astype(jnp.int32), q.reshape(R, H * dk), k.reshape(R, H * dk),
+        v.reshape(R, -1), state, heads=H, hb=DECODE_HEADS,
+        rpc=rows_per_cell(R), interpret=_interpret())
+    return o.reshape(R, H, -1), state
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("heads", "hb", "rpc", "interpret"))
+def _decode_call(layer, slots, fresh, q, k, v, state, *, heads, hb, rpc,
+                 interpret):
+    """The call of :func:`linear_decode`, its equations inlined into the
+    program that holds it. Jitted for its cache alone: a process binds the
+    kernel once a program (four in a serving process: the decode round and
+    the mixed step of the check's engine and of the cell's), and the body's
+    Python, a third of a second to a second a bind on a busy host, runs for
+    the first of them (``kernel.setup_trace_s``)."""
+    R, dk, dv = q.shape[0], q.shape[1] // heads, v.shape[1] // heads
+
+    def lanes(d):
+        return pl.BlockSpec((rpc, hb * d), lambda i, c, *_: (i, c))
+
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)     # the slot array stays there
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, slots, fresh
-        grid=(H // hb, R),
-        in_specs=[col, col, row, slot],
-        out_specs=[row, slot],
+        grid=(R // rpc, heads // hb),
+        in_specs=[lanes(dk), lanes(dk), lanes(dv), in_hbm],
+        out_specs=[lanes(dv), in_hbm],
+        scratch_shapes=[
+            pltpu.VMEM((3, hb, dk, dv), state.dtype),
+            pltpu.SemaphoreType.DMA((3,)),         # the fetches'
+            pltpu.SemaphoreType.DMA((3,)),         # the write-backs'
+            pltpu.SMEM((rpc + 1,), jnp.int32),     # the live rows, their count
+            *(pltpu.VMEM((rpc, hb * d), jnp.float32) for d in (dk, dk, dv)),
+        ],
     )
     with jax.named_scope("linear_attn"):
-        o, state = tracing.pallas_call(
-            functools.partial(_decode_kernel, heads=hb, n_heads=H),
+        return tracing.pallas_call(
+            functools.partial(_decode_kernel, n_heads=heads),
             grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct((R, H // hb, hb, dv), jnp.float32),
+            out_shape=[jax.ShapeDtypeStruct((R, heads * dv), jnp.float32),
                        jax.ShapeDtypeStruct(state.shape, state.dtype)],
             input_output_aliases={6: 1},  # the slot array, scalars counted
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
-            interpret=_interpret(),
+            interpret=interpret,
             name="linear_decode",
-        )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
-          fresh.astype(jnp.int32), cols(q), cols(k),
-          v.astype(jnp.float32).reshape(R, H // hb, hb, dv), state)
-    return o.reshape(R, H, dv), state
+        )(layer, slots, fresh, q, k, v, state)
 
 
 def _tile_decays(n_heads: int, tile: int):

@@ -686,6 +686,109 @@ def test_an_expert_models_round_holds_no_row_buffer(one_chip, no_compile_cache,
     assert temps <= PARENT_ROUND_TEMPS[cell], temps
 
 
+def test_a_lightning_layers_round_is_one_call_on_the_models_rows(
+        one_chip, no_compile_cache, as_tpu):
+    """The decode round of a (``sparse_attn``, ``linear_attn``) model at the
+    ``serve-doc16k`` cell's widths and shapes (48 one-token rows, 49 slots):
+    ONE ``linear_decode`` call for the lightning layer, which takes q, k and v
+    and gives o as ``(rows, heads * head_dim)``: no float32 copy of the rows
+    a head a column or a head a row around it (the parent made four a layer:
+    ``cols(q)``, ``cols(k)``, v and o as ``f32[48,4,8,128]``)."""
+    from benchmark.harness.cell import load_json
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    types = ["sparse_attn", "linear_attn"]
+    model = TransformerLM(TransformerConfig(**{
+        **load_json("configs", "minicpm-sala.json")["model"],
+        "num_layers": len(types), "layer_types": types}))
+    engine = load_json("traffic", "serve-doc16k.json")["engine"]
+    rows, width = engine["max_seqs"], model.config.hidden_size
+    params, pool, tables, starts = serving_avals(
+        one_chip, model, rows, engine["num_blocks"], engine["block_size"],
+        engine["max_seq_len"] // engine["block_size"])
+    state = jax.tree.map(
+        lambda a: aval(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init_state_cache(
+            rows, engine["max_seq_len"], dtype=jnp.bfloat16)))
+
+    def program(params, ids, pool, state, tables, starts, slots, logit_rows):
+        return model.forward_paged(
+            params, ids, pool, tables, starts, logit_rows=logit_rows,
+            moe_stats=True, rows_apart=True, state=state, row_slots=slots)
+
+    rows_i32 = aval(one_chip, (rows,), jnp.int32)
+    text = jax.jit(program, donate_argnums=(2, 3)).lower(
+        params, aval(one_chip, (rows, 1), jnp.int32), pool, state, tables,
+        starts, rows_i32, rows_i32).compile().as_text()
+    calls = re.findall(r"%linear_decode[.\d]* = \((\w+\[[\d,]+\])\S*, "
+                       r".* custom-call\(", text)
+    assert calls == [f"f32[{rows},{width}]"], calls
+    relaid = [line.strip()[:120] for line in text.splitlines() if re.search(
+        rf"= f32\[{rows},4,(?:128,8|8,128)\]\S* (?:copy|transpose|convert)\(",
+        line)]
+    assert not relaid, relaid
+
+
+def test_linear_decode_starts_no_copy_for_a_cell_with_no_live_row():
+    """The kernel's body as it is traced (nothing compiles): the slot array
+    is left where it lies (no block of it is the pipeline's to copy), and
+    every copy the kernel starts or waits for sits under a branch taken only
+    where the cell's count of live rows is above zero: a cell whose rows are
+    all dead, and a dead row of any cell, moves nothing of the slot array,
+    the trash slot included."""
+    from jax.extend.core import Var
+
+    from deepspeed_tpu.ops.transformer import linear_attention as la
+
+    rows, heads, hd = 48, 32, 128
+    sds = jax.ShapeDtypeStruct
+    act = sds((rows, heads, hd), jnp.bfloat16)
+    # a function of its own: a trace of ``la.linear_decode`` itself would be
+    # found again, interpreted, by a later ``jax.jit`` of it in this process
+    traced = jax.make_jaxpr(lambda *args: la.linear_decode(*args))(
+        sds((6, rows + 1, heads, hd, hd), jnp.float32), sds((), jnp.int32),
+        sds((rows,), jnp.int32), act, act, act, sds((rows,), jnp.bool_))
+
+    def inner(eqn):
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield sub
+
+    def eqns(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for sub in inner(eqn):
+                yield from eqns(sub)
+
+    [call] = [e for e in eqns(traced.jaxpr) if e.primitive.name == "pallas_call"]
+    in_hbm = [str(m.block_aval) for m in call.params["grid_mapping"].block_mappings
+              if m.block_aval.shape == (6, rows + 1, heads, hd, hd)]
+    assert len(in_hbm) == 2 and all(a.startswith("Ref<any>") for a in in_hbm)
+    body = call.params["jaxpr"]
+    made_by = {out: e for e in body.eqns for out in e.outvars}
+
+    def ancestors(var):
+        eqn = made_by.get(var)
+        if eqn is None:
+            return set()
+        return {eqn.primitive.name}.union(
+            *(ancestors(v) for v in eqn.invars if isinstance(v, Var)))
+
+    copies = 0
+    for eqn in body.eqns:
+        assert not eqn.primitive.name.startswith("dma_"), "a copy on every path"
+        held = sum(e.primitive.name.startswith("dma_")
+                   for sub in inner(eqn) for e in eqns(sub))
+        if held:
+            assert eqn.primitive.name == "cond"
+            assert "gt" in ancestors(eqn.invars[0]), "not under n_live > 0"
+            copies += held
+    assert copies >= 4      # the first fetch, the walk's three, the drain
+
+
 def test_flash_refusal_is_loud(as_tpu, monkeypatch):
     """On a TPU backend a shape the kernel cannot take gives way to the XLA
     path only with a logged reason, and not at all when the caller named the

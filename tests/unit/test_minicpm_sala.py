@@ -122,31 +122,87 @@ def test_the_blocked_form_is_the_recurrence(lens):
         np.testing.assert_array_equal(new[1, :2], state[1, :2])
 
 
-def test_decode_rows_kernel_and_xla_are_the_recurrence(monkeypatch):
-    """One token a row: live rows on their slots, a fresh row, padding rows on
-    the trash slot; the Pallas kernel (interpreted) and the XLA form."""
+#: (rows' slots, fresh rows, heads, head width, rows a cell at most) of a step
+#: of one-token rows: what the kernel's walk over its live rows has to get
+#: right. Slot 0 is a dead row; five slots a layer beside the trash slot
+DECODE_STEPS = {
+    # live rows on their slots, a fresh one, then padding rows
+    "live_prefix": ([2, 4, 1, 0, 0, 0], [1, 3], 16, 32, 64),
+    "no_live_row": ([0] * 8, [0, 5], 16, 32, 64),
+    "every_row_live": ([3, 1, 5, 2, 4], [2], 16, 32, 64),
+    "live_rows_scattered": ([0, 3, 0, 0, 5, 0, 1, 0], [4], 16, 32, 64),
+    # two cells of four rows: the first has nothing to walk
+    "one_live_row_in_the_last_cell": ([0, 0, 0, 0, 0, 0, 2, 0], [], 16, 32, 4),
+    # seven rows have no divisor up to four but one: a row a cell
+    "a_row_a_cell": ([4, 0, 0, 1, 0, 5, 2], [3], 16, 32, 4),
+    "a_fresh_row_beside_a_started_one": ([0, 2, 5, 0], [1], 16, 32, 64),
+    # the serve-doc16k cell's heads, bfloat16 operands: four head blocks
+    "the_cells_heads": ([0, 2, 0, 1], [3], 32, 128, 64),
+}
+
+
+@pytest.mark.parametrize("step", DECODE_STEPS)
+def test_decode_rows_kernel_and_xla_are_the_recurrence(monkeypatch, step):
+    """One token a row, in the Pallas kernel (interpreted) and in the XLA
+    form: o and the new state of a live row are the recurrence's, a dead
+    row's o is zeros, and nothing else of the slot array moved: the other
+    layers, every slot no live row names and, under the kernel, the trash
+    slot are bit for bit what they were."""
+    slots, fresh_rows, H, d, cell_rows = DECODE_STEPS[step]
     rng = np.random.default_rng(1)
-    R, H, d = 6, 16, 32
-    state = jnp.asarray(rng.normal(size=(3, 5, H, d, d)), jnp.float32)
-    slots = jnp.asarray([2, 4, 1, 0, 0, 0], jnp.int32)
-    fresh = jnp.asarray([False, True, False, True, False, False])
-    q, k, v = (jnp.asarray(rng.normal(size=(R, H, d)), jnp.float32)
+    R, layer = len(slots), 1
+    dtype = jnp.bfloat16 if d == 128 else jnp.float32
+    tol = dict(rtol=1e-4, atol=1e-4) if d == 128 else dict(rtol=1e-5,
+                                                           atol=1e-5)
+    state = jnp.asarray(rng.normal(size=(3, 6, H, d, d)), jnp.float32)
+    fresh = np.zeros(R, bool)
+    fresh[fresh_rows] = True
+    q, k, v = (jnp.asarray(rng.normal(size=(R, H, d)), dtype)
                for _ in range(3))
-    xla = la.decode_rows(state, jnp.int32(1), slots, q, k, v, fresh)
+    args = (state, jnp.int32(layer), jnp.asarray(slots, jnp.int32), q, k, v,
+            jnp.asarray(fresh))
+    xla = la.decode_rows(*args)
     monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    monkeypatch.setattr(la, "DECODE_CELL_ROWS", cell_rows)
     assert pa.kernels_wanted()
-    kernel = jax.jit(la.decode_rows)(state, jnp.int32(1), slots, q, k, v, fresh)
-    for o, new in (xla, kernel):
-        for r in range(3):
+    assert la.rows_per_cell(R) == {"one_live_row_in_the_last_cell": 4,
+                                   "a_row_a_cell": 1}.get(step, R)
+    kernel = jax.jit(lambda *a: la.decode_rows(*a))(*args)
+    live = [r for r in range(R) if slots[r]]
+    unnamed = [n for n in range(1, state.shape[1]) if n not in slots]
+    for name, (o, new) in (("xla", xla), ("kernel", kernel)):
+        assert o.shape == (R, H, d) and o.dtype == jnp.float32, name
+        for r in live:
             want, last = recurrence(
-                *(np.asarray(a)[r:r + 1] for a in (q, k, v)),
-                None if bool(fresh[r]) else state[1, slots[r]])
-            np.testing.assert_allclose(o[r], want[0], rtol=1e-5, atol=1e-5)
-            np.testing.assert_allclose(new[1, slots[r]], last, rtol=1e-5,
-                                       atol=1e-5)
-        np.testing.assert_array_equal(new[0], state[0])
-        np.testing.assert_array_equal(new[1, 3], state[1, 3])
-    np.testing.assert_array_equal(np.asarray(kernel[0])[3:], 0.0)
+                *(np.asarray(a, np.float64)[r:r + 1] for a in (q, k, v)),
+                None if fresh[r] else state[layer, slots[r]])
+            np.testing.assert_allclose(o[r], want[0], err_msg=name, **tol)
+            np.testing.assert_allclose(new[layer, slots[r]], last,
+                                       err_msg=name, **tol)
+        np.testing.assert_array_equal(new[0], state[0], err_msg=name)
+        np.testing.assert_array_equal(new[2], state[2], err_msg=name)
+        np.testing.assert_array_equal(new[layer, unnamed],
+                                      state[layer, unnamed], err_msg=name)
+    o, new = kernel
+    dead = [r for r in range(R) if not slots[r]]
+    np.testing.assert_array_equal(np.asarray(o)[dead], 0.0)
+    np.testing.assert_array_equal(new[layer, 0], state[layer, 0])
+
+
+@pytest.mark.parametrize("width,kernel", [(128, True), (64, False)])
+def test_on_a_chip_the_kernel_takes_heads_that_are_whole_lane_tiles(
+        monkeypatch, width, kernel):
+    """Where Mosaic compiles it (a TPU backend, no interpreter) the kernel is
+    taken for heads of 128 and the XLA form for any other width: read off the
+    traced program, nothing compiles."""
+    monkeypatch.delenv("DSTPU_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    sds = jax.ShapeDtypeStruct
+    act = sds((8, 16, width), jnp.bfloat16)
+    traced = jax.make_jaxpr(lambda *args: la.decode_rows(*args))(
+        sds((2, 9, 16, width, width), jnp.float32), sds((), jnp.int32),
+        sds((8,), jnp.int32), act, act, act, sds((8,), jnp.bool_))
+    assert ("pallas_call" in str(traced)) == kernel
 
 
 # -- (b) the sparse selector ------------------------------------------------
