@@ -45,7 +45,7 @@ FED_ON_DEVICE = -1
 
 
 def feed_layout(rows: int, max_seqs: int, max_blocks_per_seq: int,
-                stateful: bool):
+                stateful: bool, window_blocks: int = 0):
     """Where each field of a ragged step's feed lies in its ONE int32
     buffer (docs/SERVING.md "One packed feed"): ``({name: (slice, shape,
     dtype)}, length)``, a function of shapes the engine knows and nothing
@@ -53,7 +53,11 @@ def feed_layout(rows: int, max_seqs: int, max_blocks_per_seq: int,
     views of the same words on the host (``.view``) and bitcasts of them in
     the program. ``src_rows`` names, for a row fed on the device, its row of
     the preceding round's result (-1: the host's ``ids``); ``row_slots`` (a
-    stateful model's) is empty for any other model."""
+    stateful model's) is empty for any other model. ``wtables`` and
+    ``wbase``, a row's table of the window class of blocks (``window_blocks``
+    entries) and the position its first entry starts at, are fields only of
+    a model with a bounded class (``TransformerConfig.bounded_cache``):
+    every other model's feed is word for word what it was."""
     T, M = rows, max_seqs
     i32, f32 = np.int32, np.float32
     fields = (("ids", (T, 1), i32), ("tables", (T, max_blocks_per_seq), i32),
@@ -62,12 +66,20 @@ def feed_layout(rows: int, max_seqs: int, max_blocks_per_seq: int,
               ("temps", (M,), f32), ("top_ks", (M,), i32),
               ("top_ps", (M,), f32), ("src_rows", (T,), i32),
               ("row_slots", (T if stateful else 0,), i32))
+    if window_blocks:
+        fields += (("wtables", (T, window_blocks), i32), ("wbase", (T,), i32))
     layout, at = {}, 0
     for name, shape, dtype in fields:
         end = at + int(np.prod(shape))
         layout[name] = (slice(at, end), shape, dtype)
         at = end
     return layout, at
+
+
+#: the window fields of a feed whose layout has none (every model without a
+#: bounded class of blocks): empty, and no words of the buffer
+_NO_WINDOW = {"wtables": np.zeros((0, 0), np.int32),
+              "wbase": np.zeros((0,), np.int32)}
 
 
 class PackedFeed(NamedTuple):
@@ -86,6 +98,8 @@ class PackedFeed(NamedTuple):
     top_ps: np.ndarray
     src_rows: np.ndarray
     row_slots: np.ndarray
+    wtables: np.ndarray
+    wbase: np.ndarray
     buf: np.ndarray
 
 
@@ -196,6 +210,19 @@ class InferenceEngineV2:
         #: some layers keep a state slot a sequence beside the pool
         #: (``TransformerConfig.cache_kinds``): the second kind of cache
         self._stateful = bool(getattr(model.config, "holds_state", False))
+        #: some layers' blocks are a bounded class, freed behind a window
+        #: (``TransformerConfig.bounded_cache``): a block then no longer
+        #: holds a token in every layer
+        self._windowed = bool(getattr(model.config, "bounded_cache", False))
+        if self._windowed and (prefix_cache or decode_horizon > 1
+                               or host_tier_blocks):
+            raise ValueError(
+                "a model with a bounded class of KV blocks (window layers "
+                f"of {model.config.sliding_window} tokens) is served with "
+                "prefix_cache=False, decode_horizon=1 and no host tier: a "
+                "block freed behind the window cannot be shared, copied on "
+                "write, swapped or exported, and a rolled-back draft token "
+                "may need a block that was freed")
         if self._stateful and (prefix_cache or decode_horizon > 1):
             raise ValueError(
                 "a model with state-slot layers "
@@ -218,8 +245,13 @@ class InferenceEngineV2:
         #: not be allocated (the pool served the rows that fit instead of
         #: failing the whole step) — chunked-prefill pressure diagnostics
         self.plan_deferrals = 0
-        #: block allocations / COW copies seen by the previous dispatch
-        self._count_marks = (0, 0)
+        #: block allocations (every class's) / COW copies / window-class
+        #: blocks freed behind their windows, as the previous dispatch saw them
+        self._count_marks = (0, 0, 0)
+        #: of a model with a bounded class: the most blocks of each class in
+        #: use at any dispatch so far (what ``benchmark/class_peak.py`` sizes
+        #: a cell's classes from)
+        self.block_peaks = {"full": 0, "window": 0}
         self._ragged_fn = None
         self._cow_fn = None
         self._fused_fn = None
@@ -303,7 +335,33 @@ class InferenceEngineV2:
         # num_blocks*block_size tokens shared across sequences instead of
         # max_seqs*max_seq_len dedicated slots
         max_blocks_per_seq = -(-self.max_seq_len // block_size)
-        if num_blocks is None:
+        #: (blocks, bound, table width) of the window class, or None
+        self._window_spec = None
+        if self._windowed:
+            # the most a sequence holds: the window, one step's tokens (a
+            # chunk fits what the mixed step leaves beside its one-token
+            # rows) and the two provisional tokens a pipelined round may
+            # still take back, in whole blocks with both ends partial
+            bound = self.cfg.sliding_window
+            tile = getattr(model, "segment_tile", 1)
+            chunk = min(prefill_chunk, self.token_budget if tile == 1 else
+                        (self.token_budget - max_seqs) // tile * tile)
+            width = min((bound + 1 + max(chunk, 1)) // block_size + 2,
+                        max_blocks_per_seq)
+            classes = sorted(self.cfg.class_layers)
+            if num_blocks is not None and (
+                    not isinstance(num_blocks, dict)
+                    or not set(num_blocks) <= set(classes)):
+                raise ValueError(
+                    f"num_blocks={num_blocks!r}: a model with window layers "
+                    f"takes a count a class of KV blocks, {{class: blocks}} "
+                    f"over {classes} (a class left out gets room for "
+                    "max_seqs sequences)")
+            given = num_blocks or {}
+            num_blocks = given.get("full", 1 + max_seqs * max_blocks_per_seq)
+            self._window_spec = (given.get("window", 1 + max_seqs * width),
+                                 bound, width)
+        elif num_blocks is None:
             num_blocks = 1 + max_seqs * max_blocks_per_seq  # = slot capacity
         if sanitize_enabled():
             # checked mode (docs/ANALYSIS.md): the sanitizing cache
@@ -314,17 +372,21 @@ class InferenceEngineV2:
                 prefix_cache=self.prefix_cache,
                 host_tier_blocks=self.host_tier_blocks,
                 state_slots=max_seqs if self._stateful else 0,
+                window=self._window_spec,
                 descs=lambda: self.state.seqs.values())
         else:
             self.block_mgr = BlockedKVCache(
                 num_blocks, block_size, max_blocks_per_seq,
                 prefix_cache=self.prefix_cache,
                 host_tier_blocks=self.host_tier_blocks,
-                state_slots=max_seqs if self._stateful else 0)
+                state_slots=max_seqs if self._stateful else 0,
+                window=self._window_spec)
         self.block_mgr.demote_fn = self._demote_block
         self._bind_nvme_tier()
         init.mark("state")
-        self.kv = model.init_kv_pool(num_blocks, block_size, dtype=dtype)
+        #: the pool; of a model with a bounded class {class: pool}
+        self.kv = model.init_kv_pool(self._pool_blocks(), block_size,
+                                     dtype=dtype)
         #: the slot arrays of a stateful model (``init_state_cache``: a slot
         #: a sequence, row 0 the trash slot), donated to and returned by the
         #: ragged program beside the pool
@@ -353,9 +415,10 @@ class InferenceEngineV2:
         #: device bytes of one block's K+V across all layers — the unit
         #: of every tier/swap byte counter and of the scheduler's
         #: swap-vs-recompute cost model
-        self.block_bytes = int(self.kv.nbytes) // num_blocks
+        self.block_bytes = int(self._full_pool().nbytes) // num_blocks
         log_dist(
-            f"InferenceEngineV2(paged): blocks={num_blocks}x{block_size} "
+            f"InferenceEngineV2(paged): blocks={self._pool_blocks()}"
+            f"x{block_size} "
             f"seqs<={max_seqs} ctx={self.max_seq_len} chunk={prefill_chunk} "
             f"token_budget={self.token_budget} "
             f"decode_horizon={self.decode_horizon} "
@@ -365,6 +428,17 @@ class InferenceEngineV2:
         )
         init.mark("pool")
         init.close()
+
+    def _pool_blocks(self):
+        """The block count ``init_kv_pool`` takes: one, or one a class."""
+        n = self.block_mgr.num_blocks
+        if not self._windowed:
+            return n
+        return {"full": n, "window": self.block_mgr.window.num_blocks}
+
+    def _full_pool(self):
+        """The full class's pool (the only one, for most models)."""
+        return self.kv["full"] if self._windowed else self.kv
 
     def _cast_params(self, params):
         def cast(path, a):
@@ -474,7 +548,9 @@ class InferenceEngineV2:
                 **({"seg_from": self.max_seqs} if segs else {}),
                 **({"moe_stats": True} if self._moe_stats and greedy else {}),
                 **({"state": slot_cache, "row_slots": f["row_slots"]}
-                   if self._stateful else {}))
+                   if self._stateful else {}),
+                **({"window": (f["wtables"], f["wbase"])}
+                   if self._windowed else {}))
             if self._stateful:
                 slot_cache, *stats = stats
                 pool = (pool, slot_cache)
@@ -504,7 +580,8 @@ class InferenceEngineV2:
     def _feed_layout(self, rows: int):
         """:func:`feed_layout` of this engine's ragged step of ``rows``."""
         return feed_layout(rows, self.max_seqs,
-                           self.block_mgr.max_blocks_per_seq, self._stateful)
+                           self.block_mgr.max_blocks_per_seq, self._stateful,
+                           self._window_spec[2] if self._windowed else 0)
 
     def _feed_rows(self, length: int) -> int:
         """The rows of the step whose packed feed is ``length`` words: the
@@ -734,8 +811,10 @@ class InferenceEngineV2:
         to plain flush-preemption + journal replay. The swap store is a
         cache, never a source of truth: re-admission works identically if
         the entry has vanished."""
-        if not self.host_tier_blocks or self._stateful:
-            return False    # a state slot is not swapped: recompute
+        if not self.host_tier_blocks or self._stateful or self._windowed:
+            # a state slot is not swapped, nor a class whose blocks were
+            # freed behind the window: recompute
+            return False
         d = self.state.seqs.get(uid)
         if d is None or not d.at_rest:
             return False
@@ -805,8 +884,10 @@ class InferenceEngineV2:
         prefill, no uncommitted speculation, holding blocks). A False here
         is a deferral signal, never an error — the disaggregated pool
         re-checks next step."""
-        if self._stateful:
-            return False    # a state slot is not exported
+        if self._stateful or self._windowed:
+            # a state slot is not exported, nor blocks that no longer hold
+            # every layer's tokens
+            return False
         if uid in self._swaps:
             return True
         d = self.state.seqs.get(uid)
@@ -927,10 +1008,11 @@ class InferenceEngineV2:
         INSIDE the scan (docs/SAMPLING.md) — all-zero rows select argmax,
         bit-identical to the legacy greedy program, and no second trace
         ever exists."""
-        if self._stateful:
+        if self._stateful or self._windowed:
             raise EngineUsageError(
                 "fused multi-token decode and speculative verification are "
-                "not wired for a model with state-slot layers")
+                "not wired for a model with state-slot layers or a bounded "
+                "class of KV blocks")
         if self._fused_fn is None:
             model = self.model
             K = self.decode_horizon
@@ -957,7 +1039,7 @@ class InferenceEngineV2:
         every draft position (rejection sampling's deterministic
         specialization, docs/SAMPLING.md); all-zero sampling rows select
         argmax, bit-identical to the legacy program."""
-        if self._stateful:
+        if self._stateful or self._windowed:
             return self._get_fused()    # raises: no rollback out of a state
         if self._verify_fn is None:
             model = self.model
@@ -1099,8 +1181,9 @@ class InferenceEngineV2:
             layout, length = self._feed_layout(rows)
             buf = np.zeros(length, np.int32)
             feed = self._scratch[key] = PackedFeed(buf=buf, **{
-                name: buf[words].view(dtype).reshape(shape)
-                for name, (words, shape, dtype) in layout.items()})
+                **_NO_WINDOW,
+                **{name: buf[words].view(dtype).reshape(shape)
+                   for name, (words, shape, dtype) in layout.items()}})
         else:
             feed.buf.fill(0)
         feed.src_rows.fill(-1)   # no row is fed on the device
@@ -1304,10 +1387,16 @@ class InferenceEngineV2:
         dispatch. ``live_write``: the step's KV write went row by row for
         its live rows (1), or through the scatter over all its padded rows
         (0)."""
-        mgr = self.block_mgr
-        marks = (mgr.allocations, mgr.stats["cow_copies"])
+        mgr, w = self.block_mgr, self.block_mgr.window
+        marks = (mgr.allocations + (w.allocations if w else 0),
+                 mgr.stats["cow_copies"], w.freed_behind if w else 0)
+        if w is not None:
+            used = {"full": mgr.num_blocks - 1 - mgr.free_blocks,
+                    "window": w.in_use}
+            self.block_peaks = {c: max(n, self.block_peaks[c])
+                                for c, n in used.items()}
         if disp.recording:
-            rows = ctx = by_row = decode = seg = 0
+            rows = ctx = by_row = decode = seg = dctx = dwin = 0
             for d, take in plan:
                 seen = d.seen_tokens if fused else d.seen_tokens - take
                 rows += take
@@ -1318,18 +1407,35 @@ class InferenceEngineV2:
                 # one token pending: a decode step (or a prompt's last token)
                 if fused or take == 1:
                     decode += take
+                    if w is not None:       # one token: its context, and
+                        dctx += seen + 1    # what a window layer sees of it
+                        dwin += min(seen + 1, w.bound)
             disp.set(padded_rows=padded_rows, rows=rows, decode_rows=decode,
                      prefill_tokens=rows - decode, seg_tokens=seg,
                      seqs=len(plan),
                      live_write=int(not fused
                                     and self._rows_apart(padded_rows)
                                     and paged_attention.writes_live_rows(
-                                        self.kv)),
+                                        self._full_pool())),
                      ctx_tokens=ctx, ctx_tokens_by_row=by_row,
                      blocks_allocated=max(0, marks[0] - self._count_marks[0]),
                      cow_copies=max(0, marks[1] - self._count_marks[1]))
             if mgr.slots is not None:
                 disp.set(state_slots=mgr.slots.in_use)
+            if w is not None:
+                # the two classes of blocks, as the step leaves them: held
+                # and free of each, the sequences that hold them (those
+                # outside this step too), and what the window class freed
+                # behind its sequences' windows since the step before
+                # (``blocks_allocated`` counts both classes); the one-token
+                # rows' contexts summed, whole and as a window layer sees
+                # them: what the decode kernel has to read in this step
+                disp.set(window_blocks=used["window"],
+                         window_free=w.free_blocks,
+                         full_blocks=used["full"], full_free=mgr.free_blocks,
+                         block_seqs=w.holders,
+                         freed_behind=marks[2] - self._count_marks[2],
+                         decode_ctx_tokens=dctx, decode_window_tokens=dwin)
         self._count_marks = marks
 
     def _build_ragged_step(self, work):
@@ -1409,7 +1515,8 @@ class InferenceEngineV2:
                     min((d.seen_tokens + take - 1) // bs, len(d.blocks) - 1))
         feed = self._feed_scratch(("ragged", T), T)
         (ids, tables, starts, logit_rows, slots, seeds, poss, temps, top_ks,
-         top_ps, _, row_slots, _) = feed
+         top_ps, _, row_slots, wtables, wbase, _) = feed
+        window = self.block_mgr.window
         finals = []
         r = singles = 0
         seg_next = self.max_seqs       # next free segment tile's first row
@@ -1431,6 +1538,10 @@ class InferenceEngineV2:
             if self._stateful:
                 row_slots[r0:r0 + take] = self.block_mgr.slots.begin(
                     d.uid, d.seen_tokens)
+            if window is not None:
+                wbase[r0:r0 + take] = window.fill_row(d.uid, wtables[r0])
+                if take > 1:
+                    wtables[r0 + 1:r0 + take] = wtables[r0]
             for j in range(take):
                 ids[r, 0] = d.pending[j]
                 starts[r] = d.seen_tokens + j
@@ -1448,6 +1559,10 @@ class InferenceEngineV2:
                 d.history.extend(d.pending[:take])
             del d.pending[:take]
             d.seen_tokens += take
+            if window is not None:
+                # the step's tables are filled: what lies behind the window
+                # of the sequence's next query goes back to the class
+                window.trim(d.uid, d.seen_tokens - d.uncommitted)
         return T, plan, finals, feed
 
     # ------------------------------------------------------------------
@@ -1833,7 +1948,9 @@ class InferenceEngineV2:
                                if self._unfetched else 0)
                 feed = self._feed_scratch(("dispatch", T, scratch_set), T)
                 (ids, tables, starts, logit_rows, slots, seeds, poss, temps,
-                 top_ks, top_ps, src_rows, row_slots, _) = feed
+                 top_ks, top_ps, src_rows, row_slots, wtables, wbase,
+                 _) = feed
+                window = self.block_mgr.window
                 for r, d in enumerate(descs):
                     tok = tokens[d.uid]
                     if tok is None:
@@ -1846,6 +1963,11 @@ class InferenceEngineV2:
                     if self._stateful:
                         row_slots[r] = self.block_mgr.slots.begin(
                             d.uid, d.seen_tokens)
+                    if window is not None:
+                        wbase[r] = window.fill_row(d.uid, wtables[r])
+                        # behind the window of the oldest query the round
+                        # may still be rolled back to (``commit_step``)
+                        window.trim(d.uid, d.seen_tokens - d.uncommitted)
                     logit_rows[r] = r  # every row is a final: one token per uid
                     self._fill_sampling(d, r, slots, seeds, temps, top_ks, top_ps,
                                         poss=poss, pos=d.seen_tokens + 1)
@@ -1996,7 +2118,10 @@ class InferenceEngineV2:
 
     def _blocks_held(self, uid: int) -> int:
         desc = self.state.seqs.get(uid)
-        return len(desc.blocks) if desc is not None else 0
+        if desc is None:
+            return 0
+        window = self.block_mgr.window
+        return len(desc.blocks) + (window.blocks_of(uid) if window else 0)
 
     def rebuild(self) -> None:
         """Hot rebuild after engine loss (docs/RESILIENCE.md): replace every
@@ -2042,20 +2167,22 @@ class InferenceEngineV2:
                 prefix_cache=self.prefix_cache,
                 host_tier_blocks=self.host_tier_blocks,
                 state_slots=self.max_seqs if self._stateful else 0,
+                window=self._window_spec,
                 descs=lambda: self.state.seqs.values())
         else:
             self.block_mgr = BlockedKVCache(
                 old.num_blocks, old.block_size, old.max_blocks_per_seq,
                 prefix_cache=self.prefix_cache,
                 host_tier_blocks=self.host_tier_blocks,
-                state_slots=self.max_seqs if self._stateful else 0)
+                state_slots=self.max_seqs if self._stateful else 0,
+                window=self._window_spec)
         if self.nvme_tier_blocks:
             for hid in list(getattr(old, "_nvme", ())):
                 self._drop_block(hid)
         self.block_mgr.demote_fn = self._demote_block
         self._bind_nvme_tier()
         init.mark("state")
-        self.kv = self.model.init_kv_pool(old.num_blocks, old.block_size,
+        self.kv = self.model.init_kv_pool(self._pool_blocks(), old.block_size,
                                           dtype=self.dtype)
         if self._stateful:
             self.slot_cache = self.model.init_state_cache(
@@ -2132,4 +2259,8 @@ class InferenceEngineV2:
         # engine_v2.py:184 query / can_schedule:184)
         per_seq = self.block_mgr.blocks_needed(
             min(self.prefill_chunk, self.max_seq_len))
+        # every class of blocks has to hold the chunk: the scarcer decides
+        window = self.block_mgr.window
+        if window is not None and window.free_blocks < n_new * per_seq:
+            return False
         return self.block_mgr.free_blocks >= n_new * per_seq

@@ -110,7 +110,20 @@ class BlockedKVCache:
 
     def __init__(self, num_blocks: int, block_size: int, max_blocks_per_seq: int,
                  prefix_cache: bool = False, host_tier_blocks: int = 0,
-                 nvme_blocks: int = 0, state_slots: int = 0):
+                 nvme_blocks: int = 0, state_slots: int = 0,
+                 window: Optional[Tuple[int, int, int]] = None):
+        if window and prefix_cache:
+            raise ValueError(
+                "prefix_cache with a bounded class of blocks: a block freed "
+                "behind the window cannot be shared, and a prefix hit would "
+                "hand out the full class's blocks without the window "
+                "class's; serve such a model with prefix_cache=False")
+        #: the bounded class of blocks (:class:`WindowBlocks`: ``window`` =
+        #: (blocks, bound in tokens, table width)), None for a model whose
+        #: every layer sees its whole context. This object's own blocks are
+        #: then the ``full`` class
+        self.window: Optional[WindowBlocks] = \
+            WindowBlocks(*window, block_size) if window else None
         if state_slots and prefix_cache:
             raise ValueError(
                 "prefix_cache with state slots: a prefix hit hands out KV "
@@ -597,7 +610,11 @@ class BlockedKVCache:
     # allocation surface (pre-existing)
     # ------------------------------------------------------------------
     def ensure(self, desc: SequenceDescriptor, n_tokens: int):
-        """Grow ``desc.blocks`` to cover ``n_tokens`` logical positions."""
+        """Grow ``desc.blocks`` to cover ``n_tokens`` logical positions, in
+        every class of blocks (what one class has grown is kept if another
+        is exhausted: the retried step uses it)."""
+        if self.window is not None:
+            self.window.ensure(desc.uid, n_tokens)
         need = self.blocks_needed(n_tokens)
         if need > self.max_blocks_per_seq:
             # per-sequence context wall, same family as the engine's
@@ -632,7 +649,8 @@ class BlockedKVCache:
         if it was the last), it is never force-freed. Returns the number of
         references released."""
         keep = self.blocks_needed(n_tokens)
-        freed = 0
+        freed = self.window.rollback(desc.uid, n_tokens) \
+            if self.window is not None else 0
         while len(desc.blocks) > keep:
             self._decref(desc.blocks.pop())
             freed += 1
@@ -642,6 +660,8 @@ class BlockedKVCache:
     def free(self, desc: SequenceDescriptor):
         if self.slots is not None:
             self.slots.free(desc.uid)
+        if self.window is not None:
+            self.window.free(desc.uid)
         for b in desc.blocks:
             self._decref(b)
         desc.blocks = []
@@ -855,6 +875,8 @@ class BlockedKVCache:
         descs = list(descs)
         if self.slots is not None:
             self.slots.check_invariants(d.uid for d in descs)
+        if self.window is not None:
+            self.window.check_invariants(d.uid for d in descs)
         if descs:
             counted: Dict[int, int] = {}
             for d in descs:
@@ -862,6 +884,125 @@ class BlockedKVCache:
                     counted[b] = counted.get(b, 0) + 1
             assert counted == self._ref, (
                 f"refcounts {self._ref} != descriptor holdings {counted}")
+
+
+class WindowBlocks:
+    """The **window class** of KV blocks: the blocks of the layers whose
+    query sees only the last ``bound`` tokens (``TransformerConfig.
+    bounded_cache``), a pool of their own with its own count, free list and
+    table a sequence. A sequence holds the blocks of the logical range
+    ``[first, first + len(held))``: :meth:`ensure` grows it at the end as the
+    full class grows, :meth:`trim` frees, after every step, the blocks whose
+    last token lies before ``position - bound + 1`` of the sequence's next
+    query, so it holds about ``bound / block_size`` of them however long it
+    is. Its table row is ``width`` entries from ``first`` on
+    (:meth:`fill_row`), and the programs count positions from that block
+    (``models/transformer.py window_frame``). Block 0 is this pool's trash
+    block. A freed block holds nothing anyone may read again: no prefix
+    sharing, no copy-on-write, no swap (the engine refuses them)."""
+
+    def __init__(self, num_blocks: int, bound: int, width: int,
+                 block_size: int):
+        self.num_blocks, self.bound, self.width = num_blocks, bound, width
+        self.block_size = block_size
+        self._free: List[int] = list(range(1, num_blocks))[::-1]
+        self._held: Dict[int, List[int]] = {}    # uid -> blocks, oldest first
+        self._first: Dict[int, int] = {}         # uid -> logical index of [0]
+        #: blocks handed out and blocks freed behind a window so far, ever
+        #: (``engine.dispatch`` reports the difference between two steps)
+        self.allocations = 0
+        self.freed_behind = 0
+
+    @property
+    def free_blocks(self) -> int:
+        return len(self._free)
+
+    @property
+    def in_use(self) -> int:
+        return self.num_blocks - 1 - len(self._free)
+
+    def blocks_of(self, uid: int) -> int:
+        return len(self._held.get(uid, ()))
+
+    @property
+    def holders(self) -> int:
+        """Sequences that hold blocks of the class (in a step or not)."""
+        return sum(1 for held in self._held.values() if held)
+
+    def ensure(self, uid: int, n_tokens: int) -> None:
+        """Grow ``uid``'s range to cover ``n_tokens`` logical positions."""
+        held = self._held.setdefault(uid, [])
+        first = self._first.setdefault(uid, 0)
+        need = -(-n_tokens // self.block_size) - first
+        if need > self.width:
+            raise ContextOverflowError(
+                f"uid {uid}: {need} window-class blocks > the table's "
+                f"{self.width} (a step of more tokens than the window's "
+                "table was sized for)", uid=uid)
+        while len(held) < need:
+            if not self._free:
+                raise PoolExhaustedError(
+                    f"window-class block pool exhausted (uid {uid}; "
+                    f"{self.num_blocks - 1} usable blocks)", uid=uid)
+            held.append(self._free.pop())
+            self.allocations += 1
+
+    def trim(self, uid: int, position: int) -> int:
+        """Free ``uid``'s blocks that lie wholly before the window of a
+        query at ``position`` (its next, or an earlier one it may still be
+        rolled back to); returns how many."""
+        held = self._held.get(uid)
+        if not held:
+            return 0
+        keep_from = max(0, position - self.bound + 1) // self.block_size
+        n = min(max(0, keep_from - self._first[uid]), len(held))
+        self._free.extend(held[:n])
+        del held[:n]
+        self._first[uid] += n
+        self.freed_behind += n
+        return n
+
+    def rollback(self, uid: int, n_tokens: int) -> int:
+        """Release ``uid``'s trailing blocks past ``n_tokens`` positions."""
+        held = self._held.get(uid, [])
+        keep = max(0, -(-n_tokens // self.block_size) - self._first.get(uid, 0))
+        freed = 0
+        while len(held) > keep:
+            self._free.append(held.pop())
+            freed += 1
+        return freed
+
+    def fill_row(self, uid: int, out: np.ndarray) -> int:
+        """Write ``uid``'s table row into ``out`` (``width`` entries, the
+        trailing ones 0); returns the position its first entry starts at."""
+        held = self._held[uid]
+        out[:len(held)] = held
+        out[len(held):] = 0
+        return self._first[uid] * self.block_size
+
+    def free(self, uid: int) -> None:
+        """Release every block of ``uid`` (a no-op for one that holds none)."""
+        self._free.extend(self._held.pop(uid, ()))
+        self._first.pop(uid, None)
+
+    def check_invariants(self, uids: Iterable[int] = ()) -> None:
+        free = set(self._free)
+        held = [b for blocks in self._held.values() for b in blocks]
+        assert len(free) == len(self._free), \
+            "duplicate window-class block in the free list"
+        assert len(set(held)) == len(held), \
+            "window-class block held twice"
+        assert not (free & set(held)), "window-class block both free and held"
+        assert free | set(held) == set(range(1, self.num_blocks)), \
+            "phantom or lost window-class block"
+        assert set(self._held) == set(self._first)
+        assert all(len(b) <= self.width for b in self._held.values()), \
+            "a sequence holds more window-class blocks than its table has"
+        uids = set(uids)
+        if uids:
+            assert set(self._held) <= uids, (
+                f"window-class blocks held by {sorted(self._held)}, live "
+                f"sequences {sorted(uids)}")
 
 
 class StateSlots:

@@ -25,6 +25,7 @@ Architecture choices driven by XLA/TPU:
   iota comparison (no materialised (S,S) bool tensor at peak memory).
 """
 
+import contextlib
 import math
 import re
 from dataclasses import dataclass, field, replace
@@ -45,7 +46,9 @@ from ..utils.logging import logger
 
 
 #: the mixers a ``layer_types`` model may name
-LAYER_TYPES = ("sparse_attn", "linear_attn")
+LAYER_TYPES = ("sparse_attn", "linear_attn", "window_attn", "full_attn")
+#: those that keep KV blocks in the paged pool
+POOL_TYPES = ("sparse_attn", "window_attn", "full_attn")
 
 
 @dataclass(frozen=True)
@@ -153,10 +156,21 @@ class TransformerConfig:
     # linear_attention.py). A run of equal types is one stacked group
     # ``params["blocks_<i>"]``, scanned. Both mixers take ``qk_norm`` (RMSNorm
     # of each q and k head) and ``attn_output_gate`` (``sigmoid(W h)`` on the
-    # attention's output before ``wo``). Serving only
+    # attention's output before ``wo``). "window_attn" | "full_attn" (afmoe:
+    # Trinity): GQA over the paged pool through the decode kernel, a window
+    # layer with rotary and a query at ``i`` seeing the keys ``0 <= i - j <
+    # sliding_window``, a full layer with no positional term and ``j <= i``;
+    # the window layers' blocks are a class of their own, freed behind the
+    # window (``bounded_cache``). ``post_norms``: an RMSNorm on each
+    # sublayer's output before it joins the residual stream (four norms a
+    # layer). The feed-forward of a layer_types model is dense, or (
+    # ``num_experts`` > 0) the first ``num_dense_layers`` layers' is dense at
+    # ``dense_intermediate_size`` and the others hold experts. Serving only
     layer_types: Optional[Tuple[str, ...]] = None
     qk_norm: bool = False
     attn_output_gate: bool = False
+    sliding_window: int = 0
+    post_norms: bool = False
     sparse_kernel_size: int = 32
     sparse_kernel_stride: int = 16
     sparse_window: int = 2048       # tokens; whole blocks ending at the query's
@@ -167,7 +181,8 @@ class TransformerConfig:
     # published ``sparse_config.block_size``), and so the only pool block it
     # can be served from (``init_kv_pool`` refuses another)
     sparse_block_size: int = 64
-    linear_chunk: int = 128         # rows of a prefill tile of a linear layer
+    # rows of a prefill tile of a layer_types model (a linear layer's chunk)
+    linear_chunk: int = 128
     # muP (MiniCPM): the residual branches times ``scale_depth /
     # sqrt(scale_depth_layers or num_layers)`` (the published depth, where
     # the model is a slice of it), the head's input times ``dim_model_base /
@@ -221,6 +236,8 @@ class TransformerConfig:
                 raise ValueError(
                     f"layer_types {types}: one of {LAYER_TYPES} for each of "
                     f"the {self.num_layers} layers")
+            if "window_attn" in types and self.sliding_window <= 0:
+                raise ValueError("a window_attn layer needs sliding_window")
 
     @property
     def kv_heads(self) -> int:
@@ -238,13 +255,17 @@ class TransformerConfig:
         double layer with a pool layer for each of its attentions, or a
         ``layer_types`` mixer, a run of equal types one group."""
         if self.layer_types is not None:
-            runs = []
-            for t in self.layer_types:
-                if runs and runs[-1][1] == t:
+            # a run is of one type and one feed-forward (the leading dense
+            # layers of a model with experts are runs of their own)
+            runs, last = [], None
+            for i, t in enumerate(self.layer_types):
+                kind = (t, self.layer_is_dense(i))
+                if kind == last:
                     runs[-1][2] += 1
                 else:
                     runs.append([f"blocks_{len(runs)}", t, 1,
-                                 int(t == "sparse_attn")])
+                                 int(t in POOL_TYPES)])
+                last = kind
             return tuple(tuple(r) for r in runs)
         if not self.is_mla:
             return (("blocks", "full", self.num_layers, 1),)
@@ -257,6 +278,38 @@ class TransformerConfig:
 
     def layers_of(self, layer_type: str) -> int:
         return sum(t == layer_type for t in self.layer_types or ())
+
+    def layer_is_dense(self, i: int) -> bool:
+        """Is layer ``i``'s feed-forward dense (no experts)?"""
+        return self.num_experts == 0 or i < self.num_dense_layers
+
+    @property
+    def group_is_dense(self) -> Tuple[bool, ...]:
+        """:meth:`layer_is_dense` of each group of ``type_runs``."""
+        out, at = [], 0
+        for _, _, n, _ in self.type_runs:
+            out.append(self.layer_is_dense(at))
+            at += n
+        return tuple(out)
+
+    @property
+    def bounded_cache(self) -> bool:
+        """The ``window_attn`` layers' KV blocks are a class of their own,
+        bounded by ``sliding_window``: a query sees the last W tokens, so the
+        blocks behind every later query's window are freed and a sequence
+        holds about ``W / block_size`` of them whatever its length. A block
+        then no longer holds a token in every layer."""
+        return bool(self.layers_of("window_attn"))
+
+    @property
+    def class_layers(self) -> Dict[str, int]:
+        """Pool layers of each class of KV blocks, the model's declaration to
+        the cache manager: ``full`` (a block for every ``block_size`` tokens
+        of a sequence's length) and, where ``bounded_cache``, ``window``. A
+        class is a pool of its own (its layers, its blocks), a free list and
+        a table a sequence."""
+        w = self.layers_of("window_attn")
+        return {"full": self.pool_layers - w, **({"window": w} if w else {})}
 
     @property
     def residual_scale(self) -> float:
@@ -283,23 +336,28 @@ class TransformerConfig:
         (bytes a token a layer, in the paged pool at 2 bytes a value) or
         ``state_slot`` (bytes a sequence a layer, whatever its length: a
         lightning layer's float32 state; a sparse layer's compressed keys for
-        ``max_seq_len`` tokens, which lie by slot beside its KV blocks)."""
+        ``max_seq_len`` tokens, which lie by slot beside its KV blocks). A
+        window layer's ``kv_blocks`` carry a third entry, the bound: the
+        tokens behind which a block is freed (``bounded_cache``)."""
         kv = ("kv_blocks", 2 * self.pool_heads * sum(self.kv_row))
         kinds = dict.fromkeys(kind for _, kind, _, _ in self.type_runs)
         if not set(kinds) & set(LAYER_TYPES):
             return {"attn": (kv,)}
         nh, hd = self.num_heads, self.head_dim
-        keys = self.sparse_spec.max_keys(self.max_seq_len)
-        kept = {"sparse_attn": (kv, ("state_slot",
-                                     2 * keys * self.kv_heads * hd)),
-                "linear_attn": (("state_slot", 4 * nh * hd * hd),)}
+        kept = {"linear_attn": (("state_slot", 4 * nh * hd * hd),),
+                "window_attn": (kv + (self.sliding_window,),),
+                "full_attn": (kv,)}
+        if "sparse_attn" in kinds:      # its sizes are checked only if used
+            keys = self.sparse_spec.max_keys(self.max_seq_len)
+            kept["sparse_attn"] = (kv, ("state_slot",
+                                        2 * keys * self.kv_heads * hd))
         return {t: kept[t] for t in kinds}
 
     @property
     def holds_state(self) -> bool:
         """Some layer keeps a state slot a sequence beside the paged pool."""
         return any(kind == "state_slot" for kept in self.cache_kinds.values()
-                   for kind, _ in kept)
+                   for kind, *_ in kept)
 
     @property
     def head_dim(self) -> int:
@@ -399,9 +457,17 @@ class TransformerConfig:
         H, qd = self.hidden_size, self.num_heads * self.head_dim
         extra = (2 * self.head_dim if self.qk_norm else 0) \
             + (H * qd if self.attn_output_gate else 0)
-        if layer_type == "sparse_attn":
+        if layer_type in POOL_TYPES:
             return self._attn_params + extra
         return 4 * H * qd + qd + extra     # q, k, v, o at full width; out norm
+
+    @property
+    def _held_moe_params(self) -> int:
+        """An expert layer's feed-forward: the experts held here, the router
+        with its selection bias, the shared expert."""
+        return (self._mlp_params(self.mlp_dim) * self.num_experts
+                + (self.hidden_size + 1) * self.router_width
+                + self._mlp_params(self.moe_shared_size))
 
     @property
     def num_parameters(self) -> int:
@@ -410,8 +476,13 @@ class TransformerConfig:
         width."""
         H, L, V = self.hidden_size, self.num_layers, self.vocab_size
         if self.layer_types is not None:
-            layers = sum(self._mixer_params(t) for t in self.layer_types) \
-                + L * (2 * H + self._mlp_params(self.mlp_dim))
+            dense = self._mlp_params(self.dense_mlp_dim if self.num_experts
+                                     else self.mlp_dim)
+            layers = sum(
+                self._mixer_params(t) + (dense if self.layer_is_dense(i)
+                                         else self._held_moe_params)
+                for i, t in enumerate(self.layer_types)) \
+                + L * (4 if self.post_norms else 2) * H
             return layers + V * H + (0 if self.tie_embeddings else V * H) + H
         n_ln = 1 if (self.parallel_block and self.parallel_shared_ln) else 2
         norms = n_ln * (1 if self.norm == "rmsnorm" else 2) * H
@@ -461,7 +532,11 @@ class TransformerConfig:
             nh, hd = self.num_heads, self.head_dim
             ctx = S if S <= self.sparse_dense_len else min(
                 S, self.sparse_topk * self.sparse_block_size)
-            return (6 * n + 6 * self.layers_of("sparse_attn") * 2 * nh * hd * ctx
+            seen = (self.layers_of("sparse_attn") * ctx
+                    + self.layers_of("full_attn") * S
+                    + self.layers_of("window_attn")
+                    * min(S, self.sliding_window or S))
+            return (6 * n + 6 * 2 * nh * hd * seen
                     + 6 * self.layers_of("linear_attn") * 2 * nh * hd * hd)
         if self.is_mla:
             per_pos = self.num_heads * (self.qk_nope_head_dim
@@ -646,6 +721,9 @@ class PagedStep:
     live: Any          # (T,) bool: a real token (routed, counted, written)
     slots: Any         # (T,) each row's state slot, or None
     tile_counts: Any   # valid rows of each tile, or None (no tile)
+    #: the rows as the window class of blocks sees them (``window_frame``),
+    #: or None: a model with no bounded class
+    window: Any
     cut: int = field(metadata=dict(static=True))
     end: int = field(metadata=dict(static=True))
     tile: int = field(metadata=dict(static=True))
@@ -657,7 +735,9 @@ class PagedStep:
 
     @classmethod
     def of(cls, tables, starts, width=1, *, seg_from=None, tile=1,
-           rows_apart=False, slots=None):
+           rows_apart=False, slots=None, window=None):
+        """``window``: (tables (T, WB), base (T,), W) of the window class, or
+        None (:func:`window_frame`)."""
         T = tables.shape[0]
         cut = T if seg_from is None else seg_from
         end = cut + (T - cut) // tile * tile
@@ -667,8 +747,26 @@ class PagedStep:
             positions = positions + jnp.arange(width, dtype=jnp.int32)
         tile_counts = jnp.sum(live[cut:end].reshape(-1, tile), axis=1,
                               dtype=jnp.int32) if cut < end else None
-        return cls(tables, starts, positions, paged_limits(tables, starts),
-                   live, slots, tile_counts, cut, end, tile, rows_apart)
+        limits = paged_limits(tables, starts)
+        if window is not None:
+            window = window_frame(*window, starts, limits)
+        return cls(tables, starts, positions, limits, live, slots,
+                   tile_counts, window, cut, end, tile, rows_apart)
+
+
+def window_frame(tables, base, bound, starts, limits):
+    """A step's rows as the window class of blocks sees them. A sequence
+    holds the blocks its later queries can still see, so its window table
+    (T, WB) starts at the block of position ``base`` (T,), and everything
+    that counts tokens along a table counts from there: (tables, starts,
+    limits, first), each row's position, the tokens it attends up to and the
+    first it attends from (its query sees the last ``bound`` tokens), all
+    less ``base``. A padding row's are 0."""
+    rel = starts - base
+    first = jnp.maximum(starts - (bound - 1), 0) - base
+    live = limits > 0
+    return (tables, jnp.where(live, rel, 0), jnp.where(live, rel + 1, 0),
+            jnp.where(live, jnp.maximum(first, 0), 0))
 
 
 def sublayer_prefix(i: int) -> str:
@@ -723,10 +821,11 @@ class TransformerLM:
     def __init__(self, config: TransformerConfig, mesh_axes: Tuple[str, str] = ("model", "seq")):
         self.config = config
         self.model_axis, self.seq_axis = mesh_axes
-        if config.holds_experts and not config.is_mla:
+        if config.holds_experts and not (config.is_mla
+                                         or config.layer_types is not None):
             raise ValueError(f"moe_router='{config.moe_router}' (held "
                              "experts) is wired into attention='mla' blocks "
-                             "only")
+                             "and layer_types models only")
         if config.layer_kind == "scmoe" and not (
                 config.holds_experts and config.num_dense_layers == 0):
             raise ValueError("layer_kind='scmoe' is a double layer of latent "
@@ -834,21 +933,38 @@ class TransformerLM:
         """As :meth:`_mla_shapes`, of a ``layer_types`` model: one group a
         run of equal types (``TransformerConfig.type_runs``)."""
         cfg = self.config
-        H, V, I = cfg.hidden_size, cfg.vocab_size, cfg.mlp_dim
+        H, V, I, E = cfg.hidden_size, cfg.vocab_size, cfg.mlp_dim, \
+            cfg.num_experts
         qd, kvd, hd = cfg.num_heads * cfg.head_dim, \
             cfg.kv_heads * cfg.head_dim, cfg.head_dim
         shared = {"ln1_scale": (H,), "wq": (H, qd), "wo": (qd, H),
-                  "ln2_scale": (H,), "w_gate": (H, I), "w_up": (H, I),
-                  "w_down": (I, H)}
+                  "ln2_scale": (H,)}
+        extra = {}
         if cfg.qk_norm:
-            shared.update(q_norm_scale=(hd,), k_norm_scale=(hd,))
+            extra.update(q_norm_scale=(hd,), k_norm_scale=(hd,))
         if cfg.attn_output_gate:
-            shared["w_ogate"] = (H, qd)
-        mixer = {"sparse_attn": {"wk": (H, kvd), "wv": (H, kvd)},
+            extra["w_ogate"] = (H, qd)
+        if cfg.post_norms:
+            extra.update(post_attn_scale=(H,), post_mlp_scale=(H,))
+
+        def mlp(width, prefix=""):
+            return {prefix + "w_gate": (H, width), prefix + "w_up": (H, width),
+                    prefix + "w_down": (width, H)}
+
+        dense = mlp(cfg.dense_mlp_dim if E else I)
+        moe = {"moe_wg": (H, cfg.router_width),
+               "moe_bias": (cfg.router_width,), "wi": (E, H, I),
+               "w_gate": (E, H, I), "w_down": (E, I, H),
+               **(mlp(cfg.moe_shared_size, "shared_")
+                  if cfg.moe_shared_size else {})}
+        gqa = {"wk": (H, kvd), "wv": (H, kvd)}
+        mixer = {"sparse_attn": gqa, "window_attn": gqa, "full_attn": gqa,
                  "linear_attn": {"wk": (H, qd), "wv": (H, qd),
                                  "o_norm_scale": (qd,)}}
-        groups = {key: (n, {**shared, **mixer[kind]})
-                  for key, kind, n, _ in cfg.type_runs}
+        groups = {key: (n, {**shared, **(dense if is_dense else moe), **extra,
+                            **mixer[kind]})
+                  for (key, kind, n, _), is_dense
+                  in zip(cfg.type_runs, cfg.group_is_dense)}
         top = {"wte": (V, H), "lnf_scale": (H,)}
         if not cfg.tie_embeddings:
             top["lm_head"] = (H, V)
@@ -859,9 +975,11 @@ class TransformerLM:
         group a run of equal ``layer_types``."""
         cfg = self.config
         if (cfg.activation != "swiglu" or cfg.norm != "rmsnorm"
-                or cfg.pos_embedding != "rope" or cfg.num_experts):
+                or cfg.pos_embedding != "rope"
+                or (cfg.num_experts and not cfg.holds_experts)):
             raise ValueError("layer_types models are rmsnorm + swiglu + rope, "
-                             "with a dense feed-forward")
+                             "with a dense feed-forward or held experts "
+                             "(moe_router 'group_limited' | 'softmax_topk')")
         dt = cfg.param_dtype
         groups, top = self._typed_shapes()
         init = jax.nn.initializers.normal(0.02)
@@ -872,8 +990,10 @@ class TransformerLM:
         def leaf(name, shape):
             if name.endswith("_scale"):
                 return jnp.ones(shape, dt)
-            return (resid_init if name in ("wo", "w_down") else init)(
-                next(keys), shape, dt)
+            if name == "moe_bias":
+                return jnp.zeros(shape, dt)
+            return (resid_init if name in ("wo", "w_down", "shared_w_down")
+                    else init)(next(keys), shape, dt)
 
         params = {k: leaf(k, shape) for k, shape in top.items()}
         for group, (n, leaves) in groups.items():
@@ -1333,23 +1453,17 @@ class TransformerLM:
         lies, a tile of the ``step`` (:class:`PagedStep`) streaming its
         sequence's latent once. ``experts``: (stacked expert leaves, layer of
         the group) when the caller kept them out of ``blk``."""
-        from ..moe.layer import _gated_mlp
-
         if self.config.layer_kind == "scmoe":
             return self._block_scmoe(x, blk, positions=positions, paged=paged,
                                      step=step, experts=experts)
         blk = _dequant_woq(blk, x.dtype)
         attn_out, new_pool = self._mla_attention(
             x, blk, positions=positions, paged=paged, step=step)
-        stats = None
         with jax.named_scope("mlp"):
             x = jax.lax.optimization_barrier(x + attn_out)
             h2 = _norm(x, blk["ln2_scale"], None, "rmsnorm",
                        self.config.norm_eps)
-            if "moe_wg" in blk:
-                mlp_out, stats = self._held_experts(h2, blk, experts, step)
-            else:
-                mlp_out = _gated_mlp(h2, blk["w_gate"], blk["w_up"], blk["w_down"])
+            mlp_out, stats = self._feed_forward(h2, blk, experts, step)
             mlp_out = self._constraint(mlp_out, self._act_spec(paged is None))
         return x + mlp_out, new_pool, stats
 
@@ -1390,6 +1504,18 @@ class TransformerLM:
         with jax.named_scope("mlp"):
             y = x + self._constraint(m, self._act_spec(paged is None))
         return y, pool, stats
+
+    def _feed_forward(self, h, blk, experts, step):
+        """The feed-forward of a layer on its normed (B, S, H) ``h``, by
+        what ``blk`` holds: the held experts where it has a router
+        (:meth:`_held_experts`), the gated dense one otherwise. (its output,
+        the expert layer's counts or None): the one call of the latent and of
+        the ``layer_types`` layers."""
+        from ..moe.layer import _gated_mlp
+
+        if "moe_wg" in blk:
+            return self._held_experts(h, blk, experts, step)
+        return _gated_mlp(h, blk["w_gate"], blk["w_up"], blk["w_down"]), None
 
     def _held_experts(self, h, blk, experts, step):
         """The expert layer of ``blk`` on the normed (B, S, H) ``h``: (its
@@ -1917,7 +2043,10 @@ class TransformerLM:
         ``[c_kv | k_rope]`` with one pool head (layout and access:
         ``ops/transformer/paged_attention.py``; the widths and the layer
         count: ``TransformerConfig.kv_row``, ``pool_layers``); block 0 is the
-        reserved trash block that masked/padded writes land in."""
+        reserved trash block that masked/padded writes land in. A model with
+        a bounded class of blocks (``class_layers``) takes ``num_blocks``
+        as {class: count} and gives {class: such an array}, block 0 of each
+        its trash block."""
         from ..ops.transformer.paged_attention import init_pool
 
         cfg = self.config
@@ -1927,6 +2056,18 @@ class TransformerLM:
                 f"a pool block of {block_size} tokens is not the block the "
                 f"model's sparse layers select by ({cfg.sparse_block_size}: "
                 "sparse_block_size, the published sparse_config.block_size)")
+        if cfg.bounded_cache:
+            # a pool a class of blocks: the classes differ in layers and in
+            # blocks
+            classes = cfg.class_layers
+            if not isinstance(num_blocks, dict) \
+                    or set(num_blocks) != set(classes):
+                raise ValueError(
+                    f"num_blocks {num_blocks!r}: a model with window layers "
+                    f"takes a count for each class of {sorted(classes)}")
+            return {c: init_pool(layers, cfg.pool_heads, num_blocks[c],
+                                 block_size, cfg.kv_row, dtype)
+                    for c, layers in classes.items()}
         return init_pool(cfg.pool_layers, cfg.pool_heads, num_blocks, block_size,
                          cfg.kv_row, dtype)
 
@@ -1945,9 +2086,8 @@ class TransformerLM:
         """Names of the int32 counts ``forward_paged(moe_stats=True)`` returns
         behind its logits: they become attrs of ``engine.dispatch``."""
         cfg = self.config
-        if cfg.layer_types is not None:
-            return ("sel_blocks", "ctx_blocks") \
-                if "sparse_attn" in cfg.layer_types else ()
+        if cfg.layer_types is not None and "sparse_attn" in cfg.layer_types:
+            return ("sel_blocks", "ctx_blocks")
         if not cfg.holds_experts:
             return ()
         return ("moe_rows", "moe_rows_max") + (
@@ -1978,7 +2118,8 @@ class TransformerLM:
 
     def forward_paged(self, params, input_ids, kv_pool, tables, starts,
                       logit_rows=None, seg_from=None, moe_stats=False,
-                      rows_apart=False, state=None, row_slots=None):
+                      rows_apart=False, state=None, row_slots=None,
+                      window=None):
         """Run T rows against the blocked pool: the one paged forward of
         every served architecture. Embed; then, for each layer group of the
         model (``TransformerConfig.type_runs``), one scan of the group's
@@ -1999,7 +2140,12 @@ class TransformerLM:
         from. ``state``, ``row_slots`` (``layer_types`` models): the slot
         arrays of :meth:`init_state_cache` and each row's slot in them ((T,)
         int32, 0 for a padding row); the new ``state`` is returned behind the
-        pool. ``moe_stats``: also return, last, the int32 counts
+        pool. ``window`` (a model with a bounded class of blocks,
+        ``TransformerConfig.bounded_cache``): (tables (T, WB), base (T,)),
+        each row's table of the window class and the position its first
+        entry starts at; ``kv_pool`` is then {class: pool} and so is the
+        result, ``tables`` the full class's. ``moe_stats``: also return,
+        last, the int32 counts
         :attr:`step_counts` names, where it names any: of held experts
         (rows, rows_max): the (token, choice) pairs that landed on held
         experts summed over the layers, and the busiest held expert's; with
@@ -2020,19 +2166,33 @@ class TransformerLM:
         if cfg.holds_state and (state is None or row_slots is None):
             raise ValueError("a layer_types model's paged path needs its "
                              "slot arrays (init_state_cache) and row_slots")
+        if cfg.bounded_cache and window is None:
+            raise ValueError("a model with window layers needs its window "
+                             "class's tables and base (window=)")
         step = PagedStep.of(tables, starts, S, seg_from=seg_from,
                             tile=self.segment_tile, rows_apart=rows_apart,
-                            slots=row_slots)
+                            slots=row_slots,
+                            window=None if window is None
+                            else (*window, cfg.sliding_window))
+        # a pool a class of blocks; one class, one array
+        pools = dict(kv_pool) if cfg.bounded_cache else {"full": kv_pool}
         with jax.named_scope("embed"):
-            x = self._embed(params, input_ids, step.positions, kv_pool.dtype)
+            x = self._embed(params, input_ids, step.positions,
+                            next(iter(pools.values())).dtype)
         kinds = {"full": self._full_layer, "latent": self._latent_layer,
                  "scmoe": self._latent_layer,
                  "sparse_attn": partial(self._typed_layer, self._sparse_mixer),
-                 "linear_attn": partial(self._typed_layer, self._linear_mixer)}
+                 "linear_attn": partial(self._typed_layer, self._linear_mixer),
+                 "window_attn": partial(
+                     self._typed_layer, partial(self._attn_mixer, True),
+                     scope="window_attn"),
+                 "full_attn": partial(
+                     self._typed_layer, partial(self._attn_mixer, False),
+                     scope="full_attn")}
         state = dict(state or {})
         names = self.step_counts
         tally = jnp.zeros((len(names),), jnp.int32) if names else None
-        pool_layer = 0                        # pool layers before this group
+        pool_layer = dict.fromkeys(pools, 0)  # pool layers before this group
         # a group's scan carries the pool if its layers keep KV blocks, its
         # own slot array if they keep a state slot, and nothing else: an
         # array carried through a scan that does not touch it, or sliced by
@@ -2056,11 +2216,12 @@ class TransformerLM:
                     leaves = {k: v for k, v in leaves.items()
                               if k not in EXPERT_LEAVES}
                 caches = {"own": state[key]} if key in state else {}
+                cls = "window" if kind == "window_attn" else "full"
                 if per:
-                    caches["pool"] = kv_pool
+                    caches["pool"] = pools[cls]
 
-                def body(carry, blk, fn=kinds[kind], base=pool_layer, per=per,
-                         experts=experts):
+                def body(carry, blk, fn=kinds[kind], base=pool_layer[cls],
+                         per=per, experts=experts):
                     h, caches, l, tally = carry
                     y, caches, counts = fn(h, blk, caches, l, base + per * l,
                                            step, experts)
@@ -2071,7 +2232,9 @@ class TransformerLM:
                 if "own" in caches:
                     state[key] = caches["own"]
                 if per:
-                    kv_pool, pool_layer = caches["pool"], pool_layer + per * n
+                    pools[cls] = caches["pool"]
+                    pool_layer[cls] += per * n
+        kv_pool = pools if cfg.bounded_cache else pools["full"]
         # only the last position is projected (and of those rows, only
         # ``logit_rows``)
         x_last = x[:, S - 1]
@@ -2114,14 +2277,17 @@ class TransformerLM:
     # ``layer_types`` models: one small mixer a type, the rest shared
     # ------------------------------------------------------------------
     def _typed_layer(self, mixer, x, blk, caches, layer, pool_layer, step,
-                     experts=None):
+                     experts=None, scope=None):
         """One layer of a ``layer_types`` model on (T, 1, H): ``x + a M(N(x))``
         then ``+ a F(N(.))``, ``a`` the muP residual scale; ``mixer`` is the
-        layer type's (:meth:`_sparse_mixer`, :meth:`_linear_mixer`), the
-        norms, the gate, ``wo`` and the feed-forward are shared. Behind
-        ``mixer`` a kind of :meth:`forward_paged`."""
-        from ..moe.layer import _gated_mlp
-
+        layer type's (:meth:`_sparse_mixer`, :meth:`_linear_mixer`,
+        :meth:`_attn_mixer`), the norms, the gate, ``wo`` and the
+        feed-forward (:meth:`_feed_forward`: dense, or the held experts
+        ``experts`` = their stacked leaves) are shared; with ``post_norms``
+        each sublayer's output is normed before it joins the stream. Behind
+        ``mixer`` a kind of :meth:`forward_paged`. ``scope``: a name the
+        attention sublayer is traced under beside ``attn`` (the layer types
+        whose device time is told apart, ``utils/tracing.py``)."""
         cfg = self.config
         T, dt, a = x.shape[0], x.dtype, cfg.residual_scale
         nh, hd = cfg.num_heads, cfg.head_dim
@@ -2138,7 +2304,12 @@ class TransformerLM:
             return _norm(y, blk[name], None, "rmsnorm", cfg.norm_eps) \
                 if name and cfg.qk_norm else y
 
-        with jax.named_scope("attn"):
+        def post(y, name):
+            return _norm(once(y), blk[name], None, "rmsnorm", cfg.norm_eps) \
+                if cfg.post_norms else y
+
+        with jax.named_scope("attn"), (
+                jax.named_scope(scope) if scope else contextlib.nullcontext()):
             h = _norm(x[:, 0], blk["ln1_scale"], None, "rmsnorm", cfg.norm_eps)
             q, k = heads("wq", "q_norm_scale"), heads("wk", "k_norm_scale")
             o, caches, counts = mixer(q, k, heads("wv"), blk, caches, layer,
@@ -2146,12 +2317,64 @@ class TransformerLM:
             o = o.reshape(T, nh * hd).astype(dt)
             if cfg.attn_output_gate:
                 o = o * jax.nn.sigmoid(once(h @ blk["w_ogate"].astype(dt)))
-            x = once(x + a * (o @ blk["wo"].astype(dt))[:, None])
+            x = once(x + a * post(o @ blk["wo"].astype(dt),
+                                  "post_attn_scale")[:, None])
         with jax.named_scope("mlp"):
             h2 = _norm(x, blk["ln2_scale"], None, "rmsnorm", cfg.norm_eps)
-            x = x + a * _gated_mlp(h2, blk["w_gate"], blk["w_up"],
-                                   blk["w_down"])
-        return x, caches, counts
+            f, stats = self._feed_forward(
+                h2, blk, None if experts is None else (experts, layer), step)
+            x = x + a * post(f, "post_mlp_scale")
+        return x, caches, counts if stats is None else stats
+
+    def _attn_mixer(self, window, q, k, v, blk, caches, layer, pool_layer,
+                    step):
+        """GQA attention of this step's rows over their class of the paged
+        pool, a ``window`` layer (rotary on q and k; a query sees the last
+        ``sliding_window`` tokens; the window class's tables, which count
+        from the first block a sequence still holds: ``PagedStep.window``)
+        or a full one (no positional term; the whole context). A decode
+        round (rows apart) is one kernel call a layer, the new rows written
+        on the way; a mixed step writes its rows by the scatter, its
+        one-token rows go through the kernel and its tiles through the
+        gather path (``paged_attention.attend_tiles``)."""
+        from ..ops.transformer import paged_attention as pa
+
+        cfg = self.config
+        pool = caches["pool"]
+        T, nh, hd = q.shape
+        cut, end, tile = step.cut, step.end, step.tile
+        if window:
+            q, k = (a[:, 0] for a in _rope(q[:, None], k[:, None],
+                                           step.positions, hd, cfg.rope_theta))
+            tables, starts, limits, first = step.window
+        else:
+            tables, starts, limits, first = (step.tables, step.starts,
+                                             step.limits, None)
+        fold = (cut == T and step.rows_apart and pa.kernels_wanted()
+                and pa.writes_live_rows(pool))
+        if fold:
+            with jax.named_scope("paged_attn"):
+                o, pool = pa.paged_decode(
+                    q.reshape(T, nh * hd), pool, pool_layer, tables, limits,
+                    new_rows=(k.reshape(T, -1), v.reshape(T, -1)), first=first)
+            return o.reshape(T, nh, hd), {"pool": pool}, None
+        with jax.named_scope("kv_write"):
+            pool = pa.write_rows(pool, pool_layer, tables, starts[:, None],
+                                 k[:, None], v[:, None],
+                                 rows_apart=step.rows_apart)
+        with jax.named_scope("paged_attn"):
+            o = pa.attend_rows(
+                q[:cut], pool, pool_layer, tables[:cut], limits[:cut],
+                first=None if first is None else first[:cut])
+            if cut < end:
+                tiles = slice(cut, end, tile)   # the first row of each tile
+                o2 = pa.attend_tiles(
+                    q[cut:end].reshape(-1, tile, nh, hd), pool, pool_layer,
+                    tables[tiles], starts[tiles],
+                    window=cfg.sliding_window if window else 0)
+                o = jnp.concatenate([o, o2.reshape(-1, nh, hd)])
+        o = jnp.pad(o, ((0, T - o.shape[0]), (0, 0), (0, 0)))
+        return o, {"pool": pool}, None
 
     def _sparse_mixer(self, q, k, v, blk, caches, layer, pool_layer, step):
         """Block-sparse attention (ops/transformer/sparse_attention.py) of

@@ -53,6 +53,7 @@ live row's first blocks arrive while the row before it computes its last.
 """
 
 import functools
+import math
 import os
 
 import jax
@@ -385,7 +386,7 @@ def kv_write(pool, layer, blk, off, kv):
 
 
 def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
-                   bpt, hd, write):
+                   bpt, hd, write, bound=False):
     """Grid (B / rpc, kvh / hpc): ONE cell per ``rpc`` rows of the step
     (:func:`rows_per_cell`) and group of ``hpc`` kv heads (all of them
     wherever the buffer fits: :func:`heads_per_cell`). The cell first sorts
@@ -431,6 +432,8 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
     behind it have arrived before the store starts and are masked; no other
     row of the step names that block (the caller's promise), and a dead row
     starts nothing."""
+    if bound:
+        first_ref, *refs = refs
     if write:
         (q_ref, k_ref, v_ref, _, o_ref, pool_ref, buf, sem, live_ref,
          q32, k32, v32, o32, wbuf, wsem) = refs
@@ -445,6 +448,11 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
 
     def blocks(b):
         return (lens_ref[b] + block_size - 1) // block_size
+
+    def trip0(r):
+        """The first trip of the cell's row ``r``: the one that holds its
+        bound."""
+        return first_ref[row0 + r] // (bpt * block_size) if bound else 0
 
     def sort_row(r, n_live):
         # a dead row's number is overwritten by the next live row's
@@ -503,7 +511,8 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
 
     @pl.when(n_live > 0)
     def _cold():
-        for c in copies(live_ref[0], 0, 0):
+        r = live_ref[0]
+        for c in copies(r, trip0(r), 0):
             c.start()
 
     # every row's output starts as zeros, which is what a dead row keeps
@@ -517,6 +526,7 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
         seq_len = lens_ref[row0 + r]
         ntrip = (blocks(row0 + r) + bpt - 1) // bpt
         r_next = live_ref[jnp.minimum(k + 1, rpc - 1)]
+        j0 = trip0(r)
         if write:
             qh = heads_of(q32, r, hpc * g)
             q = jnp.stack([jnp.concatenate(qh[i * g:(i + 1) * g], axis=0)
@@ -526,7 +536,7 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
 
         def body(j, carry):
             m, l, acc = carry
-            slot = jax.lax.rem(slot0 + j, 2)
+            slot = jax.lax.rem(slot0 + j - j0 if bound else slot0 + j, 2)
 
             @pl.when(j + 1 < ntrip)
             def _prefetch():
@@ -535,7 +545,7 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
 
             @pl.when((j + 1 == ntrip) & (k + 1 < n_live))
             def _hand_over():
-                for c in copies(r_next, 0, 1 - slot):
+                for c in copies(r_next, trip0(r_next), 1 - slot):
                     c.start()
 
             for c in copies(r, j, slot):
@@ -551,7 +561,10 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
                 preferred_element_type=jnp.float32)
             kpos = j * (bpt * block_size) + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 2)
-            s = jnp.where(kpos < seq_len, s, NEG_INF)
+            seen = kpos < seq_len
+            if bound:
+                seen &= kpos >= first_ref[row0 + r]
+            s = jnp.where(seen, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m - m_new)
@@ -564,7 +577,7 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
         m0 = jnp.full((hpc, g, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((hpc, g, 1), jnp.float32)
         acc0 = jnp.zeros((hpc, g, hd), jnp.float32)
-        _, l, acc = jax.lax.fori_loop(0, ntrip, body, (m0, l0, acc0))
+        _, l, acc = jax.lax.fori_loop(j0, ntrip, body, (m0, l0, acc0))
         out = acc / l                              # a live row sees a key
         if write:
             o32[pl.ds(r, 1), :] = jnp.concatenate(
@@ -572,7 +585,7 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
                 axis=-1)
         else:
             o_ref[r] = out.astype(o_ref.dtype)
-        return jax.lax.rem(slot0 + ntrip, 2)
+        return jax.lax.rem(slot0 + ntrip - j0 if bound else slot0 + ntrip, 2)
 
     if not write:
         jax.lax.fori_loop(0, n_live, attend, 0)
@@ -588,7 +601,8 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
         store(row0 + live_ref[n_live - 1]).wait()
 
 
-def paged_decode(q, pool, layer, tables, lens, *, scale=None, new_rows=None):
+def paged_decode(q, pool, layer, tables, lens, *, scale=None, new_rows=None,
+                 first=None):
     """One-token decode attention of layer ``layer`` against the stacked pool,
     read where it lies; with ``new_rows``, the decode round's whole attention
     sublayer in one call: the rows' new keys and values written into the pool
@@ -600,6 +614,13 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None, new_rows=None):
     is ever sliced out; tables: (B, MAXB) int32 pool block ids (0-padded);
     lens: (B,) int32 valid token counts (position + 1). Returns (B, nh, hd)
     in q's dtype.
+
+    ``first``: (B,) int32, each row's lower bound (a window layer's): the row
+    attends over the tokens ``first[b] <= t < lens[b]`` of its table, the
+    trips start at the block that holds ``first[b]`` and that block is
+    masked below it. A live row has ``first < lens``. Without it the call is
+    the one it was, bit for bit: the bound is a fourth scalar operand that
+    only a bounded call has.
 
     ``new_rows``: (k, v), each (B, kvh * hd), the step's new token of every
     row as the projections leave it; ``lens`` counts it. q is then
@@ -625,7 +646,7 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None, new_rows=None):
     deadness out of the table, and a row with ``lens`` 1 and an all-zero
     table attends to the trash block's first token. A live row's output is
     bit for bit what the row gives alone, whatever shares its cell."""
-    write = new_rows is not None
+    write, bound = new_rows is not None, first is not None
     _, kvh, _, BS, row = pool.shape
     B, hd = q.shape[0], row // 2 if write else q.shape[2]
     nh = q.shape[1] // hd if write else q.shape[1]
@@ -657,21 +678,102 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None, new_rows=None):
         out_shape = jax.ShapeDtypeStruct((B, kvh, g, hd), q.dtype)
     out = tracing.pallas_call(
         functools.partial(_decode_kernel, block_size=BS, bpt=bpt, hd=hd,
-                          write=write,
+                          write=write, **({"bound": True} if bound else {}),
                           scale=scale if scale is not None else hd ** -0.5),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,  # layer, tables, lens
+            num_scalar_prefetch=3 + bound,  # layer, tables, lens[, first]
             grid=(B // rpc, kvh // hpc),
             in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape,
         # the pool, counting the scalar operands
-        input_output_aliases={6: 1} if write else {},
+        input_output_aliases={6 + bound: 1} if write else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
         name="paged_decode",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), tables, lens, *operands)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables, lens,
+      *((first.astype(jnp.int32),) if bound else ()), *operands)
     return tuple(out) if write else out.reshape(B, nh, hd)
+
+
+def attend_rows(q, pool, layer, tables, lens, *, first=None, scale=None):
+    """One-token rows over their tables, each the tokens ``first[b] <= t <
+    lens[b]`` (from 0 without ``first``): :func:`paged_decode` where the
+    kernels are wanted, one XLA gather of the rows' contexts off the chip.
+    q (B, nh, hd); a row with ``lens`` 0 is dead and gives zeros. Positions
+    are the table's own: a bounded class's table starts at the first block
+    its sequence still holds, and its caller counts from there."""
+    if kernels_wanted():
+        return paged_decode(q, pool, layer, tables, lens, scale=scale,
+                            first=first)
+    B, nh, hd = q.shape
+    kvh = pool.shape[1]
+    gk, gv = gather_context(pool, layer, tables)         # (B, T, kvh, hd)
+    kpos = jnp.arange(gk.shape[1])[None]
+    seen = kpos < lens[:, None]
+    if first is not None:
+        seen &= kpos >= first[:, None]
+    logit = jnp.einsum("bhgd,bthd->bhgt", q.reshape(B, kvh, nh // kvh, hd),
+                       gk, preferred_element_type=jnp.float32) \
+        * (scale if scale is not None else hd ** -0.5)
+    p = jax.nn.softmax(jnp.where(seen[:, None, None], logit, NEG_INF), axis=-1)
+    p = jnp.where((lens > 0)[:, None, None, None], p, 0.0).astype(gv.dtype)
+    o = jnp.einsum("bhgt,bthd->bhgd", p, gv,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, nh, hd).astype(q.dtype)
+
+
+#: context widths a tile's attention is compiled for, as shares of the
+#: table: a tile takes the narrowest that covers its last token. One width
+#: for a table of at most :data:`TILE_WIDTH_BLOCKS` blocks (a window class's)
+TILE_WIDTHS = (0.25, 0.5, 0.75, 1.0)
+TILE_WIDTH_BLOCKS = 64
+
+
+def attend_tiles(q, pool, layer, tables, first, *, window=0, scale=None):
+    """Attention of tiles of consecutive tokens of one sequence (a prefill
+    chunk's segment) over the sequence's gathered context: masked dense
+    attention, the gather path (there is no segment kernel for a pool of
+    heads). q (N, C, nh, hd); tables (N, MB) each tile's sequence's table
+    (all zero: an empty tile); first (N,) the position of each tile's first
+    token, counted as the table counts. A query at ``i`` sees the keys ``j <=
+    i``, and with ``window`` only those with ``i - j < window``; a window
+    class's table holds nothing older, so its gather is the segment the
+    tile needs and no more. Returns (N, C, nh, hd)."""
+    N, C, nh, hd = q.shape
+    kvh, BS = pool.shape[1], pool.shape[3]
+    g, MB = nh // kvh, tables.shape[1]
+    scale = scale if scale is not None else hd ** -0.5
+    widths = sorted({max(1, math.ceil(MB * w)) for w in TILE_WIDTHS}) \
+        if MB > TILE_WIDTH_BLOCKS else [MB]
+
+    def attend(width, q, table, pos):
+        """Over the table's first ``width`` blocks."""
+        gk, gv = gather_context(pool, layer, table[None, :width])
+        gk, gv = gk[0], gv[0]                             # (width*BS, kvh, hd)
+        kpos = jnp.arange(width * BS)[None]
+        seen = kpos <= pos[:, None]                       # (C, T)
+        if window:
+            seen &= kpos > pos[:, None] - window
+        logit = jnp.einsum("chgd,thd->hgct", q.reshape(C, kvh, g, hd), gk,
+                           preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(seen[None, None], logit, NEG_INF),
+                           axis=-1).astype(gv.dtype)
+        o = jnp.einsum("hgct,thd->chgd", p, gv,
+                       preferred_element_type=jnp.float32)
+        return o.reshape(C, nh, hd).astype(q.dtype)
+
+    def tile(args):
+        q, table, first = args
+        pos = first + jnp.arange(C)
+        need = (first + C + BS - 1) // BS           # blocks the tile reaches
+        branch = jnp.sum(need > jnp.asarray(widths[:-1]), dtype=jnp.int32) \
+            if len(widths) > 1 else 0
+        return jax.lax.switch(
+            branch, [functools.partial(attend, w) for w in widths],
+            q, table, pos)
+
+    return jax.lax.map(tile, (q, tables, first))
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lens, *, scale=None):

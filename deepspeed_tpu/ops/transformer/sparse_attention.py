@@ -233,7 +233,8 @@ def decode_rows(q, pool, layer, tables, keys, n, spec: SparseSpec, scale):
 
 #: context widths a tile's attention is compiled for, as shares of the
 #: longest context: a tile takes the narrowest that covers its last token
-TILE_WIDTHS = (0.25, 0.5, 0.75, 1.0)
+#: (the paged pool's own, ``paged_attention.attend_tiles``)
+TILE_WIDTHS = pa.TILE_WIDTHS
 
 
 def tile_rows(q, pool, layer, tables, keys, first, spec: SparseSpec, scale):

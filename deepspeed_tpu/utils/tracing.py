@@ -472,9 +472,13 @@ SERVE_SCOPES = ("kv_write", "paged_attn", "sample")
 #: attention's projections, the expert layer's routing, grouped products,
 #: shared expert and identity experts, a double layer's dense feed-forwards,
 #: a lightning layer's recurrence (decay, update, read-out, output norm), a
-#: sparse layer's selector (compressed keys, scores, top-k, compacted tables)
+#: sparse layer's selector (compressed keys, scores, top-k, compacted tables),
+#: the attention sublayer of a window and of a full GQA layer (its norms,
+#: projections, rotary, gate and ``wo``: the kernel and the gather read
+#: ``paged_attn``, the rows' write ``kv_write``, as in every served model)
 LAYER_SCOPES = ("mla_proj", "moe_route", "moe_experts", "moe_shared",
-                "moe_zero", "dense_ffn", "linear_attn", "sparse_select")
+                "moe_zero", "dense_ffn", "linear_attn", "sparse_select",
+                "window_attn", "full_attn")
 
 
 def _abstract(x):
@@ -503,7 +507,8 @@ def classify(op_name: str) -> str:
     differentiated forward); the serving scopes ``kv_write``, ``paged_attn``,
     ``sample``; a layer's own parts ``mla_proj``, ``moe_route``,
     ``moe_experts``, ``moe_shared``, ``moe_zero``, ``dense_ffn``,
-    ``linear_attn``, ``sparse_select`` and else
+    ``linear_attn``, ``sparse_select``, ``window_attn``, ``full_attn`` and
+    else
     ``model`` (a model scope in a program without gradients);
     ``kv_carry`` (the paged program's layer scan itself: the ops that belong
     to the loop and to no layer scope, which is what it does to the arrays it
