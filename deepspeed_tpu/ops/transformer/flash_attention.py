@@ -26,10 +26,13 @@ sequence) is taken a chunk of queries (``FWD_CHUNK``; backward: of keys,
 ``BWD_CHUNK``) at a time in straight code, because the chip overlaps one
 chunk's vector work with the next one's products there and not across the
 iterations of a loop. Under a causal mask a chunk meets only the keys up to
-its own (backward: the queries from its own on): what lies above the diagonal
-is never computed, and only the chunk-sized corner on the diagonal is masked.
-Blocks before (after) the own one, which exist when a sequence is longer than
-``BLOCK``, take a loop with no mask.
+its own (backward: the queries from its own on), and the chunk-sized corner
+on the diagonal is walked as a staircase of ``LANES`` queries a strip
+(``_staircase``): a strip's products stop at the diagonal's tile, the only one
+masked, so of what lies above the diagonal nothing is multiplied but the upper
+halves of those tiles, and the chunk keeps its one softmax over all its
+pieces. Blocks before (after) the own one, which exist when a sequence is
+longer than ``BLOCK``, take a loop with no mask.
 ``scale`` goes onto the q (backward: k) tile once where that is exact (a power
 of two: head 64 and 256) and onto the float32 scores otherwise. The backward
 works on transposed scores (keys, queries), so lse and delta are lane-dense
@@ -137,19 +140,73 @@ def _kv_head(t, h, per_tile, g):
     return kv // per_tile, kv % per_tile
 
 
-def _causal(s, n, queries):
-    """Scores whose ``n`` x ``n`` corner starts on the causal diagonal (its
-    first query and first key are the same position), that corner masked
-    above the diagonal. ``queries`` is the dimension the queries run along:
-    0 with the corner at the last ``n`` keys (forward), 1 with it at the
-    first ``n`` queries (transposed scores, backward)."""
-    corner = s[:, -n:] if queries == 0 else s[:, :n]
-    at = functools.partial(jax.lax.broadcasted_iota, jnp.int32, corner.shape)
-    corner = jnp.where(at(queries) >= at(1 - queries), corner, NEG_INF)
-    if s.shape[1] == n:
-        return corner
-    return jnp.concatenate(
-        [s[:, :-n], corner] if queries == 0 else [corner, s[:, n:]], axis=1)
+def _staircase(chunk):
+    """A chunk's causal corner (``chunk`` queries by ``chunk`` keys from one
+    position on) as strips of ``LANES`` queries: rectangles (q0, q1, k0, k1)
+    of it, a strip's queries against the keys up to theirs. Tile for tile of
+    ``LANES`` they hold the diagonal and what lies under it exactly once and
+    nothing above it; a strip meets the diagonal in ONE tile, its last, the
+    only one to mask, and a chunk of ``LANES`` is that tile alone. Cut by
+    queries for both kernels: the forward's strips are then the row bands of
+    its chunk, each with a softmax chain of its own, and the backward's keep
+    all their keys' rows but for the first strips'. Cut by keys, both kernels
+    lost on the chip (PERF.md 5, PR 63)."""
+    return [(a, a + LANES, 0, a + LANES) for a in range(0, chunk, LANES)]
+
+
+def _pairs(sq, skv, block, chunk, causal):
+    """What a kernel's bind record says of its walk (``tracing.pallas_call``):
+    the scores one head's grid cells multiply on the MXU over a whole call,
+    walking chunks of ``chunk``, and those attention needs (S (S + 1) / 2
+    under a causal mask)."""
+    computed = needed = sq * skv
+    if causal:
+        stairs = sum((q1 - q0) * (k1 - k0)
+                     for q0, q1, k0, k1 in _staircase(chunk))
+        own = sum(stairs + chunk * c for c in range(0, block, chunk))
+        nq, nk = sq // block, skv // block
+        off = sum(min(i, nk) for i in range(nq))    # whole blocks off the diagonal
+        short = min(sq, skv)
+        computed = min(nq, nk) * own + off * block * block
+        needed = short * (short + 1) // 2 + (sq - short) * skv
+    return {"pairs_computed": computed, "pairs_causal": needed}
+
+
+def _causal(s, q0, k0, queries):
+    """Scores of the queries from ``q0`` and the keys from ``k0`` of a corner
+    (each as many as ``s`` has) with the block the diagonal crosses, the
+    positions both ranges hold, masked above the diagonal: a strip's one
+    tile. ``queries`` is the dimension the queries run along: 0 in the
+    forward, 1 on the backward's transposed scores."""
+    nq, nk = s.shape[queries], s.shape[1 - queries]
+    lo, hi = max(q0, k0), min(q0 + nq, k0 + nk)
+    at = (lo - q0, lo - k0)                      # the block's query, key in s
+    r, c = at if queries == 0 else at[::-1]
+    n = hi - lo
+    block = s[r:r + n, c:c + n]
+    pos = functools.partial(jax.lax.broadcasted_iota, jnp.int32, block.shape)
+    block = jnp.where(pos(queries) >= pos(1 - queries), block, NEG_INF)
+    band = _join([s[r:r + n, :c], block, s[r:r + n, c + n:]], 1)
+    return _join([s[:r], band, s[r + n:]], 0)
+
+
+def _join(parts, axis):
+    """The non-empty ``parts`` side by side along ``axis``."""
+    parts = [x for x in parts if x.shape[axis]]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis)
+
+
+def _rows(x, at, n, fill):
+    """``x`` as the rows from ``at`` of ``n``, ``fill`` in the others."""
+    def const(rows):
+        return jnp.full((rows, x.shape[1]), fill, x.dtype)
+    return _join([const(at), x, const(n - at - x.shape[0])], 0)
+
+
+def _fold(op, x):
+    """The lane tiles of ``x`` folded into one by ``op``, elementwise."""
+    return functools.reduce(op, [x[:, j:j + LANES]
+                                 for j in range(0, x.shape[1], LANES)])
 
 
 # ----------------------------------------------------------------------------
@@ -179,20 +236,42 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, hd, g, block_k, chunk,
     def step(qc, carry, start, size, diagonal=False):
         """Online softmax of the chunk ``qc`` over the keys [start, start +
         size); with ``diagonal`` the last ``chunk`` of them start at its
-        first query."""
+        first query and are taken strip by strip, a row band of the chunk
+        each. One max, one ``exp`` and one row sum a row over all its pieces;
+        what no piece holds is never multiplied."""
         m, l, acc = carry
-        keys = pl.ds(pl.multiple_of(start, LANES), size)
-        k, v = k_ref[0, keys, :], v_ref[0, keys, :]
-        s = _dot(qc, k, _NT)                         # (chunk, size) float32
-        if not _exact(scale):
-            s = s * scale
+        body = size - chunk if diagonal else size
+        pieces = [(0, chunk, 0, body)] if body else []
         if diagonal:
-            s = _causal(s, chunk, 0)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+            pieces += [(q0, q1, body + k0, body + k1)
+                       for q0, q1, k0, k1 in _staircase(chunk)]
+        scores, values = [], []
+        for q0, q1, k0, k1 in pieces:
+            keys = pl.ds(pl.multiple_of(start + k0, LANES), k1 - k0)
+            s = _dot(qc[q0:q1], k_ref[0, keys, :], _NT)   # float32
+            if not _exact(scale):
+                s = s * scale
+            if diagonal and k0 >= body:   # a strip
+                s = _causal(s, q0, k0 - body, 0)
+            scores.append(s)
+            values.append(v_ref[0, keys, :])
+
+        # a row's max and sum: the pieces' lane tiles folded elementwise, then
+        # ONE reduction across the lanes a row of the chunk
+        top = [_rows(_fold(jnp.maximum, s), q0, chunk, NEG_INF)
+               for (q0, *_), s in zip(pieces, scores)]
+        m_new = jnp.maximum(m, jnp.max(functools.reduce(jnp.maximum, top),
+                                       axis=-1, keepdims=True))
+        wide = jnp.broadcast_to(m_new, (chunk, LANES))
         alpha = jnp.exp(m - m_new)
-        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + _dot(p.astype(v.dtype), v, _NN)
+        acc = acc * alpha
+        total = []
+        for (q0, q1, k0, k1), s, v in zip(pieces, scores, values):
+            p = jnp.exp(s - _join([wide[q0:q1]] * ((k1 - k0) // LANES), 1))
+            total.append(_rows(_fold(jnp.add, p), q0, chunk, 0.0))
+            acc = acc + _rows(_dot(p.astype(v.dtype), v, _NN), q0, chunk, 0.0)
+        l = alpha * l + jnp.sum(functools.reduce(jnp.add, total), axis=-1,
+                                keepdims=True)
         return m_new, l, acc
 
     lse = []
@@ -243,10 +322,11 @@ def _fwd(q, k, v, *, causal, num_kv_groups, scale, block_q, block_k):
     def kv_tile(b, t, i, h):
         return b, 0, _kv_head(t, h, per_tile, g)[0]
 
+    chunk = math.gcd(FWD_CHUNK, block_q)
     out, lse = tracing.pallas_call(
         functools.partial(_fwd_kernel, hd=hd, g=g, block_k=block_k,
-                          chunk=math.gcd(FWD_CHUNK, block_q), causal=causal,
-                          scale=scale, inside=Sq <= Skv),
+                          chunk=chunk, causal=causal, scale=scale,
+                          inside=Sq <= Skv),
         grid=(B, nh // per_tile, Sq // block_q, per_tile),
         in_specs=[
             pl.BlockSpec((1, block_q, width), lambda b, t, i, h: (b, i, t)),
@@ -264,6 +344,7 @@ def _fwd(q, k, v, *, causal, num_kv_groups, scale, block_q, block_k):
         ],
         interpret=_interpret(),
         name="flash_fwd",
+        attrs=_pairs(Sq, Skv, block_q, chunk, causal),
     )(q.reshape(B, Sq, nh * hd), k.reshape(B, Skv, kvh * hd),
       v.reshape(B, Skv, kvh * hd))
     return out.reshape(q.shape), lse.reshape(B, nh, Sq)
@@ -317,22 +398,31 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 
     def step(keys, start, size, diagonal=False):
         """The block's ``keys`` against the queries [start, start + size);
-        with ``diagonal`` the first ``chunk`` of them start at the first key."""
-        rows = pl.ds(pl.multiple_of(start, LANES), size)
-        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
-        lse = _row(lse_ref[0, 0, :, rows], h)        # (1, size)
-        delta = _row(delta_acc[:, rows], h)
-        st = _dot(k_s[keys], q, _NT)                 # (chunk, size) float32
-        if not _exact(scale):
-            st = st * scale
+        with ``diagonal`` the first ``chunk`` of them start at the first key
+        and are taken strip by strip, the last strip (it holds every key) in
+        one piece with the queries after it."""
+        pieces = [(0, size, 0, chunk)]
         if diagonal:
-            st = _causal(st, chunk, 1)
-        pt = jnp.exp(st - lse)
-        dv_acc[keys] += _dot(pt.astype(do.dtype), do, _NN)
-        dpt = _dot(v[keys], do, _NT)
-        dst = (pt * (dpt - delta)).astype(q.dtype)   # scale: on dk, dq at the end
-        dk_acc[keys] += _dot(dst, q, _NN)
-        dq_acc[rows, :] += _dot(dst, k[keys], _TN)   # this head's lanes only
+            *pieces, (q0, _, k0, k1) = _staircase(chunk)
+            pieces.append((q0, size, k0, k1))
+        for q0, q1, k0, k1 in pieces:
+            rows = pl.ds(pl.multiple_of(start + q0, LANES), q1 - q0)
+            own = slice(keys.start + k0, keys.start + k1)
+            q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+            lse = _row(lse_ref[0, 0, :, rows], h)    # (1, queries)
+            delta = _row(delta_acc[:, rows], h)
+            st = _dot(k_s[own], q, _NT)              # (keys, queries) float32
+            if not _exact(scale):
+                st = st * scale
+            if diagonal:
+                st = _causal(st, q0, k0, 1)
+            pt = jnp.exp(st - lse)
+            dv_acc[own] += _dot(pt.astype(do.dtype), do, _NN)
+            dpt = _dot(v[own], do, _NT)
+            # scale: on dk, dq at the end
+            dst = (pt * (dpt - delta)).astype(q.dtype)
+            dk_acc[own] += _dot(dst, q, _NN)
+            dq_acc[rows, :] += _dot(dst, k[own], _TN)   # this head's lanes only
 
     for c in range(0, block_k, chunk):
         keys = slice(c, c + chunk)
@@ -376,10 +466,11 @@ def _bwd(causal, num_kv_groups, scale, block_q, block_k, res, do):
     row = pl.BlockSpec((1, 1, per_tile, Sq), lambda b, t, i, h: (b, t, 0, 0))
     dkv = pl.BlockSpec((1, block_k, width), lambda b, t, i, h: (b, i, t))
 
+    chunk = math.gcd(BWD_CHUNK, block_k)
     dq, dkh, dvh = tracing.pallas_call(
         functools.partial(_bwd_kernel, hd=hd, g=g, block_q=block_q,
-                          chunk=math.gcd(BWD_CHUNK, block_k), causal=causal,
-                          scale=scale, inside=Skv <= Sq),
+                          chunk=chunk, causal=causal, scale=scale,
+                          inside=Skv <= Sq),
         grid=(B, tiles, Skv // block_k, per_tile),
         in_specs=[seq, pl.BlockSpec((1, block_k, width), kv_block),
                   pl.BlockSpec((1, block_k, width), kv_block), seq, seq, row],
@@ -395,6 +486,7 @@ def _bwd(causal, num_kv_groups, scale, block_q, block_k, res, do):
                         pltpu.VMEM((block_k, width), jnp.float32)],
         interpret=_interpret(),
         name="flash_bwd",
+        attrs=_pairs(Sq, Skv, block_k, chunk, causal),
     )(q.reshape(B, Sq, nh * hd), k.reshape(B, Skv, kvh * hd),
       v.reshape(B, Skv, kvh * hd), out.reshape(B, Sq, nh * hd),
       do.reshape(B, Sq, nh * hd), lse.reshape(B, tiles, per_tile, Sq))

@@ -269,7 +269,8 @@ _JIT_WRAPPER = "deepspeed_tpu.analysis.program_audit"
 
 def _pending() -> list:
     """This thread's trace, lowering and kernel-bind intervals that no
-    finished build has claimed yet: (kind, name, start, end), by ``end``."""
+    finished build has claimed yet: (kind, name, start, end, attrs), by
+    ``end``; ``attrs`` is what a kernel was bound with, else None."""
     try:
         return _local.pending
     except AttributeError:
@@ -313,20 +314,23 @@ def _close_build(program: str, load_s: float, end: int, frame) -> None:
             break
     trace_s = lower_s = 0.0
     kernels: Dict[str, list] = {}
+    kernel_attrs: Dict[str, dict] = {}
     cut = lower
     if lower is not None:
         lower_s = (pend[lower][3] - pend[lower][2]) / 1e9
     if trace is not None:
-        _, _, start, stop = pend[trace]
+        start, stop = pend[trace][2:4]
         trace_s = (stop - start) / 1e9
         cut = trace
         while cut and pend[cut - 1][3] >= start:
             cut -= 1
-            kind, name, k_start, k_stop = pend[cut]
+            kind, name, k_start, k_stop, attrs = pend[cut]
             if kind == "kernel":
                 into = kernels.setdefault(name, [0, 0.0])
                 into[0] += 1
                 into[1] += (k_stop - k_start) / 1e9
+                if attrs:
+                    kernel_attrs[name] = attrs
     if cut is not None:
         del pend[cut:]
     site, caller = _site(frame)
@@ -338,7 +342,8 @@ def _close_build(program: str, load_s: float, end: int, frame) -> None:
     keep("compile", end - int(load_s * 1e9), end, kept=big,
          program=program, cached=cached, site=site, caller=caller,
          trace_s=trace_s, lower_s=lower_s, load_s=load_s,
-         kernels={k: tuple(v) for k, v in kernels.items()})
+         kernels={k: tuple(v) for k, v in kernels.items()},
+         kernel_attrs=kernel_attrs)
 
 
 def _on_duration(name: str, seconds: float, **kw) -> None:
@@ -354,7 +359,8 @@ def _on_duration(name: str, seconds: float, **kw) -> None:
         if len(pend) > 2048:        # traces nobody compiled (eval_shape)
             del pend[:1024]
         pend.append(("trace" if name == _TRACE else "lower",
-                     kw.get("fun_name", ""), now - int(seconds * 1e9), now))
+                     kw.get("fun_name", ""), now - int(seconds * 1e9), now,
+                     None))
     elif name == _CACHE_READ:
         _local.cache_read = True
     elif name == _COMPILE:
@@ -387,7 +393,8 @@ def builds() -> List[Record]:
     no profiler: ``compile`` records whose span is the backend compile or
     the cache load and whose attributes are ``program`` (JAX's name of it),
     ``site``, ``caller``, ``trace_s``, ``lower_s``, ``load_s``, ``cached``
-    and ``kernels`` ({name: (binds, seconds)}). A live process that
+    ``kernels`` ({name: (binds, seconds)}) and ``kernel_attrs`` ({name: what
+    the kernel said of itself at its bind}). A live process that
     recompiles shows it here. Short builds of the caller's own are in
     :func:`small_builds` instead."""
     return [r for r in _kept if r.name == "compile"]
@@ -413,10 +420,10 @@ def listener_seconds() -> float:
 class _Kernel:
     """What :func:`pallas_call` returns: the kernel's bind, timed under a
     trace."""
-    __slots__ = ("name", "call")
+    __slots__ = ("name", "call", "attrs")
 
-    def __init__(self, name: str, call):
-        self.name, self.call = name, call
+    def __init__(self, name: str, call, attrs):
+        self.name, self.call, self.attrs = name, call, attrs
 
     def __call__(self, *args):
         if not any(isinstance(a, jax.core.Tracer) for a in args):
@@ -425,19 +432,22 @@ class _Kernel:
         try:
             return self.call(*args)
         finally:
-            _pending().append(("kernel", self.name, start, clock_ns()))
+            _pending().append(("kernel", self.name, start, clock_ns(),
+                               self.attrs))
 
 
-def pallas_call(kernel, **kwargs):
+def pallas_call(kernel, attrs: Optional[dict] = None, **kwargs):
     """``pl.pallas_call`` for every kernel of the package. Binding a kernel
     traces its body's Python, in every process and for every program that
     holds it, compile cache or none: under a trace the bind is timed and
     lands, by the kernel's ``name``, on the record of the program being
-    traced on this thread. It runs when a program is traced and never when
-    it executes."""
+    traced on this thread, with ``attrs``: what is known of the call's work
+    when it is bound (the flash kernels' ``pairs_computed`` beside
+    ``pairs_causal``). It runs when a program is traced and never when it
+    executes."""
     from jax.experimental import pallas as pl
 
-    return _Kernel(kwargs["name"], pl.pallas_call(kernel, **kwargs))
+    return _Kernel(kwargs["name"], pl.pallas_call(kernel, **kwargs), attrs)
 
 
 class Phases:

@@ -107,13 +107,19 @@ def test_flash_attention_compiles(one_chip, no_compile_cache, as_tpu, via,
         "flash attention gave way to the XLA path"
 
 
+@pytest.mark.parametrize("shape", [(2, S, NH, HD), (4, 2048, 16, 128)],
+                         ids=["gpt2-medium", "pythia-1.4b"])
 def test_a_kernel_programs_build_record_names_its_kernels(
-        one_chip, no_compile_cache, as_tpu):
+        one_chip, no_compile_cache, as_tpu, shape):
     """The always-on account (docs/TRACING.md "Set-up and recompiles") of a
-    program lowered and compiled for the described chip: one record, made by
-    the test (no ``site``), with both flash kernels by name, how often each
-    was bound while the program was traced and what tracing their bodies
-    cost; lowering them to Mosaic is inside ``lower_s``."""
+    program lowered and compiled for the described chip, under the 16 MiB of
+    VMEM the compiler gives unasked: one record, made by the test (no
+    ``site``), with both flash kernels by name, how often each was bound
+    while the program was traced and what tracing their bodies cost; lowering
+    them to Mosaic is inside ``lower_s``. Each kernel's bind says how many
+    scores a head multiplies beside the causal pairs it needs: the 128 x 128
+    tiles on and under the diagonal (1.124 x the pairs at 1024; the parent's
+    one masked square a chunk read 1.50 forward and 1.25 backward)."""
     from deepspeed_tpu.ops.transformer.flash_attention import flash_attention
     from deepspeed_tpu.utils import tracing
 
@@ -121,7 +127,7 @@ def test_a_kernel_programs_build_record_names_its_kernels(
         return sq_loss(lambda *a: flash_attention(*a, causal=True))(q, k, v)
 
     mark = tracing.clock_ns()
-    q = aval(one_chip, (2, S, NH, HD), jnp.bfloat16)
+    q = aval(one_chip, shape, jnp.bfloat16)
     compile_text(jax.grad(kernel_program, argnums=(0, 1, 2)), q, q, q)
     rec, = [r for r in tracing.builds()
             if r.end > mark and "kernel_program" in r.attrs["program"]]
@@ -131,6 +137,15 @@ def test_a_kernel_programs_build_record_names_its_kernels(
     assert set(a["kernels"]) == {"flash_fwd", "flash_bwd"}
     for calls, seconds in a["kernels"].values():
         assert calls == 1 and 0 < seconds < a["trace_s"]
+    s, tiles = shape[1], shape[1] // 128
+    assert a["kernel_attrs"] == dict.fromkeys(
+        ("flash_fwd", "flash_bwd"),
+        {"pairs_computed": 128 * 128 * tiles * (tiles + 1) // 2,
+         "pairs_causal": s * (s + 1) // 2})
+    if s == 1024:
+        pairs = a["kernel_attrs"]["flash_fwd"]
+        assert pairs["pairs_computed"] == 589_824
+        assert round(pairs["pairs_computed"] / pairs["pairs_causal"], 3) == 1.124
 
 
 #: a ``copy`` or ``transpose`` of the compiled program (at the top level or

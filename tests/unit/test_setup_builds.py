@@ -220,7 +220,7 @@ def test_kernel_bodies_are_on_the_record_of_the_program_that_traced_them():
     mark = tracing.clock_ns()
     jax.jit(lambda x: x + 13)(jnp.ones((13,)))
     unrelated, = named(since(mark), "<lambda>")
-    assert unrelated.attrs["kernels"] == {}
+    assert unrelated.attrs["kernels"] == unrelated.attrs["kernel_attrs"] == {}
 
     mark = tracing.clock_ns()
     jax.jit(jax.grad(flash_loss)).lower(jnp.ones((1, 256, 2, 64))).compile()
@@ -229,6 +229,10 @@ def test_kernel_bodies_are_on_the_record_of_the_program_that_traced_them():
     assert set(kernels) == {"flash_fwd", "flash_bwd"}
     for calls, seconds in kernels.values():
         assert calls == 1 and 0 < seconds < rec.attrs["trace_s"]
+    # what each kernel said of its work when it was bound: at 256 positions a
+    # head multiplies the three 128-tiles on and under the diagonal
+    assert rec.attrs["kernel_attrs"] == dict.fromkeys(
+        kernels, {"pairs_computed": 3 * 128 * 128, "pairs_causal": 256 * 257 // 2})
 
 
 def test_an_eager_kernel_call_is_timed_as_no_bind():
@@ -263,7 +267,7 @@ def test_under_a_session_the_same_record_is_the_compile_span(tmp_path):
     span, = spans
     assert span.parent == sp.id
     assert {"program", "cached", "site", "caller", "trace_s", "lower_s",
-            "load_s", "kernels"} == set(span.attrs)
+            "load_s", "kernels", "kernel_attrs"} == set(span.attrs)
     assert span.attrs["trace_s"] > 0 and span.attrs["lower_s"] > 0
     kept = [r for r in since(mark) if r.id == span.id]
     assert kept and kept[0] is span     # one record, two stores
