@@ -30,7 +30,7 @@ import math
 import re
 from dataclasses import dataclass, field, replace
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,13 +42,212 @@ from ..ops.quantizer.woq import dequant_params as _dequant_woq
 from ..ops.transformer.attention import attention as _attention_op
 from ..ops.transformer.fused_ce import head_nll
 from ..ops.transformer.gelu_exact import gelu_exact
+from ..utils import tracing
 from ..utils.logging import logger
 
 
 #: the mixers a ``layer_types`` model may name
 LAYER_TYPES = ("sparse_attn", "linear_attn", "window_attn", "full_attn")
-#: those that keep KV blocks in the paged pool
-POOL_TYPES = ("sparse_attn", "window_attn", "full_attn")
+
+
+@dataclass(frozen=True)
+class LayerKind:
+    """One kind of layer, described once: what the tree, the specs, the
+    counts, the cache declaration, the paged forward and the tracer ask of
+    it. ``TransformerConfig.type_runs`` turns a configuration into kinds and
+    everything else reads the kind's record in ``LAYER_KINDS``, so a new
+    architecture is its configuration fields, a record here and the layer
+    function the record names. ``cfg`` is the model's
+    :class:`TransformerConfig`, ``lm`` its :class:`TransformerLM`."""
+    #: cfg -> {leaf: shape a layer}: a layer's leaves but for its
+    #: feed-forward's, which ``TransformerConfig.tree_shapes`` adds. None:
+    #: the GPT-2 family's tree, which ``init_params`` writes out itself
+    leaves: Optional[Callable]
+    #: cfg -> (heads, (key width, value width)) of a token's row of the pool
+    row: Callable
+    #: (cfg, S) -> the attention's FLOPs a token, forward and backward, in a
+    #: sequence of S tokens, beside its matrices' (``flops_per_token``)
+    attn_flops: Callable
+    #: cfg -> rows of a chunk-segment tile of a paged step (1: a prefill
+    #: chunk is one-token rows like any other)
+    tile: Callable
+    #: lm -> the layer function ``forward_paged`` scans over a group
+    layer: Callable
+    #: a paged row may hold more than one token
+    wide_rows: bool = False
+    #: pool layers a layer: KV blocks a token for each (0: none)
+    pool_layers: int = 1
+    #: the class of those blocks, and for a bounded class cfg -> the tokens
+    #: behind which a sequence's blocks are freed
+    block_class: str = "full"
+    bound: Optional[Callable] = None
+    #: where the kind keeps a state slot a sequence a layer: (cfg, layers,
+    #: max_seqs, max_seq_len, dtype) -> a group's slot array (layers, 1 +
+    #: max_seqs, ...), slot 0 the trash slot
+    slots: Optional[Callable] = None
+    #: the int32 counts its layer function reports (``step_counts``)
+    counts: Tuple[str, ...] = ()
+    #: the ``jax.named_scope`` names it opens that are a layer's own part
+    #: on the device (``utils/tracing.py`` ``classify``)
+    scopes: Tuple[str, ...] = ()
+
+
+def _gqa_row(cfg):
+    return cfg.kv_heads, (cfg.head_dim, cfg.head_dim)
+
+
+def _latent_row(cfg):
+    from ..ops.transformer.paged_attention import latent_row
+
+    return 1, latent_row(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+
+
+def _latent_tile(cfg):
+    from ..ops.transformer.paged_attention import SEGMENT_TILE
+
+    return SEGMENT_TILE
+
+
+def _gated_mlp_leaves(cfg, width, prefix=""):
+    H = cfg.hidden_size
+    return {prefix + "w_gate": (H, width), prefix + "w_up": (H, width),
+            prefix + "w_down": (width, H)}
+
+
+def _latent_leaves(cfg):
+    H, nh = cfg.hidden_size, cfg.num_heads
+    qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {"ln1_scale": (H,), "wq_a": (H, qr), "q_a_scale": (qr,),
+            "wq_b": (qr, nh * (nope + rope)), "wkv_a": (H, kvr + rope),
+            "kv_a_scale": (kvr,), "wkv_b": (kvr, nh * (nope + vd)),
+            "wo": (nh * vd, H), "ln2_scale": (H,)}
+
+
+def _scmoe_leaves(cfg):
+    # a sublayer's leaves are a dense layer's, under its prefix
+    dense = {**_latent_leaves(cfg),
+             **_gated_mlp_leaves(cfg, cfg.dense_mlp_dim)}
+    return {sublayer_prefix(i) + k: v for i in range(cfg.sublayers)
+            for k, v in dense.items()}
+
+
+def _mixer_leaves(cfg, kv_width, **own):
+    """A ``layer_types`` layer's: the norms, q, k (``kv_width`` wide) and v,
+    ``wo``, the head norms and the gate where the model has them, the
+    mixer's ``own``."""
+    H, hd = cfg.hidden_size, cfg.head_dim
+    qd = cfg.num_heads * hd
+    leaves = {"ln1_scale": (H,), "wq": (H, qd), "wk": (H, kv_width),
+              "wv": (H, kv_width), "wo": (qd, H), "ln2_scale": (H,)}
+    if cfg.qk_norm:
+        leaves.update(q_norm_scale=(hd,), k_norm_scale=(hd,))
+    if cfg.attn_output_gate:
+        leaves["w_ogate"] = (H, qd)
+    if cfg.post_norms:
+        leaves.update(post_attn_scale=(H,), post_mlp_scale=(H,))
+    return {**leaves, **own}
+
+
+def _gqa_leaves(cfg):
+    return _mixer_leaves(cfg, cfg.kv_heads * cfg.head_dim)
+
+
+def _linear_leaves(cfg):
+    qd = cfg.num_heads * cfg.head_dim
+    return _mixer_leaves(cfg, qd, o_norm_scale=(qd,))
+
+
+def _heads_flops(cfg, seen):
+    """q k and p v of every head over ``seen`` positions a token."""
+    return 6 * 2 * cfg.num_heads * cfg.head_dim * seen
+
+
+def _latent_flops(cfg, S):
+    return 6 * cfg.num_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                                + cfg.v_head_dim) * S
+
+
+def _sparse_flops(cfg, S):
+    # at most topk blocks are scored (the whole context under dense_len)
+    return _heads_flops(cfg, S if S <= cfg.sparse_dense_len else min(
+        S, cfg.sparse_topk * cfg.sparse_block_size))
+
+
+def _linear_slots(cfg, n, max_seqs, max_seq_len, dtype):
+    from ..ops.transformer import linear_attention as la
+
+    return la.init_state(n, max_seqs, cfg.num_heads, cfg.head_dim,
+                         cfg.head_dim)
+
+
+def _sparse_slots(cfg, n, max_seqs, max_seq_len, dtype):
+    from ..ops.transformer import sparse_attention as sa
+
+    return sa.init_keys(n, max_seqs, cfg.kv_heads,
+                        cfg.sparse_spec.max_keys(max_seq_len), cfg.head_dim,
+                        dtype)
+
+
+def _typed_kind(mixer, attn_flops, leaves=_gqa_leaves, scope=None, **kw):
+    """The record of a ``layer_types`` kind: ``_typed_layer`` around the
+    kind's ``mixer`` (lm -> its function), the whole attention sublayer under
+    ``scope`` where its device time is told apart from the other kinds';
+    GQA rows in the pool, tiles of ``linear_chunk`` rows."""
+    if scope:
+        kw["scopes"] = (scope,)
+    return LayerKind(
+        leaves=leaves, row=_gqa_row, attn_flops=attn_flops,
+        tile=lambda cfg: cfg.linear_chunk,
+        layer=lambda lm: partial(lm._typed_layer, mixer(lm), scope=scope),
+        **kw)
+
+
+#: kind (``TransformerConfig.type_runs``' second column) -> its record
+LAYER_KINDS: Dict[str, LayerKind] = {
+    # GPT-2 and LLaMA style attention (``_block``'s paged branch)
+    "full": LayerKind(
+        leaves=None, row=_gqa_row, attn_flops=_heads_flops,
+        tile=lambda cfg: 1, layer=lambda lm: lm._full_layer, wide_rows=True),
+    # latent attention (``_block_mla``), the cache a row of [c_kv | k_rope]
+    "latent": LayerKind(
+        leaves=_latent_leaves, row=_latent_row, attn_flops=_latent_flops,
+        tile=_latent_tile, scopes=("mla_proj",),
+        layer=lambda lm: partial(lm._latent_layer, lm._block_mla)),
+    # the shortcut-connected double layer (``_block_scmoe``): two latent
+    # attentions, each with its pool layer, and two dense feed-forwards
+    "scmoe": LayerKind(
+        leaves=_scmoe_leaves, row=_latent_row, pool_layers=2,
+        attn_flops=lambda cfg, S: 2 * _latent_flops(cfg, S),
+        tile=_latent_tile, scopes=("mla_proj", "dense_ffn"),
+        layer=lambda lm: partial(lm._latent_layer, lm._block_scmoe)),
+    # block-sparse GQA: a slot of compressed keys, for ``max_seq_len``
+    # tokens, beside the KV blocks
+    "sparse_attn": _typed_kind(
+        lambda lm: lm._sparse_mixer, _sparse_flops, slots=_sparse_slots,
+        counts=("sel_blocks", "ctx_blocks"), scopes=("sparse_select",)),
+    # lightning attention: a float32 state a head, the same at any length
+    # (k^T v and q S), and no KV blocks
+    "linear_attn": _typed_kind(
+        lambda lm: lm._linear_mixer,
+        lambda cfg, S: _heads_flops(cfg, cfg.head_dim),
+        leaves=_linear_leaves, pool_layers=0, slots=_linear_slots,
+        scopes=("linear_attn",)),
+    # GQA with rotary over the last ``sliding_window`` tokens, its blocks a
+    # class of their own
+    "window_attn": _typed_kind(
+        lambda lm: partial(lm._attn_mixer, True),
+        lambda cfg, S: _heads_flops(cfg, min(S, cfg.sliding_window or S)),
+        scope="window_attn", block_class="window",
+        bound=lambda cfg: cfg.sliding_window),
+    # GQA with no positional term over the whole context
+    "full_attn": _typed_kind(
+        lambda lm: partial(lm._attn_mixer, False), _heads_flops,
+        scope="full_attn"),
+}
+# a kind's scopes are declared to the tracer here, where they are opened
+tracing.layer_scopes(*(s for kind in LAYER_KINDS.values()
+                       for s in kind.scopes))
 
 
 @dataclass(frozen=True)
@@ -248,8 +447,9 @@ class TransformerConfig:
         """The model's layers as the stacked groups of its tree, in forward
         order: (params key, kind, layers, pool layers a layer). THE place the
         layer pattern is read from (the tree's groups, the pool's layer axis,
-        the slot arrays, the paged forward's loop). ``kind`` names the layer
-        function that serves the group (``TransformerLM.forward_paged``):
+        the slot arrays, the paged forward's loop) and the one place the
+        three selectors (``attention``, ``layer_kind``, ``layer_types``)
+        become kinds. ``kind`` names the group's record in ``LAYER_KINDS``:
         ``full`` attention, ``latent`` attention (a leading dense group
         before the expert layers, where the model has both), the ``scmoe``
         double layer with a pool layer for each of its attentions, or a
@@ -263,21 +463,24 @@ class TransformerConfig:
                 if kind == last:
                     runs[-1][2] += 1
                 else:
-                    runs.append([f"blocks_{len(runs)}", t, 1,
-                                 int(t in POOL_TYPES)])
+                    runs.append([f"blocks_{len(runs)}", t, 1])
                 last = kind
-            return tuple(tuple(r) for r in runs)
-        if not self.is_mla:
-            return (("blocks", "full", self.num_layers, 1),)
-        if self.layer_kind == "scmoe":
-            return (("blocks", "scmoe", self.num_layers, self.sublayers),)
-        dense = self.num_dense_layers if self.num_experts > 0 else 0
-        return tuple(g for g in (
-            ("dense_blocks", "latent", dense, 1),
-            ("blocks", "latent", self.num_layers - dense, 1)) if g[2])
+        elif not self.is_mla:
+            runs = [("blocks", "full", self.num_layers)]
+        elif self.layer_kind == "scmoe":
+            runs = [("blocks", "scmoe", self.num_layers)]
+        else:
+            dense = self.num_dense_layers if self.num_experts > 0 else 0
+            runs = [g for g in (("dense_blocks", "latent", dense),
+                                ("blocks", "latent", self.num_layers - dense))
+                    if g[2]]
+        return tuple((key, kind, n, LAYER_KINDS[kind].pool_layers)
+                     for key, kind, n in runs)
 
-    def layers_of(self, layer_type: str) -> int:
-        return sum(t == layer_type for t in self.layer_types or ())
+    @property
+    def kinds(self) -> Tuple[LayerKind, ...]:
+        """The record of each group of ``type_runs``."""
+        return tuple(LAYER_KINDS[kind] for _, kind, _, _ in self.type_runs)
 
     def layer_is_dense(self, i: int) -> bool:
         """Is layer ``i``'s feed-forward dense (no experts)?"""
@@ -294,12 +497,12 @@ class TransformerConfig:
 
     @property
     def bounded_cache(self) -> bool:
-        """The ``window_attn`` layers' KV blocks are a class of their own,
+        """Some kind's KV blocks (``window_attn``'s) are a class of their own,
         bounded by ``sliding_window``: a query sees the last W tokens, so the
         blocks behind every later query's window are freed and a sequence
         holds about ``W / block_size`` of them whatever its length. A block
         then no longer holds a token in every layer."""
-        return bool(self.layers_of("window_attn"))
+        return len(self.class_layers) > 1
 
     @property
     def class_layers(self) -> Dict[str, int]:
@@ -308,8 +511,12 @@ class TransformerConfig:
         of a sequence's length) and, where ``bounded_cache``, ``window``. A
         class is a pool of its own (its layers, its blocks), a free list and
         a table a sequence."""
-        w = self.layers_of("window_attn")
-        return {"full": self.pool_layers - w, **({"window": w} if w else {})}
+        layers = {"full": 0}
+        for (_, _, n, per), rec in zip(self.type_runs, self.kinds):
+            if per:
+                layers[rec.block_class] = layers.get(rec.block_class, 0) \
+                    + n * per
+        return layers
 
     @property
     def residual_scale(self) -> float:
@@ -332,32 +539,32 @@ class TransformerConfig:
     @property
     def cache_kinds(self) -> Dict[str, Tuple[Tuple[str, int], ...]]:
         """What each layer type keeps a sequence, the model's declaration to
-        the engine: {layer type: ((kind, bytes), ...)} with kind ``kv_blocks``
+        the engine: {layer type (``attn``: a model that names none): ((kind,
+        bytes), ...)} with kind ``kv_blocks``
         (bytes a token a layer, in the paged pool at 2 bytes a value) or
         ``state_slot`` (bytes a sequence a layer, whatever its length: a
         lightning layer's float32 state; a sparse layer's compressed keys for
         ``max_seq_len`` tokens, which lie by slot beside its KV blocks). A
         window layer's ``kv_blocks`` carry a third entry, the bound: the
         tokens behind which a block is freed (``bounded_cache``)."""
-        kv = ("kv_blocks", 2 * self.pool_heads * sum(self.kv_row))
-        kinds = dict.fromkeys(kind for _, kind, _, _ in self.type_runs)
-        if not set(kinds) & set(LAYER_TYPES):
-            return {"attn": (kv,)}
-        nh, hd = self.num_heads, self.head_dim
-        kept = {"linear_attn": (("state_slot", 4 * nh * hd * hd),),
-                "window_attn": (kv + (self.sliding_window,),),
-                "full_attn": (kv,)}
-        if "sparse_attn" in kinds:      # its sizes are checked only if used
-            keys = self.sparse_spec.max_keys(self.max_seq_len)
-            kept["sparse_attn"] = (kv, ("state_slot",
-                                        2 * keys * self.kv_heads * hd))
-        return {t: kept[t] for t in kinds}
+        declared = {}
+        for (_, kind, _, _), rec in zip(self.type_runs, self.kinds):
+            kept = ()
+            if rec.pool_layers:
+                heads, row = rec.row(self)
+                kept += (("kv_blocks", 2 * heads * sum(row))
+                         + ((rec.bound(self),) if rec.bound else ()),)
+            if rec.slots:
+                slot = jax.eval_shape(lambda: rec.slots(
+                    self, 1, 0, self.max_seq_len, jnp.bfloat16))
+                kept += (("state_slot", slot.size * slot.dtype.itemsize),)
+            declared.setdefault(kind if kind in LAYER_TYPES else "attn", kept)
+        return declared
 
     @property
     def holds_state(self) -> bool:
         """Some layer keeps a state slot a sequence beside the paged pool."""
-        return any(kind == "state_slot" for kept in self.cache_kinds.values()
-                   for kind, *_ in kept)
+        return any(rec.slots for rec in self.kinds)
 
     @property
     def head_dim(self) -> int:
@@ -368,26 +575,29 @@ class TransformerConfig:
         return self.attention == "mla"
 
     @property
+    def _pool_row(self):
+        """(heads, (key width, value width)) of the pool's rows: one for all
+        the model's kinds, whose layers are layers of one pool."""
+        return self.kinds[0].row(self)
+
+    @property
     def kv_row(self) -> Tuple[int, int]:
         """(key width, value width) of one token's row of the paged pool, a
         layer and a pool head: ``[k | v]`` of one kv head, or the latent
         ``[c_kv | k_rope]`` every head shares. THE place the pool's row width
         is read from (``init_kv_pool``, the engine's block programs)."""
-        if self.is_mla:
-            from ..ops.transformer.paged_attention import latent_row
-
-            return latent_row(self.kv_lora_rank, self.qk_rope_head_dim)
-        return (self.head_dim, self.head_dim)
+        return self._pool_row[1]
 
     @property
     def pool_heads(self) -> int:
         """Heads the paged pool keeps rows for: one under latent attention."""
-        return 1 if self.is_mla else self.kv_heads
+        return self._pool_row[0]
 
     @property
     def sublayers(self) -> int:
-        """Attentions (and dense feed-forwards) of one layer."""
-        return 2 if self.layer_kind == "scmoe" else 1
+        """Attentions (and dense feed-forwards) of one layer, each with a
+        pool layer of its own."""
+        return max(1, *(per for *_, per in self.type_runs))
 
     @property
     def pool_layers(self) -> int:
@@ -437,37 +647,34 @@ class TransformerConfig:
         return (3 if self.activation in ("swiglu", "geglu") else 2) \
             * self.hidden_size * width
 
-    @property
-    def _attn_params(self) -> int:
-        H = self.hidden_size
-        if self.is_mla:
-            nh, qr, kvr = self.num_heads, self.q_lora_rank, self.kv_lora_rank
-            nope, rope, vd = (self.qk_nope_head_dim, self.qk_rope_head_dim,
-                              self.v_head_dim)
-            return (H * qr + qr * nh * (nope + rope) + H * (kvr + rope)
-                    + kvr * nh * (nope + vd) + nh * vd * H
-                    + qr + kvr)  # the two latent norms
-        qd = self.num_heads * self.head_dim
-        kvd = self.kv_heads * self.head_dim
-        return H * qd + 2 * H * kvd + qd * H  # q, k, v, o
-
-    def _mixer_params(self, layer_type: str) -> int:
-        """Parameters of one ``layer_types`` mixer, its head norms, output
-        norm and gate included."""
-        H, qd = self.hidden_size, self.num_heads * self.head_dim
-        extra = (2 * self.head_dim if self.qk_norm else 0) \
-            + (H * qd if self.attn_output_gate else 0)
-        if layer_type in POOL_TYPES:
-            return self._attn_params + extra
-        return 4 * H * qd + qd + extra     # q, k, v, o at full width; out norm
+    def tree_shapes(self):
+        """({group: (layers, {leaf: shape a layer})}, {top leaf: shape}) of
+        the tree ``init_params`` builds, but for the GPT-2 family's: a group
+        a run of ``type_runs``, its leaves its kind's and its feed-forward's:
+        gated and dense (``dense_mlp_dim`` wide), or the experts held here,
+        their router with its selection bias and the shared expert."""
+        H, V, I, E = (self.hidden_size, self.vocab_size, self.mlp_dim,
+                      self.num_experts)
+        dense = _gated_mlp_leaves(self, self.dense_mlp_dim)
+        moe = {"moe_wg": (H, self.router_width),
+               "moe_bias": (self.router_width,), "wi": (E, H, I),
+               "w_gate": (E, H, I), "w_down": (E, I, H),
+               **(_gated_mlp_leaves(self, self.moe_shared_size, "shared_")
+                  if self.moe_shared_size else {})}
+        groups = {key: (n, {**rec.leaves(self), **(dense if is_dense else moe)})
+                  for (key, _, n, _), rec, is_dense
+                  in zip(self.type_runs, self.kinds, self.group_is_dense)}
+        top = {"wte": (V, H), "lnf_scale": (H,)}
+        if not self.tie_embeddings:
+            top["lm_head"] = (H, V)
+        return groups, top
 
     @property
-    def _held_moe_params(self) -> int:
-        """An expert layer's feed-forward: the experts held here, the router
-        with its selection bias, the shared expert."""
-        return (self._mlp_params(self.mlp_dim) * self.num_experts
-                + (self.hidden_size + 1) * self.router_width
-                + self._mlp_params(self.moe_shared_size))
+    def grouped_tree(self) -> bool:
+        """The tree is what :meth:`tree_shapes` says, every kind's leaves
+        from its record: every family but GPT-2's, whose tree
+        ``init_params`` writes out itself."""
+        return all(rec.leaves for rec in self.kinds)
 
     @property
     def num_parameters(self) -> int:
@@ -475,32 +682,22 @@ class TransformerConfig:
         (``holds_experts``) the experts held here, not the router's
         width."""
         H, L, V = self.hidden_size, self.num_layers, self.vocab_size
-        if self.layer_types is not None:
-            dense = self._mlp_params(self.dense_mlp_dim if self.num_experts
-                                     else self.mlp_dim)
-            layers = sum(
-                self._mixer_params(t) + (dense if self.layer_is_dense(i)
-                                         else self._held_moe_params)
-                for i, t in enumerate(self.layer_types)) \
-                + L * (4 if self.post_norms else 2) * H
-            return layers + V * H + (0 if self.tie_embeddings else V * H) + H
+        if self.grouped_tree:
+            groups, top = self.tree_shapes()
+            return sum(map(math.prod, top.values())) + sum(
+                n * sum(map(math.prod, leaves.values()))
+                for n, leaves in groups.values())
+        qd, kvd = self.num_heads * self.head_dim, self.kv_heads * self.head_dim
+        attn = H * qd + 2 * H * kvd + qd * H  # q, k, v, o
         n_ln = 1 if (self.parallel_block and self.parallel_shared_ln) else 2
         norms = n_ln * (1 if self.norm == "rmsnorm" else 2) * H
         mlp = self._mlp_params(self.mlp_dim)
-        dense_layer = self._attn_params + norms + self._mlp_params(
-            self.dense_mlp_dim)
+        dense_layer = attn + norms + self._mlp_params(self.dense_mlp_dim)
         if self.num_experts > 0:
             moe = mlp * self.num_experts + H * self.router_width  # + router
-            if self.holds_experts:
-                moe += self.router_width  # the selection bias
-            moe += self._mlp_params(self.moe_shared_size)
             if self.moe_use_residual:
                 moe += mlp + 2 * H + 2  # residual MLP + coefficient
-            moe_layer = self._attn_params + norms + moe
-            if self.layer_kind == "scmoe":
-                # both sublayers' attention, norms and dense feed-forward
-                # beside the one expert layer
-                moe_layer = self.sublayers * dense_layer + moe
+            moe_layer = attn + norms + moe
         else:
             moe_layer = dense_layer
         n_moe = self.num_moe_layers if self.num_experts > 0 else L
@@ -524,27 +721,9 @@ class TransformerConfig:
         """Model FLOPs per token for one fwd+bwd (6·N_active + attention term:
         q·k and p·v over the heads' own widths)."""
         S = seq_len or self.max_seq_len
-        n = self.num_active_parameters
-        if self.layer_types is not None:
-            # a sparse layer scores at most topk blocks (the whole context
-            # under dense_len); a linear layer's state costs the same at any
-            # length: k^T v and q S, a head
-            nh, hd = self.num_heads, self.head_dim
-            ctx = S if S <= self.sparse_dense_len else min(
-                S, self.sparse_topk * self.sparse_block_size)
-            seen = (self.layers_of("sparse_attn") * ctx
-                    + self.layers_of("full_attn") * S
-                    + self.layers_of("window_attn")
-                    * min(S, self.sliding_window or S))
-            return (6 * n + 6 * 2 * nh * hd * seen
-                    + 6 * self.layers_of("linear_attn") * 2 * nh * hd * hd)
-        if self.is_mla:
-            per_pos = self.num_heads * (self.qk_nope_head_dim
-                                        + self.qk_rope_head_dim + self.v_head_dim)
-        else:
-            per_pos = 2 * self.num_heads * self.head_dim
-        attn_flops = 6 * self.pool_layers * per_pos * S  # fwd+bwd qk^T + av
-        return 6 * n + attn_flops
+        return 6 * self.num_active_parameters + sum(
+            n * rec.attn_flops(self, S)
+            for (_, _, n, _), rec in zip(self.type_runs, self.kinds))
 
 
 # ----------------------------------------------------------------------------
@@ -835,10 +1014,8 @@ class TransformerLM:
     # ------------------------------------------------------------------
     def init_params(self, rng) -> Dict[str, Any]:
         cfg = self.config
-        if cfg.is_mla:
-            return self._init_params_mla(rng)
-        if cfg.layer_types is not None:
-            return self._init_params_typed(rng)
+        if cfg.grouped_tree:
+            return self._init_grouped(rng)
         H, L, V, I = cfg.hidden_size, cfg.num_layers, cfg.vocab_size, cfg.mlp_dim
         nh, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         dt = cfg.param_dtype
@@ -929,140 +1106,26 @@ class TransformerLM:
                 params["lm_head_bias"] = jnp.zeros((V,), dt)
         return params
 
-    def _typed_shapes(self):
-        """As :meth:`_mla_shapes`, of a ``layer_types`` model: one group a
-        run of equal types (``TransformerConfig.type_runs``)."""
-        cfg = self.config
-        H, V, I, E = cfg.hidden_size, cfg.vocab_size, cfg.mlp_dim, \
-            cfg.num_experts
-        qd, kvd, hd = cfg.num_heads * cfg.head_dim, \
-            cfg.kv_heads * cfg.head_dim, cfg.head_dim
-        shared = {"ln1_scale": (H,), "wq": (H, qd), "wo": (qd, H),
-                  "ln2_scale": (H,)}
-        extra = {}
-        if cfg.qk_norm:
-            extra.update(q_norm_scale=(hd,), k_norm_scale=(hd,))
-        if cfg.attn_output_gate:
-            extra["w_ogate"] = (H, qd)
-        if cfg.post_norms:
-            extra.update(post_attn_scale=(H,), post_mlp_scale=(H,))
-
-        def mlp(width, prefix=""):
-            return {prefix + "w_gate": (H, width), prefix + "w_up": (H, width),
-                    prefix + "w_down": (width, H)}
-
-        dense = mlp(cfg.dense_mlp_dim if E else I)
-        moe = {"moe_wg": (H, cfg.router_width),
-               "moe_bias": (cfg.router_width,), "wi": (E, H, I),
-               "w_gate": (E, H, I), "w_down": (E, I, H),
-               **(mlp(cfg.moe_shared_size, "shared_")
-                  if cfg.moe_shared_size else {})}
-        gqa = {"wk": (H, kvd), "wv": (H, kvd)}
-        mixer = {"sparse_attn": gqa, "window_attn": gqa, "full_attn": gqa,
-                 "linear_attn": {"wk": (H, qd), "wv": (H, qd),
-                                 "o_norm_scale": (qd,)}}
-        groups = {key: (n, {**shared, **(dense if is_dense else moe), **extra,
-                            **mixer[kind]})
-                  for (key, kind, n, _), is_dense
-                  in zip(cfg.type_runs, cfg.group_is_dense)}
-        top = {"wte": (V, H), "lnf_scale": (H,)}
-        if not cfg.tie_embeddings:
-            top["lm_head"] = (H, V)
-        return groups, top
-
-    def _init_params_typed(self, rng) -> Dict[str, Any]:
-        """{leaf, blocks_0: {leaf}, blocks_1: {leaf}, ...}: one stacked
-        group a run of equal ``layer_types``."""
+    def _init_grouped(self, rng) -> Dict[str, Any]:
+        """{leaf, group: {leaf}, ...} as ``TransformerConfig.tree_shapes``
+        says it, a stacked group a run of ``type_runs``. Residual projections
+        are ``wo`` / ``w_down`` (a sublayer's too), norm scales ``*_scale``;
+        each drawn leaf takes the next key, in the tree's order."""
         cfg = self.config
         if (cfg.activation != "swiglu" or cfg.norm != "rmsnorm"
                 or cfg.pos_embedding != "rope"
                 or (cfg.num_experts and not cfg.holds_experts)):
-            raise ValueError("layer_types models are rmsnorm + swiglu + rope, "
-                             "with a dense feed-forward or held experts "
-                             "(moe_router 'group_limited' | 'softmax_topk')")
+            raise ValueError("attention='mla' and layer_types models are "
+                             "rmsnorm + swiglu + rope, with a dense "
+                             "feed-forward or held experts (moe_router "
+                             "'group_limited' | 'softmax_topk')")
         dt = cfg.param_dtype
-        groups, top = self._typed_shapes()
+        groups, top = cfg.tree_shapes()
         init = jax.nn.initializers.normal(0.02)
         resid_init = jax.nn.initializers.normal(
             0.02 / np.sqrt(2 * cfg.num_layers))
-        keys = iter(jax.random.split(rng, 16 * (len(groups) + 1)))
-
-        def leaf(name, shape):
-            if name.endswith("_scale"):
-                return jnp.ones(shape, dt)
-            if name == "moe_bias":
-                return jnp.zeros(shape, dt)
-            return (resid_init if name in ("wo", "w_down", "shared_w_down")
-                    else init)(next(keys), shape, dt)
-
-        params = {k: leaf(k, shape) for k, shape in top.items()}
-        for group, (n, leaves) in groups.items():
-            params[group] = {k: leaf(k, (n,) + shape)
-                             for k, shape in leaves.items()}
-        return params
-
-    def _mla_shapes(self):
-        """{group: (layers, {leaf: per-layer shape})} and {top leaf: shape}
-        of a latent-attention model: ``dense_blocks`` (the leading dense
-        layers) before ``blocks`` (expert layers, or all layers of a model
-        without experts)."""
-        cfg = self.config
-        H, V, nh = cfg.hidden_size, cfg.vocab_size, cfg.num_heads
-        qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
-        nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        attn = {
-            "ln1_scale": (H,), "wq_a": (H, qr), "q_a_scale": (qr,),
-            "wq_b": (qr, nh * (nope + rope)), "wkv_a": (H, kvr + rope),
-            "kv_a_scale": (kvr,), "wkv_b": (kvr, nh * (nope + vd)),
-            "wo": (nh * vd, H), "ln2_scale": (H,),
-        }
-
-        def mlp(width):
-            return {"w_gate": (H, width), "w_up": (H, width),
-                    "w_down": (width, H)}
-
-        E, I = cfg.num_experts, cfg.mlp_dim
-        layers = {key: n for key, _, n, _ in cfg.type_runs}
-        groups = {}
-        if "dense_blocks" in layers:
-            groups["dense_blocks"] = (layers["dense_blocks"],
-                                      {**attn, **mlp(cfg.dense_mlp_dim)})
-        if E > 0:
-            moe = {"moe_wg": (H, cfg.router_width),
-                   "moe_bias": (cfg.router_width,),
-                   "wi": (E, H, I), "w_gate": (E, H, I), "w_down": (E, I, H)}
-            if cfg.moe_shared_size:
-                moe.update({"shared_" + k: v
-                            for k, v in mlp(cfg.moe_shared_size).items()})
-            if cfg.layer_kind == "scmoe":
-                # a sublayer's leaves are a dense layer's, under its prefix
-                dense = {**attn, **mlp(cfg.dense_mlp_dim)}
-                attn = {sublayer_prefix(i) + k: v for i in range(cfg.sublayers)
-                        for k, v in dense.items()}
-            groups["blocks"] = (layers["blocks"], {**attn, **moe})
-        else:
-            groups["blocks"] = (layers["blocks"], {**attn, **mlp(cfg.dense_mlp_dim)})
-        top = {"wte": (V, H), "lnf_scale": (H,)}
-        if not cfg.tie_embeddings:
-            top["lm_head"] = (H, V)
-        return groups, top
-
-    def _init_params_mla(self, rng) -> Dict[str, Any]:
-        """{leaf, dense_blocks: {leaf}, blocks: {leaf}}: two stacked groups,
-        the dense one first. Residual projections are ``wo`` / ``w_down``,
-        norm scales ``*_scale``."""
-        cfg = self.config
-        if cfg.activation != "swiglu" or cfg.norm != "rmsnorm":
-            raise ValueError("attention='mla' models are rmsnorm + swiglu")
-        if cfg.num_experts > 0 and not cfg.holds_experts:
-            raise ValueError("attention='mla' models route with moe_router="
-                             "'group_limited' or 'softmax_topk'")
-        dt = cfg.param_dtype
-        groups, top = self._mla_shapes()
-        init = jax.nn.initializers.normal(0.02)
-        resid_init = jax.nn.initializers.normal(
-            0.02 / np.sqrt(2 * cfg.num_layers))
-        keys = iter(jax.random.split(rng, 64 * cfg.sublayers))
+        keys = iter(jax.random.split(rng, len(top) + sum(
+            len(leaves) for _, leaves in groups.values())))
 
         def leaf(name, shape):
             if name.endswith("_scale"):
@@ -1089,8 +1152,8 @@ class TransformerLM:
         """
         cfg = self.config
         m = self.model_axis
-        if cfg.is_mla:
-            return self._tp_specs_mla()
+        if cfg.grouped_tree:
+            return self._tp_specs_grouped()
         single_ln = cfg.parallel_block and cfg.parallel_shared_ln
         specs: Dict[str, Any] = {
             "wte": P(m, None),
@@ -1163,22 +1226,25 @@ class TransformerLM:
                 specs["lm_head_bias"] = P(m)
         return specs
 
-    def _tp_specs_mla(self) -> Dict[str, Any]:
-        """Latent attention: the per-head up-projections ``wq_b`` / ``wkv_b``
-        column-parallel and ``wo`` row-parallel over ``model``; the low-rank
-        down-projections, their norms and the router replicated; held experts
+    def _tp_specs_grouped(self) -> Dict[str, Any]:
+        """A grouped tree's (``TransformerConfig.tree_shapes``), by leaf
+        name: the products split into heads (``wq`` / ``wk`` / ``wv``, the
+        gate's ``w_ogate``, the latent up-projections ``wq_b`` / ``wkv_b``)
+        column-parallel and ``wo`` row-parallel over ``model``; the latent
+        down-projections, the norms and the router replicated; held experts
         over ``expert``, their widths (and the shared expert's, and the dense
         MLP's) over ``model``."""
         m, e = self.model_axis, "expert"
         col, row = P(None, None, m), P(None, m, None)
         by_name = {
+            "wq": col, "wk": col, "wv": col, "w_ogate": col,
             "wq_b": col, "wkv_b": col, "wo": row,
             "w_gate": col, "w_up": col, "w_down": row,
             "shared_w_gate": col, "shared_w_up": col, "shared_w_down": row,
         }
         expert = {"wi": P(None, e, None, m), "w_gate": P(None, e, None, m),
                   "w_down": P(None, e, m, None)}
-        groups, top = self._mla_shapes()
+        groups, top = self.config.tree_shapes()
         specs: Dict[str, Any] = {
             "wte": P(m, None), "lnf_scale": P(None), "lm_head": P(None, m)}
         specs = {k: specs[k] for k in top}
@@ -1231,8 +1297,10 @@ class TransformerLM:
                 raise NotImplementedError(
                     "attention='mla' has the full-sequence and the paged "
                     "paths only: no slot cache, padding mask or dropout")
-            y, pool, _ = self._block_mla(x, blk, positions=positions,
-                                         paged=paged, step=step)
+            block = self._block_scmoe if cfg.layer_kind == "scmoe" \
+                else self._block_mla
+            y, pool, _ = block(x, blk, positions=positions, paged=paged,
+                               step=step, experts=None)
             return y, pool, jnp.zeros((), jnp.float32)
         nh, kvh, hd = cfg.num_heads, cfg.kv_heads, cfg.head_dim
         B, S, H = x.shape
@@ -1439,10 +1507,9 @@ class TransformerLM:
 
     def _block_mla(self, x, blk, *, positions, paged=None, step=None,
                    experts=None):
-        """One latent-attention block on (B, S, H): a dense layer, an expert
-        layer where ``blk`` holds a router, or a shortcut-connected double
-        layer (``layer_kind="scmoe"``, :meth:`_block_scmoe`). Returns (y, new
-        pool, the expert layer's counts (``held_experts_ffn``) or None).
+        """One latent-attention block on (B, S, H): a dense layer, or an
+        expert layer where ``blk`` holds a router. Returns (y, new pool, the
+        expert layer's counts (``held_experts_ffn``) or None).
 
         Full sequence (``paged`` None): the latent is up-projected through
         ``wkv_b`` and attended to causally, un-absorbed. ``paged`` (pool,
@@ -1453,9 +1520,6 @@ class TransformerLM:
         lies, a tile of the ``step`` (:class:`PagedStep`) streaming its
         sequence's latent once. ``experts``: (stacked expert leaves, layer of
         the group) when the caller kept them out of ``blk``."""
-        if self.config.layer_kind == "scmoe":
-            return self._block_scmoe(x, blk, positions=positions, paged=paged,
-                                     step=step, experts=experts)
         blk = _dequant_woq(blk, x.dtype)
         attn_out, new_pool = self._mla_attention(
             x, blk, positions=positions, paged=paged, step=step)
@@ -2075,21 +2139,20 @@ class TransformerLM:
     def segment_tile(self) -> int:
         """Rows of one chunk-segment tile of the paged program (1: the model
         takes a prefill chunk as one-token rows like any other)."""
-        from ..ops.transformer.paged_attention import SEGMENT_TILE
-
-        if self.config.layer_types is not None:
-            return self.config.linear_chunk
-        return SEGMENT_TILE if self.config.is_mla else 1
+        cfg = self.config
+        # one for all the model's kinds: a step is tiled once
+        (tile,) = {rec.tile(cfg) for rec in cfg.kinds}
+        return tile
 
     @property
     def step_counts(self) -> Tuple[str, ...]:
         """Names of the int32 counts ``forward_paged(moe_stats=True)`` returns
         behind its logits: they become attrs of ``engine.dispatch``."""
         cfg = self.config
-        if cfg.layer_types is not None and "sparse_attn" in cfg.layer_types:
-            return ("sel_blocks", "ctx_blocks")
-        if not cfg.holds_experts:
-            return ()
+        own = tuple(dict.fromkeys(
+            name for rec in cfg.kinds for name in rec.counts))
+        if own or not cfg.holds_experts:
+            return own
         return ("moe_rows", "moe_rows_max") + (
             ("moe_zero_picks",) if cfg.moe_zero_experts else ())
 
@@ -2103,18 +2166,10 @@ class TransformerLM:
         selector's compressed keys (layers, 1 + max_seqs, kv heads, keys,
         hd) (ops/transformer/sparse_attention.py). Empty for a model whose
         every layer keeps KV blocks and nothing else."""
-        from ..ops.transformer import linear_attention as la
-        from ..ops.transformer import sparse_attention as sa
-
         cfg = self.config
-        nh, hd = cfg.num_heads, cfg.head_dim
-        make = {
-            "linear_attn": lambda n: la.init_state(n, max_seqs, nh, hd, hd),
-            "sparse_attn": lambda n: sa.init_keys(
-                n, max_seqs, cfg.kv_heads,
-                cfg.sparse_spec.max_keys(max_seq_len), hd, dtype)}
-        return {key: make[kind](n) for key, kind, n, _ in cfg.type_runs
-                if kind in make}
+        return {key: rec.slots(cfg, n, max_seqs, max_seq_len, dtype)
+                for (key, _, n, _), rec in zip(cfg.type_runs, cfg.kinds)
+                if rec.slots}
 
     def forward_paged(self, params, input_ids, kv_pool, tables, starts,
                       logit_rows=None, seg_from=None, moe_stats=False,
@@ -2157,10 +2212,10 @@ class TransformerLM:
         (``pool``) if the group's layers keep KV blocks and the group's slot
         array (``own``) if they keep a state slot, ``layer`` counts the
         group's layers and ``pool_layer`` the pool's. A new kind is such a
-        function, its ``cache_kinds`` and its line in ``type_runs``."""
+        function and its record in ``LAYER_KINDS``."""
         cfg = self.config
         S = input_ids.shape[1]
-        if S != 1 and (cfg.is_mla or cfg.layer_types is not None):
+        if S != 1 and not all(rec.wide_rows for rec in cfg.kinds):
             raise ValueError("the paged path of a latent-attention or a "
                              "layer_types model takes one-token rows")
         if cfg.holds_state and (state is None or row_slots is None):
@@ -2179,16 +2234,6 @@ class TransformerLM:
         with jax.named_scope("embed"):
             x = self._embed(params, input_ids, step.positions,
                             next(iter(pools.values())).dtype)
-        kinds = {"full": self._full_layer, "latent": self._latent_layer,
-                 "scmoe": self._latent_layer,
-                 "sparse_attn": partial(self._typed_layer, self._sparse_mixer),
-                 "linear_attn": partial(self._typed_layer, self._linear_mixer),
-                 "window_attn": partial(
-                     self._typed_layer, partial(self._attn_mixer, True),
-                     scope="window_attn"),
-                 "full_attn": partial(
-                     self._typed_layer, partial(self._attn_mixer, False),
-                     scope="full_attn")}
         state = dict(state or {})
         names = self.step_counts
         tally = jnp.zeros((len(names),), jnp.int32) if names else None
@@ -2203,7 +2248,7 @@ class TransformerLM:
         # leaves they slice (a layer's matrix materialised out of the stack
         # and relaid reads here, not under the layer)
         with jax.named_scope("kv_carry"):
-            for key, kind, n, per in cfg.type_runs:
+            for (key, _, n, per), rec in zip(cfg.type_runs, cfg.kinds):
                 leaves = params[key]
                 # held experts stay out of the scanned leaves: the grouped
                 # product indexes them by (layer, expert) where they lie, so
@@ -2216,11 +2261,11 @@ class TransformerLM:
                     leaves = {k: v for k, v in leaves.items()
                               if k not in EXPERT_LEAVES}
                 caches = {"own": state[key]} if key in state else {}
-                cls = "window" if kind == "window_attn" else "full"
+                cls = rec.block_class
                 if per:
                     caches["pool"] = pools[cls]
 
-                def body(carry, blk, fn=kinds[kind], base=pool_layer[cls],
+                def body(carry, blk, fn=rec.layer(self), base=pool_layer[cls],
                          per=per, experts=experts):
                     h, caches, l, tally = carry
                     y, caches, counts = fn(h, blk, caches, l, base + per * l,
@@ -2265,9 +2310,11 @@ class TransformerLM:
             paged=(caches["pool"], pool_layer, step.tables), step=step)
         return y, {"pool": pool}, None
 
-    def _latent_layer(self, h, blk, caches, layer, pool_layer, step, experts):
-        """The ``latent`` and ``scmoe`` kinds: :meth:`_block_mla`."""
-        y, pool, counts = self._block_mla(
+    def _latent_layer(self, block, h, blk, caches, layer, pool_layer, step,
+                      experts):
+        """The ``latent`` and ``scmoe`` kinds, around their ``block``
+        (:meth:`_block_mla`, :meth:`_block_scmoe`)."""
+        y, pool, counts = block(
             h, blk, positions=step.positions, step=step,
             paged=(caches["pool"], pool_layer, step.tables),
             experts=None if experts is None else (experts, layer))

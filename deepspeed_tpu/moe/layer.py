@@ -19,8 +19,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..utils import tracing
 from .sharded_moe import (group_limited_gating, softmax_topk_gating,
                           topk_gating)
+
+# the held experts' parts, told apart on the device: routing, the grouped
+# products, the shared expert, the identity experts
+tracing.layer_scopes("moe_route", "moe_experts", "moe_shared", "moe_zero")
 
 
 def _constraint(x, spec):

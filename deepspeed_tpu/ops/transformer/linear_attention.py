@@ -53,6 +53,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...utils import tracing
 
+# a lightning layer's recurrence: decay, update, read-out
+tracing.layer_scopes("linear_attn")
+
 _HI = jax.lax.Precision.HIGHEST
 
 #: heads of one cell of :func:`linear_decode`: a state block of 8 x 128 x 128

@@ -37,7 +37,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...utils import tracing
 from . import paged_attention as pa
+
+# a sparse layer's selector: compressed keys, scores, top-k, compacted tables
+tracing.layer_scopes("sparse_select")
 
 NEG = -1e30
 

@@ -478,17 +478,17 @@ _scope_cache: Dict[tuple, Dict[str, str]] = {}
 #: ``jax.named_scope`` names the program uses, by what they classify as
 MODEL_SCOPES = ("embed", "attn", "mlp", "lm_head_loss")
 SERVE_SCOPES = ("kv_write", "paged_attn", "sample")
-#: parts of a layer told apart inside a program without gradients: latent
-#: attention's projections, the expert layer's routing, grouped products,
-#: shared expert and identity experts, a double layer's dense feed-forwards,
-#: a lightning layer's recurrence (decay, update, read-out, output norm), a
-#: sparse layer's selector (compressed keys, scores, top-k, compacted tables),
-#: the attention sublayer of a window and of a full GQA layer (its norms,
-#: projections, rotary, gate and ``wo``: the kernel and the gather read
-#: ``paged_attn``, the rows' write ``kv_write``, as in every served model)
-LAYER_SCOPES = ("mla_proj", "moe_route", "moe_experts", "moe_shared",
-                "moe_zero", "dense_ffn", "linear_attn", "sparse_select",
-                "window_attn", "full_attn")
+#: parts of a layer told apart inside a program without gradients, as declared
+_layer_scopes: List[str] = []
+
+
+def layer_scopes(*names: str) -> None:
+    """Declare ``names`` as ``jax.named_scope``s that :func:`classify` gives
+    back as a layer's own part: called once, at import, by the module that
+    opens them. Of two on one name's stack the one declared first decides."""
+    for name in names:
+        if name not in _layer_scopes:
+            _layer_scopes.append(name)
 
 
 def _abstract(x):
@@ -515,11 +515,9 @@ def classify(op_name: str) -> str:
     transpose(jvp(attn))/dot_general``): ``optimizer``; ``remat`` (the
     forward recomputed under the backward); ``bwd``; ``fwd`` (the
     differentiated forward); the serving scopes ``kv_write``, ``paged_attn``,
-    ``sample``; a layer's own parts ``mla_proj``, ``moe_route``,
-    ``moe_experts``, ``moe_shared``, ``moe_zero``, ``dense_ffn``,
-    ``linear_attn``, ``sparse_select``, ``window_attn``, ``full_attn`` and
-    else
-    ``model`` (a model scope in a program without gradients);
+    ``sample``; a layer's own part, by the name its module declared
+    (:func:`layer_scopes`), and else ``model`` (a model scope in a program
+    without gradients);
     ``kv_carry`` (the paged program's layer scan itself: the ops that belong
     to the loop and to no layer scope, which is what it does to the arrays it
     carries, the stacked pool first, and to the stacked leaves it slices a
@@ -539,7 +537,7 @@ def classify(op_name: str) -> str:
         return "bwd"
     if "jvp" in parts:
         return "fwd"
-    for s in LAYER_SCOPES:
+    for s in _layer_scopes:
         if s in parts:
             return s
     if any(s in parts for s in MODEL_SCOPES):

@@ -1,0 +1,198 @@
+"""A layer kind is described once: its record in ``models/transformer.py``
+``LAYER_KINDS``, which the tree, the specs, the counts, the cache declaration,
+the paged forward and the tracer read.
+
+For each of the seven kinds, a model of that kind alone at tiny widths:
+(a) ``num_parameters`` is the size of the tree ``init_params`` builds;
+(b) ``tp_specs`` has that tree's structure, leaf for leaf, a spec entry an
+axis (before PR 64 a ``layer_types`` model got the GPT-2 tree's specs under
+the key ``blocks`` beside its own ``blocks_0``, ...);
+(c) the ``jax.named_scope`` names on the name stacks of its traced decode
+round that are no model or serving scope are the ones its record declares
+(and the held experts', which ``moe/layer.py`` declares), and
+``tracing.classify`` gives each back.
+And (d) what the five serving configurations' layer patterns declare to the
+engine, as literals read off the parent commit.
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.analysis.program_audit import _iter_eqns
+from deepspeed_tpu.models.transformer import (LAYER_KINDS, TransformerConfig,
+                                              TransformerLM)
+from deepspeed_tpu.utils import tracing
+from tests.unit.test_block_classes import tiny as window_pattern
+from tests.unit.test_served_weight_reads import (double_layers, gpt2_family,
+                                                 latent, typed)
+
+BLOCK, NUM_BLOCKS, MAXB, ROWS = 16, 24, 4, 4
+#: declared by ``moe/layer.py``: the feed-forward's, whatever the kind
+MOE_SCOPES = {"moe_route", "moe_experts", "moe_shared", "moe_zero"}
+
+
+def of_types(*types):
+    """A ``layer_types`` model of ``types`` around held experts, the first
+    layer's feed-forward dense."""
+    return window_pattern(layer_types=types, num_layers=len(types)).config
+
+
+#: kind -> a model of that kind alone (``full``: the LLaMA style, whose
+#: tree has no leaf ``num_parameters`` leaves out, as GPT-2's biases are)
+ALONE = {
+    "full": lambda: TransformerConfig(
+        vocab_size=256, hidden_size=64, num_layers=2, num_heads=4,
+        num_kv_heads=2, intermediate_size=96, max_seq_len=64,
+        pos_embedding="rope", norm="rmsnorm", activation="swiglu",
+        tie_embeddings=False),
+    "latent": latent,
+    "scmoe": double_layers,
+    "sparse_attn": lambda: TransformerConfig(**{
+        **typed().__dict__, "num_layers": 2,
+        "layer_types": ("sparse_attn", "sparse_attn")}),
+    "linear_attn": lambda: TransformerConfig(**{
+        **typed().__dict__, "num_layers": 2,
+        "layer_types": ("linear_attn", "linear_attn")}),
+    "window_attn": lambda: of_types("window_attn", "window_attn"),
+    "full_attn": lambda: of_types("full_attn", "full_attn"),
+}
+
+
+def test_every_kind_has_its_case():
+    assert sorted(ALONE) == sorted(LAYER_KINDS)
+    for kind, make in ALONE.items():
+        assert {k for _, k, _, _ in make().type_runs} == {kind}
+
+
+@pytest.mark.parametrize("kind", sorted(ALONE))
+def test_num_parameters_is_the_size_of_the_tree(kind):
+    cfg = ALONE[kind]()
+    tree = jax.eval_shape(TransformerLM(cfg).init_params,
+                          jax.random.PRNGKey(0))
+    assert cfg.num_parameters == sum(
+        math.prod(leaf.shape) for leaf in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("kind", sorted(ALONE))
+def test_tp_specs_have_the_trees_structure(kind):
+    model = TransformerLM(ALONE[kind]())
+    tree = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    specs = model.tp_specs
+
+    def is_spec(x):
+        return isinstance(x, P)
+
+    assert jax.tree.structure(specs, is_leaf=is_spec) \
+        == jax.tree.structure(tree)
+    ranks = jax.tree.map(lambda spec, leaf: (len(spec), leaf.ndim), specs,
+                         tree, is_leaf=is_spec)
+    assert all(a == b for a, b in jax.tree.leaves(
+        ranks, is_leaf=lambda x: isinstance(x, tuple)))
+
+
+def decode_round(model):
+    """Abstract arguments of one ``forward_paged`` decode round: ``ROWS``
+    one-token rows, each a sequence of its own."""
+    cfg = model.config
+    blocks = NUM_BLOCKS if not cfg.bounded_cache \
+        else dict.fromkeys(cfg.class_layers, NUM_BLOCKS)
+    tables = np.stack([1 + MAXB * r + np.arange(MAXB) for r in range(ROWS)])
+    starts = 5 + 7 * np.arange(ROWS)
+    kw = dict(rows_apart=True)
+    if cfg.holds_state:
+        kw.update(state=model.init_state_cache(ROWS, cfg.max_seq_len),
+                  row_slots=jnp.arange(1, ROWS + 1, dtype=jnp.int32))
+    if cfg.bounded_cache:
+        kw["window"] = (jnp.asarray(tables, jnp.int32),
+                        jnp.zeros((ROWS,), jnp.int32))
+    args = (jax.eval_shape(model.init_params, jax.random.PRNGKey(0)),
+            jnp.zeros((ROWS, 1), jnp.int32),
+            jax.eval_shape(lambda: model.init_kv_pool(blocks, BLOCK)),
+            jnp.asarray(tables, jnp.int32), jnp.asarray(starts, jnp.int32))
+    return args, kw
+
+
+@pytest.mark.parametrize("kind", sorted(ALONE))
+def test_the_rounds_scopes_are_the_kinds_own(kind):
+    model = TransformerLM(ALONE[kind]())
+    args, kw = decode_round(model)
+    closed = jax.make_jaxpr(lambda *a: model.forward_paged(*a, **kw))(*args)
+    stacks = {str(eqn.source_info.name_stack)
+              for eqn in _iter_eqns(closed.jaxpr)}
+    known = {"kv_carry", *tracing.MODEL_SCOPES, *tracing.SERVE_SCOPES}
+    # an einsum opens a scope named by its subscripts: no identifier
+    own = {name for stack in stacks for name in stack.split("/")
+           if name.isidentifier()} - known
+    assert own - MOE_SCOPES == set(LAYER_KINDS[kind].scopes)
+    assert bool(own & MOE_SCOPES) == model.config.holds_experts
+    # the tracer gives each back, from the module that opens it
+    given = {tracing.classify(
+        f"jit(ragged)/kv_carry/while/body/closed_call/{stack}/dot_general")
+        for stack in stacks}
+    assert own <= given
+    assert given <= own | {"model", "kv_carry", "unscoped",
+                           *tracing.SERVE_SCOPES}
+
+
+#: the five serving configurations' layer patterns at tiny widths -> what
+#: they declare, read off commit e6c1671 (PR 63)
+DECLARED = {
+    "gpt2-medium": (gpt2_family, dict(
+        type_runs=(("blocks", "full", 2, 1),),
+        cache_kinds={"attn": (("kv_blocks", 512),)},
+        pool_layers=2, class_layers={"full": 2}, kv_row=(64, 64),
+        pool_heads=2, segment_tile=1, step_counts=())),
+    "gigachat3.1-702b-a36b": (latent, dict(
+        type_runs=(("dense_blocks", "latent", 1, 1),
+                   ("blocks", "latent", 2, 1)),
+        cache_kinds={"attn": (("kv_blocks", 256),)},
+        pool_layers=3, class_layers={"full": 3}, kv_row=(32, 96),
+        pool_heads=1, segment_tile=16,
+        step_counts=("moe_rows", "moe_rows_max"))),
+    "longcat-flash-chat": (double_layers, dict(
+        type_runs=(("blocks", "scmoe", 2, 2),),
+        cache_kinds={"attn": (("kv_blocks", 256),)},
+        pool_layers=4, class_layers={"full": 4}, kv_row=(32, 96),
+        pool_heads=1, segment_tile=16,
+        step_counts=("moe_rows", "moe_rows_max", "moe_zero_picks"))),
+    "minicpm-sala": (typed, dict(
+        type_runs=(("blocks_0", "sparse_attn", 1, 1),
+                   ("blocks_1", "linear_attn", 2, 0),
+                   ("blocks_2", "sparse_attn", 1, 1)),
+        cache_kinds={"sparse_attn": (("kv_blocks", 128),
+                                     ("state_slot", 2048)),
+                     "linear_attn": (("state_slot", 8192),)},
+        pool_layers=2, class_layers={"full": 2}, kv_row=(16, 16),
+        pool_heads=2, segment_tile=16,
+        step_counts=("sel_blocks", "ctx_blocks"))),
+    "trinity-mini": (lambda: window_pattern().config, dict(
+        type_runs=(("blocks_0", "window_attn", 1, 1),
+                   ("blocks_1", "window_attn", 1, 1),
+                   ("blocks_2", "full_attn", 1, 1),
+                   ("blocks_3", "window_attn", 1, 1)),
+        cache_kinds={"window_attn": (("kv_blocks", 128, 32),),
+                     "full_attn": (("kv_blocks", 128),)},
+        pool_layers=4, class_layers={"full": 1, "window": 3},
+        kv_row=(16, 16), pool_heads=2, segment_tile=16,
+        step_counts=("moe_rows", "moe_rows_max"))),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DECLARED))
+def test_a_pattern_declares_what_it_did(name):
+    make, declared = DECLARED[name]
+    cfg = make()
+    model = TransformerLM(cfg)
+    found = {k: getattr(model if k in ("segment_tile", "step_counts")
+                        else cfg, k) for k in declared}
+    assert found == declared
+    assert list(found["cache_kinds"]) == list(declared["cache_kinds"])
+    assert cfg.bounded_cache == ("window" in declared["class_layers"])
+    assert cfg.holds_state == any(
+        kept == "state_slot" for kinds in declared["cache_kinds"].values()
+        for kept, *_ in kinds)

@@ -13,6 +13,7 @@ import pytest
 from deepspeed_tpu.ops.transformer.paged_attention import paged_decode_attention
 
 
+@jax.jit
 def oracle(q, kp, vp, tables, lens):
     kvh, NB, BS, hd = kp.shape
     B, MAXB = tables.shape
@@ -184,9 +185,11 @@ def test_paged_decode_long_context_8k():
 # ----------------------------------------------------------------------
 # one cell a sequence over its kv heads; a row with lens 0 is dead
 # ----------------------------------------------------------------------
+@jax.jit
 def plain_attention(q, pool, layer, tables, lens):
     """``gather_context`` + plain float32 attention: what the kernel must
-    give for every row, and zeros for a row with ``lens`` 0."""
+    give for every row, and zeros for a row with ``lens`` 0. (Jitted: eagerly
+    each of its operations is compiled by itself, a second a call.)"""
     from deepspeed_tpu.ops.transformer import paged_attention as pa
 
     gk, gv = pa.gather_context(pool, layer, tables)   # (B, T, kvh, hd)
@@ -220,10 +223,10 @@ def _case(kvh, nh, hd, BS, lens, L=2, MAXB=3, dead=0, seed=3, at=None):
     rng = np.random.default_rng(seed)
     B = len(lens) + dead
     NB = 1 + len(lens) * MAXB
-    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
-    pool = jax.random.normal(
-        ks[0], pa.init_pool(L, kvh, NB, BS, hd, jnp.float32).shape)
-    q = jax.random.normal(ks[1], (B, nh, hd))
+    # drawn on the host: ``jax.random`` compiles a program a shape
+    pool = jnp.asarray(rng.standard_normal(
+        pa.init_pool(L, kvh, NB, BS, hd, jnp.float32).shape), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, nh, hd)), jnp.float32)
     live = np.sort(rng.permutation(B)[:len(lens)] if at is None else at)
     row_lens = np.zeros(B, np.int32)
     row_lens[live] = lens
@@ -716,6 +719,10 @@ def test_trips_of_several_blocks_match_plain_attention(lens):
 # ----------------------------------------------------------------------
 # the folded write: a decode round's attention sublayer in one call
 # ----------------------------------------------------------------------
+#: the most live rows of a round of :func:`_round_case`
+LIVE_MAX = 7
+
+
 def _round_case(kvh, g, hd, rows, lens_by_row, *, BS=16, MAXB=3, L=2,
                 dtype=jnp.bfloat16, seed=0):
     """A decode round of ``rows`` one-token rows, ``lens_by_row`` {row:
@@ -725,7 +732,10 @@ def _round_case(kvh, g, hd, rows, lens_by_row, *, BS=16, MAXB=3, L=2,
     from deepspeed_tpu.ops.transformer import paged_attention as pa
 
     rng = np.random.default_rng(seed)
-    NB = 1 + len(lens_by_row) * MAXB + 2
+    # blocks for the most live rows any case has, however many this one has:
+    # the cases of one (head size, group, row count) then share a compile
+    assert len(lens_by_row) <= LIVE_MAX
+    NB = 1 + LIVE_MAX * MAXB + 2
     pool = jnp.asarray(rng.standard_normal(
         pa.init_pool(L, kvh, NB, BS, hd, dtype).shape), dtype)
     q, k, v = (jnp.asarray(rng.standard_normal((rows, n * hd)), dtype)
@@ -742,26 +752,37 @@ def _round_case(kvh, g, hd, rows, lens_by_row, *, BS=16, MAXB=3, L=2,
 
 @functools.lru_cache(maxsize=None)
 def _round_forms():
-    """(the parent's two calls, the one call), each under one ``jax.jit``:
-    ``write_rows`` of rows that are apart (``kv_write``) then the read-only
-    ``paged_decode`` on q as heads, against ``paged_decode`` handed the new
-    rows, everything in the model's ``(rows, heads * hd)`` layout."""
+    """(the parent's two calls, the one call): ``write_rows`` of rows that
+    are apart (``kv_write``) then the read-only ``paged_decode`` on q as
+    heads, each under a ``jax.jit`` of its own (the write's shapes know
+    nothing of the query groups: one compile for every ``g``), against
+    ``paged_decode`` handed the new rows, everything in the model's ``(rows,
+    heads * hd)`` layout."""
     from deepspeed_tpu.ops.transformer import paged_attention as pa
 
-    def two_calls(q, k, v, pool, layer, tables, lens):
-        B, (_, kvh, _, _, row) = q.shape[0], pool.shape
-        heads = lambda a: a.reshape(B, 1, -1, row // 2)
+    def heads(a, pool):
+        return a.reshape(a.shape[0], 1, -1, pool.shape[-1] // 2)
+
+    @jax.jit
+    def write(k, v, pool, layer, tables, lens):
         assert pa.writes_live_rows(pool)
-        pool = pa.write_rows(pool, layer, tables,
-                             jnp.maximum(lens - 1, 0)[:, None], heads(k),
-                             heads(v), rows_apart=True)
-        out = pa.paged_decode(heads(q)[:, 0], pool, layer, tables, lens)
-        return out.reshape(q.shape), pool
+        return pa.write_rows(pool, layer, tables,
+                             jnp.maximum(lens - 1, 0)[:, None],
+                             heads(k, pool), heads(v, pool), rows_apart=True)
+
+    @jax.jit
+    def read(q, pool, layer, tables, lens):
+        out = pa.paged_decode(heads(q, pool)[:, 0], pool, layer, tables, lens)
+        return out.reshape(q.shape)
+
+    def two_calls(q, k, v, pool, layer, tables, lens):
+        pool = write(k, v, pool, layer, tables, lens)
+        return read(q, pool, layer, tables, lens), pool
 
     def one_call(q, k, v, pool, layer, tables, lens):
         return pa.paged_decode(q, pool, layer, tables, lens, new_rows=(k, v))
 
-    return jax.jit(two_calls), jax.jit(one_call)
+    return two_calls, jax.jit(one_call)
 
 
 def _assert_the_one_call_is_the_two(case, layer=1):
