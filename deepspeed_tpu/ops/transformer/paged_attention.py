@@ -48,7 +48,8 @@ the pool's blocks are too large to buffer), and a row works as long as its
 is the caller: the model gives ``lens`` 0 to a row whose table names no block
 (block 0 is the trash block), and such a row fetches nothing, keeps the zeros
 its cell's output starts as and costs the cell one step of a scalar loop, not
-the grid a step. The kernel does not look at the table to decide it. A cell's live rows hand the double buffer on: the next
+the grid a step. The kernel does not look at the table to decide it. A
+cell's live rows are one stream of trips through a ring of buffers: the next
 live row's first blocks arrive while the row before it computes its last.
 """
 
@@ -186,39 +187,53 @@ def payload_shape(pool, k_width=None):
 # ----------------------------------------------------------------------
 # the kernels
 # ----------------------------------------------------------------------
-#: bytes of VMEM the decode kernel's double buffer of pool blocks may take;
-#: the kernel's float32 temporaries are a few times one half of it
+#: bytes of VMEM two pool blocks of a cell's heads may take: what decides how
+#: many kv heads a cell covers. (The kernel's ring is :data:`DECODE_SLOTS`
+#: trips, 1.5 MB for the cells' pools, 3 MB at most, where a block is 1 MB.)
 DECODE_BUFFER_BYTES = 2 * 1024 * 1024
 
 
 def heads_per_cell(pool) -> int:
     """kv heads one cell of :func:`paged_decode` covers, from the pool's
-    shape and dtype alone: all ``kvh`` where the double buffer
-    ``(2, kvh, BS, row)`` fits :data:`DECODE_BUFFER_BYTES`, else the largest
-    divisor of ``kvh`` whose buffer does (at least one)."""
+    shape and dtype alone: all ``kvh`` where two blocks of them,
+    ``(2, kvh, BS, row)``, fit :data:`DECODE_BUFFER_BYTES`, else the largest
+    divisor of ``kvh`` whose blocks do (at least one)."""
     _, kvh, _, BS, row = pool.shape
     fit = DECODE_BUFFER_BYTES // (2 * BS * row * pool.dtype.itemsize)
     return max(d for d in range(1, kvh + 1) if kvh % d == 0 and d <= max(fit, 1))
 
 
-#: bytes one trip of the decode kernel's loop fetches, at least, where a
-#: row has that many blocks: a trip's fixed cost (the DMA's wait, two small
-#: products, the running softmax) is ~0.35 us whatever the block holds
-DECODE_TRIP_BYTES = 256 * 1024
+#: ``[k | v]`` rows (a token of one kv head) one trip of the decode kernel's
+#: loop fetches, at least, where a row has that many blocks. A trip is a chain
+#: (the fetch's wait, the scores' product, the running softmax, the values'
+#: product, each waiting for the one before) of ~0.55 us on the chip however
+#: little it holds: 256 rows (128 KB) or 1024 took the same time, PERF.md 6.
+#: What a trip does a row is the same at a head of 64 and of 128 (a push, a
+#: score, an exp), so the trip is sized in rows, not bytes: 1024 are 256 KB of
+#: heads of 64 (gpt2-medium's block of 16 heads, whose products take as long
+#: as its fetch) and 512 KB of heads of 128 (four blocks of trinity-mini's
+#: four heads, sixteen of the sparse view's one)
+DECODE_TRIP_ROWS = 1024
 #: pool blocks a trip may fetch at most
-DECODE_TRIP_BLOCKS = 8
+DECODE_TRIP_BLOCKS = 16
+#: buffers of a trip each that the kernel's fetches go round: the fetches run
+#: two trips ahead of the products, so the DMA engine has the next trip when it
+#: is done with one (on the chip three beat two by 14-31% at a full cell and
+#: four gave nothing over three, PERF.md 6)
+DECODE_SLOTS = 3
 
 
 def blocks_per_trip(pool) -> int:
     """Pool blocks one trip of :func:`paged_decode`'s loop fetches and
-    attends together, from the pool's shape and dtype alone (as
-    :func:`heads_per_cell`): as many as make :data:`DECODE_TRIP_BYTES` of a
-    cell's heads, at most :data:`DECODE_TRIP_BLOCKS`. One wherever a cell's
-    block is that large already (16 kv heads of 64: 256 KB); eight for a pool
-    read one kv head a cell (sparse attention's view, 32 KB a block)."""
-    _, _, _, BS, row = pool.shape
-    block = heads_per_cell(pool) * BS * row * pool.dtype.itemsize
-    return max(1, min(DECODE_TRIP_BLOCKS, DECODE_TRIP_BYTES // block))
+    attends together, from the pool's shape alone (as
+    :func:`heads_per_cell`): as many as hold :data:`DECODE_TRIP_ROWS` rows of
+    a cell's heads, at most :data:`DECODE_TRIP_BLOCKS`. One wherever a cell's
+    block is that large already (16 kv heads over 64 tokens); four for
+    trinity-mini's four heads, sixteen for a pool read one kv head a cell
+    (sparse attention's view, 32 KB a block)."""
+    _, _, _, BS, _ = pool.shape
+    return max(1, min(DECODE_TRIP_BLOCKS,
+                      DECODE_TRIP_ROWS // (heads_per_cell(pool) * BS)))
 
 
 #: rows of a step one cell of the decode kernel covers at most (on the chip,
@@ -391,20 +406,33 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
     (:func:`rows_per_cell`) and group of ``hpc`` kv heads (all of them
     wherever the buffer fits: :func:`heads_per_cell`). The cell first sorts
     its rows by what ``lens`` says in a loop of scalar steps (the live rows'
-    numbers go to ``live_ref`` in order), starts the first live row's first
-    fetch, and zeroes its output block while that is on its way: a row with
-    ``lens[b] == 0`` is dead, keeps those zeros and costs its compare and
-    nothing else. Then the cell walks its live rows. A row streams
-    its ACTIVE pool blocks from HBM with double-buffered DMA (prefetch trip
-    j+1 while computing trip j), ``bpt`` blocks a trip
-    (:func:`blocks_per_trip`), and computes every head of the group from
-    each: a row's work follows its real length, and what it computes does not
-    depend on its neighbours.
+    numbers go to ``live_ref`` in order), starts its first fetches, and zeroes
+    its output block while they are on their way: a row with ``lens[b] == 0``
+    is dead, keeps those zeros and costs its compare and nothing else. Then
+    the cell walks its live rows. A row streams its ACTIVE pool blocks from
+    HBM, ``bpt`` blocks a trip (:func:`blocks_per_trip`), and computes every
+    head of the group from each: a row's work follows its real length, and
+    what it computes does not depend on its neighbours.
 
-    The double buffer is handed from row to row: while a row computes its
-    last trip the first trip of the cell's next live row is fetched into the
-    slot that is free, so only the first live row of a cell waits for a fetch
-    with nothing to hide it.
+    WHAT A TRIP COMPUTES WITH is what it fetched: both products take the
+    buffer in the pool's dtype and accumulate in float32, ``q`` cast to that
+    dtype once a live row and ``p`` once a trip, as :func:`mla_decode`, the
+    flash kernels and the XLA forms (:func:`attend_rows`,
+    :func:`attend_tiles`) do. The scores lose nothing by it: a product of two
+    bfloat16 numbers is exact in float32, and the scale multiplies their
+    float32 sum. The running softmax (``m``, ``l``, ``alpha``, ``acc``) is
+    float32. On the chip a trip of heads of 128 is bound by its fetch either
+    way (alone the products take 56% of it; at float32 the ring ran the
+    same), and gpt2-medium's 16 heads of 64, whose products pass their
+    fetch, run 9% faster for it at a full cell (PERF.md 6, PR 65).
+
+    The cell's trips are ONE STREAM, row behind row, through a ring of
+    ``slots`` buffers (:data:`DECODE_SLOTS`): the fetches run ``slots - 1``
+    trips ahead of the products, over the row's end into the next live row's
+    first trips, so the DMA engine is handed the next trip before it is done
+    with one and only the cell's first fetch has nothing to hide it. The
+    fetches' cursor ``(k, j)``, trip ``j`` of the ``k``-th live row, is
+    carried with the count of the cell's trips, which names the slot.
 
     The pool is the whole stacked pool as it lies in HBM (see
     :func:`init_pool`): one DMA fetches ``pool[layer, h0:h0+hpc, tables[b, j]]``,
@@ -445,14 +473,25 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
     row0 = pl.program_id(0) * rpc
     layer = layer_ref[0]
     heads = pl.ds(pl.program_id(1) * hpc, hpc)
+    slots = buf.shape[0]
+    span = bpt * block_size            # tokens a trip covers
+
+    # ``lax.div`` / ``lax.rem``: the counts are not negative, and ``//`` and
+    # ``%`` trace (and run) a sign's worth of scalar steps more each
+    div, rem = jax.lax.div, jax.lax.rem
 
     def blocks(b):
-        return (lens_ref[b] + block_size - 1) // block_size
+        return div(lens_ref[b] + (block_size - 1), block_size)
 
     def trip0(r):
         """The first trip of the cell's row ``r``: the one that holds its
         bound."""
-        return first_ref[row0 + r] // (bpt * block_size) if bound else 0
+        return div(first_ref[row0 + r], span) if bound else 0
+
+    def trips(r):
+        """Where the trips of the cell's row ``r`` end: behind the one that
+        holds its last token."""
+        return div(blocks(row0 + r) + (bpt - 1), bpt)
 
     def sort_row(r, n_live):
         # a dead row's number is overwritten by the next live row's
@@ -461,20 +500,41 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
 
     n_live = jax.lax.fori_loop(0, rpc, sort_row, 0)
 
+    def live_row(k):
+        """The cell's ``k``-th live row (its first row behind the last)."""
+        return jax.lax.select(k < n_live, live_ref[jax.lax.min(k, rpc - 1)], 0)
+
     def copies(r, j, slot):
         """Trip ``j`` of the cell's row ``r`` into buffer ``slot``."""
         b = row0 + r
         last = blocks(b) - 1
         return [pltpu.make_async_copy(
             pool_ref.at[layer, heads,
-                        tables_ref[b, jnp.minimum(j * bpt + i, last)
+                        tables_ref[b, jax.lax.min(j * bpt + i, last)
                                    if bpt > 1 else j]],
             buf.at[slot, :, pl.ds(i * block_size, block_size)],
             sem.at[slot, i]) for i in range(bpt)]
 
+    def fetch(t, cursor):
+        """Start the fetch the cursor stands at, ``(k, j)``: trip ``j`` of the
+        ``k``-th live row, the ``t``-th trip of the cell, into the slot whose
+        turn it is; nothing behind the last live row. Returns the cursor
+        moved on."""
+        k, j = cursor
+        r = live_row(k)
+
+        @pl.when(k < n_live)
+        def _start():
+            for c in copies(r, j, rem(t, slots)):
+                c.start()
+
+        row_ends = j + 1 >= trips(r)
+        return (jax.lax.select(row_ends, k + 1, k),
+                jax.lax.select(row_ends, trip0(live_row(k + 1)), j + 1))
+
     def store(b):
         """``wbuf`` to the sub-tile of row ``b``'s new token."""
-        start = (lens_ref[b] - 1) % block_size // SUB_TILE * SUB_TILE
+        start = div(rem(lens_ref[b] - 1, block_size), SUB_TILE) * SUB_TILE
         return pltpu.make_async_copy(
             wbuf, pool_ref.at[layer, heads, tables_ref[b, blocks(b) - 1],
                               pl.ds(pl.multiple_of(start, SUB_TILE), SUB_TILE)],
@@ -491,8 +551,8 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
         and the sub-tile around it on its way back to the pool."""
         b = row0 + r
         tok = ((blocks(b) - 1 - j * bpt) * block_size
-               + (lens_ref[b] - 1) % block_size)
-        at = pl.multiple_of(tok // SUB_TILE * SUB_TILE, SUB_TILE)
+               + rem(lens_ref[b] - 1, block_size))
+        at = pl.multiple_of(div(tok, SUB_TILE) * SUB_TILE, SUB_TILE)
         new = jnp.stack([jnp.concatenate(kv, axis=-1) for kv in zip(
             heads_of(k32, r, hpc), heads_of(v32, r, hpc))])  # (hpc, 1, 2*hd)
         # through float32, which every bfloat16 survives unchanged: the
@@ -509,45 +569,32 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
         wbuf[...] = tile
         store(b).start()
 
-    @pl.when(n_live > 0)
-    def _cold():
-        r = live_ref[0]
-        for c in copies(r, trip0(r), 0):
-            c.start()
+    # the cell's trips are one stream, row behind row, and the fetches run
+    # ``slots - 1`` trips ahead of the products
+    cursor = jax.lax.fori_loop(
+        0, slots - 1, fetch, (jnp.int32(0), jnp.int32(trip0(live_row(0)))))
 
     # every row's output starts as zeros, which is what a dead row keeps
-    # (stored while the first fetch is on its way)
+    # (stored while the first fetches are on their way)
     o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
-    def attend(k, slot0):
-        """The cell's ``k``-th live row, whose first trip is in flight into
-        buffer ``slot0``; returns the slot of the next row's first trip."""
+    def attend(k, stream):
+        """The cell's ``k``-th live row; ``stream`` is the number of the
+        cell's trips so far and the fetches' cursor."""
         r = live_ref[k]
         seq_len = lens_ref[row0 + r]
-        ntrip = (blocks(row0 + r) + bpt - 1) // bpt
-        r_next = live_ref[jnp.minimum(k + 1, rpc - 1)]
-        j0 = trip0(r)
+        j0, ntrip = trip0(r), trips(r)
         if write:
             qh = heads_of(q32, r, hpc * g)
             q = jnp.stack([jnp.concatenate(qh[i * g:(i + 1) * g], axis=0)
-                           for i in range(hpc)]) * scale  # (hpc, g, hd)
+                           for i in range(hpc)]).astype(buf.dtype)
         else:
-            q = q_ref[r].astype(jnp.float32) * scale  # (hpc, g, hd)
+            q = q_ref[r].astype(buf.dtype)            # (hpc, g, hd)
 
         def body(j, carry):
-            m, l, acc = carry
-            slot = jax.lax.rem(slot0 + j - j0 if bound else slot0 + j, 2)
-
-            @pl.when(j + 1 < ntrip)
-            def _prefetch():
-                for c in copies(r, j + 1, 1 - slot):
-                    c.start()
-
-            @pl.when((j + 1 == ntrip) & (k + 1 < n_live))
-            def _hand_over():
-                for c in copies(r_next, trip0(r_next), 1 - slot):
-                    c.start()
-
+            m, l, acc, t, cursor = carry
+            slot = rem(t, slots)
+            cursor = fetch(t + slots - 1, cursor)   # into the slot left last
             for c in copies(r, j, slot):
                 c.wait()
             if write:
@@ -555,12 +602,11 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
                 def _new_token():
                     set_row(k, r, j, slot)
 
-            kv = buf[slot].astype(jnp.float32)  # (hpc, bpt*BS, 2*hd)
+            kv = buf[slot]                    # (hpc, bpt*BS, 2*hd), as it came
             s = jax.lax.dot_general(          # every head of the group: (hpc, g, T)
                 q, kv[..., :hd], (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-            kpos = j * (bpt * block_size) + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 2)
+                preferred_element_type=jnp.float32) * scale
+            kpos = j * span + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
             seen = kpos < seq_len
             if bound:
                 seen &= kpos >= first_ref[row0 + r]
@@ -570,14 +616,15 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
             alpha = jnp.exp(m - m_new)
             l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
             acc_new = acc * alpha + jax.lax.dot_general(
-                p, kv[..., hd:], (((2,), (1,)), ((0,), (0,))),
+                p.astype(kv.dtype), kv[..., hd:], (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)
-            return m_new, l_new, acc_new
+            return m_new, l_new, acc_new, t + 1, cursor
 
         m0 = jnp.full((hpc, g, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((hpc, g, 1), jnp.float32)
         acc0 = jnp.zeros((hpc, g, hd), jnp.float32)
-        _, l, acc = jax.lax.fori_loop(j0, ntrip, body, (m0, l0, acc0))
+        _, l, acc, t, cursor = jax.lax.fori_loop(
+            j0, ntrip, body, (m0, l0, acc0, *stream))
         out = acc / l                              # a live row sees a key
         if write:
             o32[pl.ds(r, 1), :] = jnp.concatenate(
@@ -585,10 +632,11 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
                 axis=-1)
         else:
             o_ref[r] = out.astype(o_ref.dtype)
-        return jax.lax.rem(slot0 + ntrip - j0 if bound else slot0 + ntrip, 2)
+        return t, cursor
 
+    stream = (jnp.int32(0), cursor)
     if not write:
-        jax.lax.fori_loop(0, n_live, attend, 0)
+        jax.lax.fori_loop(0, n_live, attend, stream)
         return
 
     @pl.when(n_live > 0)
@@ -596,7 +644,7 @@ def _decode_kernel(layer_ref, tables_ref, lens_ref, *refs, block_size, scale,
         for staged, ref in ((q32, q_ref), (k32, k_ref), (v32, v_ref)):
             staged[...] = ref[...].astype(jnp.float32)
         o32[...] = jnp.zeros(o32.shape, o32.dtype)
-        jax.lax.fori_loop(0, n_live, attend, 0)
+        jax.lax.fori_loop(0, n_live, attend, stream)
         o_ref[...] = o32[...].astype(o_ref.dtype)
         store(row0 + live_ref[n_live - 1]).wait()
 
@@ -654,8 +702,8 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None, new_rows=None,
     rpc = rows_per_cell(B)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)     # the pool stays in HBM
     scratch = [
-        pltpu.VMEM((2, hpc, bpt * BS, row), pool.dtype),
-        pltpu.SemaphoreType.DMA((2, bpt)),     # the double buffer's
+        pltpu.VMEM((DECODE_SLOTS, hpc, bpt * BS, row), pool.dtype),
+        pltpu.SemaphoreType.DMA((DECODE_SLOTS, bpt)),    # the ring's
         pltpu.SMEM((rpc,), jnp.int32),         # the cell's live rows
     ]
     if write:
@@ -680,6 +728,9 @@ def paged_decode(q, pool, layer, tables, lens, *, scale=None, new_rows=None,
         functools.partial(_decode_kernel, block_size=BS, bpt=bpt, hd=hd,
                           write=write, **({"bound": True} if bound else {}),
                           scale=scale if scale is not None else hd ** -0.5),
+        attrs={"trip_bytes": hpc * bpt * BS * row * pool.dtype.itemsize,
+               "blocks_per_trip": bpt, "slots": DECODE_SLOTS,
+               "operand_dtype": pool.dtype.name},
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3 + bound,  # layer, tables, lens[, first]
             grid=(B // rpc, kvh // hpc),

@@ -89,9 +89,9 @@ _CORE = (
 # for one: these start first and the short files fill in behind them. Order
 # within a file is untouched.
 _LONGEST_FIRST = (
+    "tests/unit/test_paged_kernel.py",
     "tests/benchmark/test_cells.py",
     "tests/unit/test_zero_sharded.py",
-    "tests/unit/test_paged_kernel.py",
     "tests/unit/test_pipelined_dispatch.py",
     "tests/unit/test_train_resilience.py",
     "tests/unit/test_aux.py",

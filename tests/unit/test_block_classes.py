@@ -371,16 +371,18 @@ def test_without_a_bound_the_kernel_is_bit_for_bit_the_parents(pool_and_rows):
     k = jnp.zeros((B, pool.shape[1] * hd))
     fold = call_of(lambda *a: pa.paged_decode(
         a[0].reshape(B, nh * hd), a[1], 1, a[2], a[3], new_rows=(k, k)))
-    # the kernel's traced body, every equation of it, as the parent
-    # (072ac8c) traces it at these shapes: read-only and with the round's
-    # rows. Re-pin only when a PR changes the unbounded kernel on purpose
+    # the kernel's traced body, every equation of it, at these shapes:
+    # read-only and with the round's rows. PR 65 changed the unbounded kernel
+    # on purpose (a trip multiplies in the pool's dtype, the fetches go round
+    # a ring) and re-pinned it; before that it was the body PR 62's parent
+    # (072ac8c) traced. Re-pin only when a PR changes the kernel on purpose
     digests = [hashlib.sha256(str(e.params["jaxpr"]).encode()).hexdigest()[:16]
                for e in (unbounded, fold)]
     assert digests == UNBOUNDED_BODY_SHA, digests
     assert str(bounded.params["jaxpr"]) != str(unbounded.params["jaxpr"])
 
 
-UNBOUNDED_BODY_SHA = ["77a8ba65b89635a6", "e6853962c920cc96"]
+UNBOUNDED_BODY_SHA = ["734eaa2fbc6f869c", "68fd655f3ec9e115"]
 
 
 def test_the_window_frame_counts_from_the_first_held_block():

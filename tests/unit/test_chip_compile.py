@@ -389,9 +389,12 @@ def test_the_heads_unit_compiles(one_chip, no_compile_cache, as_tpu, tokens,
 # (rows, query heads, kv heads, pool blocks, head size, table width, kv heads
 # a cell, blocks a trip): the chat server's decode round and mixed step, and
 # serve-doc16k's sparse view (a (row, kv head) pair a row of the pool with its
-# kv heads folded into its blocks, 64 chosen 32 KB blocks a row)
+# kv heads folded into its blocks, 64 chosen 32 KB blocks a row: four trips)
 PAGED_CALLS = [(rows, NH, NH, NB, HD, MAXB, NH, 1) for rows in ROWS] + [
-    (96, 16, 1, 27136, 128, 64, 1, 8)]
+    (96, 16, 1, 27136, 128, 64, 1, 16),
+    # serve-chat's own pool and round, serve-win16k's two classes of blocks
+    (CELL_SEQS, NH, NH, CELL_NB, HD, MAXB, NH, 1),
+    (32, 32, 4, 608, 128, 42, 4, 4), (32, 32, 4, 2048, 128, 274, 4, 4)]
 
 
 @pytest.mark.parametrize("rows,nh,kvh,nb,hd,maxb,hpc,bpt", PAGED_CALLS)
@@ -442,6 +445,8 @@ def test_kv_write_compiles(one_chip, no_compile_cache, as_tpu, shape, rows,
 @pytest.mark.parametrize("shape,rows,g,dtype", [
     ((24, NH, CELL_NB, BS, 2 * HD), CELL_SEQS, 1, jnp.bfloat16),  # serve-chat
     ((24, NH, 416, BS, 256), CELL_SEQS, 1, jnp.bfloat16),  # Pythia-1.4B
+    ((10, 4, 608, BS, 256), 32, 8, jnp.bfloat16),   # serve-win16k, window
+    ((3, 4, 2048, BS, 256), 32, 8, jnp.bfloat16),   # serve-win16k, full
     ((2, 8, NB, BS, 512), 256, 1, jnp.bfloat16),    # heads of 256
     ((4, 2, NB, 16, 2 * HD), 8, 4, jnp.bfloat16),   # grouped queries
     ((4, 4, NB, 16, 256), 8, 4, jnp.float32),       # a float32 pool
@@ -469,6 +474,54 @@ def test_paged_decode_with_new_rows_compiles(one_chip, no_compile_cache,
     assert pool_sized_movers(text, math.prod(shape) // shape[0]) == []
     assert "may-alias" in text or "must-alias" in text, \
         "the donated pool is no longer written in place"
+
+
+@pytest.mark.parametrize("shape,rows,g,maxb,form", [
+    ((10, 4, 608, BS, 256), 32, 8, 42, "write+bounded"),   # serve-win16k
+    ((3, 4, 2048, BS, 256), 32, 8, 274, "write"),
+    ((10, 4, 608, BS, 256), 32, 8, 42, "read+bounded"),    # its mixed step
+    ((8, 1, 27136, BS, 256), 96, 16, 64, "read"),          # serve-doc16k
+    ((24, NH, CELL_NB, BS, 2 * HD), CELL_SEQS, 1, MAXB, "write"),  # serve-chat
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_a_bfloat16_pools_trip_multiplies_what_it_fetched(shape, rows, g, maxb,
+                                                          form):
+    """The kernel's traced body at the cells' shapes: both products of a trip
+    take bfloat16 operands and give float32, and no array of a trip's tokens
+    is converted from bfloat16 to float32 (the parent converted the whole
+    ``(hpc, bpt * BS, 2 * hd)`` buffer every trip)."""
+    from deepspeed_tpu.analysis.program_audit import _iter_eqns
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    kvh, hd = shape[1], shape[4] // 2
+    pool = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    bound = (lambda f: {"first": f}) if "bounded" in form else (lambda f: {})
+    if "write" in form:
+        lanes = lambda n: jax.ShapeDtypeStruct((rows, n * hd), jnp.bfloat16)
+
+        def call(pool, q, k, v, tables, lens, first):
+            return pa.paged_decode(q, pool, 1, tables, lens, new_rows=(k, v),
+                                   **bound(first))
+        args = (pool, lanes(kvh * g), lanes(kvh), lanes(kvh))
+    else:
+        def call(pool, q, tables, lens, first):
+            return pa.paged_decode(q, pool, 1, tables, lens, **bound(first))
+        args = (pool, jax.ShapeDtypeStruct((rows, kvh * g, hd), jnp.bfloat16))
+    traced = jax.make_jaxpr(call)(*args, i32(rows, maxb), i32(rows), i32(rows))
+    (bind,) = [e for e in traced.eqns if e.primitive.name == "pallas_call"]
+    eqns = list(_iter_eqns(bind.params["jaxpr"]))
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 2
+    for dot in dots:
+        assert [v.aval.dtype for v in dot.invars] == [jnp.bfloat16] * 2
+        assert dot.outvars[0].aval.dtype == jnp.float32
+    tokens = pa.blocks_per_trip(pool) * BS
+    widened = [e.invars[0].aval.shape for e in eqns
+               if e.primitive.name == "convert_element_type"
+               and e.invars[0].aval.dtype == jnp.bfloat16
+               and e.outvars[0].aval.dtype == jnp.float32
+               and e.invars[0].aval.shape[-2:-1] == (tokens,)]
+    assert widened == []
 
 
 @pytest.mark.parametrize("rows", ROWS)

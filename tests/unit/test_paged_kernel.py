@@ -186,10 +186,11 @@ def test_paged_decode_long_context_8k():
 # one cell a sequence over its kv heads; a row with lens 0 is dead
 # ----------------------------------------------------------------------
 @jax.jit
-def plain_attention(q, pool, layer, tables, lens):
-    """``gather_context`` + plain float32 attention: what the kernel must
-    give for every row, and zeros for a row with ``lens`` 0. (Jitted: eagerly
-    each of its operations is compiled by itself, a second a call.)"""
+def plain_attention(q, pool, layer, tables, lens, first=None):
+    """``gather_context`` + plain float32 attention over the tokens ``first
+    <= t < lens`` (from 0 without ``first``): what the kernel must give for
+    every row, and zeros for a row with ``lens`` 0. (Jitted: eagerly each of
+    its operations is compiled by itself, a second a call.)"""
     from deepspeed_tpu.ops.transformer import paged_attention as pa
 
     gk, gv = pa.gather_context(pool, layer, tables)   # (B, T, kvh, hd)
@@ -197,7 +198,10 @@ def plain_attention(q, pool, layer, tables, lens):
     qg = q.astype(jnp.float32).reshape(B, kvh, -1, hd)
     s = jnp.einsum("bhgd,bkhd->bhgk", qg, gk.astype(jnp.float32),
                    precision="highest") * hd ** -0.5
-    seen = jnp.arange(T)[None, None, None] < lens[:, None, None, None]
+    kpos = jnp.arange(T)[None, None, None]
+    seen = kpos < lens[:, None, None, None]
+    if first is not None:
+        seen &= kpos >= first[:, None, None, None]
     p = jax.nn.softmax(jnp.where(seen, s, -1e30), -1) * seen
     out = jnp.einsum("bhgk,bkhd->bhgd", p, gv.astype(jnp.float32),
                      precision="highest")
@@ -690,12 +694,16 @@ def test_forward_paged_rows_apart_writes_the_live_rows_alone(monkeypatch):
 
 
 @pytest.mark.parametrize("shape,dtype,bpt", [
-    ((24, 16, 832, 64, 128), jnp.bfloat16, 1),     # gpt2-medium's: 256 KB
+    ((24, 16, 832, 64, 128), jnp.bfloat16, 1),     # gpt2-medium's: 1024 rows
     ((24, 16, 832, 64, 256), jnp.bfloat16, 1),     # Pythia-1.4B's: 512 KB
-    ((2, 1, 27136, 64, 256), jnp.bfloat16, 8),     # sparse attention's view
-    ((2, 2, 13568, 64, 256), jnp.bfloat16, 4),
-    ((5, 1, 3072, 64, 640), jnp.bfloat16, 3),
-    ((1, 1, 64, 16, 128), jnp.float32, 8),         # never more than eight
+    ((10, 4, 608, 64, 256), jnp.bfloat16, 4),      # trinity-mini's window
+    ((3, 4, 2048, 64, 256), jnp.bfloat16, 4),      # class and its full one
+    ((2, 1, 27136, 64, 256), jnp.bfloat16, 16),    # sparse attention's view
+    ((2, 2, 13568, 64, 256), jnp.bfloat16, 8),
+    ((5, 1, 3072, 64, 640), jnp.bfloat16, 16),
+    ((2, 8, 129, 64, 512), jnp.bfloat16, 2),       # heads of 256: 1 MB
+    ((24, 16, 832, 64, 128), jnp.float32, 1),      # rows, not bytes
+    ((1, 1, 64, 16, 128), jnp.float32, 16),        # never more than sixteen
 ])
 def test_blocks_per_trip_follows_the_pools_shape(shape, dtype, bpt):
     from deepspeed_tpu.ops.transformer import paged_attention as pa
@@ -710,7 +718,7 @@ def test_trips_of_several_blocks_match_plain_attention(lens):
     place and masked), a row shorter than one trip."""
     pa, pool, q, tables, row_lens, _ = _case(1, 4, 64, 16, list(lens),
                                              MAXB=24, dead=2)
-    assert pa.blocks_per_trip(pool) == 8
+    assert pa.blocks_per_trip(pool) == 16
     out = pa.paged_decode(q, pool, jnp.int32(1), tables, row_lens)
     ref = plain_attention(q, pool, jnp.int32(1), tables, row_lens)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
@@ -831,7 +839,7 @@ def test_the_folded_write_is_kv_write_then_paged_decode(monkeypatch, hd, g,
 
 
 #: a live row's tokens (the new one counted) by the edge it stands for, over
-#: blocks of 16 (two sub-tiles) fetched eight a trip
+#: blocks of 16 (two sub-tiles) fetched sixteen a trip
 EDGES = {
     "offset_0_of_a_fresh_block": 2 * 16 + 1,
     "last_offset_of_a_block": 2 * 16,
@@ -853,7 +861,7 @@ def _edges():
                                                  EDGES.values())),
                            MAXB=16, dtype=jnp.bfloat16, seed=5)
         from deepspeed_tpu.ops.transformer import paged_attention as pa
-        assert pa.blocks_per_trip(case[0]) == 8
+        assert pa.blocks_per_trip(case[0]) == 16
         out, pool = _assert_the_one_call_is_the_two(case)
     return np.asarray(case[5]), _bits(out), _bits(pool), case
 
@@ -918,3 +926,144 @@ def test_the_models_decode_round_is_one_call(monkeypatch):
     del calls[:]
     jax.eval_shape(m.forward_paged, *args)
     assert calls == [("paged_decode", 3, False)]
+
+
+# ----------------------------------------------------------------------
+# a trip computes in the pool's dtype
+# ----------------------------------------------------------------------
+#: (kv heads, query heads a kv head, head size): gpt2-medium's pool of
+#: heads, trinity-mini's grouped queries, minicpm-sala's sparse view
+OPERAND_SHAPES = {"chat": (16, 1, 64), "win16k": (4, 8, 128),
+                  "doc16k": (1, 16, 128)}
+
+
+def _operand_case(kvh, g, hd, dtype, *, BS=16, MAXB=12, seed=11):
+    """Five rows over a pool of ``dtype`` (a dead one among them): several
+    trips, a trip the blocks end inside, one token; q float32 numbers that
+    the pool's dtype holds (the result is float32, the products are the
+    pool's); each row's lower bound for a bounded call."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    rng = np.random.default_rng(seed)
+    lens = np.asarray([1, 40, 0, MAXB * BS, 7 * BS + 3], np.int32)
+    first = np.asarray([0, 17, 0, 5 * BS + 1, 2 * BS], np.int32)
+    NB = 1 + len(lens) * MAXB
+    pool = jnp.asarray(rng.standard_normal(
+        pa.init_pool(2, kvh, NB, BS, hd, dtype).shape), dtype)
+    q = jnp.asarray(rng.standard_normal((len(lens), kvh * g, hd)), dtype) \
+        .astype(jnp.float32)
+    tables = np.zeros((len(lens), MAXB), np.int32)
+    ids = iter(rng.permutation(np.arange(1, NB)))
+    for b, n in enumerate(lens):
+        for j in range(-(-int(n) // BS)):
+            tables[b, j] = next(ids)
+    return pa, pool, q, jnp.asarray(tables), jnp.asarray(lens), \
+        jnp.asarray(first)
+
+
+@pytest.mark.parametrize("bounded", [False, True], ids=["whole", "bounded"])
+@pytest.mark.parametrize("cell", sorted(OPERAND_SHAPES))
+def test_a_bfloat16_pools_trip_is_attend_rows_arithmetic(monkeypatch, cell,
+                                                         bounded):
+    """The kernel on a bfloat16 pool against its XLA twin, which multiplies
+    bfloat16 by bfloat16 into float32 and rounds ``p`` to bfloat16 as the
+    kernel now does, and against float32 attention over the same bfloat16
+    numbers. The scores are exact in both; what is left is ``p`` rounded to
+    8 bits, by the twin after it is normalised and by the kernel before, so
+    each is within 2^-9 of the largest value of float32 attention and of
+    the other (found: 3-6e-4 the kernel, 5-9e-4 the twin, 6-11e-4 between
+    them; the float32 kernel was 4e-7 from float32 and the twin's 9e-4 from
+    the twin)."""
+    monkeypatch.delenv("DSTPU_FORCE_PAGED_KERNEL", raising=False)
+    pa, pool, q, tables, lens, first = _operand_case(
+        *OPERAND_SHAPES[cell], jnp.bfloat16)
+    if not bounded:
+        first = jnp.zeros_like(first)
+    bound = {"first": first} if bounded else {}
+    got = jax.jit(functools.partial(pa.paged_decode, layer=1))(
+        q, pool, tables=tables, lens=lens, **bound)
+    assert not np.asarray(got[2]).any()
+    twin = jax.jit(functools.partial(pa.attend_rows, layer=1))(
+        q, pool, tables=tables, lens=lens, **bound)
+    want = np.asarray(plain_attention(q, pool, 1, tables, lens, first))
+    got, twin = (np.asarray(a, np.float32) for a in (got, twin))
+    top = np.abs(want).max()
+    assert np.abs(got - twin).max() <= 2 ** -9 * top
+    assert 1e-5 * top < np.abs(got - want).max() <= 2 ** -9 * top
+
+
+def test_a_trips_scores_are_sums_of_exact_products():
+    """``v`` a one-hot selector of the token: a row of one trip then returns
+    its ``p`` over the trip's tokens, rounded to bfloat16 and divided by
+    ``l``. Relative to the largest, that is ``bfloat16(exp(s - max s))`` with
+    ``s`` the scaled sum of the exact products of bfloat16 numbers: a
+    product rounded to bfloat16, or a ``q * scale`` rounded before the
+    product, moves ``p`` by 2^-9 and flips its rounding in every other
+    element."""
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    kvh, g, hd, BS, n = 2, 4, 128, 16, 100
+    rng = np.random.default_rng(5)
+    pool = np.zeros(pa.init_pool(1, kvh, 9, BS, hd).shape, np.float32)
+    pool[..., :hd] = rng.standard_normal(pool[..., :hd].shape)
+    tables = np.arange(1, 8, dtype=np.int32)[None]
+    for t in range(n):                  # token t's value is the unit vector t
+        pool[0, :, tables[0, t // BS], t % BS, hd + t] = 1.0
+    pool = jnp.asarray(pool, jnp.bfloat16)
+    assert pa.blocks_per_trip(pool) * BS >= n           # one trip
+    # float32 queries that bfloat16 holds exactly: the result is float32
+    q = jnp.asarray(rng.standard_normal((1, kvh * g, hd)), jnp.bfloat16)
+    got = np.asarray(pa.paged_decode(
+        q.astype(jnp.float32), pool, 0, jnp.asarray(tables),
+        jnp.asarray([n], jnp.int32)), np.float64)[0].reshape(kvh, g, hd)
+    keys = np.asarray(pool[0, :, tables[0], :, :hd], np.float64)
+    keys = np.moveaxis(keys, 1, 0).reshape(kvh, -1, hd)[:, :n]
+    s = np.einsum("hgd,htd->hgt", np.asarray(q, np.float64)[0].reshape(
+        kvh, g, hd), keys) * hd ** -0.5
+    p = np.exp(s - s.max(-1, keepdims=True))
+    rounded = np.asarray(jnp.asarray(p, jnp.bfloat16), np.float64)
+    # but for a p within float32's reach of a rounding boundary
+    both = np.asarray(jnp.asarray(np.stack([p * (1 - 1e-5), p * (1 + 1e-5)]),
+                                  jnp.bfloat16), np.float64)
+    clear = both[0] == both[1]
+    assert clear.mean() > 0.98
+    ratio = got[..., :n] / got[..., :n].max(-1, keepdims=True)
+    np.testing.assert_allclose(ratio[clear], rounded[clear], rtol=1e-6)
+    assert not got[..., n:].any()
+
+
+@pytest.mark.parametrize("write", [False, True], ids=["read", "write"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_a_trips_products_take_the_pools_dtype(dtype, write):
+    """Both products of a trip multiply in the pool's dtype and accumulate
+    in float32 (a float32 pool still multiplies at float32), and nothing of
+    the trip's buffer is converted: the one convert of pool numbers to
+    float32 left is the :data:`SUB_TILE` tokens around a written row."""
+    from deepspeed_tpu.analysis.program_audit import _iter_eqns
+    from deepspeed_tpu.ops.transformer import paged_attention as pa
+
+    kvh, g, hd, BS, rows = 4, 8, 128, 64, 8
+    pool = jnp.zeros(pa.init_pool(2, kvh, 9, BS, hd, dtype).shape, dtype)
+    tables, lens = jnp.zeros((rows, 4), jnp.int32), jnp.zeros(rows, jnp.int32)
+    if write:
+        q, k = (jnp.zeros((rows, kvh * n * hd), jnp.bfloat16) for n in (g, 1))
+        fn = lambda: pa.paged_decode(q, pool, 0, tables, lens, new_rows=(k, k))
+    else:
+        q = jnp.zeros((rows, kvh * g, hd), jnp.bfloat16)
+        fn = lambda: pa.paged_decode(q, pool, 0, tables, lens)
+    (call,) = [e for e in jax.make_jaxpr(fn)().eqns
+               if e.primitive.name == "pallas_call"]
+    eqns = list(_iter_eqns(call.params["jaxpr"]))
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 2
+    for dot in dots:
+        assert [v.aval.dtype for v in dot.invars] == [dtype, dtype]
+        assert dot.outvars[0].aval.dtype == jnp.float32
+    trip = pa.blocks_per_trip(pool) * BS
+    widened = [e.invars[0].aval.shape for e in eqns
+               if e.primitive.name == "convert_element_type"
+               and e.invars[0].aval.dtype == jnp.bfloat16
+               and e.outvars[0].aval.dtype == jnp.float32
+               and e.invars[0].aval.shape[-2:-1] == (trip,)]
+    assert widened == []
