@@ -248,9 +248,9 @@ class InferenceEngineV2:
         #: block allocations (every class's) / COW copies / window-class
         #: blocks freed behind their windows, as the previous dispatch saw them
         self._count_marks = (0, 0, 0)
-        #: of a model with a bounded class: the most blocks of each class in
-        #: use at any dispatch so far (what ``benchmark/class_peak.py`` sizes
-        #: a cell's classes from)
+        #: the most blocks of each class in use at any dispatch so far
+        #: (``window`` stays 0 without a bounded class): what
+        #: ``benchmark/class_peak.py`` sizes a cell's ``num_blocks`` from
         self.block_peaks = {"full": 0, "window": 0}
         self._ragged_fn = None
         self._cow_fn = None
@@ -1390,11 +1390,11 @@ class InferenceEngineV2:
         mgr, w = self.block_mgr, self.block_mgr.window
         marks = (mgr.allocations + (w.allocations if w else 0),
                  mgr.stats["cow_copies"], w.freed_behind if w else 0)
+        used = {"full": mgr.num_blocks - 1 - mgr.free_blocks}
         if w is not None:
-            used = {"full": mgr.num_blocks - 1 - mgr.free_blocks,
-                    "window": w.in_use}
-            self.block_peaks = {c: max(n, self.block_peaks[c])
-                                for c, n in used.items()}
+            used["window"] = w.in_use
+        self.block_peaks.update({c: max(n, self.block_peaks[c])
+                                 for c, n in used.items()})
         if disp.recording:
             rows = ctx = by_row = decode = seg = dctx = dwin = 0
             for d, take in plan:
@@ -1407,8 +1407,8 @@ class InferenceEngineV2:
                 # one token pending: a decode step (or a prompt's last token)
                 if fused or take == 1:
                     decode += take
-                    if w is not None:       # one token: its context, and
-                        dctx += seen + 1    # what a window layer sees of it
+                    dctx += seen + 1        # one token: its context, and
+                    if w is not None:       # what a window layer sees of it
                         dwin += min(seen + 1, w.bound)
             disp.set(padded_rows=padded_rows, rows=rows, decode_rows=decode,
                      prefill_tokens=rows - decode, seg_tokens=seg,
@@ -1419,7 +1419,8 @@ class InferenceEngineV2:
                                         self._full_pool())),
                      ctx_tokens=ctx, ctx_tokens_by_row=by_row,
                      blocks_allocated=max(0, marks[0] - self._count_marks[0]),
-                     cow_copies=max(0, marks[1] - self._count_marks[1]))
+                     cow_copies=max(0, marks[1] - self._count_marks[1]),
+                     decode_ctx_tokens=dctx)
             if mgr.slots is not None:
                 disp.set(state_slots=mgr.slots.in_use)
             if w is not None:
@@ -1428,14 +1429,15 @@ class InferenceEngineV2:
                 # outside this step too), and what the window class freed
                 # behind its sequences' windows since the step before
                 # (``blocks_allocated`` counts both classes); the one-token
-                # rows' contexts summed, whole and as a window layer sees
-                # them: what the decode kernel has to read in this step
+                # rows' contexts as a window layer sees them, summed
+                # (``decode_ctx_tokens``: whole): what the decode kernel has
+                # to read in this step
                 disp.set(window_blocks=used["window"],
                          window_free=w.free_blocks,
                          full_blocks=used["full"], full_free=mgr.free_blocks,
                          block_seqs=w.holders,
                          freed_behind=marks[2] - self._count_marks[2],
-                         decode_ctx_tokens=dctx, decode_window_tokens=dwin)
+                         decode_window_tokens=dwin)
         self._count_marks = marks
 
     def _build_ragged_step(self, work):

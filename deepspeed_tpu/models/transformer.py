@@ -47,7 +47,8 @@ from ..utils.logging import logger
 
 
 #: the mixers a ``layer_types`` model may name
-LAYER_TYPES = ("sparse_attn", "linear_attn", "window_attn", "full_attn")
+LAYER_TYPES = ("sparse_attn", "linear_attn", "window_attn", "full_attn",
+               "hybrid_ssm")
 
 
 @dataclass(frozen=True)
@@ -83,7 +84,9 @@ class LayerKind:
     bound: Optional[Callable] = None
     #: where the kind keeps a state slot a sequence a layer: (cfg, layers,
     #: max_seqs, max_seq_len, dtype) -> a group's slot array (layers, 1 +
-    #: max_seqs, ...), slot 0 the trash slot
+    #: max_seqs, ...), slot 0 the trash slot; or a small pytree of such
+    #: arrays, where a slot is more than one thing (a recurrence's state and
+    #: its convolution's window): a slot stays one row index into all of them
     slots: Optional[Callable] = None
     #: the int32 counts its layer function reports (``step_counts``)
     counts: Tuple[str, ...] = ()
@@ -158,6 +161,27 @@ def _linear_leaves(cfg):
     return _mixer_leaves(cfg, qd, o_norm_scale=(qd,))
 
 
+def _hybrid_leaves(cfg):
+    """A ``hybrid_ssm`` layer's: GQA attention and an SSD (Mamba-2) mixer off
+    one norm. The mixer's in-projection gives ``[z | x | B | C | dt]``
+    (``ssm_inner`` | ``ssm_inner`` | 2 x ``ssm_groups * ssm_state`` | a head
+    each), the depthwise convolution runs over ``[x | B | C]``; ``a_log``,
+    ``dt_bias`` and the skip ``D`` are a head's; the gated norm's scale is
+    ``ssm_inner`` wide. The leaves whose published initialisation is near one
+    (the taps, ``D``, the norm) are named ``*_scale``."""
+    H, hd = cfg.hidden_size, cfg.head_dim
+    qd, kvd = cfg.num_heads * hd, cfg.kv_heads * hd
+    inner, nh = cfg.ssm_inner, cfg.ssm_heads
+    return {"ln1_scale": (H,), "wq": (H, qd), "wk": (H, kvd), "wv": (H, kvd),
+            "wo": (qd, H),
+            "ssm_w_in": (H, inner + cfg.ssm_conv_channels + nh),
+            "ssm_conv_scale": (cfg.ssm_conv, cfg.ssm_conv_channels),
+            "ssm_conv_bias": (cfg.ssm_conv_channels,),
+            "a_log": (nh,), "dt_bias": (nh,), "ssm_d_scale": (nh,),
+            "ssm_norm_scale": (inner,), "ssm_w_out": (inner, H),
+            "ln2_scale": (H,)}
+
+
 def _heads_flops(cfg, seen):
     """q k and p v of every head over ``seen`` positions a token."""
     return 6 * 2 * cfg.num_heads * cfg.head_dim * seen
@@ -179,6 +203,17 @@ def _linear_slots(cfg, n, max_seqs, max_seq_len, dtype):
 
     return la.init_state(n, max_seqs, cfg.num_heads, cfg.head_dim,
                          cfg.head_dim)
+
+
+def _hybrid_slots(cfg, n, max_seqs, max_seq_len, dtype):
+    """The SSD state (float32) and the convolution's last ``ssm_conv - 1``
+    input rows (the model's dtype), a slot a sequence in both."""
+    from ..ops.transformer import linear_attention as la
+
+    return {"ssm": la.init_state(n, max_seqs, cfg.ssm_heads, cfg.ssm_state,
+                                 cfg.ssm_head_dim),
+            "conv": la.init_conv(n, max_seqs, cfg.ssm_conv,
+                                 cfg.ssm_conv_channels, dtype)}
 
 
 def _sparse_slots(cfg, n, max_seqs, max_seq_len, dtype):
@@ -236,14 +271,25 @@ LAYER_KINDS: Dict[str, LayerKind] = {
     # GQA with rotary over the last ``sliding_window`` tokens, its blocks a
     # class of their own
     "window_attn": _typed_kind(
-        lambda lm: partial(lm._attn_mixer, True),
+        lambda lm: partial(lm._attn_mixer, True, True),
         lambda cfg, S: _heads_flops(cfg, min(S, cfg.sliding_window or S)),
         scope="window_attn", block_class="window",
         bound=lambda cfg: cfg.sliding_window),
     # GQA with no positional term over the whole context
     "full_attn": _typed_kind(
-        lambda lm: partial(lm._attn_mixer, False), _heads_flops,
+        lambda lm: partial(lm._attn_mixer, False, False), _heads_flops,
         scope="full_attn"),
+    # GQA with rotary over the whole context and an SSD (Mamba-2) mixer side
+    # by side off one norm: KV blocks AND a slot of two arrays a layer (the
+    # float32 state, the convolution's window); the recurrence's own scope is
+    # declared before the branch's, so it is told apart inside it
+    "hybrid_ssm": LayerKind(
+        leaves=_hybrid_leaves, row=_gqa_row,
+        attn_flops=lambda cfg, S: _heads_flops(cfg, S) + 6 * 2 * (
+            cfg.ssm_heads * cfg.ssm_state * cfg.ssm_head_dim),
+        tile=lambda cfg: cfg.linear_chunk,
+        layer=lambda lm: lm._hybrid_layer, slots=_hybrid_slots,
+        scopes=("ssm_scan", "ssm_mixer", "dense_ffn")),
 }
 # a kind's scopes are declared to the tracer here, where they are opened
 tracing.layer_scopes(*(s for kind in LAYER_KINDS.values()
@@ -380,8 +426,36 @@ class TransformerConfig:
     # published ``sparse_config.block_size``), and so the only pool block it
     # can be served from (``init_kv_pool`` refuses another)
     sparse_block_size: int = 64
-    # rows of a prefill tile of a layer_types model (a linear layer's chunk)
+    # rows of a prefill tile of a layer_types model (a linear layer's chunk,
+    # an SSD layer's ``mamba_chunk_size``)
     linear_chunk: int = 128
+    # "hybrid_ssm" (Falcon-H1): GQA attention with rotary over the whole
+    # context and an SSD (Mamba-2) mixer in parallel off one norm, their
+    # outputs summed into the stream, then the feed-forward. The mixer:
+    # ``ssm_heads`` heads of ``ssm_head_dim`` values, keys and queries (B, C)
+    # of ``ssm_state`` shared by the heads of each of ``ssm_groups`` groups, a
+    # depthwise causal convolution of ``ssm_conv`` taps over [x | B | C], a
+    # decay a token a head ``exp(softplus(dt + dt_bias) * -exp(a_log))``, the
+    # skip ``D x``, and an RMSNorm of ``y * silu(z)`` in ``ssm_groups`` groups
+    # (ops/transformer/linear_attention.py: the decay as an operand)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    # muP scalars of that family, each applied in the program where the
+    # published forward has it (1.0 = off; the embedding's is ``embed_scale``):
+    # the attention's input, its keys and its output; the mixer's input, its
+    # in-projection's five zones (z, x, B, C, dt) and its output; the
+    # feed-forward's gate and output; the logits
+    attn_in_mult: float = 1.0
+    key_mult: float = 1.0
+    attn_out_mult: float = 1.0
+    ssm_in_mult: float = 1.0
+    ssm_zone_mults: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_mult: float = 1.0
+    mlp_mults: Tuple[float, float] = (1.0, 1.0)
+    head_mult: float = 1.0
     # muP (MiniCPM): the residual branches times ``scale_depth /
     # sqrt(scale_depth_layers or num_layers)`` (the published depth, where
     # the model is a slice of it), the head's input times ``dim_model_base /
@@ -437,10 +511,33 @@ class TransformerConfig:
                     f"the {self.num_layers} layers")
             if "window_attn" in types and self.sliding_window <= 0:
                 raise ValueError("a window_attn layer needs sliding_window")
+            if "hybrid_ssm" in types and not (
+                    self.ssm_heads > 0 and self.ssm_head_dim > 0
+                    and self.ssm_state > 0 and self.ssm_conv > 1
+                    and self.ssm_heads % self.ssm_groups == 0):
+                raise ValueError(
+                    "a hybrid_ssm layer needs ssm_heads (a multiple of "
+                    "ssm_groups), ssm_head_dim, ssm_state and ssm_conv > 1")
+        for name in ("ssm_zone_mults", "mlp_mults"):
+            object.__setattr__(self, name, tuple(
+                float(m) for m in getattr(self, name)))
+        if len(self.ssm_zone_mults) != 5 or len(self.mlp_mults) != 2:
+            raise ValueError("ssm_zone_mults has five entries (z, x, B, C, "
+                             "dt), mlp_mults two (gate, output)")
 
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of an SSD mixer's values, all heads (``mamba_d_ssm``)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """Channels of an SSD mixer's convolution: [x | B | C]."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
     def type_runs(self) -> Tuple[Tuple[str, str, int, int], ...]:
@@ -544,7 +641,9 @@ class TransformerConfig:
         (bytes a token a layer, in the paged pool at 2 bytes a value) or
         ``state_slot`` (bytes a sequence a layer, whatever its length: a
         lightning layer's float32 state; a sparse layer's compressed keys for
-        ``max_seq_len`` tokens, which lie by slot beside its KV blocks). A
+        ``max_seq_len`` tokens, which lie by slot beside its KV blocks; a
+        ``hybrid_ssm`` layer's SSD state and convolution window together,
+        beside its KV blocks). A
         window layer's ``kv_blocks`` carry a third entry, the bound: the
         tokens behind which a block is freed (``bounded_cache``)."""
         declared = {}
@@ -557,7 +656,9 @@ class TransformerConfig:
             if rec.slots:
                 slot = jax.eval_shape(lambda: rec.slots(
                     self, 1, 0, self.max_seq_len, jnp.bfloat16))
-                kept += (("state_slot", slot.size * slot.dtype.itemsize),)
+                kept += (("state_slot", sum(
+                    a.size * a.dtype.itemsize
+                    for a in jax.tree.leaves(slot))),)
             declared.setdefault(kind if kind in LAYER_TYPES else "attn", kept)
         return declared
 
@@ -802,6 +903,14 @@ def _norm(x, scale, bias, kind: str, eps: float, weight_offset: float = 0.0):
         if bias is not None:
             y = y + bias.astype(jnp.float32)
     return y.astype(x.dtype)
+
+
+def _times(x, m: float):
+    """``x * m`` for a muP scalar ``m``, in float32 and back to ``x``'s dtype
+    (the scalar itself is not rounded to bfloat16); ``x`` where ``m`` is 1."""
+    if m == 1.0:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
 
 
 def _rope(q, k, positions, head_dim, theta, rotary_dim=None):
@@ -1575,11 +1684,15 @@ class TransformerLM:
         (:meth:`_held_experts`), the gated dense one otherwise. (its output,
         the expert layer's counts or None): the one call of the latent and of
         the ``layer_types`` layers."""
-        from ..moe.layer import _gated_mlp
-
         if "moe_wg" in blk:
             return self._held_experts(h, blk, experts, step)
-        return _gated_mlp(h, blk["w_gate"], blk["w_up"], blk["w_down"]), None
+        # W_down(W_up h * silu(W_gate h * gate)) * out (muP's two scalars: 1
+        # and no operation for every family but Falcon-H1's)
+        gate, out = self.config.mlp_mults
+        dt = h.dtype
+        y = (jax.nn.silu(_times(h @ blk["w_gate"].astype(dt), gate))
+             * (h @ blk["w_up"].astype(dt))) @ blk["w_down"].astype(dt)
+        return _times(y, out), None
 
     def _held_experts(self, h, blk, experts, step):
         """The expert layer of ``blk`` on the normed (B, S, H) ``h``: (its
@@ -1966,11 +2079,12 @@ class TransformerLM:
         return x, params["lm_head"], params.get("lm_head_bias"), False
 
     def _head(self, params, x):
+        cfg = self.config
         x, w, bias, vocab_major = self._head_operands(params, x)
         out = x @ (w.T if vocab_major else w).astype(x.dtype)  # (B,S,V)
         if bias is not None:
             out = out + bias.astype(x.dtype)
-        return out
+        return _times(out, cfg.head_mult)
 
     # ------------------------------------------------------------------
     def _hidden_aux(self, params, input_ids, positions=None, train=False, rng=None,
@@ -2373,13 +2487,15 @@ class TransformerLM:
             x = x + a * post(f, "post_mlp_scale")
         return x, caches, counts if stats is None else stats
 
-    def _attn_mixer(self, window, q, k, v, blk, caches, layer, pool_layer,
-                    step):
+    def _attn_mixer(self, window, rotary, q, k, v, blk, caches, layer,
+                    pool_layer, step):
         """GQA attention of this step's rows over their class of the paged
-        pool, a ``window`` layer (rotary on q and k; a query sees the last
-        ``sliding_window`` tokens; the window class's tables, which count
-        from the first block a sequence still holds: ``PagedStep.window``)
-        or a full one (no positional term; the whole context). A decode
+        pool, a ``window`` layer (a query sees the last ``sliding_window``
+        tokens; the window class's tables, which count from the first block
+        a sequence still holds: ``PagedStep.window``) or a full one (the
+        whole context), with ``rotary`` on q and k or no positional term
+        (afmoe: the window layers rotate, the full ones do not; a
+        ``hybrid_ssm`` layer is full with rotary). A decode
         round (rows apart) is one kernel call a layer, the new rows written
         on the way; a mixed step writes its rows by the scatter, its
         one-token rows go through the kernel and its tiles through the
@@ -2390,9 +2506,10 @@ class TransformerLM:
         pool = caches["pool"]
         T, nh, hd = q.shape
         cut, end, tile = step.cut, step.end, step.tile
-        if window:
+        if rotary:
             q, k = (a[:, 0] for a in _rope(q[:, None], k[:, None],
                                            step.positions, hd, cfg.rope_theta))
+        if window:
             tables, starts, limits, first = step.window
         else:
             tables, starts, limits, first = (step.tables, step.starts,
@@ -2422,6 +2539,124 @@ class TransformerLM:
                 o = jnp.concatenate([o, o2.reshape(-1, nh, hd)])
         o = jnp.pad(o, ((0, T - o.shape[0]), (0, 0), (0, 0)))
         return o, {"pool": pool}, None
+
+    def _hybrid_layer(self, x, blk, caches, layer, pool_layer, step,
+                      experts=None):
+        """One ``hybrid_ssm`` layer on (T, 1, H), a kind of
+        :meth:`forward_paged`: attention and the SSD mixer side by side off
+        one norm, then the feed-forward, each muP scalar where the published
+        forward has it::
+
+            h = N(x)
+            x = x + Attn(h * attn_in) * attn_out + SSM(h) * ssm_out
+            x = x + F(N(x))
+
+        The attention is :meth:`_attn_mixer` (full context, rotary; keys times
+        ``key_mult``) over the layer's KV blocks, the mixer :meth:`_ssm_mixer`
+        on the layer's slot (state and window), the feed-forward
+        :meth:`_feed_forward`."""
+        cfg = self.config
+        T, dt = x.shape[0], x.dtype
+        nh, hd = cfg.num_heads, cfg.head_dim
+        blk = _dequant_woq(blk, dt)
+        once = jax.lax.optimization_barrier
+        with jax.named_scope("attn"):
+            h = _norm(x[:, 0], blk["ln1_scale"], None, "rmsnorm", cfg.norm_eps)
+            ha = _times(h, cfg.attn_in_mult)
+            # behind barriers, as ``_typed_layer``'s: the products keep
+            # their own layout and the matrix is read where it lies
+            q, k, v = (once(ha @ blk[w].astype(dt)).reshape(T, -1, hd)
+                       for w in ("wq", "wk", "wv"))
+            o, kept, _ = self._attn_mixer(
+                False, True, q, _times(k, cfg.key_mult), v, blk,
+                {"pool": caches["pool"]}, layer, pool_layer, step)
+            a = _times(o.reshape(T, nh * hd).astype(dt)
+                       @ blk["wo"].astype(dt), cfg.attn_out_mult)
+            m, own = self._ssm_mixer(h, blk, caches["own"], layer, step)
+            x = once(x + (a + m)[:, None])
+        with jax.named_scope("mlp"):
+            h2 = _norm(x, blk["ln2_scale"], None, "rmsnorm", cfg.norm_eps)
+            with jax.named_scope("dense_ffn"):
+                f, _ = self._feed_forward(h2, blk, None, step)
+            x = x + f
+        return x, {"pool": kept["pool"], "own": own}, None
+
+    def _ssm_mixer(self, h, blk, own, layer, step):
+        """The SSD (Mamba-2) mixer of this step's rows on their sequences'
+        slots (``own``: ``ssm`` the float32 states, ``conv`` the
+        convolution's windows), ``h`` (T, H) the normed stream::
+
+            [z | xBC | dt] = (W_in (h * ssm_in)) * zone multipliers
+            [x | B | C]    = silu(conv(xBC) + b)         a window of ssm_conv
+            dt = softplus(dt + dt_bias);  A = -exp(a_log)
+            S  = exp(dt A) S + B^T (dt x);   y = C S + D x
+            out = W_out N_groups(y * silu(z)) * ssm_out
+
+        The one-token rows go through ``conv_rows`` / ``decode_rows``, the
+        tiles through ``conv_tiles`` / ``chunk_tiles``
+        (ops/transformer/linear_attention.py), the decay ``dt A`` their
+        operand, B and C a group's keys and queries, ``dt x`` the value."""
+        from ..ops.transformer import linear_attention as la
+
+        cfg = self.config
+        T, dt = h.shape[0], h.dtype
+        nh, hd, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                        cfg.ssm_state)
+        inner, ch = cfg.ssm_inner, cfg.ssm_conv_channels
+        cut, end, tile = step.cut, step.end, step.tile
+        slots, fresh = step.slots, step.starts == 0
+        tiles = slice(cut, end, tile)
+        f32 = jnp.float32
+
+        def rows_then_tiles(one, many, *xs):
+            """``one`` over the one-token rows of each of ``xs``, ``many``
+            over the tiles, the results joined and padded to T rows."""
+            y, carry = one(*(a[:cut] for a in xs))
+            if cut < end:
+                y2, carry = many(carry, *(
+                    a[cut:end].reshape(-1, tile, *a.shape[1:]) for a in xs))
+                y = jnp.concatenate([y, y2.reshape(-1, *y.shape[1:])])
+            return jnp.pad(y, ((0, T - y.shape[0]),)
+                           + ((0, 0),) * (y.ndim - 1)), carry
+
+        with jax.named_scope("ssm_mixer"):
+            p = jax.lax.optimization_barrier(
+                _times(h, cfg.ssm_in_mult) @ blk["ssm_w_in"].astype(dt))
+            zones = np.repeat(np.asarray(cfg.ssm_zone_mults, np.float32),
+                              [inner, inner, G * N, G * N, nh])
+            if not np.all(zones == 1.0):
+                p = (p.astype(f32) * zones).astype(dt)
+            z, xbc, dtr = p[:, :inner], p[:, inner:inner + ch], p[:, -nh:]
+            taps, bias = blk["ssm_conv_scale"], blk["ssm_conv_bias"]
+            y, conv = rows_then_tiles(
+                lambda x: la.conv_rows(own["conv"], layer, slots[:cut], x,
+                                       taps, bias, fresh[:cut]),
+                lambda conv, x: la.conv_tiles(
+                    conv, layer, slots[tiles], step.tile_counts, x, taps,
+                    bias, fresh[tiles]), xbc)
+            xbc = jax.nn.silu(y).astype(dt)
+            xs = xbc[:, :inner].reshape(T, nh, hd).astype(f32)
+            B, C = (xbc[:, inner + i * G * N:inner + (i + 1) * G * N]
+                    .reshape(T, G, N) for i in range(2))
+            step_dt = jax.nn.softplus(dtr.astype(f32)
+                                      + blk["dt_bias"].astype(f32))
+            decay = step_dt * -jnp.exp(blk["a_log"].astype(f32))   # <= 0
+            y, ssm = rows_then_tiles(
+                lambda *a: la.decode_rows(
+                    own["ssm"], layer, slots[:cut], *a[:3], fresh[:cut],
+                    log_decay=a[3], scope="ssm_scan"),
+                lambda ssm, *a: la.chunk_tiles(
+                    ssm, layer, slots[tiles], step.tile_counts, *a[:3],
+                    fresh[tiles], log_decay=a[3], scope="ssm_scan"),
+                C, B, xs * step_dt[:, :, None], decay)
+            y = y + blk["ssm_d_scale"].astype(f32)[None, :, None] * xs
+            y = y.reshape(T, inner) * jax.nn.silu(z.astype(f32))
+            # the gated norm, a group at a time (norm_before_gate false)
+            y = _norm(y.reshape(T, G, inner // G),
+                      blk["ssm_norm_scale"].reshape(G, -1), None, "rmsnorm",
+                      cfg.norm_eps).reshape(T, inner).astype(dt)
+            out = _times(y @ blk["ssm_w_out"].astype(dt), cfg.ssm_out_mult)
+        return out, {"ssm": ssm, "conv": conv}
 
     def _sparse_mixer(self, q, k, v, blk, caches, layer, pool_layer, step):
         """Block-sparse attention (ops/transformer/sparse_attention.py) of

@@ -1,12 +1,22 @@
-"""Lightning (linear) attention over per-sequence state slots.
+"""Linear recurrences over per-sequence state slots: lightning attention and
+the state-space duality (SSD, Mamba-2) form, one family.
 
-A lightning layer keeps no growing cache: per head one matrix ``S`` (key
-width x value width, float32) that every token decays and adds to::
+Such a layer keeps no growing cache: per head one matrix ``S`` (key width x
+value width, float32) that every token decays and adds to::
 
-    S_t = lambda_h * S_{t-1} + k_t^T v_t          o_t = q_t S_t
+    S_t = a_t,h * S_{t-1} + k_t^T v_t          o_t = q_t S_t
 
-``lambda_h`` is a constant of the head (:func:`head_decay`), the same in every
-layer and no parameter. The states of all lightning layers live in ONE array
+**The decay is an operand.** Lightning attention's ``a`` is a constant of the
+head, ``lambda_h`` (:func:`head_decay_rates`), the same in every layer and no
+parameter: a caller that passes no ``log_decay`` gets it. An SSD layer's is the
+token's own, ``exp(dt_t,h A_h)``: the caller passes ``log_decay`` ``(rows,
+heads)`` float32, never positive, so every power taken here is ``exp(<= 0)``.
+Keys and queries may be a group's: ``q, k (rows, groups, dk)`` beside ``v
+(rows, heads, dv)``, ``groups`` a divisor of ``heads`` and ``dk`` any width
+beside ``dv``. What else an SSD layer has (``dt`` on the value, the skip ``D
+x``, its gate and norm) is the caller's. Its causal convolution's window, the
+last ``taps - 1`` rows a sequence, is a second slot array of the same slots
+(:func:`conv_rows`, :func:`conv_tiles`). The states of all lightning layers live in ONE array
 ``(layers, slots, heads, dk, dv)`` float32, a **slot** a sequence: slot 0 is
 the trash slot that padding rows read and write (as block 0 of the KV pool
 is), a live sequence holds one of the others from admission to its end. The
@@ -29,18 +39,20 @@ Two forms of the same recurrence:
   overlapped copies from the slot array left in HBM; a padding row costs
   its compare and touches no slot, the trash slot included. It takes q, k, v
   and gives o as the model's ``(rows, heads * d)`` rows, a head a lane tile:
-  on a chip a model whose heads are not 128 wide keeps the XLA form;
+  on a chip a model whose values are not 128 wide, or whose keys are no
+  multiple of 128, keeps the XLA form;
 - :func:`chunk_tiles`: tiles of ``C`` consecutive tokens of one sequence (a
-  prefill chunk's segment), the blocked form
+  prefill chunk's segment), the blocked form, with ``c_i`` the running sum of
+  the tile's log-decays up to row ``i`` (lightning: ``-rate * (i + 1)``)
 
-      O   = ((Q K^T) * D) V + (Q * lambda^i) S_0
-      S_n = lambda^n S_0 + sum_j lambda^(n-j) k_j^T v_j
+      O   = ((Q K^T) * L) V + (Q * exp(c_i)) S_0
+      S_n = exp(c_n) S_0 + sum_j exp(c_n - c_j) k_j^T v_j
 
-  with ``D[i, j] = lambda^(i-j)`` for ``j <= i``. A tile may be valid only in
+  with ``L[i, j] = exp(c_i - c_j)`` for ``j <= i``. A tile may be valid only in
   its first ``n`` rows (the tail of a chunk); tiles are walked in row order,
   so two tiles of one sequence in one step see each other's state. Every
-  power of ``lambda`` is computed as ``exp(-rate * distance)`` with a distance
-  that is never negative: nothing overflows, and what underflows is zero.
+  power is ``exp`` of a difference that is never positive: nothing overflows,
+  and what underflows is zero.
 """
 
 import functools
@@ -85,27 +97,43 @@ def init_state(layers: int, slots: int, heads: int, dk: int, dv: int):
     return jnp.zeros((layers, 1 + slots, heads, dk, dv), jnp.float32)
 
 
-def decode_rows(state, layer, slots, q, k, v, fresh):
+def _to_heads(x, heads: int):
+    """``x`` (..., groups, d) with a group's row repeated for each of its
+    heads, (..., heads, d); itself where a head has its own."""
+    groups = x.shape[-2]
+    return x if groups == heads else jnp.repeat(x, heads // groups, axis=-2)
+
+
+def decode_rows(state, layer, slots, q, k, v, fresh, log_decay=None,
+                scope="linear_attn"):
     """One token a row. ``state``: the slot array; ``layer``: int32 scalar
-    (traced or not); ``slots`` (R,) int32, 0 for a padding row; q, k (R, h,
-    dk), v (R, h, dv), q already scaled; ``fresh`` (R,) bool: the row is its
-    sequence's first token. Returns (o (R, h, dv) float32, new state)."""
+    (traced or not); ``slots`` (R,) int32, 0 for a padding row; q, k (R, g,
+    dk), ``g`` the heads or a divisor of them (a group's keys and queries), v
+    (R, h, dv), q already scaled; ``fresh`` (R,) bool: the row is its
+    sequence's first token; ``log_decay`` (R, h) float32, never positive: the
+    row's own decay, or None for lightning's constant of the head. ``scope``:
+    the ``jax.named_scope`` the recurrence's operations are traced under.
+    Returns (o (R, h, dv) float32, new state)."""
     from .paged_attention import _interpret, kernels_wanted
 
+    H, G = v.shape[1], q.shape[1]
     # Mosaic takes rows whose heads are whole lane tiles; the interpreter any
-    lane_tiles = q.shape[2] == v.shape[2] == 128
-    if (kernels_wanted() and q.shape[1] % DECODE_HEADS == 0
+    lane_tiles = q.shape[2] % 128 == 0 and v.shape[2] == 128
+    if (kernels_wanted() and H % DECODE_HEADS == 0
+            and (G == H or (H // G) % DECODE_HEADS == 0)
             and (lane_tiles or _interpret())):
-        return linear_decode(state, layer, slots, q, k, v, fresh)
-    rates = jnp.asarray(head_decay_rates(q.shape[1]))
-    with jax.named_scope("linear_attn"):
+        return linear_decode(state, layer, slots, q, k, v, fresh, log_decay,
+                             scope)
+    with jax.named_scope(scope):
+        decay = jnp.exp(-jnp.asarray(head_decay_rates(H)))[None] \
+            if log_decay is None else jnp.exp(log_decay)
         s = state[layer, slots]                                # (R, h, dk, dv)
-        keep = jnp.where(fresh, 0.0, 1.0)[:, None] * jnp.exp(-rates)[None]
+        keep = jnp.where(fresh, 0.0, 1.0)[:, None] * decay
         s = s * keep[:, :, None, None] + (
-            k.astype(jnp.float32)[..., :, None]
+            _to_heads(k, H).astype(jnp.float32)[..., :, None]
             * v.astype(jnp.float32)[..., None, :])
-        o = jnp.einsum("rhk,rhkv->rhv", q.astype(jnp.float32), s,
-                       precision=_HI)
+        o = jnp.einsum("rhk,rhkv->rhv", _to_heads(q, H).astype(jnp.float32),
+                       s, precision=_HI)
         state = state.at[layer, slots].set(s)
     return o, state
 
@@ -119,15 +147,22 @@ def rows_per_cell(rows: int) -> int:
     return max(d for d in range(1, DECODE_CELL_ROWS + 1) if rows % d == 0)
 
 
-def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref, _,
-                   o_ref, s_ref, buf, rsem, wsem, live_ref, q32, k32, v32, *,
-                   n_heads):
+#: rows of a state block's key axis one piece of a live row's update holds
+KEY_PIECE = 128
+
+
+def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref,
+                   *rest, n_heads, operand, grouped):
     """Grid (R / rpc, heads / hb): ONE cell per ``rpc`` rows of the step
     (:func:`rows_per_cell`) and block of ``hb`` heads (:data:`DECODE_HEADS`).
-    q_ref, k_ref (rpc, hb * dk) and v_ref, o_ref (rpc, hb * dv) are blocks of
-    the model's lane-dense rows; ``s_ref`` is the whole slot array where it
-    lies in HBM, the call's aliased OUTPUT, read and written by the kernel's
-    own copies through ``buf`` (3, hb, dk, dv).
+    v_ref, o_ref (rpc, hb * dv) are blocks of the model's lane-dense rows;
+    q_ref, k_ref (rpc, hb * dk) likewise, or (``grouped``) the (rpc, dk) row
+    of the one group the block's heads share; ``s_ref`` is the whole slot
+    array where it lies in HBM, the call's aliased OUTPUT, read and written
+    by the kernel's own copies through ``buf`` (3, hb, dk, dv). With
+    ``operand`` a block ``d_ref`` (rpc, hb * dv) float32 comes behind v: each
+    row's own decay of each head, a head a lane tile; without, the decay is
+    lightning's constant of the head, made here.
 
     The first head block of a row cell lists the cell's live rows
     (``slots > 0``) in ``live_ref`` with a loop of scalar steps and leaves
@@ -145,8 +180,13 @@ def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref, _,
     write-back of pair ``g - 2`` is done. Only the first pair of a row cell
     waits for a fetch with nothing to hide it, and only its last head block
     waits for the write-backs to drain."""
+    d_ref = rest[0] if operand else None
+    (_, o_ref, s_ref, buf, rsem, wsem, live_ref, q32, k32,
+     v32) = rest[1 if operand else 0:]
     del _  # the same buffer as ``s_ref``: input_output_aliases
-    rpc, (nbuf, hb, dk, dv) = q_ref.shape[0], buf.shape
+    rpc, (nbuf, hb, dk, dv) = v_ref.shape[0], buf.shape
+    kp = min(dk, KEY_PIECE)      # a state block's key axis goes in pieces
+    pieces = dk // kp
     c, n_blocks = pl.program_id(1), pl.num_programs(1)
     row0 = pl.program_id(0) * rpc
     layer = layer_ref[0]
@@ -192,7 +232,7 @@ def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref, _,
         return jax.lax.slice(x, lo, hi)
 
     def spread(x):
-        return jax.lax.broadcast_in_dim(x, (dk, dv), (0, 1))
+        return jax.lax.broadcast_in_dim(x, (kp, dv), (0, 1))
 
     def heads_of(staged, r, d):
         """Row ``r`` of a staged ``(rpc, hb * d)`` block as (hb, d)."""
@@ -200,9 +240,31 @@ def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref, _,
         return jax.lax.concatenate([piece(flat, 1, h, d) for h in range(hb)],
                                    0)
 
+    def columns(staged, r):
+        """Row ``r`` of staged q or k a head a column: [(kp, hb)] a piece of
+        the key axis; of a group's row [(kp, dv)], the one column its heads
+        share, spread once."""
+        if not grouped:
+            rows = heads_of(staged, r, dk)
+            if pieces == 1:
+                return [rows.T]
+            return [piece(rows, 1, p, kp).T for p in range(pieces)]
+        # a piece of the row eight times over is the tile Mosaic transposes
+        # (a (1, 128) value it does not broadcast over sublanes)
+        flat = staged[pl.ds(r, 1), :]
+        return [spread(piece(jax.lax.concatenate(
+            [piece(flat, 1, p, kp)] * 8, 0).T, 1, 0)) for p in range(pieces)]
+
+    def column(cols, p, h):
+        return cols[p] if grouped else spread(piece(cols[p], 1, h))
+
+    def state_piece(slot, h, p):
+        return (slot, h) if pieces == 1 else (slot, h, pl.ds(p * kp, kp))
+
     def update(k, decay):
         """The cell's ``k``-th live row, its block in flight or arrived;
-        ``decay`` (hb, dv): lambda_h a row of lanes, a head a row."""
+        ``decay`` (hb, dv): lambda_h a row of lanes, a head a row (the
+        constant; with ``operand`` the row's own is read here)."""
         g = c * n_live + k
         slot, free = jax.lax.rem(g, nbuf), jax.lax.rem(g + 1, nbuf)
         last = k + 1 == n_live
@@ -219,20 +281,26 @@ def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref, _,
 
         r = live_ref[k]
         started = fresh_ref[row0 + r] == 0
-        # q and k a head a column, (dk, hb); v a head a row
-        q_col, k_col = heads_of(q32, r, dk).T, heads_of(k32, r, dk).T
+        # q and k a head a column; v a head a row
+        q_cols, k_cols = columns(q32, r), columns(k32, r)
         v_row = heads_of(v32, r, dv)
-        keep = jnp.where(started, decay, 0.0)
+        keep = jnp.where(started, heads_of(d_ref, r, dv) if operand
+                         else decay, 0.0)
         fetch(c, k, slot).wait()
         out = []
         for h in range(hb):
-            new = jax.lax.add(
-                jax.lax.mul(spread(piece(keep, 0, h)), buf[slot, h]),
-                jax.lax.mul(spread(piece(k_col, 1, h)),
-                            spread(piece(v_row, 0, h))))
-            buf[slot, h] = new
-            out.append(jnp.sum(jax.lax.mul(spread(piece(q_col, 1, h)), new),
-                               axis=0, keepdims=True))
+            read = None
+            for p in range(pieces):
+                at = state_piece(slot, h, p)
+                new = jax.lax.add(
+                    jax.lax.mul(spread(piece(keep, 0, h)), buf[at]),
+                    jax.lax.mul(column(k_cols, p, h),
+                                spread(piece(v_row, 0, h))))
+                buf[at] = new
+                part = jax.lax.mul(column(q_cols, p, h), new)
+                # the pieces folded before the one reduction over the rows
+                read = part if read is None else jax.lax.add(read, part)
+            out.append(jnp.sum(read, axis=0, keepdims=True))
         o_ref[pl.ds(r, 1), :] = jax.lax.concatenate(out, 1)
         store(c, k, slot).start()
         return decay
@@ -258,12 +326,17 @@ def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref, _,
             jax.lax.fori_loop(jnp.maximum(pairs - nbuf + 1, 0), pairs, wait, 0)
 
 
-def linear_decode(state, layer, slots, q, k, v, fresh):
+def linear_decode(state, layer, slots, q, k, v, fresh, log_decay=None,
+                  scope="linear_attn"):
     """:func:`decode_rows` as a Pallas kernel, in place on the slot array
     (left in HBM whole and aliased to the result). q, k, v go in and o comes
     out as the model holds them, ``(R, heads * d)`` lane-dense rows in their
-    own dtype (o float32): the column forms of q and k that the outer product
-    and the read-out need are made in the kernel, a live row at a time. The
+    own dtype (o float32; q and k ``(R, groups * dk)`` where a group shares
+    them): the column forms of q and k that the outer product and the
+    read-out need are made in the kernel, a live row at a time. A row's own
+    decay (``log_decay``) goes in as one more such block, ``exp`` taken and a
+    head's spread over its ``dv`` lanes here (rows x heads x dv x 4 bytes: a
+    five-hundredth of what the row's state moves). The
     grid is ``(R / rpc, heads / DECODE_HEADS)``; a cell lists its live rows
     (``slots > 0``) and walks them, moving each one's state block in, updating
     it where it lies in VMEM and moving it back with its own overlapped
@@ -273,60 +346,79 @@ def linear_decode(state, layer, slots, q, k, v, fresh):
     live rows may name one slot."""
     from .paged_attention import _interpret
 
-    R, H, dk = q.shape
+    R, H, dv = v.shape
+    G, dk = q.shape[1:]
+    decay = None if log_decay is None else jnp.broadcast_to(
+        jnp.exp(log_decay.astype(jnp.float32))[:, :, None],
+        (R, H, dv)).reshape(R, H * dv)
     o, state = _decode_call(
         jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
-        fresh.astype(jnp.int32), q.reshape(R, H * dk), k.reshape(R, H * dk),
-        v.reshape(R, -1), state, heads=H, hb=DECODE_HEADS,
-        rpc=rows_per_cell(R), interpret=_interpret())
-    return o.reshape(R, H, -1), state
+        fresh.astype(jnp.int32), q.reshape(R, G * dk), k.reshape(R, G * dk),
+        v.reshape(R, H * dv), decay, state, heads=H, groups=G,
+        hb=DECODE_HEADS, rpc=rows_per_cell(R), interpret=_interpret(),
+        scope=scope)
+    return o.reshape(R, H, dv), state
 
 
-@functools.partial(jax.jit, inline=True,
-                   static_argnames=("heads", "hb", "rpc", "interpret"))
-def _decode_call(layer, slots, fresh, q, k, v, state, *, heads, hb, rpc,
-                 interpret):
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "heads", "groups", "hb", "rpc", "interpret", "scope"))
+def _decode_call(layer, slots, fresh, q, k, v, decay, state, *, heads, groups,
+                 hb, rpc, interpret, scope):
     """The call of :func:`linear_decode`, its equations inlined into the
     program that holds it. Jitted for its cache alone: a process binds the
     kernel once a program (four in a serving process: the decode round and
     the mixed step of the check's engine and of the cell's), and the body's
     Python, a third of a second to a second a bind on a busy host, runs for
     the first of them (``kernel.setup_trace_s``)."""
-    R, dk, dv = q.shape[0], q.shape[1] // heads, v.shape[1] // heads
+    R, dk, dv = q.shape[0], q.shape[1] // groups, v.shape[1] // heads
+    grouped, operand = groups != heads, decay is not None
+    per_group = heads // groups
 
     def lanes(d):
         return pl.BlockSpec((rpc, hb * d), lambda i, c, *_: (i, c))
 
+    # a group's keys and queries: the one row its head blocks share
+    keys = pl.BlockSpec((rpc, dk), lambda i, c, *_: (
+        i, jax.lax.div(c * hb, per_group))) if grouped else lanes(dk)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)     # the slot array stays there
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, slots, fresh
         grid=(R // rpc, heads // hb),
-        in_specs=[lanes(dk), lanes(dk), lanes(dv), in_hbm],
+        in_specs=[keys, keys, lanes(dv), *([lanes(dv)] if operand else []),
+                  in_hbm],
         out_specs=[lanes(dv), in_hbm],
         scratch_shapes=[
             pltpu.VMEM((3, hb, dk, dv), state.dtype),
             pltpu.SemaphoreType.DMA((3,)),         # the fetches'
             pltpu.SemaphoreType.DMA((3,)),         # the write-backs'
             pltpu.SMEM((rpc + 1,), jnp.int32),     # the live rows, their count
-            *(pltpu.VMEM((rpc, hb * d), jnp.float32) for d in (dk, dk, dv)),
+            *(pltpu.VMEM((rpc, (1 if grouped else hb) * dk), jnp.float32)
+              for _ in "qk"),
+            pltpu.VMEM((rpc, hb * dv), jnp.float32),
         ],
     )
-    with jax.named_scope("linear_attn"):
+    block_bytes = hb * dk * dv * state.dtype.itemsize
+    with jax.named_scope(scope):
         return tracing.pallas_call(
-            functools.partial(_decode_kernel, n_heads=heads),
+            functools.partial(_decode_kernel, n_heads=heads, operand=operand,
+                              grouped=grouped),
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((R, heads * dv), jnp.float32),
                        jax.ShapeDtypeStruct(state.shape, state.dtype)],
-            input_output_aliases={6: 1},  # the slot array, scalars counted
+            # the slot array, scalars counted
+            input_output_aliases={7 if operand else 6: 1},
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
             name="linear_decode",
-        )(layer, slots, fresh, q, k, v, state)
+            attrs=dict(state_block_bytes=block_bytes, dk=dk, dv=dv,
+                       rows_per_cell=rpc, groups=groups,
+                       decay="operand" if operand else "head"),
+        )(layer, slots, fresh, q, k, v, *([decay] if operand else []), state)
 
 
 def _tile_decays(n_heads: int, tile: int):
-    """The constants of a tile: ``D`` (h, C, C) and the row decays
+    """The constants of a lightning tile: ``D`` (h, C, C) and the row decays
     ``lambda^i`` (h, C), ``i`` counted from 1."""
     rates = head_decay_rates(n_heads).astype(np.float64)[:, None, None]
     i = np.arange(tile)
@@ -336,36 +428,107 @@ def _tile_decays(n_heads: int, tile: int):
     return d.astype(np.float32), rows.astype(np.float32)
 
 
-def chunk_tiles(state, layer, slots, counts, q, k, v, fresh):
+def chunk_tiles(state, layer, slots, counts, q, k, v, fresh, log_decay=None,
+                scope="linear_attn"):
     """Tiles of ``C`` consecutive tokens. ``slots`` (N,) int32 the slot of
     each tile's sequence (0: an empty tile); ``counts`` (N,) int32 the valid
-    rows of each tile, a prefix of it; q, k (N, C, h, dk), v (N, C, h, dv), q
-    already scaled; ``fresh`` (N,) bool: the tile starts its sequence. Returns
-    (o (N, C, h, dv) float32, new state). Rows past a tile's count give
-    garbage that nothing reads and add nothing to the state."""
-    N, C, H, _ = q.shape
-    rates = jnp.asarray(head_decay_rates(H))
-    d_const, row_decay = (jnp.asarray(a) for a in _tile_decays(H, C))
+    rows of each tile, a prefix of it; q, k (N, C, g, dk), ``g`` the heads or
+    a divisor of them, v (N, C, h, dv), q already scaled; ``fresh`` (N,)
+    bool: the tile starts its sequence; ``log_decay`` (N, C, h) float32,
+    never positive, or None for lightning's constant of the head. Returns (o
+    (N, C, h, dv) float32, new state). Rows past a tile's count give garbage
+    that nothing reads and add nothing to the state."""
+    N, C, H, _ = v.shape
     idx = jnp.arange(C)
+    if log_decay is None:
+        rates = jnp.asarray(head_decay_rates(H))
+        d_const, row_decay = (jnp.asarray(a) for a in _tile_decays(H, C))
+
+    def decays(n, ld):
+        """What a tile of ``n`` valid rows needs of its decays: ``L`` (h, C,
+        C), the rows' ``exp(c_i)`` (h, C), each valid row's part in the state
+        the tile leaves ``exp(c_n - c_j)`` (h, C), nothing for the rest, and
+        ``exp(c_n)`` (h,)."""
+        valid = idx < n
+        if ld is None:
+            # lambda^(n-1-j) for the valid rows j < n
+            w = jnp.where(valid[None], jnp.exp(
+                -rates[:, None] * jnp.maximum(n - 1 - idx, 0)[None]), 0.0)
+            return d_const, row_decay, w, jnp.exp(-rates * n)
+        c = jnp.cumsum(jnp.where(valid[:, None], ld, 0.0), axis=0).T  # (h, C)
+        lower = idx[:, None] >= idx[None, :]
+        d = jnp.where(lower[None], jnp.exp(jnp.minimum(
+            c[:, :, None] - c[:, None, :], 0.0)), 0.0)
+        total = c[:, -1]            # the valid rows' sum: the rest add zero
+        w = jnp.where(valid[None], jnp.exp(
+            jnp.minimum(total[:, None] - c, 0.0)), 0.0)
+        return d, jnp.exp(c), w, jnp.exp(total)
 
     def tile(state, args):
-        slot, n, q, k, v, fresh = args
-        with jax.named_scope("linear_attn"):
-            q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        slot, n, q, k, v, fresh, ld = args
+        with jax.named_scope(scope):
+            q, k = (_to_heads(a, H).astype(jnp.float32) for a in (q, k))
+            v = v.astype(jnp.float32)
+            d, rows, w, total = decays(n, ld)
             s0 = jnp.where(fresh, 0.0, 1.0) * state[layer, slot]  # (h, dk, dv)
-            a = jnp.einsum("ihk,jhk->hij", q, k) * d_const
+            a = jnp.einsum("ihk,jhk->hij", q, k) * d
             o = (jnp.einsum("hij,jhv->ihv", a, v)
                  + jnp.einsum("ihk,hkv->ihv", q, s0, precision=_HI)
-                 * row_decay.T[:, :, None])
-            # a row's part in the state the tile leaves: lambda^(n-1-j) for
-            # the valid rows j < n, nothing for the rest
-            w = jnp.where(idx[None] < n, jnp.exp(
-                -rates[:, None] * jnp.maximum(n - 1 - idx, 0)[None]), 0.0)
-            s = (jnp.exp(-rates * n)[:, None, None] * s0
+                 * rows.T[:, :, None])
+            s = (total[:, None, None] * s0
                  + jnp.einsum("jhk,jhv->hkv", k * w.T[:, :, None], v,
                               precision=_HI))
             state = state.at[layer, slot].set(s)
         return state, o
 
-    state, o = jax.lax.scan(tile, state, (slots, counts, q, k, v, fresh))
+    state, o = jax.lax.scan(
+        tile, state, (slots, counts, q, k, v, fresh, log_decay))
     return o, state
+
+
+# -- the causal convolution before an SSD layer's recurrence ----------------
+
+def init_conv(layers: int, slots: int, taps: int, channels: int, dtype):
+    """The zeroed window array ``(layers, 1 + slots, taps - 1, channels)``: a
+    sequence's last ``taps - 1`` rows of the convolution's input, the oldest
+    first; slot 0 is the trash slot."""
+    return jnp.zeros((layers, 1 + slots, taps - 1, channels), dtype)
+
+
+def conv_rows(window, layer, slots, x, taps, bias, fresh):
+    """The depthwise causal convolution of one token a row: ``y = sum_i
+    taps[i] * x_{t-K+1+i} + bias`` over the slot's ``K - 1`` rows and the
+    row's own. ``window``: :func:`init_conv`'s array; ``slots`` (R,) int32;
+    ``x`` (R, ch); ``taps`` (K, ch), the oldest first; ``bias`` (ch,);
+    ``fresh`` (R,) bool: the row starts its sequence, on zeros whatever the
+    slot held. Returns (y (R, ch) float32, the window with each row's slot
+    moved on by its row)."""
+    past = jnp.where(fresh[:, None, None], 0, window[layer, slots])
+    full = jnp.concatenate([past, x[:, None].astype(window.dtype)], axis=1)
+    y = jnp.sum(full.astype(jnp.float32) * taps.astype(jnp.float32)[None],
+                axis=1) + bias.astype(jnp.float32)
+    return y, window.at[layer, slots].set(full[:, 1:])
+
+
+def conv_tiles(window, layer, slots, counts, x, taps, bias, fresh):
+    """:func:`conv_rows` over tiles of ``C`` consecutive tokens: a tile reads
+    the ``K - 1`` rows before its first from its slot (zeros where ``fresh``)
+    and leaves its last ``K - 1`` valid ones there. ``slots``, ``counts``,
+    ``fresh`` (N,) as :func:`chunk_tiles` takes them, ``x`` (N, C, ch); tiles
+    are walked in order, so a tile sees what the tile before it left. Returns
+    (y (N, C, ch) float32, new window)."""
+    K, C = taps.shape[0], x.shape[1]
+    w, b = taps.astype(jnp.float32), bias.astype(jnp.float32)
+
+    def tile(window, args):
+        slot, n, x, fresh = args
+        past = jnp.where(fresh, 0, window[layer, slot])       # (K - 1, ch)
+        full = jnp.concatenate([past, x.astype(window.dtype)])
+        f32 = full.astype(jnp.float32)
+        y = sum(w[i] * f32[i:i + C] for i in range(K)) + b
+        # the tokens n - K + 1 .. n - 1, counted from the tile's first
+        last = jax.lax.dynamic_slice_in_dim(full, n, K - 1)
+        return window.at[layer, slot].set(last), y
+
+    window, y = jax.lax.scan(tile, window, (slots, counts, x, fresh))
+    return y, window

@@ -104,6 +104,7 @@ _LONGEST_FIRST = (
     "tests/unit/test_pool.py",
     "tests/benchmark/test_minicpm_sala.py",
     "tests/benchmark/test_afmoe.py",
+    "tests/benchmark/test_falcon_h1.py",
     "tests/unit/test_extras.py",
     "tests/unit/test_flash_layout.py",
     "tests/unit/test_pipe.py",

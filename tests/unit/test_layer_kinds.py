@@ -2,7 +2,7 @@
 ``LAYER_KINDS``, which the tree, the specs, the counts, the cache declaration,
 the paged forward and the tracer read.
 
-For each of the seven kinds, a model of that kind alone at tiny widths:
+For each of the eight kinds, a model of that kind alone at tiny widths:
 (a) ``num_parameters`` is the size of the tree ``init_params`` builds;
 (b) ``tp_specs`` has that tree's structure, leaf for leaf, a spec entry an
 axis (before PR 64 a ``layer_types`` model got the GPT-2 tree's specs under
@@ -11,8 +11,9 @@ the key ``blocks`` beside its own ``blocks_0``, ...);
 round that are no model or serving scope are the ones its record declares
 (and the held experts', which ``moe/layer.py`` declares), and
 ``tracing.classify`` gives each back.
-And (d) what the five serving configurations' layer patterns declare to the
-engine, as literals read off the parent commit.
+And (d) what the serving configurations' layer patterns declare to the
+engine, as literals read off the parent commit (the sixth, PR 66's, off its
+own: a kind that keeps KV blocks and a slot of two arrays).
 """
 
 import math
@@ -34,6 +35,22 @@ from tests.unit.test_served_weight_reads import (double_layers, gpt2_family,
 BLOCK, NUM_BLOCKS, MAXB, ROWS = 16, 24, 4, 4
 #: declared by ``moe/layer.py``: the feed-forward's, whatever the kind
 MOE_SCOPES = {"moe_route", "moe_experts", "moe_shared", "moe_zero"}
+
+
+def hybrid():
+    """The ``falcon-h1-34b-instruct`` rehearsal's pattern: attention and an
+    SSD mixer side by side in every layer, every muP scalar on."""
+    return TransformerConfig(
+        vocab_size=256, hidden_size=128, num_layers=3, num_heads=4,
+        num_kv_heads=2, head_dim_override=32, intermediate_size=256,
+        max_seq_len=128, pos_embedding="rope", norm="rmsnorm",
+        activation="swiglu", tie_embeddings=False, rope_theta=1e11,
+        layer_types=("hybrid_ssm",) * 3, linear_chunk=16, ssm_heads=4,
+        ssm_head_dim=32, ssm_groups=2, ssm_state=16, ssm_conv=4,
+        embed_scale=5.657, attn_in_mult=1.0, key_mult=0.011,
+        attn_out_mult=0.0375, ssm_in_mult=0.25,
+        ssm_zone_mults=(0.354, 0.25, 0.177, 0.5, 0.354), ssm_out_mult=0.0884,
+        mlp_mults=(0.177, 0.0112), head_mult=0.0078125)
 
 
 def of_types(*types):
@@ -60,6 +77,7 @@ ALONE = {
         "layer_types": ("linear_attn", "linear_attn")}),
     "window_attn": lambda: of_types("window_attn", "window_attn"),
     "full_attn": lambda: of_types("full_attn", "full_attn"),
+    "hybrid_ssm": hybrid,
 }
 
 
@@ -180,7 +198,27 @@ DECLARED = {
         pool_layers=4, class_layers={"full": 1, "window": 3},
         kv_row=(16, 16), pool_heads=2, segment_tile=16,
         step_counts=("moe_rows", "moe_rows_max"))),
+    # KV blocks of 2 heads of 32 + 32, and a slot of two arrays: the float32
+    # state (4 heads x 16 x 32) and the window's 3 rows of 128 + 2 x 2 x 16
+    "falcon-h1-34b-instruct": (hybrid, dict(
+        type_runs=(("blocks_0", "hybrid_ssm", 3, 1),),
+        cache_kinds={"hybrid_ssm": (("kv_blocks", 256),
+                                    ("state_slot", 8192 + 3 * 192 * 2))},
+        pool_layers=3, class_layers={"full": 3}, kv_row=(32, 32),
+        pool_heads=2, segment_tile=16, step_counts=())),
 }
+
+
+def test_a_slot_may_be_a_small_tree_of_arrays():
+    """``init_state_cache`` builds what the record's ``slots`` gives, a slot
+    a row index into every array of it; ``cache_kinds`` sums their bytes."""
+    model = TransformerLM(hybrid())
+    state = model.init_state_cache(5, 128, dtype=jnp.bfloat16)
+    assert jax.tree.map(lambda a: (a.shape, a.dtype.name), state) == {
+        "blocks_0": {"ssm": ((3, 6, 4, 16, 32), "float32"),
+                     "conv": ((3, 6, 3, 192), "bfloat16")}}
+    assert dict(model.config.cache_kinds["hybrid_ssm"])["state_slot"] \
+        == 8192 + 3 * 192 * 2
 
 
 @pytest.mark.parametrize("name", sorted(DECLARED))
