@@ -866,11 +866,40 @@ def mla_blocks_per_trip(q_rows: int, pool) -> int:
     return max(1, min(MLA_TRIP_BLOCKS, MLA_SCORE_BYTES // (q_rows * BS * 4)))
 
 
+def _mla_trip(q_lat, q_rope, kv, first, lim, m_ref, l_ref, acc_ref, *, rank,
+              scale):
+    """One trip of the latent kernels' running softmax (``m``, ``l``, ``acc``
+    in float32 scratch): the cell's query rows against the fetched latent
+    rows ``kv``, tokens ``first`` on of the sequence, a query row seeing the
+    tokens below its ``lim`` (a scalar, or a column a row). Scores
+    ``q_lat . c_kv + q_rope . k_rope`` in float32 from the operands as they
+    lie, ``p`` cast once, the weighted latent accumulated."""
+    c_kv, k_rope = kv[:, :rank], kv[:, rank:rank + q_rope.shape[-1]]
+    dims = (((1,), (1,)), ((), ()))
+    s = (jax.lax.dot_general(q_lat, c_kv, dims,
+                             preferred_element_type=jnp.float32)
+         + jax.lax.dot_general(q_rope, k_rope, dims,
+                               preferred_element_type=jnp.float32)) * scale
+    kpos = first + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(kpos < lim, s, NEG_INF)                 # (Q, width)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(kv.dtype), c_kv, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
 def _mla_kernel(layer_ref, tables_ref, nblk_ref, ql_ref, qr_ref, lim_ref,
                 pool_ref, o_ref, buf, sem, m_ref, l_ref, acc_ref, slot_ref, *,
                 block_size, rank, scale, kv_blocks):
-    """Grid (tiles,): ONE cell per query tile. The tile's ``Q`` rows (its
-    tokens times all heads) are the absorbed queries ``[q_nope W_uk | q_rope]``
+    """The segment form (``q_tile`` > 1; one-token rows take
+    :func:`_mla_rows_kernel`). Grid (tiles,): ONE cell per query tile. The
+    tile's ``Q`` rows (its tokens times all heads) are the absorbed queries
+    ``[q_nope W_uk | q_rope]``
     of tokens of ONE sequence; the kernel streams that sequence's pool blocks
     ``pool[layer, 0, tables[t, j]]`` from HBM, up to ``kv_blocks`` a trip and
     double-buffered, and contracts every head with each latent tile once:
@@ -929,25 +958,9 @@ def _mla_kernel(layer_ref, tables_ref, nblk_ref, ql_ref, qr_ref, lim_ref,
     def attend(j, slot, w):
         """Trip ``j``'s first ``w`` blocks of buffer ``slot`` into the
         running softmax."""
-        kv = buf[slot, :w * block_size]                   # (width, row)
-        c_kv, k_rope = kv[:, :rank], kv[:, rank:rank + qr_ref.shape[-1]]
-        dims = (((1,), (1,)), ((), ()))
-        s = (jax.lax.dot_general(ql_ref[0], c_kv, dims,
-                                 preferred_element_type=jnp.float32)
-             + jax.lax.dot_general(qr_ref[0], k_rope, dims,
-                                   preferred_element_type=jnp.float32)) * scale
-        kpos = j * (kv_blocks * block_size) + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < lim_ref[0], s, NEG_INF)       # (Q, width)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(kv.dtype), c_kv, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        _mla_trip(ql_ref[0], qr_ref[0], buf[slot, :w * block_size],
+                  j * (kv_blocks * block_size), lim_ref[0], m_ref, l_ref,
+                  acc_ref, rank=rank, scale=scale)
 
     def body(j, _):
         slot = jax.lax.rem(slot0 + j, 2)
@@ -996,6 +1009,281 @@ def _mla_kernel(layer_ref, tables_ref, nblk_ref, ql_ref, qr_ref, lim_ref,
         slot_ref[0] = jax.lax.rem(slot0 + n_trips, 2)
 
 
+#: buffers of a trip each that the one-token latent kernel's fetches go round,
+#: three trips ahead of the products. One more than :data:`DECODE_SLOTS`: a
+#: trip's fetch is started from inside the block of its products (behind them
+#: in the program's order, so that the compiler schedules the copies' scalar
+#: work among the products), which is a trip later than in front of them. On
+#: the chip, us a call of 96 full | 39 of 96 | 6 of 32 live rows: two buffers
+#: 373.6 | 148.9 | 74.3, three 247.1 | 100.2 | 54.4, four 230.5 | 94.1 | 53.6,
+#: and the fetches alone, with no products, 231.1 | 93.0 | 51.2 (PERF.md 5)
+MLA_SLOTS = 4
+
+
+def _mla_rows_kernel(layer_ref, tables_ref, lim_ref, ql_hbm, qr_hbm, pool_ref,
+                     o_hbm, buf, sem, live_ref, m_ref, l_ref, acc_ref, ql, qr,
+                     qsem, obuf, osem, zeros, zsem, *, block_size, rank, scale,
+                     bpt):
+    """The one-token form of :func:`mla_decode`: ONE cell walks all the
+    step's rows. Each row's 64 x 576 queries and 64 x 512 result are 138 KB,
+    so they stay in HBM like the pool, and the cell moves what a LIVE row
+    needs by its own copies: blocks of rows through Pallas' pipeline would
+    move every row's, dead or alive (13 MB a call of 96 rows beside the live
+    rows' ~58 MB of latent).
+
+    The cell sorts the rows by what ``limits`` says in a loop of scalar steps
+    (the live rows' numbers go to ``live_ref`` in order) and starts its first
+    fetches. A row with ``limits[b] == 0`` is dead: it costs that compare and
+    one copy of 64 KB of zeros from a zeroed buffer to its row of the result,
+    and a call with no live row starts no fetch. Then the cell walks its live
+    rows: a row streams the ``ceil(limits / BS)`` blocks it has,
+    ``pool[layer, 0, tables[b, i]]``, ``bpt`` a trip
+    (:func:`mla_blocks_per_trip`), and contracts every head with each latent
+    tile once: scores ``q_lat . c_kv + q_rope . k_rope`` in float32 from the
+    operands as they lie, masked by ``limits``, the running softmax in
+    float32, ``p`` cast once a trip, and the weighted LATENT as the result.
+    A trip takes what the row has left and is computed at half the buffer's
+    width where that holds it (what lies behind the fetched blocks is masked;
+    the ring is zeroed once a call, so it is zeros or older pool blocks,
+    finite either way). What a live row computes does not depend on its
+    neighbours.
+
+    The live rows' trips are ONE STREAM, row behind row, through a ring of
+    ``slots`` buffers (:data:`MLA_SLOTS`): the fetches' cursor ``(k, j)``,
+    trip ``j`` of the ``k``-th live row, runs ``slots - 1`` trips ahead of
+    the products, over a row's end into the next live row's first trip, so
+    only the call's first fetch has nothing to hide it. A row's ``q_lat``
+    (64 KB, a ring of its own) rides with its first trip; ``q_rope`` comes
+    whole, once a call (a DMA cannot slice rows of 64 lanes); a row's result
+    leaves through ``obuf`` by a copy that the next live row waits for.
+
+    The copies of a trip are straight code, each under its own condition, and
+    the fetch stands inside the block of the products: the compiler turns
+    them into predicated instructions and schedules their scalar work among
+    the products (in a loop of their own, sixteen copies cost a trip a third
+    of its products' time with nothing beside them). Mosaic's bounds checks
+    are off for the same reason: twenty scalar bundles a copy."""
+    rows = live_ref.shape[0]
+    layer = layer_ref[0]
+    slots = buf.shape[0]
+    half = bpt // 2
+    span = bpt * block_size
+    div, rem = jax.lax.div, jax.lax.rem
+
+    def blocks(b):
+        return div(lim_ref[b] + (block_size - 1), block_size)
+
+    def trips(b):
+        return div(blocks(b) + (bpt - 1), bpt)
+
+    def sort_row(b, n_live):
+        # a dead row's number is overwritten by the next live row's
+        live_ref[n_live] = b
+        return n_live + (lim_ref[b] > 0).astype(jnp.int32)
+
+    n_live = jax.lax.fori_loop(0, rows, sort_row, 0)
+
+    def live_row(k):
+        """The ``k``-th live row (row 0 behind the last)."""
+        return jax.lax.select(k < n_live, live_ref[jax.lax.min(k, rows - 1)], 0)
+
+    def each_copy(b, j, slot, act, on=True):
+        """``act`` on the copies of trip ``j`` of row ``b`` into buffer
+        ``slot``: one a block the row has left, ``bpt`` at most, none unless
+        ``on``. ``lax`` on the scalars: a ``jnp`` operator is a traced call
+        each."""
+        left, first = blocks(b) - j * bpt, j * bpt
+        if on is not True:
+            left = jax.lax.select(on, left, 0)
+        for i in range(bpt):
+            @pl.when(jax.lax.gt(left, jnp.int32(i)))
+            def _one():
+                act(pltpu.make_async_copy(
+                    pool_ref.at[layer, 0,
+                                tables_ref[b, jax.lax.add(first, jnp.int32(i))]],
+                    buf.at[slot, pl.ds(i * block_size, block_size)],
+                    sem.at[slot, i]))
+
+    def queries(b, k):
+        """Row ``b``'s latent queries into the slot of the ``k``-th live
+        row."""
+        s = rem(k, slots)
+        return pltpu.make_async_copy(ql_hbm.at[b], ql.at[s], qsem.at[s])
+
+    def rope_queries():
+        return pltpu.make_async_copy(qr_hbm, qr, qsem.at[slots])
+
+    def fetch(t, cursor):
+        """Start the fetch the cursor stands at, ``(k, j)``: trip ``j`` of the
+        ``k``-th live row, the ``t``-th trip of the call, into the slot whose
+        turn it is (a row's queries with its first trip); nothing behind the
+        last live row."""
+        k, j = cursor
+        b = live_row(k)
+
+        @pl.when((k < n_live) & (j == 0))
+        def _queries():
+            queries(b, k).start()
+
+        each_copy(b, j, rem(t, slots), lambda c: c.start(), k < n_live)
+
+    def moved_on(cursor):
+        k, j = cursor
+        row_ends = j + 1 >= trips(live_row(k))
+        return (jax.lax.select(row_ends, k + 1, k),
+                jax.lax.select(row_ends, 0, j + 1))
+
+    @pl.when(n_live > 0)
+    def _rope():
+        rope_queries().start()
+
+    def prime(t, cursor):
+        # what lies behind a short trip's blocks is masked, and has to be
+        # finite: zeros, or older pool blocks
+        buf[t] = jnp.zeros(buf.shape[1:], buf.dtype)
+        fetch(t, cursor)
+        return moved_on(cursor)
+
+    some = (n_live > 0).astype(jnp.int32)
+    cursor = jax.lax.fori_loop(0, some * (slots - 1), prime,
+                               (jnp.int32(0), jnp.int32(0)))
+
+    @pl.when(n_live > 0)
+    def _last_slot():
+        buf[slots - 1] = jnp.zeros(buf.shape[1:], buf.dtype)
+
+    def zero_store(b):
+        return pltpu.make_async_copy(zeros, o_hbm.at[b], zsem.at[0])
+
+    def result_store(b):
+        return pltpu.make_async_copy(obuf, o_hbm.at[b], osem.at[0])
+
+    # every dead row's result is zeros (on their way while the first fetches
+    # arrive)
+    zeros[...] = jnp.zeros(zeros.shape, zeros.dtype)
+
+    def zero_row(b, _):
+        @pl.when(lim_ref[b] == 0)
+        def _dead():
+            zero_store(b).start()
+
+        return 0
+
+    jax.lax.fori_loop(0, rows, zero_row, 0)
+
+    @pl.when(n_live > 0)
+    def _rope_is_here():
+        rope_queries().wait()
+
+    def row(k, stream):
+        """The ``k``-th live row; ``stream`` is the number of the call's
+        trips so far and the fetches' cursor."""
+        b = live_ref[k]
+        lim, nblk = lim_ref[b], blocks(b)
+        queries(b, k).wait()
+        qs = rem(k, slots)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def body(j, carry):
+            t, cursor = carry
+            slot = rem(t, slots)
+            each_copy(b, j, slot, lambda c: c.wait())
+
+            def trip(w):
+                # the products, and in their block the fetch ``slots - 1``
+                # trips ahead, into the slot left last
+                _mla_trip(ql[qs], qr[b], buf[slot, :w * block_size], j * span,
+                          lim, m_ref, l_ref, acc_ref, rank=rank, scale=scale)
+                fetch(t + slots - 1, cursor)
+
+            if half:
+                fits_half = nblk - j * bpt <= half
+                pl.when(fits_half)(lambda: trip(half))
+                pl.when(jnp.logical_not(fits_half))(lambda: trip(bpt))
+            else:
+                trip(bpt)
+            return t + 1, moved_on(cursor)
+
+        stream = jax.lax.fori_loop(0, trips(b), body, stream)
+
+        @pl.when(k > 0)
+        def _obuf_is_free():
+            result_store(b).wait()   # the row's before: same bytes
+
+        # a live row sees a key
+        obuf[...] = (acc_ref[...] / l_ref[...]).astype(obuf.dtype)
+        result_store(b).start()
+        return stream
+
+    jax.lax.fori_loop(0, n_live, row, (jnp.int32(0), cursor))
+
+    @pl.when(n_live > 0)
+    def _last_result():
+        result_store(0).wait()
+
+    def zero_done(_, carry):
+        zero_store(0).wait()
+        return carry
+
+    jax.lax.fori_loop(0, rows - n_live, zero_done, 0)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("scale", "interpret"))
+def _mla_rows(q_lat, q_rope, pool, layer, tables, limits, *, scale, interpret):
+    """:func:`mla_decode` at ``q_tile`` 1: :func:`_mla_rows_kernel` over the
+    step's rows, queries and result left in HBM. Jitted for its cache alone,
+    its equations inlined into the program that holds it (as
+    ``linear_attention._decode_call``): a serving process binds the kernel
+    once a pool sublayer of each program, and the body's Python, with its
+    copies unrolled half a second a bind on a busy host, runs for the first
+    of them (``kernel.setup_trace_s``)."""
+    N, nh, rank = q_lat.shape
+    rope = q_rope.shape[-1]
+    _, _, _, BS, row = pool.shape
+    bpt = mla_blocks_per_trip(nh, pool)
+    pad = -tables.shape[1] % bpt
+    if pad:    # a whole number of trips: a copy's condition is its own, and
+        # the table is read before it is asked; the padding is never fetched
+        tables = jnp.pad(tables, ((0, 0), (0, pad)))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return tracing.pallas_call(
+        functools.partial(_mla_rows_kernel, block_size=BS, rank=rank,
+                          scale=scale, bpt=bpt),
+        attrs={"rows_per_cell": N, "slots": MLA_SLOTS, "blocks_per_trip": bpt,
+               "trip_bytes": bpt * BS * row * pool.dtype.itemsize,
+               "operand_dtype": pool.dtype.name},
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # layer, tables, limits
+            grid=(1,),
+            in_specs=[in_hbm, in_hbm, in_hbm], out_specs=in_hbm,
+            scratch_shapes=[
+                pltpu.VMEM((MLA_SLOTS, bpt * BS, row), pool.dtype),  # the ring
+                pltpu.SemaphoreType.DMA((MLA_SLOTS, bpt)),
+                pltpu.SMEM((N,), jnp.int32),          # the live rows
+                pltpu.VMEM((nh, 1), jnp.float32),     # m
+                pltpu.VMEM((nh, 1), jnp.float32),     # l
+                pltpu.VMEM((nh, rank), jnp.float32),  # acc
+                pltpu.VMEM((MLA_SLOTS, nh, rank), q_lat.dtype),  # q_lat's ring
+                pltpu.VMEM((N, nh, rope), q_rope.dtype),
+                pltpu.SemaphoreType.DMA((MLA_SLOTS + 1,)),
+                pltpu.VMEM((nh, rank), q_lat.dtype),  # a live row's result
+                pltpu.SemaphoreType.DMA((1,)),
+                pltpu.VMEM((nh, rank), q_lat.dtype),  # a dead row's
+                pltpu.SemaphoreType.DMA((1,)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q_lat.shape, q_lat.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024,
+            disable_bounds_checks=True),
+        interpret=interpret,
+        name="mla_decode",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables,
+      limits.astype(jnp.int32), q_lat, q_rope, pool)
+
+
 def mla_decode(q_lat, q_rope, pool, layer, tables, limits, *, scale,
                q_tile: int = 1):
     """Absorbed latent attention of layer ``layer`` against the stacked
@@ -1012,21 +1300,32 @@ def mla_decode(q_lat, q_rope, pool, layer, tables, limits, *, scale,
     is streamed once a tile, not once a token. Returns the weighted latent
     (N, nh, rank) in q_lat's dtype.
 
-    A row with ``limits`` 0 is dead, and a tile all of whose rows are dead is
-    a dead cell: it fetches nothing and its output is zeros. A dead row
-    inside a live tile sees nothing and gets a finite value nobody reads. As
-    for :func:`paged_decode` the CALLER decides which rows are dead (the
-    model: a row whose table names no block); the kernel never reads deadness
-    out of the table, and a row with ``limits`` 1 and an all-zero table
-    attends to the trash block's first token. A live cell fetches the blocks
-    its largest ``limits`` reaches, :func:`mla_blocks_per_trip` a trip, and
-    never the table's padding."""
+    A row with ``limits`` 0 is dead. At ``q_tile`` 1 (:func:`_mla_rows_kernel`)
+    one cell walks all the step's rows: a dead row costs a step of a scalar
+    loop and a copy of zeros to its row of the result, a call with no live row
+    starts no fetch, and the live rows' trips are one stream through a ring of
+    :data:`MLA_SLOTS` buffers, the fetches ``MLA_SLOTS - 1`` trips ahead of
+    the products, out of a row into the next live one; ``q_lat``, ``q_rope``
+    and the result stay in HBM and the kernel moves a live row's by its own
+    copies. A larger tile (:func:`_mla_kernel`) is a cell of its own: a tile
+    all of whose rows are dead fetches nothing and its output is zeros, and
+    a dead row inside a live tile sees nothing and gets a finite value nobody
+    reads. As for :func:`paged_decode` the CALLER decides which rows are dead
+    (the model: a row whose table names no block); the kernel never reads
+    deadness out of the table, and a row with ``limits`` 1 and an all-zero
+    table attends to the trash block's first token. A live row or tile
+    fetches the blocks its largest ``limits`` reaches,
+    :func:`mla_blocks_per_trip` a trip, and never the table's padding; what
+    it computes does not depend on what else the call holds."""
     N, nh, rank = q_lat.shape
     rope = q_rope.shape[-1]
     _, _, _, BS, row = pool.shape
     if row != sum(latent_row(rank, rope)):
         raise ValueError(f"pool row {row} is not rank {rank} + rope {rope}, "
                          "padded to 128 lanes")
+    if q_tile == 1:
+        return _mla_rows(q_lat, q_rope, pool, layer, tables, limits,
+                         scale=float(scale), interpret=_interpret())
     tiles, Q = N // q_tile, q_tile * nh
     kv_blocks = mla_blocks_per_trip(Q, pool)
     pad = -tables.shape[1] % kv_blocks

@@ -356,8 +356,10 @@ def _on_duration(name: str, seconds: float, **kw) -> None:
     now = clock_ns()
     if name == _TRACE or name == _LOWER:
         pend = _pending()
-        if len(pend) > 2048:        # traces nobody compiled (eval_shape)
-            del pend[:1024]
+        # traces nobody compiled (eval_shape); an interpreted kernel's
+        # lowering alone leaves thousands behind its program's own trace
+        if len(pend) > 16384:
+            del pend[:8192]
         pend.append(("trace" if name == _TRACE else "lower",
                      kw.get("fun_name", ""), now - int(seconds * 1e9), now,
                      None))

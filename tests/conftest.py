@@ -91,6 +91,7 @@ _CORE = (
 _LONGEST_FIRST = (
     "tests/unit/test_paged_kernel.py",
     "tests/benchmark/test_cells.py",
+    "tests/unit/test_mla_decode_live_context.py",
     "tests/unit/test_zero_sharded.py",
     "tests/unit/test_pipelined_dispatch.py",
     "tests/unit/test_train_resilience.py",

@@ -124,9 +124,12 @@ def test_every_mla_decode_call_gets_limit_zero_for_a_padding_row(
         assert sorted(e.params["name"] for e in calls) == sorted(
             ["mla_decode", "mla_decode_segment"] * per_body)
         for call in calls:
-            # operands: layer, tables, each cell's blocks, q_lat, q_rope,
-            # each row's limit, the pool
-            for operand in (call.invars[2], call.invars[5]):
+            # operands of the one-token call: layer, tables, each row's
+            # limit, q_lat, q_rope, the pool; of the segment call: layer,
+            # tables, each cell's blocks, q_lat, q_rope, each row's limit,
+            # the pool
+            said = (2,) if call.params["name"] == "mla_decode" else (2, 5)
+            for operand in (call.invars[i] for i in said):
                 assert "select_n" not in producers(body, operand)
 
     jax.block_until_ready(jax.jit(run)(*padded))
