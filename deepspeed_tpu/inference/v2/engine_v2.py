@@ -256,6 +256,8 @@ class InferenceEngineV2:
         self._cow_fn = None
         self._fused_fn = None
         self._verify_fn = None
+        #: {program: what the model said of it where it was traced}
+        self._program_attrs = {}
         # per-shape host scratch for the ragged/fused step inputs: reused
         # (zeroed in place) instead of np.zeros every step — the steady-state
         # decode loop must not pay a fresh allocation per dispatch. Safe to
@@ -541,16 +543,18 @@ class InferenceEngineV2:
             # a stateful model's slot arrays ride beside the pool, each row
             # naming its sequence's slot: (logits, pool, slot arrays[, counts])
             segs = self._seg_tile > 1 and ids.shape[0] > self.max_seqs
-            lg, pool, *stats = model.forward_paged(
-                params, ids, pool, f["tables"], f["starts"],
-                logit_rows=f["logit_rows"],
-                rows_apart=self._rows_apart(ids.shape[0]),
-                **({"seg_from": self.max_seqs} if segs else {}),
-                **({"moe_stats": True} if self._moe_stats and greedy else {}),
-                **({"state": slot_cache, "row_slots": f["row_slots"]}
-                   if self._stateful else {}),
-                **({"window": (f["wtables"], f["wbase"])}
-                   if self._windowed else {}))
+            with self._saying("ragged"):
+                lg, pool, *stats = model.forward_paged(
+                    params, ids, pool, f["tables"], f["starts"],
+                    logit_rows=f["logit_rows"],
+                    rows_apart=self._rows_apart(ids.shape[0]),
+                    **({"seg_from": self.max_seqs} if segs else {}),
+                    **({"moe_stats": True} if self._moe_stats and greedy
+                       else {}),
+                    **({"state": slot_cache, "row_slots": f["row_slots"]}
+                       if self._stateful else {}),
+                    **({"window": (f["wtables"], f["wbase"])}
+                       if self._windowed else {}))
             if self._stateful:
                 slot_cache, *stats = stats
                 pool = (pool, slot_cache)
@@ -576,6 +580,15 @@ class InferenceEngineV2:
                          static_argnums=(5,))
         self._ragged_fn = fn
         return fn
+
+    def _saying(self, program: str):
+        """Around the model's call where ``program`` is traced: what the
+        model says of the path its shapes took (``head``: the vocabulary
+        head as the ``stream``ing kernel or as ``xla``'s product) lands in
+        ``_program_attrs[program]`` and from there on the program's
+        ``engine.enqueue`` spans, as ``head_loss`` does in training."""
+        return tracing.program_attrs(
+            self._program_attrs.setdefault(program, {}))
 
     def _feed_layout(self, rows: int):
         """:func:`feed_layout` of this engine's ragged step of ``rows``."""
@@ -1019,10 +1032,11 @@ class InferenceEngineV2:
 
             def fused(params, pool, toks, tables, starts,
                       slots, seeds, temps, top_ks, top_ps, bias_pool):
-                return model.decode_paged_multi(
-                    params, pool, toks, tables, starts, K,
-                    sampling=(seeds, temps, top_ks, top_ps,
-                              bias_pool[slots]))
+                with self._saying("fused"):
+                    return model.decode_paged_multi(
+                        params, pool, toks, tables, starts, K,
+                        sampling=(seeds, temps, top_ks, top_ps,
+                                  bias_pool[slots]))
 
             self._fused_fn = audited_jit("engine_v2.fused", fused,
                                          donate_argnums=(1,))
@@ -1046,10 +1060,11 @@ class InferenceEngineV2:
 
             def verify(params, pool, segs, tables, starts,
                        slots, seeds, temps, top_ks, top_ps, bias_pool):
-                return model.verify_paged_multi(
-                    params, pool, segs, tables, starts,
-                    sampling=(seeds, temps, top_ks, top_ps,
-                              bias_pool[slots]))
+                with self._saying("verify"):
+                    return model.verify_paged_multi(
+                        params, pool, segs, tables, starts,
+                        sampling=(seeds, temps, top_ks, top_ps,
+                                  bias_pool[slots]))
 
             self._verify_fn = audited_jit("engine_v2.verify", verify,
                                           donate_argnums=(1,))
@@ -1197,7 +1212,7 @@ class InferenceEngineV2:
         the program merges the fed tokens from (None: there is none).
         Returns the program's first result, still on the device."""
         fn = self._get_ragged()
-        with tracing.span("engine.enqueue"):
+        with tracing.span("engine.enqueue") as sp:
             if prev is None:
                 if self._no_prev is None:
                     self._no_prev = jnp.zeros(self._prev_shape(), jnp.int32)
@@ -1210,6 +1225,7 @@ class InferenceEngineV2:
                 tracing.note_program("engine_v2.ragged", fn, args,
                                      key=(rows, greedy))
             out = self._keep_caches(self._launch(fn, *args))
+            sp.set(**self._program_attrs.get("ragged", {}))  # once traced
         self._count_calls(disp)
         self._note_launch(disp)
         return out
@@ -1747,13 +1763,14 @@ class InferenceEngineV2:
         """Enqueue a K-position program (fused decode, speculative verify)
         and fetch its (max_seqs, K) result: ONE designed transfer per K
         tokens, the same budget as the ragged step's."""
-        with tracing.span("engine.enqueue"):
+        with tracing.span("engine.enqueue") as sp:
             self._feeds += len(feed)
             args = (self.params, self.kv,
                     *(jnp.asarray(a) for a in feed), self._bias())
             if disp.recording:
                 tracing.note_program("engine_v2." + program, fn, args)
             ys, self.kv = self._launch(fn, *args)
+            sp.set(**self._program_attrs.get(program, {}))
         self._count_calls(disp)
         self._note_launch(disp)
         with tracing.span("engine.fetch") as fetched:

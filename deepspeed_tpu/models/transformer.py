@@ -40,7 +40,7 @@ from jax.sharding import PartitionSpec as P
 from ..comm.topology import ZERO_AXES
 from ..ops.quantizer.woq import dequant_params as _dequant_woq
 from ..ops.transformer.attention import attention as _attention_op
-from ..ops.transformer.fused_ce import head_nll
+from ..ops.transformer.fused_ce import head_logits, head_nll, stream_block
 from ..ops.transformer.gelu_exact import gelu_exact
 from ..utils import tracing
 from ..utils.logging import logger
@@ -2081,7 +2081,14 @@ class TransformerLM:
     def _head(self, params, x):
         cfg = self.config
         x, w, bias, vocab_major = self._head_operands(params, x)
-        out = x @ (w.T if vocab_major else w).astype(x.dtype)  # (B,S,V)
+        # a served step's few rows over a tied table: one kernel call that
+        # streams the table from HBM once (ops/transformer/fused_ce.py)
+        block_v = stream_block(x, w, vocab_major)
+        tracing.set_program_attr(head="xla" if block_v is None else "stream")
+        if block_v is None:
+            out = x @ (w.T if vocab_major else w).astype(x.dtype)  # (B,S,V)
+        else:
+            out = head_logits(x, w, vocab_major=vocab_major, block_v=block_v)
         if bias is not None:
             out = out + bias.astype(x.dtype)
         return _times(out, cfg.head_mult)

@@ -40,6 +40,21 @@ def open_mesh_axes():
             tuple(a for a in mesh.axis_names if a not in manual))
 
 
+def one_device() -> bool:
+    """Is no mesh of several devices in sight of the code being traced:
+    neither a ``kernel_mesh``, nor JAX's own (``jax.set_mesh``, a
+    ``shard_map``), nor the process's topology (``comm/topology.py``: the
+    training engine's and ``InferenceEngine``'s, which places its weights
+    on it and names no mesh where it traces)? What a kernel asks that has
+    no mapped form."""
+    from ..comm.topology import get_topology
+
+    mesh, topo = _KERNEL_MESH.get(), get_topology(required=False)
+    return ((mesh is None or mesh.size == 1)
+            and jax.sharding.get_abstract_mesh().size <= 1
+            and (topo is None or topo.world_size == 1))
+
+
 def pallas_interpret() -> bool:
     """Should Pallas kernels run under the interpreter?
 

@@ -27,6 +27,15 @@ The weight is read where it lies: ``(V, H)`` (the tied embedding table,
 ``vocab_major``) or ``(H, V)`` (an untied head), never through a transposed
 copy.
 
+``head_logits(x, w)`` is the product alone for the few rows of a served step
+(``stream_block`` says when): the same walk of the vocabulary, the table
+streamed from HBM once under the products and each logits tile written once,
+with no row statistics. Left to XLA, a served program's table of at most
+VMEM's size is copied HBM -> VMEM whole by a cross-program prefetch that the
+program's first op, the embedding gather, waits for, and then multiplied out
+of VMEM (PERF.md 5, ``serve-chat``); a kernel's operand is no candidate for
+that prefetch.
+
 Status (my chip runs, PR 56, TPU v5e, ``.bench_scratch/kbench.py``: the unit's
 ``value_and_grad`` behind the final norm, eight calls in one jitted loop,
 device time a call from the profiler; PERF.md 5 has every form tried). At
@@ -53,7 +62,7 @@ from jax.sharding import PartitionSpec as P
 
 from ...comm.topology import MODEL_AXIS, SEQ_AXIS, ZERO_AXES
 from ...utils import tracing
-from ..pallas_utils import open_mesh_axes, pallas_interpret
+from ..pallas_utils import one_device, open_mesh_axes, pallas_interpret
 
 LANES = 128
 #: VMEM the kernels ask for, of the v5e's 128 MiB; a call that would need more
@@ -62,6 +71,17 @@ VMEM_LIMIT = 100 * 1024 * 1024
 #: rows a product in a grid step's straight code: the next chunk's product
 #: runs under this chunk's exponentials (PERF.md 5: 128 | 256 | 512 | whole)
 CHUNK = 256
+#: rows up to which :func:`head_logits` takes a head: below the chip's ridge
+#: (197 TFLOP/s over 819 GB/s: 240 rows of bf16) the product is bound by the
+#: table's bytes; a prefill's or an evaluation's rows are bound by the
+#: products, where XLA is at its rate
+STREAM_ROWS = 256
+#: the v5e's VMEM: a table that fits is the one XLA's cross-program prefetch
+#: would copy there before a served program's first op
+VMEM_BYTES = 128 * 1024 * 1024
+#: VMEM :func:`head_logits` may take (two table tiles, the rows, two logits
+#: tiles), inside the 16 MiB a call gets without asking
+STREAM_VMEM = 12 * 1024 * 1024
 LSE, G, LABEL = 0, 1, 2   # lanes of the backward's one (rows, 128) row table
 _PARAMS = pltpu.CompilerParams(
     dimension_semantics=("arbitrary", "arbitrary"), vmem_limit_bytes=VMEM_LIMIT)
@@ -133,6 +153,109 @@ def _fwd_call(x, w, vocab_major, block_r, block_v):
     )(x.reshape(nr, block_r, H), w)
     stat = stat.reshape(N, LANES)
     return lg, stat[:, 0] + jnp.log(stat[:, 1])
+
+
+# ----------------------------------------------------------------------------
+# the served head: the logits alone, the table streamed once
+# ----------------------------------------------------------------------------
+
+def _logits_kernel(x_ref, w_ref, lg_ref, *, vocab_major):
+    # a body of its own and not ``_fwd_kernel`` with its statistics thrown
+    # away: those are a maximum, an exponential and two lane reductions a
+    # tile that nobody reads, and the training bind stays what it was
+    lg_ref[...] = _product(x_ref[0], w_ref[...],
+                           (1, 1 if vocab_major else 0)).astype(lg_ref.dtype)
+
+
+def stream_block(x, w, vocab_major):
+    """The vocabulary tile with which :func:`head_logits` takes the head of
+    ``x`` (..., H) over ``w``, or None where the product stays XLA's. From
+    what the head is handed alone: a vocabulary-major table of at most
+    VMEM's size (the tied table, which a served program's first op gathers
+    from, so its copy to VMEM is waited for in full; an untied ``(H, V)``
+    head's prefetch is waited for by the head, the program's last product,
+    and hides under the layers: PERF.md 7 has the read of every serving
+    cell's programs), vocabulary and width multiples of 128, one dtype, at
+    most ``STREAM_ROWS`` rows, and no mesh of several devices in sight (XLA
+    partitions its own product over one, a Mosaic kernel it cannot)."""
+    H = x.shape[-1]
+    N = math.prod(x.shape[:-1])
+    if (not vocab_major or x.dtype != w.dtype or H % LANES
+            or not 0 < N <= STREAM_ROWS or not one_device()):
+        return None
+    V, item = w.shape[0], x.dtype.itemsize
+    if V * H * item > VMEM_BYTES:
+        return None
+    rows = _padded(N, item)
+    return next((v for v in (512, 384, 256, 128) if V % v == 0 and
+                 (2 * v * H + rows * H + 2 * rows * v) * item <= STREAM_VMEM),
+                None)
+
+
+def _padded(rows, itemsize):
+    """``rows`` up to the sublane tile: 8 rows of 32 bits, 16 of bfloat16."""
+    tile = 8 * max(1, 4 // itemsize)
+    return -(-rows // tile) * tile
+
+
+def head_logits(x, w, *, vocab_major, block_v):
+    """``x @ w^T`` in ``x``'s dtype from float32 sums, as XLA's product of
+    the same operands gives it: ``x`` (..., H), ``w`` (V, H) if
+    ``vocab_major`` else (H, V), ``block_v`` from :func:`stream_block`. One
+    call whose grid walks the vocabulary: the rows stay in VMEM whole, a
+    table tile is fetched under the tile before's product, a logits tile is
+    written once, and the table is read from HBM once. Differentiable (an
+    evaluation's logits under somebody's own loss): the backward is XLA's
+    two products."""
+    *lead, H = x.shape
+    lg = _stream(x.reshape(-1, H), w, vocab_major, block_v)
+    return lg.reshape(*lead, lg.shape[1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _stream(x, w, vocab_major, block_v):
+    N = x.shape[0]
+    rows = _padded(N, x.dtype.itemsize)
+    # padded for one row or a verify step's odd count, not for a round's 64
+    lg = _logits_call(jnp.pad(x, ((0, rows - N), (0, 0))) if rows != N else x,
+                      w, vocab_major=vocab_major, block_v=block_v,
+                      interpret=pallas_interpret())
+    return lg[:N] if rows != N else lg
+
+
+def _stream_fwd(x, w, vocab_major, block_v):
+    return _stream(x, w, vocab_major, block_v), (x, w)
+
+
+def _stream_bwd(vocab_major, block_v, res, dlg):
+    x, w = res
+    return (dlg @ (w if vocab_major else w.T),
+            dlg.T @ x if vocab_major else x.T @ dlg)
+
+
+_stream.defvjp(_stream_fwd, _stream_bwd)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("vocab_major", "block_v", "interpret"))
+def _logits_call(x, w, *, vocab_major, block_v, interpret):
+    """The call of :func:`head_logits`, its equations inlined into the
+    program that holds it; jitted for its cache alone, so that a process
+    traces the body once a shape and not once a program (a serving process
+    holds four: the round and the mixed step, greedy and not)."""
+    N, H = x.shape
+    V = w.shape[0 if vocab_major else 1]
+    return tracing.pallas_call(
+        functools.partial(_logits_kernel, vocab_major=vocab_major),
+        dict(rows=N, block_v=block_v, table_bytes=w.size * w.dtype.itemsize,
+             vocab_major=vocab_major, operand_dtype=x.dtype.name),
+        grid=(V // block_v, 1),
+        in_specs=[_whole((1, N, H)), _w_spec(H, block_v, vocab_major)],
+        out_specs=pl.BlockSpec((N, block_v), lambda j, i: (0, j)),
+        out_shape=jax.ShapeDtypeStruct((N, V), x.dtype),
+        interpret=interpret,
+        name="head_logits",
+    )(x.reshape(1, N, H), w)
 
 
 # ----------------------------------------------------------------------------

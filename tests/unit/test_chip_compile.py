@@ -670,7 +670,12 @@ def test_serving_program_moves_no_pool(one_chip, no_compile_cache, as_tpu,
     text = compiled.as_text()
     # the round's rows ride in ``paged_decode``, the mixed step scatters
     assert not re.search(r"%kv_write[.\d]* = \S+ custom-call\(", text)
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # GPT-2's tied table is streamed by ``head_logits``; Pythia's untied head
+    # is XLA's product
+    tied = model.config.tie_embeddings
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 + tied
+    assert bool(re.search(r"%head_logits[.\d]* = \S+ custom-call\(",
+                          text)) == tied
     if rows != CELL_SEQS:
         digest = hashlib.sha256(
             "\n".join(sublayer_lines(text)).encode()).hexdigest()
@@ -684,6 +689,60 @@ def test_serving_program_moves_no_pool(one_chip, no_compile_cache, as_tpu,
     assert not ungrouped, ungrouped
     call = re.search(r"%paged_decode[.\d]* = \((\w+\[[\d,]+\])", text)
     assert call and call.group(1) == f"bf16[{rows},{NH * head_dim}]"
+
+
+@pytest.fixture(scope="module")
+def chat_engine():
+    """``gpt2-medium.serve-chat``'s engine (its traffic file's geometry) over
+    two of the model's layers: the programs scan the layers, so their number
+    changes nothing outside the loop, where the table is used."""
+    from deepspeed_tpu.inference.v2 import InferenceEngineV2
+
+    model = serving_model(64, 2)
+    return InferenceEngineV2(
+        model, model.init_params(jax.random.PRNGKey(0)), dtype=jnp.bfloat16,
+        max_seqs=CELL_SEQS, max_seq_len=1024, block_size=BS, token_budget=256,
+        prefill_chunk=128, num_blocks=CELL_NB)
+
+
+@pytest.mark.parametrize("rows, greedy", [
+    (CELL_SEQS, True), (CELL_SEQS, False), (256, False)])
+def test_serve_chats_programs_copy_no_table_into_vmem(
+        one_chip, no_compile_cache, as_tpu, chat_engine, rows, greedy):
+    """The engine's own ragged programs (the decode round with the sampler
+    behind it, and both shapes returning logits) for the described v5e: the
+    tied table is gathered from and streamed where it lies in HBM. XLA's own
+    product made it the program's cross-program prefetch: 103 MB copied to
+    VMEM that the program waited for in full (PERF.md 5, ``serve-chat``)."""
+    engine = chat_engine
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: aval(one_chip, a.shape, a.dtype), tree)
+
+    vocab, width = engine.params["wte"].shape
+    text = engine._get_ragged().lower(
+        on_chip(engine.params), on_chip(engine.kv),
+        aval(one_chip, (engine._feed_layout(rows)[1],), jnp.int32),
+        aval(one_chip, engine._prev_shape(), jnp.int32),
+        aval(one_chip, (CELL_SEQS, vocab), jnp.float32), greedy,
+    ).compile().as_text()
+    assert engine._program_attrs["ragged"] == {"head": "stream"}
+    assert len(re.findall(r"%head_logits[.\d]* = \S+ custom-call\(",
+                          text)) == 1
+    table = f"bf16[{vocab},{width}]"
+    copies = [line.strip()[:140] for line in text.splitlines()
+              if table in line and re.search(r" copy-(start|done)\(", line)]
+    assert not copies, copies
+    prefetched = [line.strip()[:200] for line in text.splitlines()
+                  if "cross_program_prefetch" in line and "wte" in line]
+    assert not prefetched, prefetched
+    # the parameter itself, not a copy of it in VMEM (memory space 1)
+    wte, = re.findall(r"%(params__wte__[.\d]*) = (\S+) parameter\(", text)
+    assert "S(1)" not in wte[1]
+    readers = [line for line in text.splitlines() if f"%{wte[0]}" in line
+               and " parameter(" not in line]
+    assert any("/embed/" in line and "gather" in line for line in readers)
+    assert any("%head_logits" in line for line in readers)
 
 
 @pytest.mark.parametrize("program", ["fused", "verify"])
