@@ -48,7 +48,7 @@ from ..utils.logging import logger
 
 #: the mixers a ``layer_types`` model may name
 LAYER_TYPES = ("sparse_attn", "linear_attn", "window_attn", "full_attn",
-               "hybrid_ssm")
+               "hybrid_ssm", "delta_attn", "latent_attn")
 
 
 @dataclass(frozen=True)
@@ -118,12 +118,19 @@ def _gated_mlp_leaves(cfg, width, prefix=""):
 
 
 def _latent_leaves(cfg):
+    """A latent-attention layer's: the queries through a low-rank step
+    (``wq_a``, its norm, ``wq_b``) or, with ``q_lora_rank`` 0, one matrix
+    ``wq``; with ``attn_head_gate`` the gate's one projection, a scalar a
+    head."""
     H, nh = cfg.hidden_size, cfg.num_heads
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    return {"ln1_scale": (H,), "wq_a": (H, qr), "q_a_scale": (qr,),
-            "wq_b": (qr, nh * (nope + rope)), "wkv_a": (H, kvr + rope),
+    q = {"wq_a": (H, qr), "q_a_scale": (qr,),
+         "wq_b": (qr, nh * (nope + rope))} if qr \
+        else {"wq": (H, nh * (nope + rope))}
+    return {"ln1_scale": (H,), **q, "wkv_a": (H, kvr + rope),
             "kv_a_scale": (kvr,), "wkv_b": (kvr, nh * (nope + vd)),
+            **({"w_ogate": (H, nh)} if cfg.attn_head_gate else {}),
             "wo": (nh * vd, H), "ln2_scale": (H,)}
 
 
@@ -182,6 +189,23 @@ def _hybrid_leaves(cfg):
             "ln2_scale": (H,)}
 
 
+def _delta_leaves(cfg):
+    """A ``delta_attn`` (KDA) layer's: q, k and v a head each of ``head_dim``,
+    the depthwise convolution's taps over ``[q | k | v]`` (no bias), the
+    channels' decay (``w_decay`` one full-rank matrix, ``dt_bias`` a channel's,
+    ``a_log`` a head's), ``w_beta`` a scalar a head, the output norm's one
+    scale of ``head_dim`` and, with ``attn_head_gate``, the gate's
+    projection, a scalar a head."""
+    H, hd, nh = cfg.hidden_size, cfg.head_dim, cfg.num_heads
+    qd = nh * hd
+    return {"ln1_scale": (H,), "wq": (H, qd), "wk": (H, qd), "wv": (H, qd),
+            "kda_conv_scale": (cfg.ssm_conv, 3 * qd), "w_decay": (H, qd),
+            "a_log": (nh,), "dt_bias": (qd,), "w_beta": (H, nh),
+            "o_norm_scale": (hd,),
+            **({"w_ogate": (H, nh)} if cfg.attn_head_gate else {}),
+            "wo": (qd, H), "ln2_scale": (H,)}
+
+
 def _heads_flops(cfg, seen):
     """q k and p v of every head over ``seen`` positions a token."""
     return 6 * 2 * cfg.num_heads * cfg.head_dim * seen
@@ -214,6 +238,18 @@ def _hybrid_slots(cfg, n, max_seqs, max_seq_len, dtype):
                                  cfg.ssm_head_dim),
             "conv": la.init_conv(n, max_seqs, cfg.ssm_conv,
                                  cfg.ssm_conv_channels, dtype)}
+
+
+def _delta_slots(cfg, n, max_seqs, max_seq_len, dtype):
+    """The delta rule's state (float32) and the convolution's last
+    ``ssm_conv - 1`` rows of ``[q | k | v]`` (the model's dtype), a slot a
+    sequence in both."""
+    from ..ops.transformer import linear_attention as la
+
+    nh, hd = cfg.num_heads, cfg.head_dim
+    return {"state": la.init_state(n, max_seqs, nh, hd, hd),
+            "conv": la.init_conv(n, max_seqs, cfg.ssm_conv, 3 * nh * hd,
+                                 dtype)}
 
 
 def _sparse_slots(cfg, n, max_seqs, max_seq_len, dtype):
@@ -290,6 +326,22 @@ LAYER_KINDS: Dict[str, LayerKind] = {
         tile=lambda cfg: cfg.linear_chunk,
         layer=lambda lm: lm._hybrid_layer, slots=_hybrid_slots,
         scopes=("ssm_scan", "ssm_mixer", "dense_ffn")),
+    # the gated delta rule with a decay of each key channel (KDA): a float32
+    # state a head and the window of a convolution over [q | k | v], and no KV
+    # blocks; the recurrence's own scope is declared before the sublayer's
+    "delta_attn": LayerKind(
+        leaves=_delta_leaves, row=_gqa_row,
+        attn_flops=lambda cfg, S: 6 * 7 * cfg.num_heads * cfg.head_dim ** 2,
+        tile=lambda cfg: cfg.linear_chunk, pool_layers=0,
+        layer=lambda lm: lm._delta_layer, slots=_delta_slots,
+        scopes=("delta_scan", "delta_attn", "dense_ffn")),
+    # latent attention as one mixer of a ``layer_types`` model: the latent
+    # kind's block, its rows in tiles of the model's ``linear_chunk`` (the
+    # kernel takes them ``SEGMENT_TILE`` at a time)
+    "latent_attn": LayerKind(
+        leaves=_latent_leaves, row=_latent_row, attn_flops=_latent_flops,
+        tile=lambda cfg: cfg.linear_chunk, scopes=("mla_proj",),
+        layer=lambda lm: partial(lm._latent_layer, lm._block_mla)),
 }
 # a kind's scopes are declared to the tracer here, where they are opened
 tracing.layer_scopes(*(s for kind in LAYER_KINDS.values()
@@ -411,9 +463,22 @@ class TransformerConfig:
     # layer). The feed-forward of a layer_types model is dense, or (
     # ``num_experts`` > 0) the first ``num_dense_layers`` layers' is dense at
     # ``dense_intermediate_size`` and the others hold experts. Serving only
+    # "delta_attn" (Kimi Delta Attention, bailing_hybrid's linear layers):
+    # q, k, v a head each through a depthwise causal convolution of
+    # ``ssm_conv`` taps (no bias) and SiLU, q and k L2-normed a head, a decay
+    # of each key channel ``kda_log_floor * sigmoid(exp(a_log) * (W_f h +
+    # dt_bias))`` in (``kda_log_floor``, 0) and the gated delta rule
+    # (ops/transformer/linear_attention.py: ``beta``), an RMSNorm of each
+    # head's output and the output gate; a state slot and no KV blocks.
+    # "latent_attn": latent attention (``kv_lora_rank`` and the three head
+    # widths; ``q_lora_rank`` 0: the queries are one matrix) as a mixer beside
+    # other kinds, its rows in the latent pool. ``attn_head_gate``: the
+    # output gate of these two is one scalar a head (``w_ogate`` (H, heads))
     layer_types: Optional[Tuple[str, ...]] = None
     qk_norm: bool = False
     attn_output_gate: bool = False
+    attn_head_gate: bool = False
+    kda_log_floor: float = -5.0
     sliding_window: int = 0
     post_norms: bool = False
     sparse_kernel_size: int = 32
@@ -518,6 +583,21 @@ class TransformerConfig:
                 raise ValueError(
                     "a hybrid_ssm layer needs ssm_heads (a multiple of "
                     "ssm_groups), ssm_head_dim, ssm_state and ssm_conv > 1")
+            if "delta_attn" in types:
+                from ..ops.transformer.linear_attention import DELTA_LOG_FLOOR
+
+                if not (self.ssm_conv > 1
+                        and -DELTA_LOG_FLOOR <= self.kda_log_floor < 0):
+                    raise ValueError(
+                        "a delta_attn layer needs ssm_conv > 1 taps and a "
+                        f"kda_log_floor in [-{DELTA_LOG_FLOOR}, 0): what the "
+                        "blocked delta rule's sub-tiles hold in float32")
+            if "latent_attn" in types and not (
+                    self.kv_lora_rank > 0 and self.qk_nope_head_dim > 0
+                    and self.qk_rope_head_dim > 0 and self.v_head_dim > 0):
+                raise ValueError(
+                    "a latent_attn layer needs kv_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
         for name in ("ssm_zone_mults", "mlp_mults"):
             object.__setattr__(self, name, tuple(
                 float(m) for m in getattr(self, name)))
@@ -678,8 +758,10 @@ class TransformerConfig:
     @property
     def _pool_row(self):
         """(heads, (key width, value width)) of the pool's rows: one for all
-        the model's kinds, whose layers are layers of one pool."""
-        return self.kinds[0].row(self)
+        the model's kinds that keep KV blocks, whose layers are layers of one
+        pool (a model none of whose kinds does: its first kind's)."""
+        pooled = [rec for rec in self.kinds if rec.pool_layers]
+        return (pooled or self.kinds)[0].row(self)
 
     @property
     def kv_row(self) -> Tuple[int, int]:
@@ -1057,6 +1139,22 @@ def window_frame(tables, base, bound, starts, limits):
             jnp.where(live, jnp.maximum(first, 0), 0))
 
 
+def _rows_then_tiles(step, one, many, *xs):
+    """``one`` over the one-token rows of each of ``xs`` ((T, ...) arrays of a
+    :class:`PagedStep`'s rows), ``many`` over its tiles (the carry ``one``
+    returned first, then each of ``xs`` as (tiles, tile, ...)), the results
+    joined and padded to T rows: (y, the last carry). How a slot-keeping
+    mixer walks a step: ``linear_attention``'s ``*_rows`` then ``*_tiles``."""
+    cut, end, tile, T = step.cut, step.end, step.tile, xs[0].shape[0]
+    y, carry = one(*(a[:cut] for a in xs))
+    if cut < end:
+        y2, carry = many(carry, *(
+            a[cut:end].reshape(-1, tile, *a.shape[1:]) for a in xs))
+        y = jnp.concatenate([y, y2.reshape(-1, *y.shape[1:])])
+    return jnp.pad(y, ((0, T - y.shape[0]),)
+                   + ((0, 0),) * (y.ndim - 1)), carry
+
+
 def sublayer_prefix(i: int) -> str:
     """Prefix of sublayer ``i``'s leaves in a double layer's ``blocks``."""
     return f"s{i}_"
@@ -1346,7 +1444,7 @@ class TransformerLM:
         m, e = self.model_axis, "expert"
         col, row = P(None, None, m), P(None, m, None)
         by_name = {
-            "wq": col, "wk": col, "wv": col, "w_ogate": col,
+            "wq": col, "wk": col, "wv": col, "w_ogate": col, "w_decay": col,
             "wq_b": col, "wkv_b": col, "wo": row,
             "w_gate": col, "w_up": col, "w_down": row,
             "shared_w_gate": col, "shared_w_up": col, "shared_w_down": row,
@@ -1748,8 +1846,12 @@ class TransformerLM:
         with jax.named_scope("attn"):
             with jax.named_scope("mla_proj"):
                 h = rms(x, "ln1_scale")
-                c_q = rms(once(h @ blk["wq_a"].astype(dt)), "q_a_scale", q_scale)
-                q = c_q @ blk["wq_b"].astype(dt)
+                if "wq_a" in blk:
+                    c_q = rms(once(h @ blk["wq_a"].astype(dt)), "q_a_scale",
+                              q_scale)
+                    q = c_q @ blk["wq_b"].astype(dt)
+                else:                    # no low-rank step (q_lora_rank 0)
+                    q = h @ blk["wq"].astype(dt)
                 if paged is not None:
                     # whole before the split into heads, or the compiler
                     # relays a transposed copy of the matrix (:meth:`_block`)
@@ -1799,6 +1901,9 @@ class TransformerLM:
                         q_lat, q_rope[:, 0], pool, layer, step, scale)
                 with jax.named_scope("mla_proj"):
                     attn = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv)[:, None]
+            if "w_ogate" in blk:         # one scalar a head (attn_head_gate)
+                attn = attn * jax.nn.sigmoid(
+                    once(h @ blk["w_ogate"].astype(dt)))[..., None]
             attn_out = attn.reshape(B, S, nh * vd) @ blk["wo"].astype(dt)
             attn_out = self._constraint(attn_out, self._act_spec(paged is None))
         return attn_out, new_pool
@@ -1809,20 +1914,31 @@ class TransformerLM:
         in segment tiles. The Pallas kernel on a TPU (or forced, as the GPT-2
         path's is), the XLA gather off it. A row with ``limits`` 0 is dead:
         the kernel fetches nothing for a one-token row or a tile of such
-        rows."""
+        rows. A step whose tiles are longer than the kernel's
+        (``SEGMENT_TILE``: a ``layer_types`` model's ``linear_chunk``) hands
+        them over ``SEGMENT_TILE`` rows at a time: every live row carries its
+        sequence's table, and a tile's valid rows are a prefix."""
         from ..ops.transformer import paged_attention as pa
 
         attend = pa.mla_decode if pa.kernels_wanted() else pa.mla_attend_xla
-        tables, limits, cut = step.tables, step.limits, step.cut
+        tables, limits = step.tables, step.limits
+        cut, end, T = step.cut, step.end, q_lat.shape[0]
+        tile = min(step.tile, pa.SEGMENT_TILE)
+        if step.tile % tile:
+            raise ValueError(f"a step's tile of {step.tile} rows is no "
+                             f"multiple of the latent kernel's {tile}")
         parts = []
         if cut:
             parts.append(attend(q_lat[:cut], q_rope[:cut], pool, layer,
                                 tables[:cut], limits[:cut], scale=scale))
-        if cut < q_lat.shape[0]:
-            parts.append(attend(q_lat[cut:], q_rope[cut:], pool, layer,
-                                tables[cut::step.tile], limits[cut:],
-                                scale=scale, q_tile=step.tile))
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        if cut < end:
+            parts.append(attend(q_lat[cut:end], q_rope[cut:end], pool, layer,
+                                tables[cut:end:tile], limits[cut:end],
+                                scale=scale, q_tile=tile))
+        o = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+        # the rows behind the last whole tile are padding
+        return o if end == T else jnp.pad(
+            o, ((0, T - end), (0, 0), (0, 0)))
 
     def _moe_ffn(self, h, blk, train):
         """Routed expert FFN on (B,S,H) — delegates to the shared MoE core
@@ -2615,16 +2731,7 @@ class TransformerLM:
         tiles = slice(cut, end, tile)
         f32 = jnp.float32
 
-        def rows_then_tiles(one, many, *xs):
-            """``one`` over the one-token rows of each of ``xs``, ``many``
-            over the tiles, the results joined and padded to T rows."""
-            y, carry = one(*(a[:cut] for a in xs))
-            if cut < end:
-                y2, carry = many(carry, *(
-                    a[cut:end].reshape(-1, tile, *a.shape[1:]) for a in xs))
-                y = jnp.concatenate([y, y2.reshape(-1, *y.shape[1:])])
-            return jnp.pad(y, ((0, T - y.shape[0]),)
-                           + ((0, 0),) * (y.ndim - 1)), carry
+        rows_then_tiles = partial(_rows_then_tiles, step)
 
         with jax.named_scope("ssm_mixer"):
             p = jax.lax.optimization_barrier(
@@ -2664,6 +2771,91 @@ class TransformerLM:
                       cfg.norm_eps).reshape(T, inner).astype(dt)
             out = _times(y @ blk["ssm_w_out"].astype(dt), cfg.ssm_out_mult)
         return out, {"ssm": ssm, "conv": conv}
+
+    def _delta_layer(self, x, blk, caches, layer, pool_layer, step,
+                     experts=None):
+        """One ``delta_attn`` (KDA) layer on (T, 1, H), a kind of
+        :meth:`forward_paged`, on the layer's slot (``own``: ``state`` the
+        float32 states, ``conv`` the convolution's windows)::
+
+            h = N(x);  [q | k | v] = silu(conv(h [W_q | W_k | W_v]))
+            q = q / |q| * d^-1/2;  k = k / |k|                      a head
+            a = floor * sigmoid(exp(a_log) * (h W_f + dt_bias))     a channel
+            b = sigmoid(h W_b)                                      a head
+            S' = Diag(exp(a)) S;  S = S' + b k^T (v - k S');  o = q S
+            x = x + W_o (N_head(o) * sigmoid(h W_g))
+
+        then the feed-forward (:meth:`_feed_forward`: dense, or the held
+        experts). The one-token rows go through ``conv_rows`` /
+        ``decode_rows``, the tiles through ``conv_tiles`` / ``chunk_tiles``
+        (ops/transformer/linear_attention.py: ``beta`` selects the delta
+        rule)."""
+        from ..ops.transformer import linear_attention as la
+
+        cfg = self.config
+        T, dt, f32 = x.shape[0], x.dtype, jnp.float32
+        nh, hd = cfg.num_heads, cfg.head_dim
+        own = caches["own"]
+        cut, end, tile = step.cut, step.end, step.tile
+        slots, fresh = step.slots, step.starts == 0
+        tiles = slice(cut, end, tile)
+        blk = _dequant_woq(blk, dt)
+        once = jax.lax.optimization_barrier
+        rows_then_tiles = partial(_rows_then_tiles, step)
+
+        def unit(a):
+            """Each head's row over its L2 norm."""
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + cfg.norm_eps)
+
+        with jax.named_scope("attn"), jax.named_scope("delta_attn"):
+            h = _norm(x[:, 0], blk["ln1_scale"], None, "rmsnorm", cfg.norm_eps)
+            # behind barriers, as ``_typed_layer``'s: the products keep
+            # their own layout and each matrix is read where it lies
+            qkv = jnp.concatenate(
+                [once(h @ blk[w].astype(dt)) for w in ("wq", "wk", "wv")], -1)
+            taps = blk["kda_conv_scale"]
+            y, conv = rows_then_tiles(
+                lambda a: la.conv_rows(own["conv"], layer, slots[:cut], a,
+                                       taps, None, fresh[:cut]),
+                lambda conv, a: la.conv_tiles(
+                    conv, layer, slots[tiles], step.tile_counts, a, taps,
+                    None, fresh[tiles]), qkv)
+            q, k, v = (a.reshape(T, nh, hd) for a in jnp.split(
+                jax.nn.silu(y).astype(dt).astype(f32), 3, axis=-1))
+            q, k = unit(q) * hd ** -0.5, unit(k)
+            decay = cfg.kda_log_floor * jax.nn.sigmoid(
+                jnp.exp(blk["a_log"].astype(f32))[None, :, None] * (
+                    once(h @ blk["w_decay"].astype(dt)).astype(f32)
+                    + blk["dt_bias"].astype(f32)).reshape(T, nh, hd))
+            beta = jax.nn.sigmoid(
+                once(h @ blk["w_beta"].astype(dt)).astype(f32))
+            o, state = rows_then_tiles(
+                lambda *a: la.decode_rows(
+                    own["state"], layer, slots[:cut], *a[:3], fresh[:cut],
+                    log_decay=a[3], scope="delta_scan", beta=a[4]),
+                lambda state, *a: la.chunk_tiles(
+                    state, layer, slots[tiles], step.tile_counts, *a[:3],
+                    fresh[tiles], log_decay=a[3], scope="delta_scan",
+                    beta=a[4]),
+                q, k, v, decay, beta)
+            o = _norm(o, blk["o_norm_scale"], None, "rmsnorm", cfg.norm_eps)
+            if "w_ogate" in blk:         # one scalar a head
+                o = o * jax.nn.sigmoid(once(
+                    h @ blk["w_ogate"].astype(dt)).astype(f32))[..., None]
+            x = once(x + (o.reshape(T, nh * hd).astype(dt)
+                          @ blk["wo"].astype(dt))[:, None])
+        with jax.named_scope("mlp"):
+            h2 = _norm(x, blk["ln2_scale"], None, "rmsnorm", cfg.norm_eps)
+            if "moe_wg" in blk:
+                f, stats = self._feed_forward(
+                    h2, blk, None if experts is None else (experts, layer),
+                    step)
+            else:
+                with jax.named_scope("dense_ffn"):
+                    f, stats = self._feed_forward(h2, blk, None, step)
+            x = x + f
+        return x, {"own": {"state": state, "conv": conv}}, stats
 
     def _sparse_mixer(self, q, k, v, blk, caches, layer, pool_layer, step):
         """Block-sparse attention (ops/transformer/sparse_attention.py) of

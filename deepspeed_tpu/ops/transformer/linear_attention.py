@@ -1,22 +1,33 @@
-"""Linear recurrences over per-sequence state slots: lightning attention and
-the state-space duality (SSD, Mamba-2) form, one family.
+"""Linear recurrences over per-sequence state slots: lightning attention,
+the state-space duality (SSD, Mamba-2) form and the gated delta rule (Kimi
+Delta Attention, KDA), one family.
 
 Such a layer keeps no growing cache: per head one matrix ``S`` (key width x
-value width, float32) that every token decays and adds to::
+value width, float32) that every token decays and writes to. Three
+recurrences, told apart by what the caller passes::
 
-    S_t = a_t,h * S_{t-1} + k_t^T v_t          o_t = q_t S_t
+    lightning   S_t = lambda_h S_{t-1} + k_t^T v_t            o_t = q_t S_t
+    SSD         S_t = a_t,h S_{t-1} + k_t^T v_t               o_t = q_t S_t
+    delta       S'  = Diag(alpha_t,h) S_{t-1}
+                S_t = S' + beta_t,h k_t^T (v_t - k_t S')      o_t = q_t S_t
 
 **The decay is an operand.** Lightning attention's ``a`` is a constant of the
 head, ``lambda_h`` (:func:`head_decay_rates`), the same in every layer and no
 parameter: a caller that passes no ``log_decay`` gets it. An SSD layer's is the
 token's own, ``exp(dt_t,h A_h)``: the caller passes ``log_decay`` ``(rows,
 heads)`` float32, never positive, so every power taken here is ``exp(<= 0)``.
-Keys and queries may be a group's: ``q, k (rows, groups, dk)`` beside ``v
-(rows, heads, dv)``, ``groups`` a divisor of ``heads`` and ``dk`` any width
-beside ``dv``. What else an SSD layer has (``dt`` on the value, the skip ``D
-x``, its gate and norm) is the caller's. Its causal convolution's window, the
-last ``taps - 1`` rows a sequence, is a second slot array of the same slots
-(:func:`conv_rows`, :func:`conv_tiles`). The states of all lightning layers live in ONE array
+The delta rule's is **a key channel's own**: ``log_decay`` ``(rows, heads,
+dk)``, and with it ``beta`` ``(rows, heads)`` in (0, 1), which is what selects
+the form (a static choice: the lightning and SSD programs hold nothing of it):
+before a token writes ``k^T v`` it erases what the decayed state already
+answers to its key (``k S'``, a read-out before the write).
+Keys and queries may be a group's (lightning, SSD): ``q, k (rows, groups,
+dk)`` beside ``v (rows, heads, dv)``, ``groups`` a divisor of ``heads`` and
+``dk`` any width beside ``dv``. What else an SSD layer has (``dt`` on the
+value, the skip ``D x``, its gate and norm) is the caller's, as are the delta
+rule's L2 norms of q and k. A causal convolution's window, the last ``taps -
+1`` rows a sequence, is a second slot array of the same slots
+(:func:`conv_rows`, :func:`conv_tiles`). The states of all layers of a group live in ONE array
 ``(layers, slots, heads, dk, dv)`` float32, a **slot** a sequence: slot 0 is
 the trash slot that padding rows read and write (as block 0 of the KV pool
 is), a live sequence holds one of the others from admission to its end. The
@@ -28,7 +39,7 @@ state whatever the slot held (``fresh``), so a slot handed to the next
 sequence, or to a preempted one that recomputes from its prompt, cannot leak
 the last owner's state.
 
-Two forms of the same recurrence:
+Two forms of each recurrence:
 
 - :func:`decode_rows`: rows of one token each, one sequence a row: the
   Pallas kernel :func:`linear_decode` where the paged programs take kernels
@@ -50,9 +61,19 @@ Two forms of the same recurrence:
 
   with ``L[i, j] = exp(c_i - c_j)`` for ``j <= i``. A tile may be valid only in
   its first ``n`` rows (the tail of a chunk); tiles are walked in row order,
-  so two tiles of one sequence in one step see each other's state. Every
-  power is ``exp`` of a difference that is never positive: nothing overflows,
-  and what underflows is zero.
+  so two tiles of one sequence in one step see each other's state.
+
+**Which invariant holds for which.** Lightning and SSD: every power is
+``exp`` of a difference that is never positive: nothing overflows, and what
+underflows is zero (``L`` is made from the differences, a (heads, C, C)
+term). The delta rule's ``L`` would be a (C, C, dk) term a head, so its
+blocked form (:func:`_delta_tiles`) factors ``exp(c_i - c_j)`` into ``(k *
+exp(c)) (k * exp(-c))^T``, and ``exp(-c)`` is a power of a POSITIVE number: it
+is bounded by working in sub-tiles of :data:`DELTA_SUB` rows, ``c`` counted
+from the sub-tile's first row, under the caller's promise that a channel
+loses at most :data:`DELTA_LOG_FLOOR` a token (KDA's ``kda_safe_gate`` lower
+bound, -5: ``exp(16 * 5)`` fits float32). Sub-tiles join through the state,
+whose decays are differences that are never positive.
 """
 
 import functools
@@ -82,6 +103,12 @@ DECODE_HEADS = 8
 #: at 11 live rows of 48, a prefix | scattered: 16 rows a cell 73.7 | 75.4,
 #: 24 rows 73.3 | 74.1, all 48 in one cell 72.9 | 72.9)
 DECODE_CELL_ROWS = 64
+#: rows of a sub-tile of the delta rule's blocked form, and the most a key
+#: channel may lose a token there (``log_decay >= -DELTA_LOG_FLOOR``): inside a
+#: sub-tile ``exp(-c)`` is at most ``exp(DELTA_SUB * DELTA_LOG_FLOOR)`` =
+#: ``exp(80)`` = 5.5e34, which float32 holds
+DELTA_SUB = 16
+DELTA_LOG_FLOOR = 5.0
 
 
 def head_decay_rates(n_heads: int) -> np.ndarray:
@@ -105,14 +132,16 @@ def _to_heads(x, heads: int):
 
 
 def decode_rows(state, layer, slots, q, k, v, fresh, log_decay=None,
-                scope="linear_attn"):
+                scope="linear_attn", beta=None):
     """One token a row. ``state``: the slot array; ``layer``: int32 scalar
     (traced or not); ``slots`` (R,) int32, 0 for a padding row; q, k (R, g,
     dk), ``g`` the heads or a divisor of them (a group's keys and queries), v
     (R, h, dv), q already scaled; ``fresh`` (R,) bool: the row is its
     sequence's first token; ``log_decay`` (R, h) float32, never positive: the
-    row's own decay, or None for lightning's constant of the head. ``scope``:
-    the ``jax.named_scope`` the recurrence's operations are traced under.
+    row's own decay, or None for lightning's constant of the head. ``beta``
+    (R, h) float32 selects the delta rule: ``log_decay`` is then (R, h, dk), a
+    key channel's own, and ``g`` the heads. ``scope``: the
+    ``jax.named_scope`` the recurrence's operations are traced under.
     Returns (o (R, h, dv) float32, new state)."""
     from .paged_attention import _interpret, kernels_wanted
 
@@ -123,15 +152,26 @@ def decode_rows(state, layer, slots, q, k, v, fresh, log_decay=None,
             and (G == H or (H // G) % DECODE_HEADS == 0)
             and (lane_tiles or _interpret())):
         return linear_decode(state, layer, slots, q, k, v, fresh, log_decay,
-                             scope)
+                             scope, beta)
     with jax.named_scope(scope):
-        decay = jnp.exp(-jnp.asarray(head_decay_rates(H)))[None] \
-            if log_decay is None else jnp.exp(log_decay)
-        s = state[layer, slots]                                # (R, h, dk, dv)
-        keep = jnp.where(fresh, 0.0, 1.0)[:, None] * decay
-        s = s * keep[:, :, None, None] + (
-            _to_heads(k, H).astype(jnp.float32)[..., :, None]
-            * v.astype(jnp.float32)[..., None, :])
+        if beta is None:
+            decay = jnp.exp(-jnp.asarray(head_decay_rates(H)))[None] \
+                if log_decay is None else jnp.exp(log_decay)
+            s = state[layer, slots]                            # (R, h, dk, dv)
+            keep = jnp.where(fresh, 0.0, 1.0)[:, None] * decay
+            s = s * keep[:, :, None, None] + (
+                _to_heads(k, H).astype(jnp.float32)[..., :, None]
+                * v.astype(jnp.float32)[..., None, :])
+        else:
+            kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+            keep = jnp.where(fresh, 0.0, 1.0)[:, None, None] \
+                * jnp.exp(log_decay)
+            s = state[layer, slots] * keep[..., None]
+            # what the decayed state answers to the key, erased before the
+            # write
+            erase = jnp.einsum("rhk,rhkv->rhv", kf, s, precision=_HI)
+            s = s + kf[..., :, None] * (
+                beta[..., None] * (vf - erase))[..., None, :]
         o = jnp.einsum("rhk,rhkv->rhv", _to_heads(q, H).astype(jnp.float32),
                        s, precision=_HI)
         state = state.at[layer, slots].set(s)
@@ -152,7 +192,7 @@ KEY_PIECE = 128
 
 
 def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref,
-                   *rest, n_heads, operand, grouped):
+                   *rest, n_heads, operand, grouped, delta=False):
     """Grid (R / rpc, heads / hb): ONE cell per ``rpc`` rows of the step
     (:func:`rows_per_cell`) and block of ``hb`` heads (:data:`DECODE_HEADS`).
     v_ref, o_ref (rpc, hb * dv) are blocks of the model's lane-dense rows;
@@ -162,7 +202,13 @@ def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref,
     by the kernel's own copies through ``buf`` (3, hb, dk, dv). With
     ``operand`` a block ``d_ref`` (rpc, hb * dv) float32 comes behind v: each
     row's own decay of each head, a head a lane tile; without, the decay is
-    lightning's constant of the head, made here.
+    lightning's constant of the head, made here. With ``delta`` two blocks
+    come behind v: ``a_ref`` (rpc, hb * dk) float32, each row's decay of each
+    key channel (laid out as k is), and ``b_ref`` (rpc, hb * dv) float32, each
+    row's ``beta`` of each head over the head's lanes; a live row's block is
+    decayed a key row at a time, read out against the key (``k S'``) and
+    written with ``beta k^T (v - k S')``, all where it lies in its buffer: the
+    block is still fetched once and stored once.
 
     The first head block of a row cell lists the cell's live rows
     (``slots > 0``) in ``live_ref`` with a loop of scalar steps and leaves
@@ -181,8 +227,9 @@ def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref,
     waits for a fetch with nothing to hide it, and only its last head block
     waits for the write-backs to drain."""
     d_ref = rest[0] if operand else None
+    a_ref, b_ref = rest[:2] if delta else (None, None)
     (_, o_ref, s_ref, buf, rsem, wsem, live_ref, q32, k32,
-     v32) = rest[1 if operand else 0:]
+     v32) = rest[2 if delta else 1 if operand else 0:]
     del _  # the same buffer as ``s_ref``: input_output_aliases
     rpc, (nbuf, hb, dk, dv) = v_ref.shape[0], buf.shape
     kp = min(dk, KEY_PIECE)      # a state block's key axis goes in pieces
@@ -284,18 +331,39 @@ def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref,
         # q and k a head a column; v a head a row
         q_cols, k_cols = columns(q32, r), columns(k32, r)
         v_row = heads_of(v32, r, dv)
-        keep = jnp.where(started, heads_of(d_ref, r, dv) if operand
-                         else decay, 0.0)
+        if delta:
+            a_cols = [jnp.where(started, a, 0.0) for a in columns(a_ref, r)]
+            b_row = heads_of(b_ref, r, dv)
+        else:
+            keep = jnp.where(started, heads_of(d_ref, r, dv) if operand
+                             else decay, 0.0)
         fetch(c, k, slot).wait()
         out = []
         for h in range(hb):
             read = None
+            if delta:
+                # S' = Diag(alpha) S, a piece at a time, and k S' over all
+                # the pieces before any of them is written
+                kept, erase = [], None
+                for p in range(pieces):
+                    kept.append(jax.lax.mul(column(a_cols, p, h),
+                                            buf[state_piece(slot, h, p)]))
+                    part = jax.lax.mul(column(k_cols, p, h), kept[p])
+                    erase = part if erase is None else jax.lax.add(erase,
+                                                                   part)
+                write = spread(jax.lax.mul(piece(b_row, 0, h), jax.lax.sub(
+                    piece(v_row, 0, h), jnp.sum(erase, axis=0,
+                                                keepdims=True))))
             for p in range(pieces):
                 at = state_piece(slot, h, p)
-                new = jax.lax.add(
-                    jax.lax.mul(spread(piece(keep, 0, h)), buf[at]),
-                    jax.lax.mul(column(k_cols, p, h),
-                                spread(piece(v_row, 0, h))))
+                if delta:
+                    new = jax.lax.add(kept[p], jax.lax.mul(
+                        column(k_cols, p, h), write))
+                else:
+                    new = jax.lax.add(
+                        jax.lax.mul(spread(piece(keep, 0, h)), buf[at]),
+                        jax.lax.mul(column(k_cols, p, h),
+                                    spread(piece(v_row, 0, h))))
                 buf[at] = new
                 part = jax.lax.mul(column(q_cols, p, h), new)
                 # the pieces folded before the one reduction over the rows
@@ -327,7 +395,7 @@ def _decode_kernel(layer_ref, slots_ref, fresh_ref, q_ref, k_ref, v_ref,
 
 
 def linear_decode(state, layer, slots, q, k, v, fresh, log_decay=None,
-                  scope="linear_attn"):
+                  scope="linear_attn", beta=None):
     """:func:`decode_rows` as a Pallas kernel, in place on the slot array
     (left in HBM whole and aliased to the result). q, k, v go in and o comes
     out as the model holds them, ``(R, heads * d)`` lane-dense rows in their
@@ -336,7 +404,10 @@ def linear_decode(state, layer, slots, q, k, v, fresh, log_decay=None,
     read-out need are made in the kernel, a live row at a time. A row's own
     decay (``log_decay``) goes in as one more such block, ``exp`` taken and a
     head's spread over its ``dv`` lanes here (rows x heads x dv x 4 bytes: a
-    five-hundredth of what the row's state moves). The
+    five-hundredth of what the row's state moves). The delta rule
+    (``beta`` (R, h), ``log_decay`` (R, h, dk)) hands the channels' decays in
+    laid out as k is and ``beta`` as a head's scalar decay is, and runs as
+    the kernel ``delta_decode``. The
     grid is ``(R / rpc, heads / DECODE_HEADS)``; a cell lists its live rows
     (``slots > 0``) and walks them, moving each one's state block in, updating
     it where it lies in VMEM and moving it back with its own overlapped
@@ -348,13 +419,21 @@ def linear_decode(state, layer, slots, q, k, v, fresh, log_decay=None,
 
     R, H, dv = v.shape
     G, dk = q.shape[1:]
-    decay = None if log_decay is None else jnp.broadcast_to(
-        jnp.exp(log_decay.astype(jnp.float32))[:, :, None],
-        (R, H, dv)).reshape(R, H * dv)
+    def lanes(x):
+        """A head's scalar (R, h) float32 over the head's ``dv`` lanes."""
+        return jnp.broadcast_to(x.astype(jnp.float32)[:, :, None],
+                                (R, H, dv)).reshape(R, H * dv)
+
+    if beta is not None:
+        decay = jnp.exp(log_decay.astype(jnp.float32)).reshape(R, H * dk)
+        beta = lanes(beta)
+    else:
+        decay = None if log_decay is None else lanes(jnp.exp(
+            log_decay.astype(jnp.float32)))
     o, state = _decode_call(
         jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
         fresh.astype(jnp.int32), q.reshape(R, G * dk), k.reshape(R, G * dk),
-        v.reshape(R, H * dv), decay, state, heads=H, groups=G,
+        v.reshape(R, H * dv), decay, state, beta, heads=H, groups=G,
         hb=DECODE_HEADS, rpc=rows_per_cell(R), interpret=_interpret(),
         scope=scope)
     return o.reshape(R, H, dv), state
@@ -362,8 +441,8 @@ def linear_decode(state, layer, slots, q, k, v, fresh, log_decay=None,
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "heads", "groups", "hb", "rpc", "interpret", "scope"))
-def _decode_call(layer, slots, fresh, q, k, v, decay, state, *, heads, groups,
-                 hb, rpc, interpret, scope):
+def _decode_call(layer, slots, fresh, q, k, v, decay, state, beta=None, *,
+                 heads, groups, hb, rpc, interpret, scope):
     """The call of :func:`linear_decode`, its equations inlined into the
     program that holds it. Jitted for its cache alone: a process binds the
     kernel once a program (four in a serving process: the decode round and
@@ -371,7 +450,10 @@ def _decode_call(layer, slots, fresh, q, k, v, decay, state, *, heads, groups,
     Python, a third of a second to a second a bind on a busy host, runs for
     the first of them (``kernel.setup_trace_s``)."""
     R, dk, dv = q.shape[0], q.shape[1] // groups, v.shape[1] // heads
-    grouped, operand = groups != heads, decay is not None
+    delta = beta is not None
+    grouped, operand = groups != heads, decay is not None and not delta
+    # behind v: a head's decay (SSD), or the channels' decays and beta (delta)
+    extra = [decay, beta] if delta else [decay] if operand else []
     per_group = heads // groups
 
     def lanes(d):
@@ -384,8 +466,9 @@ def _decode_call(layer, slots, fresh, q, k, v, decay, state, *, heads, groups,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, slots, fresh
         grid=(R // rpc, heads // hb),
-        in_specs=[keys, keys, lanes(dv), *([lanes(dv)] if operand else []),
-                  in_hbm],
+        in_specs=[keys, keys, lanes(dv), *(
+            [lanes(dk), lanes(dv)] if delta else [lanes(dv)] if operand
+            else []), in_hbm],
         out_specs=[lanes(dv), in_hbm],
         scratch_shapes=[
             pltpu.VMEM((3, hb, dk, dv), state.dtype),
@@ -401,20 +484,21 @@ def _decode_call(layer, slots, fresh, q, k, v, decay, state, *, heads, groups,
     with jax.named_scope(scope):
         return tracing.pallas_call(
             functools.partial(_decode_kernel, n_heads=heads, operand=operand,
-                              grouped=grouped),
+                              grouped=grouped, delta=delta),
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((R, heads * dv), jnp.float32),
                        jax.ShapeDtypeStruct(state.shape, state.dtype)],
             # the slot array, scalars counted
-            input_output_aliases={7 if operand else 6: 1},
+            input_output_aliases={6 + len(extra): 1},
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
-            name="linear_decode",
+            name="delta_decode" if delta else "linear_decode",
             attrs=dict(state_block_bytes=block_bytes, dk=dk, dv=dv,
                        rows_per_cell=rpc, groups=groups,
-                       decay="operand" if operand else "head"),
-        )(layer, slots, fresh, q, k, v, *([decay] if operand else []), state)
+                       decay="channel" if delta else
+                       "operand" if operand else "head"),
+        )(layer, slots, fresh, q, k, v, *extra, state)
 
 
 def _tile_decays(n_heads: int, tile: int):
@@ -429,15 +513,21 @@ def _tile_decays(n_heads: int, tile: int):
 
 
 def chunk_tiles(state, layer, slots, counts, q, k, v, fresh, log_decay=None,
-                scope="linear_attn"):
+                scope="linear_attn", beta=None):
     """Tiles of ``C`` consecutive tokens. ``slots`` (N,) int32 the slot of
     each tile's sequence (0: an empty tile); ``counts`` (N,) int32 the valid
     rows of each tile, a prefix of it; q, k (N, C, g, dk), ``g`` the heads or
     a divisor of them, v (N, C, h, dv), q already scaled; ``fresh`` (N,)
     bool: the tile starts its sequence; ``log_decay`` (N, C, h) float32,
-    never positive, or None for lightning's constant of the head. Returns (o
+    never positive, or None for lightning's constant of the head. ``beta``
+    (N, C, h) float32 selects the delta rule (:func:`_delta_tiles`):
+    ``log_decay`` is then (N, C, h, dk), a key channel's own, not below
+    ``-DELTA_LOG_FLOOR``. Returns (o
     (N, C, h, dv) float32, new state). Rows past a tile's count give garbage
     that nothing reads and add nothing to the state."""
+    if beta is not None:
+        return _delta_tiles(state, layer, slots, counts, q, k, v, fresh,
+                            log_decay, beta, scope)
     N, C, H, _ = v.shape
     idx = jnp.arange(C)
     if log_decay is None:
@@ -486,6 +576,91 @@ def chunk_tiles(state, layer, slots, counts, q, k, v, fresh, log_decay=None,
     return o, state
 
 
+def _unit_lower_inverse(low):
+    """``(I + low)^-1`` of strictly lower triangular ``low`` (..., C, C), by
+    forward substitution a row: row ``i`` of the inverse is ``e_i - low[i, :i]
+    @ rows[:i]``."""
+    C = low.shape[-1]
+    eye = jnp.eye(C, dtype=low.dtype)
+    rows = [jnp.broadcast_to(eye[0], low.shape[:-2] + (C,))]
+    for i in range(1, C):
+        rows.append(eye[i] - jnp.einsum(
+            "...j,...jc->...c", low[..., i, :i], jnp.stack(rows, axis=-2),
+            precision=_HI))
+    return jnp.stack(rows, axis=-2)
+
+
+def _delta_tiles(state, layer, slots, counts, q, k, v, fresh, log_decay, beta,
+                 scope):
+    """:func:`chunk_tiles` of the delta rule: q, k (N, C, h, dk), v (N, C, h,
+    dv), ``log_decay`` (N, C, h, dk) in ``[-DELTA_LOG_FLOOR, 0]``, ``beta``
+    (N, C, h). A tile goes in sub-tiles of :data:`DELTA_SUB` rows (all of it
+    where it has fewer). With ``c_i`` the running sum of a sub-tile's
+    log-decays from its first row, ``Kd = K * exp(c)``, ``Ki = K * exp(-c)``
+    (the one power of a positive number: at most ``exp(DELTA_SUB *
+    DELTA_LOG_FLOOR)``) and ``A = tril(Kd Ki^T, -1)``, the writes ``U`` of a
+    sub-tile solve ``(I + diag(beta) A) U = diag(beta) (V - Kd S_0)``: with
+    ``T`` the inverse (forward substitution), ``U = T beta V - (T beta Kd)
+    S_0``, the two products made for all sub-tiles at once and only what
+    holds ``S_0`` walked in row order::
+
+        O   = (Q * exp(c)) S_0 + tril((Q * exp(c)) Ki^T) U
+        S_n = exp(c_n) S_0 + (K * exp(c_n - c))^T U
+
+    A row past its tile's count decays nothing and writes nothing (``beta``
+    0), so ``c_n`` is the valid rows' sum."""
+    N, C, H, dk = q.shape
+    if C > DELTA_SUB and C % DELTA_SUB:
+        raise ValueError(f"a tile of {C} rows is no multiple of the delta "
+                         f"rule's sub-tile of {DELTA_SUB}")
+    sub = min(C, DELTA_SUB)
+    per = C // sub
+    f32 = jnp.float32
+    with jax.named_scope(scope):
+        valid = (jnp.arange(C)[None] < counts[:, None])[..., None]  # (N, C, 1)
+        beta = jnp.where(valid, beta.astype(f32), 0.0)
+        log_decay = jnp.where(valid[..., None], log_decay.astype(f32), 0.0)
+
+        def subs(x):
+            return x.astype(f32).reshape(N * per, sub, *x.shape[2:])
+
+        q, k, v, beta, log_decay = map(subs, (q, k, v, beta, log_decay))
+        c = jnp.cumsum(log_decay, axis=1)                    # (M, sub, h, dk)
+        total = c[:, -1]                                     # (M, h, dk)
+        qd, kd, ki = q * jnp.exp(c), k * jnp.exp(c), k * jnp.exp(-c)
+        k_end = k * jnp.exp(total[:, None] - c)
+        idx = jnp.arange(sub)
+        below = (idx[:, None] > idx[None, :])[None, None]    # j < i
+        upto = (idx[:, None] >= idx[None, :])[None, None]    # j <= i
+        a = jnp.einsum("mihk,mjhk->mhij", kd, ki, precision=_HI)
+        t = _unit_lower_inverse(jnp.where(
+            below, a * beta.transpose(0, 2, 1)[..., None], 0.0))
+        t = t * beta.transpose(0, 2, 1)[:, :, None, :]       # T diag(beta)
+        w_v = jnp.einsum("mhij,mjhv->mihv", t, v, precision=_HI)
+        w_k = jnp.einsum("mhij,mjhk->mihk", t, kd, precision=_HI)
+        b = jnp.where(upto, jnp.einsum("mihk,mjhk->mhij", qd, ki,
+                                       precision=_HI), 0.0)
+        # a sub-tile's slot is its tile's; only a tile's first can be fresh
+        slot_of = jnp.repeat(slots, per)
+        fresh_of = jnp.repeat(fresh, per) & (jnp.arange(N * per) % per == 0)
+
+    def step(state, args):
+        slot, fresh, qd, w_v, w_k, b, k_end, total = args
+        with jax.named_scope(scope):
+            s0 = jnp.where(fresh, 0.0, 1.0) * state[layer, slot]  # (h, dk, dv)
+            u = w_v - jnp.einsum("ihk,hkv->ihv", w_k, s0, precision=_HI)
+            o = (jnp.einsum("ihk,hkv->ihv", qd, s0, precision=_HI)
+                 + jnp.einsum("hij,jhv->ihv", b, u, precision=_HI))
+            s = (jnp.exp(total)[..., None] * s0
+                 + jnp.einsum("jhk,jhv->hkv", k_end, u, precision=_HI))
+            state = state.at[layer, slot].set(s)
+        return state, o
+
+    state, o = jax.lax.scan(
+        step, state, (slot_of, fresh_of, qd, w_v, w_k, b, k_end, total))
+    return o.reshape(N, C, H, -1), state
+
+
 # -- the causal convolution before an SSD layer's recurrence ----------------
 
 def init_conv(layers: int, slots: int, taps: int, channels: int, dtype):
@@ -499,14 +674,16 @@ def conv_rows(window, layer, slots, x, taps, bias, fresh):
     """The depthwise causal convolution of one token a row: ``y = sum_i
     taps[i] * x_{t-K+1+i} + bias`` over the slot's ``K - 1`` rows and the
     row's own. ``window``: :func:`init_conv`'s array; ``slots`` (R,) int32;
-    ``x`` (R, ch); ``taps`` (K, ch), the oldest first; ``bias`` (ch,);
+    ``x`` (R, ch); ``taps`` (K, ch), the oldest first; ``bias`` (ch,) or None;
     ``fresh`` (R,) bool: the row starts its sequence, on zeros whatever the
     slot held. Returns (y (R, ch) float32, the window with each row's slot
     moved on by its row)."""
     past = jnp.where(fresh[:, None, None], 0, window[layer, slots])
     full = jnp.concatenate([past, x[:, None].astype(window.dtype)], axis=1)
     y = jnp.sum(full.astype(jnp.float32) * taps.astype(jnp.float32)[None],
-                axis=1) + bias.astype(jnp.float32)
+                axis=1)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return y, window.at[layer, slots].set(full[:, 1:])
 
 
@@ -518,7 +695,8 @@ def conv_tiles(window, layer, slots, counts, x, taps, bias, fresh):
     are walked in order, so a tile sees what the tile before it left. Returns
     (y (N, C, ch) float32, new window)."""
     K, C = taps.shape[0], x.shape[1]
-    w, b = taps.astype(jnp.float32), bias.astype(jnp.float32)
+    w = taps.astype(jnp.float32)
+    b = 0.0 if bias is None else bias.astype(jnp.float32)
 
     def tile(window, args):
         slot, n, x, fresh = args

@@ -2,7 +2,7 @@
 ``LAYER_KINDS``, which the tree, the specs, the counts, the cache declaration,
 the paged forward and the tracer read.
 
-For each of the eight kinds, a model of that kind alone at tiny widths:
+For each of the ten kinds, a model of that kind alone at tiny widths:
 (a) ``num_parameters`` is the size of the tree ``init_params`` builds;
 (b) ``tp_specs`` has that tree's structure, leaf for leaf, a spec entry an
 axis (before PR 64 a ``layer_types`` model got the GPT-2 tree's specs under
@@ -13,7 +13,9 @@ round that are no model or serving scope are the ones its record declares
 ``tracing.classify`` gives each back.
 And (d) what the serving configurations' layer patterns declare to the
 engine, as literals read off the parent commit (the sixth, PR 66's, off its
-own: a kind that keeps KV blocks and a slot of two arrays).
+own: a kind that keeps KV blocks and a slot of two arrays; the seventh, PR
+69's, off its own: state slots of two arrays beside a latent pool of one
+layer).
 """
 
 import math
@@ -53,6 +55,25 @@ def hybrid():
         mlp_mults=(0.177, 0.0112), head_mult=0.0078125)
 
 
+def delta_latent(layer_types=("delta_attn", "delta_attn", "delta_attn",
+                              "latent_attn"), **over):
+    """The ``ling-3.0-flash`` rehearsal's pattern: delta-rule (KDA) layers
+    on state slots and a latent layer on the latent pool, the first layer's
+    feed-forward dense and the others' held experts; ``over`` to make a model
+    of one of the kinds alone."""
+    return TransformerConfig(**{**dict(
+        vocab_size=256, hidden_size=128, num_layers=len(layer_types),
+        num_heads=4, head_dim_override=32, intermediate_size=64,
+        dense_intermediate_size=192, num_dense_layers=1, max_seq_len=128,
+        pos_embedding="rope", norm="rmsnorm", activation="swiglu",
+        tie_embeddings=False, norm_eps=1e-6, rope_theta=6e6,
+        layer_types=layer_types, linear_chunk=16, ssm_conv=4,
+        attn_head_gate=True, kv_lora_rank=32, qk_nope_head_dim=32,
+        qk_rope_head_dim=16, v_head_dim=32, num_experts=4, moe_top_k=4,
+        moe_router="group_limited", moe_router_width=16, moe_n_group=4,
+        moe_topk_group=2, moe_score_scale=2.5, moe_shared_size=64), **over})
+
+
 def of_types(*types):
     """A ``layer_types`` model of ``types`` around held experts, the first
     layer's feed-forward dense."""
@@ -78,6 +99,9 @@ ALONE = {
     "window_attn": lambda: of_types("window_attn", "window_attn"),
     "full_attn": lambda: of_types("full_attn", "full_attn"),
     "hybrid_ssm": hybrid,
+    # dense feed-forwards: the kind's record declares ``dense_ffn``
+    "delta_attn": lambda: delta_latent(("delta_attn",) * 2, num_experts=0),
+    "latent_attn": lambda: delta_latent(("latent_attn",) * 2, num_experts=0),
 }
 
 
@@ -206,6 +230,18 @@ DECLARED = {
                                     ("state_slot", 8192 + 3 * 192 * 2))},
         pool_layers=3, class_layers={"full": 3}, kv_row=(32, 32),
         pool_heads=2, segment_tile=16, step_counts=())),
+    # a slot of two arrays a KDA layer (the float32 state of 4 heads x 32 x
+    # 32 and the window's 3 rows of [q | k | v]) and ONE pool layer of latent
+    # rows (32 + 16, padded to 128 lanes) for the model's one latent layer
+    "ling-3.0-flash": (delta_latent, dict(
+        type_runs=(("blocks_0", "delta_attn", 1, 0),
+                   ("blocks_1", "delta_attn", 2, 0),
+                   ("blocks_2", "latent_attn", 1, 1)),
+        cache_kinds={"delta_attn": (("state_slot", 16384 + 3 * 384 * 2),),
+                     "latent_attn": (("kv_blocks", 256),)},
+        pool_layers=1, class_layers={"full": 1}, kv_row=(32, 96),
+        pool_heads=1, segment_tile=16,
+        step_counts=("moe_rows", "moe_rows_max"))),
 }
 
 
@@ -219,6 +255,34 @@ def test_a_slot_may_be_a_small_tree_of_arrays():
                      "conv": ((3, 6, 3, 192), "bfloat16")}}
     assert dict(model.config.cache_kinds["hybrid_ssm"])["state_slot"] \
         == 8192 + 3 * 192 * 2
+
+
+def test_slots_and_a_latent_pool_in_one_model():
+    """``ling-3.0-flash``'s pattern: the pool's row is the latent layer's
+    (the first kind keeps no KV blocks), the slot arrays are the KDA groups'
+    alone, and a latent layer without a low-rank query step has one ``wq``."""
+    model = TransformerLM(delta_latent())
+    cfg = model.config
+    assert cfg.holds_state and not cfg.is_mla
+    pool = jax.eval_shape(lambda: model.init_kv_pool(NUM_BLOCKS, BLOCK))
+    assert pool.shape == (1, 1, NUM_BLOCKS, BLOCK, 128)
+    state = model.init_state_cache(5, 128, dtype=jnp.bfloat16)
+    assert jax.tree.map(lambda a: (a.shape, a.dtype.name), state) == {
+        key: {"state": ((n, 6, 4, 32, 32), "float32"),
+              "conv": ((n, 6, 3, 384), "bfloat16")}
+        for key, n in (("blocks_0", 1), ("blocks_1", 2))}
+    groups, _ = cfg.tree_shapes()
+    latent_leaves = groups["blocks_2"][1]
+    assert latent_leaves["wq"] == (128, 4 * 48) and "wq_a" not in latent_leaves
+    assert latent_leaves["w_ogate"] == (128, 4) \
+        == groups["blocks_1"][1]["w_ogate"]
+    with pytest.raises(ValueError, match="latent_attn layer needs"):
+        delta_latent(kv_lora_rank=0)
+    with pytest.raises(ValueError, match="delta_attn layer needs"):
+        delta_latent(ssm_conv=1)
+    # a floor the blocked form's sub-tiles could not hold in float32
+    with pytest.raises(ValueError, match="delta_attn layer needs"):
+        delta_latent(kda_log_floor=-8.0)
 
 
 @pytest.mark.parametrize("name", sorted(DECLARED))
