@@ -25,14 +25,31 @@ Keys and queries may be a group's (lightning, SSD): ``q, k (rows, groups,
 dk)`` beside ``v (rows, heads, dv)``, ``groups`` a divisor of ``heads`` and
 ``dk`` any width beside ``dv``. What else an SSD layer has (``dt`` on the
 value, the skip ``D x``, its gate and norm) is the caller's, as are the delta
-rule's L2 norms of q and k. A causal convolution's window, the last ``taps -
-1`` rows a sequence, is a second slot array of the same slots
-(:func:`conv_rows`, :func:`conv_tiles`). The states of all layers of a group live in ONE array
-``(layers, slots, heads, dk, dv)`` float32, a **slot** a sequence: slot 0 is
-the trash slot that padding rows read and write (as block 0 of the KV pool
-is), a live sequence holds one of the others from admission to its end. The
-array rides the model's layer scan as a carry on a donated buffer and is
+rule's L2 norms of q and k. The states of all layers of a group live in ONE
+array ``(layers, slots, heads, dk, dv)`` float32, a **slot** a sequence: slot
+0 is the trash slot that padding rows read and write (as block 0 of the KV
+pool is), a live sequence holds one of the others from admission to its end.
+The array rides the model's layer scan as a carry on a donated buffer and is
 updated where it lies.
+
+**The window array.** A causal convolution's window, the last ``taps - 1``
+rows a sequence in the model's dtype, is a second slot array of the same
+slots, ``(layers, 1 + slots, taps - 1, channels)`` (:func:`init_conv`), on
+the same carry. Nobody but :func:`conv_rows` and :func:`conv_tiles` touches
+it. On the chip such an array lies with its SLOTS as the second-minor
+dimension (``bf16[5,129,3,12288]{3,1,2,0:T(8,128)(2,1)}``: the least
+padding), whatever a program does with it: it IS the planes ``(layers, taps -
+1, 1 + slots, channels)``, a slot one row of each, and a program that reads
+``window[layer, slot]`` slabs relays the whole array into its layer scan and
+back, every dispatch. So where the paged programs take kernels a
+convolution without a bias works on the planes (:func:`_on_planes`): a
+round's one-token rows through the kernel :func:`conv_decode`, which streams
+a layer's planes through VMEM a block of channels at a time, the array pinned
+in HBM and aliased to the result, and changes the live rows' slots; a mixed
+step's tiles through :func:`conv_tiles`' scan, which reads and writes a slot
+with the eight rows of its HBM tile. Elsewhere (the CPU, a biased
+convolution, channels that are no lane tiles) both keep the XLA form on
+``window[layer, slot]``.
 
 Nobody zeroes a slot: a row at position 0 of its sequence starts from a zero
 state whatever the slot held (``fresh``), so a slot handed to the next
@@ -670,6 +687,21 @@ def init_conv(layers: int, slots: int, taps: int, channels: int, dtype):
     return jnp.zeros((layers, 1 + slots, taps - 1, channels), dtype)
 
 
+def _on_planes(taps, bias, channels: int) -> bool:
+    """Does a step's convolution run on the window array as the planes it is
+    on the chip (:func:`conv_decode`; :func:`conv_tiles` beside it, so that a
+    program holds the array in one layout)? Where the paged programs take
+    kernels and the channels are whole lane tiles. A convolution with a bias
+    (the SSD mixer's) keeps the XLA form whatever the kernel takes: the
+    benchmark's own compile test of its cell
+    (``tests/benchmark/test_chip_compile_falcon.py``) pins the custom calls
+    of a hybrid layer's body at two, and is the benchmark's to change."""
+    from .paged_attention import kernels_wanted
+
+    return (kernels_wanted() and bias is None and taps.shape[0] > 1
+            and channels % 128 == 0)
+
+
 def conv_rows(window, layer, slots, x, taps, bias, fresh):
     """The depthwise causal convolution of one token a row: ``y = sum_i
     taps[i] * x_{t-K+1+i} + bias`` over the slot's ``K - 1`` rows and the
@@ -677,7 +709,11 @@ def conv_rows(window, layer, slots, x, taps, bias, fresh):
     ``x`` (R, ch); ``taps`` (K, ch), the oldest first; ``bias`` (ch,) or None;
     ``fresh`` (R,) bool: the row starts its sequence, on zeros whatever the
     slot held. Returns (y (R, ch) float32, the window with each row's slot
-    moved on by its row)."""
+    moved on by its row). The kernel :func:`conv_decode` where
+    :func:`_on_planes` says so, else the same arithmetic in XLA over all rows
+    (what the tests compare the kernel with)."""
+    if _on_planes(taps, bias, x.shape[1]):
+        return conv_decode(window, layer, slots, x, taps, bias, fresh)
     past = jnp.where(fresh[:, None, None], 0, window[layer, slots])
     full = jnp.concatenate([past, x[:, None].astype(window.dtype)], axis=1)
     y = jnp.sum(full.astype(jnp.float32) * taps.astype(jnp.float32)[None],
@@ -687,26 +723,217 @@ def conv_rows(window, layer, slots, x, taps, bias, fresh):
     return y, window.at[layer, slots].set(full[:, 1:])
 
 
+#: channels of one cell of :func:`conv_decode` at most: a cell holds the
+#: layer's windows of that many channels twice over, coming and going, and
+#: three float32 stages of them, 6.7 MiB of VMEM at 129 slots of three rows
+#: and 128 rows (on the chip, us a call there at 31 | 128 live rows, PERF.md
+#: 5: 512 channels 54.1 | 66.3, 1024 49.5 | 56.5, 2048 49.4 | 52.6; 3072 do
+#: not fit)
+CONV_LANES = 1024
+
+
+#: slots of the piece of the window array that :func:`conv_tiles` reads and
+#: writes back around a tile's slot: the rows of one HBM tile (8, 128)
+CONV_SLOT_ROWS = 8
+
+
+def conv_lanes(channels: int) -> int:
+    """Channels of one cell of :func:`conv_decode`: the most whole lane tiles
+    that divide ``channels`` and are at most :data:`CONV_LANES`."""
+    return max(d for d in range(128, CONV_LANES + 1, 128) if channels % d == 0)
+
+
+def _conv_kernel(layer_ref, slots_ref, fresh_ref, x_ref, t_ref, *rest, bias):
+    """Grid (channels / cb,): ONE cell per ``cb`` channels
+    (:func:`conv_lanes`) of all the step's rows. ``w_ref``, ``o_ref`` (K - 1,
+    1 + slots, cb): the layer's windows of those channels, a plane a tap and
+    a slot a row, as the array lies in HBM (:func:`conv_decode`), the result
+    aliased to the operand; ``x_ref`` (R, cb), ``y_ref`` (R, cb) float32,
+    ``t_ref`` (K, cb) float32, with ``bias`` a (1, cb) float32 block behind
+    it.
+
+    The first cell lists the step's live rows (``slots > 0``) in
+    ``live_ref`` with a loop of scalar steps, their count behind them, and
+    says of every slot in ``mode_ref`` (1 + slots, 128) what its row is: 0
+    none, 1 a row that goes on, 2 a fresh one. Every cell then makes what the
+    windows give each SLOT (``sum_i taps[i] * plane_i``, zeros for a fresh
+    slot's planes) over all slots at once, walks the live rows, each one's
+    sum carried to its row of ``y_ref`` and its ``x`` row to its slot's row
+    of ``xs``, adds the row's own tap (and the bias) over all rows at once,
+    and writes the planes back moved on by a row where the slot is live and
+    as they were where it is not: a dead row costs a scalar step, gets the
+    ``y`` of a zero window, and no slot changes for it, the trash slot
+    included."""
+    b_ref = rest[0] if bias else None
+    w_ref, y_ref, o_ref, live_ref, mode_ref, x32, ys, xs = rest[bias:]
+    del layer_ref                # the blocks' index: the specs read it
+    R, planes = x_ref.shape[0], w_ref.shape[0]
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(0) == 0)
+    def _sort():
+        mode_ref[...] = jnp.zeros(mode_ref.shape, jnp.int32)
+
+        def sort_row(r, n_live):
+            slot = slots_ref[r]
+            live = (slot > 0).astype(jnp.int32)
+            # a dead row's number is overwritten by the next live row's, and
+            # it says of the trash slot what is true of it: no row
+            live_ref[n_live] = r
+            mode_ref[pl.ds(slot, 1), :] = jnp.full(
+                (1, mode_ref.shape[1]), live * (1 + fresh_ref[r]), jnp.int32)
+            return n_live + live
+
+        live_ref[R] = jax.lax.fori_loop(0, R, sort_row, 0)
+
+    mode = mode_ref[:, :1]
+    live, fresh = mode > 0, mode > 1
+
+    def tap(i):
+        return t_ref[pl.ds(i, 1), :]
+
+    def past(i):
+        """Plane ``i`` as a row's convolution reads it."""
+        return jnp.where(fresh, 0.0, w_ref[i].astype(f32))
+
+    acc = tap(0) * past(0)
+    for i in range(1, planes):
+        acc = acc + tap(i) * past(i)
+    ys[...] = acc
+    x32[...] = x_ref[...].astype(f32)
+    y_ref[...] = jnp.zeros(y_ref.shape, f32)
+
+    def row(k, _):
+        r = live_ref[k]
+        slot = slots_ref[r]
+        xs[pl.ds(slot, 1), :] = x32[pl.ds(r, 1), :]
+        y_ref[pl.ds(r, 1), :] = ys[pl.ds(slot, 1), :]
+        return 0
+
+    jax.lax.fori_loop(0, live_ref[R], row, 0)
+    y = y_ref[...] + tap(planes) * x32[...]
+    y_ref[...] = y + b_ref[...] if bias else y
+    for i in range(planes):
+        moved = past(i + 1) if i + 1 < planes else xs[...]
+        o_ref[i] = jnp.where(live, moved, w_ref[i].astype(f32)).astype(
+            o_ref.dtype)
+
+
+def conv_decode(window, layer, slots, x, taps, bias, fresh):
+    """:func:`conv_rows` as a Pallas kernel, in place on the window array
+    (aliased to the result). The array's second-minor dimension on the chip
+    is its SLOTS, not a slot's ``K - 1`` rows (a device array ``(layers, 1 +
+    slots, 3, channels)`` in bfloat16 lies as ``{3,1,2,0:T(8,128)(2,1)}``:
+    the least padding), so the call takes it as the planes ``(layers, K - 1,
+    1 + slots, channels)`` that it is there, which costs nothing (a transpose
+    that is a bitcast), where a call on ``window[layer, slot]`` slabs has the
+    whole array relaid into the layer scan and back, every dispatch. A slot
+    is then one row of each plane, half of a packed pair of rows that no copy
+    moves alone: a cell takes ALL the layer's slots of its channels
+    (:func:`_conv_kernel`; ``K - 1`` rows a slot, where a slot of the float32
+    state beside them is ``dk x dv`` rows a head) through the pipeline of its
+    block specs, and the live rows decide what changes, not what moves. No
+    two live rows may name one slot."""
+    from .paged_attention import _interpret
+
+    y, planes = _conv_call(
+        jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
+        fresh.astype(jnp.int32), x.astype(window.dtype),
+        taps.astype(jnp.float32),
+        None if bias is None else bias.astype(jnp.float32)[None],
+        jnp.swapaxes(window, 1, 2), lanes=conv_lanes(x.shape[1]),
+        interpret=_interpret())
+    return y, jnp.swapaxes(planes, 1, 2)
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("lanes", "interpret"))
+def _conv_call(layer, slots, fresh, x, taps, bias, planes, *, lanes,
+               interpret):
+    """The call of :func:`conv_decode`, behind a jit for its cache alone, as
+    :func:`_decode_call` is."""
+    (R, ch), K = x.shape, taps.shape[0]
+    S = planes.shape[2]
+    extra = [] if bias is None else [bias]
+
+    def rows(n):
+        return pl.BlockSpec((n, lanes), lambda c, *_: (0, c))
+
+    layers_planes = pl.BlockSpec((None, K - 1, S, lanes),
+                                 lambda c, layer, *_: (layer[0], 0, 0, c))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # layer, slots, fresh
+        grid=(ch // lanes,),
+        in_specs=[rows(R), rows(K), *(rows(1) for _ in extra), layers_planes],
+        out_specs=[rows(R), layers_planes],
+        scratch_shapes=[
+            pltpu.SMEM((R + 1,), jnp.int32),       # the live rows, their count
+            pltpu.VMEM((S, 128), jnp.int32),       # what each slot's row is
+            pltpu.VMEM((R, lanes), jnp.float32),   # x, a row a sublane
+            pltpu.VMEM((S, lanes), jnp.float32),   # the windows' sums, a slot
+            pltpu.VMEM((S, lanes), jnp.float32),   # the live rows' x, a slot
+        ],
+    )
+    return tracing.pallas_call(
+        functools.partial(_conv_kernel, bias=len(extra)),
+        grid_spec=grid_spec,
+        # the window array is the compiler's to place no more than the state
+        # beside it: left where it can, a layer's call waits for a copy of
+        # the whole array into VMEM and another one back
+        out_shape=[jax.ShapeDtypeStruct((R, ch), jnp.float32),
+                   pltpu.HBM(planes.shape, planes.dtype)],
+        # the window array, scalars counted
+        input_output_aliases={5 + len(extra): 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="conv_decode",
+        attrs=dict(window_slot_bytes=(K - 1) * ch * planes.dtype.itemsize,
+                   channels=ch, taps=K, lanes=lanes, slots=S),
+    )(layer, slots, fresh, x, taps, *extra, planes)
+
+
 def conv_tiles(window, layer, slots, counts, x, taps, bias, fresh):
     """:func:`conv_rows` over tiles of ``C`` consecutive tokens: a tile reads
     the ``K - 1`` rows before its first from its slot (zeros where ``fresh``)
     and leaves its last ``K - 1`` valid ones there. ``slots``, ``counts``,
     ``fresh`` (N,) as :func:`chunk_tiles` takes them, ``x`` (N, C, ch); tiles
     are walked in order, so a tile sees what the tile before it left. Returns
-    (y (N, C, ch) float32, new window)."""
+    (y (N, C, ch) float32, new window). Where the one-token rows beside them
+    go through :func:`conv_decode` (:func:`_on_planes`) the tiles take the
+    array as the planes it is too, and read and write a slot with the
+    :data:`CONV_SLOT_ROWS` rows of its HBM tile: a lone row of each plane the
+    compiler reads from a copy of the whole array in a layout of its own,
+    every tile."""
     K, C = taps.shape[0], x.shape[1]
     w = taps.astype(jnp.float32)
     b = 0.0 if bias is None else bias.astype(jnp.float32)
+    planes = _on_planes(taps, bias, x.shape[2])
+    if planes:
+        window = jnp.swapaxes(window, 1, 2)
+        S = window.shape[2]
+        rows = min(S, CONV_SLOT_ROWS)
 
     def tile(window, args):
         slot, n, x, fresh = args
-        past = jnp.where(fresh, 0, window[layer, slot])       # (K - 1, ch)
+        if planes:
+            first = jnp.minimum(slot // rows * rows, S - rows)
+            at = (layer, 0, first, 0)
+            group = jax.lax.dynamic_slice(window, at,
+                                          (1, K - 1, rows, x.shape[1]))
+            mine = (jnp.arange(rows) == slot - first)[None, None, :, None]
+            past = jnp.sum(jnp.where(mine & ~fresh, group, 0), axis=(0, 2))
+        else:
+            past = jnp.where(fresh, 0, window[layer, slot])   # (K - 1, ch)
         full = jnp.concatenate([past, x.astype(window.dtype)])
         f32 = full.astype(jnp.float32)
         y = sum(w[i] * f32[i:i + C] for i in range(K)) + b
         # the tokens n - K + 1 .. n - 1, counted from the tile's first
         last = jax.lax.dynamic_slice_in_dim(full, n, K - 1)
+        if planes:
+            return jax.lax.dynamic_update_slice(
+                window, jnp.where(mine, last[None, :, None], group), at), y
         return window.at[layer, slot].set(last), y
 
     window, y = jax.lax.scan(tile, window, (slots, counts, x, fresh))
-    return y, window
+    return y, jnp.swapaxes(window, 1, 2) if planes else window

@@ -857,6 +857,135 @@ def test_a_lightning_layers_round_is_one_call_on_the_models_rows(
     assert not relaid, relaid
 
 
+#: the cells whose layers keep a convolution's window beside their state:
+#: (configuration, traffic, the window arrays of the cell's layer groups)
+CONV_CELLS = [
+    ("ling-3.0-flash", "serve-reason",
+     [(1, 129, 3, 12288), (5, 129, 3, 12288)]),
+    ("falcon-h1-34b-instruct", "serve-crowd", [(9, 65, 3, 5120)]),
+]
+_MOVES = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (\(?[^=]*?) "
+                    r"(?:copy|copy-start|gather|scatter)\((.*)$")
+
+
+def window_movers(text, shapes):
+    """The ``copy``, ``gather`` and ``scatter`` instructions of optimized HLO
+    (fused computations included, a copy into another memory space too) whose
+    result or one of whose operands is an array of one of ``shapes``."""
+    wanted = {"[" + ",".join(map(str, s)) + "]" for s in shapes}
+    shape_of = {m.group(1): m.group(2) for m in re.finditer(
+        r"%?([\w.\-]+)(?: =|:) \(*\w+(\[[\d,]*\])", text)}
+    found = []
+    for line in text.splitlines():
+        m = _MOVES.match(line)
+        if m:
+            operands = re.findall(r"%([\w.\-]+)", m.group(2).split("), ")[0])
+            moved = set(re.findall(r"\[[\d,]*\]", m.group(1))) | {
+                shape_of.get(name) for name in operands}
+            if moved & wanted:
+                found.append(line.strip()[:140])
+    return found
+
+
+def conv_cell(name, traffic):
+    """(model, the engine's geometry) of a cell, every width as published."""
+    from benchmark.harness.cell import load_json
+    from deepspeed_tpu.models import TransformerLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    mix = load_json("traffic", traffic + ".json")
+    return TransformerLM(TransformerConfig(**{
+        **load_json("configs", name + ".json")["model"],
+        **mix.get("model", {})})), mix["engine"]
+
+
+@pytest.mark.parametrize("cell", CONV_CELLS, ids=lambda c: c[1])
+def test_the_convolutions_kernel_at_a_cells_slots(one_chip, no_compile_cache,
+                                                  as_tpu, cell):
+    """``conv_rows`` alone at the cell's rows, slots and channels (128 rows on
+    129 x 3 x 12288 without a bias, 64 on 65 x 3 x 5120 with one), the window
+    array donated: Mosaic takes ``conv_decode``, in place on the array as it
+    lies (no second array, no gathered copy of the rows' windows)."""
+    from deepspeed_tpu.ops.transformer import linear_attention as la
+
+    _, engine = conv_cell(*cell[:2])
+    shape, rows = cell[2][-1], engine["max_seqs"]
+    ch, biased = shape[3], cell[1] == "serve-crowd"
+    assert shape[1] == 1 + rows
+
+    def call(window, layer, slots, x, taps, bias, fresh):
+        # by its name where there is a bias: ``conv_rows`` keeps those in XLA
+        return (la.conv_decode if biased else la.conv_rows)(
+            window, layer, slots, x, taps, bias if biased else None, fresh)
+
+    bf16 = jnp.bfloat16
+    compiled = jax.jit(call, donate_argnums=(0,)).lower(
+        aval(one_chip, shape, bf16), aval(one_chip, (), jnp.int32),
+        aval(one_chip, (rows,), jnp.int32), aval(one_chip, (rows, ch), bf16),
+        aval(one_chip, (4, ch), bf16), aval(one_chip, (ch,), bf16),
+        aval(one_chip, (rows,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "conv_decode" in text
+    assert "input_output_alias" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 * 1024
+    planes = (shape[0], shape[2], shape[1], shape[3])
+    assert window_movers(text, [shape, planes]) == []
+
+
+@pytest.mark.parametrize("step", ["round", "mixed"])
+@pytest.mark.parametrize("cell", CONV_CELLS, ids=lambda c: c[1])
+def test_a_state_layers_window_is_moved_by_conv_decode_alone(
+        one_chip, no_compile_cache, as_tpu, cell, step):
+    """Both programs of both cells through ``forward_paged`` at the cells' own
+    shapes (the decode round: 128 | 64 one-token rows apart; the mixed step:
+    those and three tiles of 128 in a 512-row budget). ``serve-reason``: ONE
+    ``conv_decode`` call a KDA layer's body, and no ``copy`` (into another
+    layout or another memory space), ``gather`` or ``scatter`` reads or
+    writes an array of a window array's shape, as it is declared or as the
+    planes the kernel takes. The parent's round relaid the whole array into
+    its layer scan and back, once each a dispatch (``copy.211`` / ``copy.237
+    bf16[5,129,3,12288]``), and gathered and scattered the rows' windows a
+    layer; the mixed step's tiles (``conv_tiles``) read and write a slot with
+    the rows of its HBM tile where the array lies. ``serve-crowd``: the SSD
+    mixer's convolution has a bias and keeps the XLA form, copies and all:
+    its layer's body holds the two custom calls the benchmark's own test pins
+    (``tests/benchmark/test_chip_compile_falcon.py``)."""
+    model, engine = conv_cell(*cell[:2])
+    seqs = engine["max_seqs"]
+    rows = seqs if step == "round" else engine["token_budget"]
+    params, pool, tables, starts = serving_avals(
+        one_chip, model, rows, engine["num_blocks"], engine["block_size"],
+        engine["max_seq_len"] // engine["block_size"])
+    state = jax.tree.map(
+        lambda a: aval(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init_state_cache(
+            seqs, engine["max_seq_len"], dtype=jnp.bfloat16)))
+    windows = [group["conv"].shape for group in state.values()]
+    assert sorted(windows) == cell[2]
+
+    def program(params, ids, pool, state, tables, starts, slots, logit_rows):
+        return model.forward_paged(
+            params, ids, pool, tables, starts, logit_rows=logit_rows,
+            seg_from=seqs if rows > seqs else None, moe_stats=True,
+            rows_apart=rows == seqs, state=state, row_slots=slots)
+
+    rows_i32 = aval(one_chip, (rows,), jnp.int32)
+    text = jax.jit(program, donate_argnums=(2, 3)).lower(
+        params, aval(one_chip, (rows, 1), jnp.int32), pool, state, tables,
+        starts, rows_i32, aval(one_chip, (seqs,), jnp.int32)
+    ).compile().as_text()
+    calls = re.findall(r"%conv_decode[.\d]* = \((\w+\[[\d,]+\])\S*, "
+                       r"(\w+\[[\d,]+\]).* custom-call\(", text)
+    planes = [(n, k, s, ch) for n, s, k, ch in windows]
+    if cell[1] == "serve-crowd":
+        assert calls == [] and window_movers(text, windows)
+        return
+    assert sorted(calls) == sorted(
+        (f"f32[{seqs},{ch}]", f"bf16[{n},{k},{s},{ch}]")
+        for n, k, s, ch in planes), calls
+    assert window_movers(text, windows + planes) == []
+
+
 def test_linear_decode_starts_no_copy_for_a_cell_with_no_live_row():
     """The kernel's body as it is traced (nothing compiles): the slot array
     is left where it lies (no block of it is the pipeline's to copy), and

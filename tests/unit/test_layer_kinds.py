@@ -181,6 +181,30 @@ def test_the_rounds_scopes_are_the_kinds_own(kind):
                            *tracing.SERVE_SCOPES}
 
 
+def test_a_kda_layers_round_binds_the_convolutions_kernel_under_its_scope(
+        monkeypatch):
+    """Where the paged programs take kernels a ``delta_attn`` layer's round
+    holds ONE ``conv_decode``, under the sublayer's own scope (the tracer gives its time to ``delta_attn``, as it
+    gave the gather, the product and the scatter it replaces); the SSD
+    mixer's biased convolution binds none."""
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+
+    def kernels(kind):
+        model = TransformerLM(ALONE[kind]())
+        args, kw = decode_round(model)
+        closed = jax.make_jaxpr(lambda *a: model.forward_paged(*a, **kw))(
+            *args)
+        return [(e.params["name"], tracing.classify(
+            "jit(ragged)/kv_carry/while/body/closed_call/"
+            f"{e.source_info.name_stack}/pallas_call"))
+            for e in _iter_eqns(closed.jaxpr)
+            if e.primitive.name == "pallas_call"]
+
+    # (four heads: the recurrence keeps its XLA form at these widths)
+    assert kernels("delta_attn") == [("conv_decode", "delta_attn")]
+    assert "conv_decode" not in dict(kernels("hybrid_ssm"))
+
+
 #: the five serving configurations' layer patterns at tiny widths -> what
 #: they declare, read off commit e6c1671 (PR 63)
 DECLARED = {

@@ -16,6 +16,7 @@ bit: the XLA forms against the parent's text (kept here), the kernel by its
 traced body (the parent's digest).
 """
 
+import functools
 import hashlib
 
 import jax
@@ -258,6 +259,184 @@ def test_the_conv_window_crosses_a_tiles_edge_and_a_slots_reuse(tokens):
                           jnp.asarray(bias), jnp.asarray([True]))
     np.testing.assert_allclose(np.asarray(y)[0], want[0], rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(np.asarray(new[1, 2, :-1]), 0.0)
+
+
+def conv_rows_as(form, monkeypatch, *args):
+    """``conv_rows`` traced as the XLA form or as the interpreted kernel
+    ``conv_decode`` (called by its name with a bias: ``conv_rows`` hands it
+    the convolutions without one); a trace a form and a shape (an eager call
+    of an interpreted kernel compiles it anew every time)."""
+    if form == "kernel":
+        monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+    else:
+        monkeypatch.delenv("DSTPU_FORCE_PAGED_KERNEL", raising=False)
+    assert pa.kernels_wanted() == (form == "kernel")
+    return _conv_jit(form, form == "kernel" and args[5] is not None)(*args)
+
+
+@functools.lru_cache(maxsize=None)
+def _conv_jit(form, by_name):
+    return jax.jit(lambda *a: (la.conv_decode if by_name else la.conv_rows)(*a))
+
+
+#: which of eight rows hold a slot (of 1..9; 0: a padding row)
+LIVE_ROWS = {"none": [0] * 8, "a prefix": [4, 7, 1, 0, 0, 0, 0, 0],
+             "scattered": [0, 5, 0, 0, 2, 0, 9, 0],
+             "all": [3, 8, 1, 9, 2, 7, 4, 6]}
+
+
+@pytest.mark.parametrize("live", LIVE_ROWS)
+@pytest.mark.parametrize("ch", [640, 1536])
+@pytest.mark.parametrize("biased", [True, False], ids=["bias", "no bias"])
+def test_the_convolutions_kernel_is_conv_rows_on_the_live_rows_slots(
+        monkeypatch, biased, ch, live):
+    """``conv_decode`` (interpreted; 1536 channels are two cells) against the
+    XLA form on a bfloat16 window array: a live row's ``y`` to float32
+    rounding and its slot bit for bit; a fresh row, live or dead, reads zeros
+    whatever the slot held; a dead row gets the ``y`` of a zero window and
+    changes no slot, the trash slot included (where the XLA form writes what
+    it pleases); the other layers are not touched."""
+    rng = np.random.default_rng(ch)
+    K, R, slots = 4, 8, np.asarray(LIVE_ROWS[live], np.int32)
+    bf16, f32 = jnp.bfloat16, np.float32
+    window = jnp.asarray(rng.normal(size=(3, 10, K - 1, ch)), bf16)
+    x = jnp.asarray(rng.normal(size=(R, ch)), bf16)
+    taps = jnp.asarray(rng.normal(size=(K, ch)), bf16)
+    bias = jnp.asarray(rng.normal(size=(ch,)), bf16) if biased else None
+    fresh = np.asarray([0, 0, 1, 0, 1, 0, 1, 1], bool)
+    args = (window, jnp.int32(1), jnp.asarray(slots), x, taps, bias,
+            jnp.asarray(fresh))
+    want_y, want_w = conv_rows_as("xla", monkeypatch, *args)
+    y, new = conv_rows_as("kernel", monkeypatch, *args)
+    assert y.shape == (R, ch) and y.dtype == jnp.float32
+    assert new.shape == window.shape and new.dtype == window.dtype
+    held = slots > 0
+    np.testing.assert_allclose(np.asarray(y)[held], np.asarray(want_y)[held],
+                               rtol=2e-6, atol=2e-6)
+    named = slots[held]
+    np.testing.assert_array_equal(np.asarray(new[1, named], f32),
+                                  np.asarray(want_w[1, named], f32))
+    rest = [s for s in range(10) if s not in named]          # slot 0 too
+    np.testing.assert_array_equal(np.asarray(new[1, rest], f32),
+                                  np.asarray(window[1, rest], f32))
+    for layer in (0, 2):
+        np.testing.assert_array_equal(np.asarray(new[layer], f32),
+                                      np.asarray(window[layer], f32))
+    zero_window = np.asarray(taps, f32)[-1] * np.asarray(x, f32) + (
+        np.asarray(bias, f32) if biased else 0.0)
+    alone = ~held | fresh
+    np.testing.assert_allclose(np.asarray(y)[alone], zero_window[alone],
+                               rtol=2e-6, atol=2e-6)
+    for r in np.flatnonzero(held & fresh):
+        np.testing.assert_array_equal(
+            np.asarray(new[1, slots[r], :-1], f32), 0.0)
+        np.testing.assert_array_equal(np.asarray(new[1, slots[r], -1], f32),
+                                      np.asarray(x[r], f32))
+
+
+def test_the_convolutions_cells_hand_their_channels_on(monkeypatch):
+    """Five cells of 128 channels (``CONV_LANES`` lowered so that the test's
+    640 channels are more cells than one) give what one cell gives: the live
+    rows' list and the slots' modes are made by the first cell and read by
+    all."""
+    rng = np.random.default_rng(5)
+    window = jnp.asarray(rng.normal(size=(2, 10, 3, 640)), jnp.bfloat16)
+    args = (window, jnp.int32(0), jnp.asarray(LIVE_ROWS["scattered"]),
+            jnp.asarray(rng.normal(size=(8, 640)), jnp.bfloat16),
+            jnp.asarray(rng.normal(size=(4, 640)), jnp.bfloat16), None,
+            jnp.asarray([False, True] * 4))
+    want_y, want_w = conv_rows_as("kernel", monkeypatch, *args)
+    monkeypatch.setattr(la, "CONV_LANES", 128)
+    assert la.conv_lanes(640) == 128 and la.conv_lanes(12288) == 128
+    y, new = jax.jit(lambda *a: la.conv_rows(*a))(*args)
+    np.testing.assert_array_equal(y, want_y)
+    np.testing.assert_array_equal(np.asarray(new, np.float32),
+                                  np.asarray(want_w, np.float32))
+
+
+def test_channels_that_are_no_lane_tiles_keep_the_xla_form(monkeypatch):
+    """The kernel's cells are whole lane tiles of channels (640 of the 5120
+    and 12288 of the served models are); another count keeps the XLA form,
+    kernels wanted or not, and so do a convolution of one tap and one with a
+    bias (the benchmark's compile test of the SSD mixer's cell pins its
+    layer's custom calls)."""
+    monkeypatch.setenv("DSTPU_FORCE_PAGED_KERNEL", "1")
+
+    def traced(ch, taps=4, bias=None):
+        return str(jax.make_jaxpr(
+            lambda w, x, t: la.conv_rows(w, 0, jnp.asarray([1, 0]), x, t,
+                                         bias, jnp.asarray([False, False])))(
+            jnp.zeros((1, 3, taps - 1, ch)), jnp.zeros((2, ch)),
+            jnp.zeros((taps, ch))))
+
+    assert "pallas_call" in traced(640) and "conv_decode" in traced(640)
+    assert "pallas_call" not in traced(24)
+    assert "pallas_call" not in traced(200)
+    assert "pallas_call" not in traced(640, taps=1)
+    assert "pallas_call" not in traced(640, bias=jnp.zeros((640,)))
+    assert [la.conv_lanes(c) for c in (128, 640, 1536, 5120, 12288)] == [
+        128, 640, 768, 1024, 1024]
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+@pytest.mark.parametrize("biased", [True, False], ids=["bias", "no bias"])
+def test_a_window_crosses_from_rounds_into_tiles_and_back_on_a_reused_slot(
+        monkeypatch, form, biased):
+    """Slot 2 of layer 1: one sequence's rounds leave their rows there; the
+    next sequence takes the slot with a fresh round, goes on for two rounds,
+    then a mixed step's tiles (two, the last partly filled) read what the
+    rounds left and leave their last three rows, and three more rounds read
+    those; every ``y`` of the second sequence is the plain causal
+    convolution of its own tokens. A second live row on slot 1 runs beside
+    the rounds; the tiles leave its slot as the rounds left it."""
+    rng = np.random.default_rng(11)
+    K, ch = 4, 640
+    before, tokens = 5, 3 + C + 5 + 3
+    old = rng.normal(size=(before, ch)).astype(np.float32)
+    x = rng.normal(size=(tokens, ch)).astype(np.float32)
+    other = rng.normal(size=(before + tokens, ch)).astype(np.float32)
+    taps = rng.normal(size=(K, ch)).astype(np.float32)
+    bias = rng.normal(size=(ch,)).astype(np.float32) if biased else None
+    window = jnp.asarray(rng.normal(size=(2, 4, K - 1, ch)), jnp.float32)
+    slots = jnp.asarray([2, 0, 1, 0], jnp.int32)
+    got, beside = [], []
+
+    def round_(window, mine, its, fresh):
+        rows = jnp.asarray(np.stack([mine, mine, its, its]))
+        y, window = conv_rows_as(
+            form, monkeypatch, window, jnp.int32(1), slots, rows,
+            jnp.asarray(taps), None if bias is None else jnp.asarray(bias),
+            jnp.asarray([fresh, False, len(beside) == 0, True]))
+        beside.append(np.asarray(y)[2])
+        return np.asarray(y)[0], window
+
+    for t in range(before):
+        _, window = round_(window, old[t], other[t], t == 0)
+    for t in range(3):
+        y, window = round_(window, x[t], other[before + t], t == 0)
+        got.append(y[None])
+    kept = np.asarray(window[1, 1])
+    n = C + 5
+    counts = jnp.asarray([C, 5], jnp.int32)
+    y, window = la.conv_tiles(
+        window, jnp.int32(1), jnp.asarray([2, 2], jnp.int32), counts,
+        tiled(x[3:3 + n], 2), jnp.asarray(taps),
+        None if bias is None else jnp.asarray(bias),
+        jnp.asarray([False, False]))
+    got.append(np.asarray(y).reshape(2 * C, ch)[:n])
+    np.testing.assert_array_equal(np.asarray(window[1, 1]), kept)
+    np.testing.assert_array_equal(np.asarray(window[1, 2]), x[n:3 + n])
+    for t in range(3 + n, tokens):
+        y, window = round_(window, x[t], other[before + t - n], False)
+        got.append(y[None])
+    b = 0.0 if bias is None else bias
+    np.testing.assert_allclose(
+        np.concatenate(got), causal_conv(x.astype(np.float64), taps, b),
+        rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.stack(beside), causal_conv(
+            other[:before + 6].astype(np.float64), taps, b),
+        rtol=1e-5, atol=1e-5)
 
 
 # -- (c) lightning is the parent's ------------------------------------------

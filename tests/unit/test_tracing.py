@@ -450,6 +450,39 @@ def test_fused_ce_kernels_are_named():
     assert names == ["fused_ce_fwd", "fused_ce_bwd"]
 
 
+def conv_round(window, x, taps):
+    from deepspeed_tpu.ops.transformer import linear_attention as la
+
+    with jax.named_scope("attn"), jax.named_scope("delta_attn"):
+        return la.conv_decode(window, 0, jnp.asarray([2, 0], jnp.int32), x,
+                              taps, None, jnp.asarray([False, True]))
+
+
+def test_the_convolutions_kernel_is_named_and_says_what_it_moves():
+    """``conv_decode`` by its name in the jaxpr, and on the record of the
+    program that bound it with what it said of the call: the bytes of a
+    slot's window, the channels, the taps, a cell's channels and the slots a
+    cell holds."""
+    from deepspeed_tpu.ops.transformer import linear_attention as la
+
+    args = (jnp.ones((2, 5, 3, 256), jnp.bfloat16),
+            jnp.ones((2, 256), jnp.bfloat16), jnp.ones((4, 256), jnp.bfloat16))
+    assert kernel_names(lambda *a: conv_round(*a), *args) == ["conv_decode"]
+    # the call sits behind a jit of its own: a trace it has cached binds
+    # nothing again
+    la._conv_call.clear_cache()
+    mark = tracing.clock_ns()
+    jax.jit(conv_round).lower(*args).compile()
+    rec, = [r for r in tracing.builds()
+            if r.end > mark and "conv_round" in r.attrs["program"]]
+    (calls, seconds), = rec.attrs["kernels"].values()
+    assert list(rec.attrs["kernels"]) == ["conv_decode"]
+    assert calls == 1 and 0 < seconds < rec.attrs["trace_s"]
+    assert rec.attrs["kernel_attrs"] == {"conv_decode": dict(
+        window_slot_bytes=3 * 256 * 2, channels=256, taps=4, lanes=256,
+        slots=5)}
+
+
 @pytest.mark.parametrize("vocab, path", [(256, "fused"), (200, "plain")])
 def test_the_train_step_says_which_head_loss_it_took(vocab, path, session):
     """``head_loss`` on the step's ``engine.enqueue`` spans: ``fused`` where
@@ -489,6 +522,11 @@ def test_the_train_step_says_which_head_loss_it_took(vocab, path, session):
     ("jit(ragged)/kv_carry/while/body/closed_call/attn/kv_write/scatter",
      "kv_write"),
     ("jit(ragged)/kv_carry/while/body/closed_call/mlp/dot_general", "model"),
+    # the convolution's kernel is its caller's: the KDA sublayer's, the mixer's
+    ("jit(ragged)/kv_carry/while/body/closed_call/attn/delta_attn/conv_decode/"
+     "pallas_call", "delta_attn"),
+    ("jit(ragged)/kv_carry/while/body/closed_call/attn/ssm_mixer/conv_decode/"
+     "pallas_call", "ssm_mixer"),
     ("jit(step)/optimizers/sub", "unscoped"),
     # the last component is the primitive and marks nothing: an array's
     # transpose is no backward pass, in a serving program or a forward
