@@ -1436,7 +1436,12 @@ class InferenceEngineV2:
                      ctx_tokens=ctx, ctx_tokens_by_row=by_row,
                      blocks_allocated=max(0, marks[0] - self._count_marks[0]),
                      cow_copies=max(0, marks[1] - self._count_marks[1]),
-                     decode_ctx_tokens=dctx)
+                     decode_ctx_tokens=dctx,
+                     # the pool as the step leaves it, every model's: the
+                     # blocks held and free (of the full class, where there
+                     # are two), and how often a token walks the layers
+                     pool_blocks=used["full"], pool_free=mgr.free_blocks,
+                     loop_steps=self.cfg.loop_steps)
             if mgr.slots is not None:
                 disp.set(state_slots=mgr.slots.in_use)
             if w is not None:

@@ -203,6 +203,74 @@ def from_hf_llama(model) -> Tuple[TransformerLM, Dict[str, Any]]:
     return model_out, params
 
 
+def from_hf_ouro(model) -> Tuple[TransformerLM, Dict[str, Any]]:
+    """Convert an Ouro looped causal LM (``OuroForCausalLM``, ``model_type``
+    ``ouro``): ``num_hidden_layers`` layers run ``total_ut_steps`` times a
+    token, four RMSNorms a layer, the model's one norm closing every step and
+    an exit gate beside it (``TransformerConfig.loop_steps``). ``model`` is
+    the HF module or anything with its ``config`` and ``state_dict()``.
+
+    The name map is recalled from the public ``modeling_ouro.py``, not read
+    (there is no checkpoint in the repository): ``input_layernorm`` and
+    ``input_layernorm_2`` norm the attention's input and output,
+    ``post_attention_layernorm`` and ``post_attention_layernorm_2`` the
+    feed-forward's; ``model.norm`` is the closing norm, ``model.
+    early_exit_gate`` a ``Linear(hidden_size, 1)`` with a bias."""
+    hf_cfg = model.config
+    sd = {k: _np(v) for k, v in model.state_dict().items()}
+    H, L = hf_cfg.hidden_size, hf_cfg.num_hidden_layers
+    nh = hf_cfg.num_attention_heads
+    V = hf_cfg.vocab_size
+    tie = bool(getattr(hf_cfg, "tie_word_embeddings", False))
+    cfg = TransformerConfig(
+        vocab_size=V, hidden_size=H, num_layers=L, num_heads=nh,
+        num_kv_heads=getattr(hf_cfg, "num_key_value_heads", nh),
+        head_dim_override=getattr(hf_cfg, "head_dim", None),
+        intermediate_size=hf_cfg.intermediate_size,
+        max_seq_len=getattr(hf_cfg, "max_position_embeddings", 4096),
+        pos_embedding="rope", norm="rmsnorm", activation="swiglu",
+        tie_embeddings=tie, norm_eps=getattr(hf_cfg, "rms_norm_eps", 1e-6),
+        rope_theta=float(getattr(hf_cfg, "rope_theta", 10000.0)),
+        post_norms=True, loop_steps=int(hf_cfg.total_ut_steps),
+        early_exit_threshold=float(getattr(hf_cfg, "early_exit_threshold", 1.0)),
+        name="ouro-hf",
+    )
+    if not cfg.looped:
+        raise ValueError("from_hf_ouro: total_ut_steps 1 is no looped model "
+                         "(its tree has no `loop` group)")
+    pre = "model.layers.{}"
+    params = {
+        "wte": jnp.asarray(sd["model.embed_tokens.weight"]),
+        "blocks": {
+            "ln1_scale": _stack(sd, pre + ".input_layernorm.weight", L),
+            "wq": _stackT(sd, pre + ".self_attn.q_proj.weight", L),
+            "wk": _stackT(sd, pre + ".self_attn.k_proj.weight", L),
+            "wv": _stackT(sd, pre + ".self_attn.v_proj.weight", L),
+            "wo": _stackT(sd, pre + ".self_attn.o_proj.weight", L),
+            "post_attn_scale": _stack(sd, pre + ".input_layernorm_2.weight", L),
+            "ln2_scale": _stack(sd, pre + ".post_attention_layernorm.weight", L),
+            "w_gate": _stackT(sd, pre + ".mlp.gate_proj.weight", L),
+            "w_up": _stackT(sd, pre + ".mlp.up_proj.weight", L),
+            "w_down": _stackT(sd, pre + ".mlp.down_proj.weight", L),
+            "post_mlp_scale": _stack(
+                sd, pre + ".post_attention_layernorm_2.weight", L),
+        },
+        # the closing norm and the gate: a stacked group of one layer
+        "loop": {
+            "norm_scale": jnp.asarray(sd["model.norm.weight"])[None],
+            "exit_w": jnp.asarray(sd["model.early_exit_gate.weight"]
+                                  ).reshape(1, H),
+            "exit_b": jnp.asarray(sd["model.early_exit_gate.bias"]
+                                  ).reshape(1),
+        },
+    }
+    if not tie:
+        params["lm_head"] = jnp.asarray(sd["lm_head.weight"].T)
+    log_dist(f"converted HF Ouro: H={H} L={L} x {cfg.loop_steps} steps "
+             f"heads={nh} vocab={V}", ranks=[0])
+    return TransformerLM(cfg), params
+
+
 def from_hf_opt(model) -> Tuple[TransformerLM, Dict[str, Any]]:
     """Convert an HF OPT causal LM (reference ``module_inject/containers/opt.py``,
     v2 ``model_implementations/opt``). Learned positions carry a +2 offset in the
@@ -923,6 +991,7 @@ _CONVERTERS = {
     "opt": from_hf_opt,
     "gptj": from_hf_gptj,
     "gptneox": from_hf_gptneox,
+    "ouro": from_hf_ouro,
     "bloom": from_hf_bloom,
     "falcon": from_hf_falcon,
     "rwforcausallm": from_hf_falcon,  # pre-rename Falcon checkpoints
@@ -944,7 +1013,7 @@ _UNSUPPORTED = ["phi3", "phimoe", "internlm2", "qwen2moe", "gptneoforcausallm",
 
 # match order matters: more specific names first ("gptneox" before "gptneo",
 # "mixtral" before "llama"-substring families)
-_MATCH_ORDER = ["gptneox", "gptj", "gptbigcode", "gpt2", "mixtral", "qwen2",
+_MATCH_ORDER = ["ouro", "gptneox", "gptj", "gptbigcode", "gpt2", "mixtral", "qwen2",
                 "internlm", "mistral", "llama", "opt", "bloom", "falcon",
                 "rwforcausallm", "phi", "distilbert", "roberta", "bert",
                 "gemma", "mpt"]

@@ -346,6 +346,9 @@ LAYER_KINDS: Dict[str, LayerKind] = {
 # a kind's scopes are declared to the tracer here, where they are opened
 tracing.layer_scopes(*(s for kind in LAYER_KINDS.values()
                        for s in kind.scopes))
+# and the one the end of a looped model's step is traced under
+# (``TransformerLM._loop_close``: the closing norm and the exit gate)
+tracing.layer_scopes("loop_close")
 
 
 @dataclass(frozen=True)
@@ -481,6 +484,19 @@ class TransformerConfig:
     kda_log_floor: float = -5.0
     sliding_window: int = 0
     post_norms: bool = False
+    # a looped model (Ouro): the SAME ``num_layers`` layers of weights run
+    # ``loop_steps`` times a token. The final norm closes every step and its
+    # output feeds the next one; a token's keys and values of step ``t``,
+    # layer ``l`` live at cache layer ``t * num_layers + l`` (``pool_layers``
+    # is ``loop_steps`` walks of the stack); an exit gate ``sigmoid(h_t .
+    # exit_w + exit_b)`` reads each step's closed state and the head reads
+    # the state of the first step at which the exit distribution's sum
+    # reaches ``early_exit_threshold`` (1.0: the last step's, every token;
+    # every step is computed either way). The closing norm and the gate are a
+    # stacked group of one layer, ``params["loop"]``, in ``lnf_scale``'s
+    # place. The ``full`` kind only
+    loop_steps: int = 1
+    early_exit_threshold: float = 1.0
     sparse_kernel_size: int = 32
     sparse_kernel_stride: int = 16
     sparse_window: int = 2048       # tokens; whole blocks ending at the query's
@@ -598,6 +614,24 @@ class TransformerConfig:
                 raise ValueError(
                     "a latent_attn layer needs kv_lora_rank, "
                     "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+        if self.loop_steps < 1:
+            raise ValueError(f"loop_steps {self.loop_steps}: at least 1")
+        if self.looped and (
+                self.layer_types is not None or self.is_mla
+                or self.norm_position == "post" or self.mlm_head
+                or self.num_experts):
+            raise NotImplementedError(
+                f"loop_steps {self.loop_steps}: a run of layers visited "
+                "several times is wired for pre-norm dense `full`-kind "
+                "layers only (no layer_types, attention='mla', experts, "
+                "post-LN or MLM head): a state slot or a latent row a step "
+                "has no layout yet")
+        if self.post_norms and self.layer_types is None and (
+                self.norm != "rmsnorm" or self.is_mla or self.parallel_block
+                or self.norm_position == "post"):
+            raise NotImplementedError(
+                "post_norms on the `full` kind: RMSNorm, sequential pre-norm "
+                "blocks")
         for name in ("ssm_zone_mults", "mlp_mults"):
             object.__setattr__(self, name, tuple(
                 float(m) for m in getattr(self, name)))
@@ -608,6 +642,11 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def looped(self) -> bool:
+        """The layers run more than once a token (``loop_steps``)."""
+        return self.loop_steps > 1
 
     @property
     def ssm_inner(self) -> int:
@@ -692,7 +731,7 @@ class TransformerConfig:
         for (_, _, n, per), rec in zip(self.type_runs, self.kinds):
             if per:
                 layers[rec.block_class] = layers.get(rec.block_class, 0) \
-                    + n * per
+                    + self.loop_steps * n * per
         return layers
 
     @property
@@ -731,7 +770,9 @@ class TransformerConfig:
             kept = ()
             if rec.pool_layers:
                 heads, row = rec.row(self)
-                kept += (("kv_blocks", 2 * heads * sum(row))
+                # a layer of weights visited ``loop_steps`` times keeps a
+                # row for each visit
+                kept += (("kv_blocks", 2 * heads * sum(row) * self.loop_steps)
                          + ((rec.bound(self),) if rec.bound else ()),)
             if rec.slots:
                 slot = jax.eval_shape(lambda: rec.slots(
@@ -785,8 +826,12 @@ class TransformerConfig:
     @property
     def pool_layers(self) -> int:
         """Layers of the paged pool: one for every attention of the model
-        that keeps KV blocks. THE place the pool's layer axis is read from."""
-        return sum(n * per for _, _, n, per in self.type_runs)
+        that keeps KV blocks, each time a token passes it (``loop_steps``
+        walks of ``type_runs``; step ``t`` of a looped model owns the layers
+        from ``t *`` one walk's on). THE place the pool's layer axis is read
+        from: a reader that means cache reads this, one that means weights
+        ``num_layers``."""
+        return self.loop_steps * sum(n * per for _, _, n, per in self.type_runs)
 
     @property
     def mla_latent_scales(self) -> Tuple[float, float]:
@@ -873,6 +918,7 @@ class TransformerConfig:
         qd, kvd = self.num_heads * self.head_dim, self.kv_heads * self.head_dim
         attn = H * qd + 2 * H * kvd + qd * H  # q, k, v, o
         n_ln = 1 if (self.parallel_block and self.parallel_shared_ln) else 2
+        n_ln += 2 * self.post_norms
         norms = n_ln * (1 if self.norm == "rmsnorm" else 2) * H
         mlp = self._mlp_params(self.mlp_dim)
         dense_layer = attn + norms + self._mlp_params(self.dense_mlp_dim)
@@ -886,8 +932,10 @@ class TransformerConfig:
         n_moe = self.num_moe_layers if self.num_experts > 0 else L
         emb = V * H + (0 if self.pos_embedding != "learned" else self.max_seq_len * H)
         head = 0 if self.tie_embeddings else V * H
+        # a layer is counted once however often it runs; a looped model's
+        # closing norm stands in the final norm's place, its gate beside it
         return ((L - n_moe) * dense_layer + n_moe * moe_layer
-                + emb + head + H)
+                + emb + head + H + (H + 1) * self.looped)
 
     @property
     def num_active_parameters(self) -> int:
@@ -904,9 +952,18 @@ class TransformerConfig:
         """Model FLOPs per token for one fwd+bwd (6·N_active + attention term:
         q·k and p·v over the heads' own widths)."""
         S = seq_len or self.max_seq_len
-        return 6 * self.num_active_parameters + sum(
+        once = 6 * self.num_active_parameters + sum(
             n * rec.attn_flops(self, S)
             for (_, _, n, _), rec in zip(self.type_runs, self.kinds))
+        if not self.looped:
+            return once
+        # every walk after the first runs the layers' matrices and their
+        # attention again (the embedding and the head run once)
+        H, qd, kvd = (self.hidden_size, self.num_heads * self.head_dim,
+                      self.kv_heads * self.head_dim)
+        layer = 2 * H * (qd + kvd) + self._mlp_params(self.mlp_dim)
+        return once + (self.loop_steps - 1) * self.num_layers * (
+            6 * layer + _heads_flops(self, S))
 
 
 # ----------------------------------------------------------------------------
@@ -1246,11 +1303,20 @@ class TransformerLM:
                 "wo": stacked(k[4], (nh * hd, H), resid_init),
             },
         }
-        if not post_ln:  # post-LN trunks end normalized; no final LN
+        if cfg.looped:
+            # the norm that closes every step and the exit gate: a stacked
+            # group of one layer in the final norm's place
+            params["loop"] = {"norm_scale": jnp.ones((1, H), dt),
+                              "exit_w": init(k[10], (1, H), dt),
+                              "exit_b": jnp.zeros((1,), dt)}
+        elif not post_ln:  # post-LN trunks end normalized; no final LN
             params["lnf_scale"] = jnp.ones((H,), dt)
         if not single_ln:
             params["blocks"]["ln2_scale"] = jnp.ones((L, H), dt)
         blocks = params["blocks"]
+        if cfg.post_norms:
+            blocks["post_attn_scale"] = jnp.ones((L, H), dt)
+            blocks["post_mlp_scale"] = jnp.ones((L, H), dt)
         E = cfg.num_experts
         if E > 0:
             blocks["moe_wg"] = stacked(k[10], (H, E))
@@ -1372,11 +1438,17 @@ class TransformerLM:
                 "wo": P(None, m, None),
             },
         }
-        if cfg.norm_position != "post":
+        if cfg.looped:
+            specs["loop"] = {"norm_scale": P(None, None),
+                             "exit_w": P(None, None), "exit_b": P(None)}
+        elif cfg.norm_position != "post":
             specs["lnf_scale"] = P(None)
         blocks = specs["blocks"]
         if not single_ln:
             blocks["ln2_scale"] = P(None, None)
+        if cfg.post_norms:
+            blocks["post_attn_scale"] = P(None, None)
+            blocks["post_mlp_scale"] = P(None, None)
         if cfg.num_experts > 0:
             # experts over the expert axis, expert-internal dims over model axis
             e = "expert"
@@ -1654,6 +1726,9 @@ class TransformerLM:
             attn_out = attn_out @ blk["wo"].astype(h.dtype)
             if "attn_bias" in blk:
                 attn_out = attn_out + blk["attn_bias"].astype(h.dtype)
+            if cfg.post_norms:
+                attn_out = self._post_norm(attn_out, blk["post_attn_scale"],
+                                           paged is not None)
             attn_out = self._constraint(attn_out, self._act_spec(kv_cache is None))
             if rng is not None:
                 rng, r1 = jax.random.split(rng)
@@ -1700,6 +1775,9 @@ class TransformerLM:
                 mlp_out = inter @ blk["w_down"].astype(h.dtype)
             if "mlp_bias" in blk:
                 mlp_out = mlp_out + blk["mlp_bias"].astype(h.dtype)
+            if cfg.post_norms:
+                mlp_out = self._post_norm(mlp_out, blk["post_mlp_scale"],
+                                          paged is not None)
             mlp_out = self._constraint(mlp_out, self._act_spec(kv_cache is None))
             if rng is not None:
                 rng, r2 = jax.random.split(rng)
@@ -1711,6 +1789,17 @@ class TransformerLM:
         if cfg.parallel_block:
             return x + attn_out + mlp_out, new_kv, aux
         return x + mlp_out, new_kv, aux
+
+    def _post_norm(self, y, scale, served):
+        """``post_norms``: a sublayer's output RMS-normed before it joins the
+        residual stream. Served, the product is whole first (the barrier: a
+        norm reads it twice, and XLA would stream the matrix for each;
+        :meth:`_typed_layer`)."""
+        cfg = self.config
+        if served:
+            y = jax.lax.optimization_barrier(y)
+        return _norm(y, scale, None, "rmsnorm", cfg.norm_eps,
+                     cfg.norm_weight_offset)
 
     def _block_mla(self, x, blk, *, positions, paged=None, step=None,
                    experts=None):
@@ -2046,9 +2135,67 @@ class TransformerLM:
             return jax.checkpoint(fn, policy=policy[name])
         return jax.checkpoint(fn)
 
+    def _loop_close(self, params, x, t, picked):
+        """The end of step ``t`` of a looped model on (..., H): the closing
+        norm, whose output is the step's state ``h_t`` and the next step's
+        input, and the exit gate ``lam_t = sigmoid(h_t . exit_w + exit_b)``
+        in float32. ``picked`` is the selection so far, ``(h_exit, done,
+        survive, reached)``: the exit distribution is ``p_t = lam_t prod_{j<t}
+        (1 - lam_j)`` (the last step takes what is left) and a token's
+        ``h_exit`` the state of the first step at which its running sum
+        reaches ``early_exit_threshold``, else the last step's. Returns
+        ``(h_t, picked)``; at a threshold of 1.0 or more the last step is
+        every token's, no gate is computed and ``picked`` stays None."""
+        cfg = self.config
+        loop = params["loop"]
+        with jax.named_scope("loop_close"):
+            h = _norm(x, loop["norm_scale"][0], None, cfg.norm, cfg.norm_eps,
+                      cfg.norm_weight_offset)
+            if cfg.early_exit_threshold >= 1.0:
+                return h, None
+            f32 = jnp.float32
+            lam = jax.nn.sigmoid(
+                jnp.sum(h.astype(f32) * loop["exit_w"][0].astype(f32), axis=-1)
+                + loop["exit_b"][0].astype(f32))
+            if picked is None:
+                picked = (jnp.zeros_like(h), jnp.zeros(lam.shape, bool),
+                          jnp.ones_like(lam), jnp.zeros_like(lam))
+            h_exit, done, survive, reached = picked
+            last = t == cfg.loop_steps - 1
+            reached = reached + (survive if last else lam * survive)
+            take = ~done & ((reached >= cfg.early_exit_threshold) | last)
+            return h, (jnp.where(take[..., None], h, h_exit), done | take,
+                       survive * (1.0 - lam), reached)
+
+    def _looped(self, params, x, walk):
+        """``walk(x, t) -> (x, extra)`` over the model's steps: once, or for
+        a looped model ``loop_steps`` times with :meth:`_loop_close` behind
+        each. Returns (what the head reads, [extra of each step]). Every
+        function that walks the layers goes through here, so none runs one
+        step of a looped model silently."""
+        cfg = self.config
+        if not cfg.looped:
+            x, extra = walk(x, 0)
+            return x, [extra]
+        extras, picked = [], None
+        for t in range(cfg.loop_steps):
+            x, extra = walk(x, t)
+            extras.append(extra)
+            x, picked = self._loop_close(params, x, t, picked)
+        return (x if picked is None else picked[0]), extras
+
     def _trunk(self, params, x, positions, rng, train, pld_theta=None,
                attn_mask_bias=None):
-        """Run all blocks via scan (remat optional). With ``pld_theta``
+        """All blocks, a looped model's ``loop_steps`` times (:meth:`_walk`);
+        returns (what the head reads, the auxiliary loss)."""
+        x, auxes = self._looped(params, x, lambda x, t: self._walk(
+            params, x, positions, rng, train, pld_theta=pld_theta,
+            attn_mask_bias=attn_mask_bias))
+        return x, sum(auxes[1:], auxes[0])
+
+    def _walk(self, params, x, positions, rng, train, pld_theta=None,
+              attn_mask_bias=None):
+        """Run all blocks once via scan (remat optional). With ``pld_theta``
         (progressive layer drop, reference ``progressive_layer_drop.py``),
         layer l keeps with prob 1 - (l/L)(1 - theta) — deeper layers dropped more."""
         cfg = self.config
@@ -2058,6 +2205,10 @@ class TransformerLM:
         if use_rng and len(cfg.type_runs) > 1:
             raise NotImplementedError(
                 "dropout / progressive layer drop over two layer groups")
+        if use_rng and cfg.looped:
+            raise NotImplementedError(
+                f"loop_steps {cfg.loop_steps}: dropout / progressive layer "
+                "drop draw one key a layer, not one a layer and step")
 
         if use_rng:
             rngs = jax.random.split(rng, L)
@@ -2116,6 +2267,10 @@ class TransformerLM:
         from ..runtime.data_pipeline.data_routing import random_ltd_block
 
         cfg = self.config
+        if cfg.looped:
+            raise NotImplementedError(
+                f"loop_steps {cfg.loop_steps}: random-LTD walks the layers "
+                "once")
         L, skip = cfg.num_layers, cfg.random_ltd_skip_ends
         use_drop = cfg.dropout > 0
         rngs = jax.random.split(rng, L)  # rng is never None here (_hidden_aux)
@@ -2185,7 +2340,9 @@ class TransformerLM:
             x = _norm(x, params["mlm_ln_scale"], params["mlm_ln_bias"],
                       "layernorm", cfg.norm_eps)
             return x, params["wte"], params["mlm_bias"], True
-        if cfg.norm_position != "post":  # post-LN trunks end already normalized
+        # post-LN trunks end already normalized, and a looped model's last
+        # step was closed by its norm (``_loop_close``)
+        if cfg.norm_position != "post" and not cfg.looped:
             x = _norm(x, params["lnf_scale"], params.get("lnf_bias"),
                       cfg.norm, cfg.norm_eps, cfg.norm_weight_offset)
         if cfg.dim_model_base:  # muP: the head reads N(x) * base / width
@@ -2312,7 +2469,9 @@ class TransformerLM:
     # ------------------------------------------------------------------
     def init_kv_cache(self, batch_size: int, max_len: int, dtype=jnp.bfloat16):
         cfg = self.config
-        shape = (cfg.num_layers, batch_size, max_len, cfg.kv_heads, cfg.head_dim)
+        # a looped model's step ``t`` owns layers ``t * num_layers`` on
+        shape = (cfg.loop_steps * cfg.num_layers, batch_size, max_len,
+                 cfg.kv_heads, cfg.head_dim)
         return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
     def _trunk_with_cache(self, params, input_ids, kv_cache, cache_index, positions):
@@ -2332,8 +2491,18 @@ class TransformerLM:
             )
             return y, new_kv
 
-        x, (nk, nv) = jax.lax.scan(body, x, (params["blocks"], kv_cache[0], kv_cache[1]))
-        return x, (nk, nv)
+        L = self.config.num_layers
+
+        def walk(x, t):
+            # step ``t`` of a looped model has cache layers of its own
+            caches = tuple(c[t * L:(t + 1) * L] for c in kv_cache) \
+                if self.config.looped else tuple(kv_cache)
+            return jax.lax.scan(body, x, (params["blocks"], *caches))
+
+        x, steps = self._looped(params, x, walk)
+        if len(steps) == 1:
+            return x, steps[0]
+        return x, tuple(jnp.concatenate(c) for c in zip(*steps))
 
     # ------------------------------------------------------------------
     # paged (blocked) KV cache — reference inference/v2 BlockedKVCache path
@@ -2484,7 +2653,15 @@ class TransformerLM:
         # the device: what they do to their carried arrays and to the stacked
         # leaves they slice (a layer's matrix materialised out of the stack
         # and relaid reads here, not under the layer)
-        with jax.named_scope("kv_carry"):
+        # a looped model walks its groups ``loop_steps`` times, each walk a
+        # scan of its own behind the last: the pool stays one carry from scan
+        # to scan and the stacked leaves are scanned where they lie, where an
+        # outer loop around the scans would carry both through a loop that
+        # does not touch them. ``pool_layer`` counts on from walk to walk, so
+        # step ``t`` reads and writes the cache layers from ``t *`` one
+        # walk's on
+        def walk(x, t):
+            nonlocal tally
             for (key, _, n, per), rec in zip(cfg.type_runs, cfg.kinds):
                 leaves = params[key]
                 # held experts stay out of the scanned leaves: the grouped
@@ -2516,6 +2693,10 @@ class TransformerLM:
                 if per:
                     pools[cls] = caches["pool"]
                     pool_layer[cls] += per * n
+            return x, None
+
+        with jax.named_scope("kv_carry"):
+            x, _ = self._looped(params, x, walk)
         kv_pool = pools if cfg.bounded_cache else pools["full"]
         # only the last position is projected (and of those rows, only
         # ``logit_rows``)
@@ -2589,8 +2770,7 @@ class TransformerLM:
                 if name and cfg.qk_norm else y
 
         def post(y, name):
-            return _norm(once(y), blk[name], None, "rmsnorm", cfg.norm_eps) \
-                if cfg.post_norms else y
+            return self._post_norm(y, blk[name], True) if cfg.post_norms else y
 
         with jax.named_scope("attn"), (
                 jax.named_scope(scope) if scope else contextlib.nullcontext()):

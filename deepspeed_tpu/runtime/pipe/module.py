@@ -446,6 +446,10 @@ class PipelinedLM:
                 f"{model.config.num_layers} layers not divisible by "
                 f"{self.num_stages} pipeline stages"
             )
+        if model.config.looped:
+            raise NotImplementedError(
+                f"loop_steps {model.config.loop_steps}: the pipeline's stages "
+                "walk the layers once")
         self.num_micro = 1  # set by the engine
 
     # ------------------------------------------------------------------

@@ -51,6 +51,10 @@ class StreamedZeroEngine:
             raise ValueError(
                 "offload_param streaming supports dense models only "
                 "(no MoE / PLD / random-LTD)")
+        if mcfg.looped:
+            raise NotImplementedError(
+                f"loop_steps {mcfg.loop_steps}: offload_param streaming walks "
+                "the layers once")
         if config.fp16_enabled:
             raise ValueError("offload_param streaming: use bf16 or fp32, not fp16")
         self.model = model

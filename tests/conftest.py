@@ -107,6 +107,7 @@ _LONGEST_FIRST = (
     "tests/benchmark/test_afmoe.py",
     "tests/benchmark/test_falcon_h1.py",
     "tests/benchmark/test_bailing_hybrid.py",
+    "tests/benchmark/test_ouro.py",
     "tests/unit/test_extras.py",
     "tests/unit/test_flash_layout.py",
     "tests/unit/test_pipe.py",
