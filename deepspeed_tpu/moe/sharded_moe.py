@@ -95,7 +95,8 @@ def group_limited_gating(logits, bias=None, *, k: int, n_group: int = 1,
     Scores ``s`` are the sigmoid of the float32 ``logits`` (T, E). ``bias`` (E,) is added for SELECTION only. The outputs form
     ``n_group`` contiguous groups; a group scores the sum of its two largest
     biased scores, the ``topk_group`` best groups are kept and the ``k``
-    largest biased scores are taken among their experts. The weights are the
+    largest biased scores are taken among their experts (a tie goes to the
+    lower index, among groups as among experts). The weights are the
     unbiased ``s`` of the chosen, divided by their sum over all ``k`` (held
     on this chip or not) and multiplied by ``scale``."""
     T, E = logits.shape
@@ -103,11 +104,21 @@ def group_limited_gating(logits, bias=None, *, k: int, n_group: int = 1,
     s = jax.nn.sigmoid(logits)
     biased = s if bias is None else s + bias.astype(jnp.float32)
     if n_group > 1:
+        # nothing is sorted to choose the groups (on the chip a ``top_k``
+        # is a sort of every score): a group's two largest are its maximum
+        # and the maximum with ONE occurrence of it taken out
         by_group = biased.reshape(T, n_group, E // n_group)
-        group_score = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)
-        kept = lax.top_k(group_score, topk_group)[1]            # (T, topk_group)
-        group_ok = jnp.any(
-            kept[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+        m1 = jnp.max(by_group, axis=-1)
+        top = by_group == m1[:, :, None]
+        m2 = jnp.where(jnp.sum(top, axis=-1) > 1, m1,
+                       jnp.max(jnp.where(top, -jnp.inf, by_group), axis=-1))
+        group_score = m1 + m2                                   # (T, n_group)
+        # a group is kept iff fewer than ``topk_group`` stand ahead of it:
+        # a larger score, or an equal one at a lower index (``top_k``'s order)
+        mine, other = group_score[:, :, None], group_score[:, None, :]
+        g = jnp.arange(n_group)
+        ahead = (other > mine) | ((other == mine) & (g[None, :] < g[:, None]))
+        group_ok = jnp.sum(ahead, axis=-1) < topk_group
         biased = jnp.where(jnp.repeat(group_ok, E // n_group, axis=1),
                            biased, -jnp.inf)
     chosen = lax.top_k(biased, k)[1].astype(jnp.int32)
@@ -115,6 +126,22 @@ def group_limited_gating(logits, bias=None, *, k: int, n_group: int = 1,
     if normalize:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return chosen, w * scale
+
+
+def _first_k(scores, k: int):
+    """(T, k) int32: each row's ``k`` largest of ``scores`` (T, E) in
+    ``lax.top_k``'s order, a tie to the lower index, as ``k`` rounds of the
+    first ``argmax`` and a mask. Every row must hold ``k`` scores above
+    ``-inf``. On the chip a ``top_k`` is a sort of the whole array: at 12 of
+    768 the rounds take 14 us where it takes 37; at 8 of 256 or fewer the
+    sort wins (``examples/kernels/route_alone.py``)."""
+    cols = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :]
+    picks = []
+    for _ in range(k):
+        i = jnp.argmax(scores, axis=-1).astype(jnp.int32)
+        picks.append(i)
+        scores = jnp.where(cols == i[:, None], -jnp.inf, scores)
+    return jnp.stack(picks, axis=1)
 
 
 def softmax_topk_gating(logits, bias=None, *, k: int, normalize: bool = False,
@@ -132,7 +159,7 @@ def softmax_topk_gating(logits, bias=None, *, k: int, normalize: bool = False,
     E = logits.shape[1]
     p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     biased = p if bias is None else p + bias.astype(jnp.float32) / E
-    chosen = lax.top_k(biased, k)[1].astype(jnp.int32)
+    chosen = _first_k(biased, k)
     w = jnp.take_along_axis(p, chosen, axis=1)
     if normalize:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
